@@ -585,7 +585,7 @@ def main():
                 out = run()
             if out is None:  # --warmup 0: still need one compile pass
                 out = run()
-            device_sync(out)  # readback barrier: block_until_ready lies
+            device_sync(out)
             t0 = time.perf_counter()
             for _ in range(args.iters):
                 out = run()

@@ -3,7 +3,7 @@ tuning table (round-3 VERDICT #2: "flash block sweep -> bake winning
 defaults into ops/flash_attention.py").
 
 Reads the `flash_sweep_*` rows that `benchmarks/flash_bench.py`
-persists into benchmarks/results.json when run on real TPU hardware,
+persists into chiprun_out/bench_results.json when run on real TPU hardware,
 and writes `pytorch_distributed_example_tpu/ops/flash_tuned.json` —
 the table `resolved_block_sizes` consults when no per-call or env
 override is given. Training (fwd+bwd) winners are used since the
@@ -21,7 +21,7 @@ import re
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RESULTS = os.path.join(ROOT, "benchmarks", "results.json")
+RESULTS = os.path.join(ROOT, "chiprun_out", "bench_results.json")
 OUT = os.path.join(
     ROOT, "pytorch_distributed_example_tpu", "ops", "flash_tuned.json"
 )
@@ -29,7 +29,7 @@ OUT = os.path.join(
 
 def main() -> int:
     if not os.path.exists(RESULTS):
-        print("no results.json; nothing to bake")
+        print("no chiprun_out/bench_results.json; nothing to bake")
         return 1
     with open(RESULTS) as f:
         doc = json.load(f)

@@ -75,7 +75,7 @@ def main():
     p = ddp.params
     for i in range(args.warmup):
         p, opt_state, loss = step(p, opt_state, x, y, jax.random.PRNGKey(i))
-    device_sync(loss)  # readback barrier: block_until_ready lies here
+    device_sync(loss)
 
     t0 = time.perf_counter()
     for i in range(args.steps):

@@ -65,7 +65,7 @@ def main():
 
     # warmup: compiles prefill + decode body (both call shapes)
     out = generate(model, params, prompt, args.new, rng=jax.random.PRNGKey(1))
-    device_sync(out)  # readback barrier: block_until_ready lies here
+    device_sync(out)
     out = generate(model, params, prompt, 1, rng=jax.random.PRNGKey(1))
     device_sync(out)
 

@@ -46,7 +46,7 @@ Usage: python benchmarks/load_harness.py [--preset tiny|small]
 
 Registered in benchmarks/run_all.py as `serve_autoscale` (quick
 hermetic + full); on TPU the record self-persists into
-benchmarks/results.json like every serve row.
+chiprun_out/bench_results.json like every serve row.
 """
 
 from __future__ import annotations

@@ -179,7 +179,7 @@ def main():
         host_cpus=host_cpus,
         caveat=(
             f"host has {host_cpus} core(s): CPU-bound workloads (numpy, "
-            "decode) cannot scale past ~1x on this box in ANY worker "
+            "decode) cannot scale past ~1x on this host in ANY worker "
             "model; the io rows isolate the dispatch pipeline, which is "
             "what transfers to multi-core hosts"
         ) if host_cpus <= 2 else None,

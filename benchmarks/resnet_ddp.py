@@ -93,7 +93,7 @@ def main():
     opt_state = opt.init(params)
     for _ in range(args.warmup):
         params, batch_stats, opt_state, loss = step(params, batch_stats, opt_state, x, y)
-    device_sync(loss)  # readback barrier: block_until_ready lies here
+    device_sync(loss)
 
     t0 = time.perf_counter()
     for _ in range(args.steps):

@@ -29,7 +29,7 @@ Usage: python benchmarks/serve_prefix.py [--preset tiny|small|base]
     [--prefill-chunk 32] [--kv-quant] [--bf16]
 
 Registered in benchmarks/run_all.py (quick + full); on TPU the record
-self-persists into benchmarks/results.json like every serve row.
+self-persists into chiprun_out/bench_results.json like every serve row.
 """
 
 from __future__ import annotations

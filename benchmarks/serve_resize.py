@@ -28,7 +28,14 @@ import cost is identical in both arms and reported separately) and
 closes at the probe's first token (`Completion.ttft_s` on the
 engine's own clock). The headline is the ratio; the acceptance bar is
 ``>= 5x``. Registered in benchmarks/run_all.py (quick + full); on TPU
-the record self-persists into benchmarks/results.json.
+the record self-persists into chiprun_out/bench_results.json.
+
+Caveat (PR 21): the cold and warm arms differ only in the cache
+DIRECTORY each child is handed, and every cache directory now goes
+through `_compat.enable_compile_cache`, where a machine-level
+`JAX_COMPILATION_CACHE_DIR` wins. On a machine that pins that variable
+both arms share one cache and the comparison means nothing — run it with
+the variable unset. Whether this row survives is ROADMAP S1's call.
 
 Usage: python benchmarks/serve_resize.py [--reps 2] [--slots 4]
 """

@@ -12,11 +12,11 @@ collective op names (`all-reduce` / `all-gather` / `collective-permute`
 scan is a dependency-free assertion that the collectives are ON the
 timeline, not just in the program.
 
-The durable record is the emitted JSON (run_all persists it in
-benchmarks/results.json); trace dirs themselves are .gitignored
-(MB-scale) — `git add -f` a curated TPU capture when one lands.
+The durable record is the emitted JSON (run_all persists it beside the
+other rows); the trace itself lands under `chiprun_out/` (MB-scale,
+git-ignored — what a chip run brings back).
 
-Usage: python benchmarks/trace_evidence.py [--out benchmarks/traces]
+Usage: python benchmarks/trace_evidence.py [--out chiprun_out/traces]
 Emits: {"metric": "trace_evidence", "value": 1.0, ...} on success.
 """
 
@@ -41,7 +41,7 @@ COLLECTIVE_MARKERS = (
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--out", default="benchmarks/traces")
+    ap.add_argument("--out", default="chiprun_out/traces")
     ap.add_argument("--steps", type=int, default=5)
     args = ap.parse_args()
 
@@ -73,7 +73,7 @@ def main():
 
     p = ddp.params
     p, opt_state, loss = step(p, opt_state, x, y)  # compile outside trace
-    device_sync(loss)  # readback barrier: block_until_ready lies here
+    device_sync(loss)
 
     run_dir = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

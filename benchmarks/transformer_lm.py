@@ -108,7 +108,7 @@ def main():
     p, s = mod.params, opt_state
     for _ in range(args.warmup):
         p, s, loss = step(p, s, toks, toks)
-    device_sync(loss)  # readback barrier: block_until_ready lies here
+    device_sync(loss)
     t0 = time.perf_counter()
     for _ in range(args.steps):
         p, s, loss = step(p, s, toks, toks)

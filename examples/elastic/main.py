@@ -49,6 +49,9 @@ def main():
         from pytorch_distributed_example_tpu._compat import force_cpu_devices
 
         force_cpu_devices(1)
+    from pytorch_distributed_example_tpu._compat import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax.numpy as jnp
     import numpy as np

@@ -43,6 +43,9 @@ def main():
         from pytorch_distributed_example_tpu._compat import force_cpu_devices
 
         force_cpu_devices(int(os.environ.get("TDX_EXAMPLES_CPU_DEVICES", "2")))
+    from pytorch_distributed_example_tpu._compat import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax.numpy as jnp
     import optax

@@ -2,11 +2,13 @@
 
 Plays the role of torch's pybind11 surface (`_C/_distributed_c10d.pyi`,
 SURVEY.md §2.2 N18) with ctypes instead of pybind11 (not available in this
-environment — task rules). The library is built on demand with `make`; if
-the toolchain is missing, callers fall back to the pure-Python
-implementations (store.py, reducer.py) transparently.
+environment — task rules). `libtdx.so` is git-ignored, so what is on disk
+proves nothing: every first `load()` runs `make`, which rebuilds when the
+library is absent or older than the tracked `csrc/*.cpp`. If the build
+fails (no toolchain), callers use the pure-Python implementations
+(store.py, reducer.py) — and `status()` says so, with the reason.
 
-Env: TDX_NATIVE=0 disables native entirely (forces Python fallbacks).
+Env: TDX_NATIVE=0 disables native entirely (forces the Python paths).
 """
 
 from __future__ import annotations
@@ -15,57 +17,74 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 from typing import Optional
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_status = "not loaded yet"
 
 _CSRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "csrc")
 _SO = os.path.join(_CSRC, "libtdx.so")
 
 
-def _make(force: bool = False) -> bool:
+def _make() -> Optional[str]:
+    """Run the csrc Makefile; None on success, else the reason."""
     try:
-        cmd = ["make", "-C", _CSRC] + (["-B"] if force else [])
-        subprocess.run(cmd, capture_output=True, timeout=120, check=True)
-        return True
-    except Exception:
-        return False
+        subprocess.run(
+            ["make", "-C", _CSRC], capture_output=True, timeout=120,
+            check=True,
+        )
+    except FileNotFoundError as e:
+        return f"make not found ({e})"
+    except subprocess.TimeoutExpired:
+        return "make timed out after 120 s"
+    except subprocess.CalledProcessError as e:
+        tail = (e.stderr or b"").decode(errors="replace").strip()[-300:]
+        return f"make failed (rc={e.returncode}): {tail}"
+    return None
+
+
+def status() -> str:
+    """Which path `load()` took: "native: <path> (built from source)",
+    "native: <path> (up to date)", "python: <why>"."""
+    load()
+    return _status
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """Load (building if needed) the native library, or None."""
-    global _lib, _tried
+    """Load the native library, (re)building it from the tracked sources
+    first if they are newer; None means the Python paths are in use."""
+    global _lib, _tried, _status
     if os.environ.get("TDX_NATIVE", "1") == "0":
+        _status = "python: TDX_NATIVE=0"
         return None
     with _lock:
         if _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) and not _make():
-            return None
-        for attempt in (0, 1):
-            lib = None
+        before = os.path.getmtime(_SO) if os.path.exists(_SO) else None
+        err = _make()
+        if err is None:
             try:
-                lib = ctypes.CDLL(_SO)
-                _lib = _bind(lib)
+                _lib = _bind(ctypes.CDLL(_SO))
+            except (OSError, AttributeError) as e:
+                err = f"{type(e).__name__}: {e}"
+            else:
+                built = before is None or os.path.getmtime(_SO) != before
+                _status = f"native: {_SO} (" + (
+                    "built from source" if built else "up to date"
+                ) + ")"
                 return _lib
-            except (OSError, AttributeError):
-                # stale .so missing newer symbols: dlclose the mapped copy
-                # (else re-dlopen returns the same stale mapping) and force
-                # one rebuild
-                if lib is not None:
-                    try:
-                        import _ctypes
-
-                        _ctypes.dlclose(lib._handle)
-                    except Exception:
-                        pass
-                if attempt == 0 and _make(force=True):
-                    continue
-                _lib = None
-                return None
+        _status = f"python: native build unavailable — {err}"
+        warnings.warn(
+            "libtdx.so could not be built; using the Python store and "
+            f"reducer ({err})",
+            RuntimeWarning,
+            stacklevel=2,
+        )
+        return None
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:

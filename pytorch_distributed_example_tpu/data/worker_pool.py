@@ -33,6 +33,7 @@ import multiprocessing as mp
 import os
 import pickle
 import queue as queue_mod
+import sys
 import traceback
 from dataclasses import dataclass
 from multiprocessing import shared_memory
@@ -137,6 +138,13 @@ def _worker_main(
     down. `run` tags which run_epoch() call dispatched the task, so the
     parent can discard leftovers of an abandoned iteration."""
     global _WORKER_INFO
+    # A loader worker never opens an accelerator: the parent owns the chip,
+    # and a second process reaching for it fails or hangs. The package
+    # imports jax without touching a backend, so this only matters if a
+    # dataset or collate_fn does — it then gets the CPU.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
     segments: List[Optional[shared_memory.SharedMemory]] = [None] * prefetch_factor
     # worker_init_fn runs ONCE per worker lifetime (torch's contract,
     # incl. persistent_workers=True) — per-epoch re-invocation would

@@ -391,13 +391,15 @@ def init_process_group(
         multiproc = jax.process_count() > 1
     except Exception as e:
         # First backend touch in many programs lands here; surface an
-        # actionable message instead of the raw PJRT plugin trace
-        # (round-1 BENCH died on exactly this, bench.py now retries).
+        # actionable message instead of the raw PJRT trace. The usual
+        # cause on a TPU host is another process holding the chip.
         raise RuntimeError(
             "init_process_group: JAX backend initialization failed "
-            f"({type(e).__name__}: {e}). If the TPU plugin is unavailable, "
-            "set JAX_PLATFORMS=cpu (optionally with XLA_FLAGS="
-            "--xla_force_host_platform_device_count=N) and retry."
+            f"({type(e).__name__}: {e}). A chip belongs to one process at "
+            "a time: check that no other process (a parent that touched "
+            "JAX, a stale worker) holds it. For a CPU run set "
+            "JAX_PLATFORMS=cpu (optionally with XLA_FLAGS="
+            "--xla_force_host_platform_device_count=N)."
         ) from e
     if multiproc:
         _world.mode = "multiproc"

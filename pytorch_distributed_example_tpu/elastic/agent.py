@@ -29,6 +29,73 @@ from .. import faults
 from ..store import TCPStore
 
 
+def local_tpu_chips() -> int:
+    """TPU chips on this host, counted from the device nodes their driver
+    creates (`/dev/vfio/N` on v5e and newer, `/dev/accelN` before) —
+    without importing JAX: an agent that opened the chips itself would
+    hold them against the workers it is about to spawn."""
+    import glob
+
+    return len(glob.glob("/dev/vfio/[0-9]*")) or len(
+        glob.glob("/dev/accel[0-9]*")
+    )
+
+
+# libtpu's process grid for "one process per chip" on one host, by chip
+# count (the host's chip topology: 2x2 for four chips, 2x4 for eight)
+_TPU_PROCESS_BOUNDS = {4: "2,2,1", 8: "2,4,1"}
+
+
+def tpu_chip_envs(nproc: int, env, free_port) -> List[Dict[str, str]]:
+    """Per local rank, the environment that gives that worker exactly ONE
+    chip of this host, so that ``nproc`` workers form one gang over ICI.
+
+    A chip belongs to one process: without this, every worker opens all
+    the host's chips and all but the first fail or hang. Every entry is
+    empty when the workers are pinned to the CPU, when the host has no
+    TPU, or when one worker owns the host (driver mode, the single-host
+    deployment). Anything else than one worker per chip cannot be laid
+    out and raises by name before any worker starts. ``free_port()``
+    supplies the port each worker's libtpu listens on.
+
+    Both spellings of the two bounds are set: the host image exports the
+    older `TPU_HOST_BOUNDS` / `TPU_CHIPS_PER_HOST_BOUNDS` for one
+    process owning every chip, and a child must not inherit them.
+    """
+    on_cpu = env.get("JAX_PLATFORMS", "").split(",")[0].strip().lower() == "cpu"
+    chips = 0 if on_cpu else local_tpu_chips()
+    if chips == 0 or nproc == 1:
+        return [{} for _ in range(nproc)]
+    if nproc != chips or chips not in _TPU_PROCESS_BOUNDS:
+        raise RuntimeError(
+            f"tpurun: {nproc} workers on a host with {chips} TPU chip(s). "
+            "A chip belongs to one process at a time, so a multi-process "
+            "gang needs exactly one worker per chip (supported chip "
+            f"counts: {sorted(_TPU_PROCESS_BOUNDS)}); use --nproc-per-node "
+            f"{chips}, or 1 for driver mode (one process owning every "
+            "chip), or JAX_PLATFORMS=cpu for a CPU gang"
+        )
+    bounds = _TPU_PROCESS_BOUNDS[chips]
+    ports = [free_port() for _ in range(nproc)]
+    shared = {
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_CHIPS_PER_HOST_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": bounds,
+        "TPU_HOST_BOUNDS": bounds,
+        "TPU_PROCESS_ADDRESSES": ",".join(f"localhost:{p}" for p in ports),
+    }
+    return [
+        {
+            **shared,
+            "TPU_VISIBLE_CHIPS": str(r),
+            "TPU_VISIBLE_DEVICES": str(r),
+            "TPU_PROCESS_PORT": str(ports[r]),
+            "CLOUD_TPU_TASK_ID": str(r),
+        }
+        for r in range(nproc)
+    ]
+
+
 class WorkerState(enum.Enum):
     INIT = "INIT"
     HEALTHY = "HEALTHY"
@@ -460,11 +527,13 @@ class LocalElasticAgent:
                 gen=self.restart_count,
             )
         self._prev_world = world
+        base_env = {**os.environ, **self.spec.env}
+        chip_envs = tpu_chip_envs(nproc, base_env, self._free_port)
         for r in range(nproc):
             global_rank = grank * nproc + r
             env = {
-                **os.environ,
-                **self.spec.env,
+                **base_env,
+                **chip_envs[r],
                 "RANK": str(global_rank),
                 "LOCAL_RANK": str(r),
                 "GROUP_RANK": str(grank),

@@ -5,8 +5,9 @@ Covers BASELINE.json configs #4/#5 ("BERT-base fine-tune", "Llama-3-8B
 FSDP full-shard → GSPMD"; SURVEY.md §6). TPU-native design:
 
 * RMSNorm + RoPE + SwiGLU + grouped-query attention (Llama topology);
-* attention runs the Pallas flash kernel (`ops/flash_attention.py`) on
-  TPU, dense softmax elsewhere/when disabled;
+* attention runs the Pallas flash kernel (`ops/flash_attention.py`;
+  compiled on TPU, interpreted elsewhere), dense softmax when disabled
+  or when the shape cannot be tiled (warned);
 * bf16-friendly: params fp32, activations cast to `dtype`, logits fp32;
 * `sharding_rules()` emits the canonical 2-D Megatron(+ZeRO) GSPMD layout
   (scaling-book recipe): attention/MLP in-features over ``fsdp``,
@@ -364,16 +365,27 @@ class Attention(nn.Module):
 
 
 def _flash_ok(L: int, Dh: int) -> bool:
-    # kernel constraint: L divisible by the EFFECTIVE block sizes.
-    # resolved_block_sizes FITS env/table candidates (halving, 128
-    # fallback) so they tile L whenever possible; this gate still
-    # catches lengths nothing can tile (e.g. L not a multiple of any
-    # candidate), falling back to dense attention instead of raising
-    # at trace time
+    """Whether the flash kernel can take this shape: L divisible by the
+    EFFECTIVE block sizes (`resolved_block_sizes` fits env/table
+    candidates so they tile L whenever possible) and head_dim within the
+    kernel's VMEM tile. A `use_flash=True` model that lands on dense
+    attention instead says so once per shape (the O(L^2) logits are a
+    different memory and speed regime, not a detail)."""
     from ..ops.flash_attention import resolved_block_sizes
 
     bq, bk = resolved_block_sizes(L)
-    return L % bq == 0 and L % bk == 0 and Dh <= 256
+    ok = L % bq == 0 and L % bk == 0 and Dh <= 256
+    if not ok:
+        import warnings
+
+        warnings.warn(
+            f"use_flash=True but the flash kernel cannot take L={L}, "
+            f"head_dim={Dh} (blocks {bq}x{bk}, head_dim limit 256): "
+            "running DENSE attention for this shape",
+            RuntimeWarning,
+            stacklevel=2,
+        )
+    return ok
 
 
 class MLP(nn.Module):

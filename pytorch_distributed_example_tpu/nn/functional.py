@@ -30,7 +30,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from .._compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 from ..types import ReduceOp
 

@@ -3,7 +3,11 @@ block-scaled quantization codec (`quant.py`) shared by the quantized
 collectives and the int8 paged KV cache."""
 
 from . import quant  # noqa: F401
-from .flash_attention import flash_attention, gather_paged_kv  # noqa: F401
+from .flash_attention import (  # noqa: F401
+    flash_attention,
+    gather_paged_kv,
+    partitioned_over,
+)
 from .quant import (  # noqa: F401
     dequantize_blockwise,
     dequantize_kv,

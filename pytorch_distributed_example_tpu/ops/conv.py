@@ -2,7 +2,7 @@
 
 XLA:CPU lowers the INPUT-gradient of a convolution to a transposed
 direct conv that measures ~2x slower than routing the same cotangent
-through an im2col formulation (this box, 12x12x10 -> 8x8x20 k5 grads:
+through an im2col formulation (one CPU host, 12x12x10 -> 8x8x20 k5 grads:
 5.6 ms lax vs 2.7 ms im2col; the forward and weight-grad direct convs
 are already the fast path). `conv2d_valid_nhwc` is therefore a
 custom_vjp whose backward mixes the best lowering per operand:

@@ -27,48 +27,45 @@ Layout note: public API takes (B, L, H, D) to match
 
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Optional
+import math
+import threading
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .._compat import tpu_compiler_params
+from .._compat import shard_map_fn
 
 NEG_INF = -1e30
 
 
 def _compiler_params(pltpu):
-    """The fwd kernel's (parallel, parallel, arbitrary) grid semantics,
-    via the version-compat `CompilerParams` constructor."""
-    return tpu_compiler_params(
+    """The streamed kernels' (parallel, parallel, arbitrary) grid
+    semantics."""
+    return pltpu.CompilerParams(
         dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                              pltpu.ARBITRARY),
     )
 
 
 def _interpret_default() -> bool:
-    """Compile only where Mosaic can lower (a TPU device); interpret elsewhere.
+    """Compile where Mosaic can lower (a TPU backend); interpret elsewhere.
 
-    Checked via device platform, not just backend name, so TPU plugins
-    registered under other platform names still get the compiled path.
-    TDX_FLASH_INTERPRET=0/1 overrides both — needed when AOT-compiling
-    for a DEVICELESS TPU topology from a CPU-pinned process, where the
-    attached-device heuristic would wrongly pick interpret mode.
+    On a TPU backend the answer is always "compile": no setting reaches
+    the interpreter there. `TDX_FLASH_INTERPRET=0` exists for one case
+    only — AOT-compiling for a DEVICELESS TPU topology from a CPU-pinned
+    process (`benchmarks/tpu_aot_check.py`), where the backend is not
+    "tpu" but the target is.
     """
     import os
 
-    env = os.environ.get("TDX_FLASH_INTERPRET")
-    if env is not None:
-        return env != "0"
     if jax.default_backend() == "tpu":
         return False
-    try:
-        return not any(d.platform == "tpu" for d in jax.devices())
-    except Exception:
-        return True
+    return os.environ.get("TDX_FLASH_INTERPRET") != "0"
 
 
 # ---------------------------------------------------------------------------
@@ -679,17 +676,17 @@ def _tuned_table() -> dict:
     by `benchmarks/flash_bench.py` and baked by
     `benchmarks/bake_flash_defaults.py` (the cuDNN-heuristic pattern:
     sweep once per geometry on hardware, ship the winners). Keys are
-    "L{seq}" plus "default"; absent/unreadable file = empty table."""
+    "L{seq}" plus "default". The file is tracked, so one that is missing
+    or does not parse is a broken checkout and raises."""
     import json
     import os
 
     path = os.path.join(os.path.dirname(__file__), "flash_tuned.json")
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-        return doc if isinstance(doc, dict) else {}
-    except Exception:
-        return {}
+    with open(path) as f:
+        doc = json.load(f)
+    if not isinstance(doc, dict):
+        raise ValueError(f"{path}: expected a JSON object, got {type(doc)}")
+    return doc
 
 
 _env_fit_warned: set = set()  # (env_name, requested, L, fitted) already warned
@@ -768,6 +765,51 @@ def resolved_block_sizes(
     return block_q, block_k
 
 
+class _MeshPartition(threading.local):
+    """(mesh, batch_axes, head_axes) while a `partitioned_over` context is
+    open on this thread, else None."""
+
+    spec = None
+
+
+_partition = _MeshPartition()
+
+
+@contextlib.contextmanager
+def partitioned_over(mesh, batch_axes: Sequence[str], head_axes: Sequence[str] = ()):
+    """Trace-time context: run `flash_attention` per device under a mesh.
+
+    A Mosaic kernel is a custom call GSPMD cannot partition, so inside a
+    jit whose operands are sharded over ``mesh`` (the FSDP/ZeRO/TP
+    trainers) the kernel must be told its layout. While this context is
+    open, `flash_attention` wraps itself in a `shard_map` over ``mesh``
+    with q/k/v/o laid out `P(batch_axes, None, head_axes, None)`: each
+    device runs the kernel on its local (batch-shard, head-shard) slice.
+    Attention is independent per (batch row, head), so no collective is
+    needed and none is introduced; L and D stay whole.
+
+    The GSPMD trainer factories (`parallel/fsdp.py`) open it around their
+    traced step; a hand-written `jax.jit` over `parallelize_module`'d
+    params opens it the same way. Do NOT open it inside a `shard_map`
+    region that already owns the mesh axes (the DDP step, ring
+    attention): there the kernel is already local.
+    """
+    jmesh = getattr(mesh, "jax_mesh", mesh)
+    sizes = dict(jmesh.shape)
+    batch_axes, head_axes = tuple(batch_axes), tuple(head_axes)
+    for ax in batch_axes + head_axes:
+        if ax not in sizes:
+            raise ValueError(
+                f"partitioned_over: mesh has no axis {ax!r}: {tuple(sizes)}"
+            )
+    prev = _partition.spec
+    _partition.spec = (jmesh, batch_axes, head_axes)
+    try:
+        yield
+    finally:
+        _partition.spec = prev
+
+
 def flash_attention(
     q,
     k,
@@ -793,6 +835,11 @@ def flash_attention(
     TDX_FLASH_STREAM=1/0 forces either. Ring attention over the mesh
     (parallel/context_parallel.py) remains the MULTI-chip long-context
     path and calls this kernel per shard.
+
+    Under a GSPMD jit over several devices, open `partitioned_over`
+    around the trace (the trainer factories do): the call then runs per
+    device on its (batch, head) shard. Without it Mosaic refuses at
+    lowering ("cannot be automatically partitioned").
     """
     B, L, H, D = q.shape
     if scale is None:
@@ -802,8 +849,29 @@ def flash_attention(
         raise ValueError(f"seq len {L} must be divisible by block sizes ({bq},{bk})")
     if interpret is None:
         interpret = _interpret_default()
-    o = _flash(_to_bh(q), _to_bh(k), _to_bh(v), scale, causal, bq, bk, interpret)
-    return _from_bh(o, B, H)
+
+    def local(q, k, v):
+        b, _, h, _ = q.shape
+        o = _flash(_to_bh(q), _to_bh(k), _to_bh(v), scale, causal, bq, bk,
+                   interpret)
+        return _from_bh(o, b, h)
+
+    if _partition.spec is None:
+        return local(q, k, v)
+    jmesh, batch_axes, head_axes = _partition.spec
+    nb = math.prod(jmesh.shape[ax] for ax in batch_axes)
+    nh = math.prod(jmesh.shape[ax] for ax in head_axes)
+    if B % nb or H % nh:
+        raise ValueError(
+            f"flash_attention under mesh {dict(jmesh.shape)}: batch {B} must "
+            f"divide over {batch_axes} (={nb}) and heads {H} over "
+            f"{head_axes} (={nh}); the kernel runs on whole (row, head) "
+            "slices and is never partitioned along L or D"
+        )
+    spec = jax.sharding.PartitionSpec(
+        batch_axes or None, None, head_axes or None, None
+    )
+    return shard_map_fn(local, jmesh, (spec, spec, spec), spec)(q, k, v)
 
 
 def gather_paged_kv(

@@ -26,7 +26,7 @@ from typing import Callable, Optional
 
 import jax
 
-from .._compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 NEG_INF = -1e30
 

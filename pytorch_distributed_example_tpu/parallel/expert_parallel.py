@@ -27,7 +27,7 @@ from __future__ import annotations
 import functools
 from typing import Callable, Optional, Tuple
 
-from .._compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 
 def _topk_routing(logits, n_experts: int, capacity: int, k: int = 1):

@@ -16,6 +16,7 @@ ZeRO stages map as: params sharded = ZeRO-3 (default); `shard_optimizer_only`
 from __future__ import annotations
 
 import functools
+import re
 from typing import Any, Callable, Optional, Sequence
 
 from . import sharding as shd
@@ -38,9 +39,11 @@ class FSDPModule:
         self.axis = axis
         self.param_specs = specs
         self.data_axes = tuple(data_axes)
+        self.head_axes = head_axes_from_specs(specs)
 
     def __call__(self, x, *args, **kwargs):
-        return self.module.apply(self.params, x, *args, **kwargs)
+        with _kernel_partition(self.mesh, self.data_axes, self.head_axes):
+            return self.module.apply(self.params, x, *args, **kwargs)
 
     def make_train_step(
         self,
@@ -91,6 +94,55 @@ def fully_shard(
     return FSDPModule(module, sharded, jmesh, axis, specs, present or (axis,))
 
 
+_Q_PROJ = re.compile(r"(^|/)q_proj/kernel$")
+
+
+def head_axes_from_specs(param_specs):
+    """The mesh axes attention heads are sharded over — what a Pallas
+    kernel inside a GSPMD step must be told (`ops.partitioned_over`). Only
+    the sharding rules know it, and a wrong answer makes GSPMD reshard
+    q/k/v around the kernel, so it is read off ``param_specs`` and never
+    guessed from the mesh: the axes on the output dim of every
+    ``q_proj/kernel`` ((d_model, heads * head_dim), so colwise = by head;
+    K/V are repeated to the query heads before the kernel, so the query
+    projection alone decides). No such leaf, or an unsharded output dim,
+    means heads are whole on every device: ``()``.
+    """
+    import jax
+
+    seen = {}
+    for kp, spec in jax.tree_util.tree_flatten_with_path(param_specs)[0]:
+        path = shd.path_of(kp)
+        if _Q_PROJ.search(path):
+            ax = spec[1] if len(spec) > 1 else None
+            axes = () if ax is None else (ax,) if isinstance(ax, str) else tuple(ax)
+            seen.setdefault(axes, path)
+    if len(seen) > 1:
+        raise ValueError(
+            "q_proj kernels disagree on the axes that shard heads: "
+            + ", ".join(f"{p} -> {a}" for a, p in seen.items())
+        )
+    return next(iter(seen), ())
+
+
+def _kernel_partition(jmesh, data_axes, head_axes):
+    """The layout Pallas kernels run under inside a GSPMD step on
+    ``jmesh`` (`ops.partitioned_over`): batch rows over the data axes,
+    heads over ``head_axes`` (`head_axes_from_specs`), whole over every other
+    axis of the mesh. ``data_axes`` are axes of the mesh (both callers
+    have already dropped absent ones)."""
+    from ..ops import partitioned_over
+
+    both = set(data_axes) & set(head_axes)
+    if both:
+        raise ValueError(
+            f"mesh axes {sorted(both)} are both data axes {tuple(data_axes)} "
+            f"and head axes {tuple(head_axes)}; a tensor dimension pair "
+            "(batch, heads) cannot share a mesh axis"
+        )
+    return partitioned_over(jmesh, data_axes, head_axes)
+
+
 def _batch_spec(jmesh, data_axes):
     from jax.sharding import PartitionSpec as P
 
@@ -118,6 +170,7 @@ def _make_constrained_train_step(
     donate: bool,
     comm_hook: Optional[Callable] = None,
     hook_axis: Optional[str] = None,
+    head_axes: Sequence[str] = (),
 ):
     """Shared fwd/bwd/update scaffold for the ZeRO family.
 
@@ -140,6 +193,10 @@ def _make_constrained_train_step(
 
     from .._compat import shard_map_fn
 
+    data_axes = batch_spec[0]
+    if isinstance(data_axes, str):
+        data_axes = (data_axes,)
+
     def step(params, opt_state, x, y, *rng):
         def objective(p, xl, yl, key):
             if has_rng:
@@ -151,9 +208,12 @@ def _make_constrained_train_step(
             return loss_fn(fwd(p), yl)
 
         if comm_hook is None:
-            loss, grads = jax.value_and_grad(
-                lambda p: objective(p, x, y, rng[0] if has_rng else None)
-            )(params)
+            # GSPMD partitions everything in this branch except Pallas
+            # kernels, which need their layout spelled out
+            with _kernel_partition(jmesh, data_axes, head_axes):
+                loss, grads = jax.value_and_grad(
+                    lambda p: objective(p, x, y, rng[0] if has_rng else None)
+                )(params)
         else:
             from jax import lax
 
@@ -225,7 +285,9 @@ def make_fsdp_train_step(
 ):
     """Compile the FSDP (ZeRO-3) train step: batch split over data axes,
     params sharded per ``param_specs``; XLA GSPMD materializes the
-    per-layer gather/scatter.
+    per-layer gather/scatter. Pallas attention kernels in ``apply_fn``
+    run per device, heads over the axes ``param_specs`` shard them by
+    (`head_axes_from_specs`).
 
     `shard_weight_update="auto"` (default) pins the optimizer state to
     the PARAM layout explicitly (under ZeRO-3 the moments mirror the
@@ -270,13 +332,25 @@ def make_fsdp_train_step(
         has_rng=has_rng,
         remat=remat,
         donate=donate,
+        head_axes=head_axes_from_specs(param_specs),
     )
 
     def init_opt_state(params):
-        """State placed in its step-native layout: `optimizer.init` on
-        the (already sharded) params — zeros_like inherits the param
-        shardings, so moments land sharded with no extra transfer."""
-        return jax.jit(optimizer.init)(params)
+        """State born in the layout the step keeps it in: the same
+        constraint the step applies, inside the jit that creates it (a
+        bare `jit(optimizer.init)` puts every moment whole on device 0 —
+        its zeros depend on no sharded input — which costs the full
+        unsharded state on one chip and a second compile of the step when
+        the re-laid-out state comes back), scalars replicated."""
+        from jax.sharding import PartitionSpec as P
+
+        state = jax.jit(lambda p: constrain_state(optimizer.init(p), p))(
+            params
+        )
+        rep = NamedSharding(jmesh, P())
+        return jax.tree_util.tree_map(
+            lambda l: l if l.ndim else jax.device_put(l, rep), state
+        )
 
     step.init_opt_state = init_opt_state
     step.weight_update_sharded = sharded_update

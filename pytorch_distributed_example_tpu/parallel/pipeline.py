@@ -43,7 +43,7 @@ from __future__ import annotations
 import functools
 from typing import Callable, Optional
 
-from .._compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x, axis_name: str = "pp"):

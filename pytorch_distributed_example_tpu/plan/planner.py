@@ -132,9 +132,9 @@ class CollectivePlanner:
             # run compiled programs under the tracer and explode — take
             # the structural default WITHOUT memoizing, so a later eager
             # dispatch at this bucket still probes for real
-            import jax
+            from .. import traceguard
 
-            if plane == "driver" and not jax.core.trace_state_clean():
+            if plane == "driver" and traceguard.under_tracing():
                 alg = cands[0]  # driver candidates lead with "onepass"
                 self.last_choice = (op, alg, "default")
                 return alg, "default"

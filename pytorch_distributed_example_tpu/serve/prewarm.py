@@ -13,9 +13,9 @@ enumerable AHEAD of time.
 Two layers make that cheap:
 
 * `enable_compile_cache` points JAX's persistent compilation cache at
-  a directory shared by every worker incarnation (the conftest already
-  does this for the test suite; workers opt in via
-  ``TDX_COMPILE_CACHE``). The cache is keyed by HLO + flags + backend,
+  a directory shared by every worker incarnation (workers name one via
+  ``TDX_COMPILE_CACHE``; a machine-level `JAX_COMPILATION_CACHE_DIR`
+  takes precedence). The cache is keyed by HLO + flags + backend,
   so a program compiled by ANY process (a pre-warm pass, a previous
   generation, a sibling rank) is a disk read for the next one.
 * `prewarm_engine_programs` AOT-compiles the engine's paged program
@@ -98,25 +98,17 @@ class GeometrySpec:
 
 
 def enable_compile_cache(cache_dir: str, min_compile_secs: float = 0.0):
-    """Point the persistent compilation cache at `cache_dir` (shared
-    across worker incarnations — the resize fast path). Zero threshold
-    on purpose: the serve programs are small on test models but their
+    """Share one persistent compilation cache across worker incarnations
+    (the resize fast path) and return its directory: ``cache_dir``,
+    unless the machine pins `JAX_COMPILATION_CACHE_DIR`, which wins
+    (`_compat.enable_compile_cache` holds the rule). Zero threshold on
+    purpose: the serve programs are small on test models but their
     re-compile is exactly the latency a resize pays, so EVERYTHING the
     engine compiles is worth the disk here (the bounded program set
-    keeps the directory small, unlike the global conftest default).
-    Returns the directory, or None when this JAX build lacks the knob
-    (the caller degrades to cold compiles, never crashes)."""
-    import jax
+    keeps the directory small)."""
+    from .._compat import enable_compile_cache as _enable
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(min_compile_secs),
-        )
-        return cache_dir
-    except AttributeError:
-        return None
+    return _enable(cache_dir, min_compile_secs)
 
 
 def reachable_geometries(

@@ -969,27 +969,41 @@ def lint(
 # violation) triggers jaxpr bisection to the first divergent eqn.
 
 
+def _harness_xla_flags(flags: str) -> str:
+    """``flags`` plus what a bitwise sweep needs of XLA:CPU — the same
+    two settings conftest.py exports for the suite:
+
+    * 8 virtual devices (the geometry matrix goes to world 4 and 8);
+    * the fusion emitters OFF. On jax 0.9.0 they contract ``a*b + c``
+      into one FMA or not depending on how XLA happened to fuse the
+      surrounding ops, so two programs with identical arithmetic differ
+      in the last bit wherever ``a*b`` is inexact (``g * (1/3)``: any
+      non-power-of-two world). Measured at world 3: the sharded and the
+      unsharded momentum disagreed in 9 of 37 elements with no change
+      to either program's math, and the seeded PR 10 reassociation
+      stopped being visible. A bitwise contract is a statement about
+      the program's arithmetic, not about a backend's fusion choices.
+    """
+    if "xla_force_host_platform_device_count" not in flags:
+        flags += " --xla_force_host_platform_device_count=8"
+    if "xla_cpu_use_fusion_emitters" not in flags:
+        flags += " --xla_cpu_use_fusion_emitters=false"
+    return flags.strip()
+
+
 def _ensure_cpu_jax() -> None:
     """Mirror conftest.py's environment for a standalone CLI run: 8
-    virtual CPU devices + the determinism pins (N001 cites these).
+    virtual CPU devices + the matmul-precision pin (N001 cites it); the
+    PRNG stream is the installed default, as in the suite.
     Must run BEFORE the first jax import in this process."""
     if "jax" not in sys.modules:
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=8"
-            ).strip()
+        os.environ["XLA_FLAGS"] = _harness_xla_flags(
+            os.environ.get("XLA_FLAGS", "")
+        )
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
     jax.config.update("jax_default_matmul_precision", "highest")
-    # Legacy threefry stream, same as conftest.py's pin (see the long
-    # comment there): sweep hashes must come from the same stream
-    # family as the suite's reference values. The prng_stream subject's
-    # packing invariance holds under either lowering (per-request
-    # fold_in keys are never split across a sharded axis), so the
-    # sweep does not need the partitionable lowering to make its claim.
-    jax.config.update("jax_threefry_partitionable", False)
 
 
 def _tree_hash(values) -> str:
@@ -1788,11 +1802,7 @@ def _maybe_reexec_for_devices(args, quick: bool) -> None:
     if jax.device_count() >= 8:
         return
     env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8"
-        ).strip()
+    env["XLA_FLAGS"] = _harness_xla_flags(env.get("XLA_FLAGS", ""))
     env.setdefault("JAX_PLATFORMS", "cpu")
     env["_TDX_NUMLINT_SWEEP_REEXEC"] = "1"
     cmd = [

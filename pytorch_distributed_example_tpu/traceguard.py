@@ -47,20 +47,13 @@ def enabled() -> bool:
 def under_tracing() -> bool:
     """True when jax is currently tracing (jit/shard_map/scan/...).
 
-    Uses `jax.core.trace_state_clean` when available; with no jax (or an
-    API drift) the guard degrades to inert rather than breaking the
-    dispatch path."""
-    try:
-        from jax import core as _core
-    except Exception:  # pragma: no cover - jax is a hard dep in practice
-        return False
-    probe = getattr(_core, "trace_state_clean", None)
-    if probe is None:  # pragma: no cover - future jax API drift
-        return False
-    try:
-        return not probe()
-    except Exception:  # pragma: no cover - defensive: guard must not crash
-        return False
+    The one probe for the package (`plan/planner.py`, `plan/probe.py`
+    and `plan/traced.py` come through here): `jax.core.trace_ctx`. A
+    jax without it raises `AttributeError` here — a guard that reads a
+    missing API as "not tracing" lets every guarded op through."""
+    import jax
+
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def check(op: str) -> None:

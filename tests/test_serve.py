@@ -236,18 +236,25 @@ class TestEngineParity:
         from pytorch_distributed_example_tpu.serve import ServeEngine
 
         model, params = _model()
-        (prompt,) = _prompts(4)
+        # re-baselined for the installed PRNG stream (PR 21 dropped the
+        # legacy jax_threefry_partitionable=False pin): under it the
+        # random-init model's greedy run from the old length-4 prompt
+        # repeats ONE token, so "the token at step 2" was also the token
+        # at step 0. The eos is now the first token whose first
+        # occurrence is at step >= 2, found from the free run itself.
+        (prompt,) = _prompts(3)
         free = ServeEngine(model, params, slots=1, min_bucket=4)
         rid = free.submit(prompt, 12)
         toks = free.run(max_steps=100)[rid].tokens
-        eos = toks[2]  # actually emitted at step 2
+        k = next(i for i in range(2, 11) if toks[i] not in toks[:i])
+        eos = toks[k]  # first emitted at step k
 
         eng = ServeEngine(model, params, slots=1, eos_id=eos, min_bucket=4)
         rid2 = eng.submit(prompt, 12)
         comp = eng.run(max_steps=100)[rid2]
         assert comp.finish_reason == "eos"
         assert comp.tokens[-1] == eos
-        assert len(comp.tokens) == 3  # retired early, budget was 12
+        assert len(comp.tokens) == k + 1  # retired early, budget was 12
         ref = np.asarray(
             generate(
                 model, params, jnp.asarray(prompt)[None], 12, eos_id=eos

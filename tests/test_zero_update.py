@@ -288,11 +288,23 @@ class TestDDPZeroUpdate:
         native = step.init_opt_state(ddp.params)
         assert _leaves_equal_bitwise(coerced, native)
 
+    @pytest.mark.parametrize("unroll", [True, False])
     def test_steps_per_call_fused_matches_sequential(
-        self, convnet_setup, world
+        self, convnet_setup, world, unroll
     ):
         """Fused multi-step dispatch composes with the sharded update:
-        K steps in one program == K sequential sharded steps, bitwise."""
+        K steps in one program == K sequential sharded steps.
+
+        Unrolled (the variant the MNIST example and bench dispatch):
+        bitwise — losses AND params. Looped `lax.scan`: the scan body
+        compiles the update math with different roundings than the
+        single-step program, so params agree to a few ULP only (this
+        test always said so) — and a loss computed from ULP-different
+        params cannot be required bitwise. Re-baselined in PR 21: at
+        the seed the looped third loss was one ULP off on jax 0.9.0
+        (0x4020b788 vs 0x4020b789) while the first two were bitwise;
+        the first step starts from identical params and stays bitwise,
+        later looped losses get the params' own envelope."""
         import jax
         import jax.numpy as jnp
         import optax
@@ -302,7 +314,9 @@ class TestDDPZeroUpdate:
         opt = optax.sgd(0.05)
         K = 3
         step1 = ddp.make_train_step(opt, _loss_fn())
-        stepK = ddp.make_train_step(opt, _loss_fn(), steps_per_call=K)
+        stepK = ddp.make_train_step(
+            opt, _loss_fn(), steps_per_call=K, unroll_steps=unroll
+        )
         gen = np.random.default_rng(7)
         n = 2 * world.size()
         xs = gen.standard_normal((K, n, 28, 28, 1)).astype(np.float32)
@@ -312,19 +326,24 @@ class TestDDPZeroUpdate:
         seq_losses = []
         for i in range(K):
             p1, o1, l = step1(p1, o1, xs[i], ys[i])
-            seq_losses.append(np.asarray(l).tobytes())
+            seq_losses.append(np.asarray(l))
         pK = jax.tree_util.tree_map(jnp.copy, ddp.params)
         oK = stepK.init_opt_state(pK)
         pK, oK, losses = stepK(pK, oK, jnp.asarray(xs), jnp.asarray(ys))
-        assert [
-            np.asarray(x).tobytes() for x in np.asarray(losses)
-        ] == seq_losses
-        # params: allclose, not bitwise — scan fuses the update math
-        # slightly differently than the single-step program (same
-        # contract as test_ddp.py::test_steps_per_call_matches_sequential)
-        for a, b in zip(
+        losses = np.asarray(losses)
+        leaves = list(zip(
             jax.tree_util.tree_leaves(p1), jax.tree_util.tree_leaves(pK)
-        ):
+        ))
+        if unroll:
+            assert [x.tobytes() for x in losses] == [
+                x.tobytes() for x in seq_losses
+            ]
+            for a, b in leaves:
+                assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+            return
+        assert losses[0].tobytes() == seq_losses[0].tobytes()
+        np.testing.assert_allclose(losses, seq_losses, rtol=1e-6)
+        for a, b in leaves:
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7
             )
