@@ -48,7 +48,8 @@ def enable_compile_cache(
     `bench.py`, the test harness, serve pre-warm) comes through here, so
     a machine that pins the variable gets one cache for all of them.
     `min_compile_secs` keeps trivial programs off the disk (JAX has no
-    eviction).
+    eviction). Scope paths and source locations are part of the cache key
+    (see below), so an edit that moves traced lines compiles again.
     """
     import jax
 
@@ -61,4 +62,12 @@ def enable_compile_cache(
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs", float(min_compile_secs)
     )
+    # The key keeps each operation's scope path and source location. JAX's
+    # default strips them, so a program that differs from a cached one only
+    # in its `jax.named_scope`s loads the OLD executable with the OLD names,
+    # and a profiler trace then reports scopes the source no longer has (or
+    # lacks the ones it gained): seen on the chip, where two checkouts
+    # shared the machine's cache directory. Per-layer metrics are read from
+    # those names (bench_matrix/reduce/scopes.py).
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return cache_dir

@@ -84,32 +84,35 @@ def rope_freqs(head_dim: int, max_len: int, theta: float):
 
 def apply_rope(x, cos, sin):
     """x: (B, L, H, D); rotate pairs (even, odd) by position angle."""
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    c = cos[None, :, None, :]
-    s = sin[None, :, None, :]
-    r1 = x1 * c - x2 * s
-    r2 = x2 * c + x1 * s
-    out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
+    with jax.named_scope("rope"):
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        c = cos[None, :, None, :]
+        s = sin[None, :, None, :]
+        r1 = x1 * c - x2 * s
+        r2 = x2 * c + x1 * s
+        out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
+        return out.astype(x.dtype)
 
 
 def apply_rope_batched(x, cos, sin):
     """x: (B, L, H, D); cos/sin: (B, L, D/2) — per-SAMPLE position
     angles, for decode batches where every row sits at its own absolute
     position (the serve engine's slot batch)."""
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    c = cos[:, :, None, :]
-    s = sin[:, :, None, :]
-    r1 = x1 * c - x2 * s
-    r2 = x2 * c + x1 * s
-    out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
+    with jax.named_scope("rope"):
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        c = cos[:, :, None, :]
+        s = sin[:, :, None, :]
+        r1 = x1 * c - x2 * s
+        r2 = x2 * c + x1 * s
+        out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
+        return out.astype(x.dtype)
 
 
 def _dense_attention(q, k, v, causal, scale):
     from ..ops.reference import dense_attention
 
-    return dense_attention(q, k, v, causal=causal, scale=scale)
+    with jax.named_scope("dense_attention"):
+        return dense_attention(q, k, v, causal=causal, scale=scale)
 
 
 class Attention(nn.Module):
@@ -145,7 +148,8 @@ class Attention(nn.Module):
         if cfg.use_flash and _flash_ok(L, Dh):
             from ..ops import flash_attention
 
-            o = flash_attention(q, k, v, causal=cfg.causal, scale=scale)
+            with jax.named_scope("flash_attention"):
+                o = flash_attention(q, k, v, causal=cfg.causal, scale=scale)
         else:
             o = _dense_attention(q, k, v, cfg.causal, scale)
         o = o.reshape(B, L, H * Dh)
@@ -227,8 +231,9 @@ class Attention(nn.Module):
             pos_sin = lax.dynamic_slice_in_dim(sin, idx, L, axis=0)
             q = apply_rope(q, pos_cos, pos_sin)
             k = apply_rope(k, pos_cos, pos_sin)
-            kf = lax.dynamic_update_slice_in_dim(ck.value, k, idx, axis=1)
-            vf = lax.dynamic_update_slice_in_dim(cv.value, v, idx, axis=1)
+            with jax.named_scope("kv_scatter"):
+                kf = lax.dynamic_update_slice_in_dim(ck.value, k, idx, axis=1)
+                vf = lax.dynamic_update_slice_in_dim(cv.value, v, idx, axis=1)
             if is_initialized:
                 ck.value = kf
                 cv.value = vf
@@ -246,8 +251,9 @@ class Attention(nn.Module):
                     buf, upd, i, axis=0
                 )
             )
-            kf = write(ck.value, k, idx)
-            vf = write(cv.value, v, idx)
+            with jax.named_scope("kv_scatter"):
+                kf = write(ck.value, k, idx)
+                vf = write(cv.value, v, idx)
             if is_initialized:
                 ck.value = kf
                 cv.value = vf
@@ -256,11 +262,12 @@ class Attention(nn.Module):
         # cache — repeating the (B, M, KV, Dh) buffers up to H heads per
         # step would forfeit the KV-cache bandwidth saving GQA exists for
         rep = H // KV
-        qg = q.reshape(B, L, KV, rep, Dh)
-        s = jnp.einsum("blkrd,bmkd->bkrlm", qg, kf) * scale  # (B,KV,rep,L,M)
-        s = jnp.where(mask[:, None, None], s.astype(jnp.float32), -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(vf.dtype)
-        o = jnp.einsum("bkrlm,bmkd->blkrd", p, vf).reshape(B, L, H * Dh)
+        with jax.named_scope("cache_attention"):
+            qg = q.reshape(B, L, KV, rep, Dh)
+            s = jnp.einsum("blkrd,bmkd->bkrlm", qg, kf) * scale  # (B,KV,rep,L,M)
+            s = jnp.where(mask[:, None, None], s.astype(jnp.float32), -1e30)
+            p = jax.nn.softmax(s, axis=-1).astype(vf.dtype)
+            o = jnp.einsum("bkrlm,bmkd->blkrd", p, vf).reshape(B, L, H * Dh)
         return dense(cfg.d_model, "o_proj")(o)
 
     def _decode_paged(
@@ -333,34 +340,39 @@ class Attention(nn.Module):
             )
             return flat_pool.reshape(nblk, bs, KV)
 
-        if quantized:
-            # quantize-on-scatter: post-RoPE K and V, one scale per
-            # (token, kv-head); value and scale ride the same flat
-            # index so a dropped write drops both
-            qk, sk = quantize_kv(k)
-            qv, sv = quantize_kv(v)
-            ck.value = scatter(ck.value, qk)
-            cv.value = scatter(cv.value, qv)
-            cks.value = scatter_scale(cks.value, sk)
-            cvs.value = scatter_scale(cvs.value, sv)
-            kf, vf = gather_paged_kv(
-                ck.value, cv.value, block_tables,
-                k_scale=cks.value, v_scale=cvs.value,
-                out_dtype=cfg.dtype,
-            )
-        else:
-            ck.value = scatter(ck.value, k)
-            cv.value = scatter(cv.value, v)
-            kf, vf = gather_paged_kv(ck.value, cv.value, block_tables)
-        Mb = nb * bs  # logical key span the tables cover (>= M)
-        key_pos = jnp.arange(Mb)
-        mask = key_pos[None, None, :] <= pos[:, :, None]  # (B, L, Mb)
-        rep = H // KV
-        qg = q.reshape(B, L, KV, rep, Dh)
-        s = jnp.einsum("blkrd,bmkd->bkrlm", qg, kf) * scale
-        s = jnp.where(mask[:, None, None], s.astype(jnp.float32), -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(vf.dtype)
-        o = jnp.einsum("bkrlm,bmkd->blkrd", p, vf).reshape(B, L, H * Dh)
+        with jax.named_scope("kv_scatter"):
+            if quantized:
+                # quantize-on-scatter: post-RoPE K and V, one scale per
+                # (token, kv-head); value and scale ride the same flat
+                # index so a dropped write drops both
+                qk, sk = quantize_kv(k)
+                qv, sv = quantize_kv(v)
+                ck.value = scatter(ck.value, qk)
+                cv.value = scatter(cv.value, qv)
+                cks.value = scatter_scale(cks.value, sk)
+                cvs.value = scatter_scale(cvs.value, sv)
+            else:
+                ck.value = scatter(ck.value, k)
+                cv.value = scatter(cv.value, v)
+        with jax.named_scope("kv_gather"):
+            if quantized:
+                kf, vf = gather_paged_kv(
+                    ck.value, cv.value, block_tables,
+                    k_scale=cks.value, v_scale=cvs.value,
+                    out_dtype=cfg.dtype,
+                )
+            else:
+                kf, vf = gather_paged_kv(ck.value, cv.value, block_tables)
+        with jax.named_scope("cache_attention"):
+            Mb = nb * bs  # logical key span the tables cover (>= M)
+            key_pos = jnp.arange(Mb)
+            mask = key_pos[None, None, :] <= pos[:, :, None]  # (B, L, Mb)
+            rep = H // KV
+            qg = q.reshape(B, L, KV, rep, Dh)
+            s = jnp.einsum("blkrd,bmkd->bkrlm", qg, kf) * scale
+            s = jnp.where(mask[:, None, None], s.astype(jnp.float32), -1e30)
+            p = jax.nn.softmax(s, axis=-1).astype(vf.dtype)
+            o = jnp.einsum("bkrlm,bmkd->blkrd", p, vf).reshape(B, L, H * Dh)
         return dense(cfg.d_model, "o_proj")(o)
 
 

@@ -484,7 +484,8 @@ def make_ddp_train_step(
                 logits = apply_fn(p, xm, dev_rng)
             else:
                 logits = apply_fn(p, xm)
-            out = loss_fn(logits, ym)
+            with jax.named_scope("loss"):
+                out = loss_fn(logits, ym)
             return out if with_aux else (out, None)
 
         obj = jax.checkpoint(objective) if remat else objective
@@ -528,11 +529,14 @@ def make_ddp_train_step(
             # device compresses its own shard's gradient), so replicating
             # it would silently drop every residual but one.
             hs_local = jax.tree_util.tree_map(lambda l: l[0], hook_state)
-            grads, hs_local = hook.apply(hs_local, grads, axis)
+            with jax.named_scope("grad_reduce"):
+                grads, hs_local = hook.apply(hs_local, grads, axis)
             hook_state = jax.tree_util.tree_map(lambda l: l[None], hs_local)
         elif not fused_rs:
-            grads = hook(grads, axis)
-        loss = lax.pmean(loss, axis)
+            with jax.named_scope("grad_reduce"):
+                grads = hook(grads, axis)
+        with jax.named_scope("loss"):
+            loss = lax.pmean(loss, axis)
         if zero_update:
             # ZeRO: update only the 1/W shard this rank owns, with the
             # optimizer state entering the region already shard-local
@@ -543,42 +547,48 @@ def make_ddp_train_step(
             # zero.shard_view's layout, so the opt-state template always
             # equals the live state (no per-step re-coercion).
             idx = lax.axis_index(axis)
-            if fused_rs:
-                grads = jax.tree_util.tree_map(
-                    lambda gl: (
-                        zero.reduce_scatter_mean(gl, axis, W)
-                        if gl.ndim
-                        else lax.pmean(gl, axis)
-                    ),
-                    grads,
+            with jax.named_scope("grad_reduce"):
+                if fused_rs:
+                    grads = jax.tree_util.tree_map(
+                        lambda gl: (
+                            zero.reduce_scatter_mean(gl, axis, W)
+                            if gl.ndim
+                            else lax.pmean(gl, axis)
+                        ),
+                        grads,
+                    )
+                else:
+                    grads = jax.tree_util.tree_map(
+                        lambda gl: (
+                            zero.shard_of(gl, idx, W) if gl.ndim else gl
+                        ),
+                        grads,
+                    )
+            with jax.named_scope("optimizer"):
+                pshard = jax.tree_util.tree_map(
+                    lambda p: zero.shard_of(p, idx, W) if p.ndim else p,
+                    params,
                 )
-            else:
-                grads = jax.tree_util.tree_map(
-                    lambda gl: (
-                        zero.shard_of(gl, idx, W) if gl.ndim else gl
-                    ),
-                    grads,
+                updates, new_opt_state = optimizer.update(
+                    grads, opt_state, pshard
                 )
-            pshard = jax.tree_util.tree_map(
-                lambda p: zero.shard_of(p, idx, W) if p.ndim else p,
-                params,
-            )
-            updates, new_opt_state = optimizer.update(
-                grads, opt_state, pshard
-            )
-            new_pshard = optax.apply_updates(pshard, updates)
-            new_params = jax.tree_util.tree_map(
-                lambda s, p: (
-                    zero.unshard(s, axis, p.shape, p.dtype)
-                    if p.ndim
-                    else s
-                ),
-                new_pshard,
-                params,
-            )
+                new_pshard = optax.apply_updates(pshard, updates)
+            with jax.named_scope("grad_reduce"):
+                new_params = jax.tree_util.tree_map(
+                    lambda s, p: (
+                        zero.unshard(s, axis, p.shape, p.dtype)
+                        if p.ndim
+                        else s
+                    ),
+                    new_pshard,
+                    params,
+                )
         else:
-            updates, new_opt_state = optimizer.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt_state = optimizer.update(
+                    grads, opt_state, params
+                )
+                new_params = optax.apply_updates(params, updates)
         return new_params, new_opt_state, hook_state, loss, aux
 
     if steps_per_call > 1 and with_aux:
@@ -1186,7 +1196,14 @@ class DistributedDataParallel:
         are compiled and differenced — forward; forward+backward; full
         step with reduction replaced by noop; full step. The differences
         are the component walls (comm includes what XLA could NOT overlap,
-        which is the number that matters for tuning).
+        which is the number that matters for tuning). Each component is a
+        difference of two wall times clamped at 0: all are >= 0 and, when
+        none was clamped, the four sum to `full_step_s`.
+
+        On a TPU the decomposition to read is the traced step's device
+        time by program component (`bench_matrix/reduce/scopes.py::table`,
+        over the `loss` / `grad_reduce` / `optimizer` scopes the step
+        carries and Flax's module names): one program, no differencing.
         """
         import time as _time
 

@@ -205,7 +205,9 @@ def _make_constrained_train_step(
                 fwd = lambda pp: apply_fn(pp, xl)
             if remat:
                 fwd = jax.checkpoint(fwd)
-            return loss_fn(fwd(p), yl)
+            logits = fwd(p)
+            with jax.named_scope("loss"):
+                return loss_fn(logits, yl)
 
         if comm_hook is None:
             # GSPMD partitions everything in this branch except Pallas
@@ -231,8 +233,11 @@ def _make_constrained_train_step(
                 loss, g = jax.value_and_grad(
                     lambda pp: objective(pp, xl, yl, key)
                 )(p)
-                g = comm_hook(g, hook_axis)
-                return lax.pmean(loss, hook_axis), g
+                with jax.named_scope("grad_reduce"):
+                    g = comm_hook(g, hook_axis)
+                with jax.named_scope("loss"):
+                    loss = lax.pmean(loss, hook_axis)
+                return loss, g
 
             loss, grads = shard_map_fn(
                 local,
@@ -241,10 +246,16 @@ def _make_constrained_train_step(
                 out_specs=(P(), P()),
             )(params, x, y)
         grads = constrain_grads(grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
+        # the sharding constraints stay outside the scope: the collectives
+        # GSPMD derives from them are the compiler's, not the update's
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
         if constrain_opt_state is not None:
             opt_state = constrain_opt_state(opt_state, params)
-        params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
+        with jax.named_scope("optimizer"):
+            params = jax.tree_util.tree_map(
+                lambda p, u: p + u, params, updates
+            )
         params = constrain_params(params)
         return params, opt_state, loss
 

@@ -120,9 +120,12 @@ def slot_programs(model, temperature: float, top_k: Optional[int]):
         )
         # per-request stream off the seed, one split consumed by the
         # first sample — mirrors generate()'s prefill rng discipline
-        key = jax.random.PRNGKey(seed)
-        key, sub = jax.random.split(key)
-        first = sample_logits(first_logits[None], sub, temperature, top_k)[0]
+        with jax.named_scope("sample"):
+            key = jax.random.PRNGKey(seed)
+            key, sub = jax.random.split(key)
+            first = sample_logits(
+                first_logits[None], sub, temperature, top_k
+            )[0]
         return vars2["cache"], first_logits, first, key
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
@@ -144,8 +147,9 @@ def slot_programs(model, temperature: float, top_k: Optional[int]):
         slot's last emitted token; rngs: (S, 2) uint32 per-slot keys.
         Returns (cache', lengths', next_tokens (S,), rngs').
         """
-        split = jax.vmap(jax.random.split)(rngs)  # (S, 2, 2)
-        subs, new_rngs = split[:, 0], split[:, 1]
+        with jax.named_scope("sample"):
+            split = jax.vmap(jax.random.split)(rngs)  # (S, 2, 2)
+            subs, new_rngs = split[:, 0], split[:, 1]
         logits, vars2 = model.apply(
             {"params": params, "cache": cache}, tokens[:, None],
             decode=True, positions=lengths, mutable=["cache"],
@@ -153,9 +157,10 @@ def slot_programs(model, temperature: float, top_k: Optional[int]):
         lg = logits[:, -1]  # (S, V)
         # sample_logits branches on the Python temperature at trace time
         # (greedy at 0.0, keys trace away), so one vmap covers both modes
-        nxt = jax.vmap(
-            lambda row, key: sample_logits(row, key, temperature, top_k)
-        )(lg, subs)
+        with jax.named_scope("sample"):
+            nxt = jax.vmap(
+                lambda row, key: sample_logits(row, key, temperature, top_k)
+            )(lg, subs)
         # clamp: a retired slot's lane keeps stepping until backfilled;
         # parking it at M-1 keeps its garbage writes in-bounds and off
         # any live request's positions (live writes end at <= M-2, the
@@ -233,9 +238,10 @@ def paged_programs(model, temperature: float, top_k: Optional[int]):
         last = lax.dynamic_index_in_dim(
             chunk_logits, end, axis=0, keepdims=False
         )
-        key = jax.random.PRNGKey(seed)
-        key, sub = jax.random.split(key)
-        first = sample_logits(last[None], sub, temperature, top_k)[0]
+        with jax.named_scope("sample"):
+            key = jax.random.PRNGKey(seed)
+            key, sub = jax.random.split(key)
+            first = sample_logits(last[None], sub, temperature, top_k)[0]
         return first, key
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
@@ -256,17 +262,19 @@ def paged_programs(model, temperature: float, top_k: Optional[int]):
         (tree', lengths', next_tokens (S,), rngs'). Parked lanes clamp
         at M-1 (in-bounds RoPE/mask) and their invalid table rows drop
         the write."""
-        split = jax.vmap(jax.random.split)(rngs)  # (S, 2, 2)
-        subs, new_rngs = split[:, 0], split[:, 1]
+        with jax.named_scope("sample"):
+            split = jax.vmap(jax.random.split)(rngs)  # (S, 2, 2)
+            subs, new_rngs = split[:, 0], split[:, 1]
         logits, vars2 = model.apply(
             {"params": params, "cache": tree}, tokens[:, None],
             decode=True, positions=lengths, block_tables=bt,
             mutable=["cache"],
         )
         lg = logits[:, -1]  # (S, V)
-        nxt = jax.vmap(
-            lambda row, key: sample_logits(row, key, temperature, top_k)
-        )(lg, subs)
+        with jax.named_scope("sample"):
+            nxt = jax.vmap(
+                lambda row, key: sample_logits(row, key, temperature, top_k)
+            )(lg, subs)
         return (
             vars2["cache"],
             jnp.minimum(lengths + 1, M - 1),

@@ -32,6 +32,9 @@ class TestCompileCacheHelper:
         # the machine's pin wins over a directory a caller names, too
         assert _compat.enable_compile_cache("/elsewhere") == str(tmp_path)
         assert self.KEY not in updates
+        # wherever the cache lives, a program that differs only in its
+        # scope names must not load the executable that has the old ones
+        assert updates["jax_compilation_cache_include_metadata_in_key"] is True
 
     def test_unset_uses_the_fixed_in_checkout_path(self, monkeypatch, updates):
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)

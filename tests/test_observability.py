@@ -252,14 +252,26 @@ class TestProfilingTier:
         assert data["avg_step_time_s"] > 0
 
     def test_profile_breakdown_fills_component_times(self, world):
+        """What `profile_breakdown` guarantees by construction, not how
+        two CPU timings of three iterations happen to compare: a component
+        is a difference of wall times clamped at 0, so each is >= 0 and,
+        when none was clamped, the four telescope to the full step. On a
+        TPU the decomposition to read is `bench_matrix.reduce.scopes.table`
+        of a traced step (device time by program component)."""
         ddp, opt, loss_fn, x, y = self._setup(world)
         out = ddp.profile_breakdown(opt, loss_fn, x, y, iters=3)
         data = ddp.get_ddp_logging_data()
-        assert data["avg_forward_compute_time_s"] > 0
-        assert data["avg_backward_compute_time_s"] > 0
-        assert out["full_step_s"] > 0
-        # components are a decomposition: each <= the full step
-        assert out["forward_s"] <= out["full_step_s"] * 1.5
+        parts = ("forward_s", "backward_s", "optimizer_s", "comm_exposed_s")
+        assert out["full_step_s"] > 0 and out["forward_s"] > 0
+        assert all(out[k] >= 0 for k in parts)
+        if all(out[k] > 0 for k in parts[1:]):  # nothing clamped
+            assert sum(out[k] for k in parts) == pytest.approx(out["full_step_s"])
+        else:
+            assert sum(out[k] for k in parts) >= out["full_step_s"]
+        assert data["avg_forward_compute_time_s"] == out["forward_s"]
+        assert data["avg_backward_compute_time_s"] == out["backward_s"]
+        assert data["avg_optimizer_time_s"] == out["optimizer_s"]
+        assert data["avg_backward_comm_time_s"] == out["comm_exposed_s"]
 
     def test_profiler_trace_context_writes_trace(self, world, tmp_path):
         ddp, opt, loss_fn, x, y = self._setup(world)

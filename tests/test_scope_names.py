@@ -1,0 +1,219 @@
+"""The `jax.named_scope`s the per-layer metrics of `bench_matrix` read sit
+where the metric files say: the paged decode step, a prefill chunk and one
+step of each trainer are lowered at a tiny size and every scope must appear
+under its Flax path. A scope changes HLO metadata only, so nothing else
+notices when a refactor drops one and a metric silently reads nothing.
+"""
+
+import json
+import re
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+import pytorch_distributed_example_tpu as tdx
+from pytorch_distributed_example_tpu.models.transformer import (
+    TransformerConfig,
+    TransformerLM,
+)
+
+# Flax names a method other than __call__ `<module>.<method>` in the path
+LAYER = r"TransformerLM\)*/layers_\d+/attn/(attn\.\w+/)*"
+# program -> scope -> where it must appear (a regex on the whole path)
+EXPECTED = {
+    "step": {
+        "rope": LAYER + r"rope/",
+        "kv_scatter": LAYER + r"kv_scatter/scatter",
+        "kv_gather": LAYER + r"kv_gather/",
+        "cache_attention": LAYER + r"cache_attention/.*dot_general",
+        "sample": r"^jit\(step\)/sample/",
+    },
+    "prefill_chunk": {
+        "rope": LAYER + r"rope/",
+        "kv_scatter": LAYER + r"kv_scatter/scatter",
+        "kv_gather": LAYER + r"kv_gather/",
+        "cache_attention": LAYER + r"cache_attention/.*dot_general",
+    },
+    "first_token": {"sample": r"^jit\(first_token\)/sample/"},
+    "ddp": {
+        "rope": LAYER + r"rope/",
+        "flash_attention": LAYER + r"flash_attention/",
+        "loss": r"(^|/)jvp\(loss\)/",
+        "grad_reduce": r"(^|/)grad_reduce/(reduce_scatter|all_gather)",
+        "optimizer": r"(^|/)optimizer/",
+    },
+    "fsdp": {
+        "rope": LAYER + r"rope/",
+        "dense_attention": LAYER + r"dense_attention/",
+        "loss": r"^jit\(step\)/jvp\(loss\)/",
+        "optimizer": r"^jit\(step\)/optimizer/",
+    },
+    "zero2_hook": {"grad_reduce": r"(^|/)grad_reduce/psum", "loss": r"(^|/)loss/psum"},
+}
+
+
+def _paths(lowered) -> dict:
+    """The program's name as the trace's modules line has it, and the scope
+    path of every operation (inside a shard_map body a path starts below
+    the `jit(...)` segment; file names and argument names are left out)."""
+    text = lowered.as_text(debug_info=True)
+    return {
+        "program": re.search(r"module @(\w+)", text).group(1),
+        "paths": {p for p in re.findall(r'loc\("([^" ]+)"', text)
+                  if "/" in p and not p.startswith("/")},
+    }
+
+
+def _model(**kw):
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2,
+        d_ff=64, max_seq_len=64, **kw)
+    model = TransformerLM(cfg)
+    return model, model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+
+
+def _loss(logits, y):
+    return optax.softmax_cross_entropy_with_integer_labels(
+        logits[:, :-1], y[:, 1:]).mean()
+
+
+@pytest.fixture(scope="module")
+def serve_paths():
+    from pytorch_distributed_example_tpu.serve.cache import init_paged_cache
+    from pytorch_distributed_example_tpu.serve.decode import paged_programs
+
+    model, variables = _model()
+    params = variables["params"]
+    prefill_chunk, first_token, _, step = paged_programs(model, 0.0, None)
+    S, bs, nblk = 2, 8, 16
+    tree = init_paged_cache(model, nblk, bs)
+    bt = jnp.zeros((S, 64 // bs), jnp.int32)
+    lanes = jnp.zeros((S,), jnp.int32)
+    rngs = jnp.zeros((S, 2), jnp.uint32)
+    return {
+        "step": _paths(step.lower(params, tree, lanes, lanes, rngs, bt)),
+        "prefill_chunk": _paths(prefill_chunk.lower(
+            params, tree, jnp.zeros((1, 16), jnp.int32), bt[:1], 0)),
+        "first_token": _paths(first_token.lower(
+            jnp.zeros((16, 64), jnp.float32), 3, 7)),
+    }
+
+
+@pytest.fixture(scope="module")
+def train_paths(world):
+    W = world.size()
+    x = jnp.zeros((W, 64), jnp.int32)
+    out = {}
+
+    # flash on: the kernel is interpreted on the CPU, the scope is the same
+    model, variables = _model(use_flash=True, remat=True)
+    ddp = tdx.DistributedDataParallel(model, variables)
+    step = ddp.make_train_step(optax.adamw(1e-3), _loss)
+    p, o = ddp.params, step.init_opt_state(ddp.params)
+    p, o, _ = step(p, o, x, x)  # under ZeRO the program is built at first dispatch
+    out["ddp"] = _paths(step._jitted.lower(p, o, {}, x, x, jax.random.PRNGKey(0)))
+
+    from pytorch_distributed_example_tpu.mesh import init_device_mesh
+    from pytorch_distributed_example_tpu.models import transformer_sharding_rules
+    from pytorch_distributed_example_tpu.parallel import fully_shard
+    from pytorch_distributed_example_tpu.parallel.fsdp import make_zero2_train_step
+    from pytorch_distributed_example_tpu.parallel import comm_hooks
+
+    model, variables = _model(use_flash=False, remat=True)
+    mesh = init_device_mesh(("fsdp", "tp"), (4, 1), devices=jax.devices()[:4])
+    rules = transformer_sharding_rules("tp", "fsdp")
+    mod = fully_shard(model, variables, mesh, axis="fsdp", rules=rules,
+                      data_axes=("fsdp",))
+    fstep = mod.make_train_step(optax.adamw(1e-3), _loss)
+    out["fsdp"] = _paths(fstep.lower(
+        mod.params, fstep.init_opt_state(mod.params), x[:4], x[:4]))
+
+    zstep = make_zero2_train_step(
+        lambda p, xb: model.apply(p, xb), _loss, optax.adamw(1e-3), mesh,
+        axis="fsdp", data_axes=("fsdp",), comm_hook=comm_hooks.allreduce_hook)
+    # with a hook the step is a host wrapper around the jitted one
+    out["zero2_hook"] = _paths(jax.jit(zstep).lower(
+        variables, zstep.init_opt_state(variables), x[:4], x[:4]))
+    return out
+
+
+CASES = [(prog, scope) for prog, scopes_ in EXPECTED.items() for scope in scopes_]
+
+
+@pytest.mark.parametrize("program,scope", CASES, ids=[f"{p}-{s}" for p, s in CASES])
+def test_scope_sits_under_its_flax_path(program, scope, request):
+    serve = program in ("step", "prefill_chunk", "first_token")
+    paths = request.getfixturevalue(
+        "serve_paths" if serve else "train_paths")[program]["paths"]
+    rx = re.compile(EXPECTED[program][scope])
+    assert any(rx.search(p) for p in paths), (
+        f"no operation of {program} is traced under {rx.pattern}; paths with "
+        f"{scope!r}: {sorted(p for p in paths if scope in p)[:5]}")
+
+
+# the metric files' own patterns, against the same lowerings (queued: the
+# harness takes a cell's metrics from its own file, a `benchmark` PR's to edit)
+METRICS = Path(__file__).resolve().parents[1] / "bench_matrix" / "layer_metrics_queued"
+READ_BY = {
+    "decode_kv_gather_ms": ["step"],
+    "decode_cache_attention_ms": ["step"],
+    "prefill_cache_attention_ms": ["prefill_chunk"],
+    "train_mlp_ms": ["ddp", "fsdp"],
+    "train_attention_ms": ["ddp", "fsdp"],
+    "train_head_loss_ms": ["ddp", "fsdp"],
+    "train_optimizer_ms": ["ddp", "fsdp"],
+}
+
+
+@pytest.mark.parametrize("metric", sorted(READ_BY))
+def test_metric_file_finds_operations_in_the_program_it_names(metric, request):
+    from bench_matrix.reduce import scopes
+
+    args = json.loads((METRICS / f"{metric}.json").read_text())["args"]
+    for program in READ_BY[metric]:
+        serve = program in ("step", "prefill_chunk")
+        low = request.getfixturevalue("serve_paths" if serve else "train_paths")[program]
+        assert re.search(args["program"], low["program"]), (metric, low["program"])
+        rx = re.compile(args["scope"])
+        assert any(rx.search("/".join(scopes.names(p)[0])) for p in low["paths"]), (
+            metric, program)
+
+
+_RENAMED = """
+import os, sys, tempfile
+os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+import jax, jax.numpy as jnp
+from pytorch_distributed_example_tpu import _compat
+_compat.enable_compile_cache(tempfile.mkdtemp(), min_compile_secs=0.0)
+
+def make(scope):
+    def f(x):
+        with jax.named_scope(scope):
+            return jnp.tanh(x @ x) * 3
+    return jax.jit(f)
+
+x = jnp.ones((64, 64))
+make("old_name")(x).block_until_ready()  # compiled and written to the cache
+jax.clear_caches()
+text = make("new_name").lower(x).compile().as_text()
+print("old_name" in text, "new_name" in text)
+"""
+
+
+def test_a_renamed_scope_is_not_served_the_cached_program_with_the_old_name():
+    """JAX's default cache key strips scope paths: the second program would
+    load the first's executable and a trace would show `old_name` (seen on
+    the chip between two checkouts). `enable_compile_cache` keys on them."""
+    import os
+    import subprocess
+    import sys
+
+    root = Path(__file__).resolve().parents[1]
+    out = subprocess.run(
+        [sys.executable, "-c", _RENAMED], cwd=root, capture_output=True,
+        text=True, timeout=300, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split()[-2:] == ["False", "True"]
