@@ -183,10 +183,11 @@ class Attention(nn.Module):
         block is unallocated (table entry == num_blocks) or out of range
         fall out of bounds and are DROPPED, which is what lets a parked
         (retired) slot lane and a padded prefill chunk ride through the
-        step without touching any live request's blocks. Reads gather the
-        row's logical layout (`ops.gather_paged_kv`) and attend under the
-        same absolute-position causal mask; there is no "index" variable
-        on this path (the pool has no per-row cursor)."""
+        step without touching any live request's blocks. Reads attend
+        the row's logical layout under the same absolute-position causal
+        mask, by one of the two paths `_decode_paged` describes; there is
+        no "index" variable on this path (the pool has no per-row
+        cursor)."""
         from jax import lax
 
         cfg = self.cfg
@@ -282,10 +283,22 @@ class Attention(nn.Module):
         `block_tables[b, p // bs] * bs + p % bs`; invalid logical blocks
         (table entry == num_blocks) and positions past the table push
         the flat index out of bounds, where `mode="drop"` discards the
-        write. Attention gathers the row's logical K/V layout and masks
-        by absolute position, so dropped/garbage regions are never
-        attended (every key <= a live row's position sits in an
-        allocated block — the engine allocates before it writes).
+        write.
+
+        Attention then takes one of two paths, chosen from what this
+        call can see (`ops.paged_decode_ok`: query length, pool shape
+        and dtype, table shape — no option names it). A DECODE call
+        (L == 1) on a pool that is not quantized, at a head size Mosaic
+        tiles, runs `ops.paged_decode_attention`: one kernel that reads
+        each row's pages out of the pool, as many as the row's length
+        and leading valid table entries give it. Every other call (prefill chunks,
+        int8 pools, the tiny head sizes of the CPU tests) gathers the
+        row's logical K/V layout (`ops.gather_paged_kv`) and masks a
+        dense einsum by absolute position. On both paths dropped or
+        garbage regions are never attended (every key <= a live row's
+        position sits in an allocated block — the engine allocates
+        before it writes), and a parked row's output is finite and
+        ignored by the scheduler.
 
         A QUANTIZED pool (int8 k/v plus `k_scale`/`v_scale` planes —
         `serve/cache.py::init_paged_cache(quantized=True)`) is detected
@@ -294,9 +307,11 @@ class Attention(nn.Module):
         and scale through the SAME flat index (same drop semantics);
         reads dequantize inside `ops.gather_paged_kv`, so the scores/
         softmax/output math below is identical in both modes."""
-        from jax import lax  # noqa: F401 — parity with _decode's imports
-
-        from ..ops import gather_paged_kv
+        from ..ops import (
+            gather_paged_kv,
+            paged_decode_attention,
+            paged_decode_ok,
+        )
         from ..ops.quant import quantize_kv
 
         cfg = self.cfg
@@ -354,15 +369,19 @@ class Attention(nn.Module):
             else:
                 ck.value = scatter(ck.value, k)
                 cv.value = scatter(cv.value, v)
+        if paged_decode_ok(L, ck.value, block_tables):
+            with jax.named_scope("cache_attention"):
+                o = paged_decode_attention(
+                    q[:, 0], ck.value, cv.value, block_tables, idx, scale
+                ).reshape(B, L, H * Dh)
+            return dense(cfg.d_model, "o_proj")(o)
         with jax.named_scope("kv_gather"):
-            if quantized:
-                kf, vf = gather_paged_kv(
-                    ck.value, cv.value, block_tables,
-                    k_scale=cks.value, v_scale=cvs.value,
-                    out_dtype=cfg.dtype,
-                )
-            else:
-                kf, vf = gather_paged_kv(ck.value, cv.value, block_tables)
+            kf, vf = gather_paged_kv(
+                ck.value, cv.value, block_tables,
+                k_scale=cks.value if quantized else None,
+                v_scale=cvs.value if quantized else None,
+                out_dtype=cfg.dtype,
+            )
         with jax.named_scope("cache_attention"):
             Mb = nb * bs  # logical key span the tables cover (>= M)
             key_pos = jnp.arange(Mb)

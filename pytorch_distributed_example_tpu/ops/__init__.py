@@ -1,12 +1,17 @@
-"""Pallas TPU kernels for the framework's hot ops — plus the jnp-level
-block-scaled quantization codec (`quant.py`) shared by the quantized
-collectives and the int8 paged KV cache."""
+"""Pallas TPU kernels for the framework's hot ops (flash attention for
+training, paged decode attention over the serve block pool) — plus the
+jnp-level block-scaled quantization codec (`quant.py`) shared by the
+quantized collectives and the int8 paged KV cache."""
 
 from . import quant  # noqa: F401
 from .flash_attention import (  # noqa: F401
     flash_attention,
     gather_paged_kv,
     partitioned_over,
+)
+from .paged_attention import (  # noqa: F401
+    paged_decode_attention,
+    paged_decode_ok,
 )
 from .quant import (  # noqa: F401
     dequantize_blockwise,

@@ -888,10 +888,14 @@ def gather_paged_kv(
     garbage, exactly like padded prefill positions). Returns
     ((B, nb*block_size, KV, Dh), (B, nb*block_size, KV, Dh)) in logical
     position order, so downstream attention indexes keys by absolute
-    position — the one seam a Pallas paged-attention kernel would
-    replace (today it lowers to an XLA gather feeding the cache-
-    attention einsum; the KV-head axis passes through untouched, so a
-    TP-sharded pool stays sharded through the gather).
+    position. It lowers to an XLA gather feeding the cache-attention
+    einsum, and it moves the whole `nb * block_size` span of every row
+    whatever the row's length: the path of prefill chunks (one row),
+    int8 pools and head sizes Mosaic cannot tile. A decode step on a
+    plain pool reads its pages in `ops.paged_decode_attention` instead
+    and never calls this (`ops.paged_decode_ok` decides). The KV-head
+    axis passes through untouched, so a TP-sharded pool stays sharded
+    through the gather.
 
     `k_scale`/`v_scale` ((num_blocks, block_size, KV) f32 — the int8
     pool's per-(token, kv-head) scale planes) switch on DEQUANT-IN-

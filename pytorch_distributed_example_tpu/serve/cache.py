@@ -590,6 +590,19 @@ class PagedKVCache:
     def pool_utilization(self) -> float:
         return self.live_blocks / self.num_blocks
 
+    @property
+    def pool_aval(self):
+        """Shape and dtype of one layer's K (and V) pool, as
+        `init_paged_cache` builds it — for callers that ask about the
+        pool without naming the tree's keys."""
+        import jax
+
+        cfg = self.model.cfg
+        return jax.ShapeDtypeStruct(
+            (self.num_blocks, self.block_size, cfg.kv_heads, cfg.head_dim),
+            np.int8 if self.quantized else cfg.dtype,
+        )
+
     @functools.cached_property
     def bytes_per_block(self) -> int:
         """HBM bytes one block pins across every layer (K + V, PLUS the

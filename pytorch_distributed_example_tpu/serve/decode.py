@@ -32,6 +32,7 @@ cached per (model, sampling knobs) exactly like `generate._programs`
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 from typing import Optional
@@ -42,6 +43,7 @@ from .cache import land_slot
 __all__ = [
     "slot_programs",
     "paged_programs",
+    "step_runs_kernel",
     "sync_slot_lanes",
     "carry_key",
 ]
@@ -79,6 +81,32 @@ def _register_programs(family: str, **programs):
         )
         for key, fn in programs.items()
     )
+
+
+def _kernel_partition(mesh, tp_axis: str):
+    """The context a tp engine's paged programs apply the model under:
+    the decode attention kernel is a Mosaic custom call GSPMD cannot
+    partition, so `ops.partitioned_over(mesh, (), (tp_axis,))` makes it
+    run per device on its KV-head shard. No mesh, no context."""
+    if mesh is None:
+        return contextlib.nullcontext()
+    from ..ops import partitioned_over
+
+    return partitioned_over(mesh, (), (tp_axis,))
+
+
+def step_runs_kernel(
+    k_pool, block_tables, mesh=None, tp_axis: str = "tp"
+) -> bool:
+    """Whether `paged_programs(..., mesh, tp_axis)`'s `step` traces
+    `ops.paged_decode_attention` for this K pool and these tables
+    (arrays or `ShapeDtypeStruct`s): `ops.paged_decode_ok` at one query
+    token, under the context the step applies the model under — the
+    fact `ServeMetrics`' kernel-step counter names."""
+    from ..ops import paged_decode_ok
+
+    with _kernel_partition(mesh, tp_axis):
+        return paged_decode_ok(1, k_pool, block_tables)
 
 
 def sync_slot_lanes(lengths, tokens, rngs):
@@ -178,9 +206,19 @@ def slot_programs(model, temperature: float, top_k: Optional[int]):
 
 
 @functools.lru_cache(maxsize=32)
-def paged_programs(model, temperature: float, top_k: Optional[int]):
+def paged_programs(
+    model, temperature: float, top_k: Optional[int], mesh=None,
+    tp_axis: str = "tp",
+):
     """(prefill_chunk, first_token, attach, step) jitted quadruple for
     the PAGED engine at the given sampling knobs.
+
+    `mesh` (a `jax.sharding.Mesh`, the tp engine's) keys a quadruple of
+    its own whose `prefill_chunk` and `step` apply the model under
+    `_kernel_partition(mesh, tp_axis)` (a chunk of ONE token is a decode
+    call to the model, so it takes the kernel too); everything else in
+    the programs is partitioned by GSPMD from the operands' shardings,
+    as before.
 
     Same hot-path discipline as `slot_programs`, adapted to the block
     pool: the pool tree and the per-slot (lengths, last-token, rng)
@@ -212,11 +250,15 @@ def paged_programs(model, temperature: float, top_k: Optional[int]):
       finished request's state lanes into the donated slot vectors (the
       block table row was already built host-side chunk by chunk).
     * ``step(params, tree, lengths, tokens, rngs, bt)`` — advance EVERY
-      slot one token through the paged attention path. Compiles ONCE
-      for the engine's lifetime; retired/prefilling slots ride along as
-      parked lanes whose table rows are all-invalid, so their garbage
-      writes are scatter-DROPPED (never in any live block) and their
-      sampled tokens are ignored by the scheduler.
+      slot one token through the paged attention path: the write
+      scatters into the pool, then `ops.paged_decode_attention` reads
+      each row's pages out of it (the gather + dense einsum where
+      `ops.paged_decode_ok` says the kernel cannot take the shape or
+      the pool is int8). Compiles ONCE for the engine's lifetime;
+      retired/prefilling slots ride along as parked lanes whose table
+      rows are all-invalid, so their garbage writes are scatter-DROPPED
+      (never in any live block), the kernel reads no page for them, and
+      their sampled tokens are ignored by the scheduler.
     """
     import jax
     import jax.numpy as jnp
@@ -224,12 +266,17 @@ def paged_programs(model, temperature: float, top_k: Optional[int]):
 
     M = model.cfg.max_seq_len
 
+    def apply_paged(params, tree, tokens, positions, bt):
+        with _kernel_partition(mesh, tp_axis):
+            return model.apply(
+                {"params": params, "cache": tree}, tokens, decode=True,
+                positions=positions, block_tables=bt, mutable=["cache"],
+            )
+
     @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill_chunk(params, tree, chunk, bt_row, start):
-        logits, vars2 = model.apply(
-            {"params": params, "cache": tree}, chunk, decode=True,
-            positions=jnp.asarray(start, jnp.int32)[None],
-            block_tables=bt_row, mutable=["cache"],
+        logits, vars2 = apply_paged(
+            params, tree, chunk, jnp.asarray(start, jnp.int32)[None], bt_row
         )
         return vars2["cache"], logits[0]  # (C, V)
 
@@ -260,15 +307,13 @@ def paged_programs(model, temperature: float, top_k: Optional[int]):
         positions); tokens: (S,) last emitted; rngs: (S, 2) per-slot
         keys; bt: (S, nb) block tables. Returns
         (tree', lengths', next_tokens (S,), rngs'). Parked lanes clamp
-        at M-1 (in-bounds RoPE/mask) and their invalid table rows drop
-        the write."""
+        at M-1 (in-bounds RoPE/mask); their invalid table rows drop the
+        write and give the decode attention kernel no page to read."""
         with jax.named_scope("sample"):
             split = jax.vmap(jax.random.split)(rngs)  # (S, 2, 2)
             subs, new_rngs = split[:, 0], split[:, 1]
-        logits, vars2 = model.apply(
-            {"params": params, "cache": tree}, tokens[:, None],
-            decode=True, positions=lengths, block_tables=bt,
-            mutable=["cache"],
+        logits, vars2 = apply_paged(
+            params, tree, tokens[:, None], lengths, bt
         )
         lg = logits[:, -1]  # (S, V)
         with jax.named_scope("sample"):

@@ -111,7 +111,7 @@ from ..numerics import numerics_contract
 from ..types import DistError
 from .bucketing import bucket_for, bucket_lengths
 from .cache import PagedKVCache
-from .decode import paged_programs, sync_slot_lanes
+from .decode import paged_programs, step_runs_kernel, sync_slot_lanes
 from .metrics import ServeMetrics
 from .queue import (
     DEFAULT_CLASS,
@@ -258,12 +258,18 @@ class ServeEngine:
         self.conservative_admission = conservative_admission
         self._reserved = 0
         self.mesh = mesh
+        jmesh = getattr(mesh, "jax_mesh", mesh)
         (
             self._prefill_chunk,
             self._first_token,
             self._attach,
             self._step,
-        ) = paged_programs(model, temperature, top_k)
+        ) = paged_programs(model, temperature, top_k, jmesh, tp_axis)
+        # whether that step program runs the paged decode attention
+        # kernel, for the metrics: one fact for the engine's lifetime
+        self._decode_kernel = step_runs_kernel(
+            self.cache.pool_aval, self.cache.block_tables, jmesh, tp_axis
+        )
         if precompiled:
             # resize fast path (serve/prewarm.py): overlay pre-warmed
             # executables — matching shapes skip trace AND compile,
@@ -917,6 +923,7 @@ class ServeEngine:
             bt,
         )
         self._dev_tokens = nxt
+        self.metrics.record_decode_step(self._decode_kernel)
         nxt_h = np.asarray(nxt)  # the hot path's one host readback
         now = self.clock()
         for s in active:

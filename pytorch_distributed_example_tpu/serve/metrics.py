@@ -157,6 +157,11 @@ class ServeMetrics:
         self.restored_generation = -1
         self._queue_class_depths: Dict[str, int] = {}
         self.steps = 0
+        # decode steps dispatched, and those whose step program runs the
+        # paged decode attention kernel (`ops.paged_decode_ok` for the
+        # engine's pool: 100 % or 0 % for one engine's lifetime)
+        self.decode_steps = 0
+        self.decode_kernel_steps = 0
         # paged-pool gauges (last observation) + time-mean accumulators
         self.pool_blocks_live = 0
         self.pool_blocks_total = 0
@@ -258,6 +263,11 @@ class ServeMetrics:
             self._step_win.append((self.clock(), queue_depth, slots_active))
             if self.slots:
                 self._occupancy_steps += slots_active / self.slots
+
+    def record_decode_step(self, kernel: bool) -> None:
+        with self._lock:
+            self.decode_steps += 1
+            self.decode_kernel_steps += bool(kernel)
 
     def record_requeue(self, n: int = 1) -> None:
         with self._lock:
@@ -605,6 +615,13 @@ class ServeMetrics:
                     "restored_generation": self.restored_generation,
                 },
                 "steps": self.steps,
+                "decode": {
+                    "steps": self.decode_steps,
+                    "kernel_steps": self.decode_kernel_steps,
+                    "kernel_share": round(
+                        self.decode_kernel_steps / self.decode_steps, 4
+                    ) if self.decode_steps else 0.0,
+                },
                 "queue_depth": self.queue_depth,
                 "slots": self.slots,
                 "slots_active": self.slots_active,

@@ -130,10 +130,7 @@ def test_fully_shard_lm_step_with_flash_compiles_under_mesh(topo, monkeypatch):
     x = sd(jax.ShapeDtypeStruct((B, L), jnp.int32), P("fsdp"))
     hlo = step.lower(p_abs, o_abs, x, x).compile().as_text()
 
-    calls = [
-        line for line in hlo.splitlines()
-        if 'custom_call_target="tpu_custom_call"' in line
-    ]
+    calls = _custom_calls(hlo)
     assert calls, "no Mosaic custom call in the compiled step"
     # kernels see (B*H, L, Dh) per device: batch/2 rows x heads/2 heads
     local_bh = (B // 2) * (H // 2)
@@ -141,3 +138,114 @@ def test_fully_shard_lm_step_with_flash_compiles_under_mesh(topo, monkeypatch):
         operands = line.split("custom-call(", 1)[1]
         shapes = set(re.findall(r"bf16\[(\d+),(\d+),(\d+)\]", operands))
         assert shapes == {(str(local_bh), str(L), str(Dh))}, line
+
+
+def _custom_calls(hlo):
+    return [
+        line for line in hlo.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+
+
+def test_paged_decode_kernel_compiles_at_mistral_widths(topo, monkeypatch):
+    """`ops.paged_decode_attention` at the serve cells' shape (32 rows, 32
+    query heads over 8 KV heads of 128, 4096 pages of 16 tokens, 512-entry
+    tables) goes through Mosaic for a v5e, and the (num_blocks, bs * KV,
+    Dh) view it reads the pools through is a bitcast: a layout change
+    would copy the whole pool in every layer."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_example_tpu.ops import paged_decode_attention
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    B, H, KV, Dh, bs, nblk, nb = 32, 32, 8, 128, 16, 4096, 512
+    pool = sd((nblk, bs, KV, Dh), jnp.bfloat16)
+    hlo = jax.jit(paged_decode_attention).lower(
+        sd((B, H, Dh), jnp.bfloat16), pool, pool,
+        sd((B, nb), jnp.int32), sd((B,), jnp.int32),
+    ).compile().as_text()
+    (call,) = _custom_calls(hlo)
+    assert f"bf16[{B},{H},{Dh}]" in call.split("custom-call(")[0]
+    pool_ops = [
+        line for line in hlo.splitlines()
+        if f" = bf16[{nblk},{bs * KV},{Dh}]" in line
+    ]
+    assert len(pool_ops) == 2 and all(" bitcast(" in l for l in pool_ops)
+
+
+def test_tp2_paged_decode_step_compiles_under_mesh(topo, monkeypatch):
+    """A tp=2 engine's decode step: GSPMD partitions everything but the
+    decode attention kernel, which the step's `ops.partitioned_over`
+    context runs per device on its KV-head shard — q split on heads,
+    the pool on KV heads, tables and lengths whole. On the CPU mesh the kernel is
+    interpreted and GSPMD partitions it like any HLO, so only this
+    compile sees the custom call under the mesh."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from pytorch_distributed_example_tpu.models import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_example_tpu.models.transformer import (
+        sharding_rules,
+    )
+    from pytorch_distributed_example_tpu.parallel import sharding as shd
+    from pytorch_distributed_example_tpu.parallel.tensor_parallel import (
+        kv_pool_spec,
+    )
+    from pytorch_distributed_example_tpu.serve.cache import init_paged_cache
+    from pytorch_distributed_example_tpu.serve.decode import paged_programs
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")
+    mesh = Mesh(np.array(topo.devices[:2]), ("tp",))
+    S, H, KV, Dh, bs, nblk, M = 8, 4, 2, 128, 16, 64, 512
+    cfg = TransformerConfig(
+        vocab_size=2048, d_model=H * Dh, n_layers=2, n_heads=H,
+        n_kv_heads=KV, d_ff=1024, max_seq_len=M, dtype=jnp.bfloat16,
+    )
+    model = TransformerLM(cfg)
+    params = jax.eval_shape(
+        lambda r: model.init(r, jnp.zeros((1, 8), jnp.int32)),
+        jax.random.PRNGKey(0),
+    )["params"]
+    specs = shd.make_param_specs(
+        params, sharding_rules(tp_axis="tp", fsdp_axis=None), mesh
+    )
+    sd = lambda l, s: jax.ShapeDtypeStruct(
+        l.shape, l.dtype, sharding=NamedSharding(mesh, s)
+    )
+    tree = jax.eval_shape(lambda: init_paged_cache(model, nblk, bs))
+    whole = lambda shape, dt: sd(jax.ShapeDtypeStruct(shape, dt), P())
+    prefill_chunk, _, _, step = paged_programs(model, 0.0, None, mesh, "tp")
+    params_in = jax.tree_util.tree_map(sd, params, specs)
+    tree_in = jax.tree_util.tree_map(
+        lambda l: sd(l, kv_pool_spec(l, mesh, "tp")), tree
+    )
+    hlo = step.lower(
+        params_in, tree_in,
+        whole((S,), jnp.int32), whole((S,), jnp.int32),
+        whole((S, 2), jnp.uint32), whole((S, M // bs), jnp.int32),
+    ).compile().as_text()
+    calls = _custom_calls(hlo)
+    assert len(calls) == cfg.n_layers
+    for line in calls:
+        out, operands = line.split("custom-call(", 1)
+        assert f"bf16[{S},{H // 2},{Dh}]" in out, line
+        pools = re.findall(rf"bf16\[{nblk},(\d+),{Dh}\]", operands)
+        assert pools == [str(bs * KV // 2)] * 2, line
+    # a prefill chunk of ONE token is a decode call to the model: it
+    # takes the kernel inside the same context (outside it, the compiler
+    # refuses: "Mosaic kernels cannot be automatically partitioned")
+    hlo = prefill_chunk.lower(
+        params_in, tree_in, whole((1, 1), jnp.int32),
+        whole((1, M // bs), jnp.int32), whole((), jnp.int32),
+    ).compile().as_text()
+    assert len(_custom_calls(hlo)) == cfg.n_layers

@@ -31,6 +31,18 @@ EXPECTED = {
         "cache_attention": LAYER + r"cache_attention/.*dot_general",
         "sample": r"^jit\(step\)/sample/",
     },
+    # the same program at a head size the decode kernel takes (Dh = 128):
+    # every layer calls the kernel's one jitted body under cache_attention
+    # (the compiler inlines it, so on the chip the custom call's path is
+    # `.../cache_attention/jit(_per_device)/paged_decode_attention/
+    # pallas_call`), and nothing is gathered
+    "step_kernel": {
+        "rope": LAYER + r"rope/",
+        "kv_scatter": LAYER + r"kv_scatter/scatter",
+        "cache_attention": LAYER + r"cache_attention/jit\(_per_device\)$",
+        "kernel_body": r"^paged_decode_attention/",
+        "sample": r"^jit\(step\)/sample/",
+    },
     "prefill_chunk": {
         "rope": LAYER + r"rope/",
         "kv_scatter": LAYER + r"kv_scatter/scatter",
@@ -67,9 +79,9 @@ def _paths(lowered) -> dict:
     }
 
 
-def _model(**kw):
+def _model(d_model=32, **kw):
     cfg = TransformerConfig(
-        vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2,
+        vocab_size=64, d_model=d_model, n_heads=4, n_kv_heads=2, n_layers=2,
         d_ff=64, max_seq_len=64, **kw)
     model = TransformerLM(cfg)
     return model, model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
@@ -93,8 +105,13 @@ def serve_paths():
     bt = jnp.zeros((S, 64 // bs), jnp.int32)
     lanes = jnp.zeros((S,), jnp.int32)
     rngs = jnp.zeros((S, 2), jnp.uint32)
+    wide, wide_vars = _model(d_model=512)  # 4 heads of 128
+    wide_step = paged_programs(wide, 0.0, None)[3]
     return {
         "step": _paths(step.lower(params, tree, lanes, lanes, rngs, bt)),
+        "step_kernel": _paths(wide_step.lower(
+            wide_vars["params"], init_paged_cache(wide, nblk, bs),
+            lanes, lanes, rngs, bt)),
         "prefill_chunk": _paths(prefill_chunk.lower(
             params, tree, jnp.zeros((1, 16), jnp.int32), bt[:1], 0)),
         "first_token": _paths(first_token.lower(
@@ -145,7 +162,7 @@ CASES = [(prog, scope) for prog, scopes_ in EXPECTED.items() for scope in scopes
 
 @pytest.mark.parametrize("program,scope", CASES, ids=[f"{p}-{s}" for p, s in CASES])
 def test_scope_sits_under_its_flax_path(program, scope, request):
-    serve = program in ("step", "prefill_chunk", "first_token")
+    serve = program in ("step", "step_kernel", "prefill_chunk", "first_token")
     paths = request.getfixturevalue(
         "serve_paths" if serve else "train_paths")[program]["paths"]
     rx = re.compile(EXPECTED[program][scope])
@@ -154,12 +171,27 @@ def test_scope_sits_under_its_flax_path(program, scope, request):
         f"{scope!r}: {sorted(p for p in paths if scope in p)[:5]}")
 
 
+def test_the_kernel_step_gathers_nothing(serve_paths):
+    """With the decode kernel in the step no operation is traced under
+    `kv_gather` (the queued `decode_kv_gather_ms` then reads nothing in
+    `jit_step`), and the old path's step keeps both scopes."""
+    assert serve_paths["step_kernel"]["program"] == "jit_step"
+    assert not [p for p in serve_paths["step_kernel"]["paths"] if "kv_gather" in p]
+    calls = [p for p in serve_paths["step_kernel"]["paths"]
+             if p.endswith("cache_attention/jit(_per_device)")]
+    assert len(calls) == 2  # one a layer, each under its own layer's scope
+    assert not [p for p in serve_paths["step"]["paths"]
+                if "paged_decode_attention" in p]
+    assert not [p for p in serve_paths["prefill_chunk"]["paths"]
+                if "paged_decode_attention" in p]
+
+
 # the metric files' own patterns, against the same lowerings (queued: the
 # harness takes a cell's metrics from its own file, a `benchmark` PR's to edit)
 METRICS = Path(__file__).resolve().parents[1] / "bench_matrix" / "layer_metrics_queued"
 READ_BY = {
     "decode_kv_gather_ms": ["step"],
-    "decode_cache_attention_ms": ["step"],
+    "decode_cache_attention_ms": ["step", "step_kernel"],
     "prefill_cache_attention_ms": ["prefill_chunk"],
     "train_mlp_ms": ["ddp", "fsdp"],
     "train_attention_ms": ["ddp", "fsdp"],
@@ -174,7 +206,7 @@ def test_metric_file_finds_operations_in_the_program_it_names(metric, request):
 
     args = json.loads((METRICS / f"{metric}.json").read_text())["args"]
     for program in READ_BY[metric]:
-        serve = program in ("step", "prefill_chunk")
+        serve = program in ("step", "step_kernel", "prefill_chunk")
         low = request.getfixturevalue("serve_paths" if serve else "train_paths")[program]
         assert re.search(args["program"], low["program"]), (metric, low["program"])
         rx = re.compile(args["scope"])

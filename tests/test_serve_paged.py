@@ -520,6 +520,168 @@ class TestTensorParallelDecode:
         q = eng.params["layers_0"]["attn"]["q_proj"]["kernel"]
         assert "tp" in (q.sharding.spec[-1] or ())
 
+class TestDecodeKernel:
+    """The decode step at a head size `ops.paged_decode_ok` accepts
+    (Dh = 128): the step program runs `ops.paged_decode_attention`
+    (interpreted here) while prefill chunks keep the gather + einsum."""
+
+    @staticmethod
+    def _wide_model():
+        import jax
+        import jax.numpy as jnp
+
+        from pytorch_distributed_example_tpu.models import (
+            TransformerConfig,
+            TransformerLM,
+        )
+
+        cfg = TransformerConfig(
+            vocab_size=64, d_model=512, n_layers=2, n_heads=4,
+            n_kv_heads=2, d_ff=64, max_seq_len=64, use_flash=False,
+        )
+        model = TransformerLM(cfg)
+        return model, model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
+        )
+
+    @pytest.mark.parametrize("tp", [1, 2])
+    def test_greedy_token_exact_and_every_step_counted(
+        self, no_fault_plan, tp
+    ):
+        """Greedy tokens equal `generate()`'s, exactly: in float32 the
+        kernel differs from the dense path by reassociation (~1e-6 of a
+        logit) and no argmax of these prompts sits that close to a tie,
+        so no match-rate bound is needed. Slots retire and park while
+        others decode, so parked lanes ride through the kernel too.
+        `tp=2` runs it per device on its KV-head shard."""
+        import jax.numpy as jnp
+
+        from pytorch_distributed_example_tpu.models import generate
+        from pytorch_distributed_example_tpu.serve import ServeEngine
+
+        model, params = self._wide_model()
+        prompts = _prompts(5, 19, 3, 11, 26)
+        budgets = [9, 4, 30, 12, 7]
+        eng = ServeEngine(
+            model, params, slots=3, min_bucket=4, block_size=8,
+            prefill_chunk_tokens=8, mesh=_tp_mesh(2) if tp == 2 else None,
+        )
+        rids = [eng.submit(p, m) for p, m in zip(prompts, budgets)]
+        out = eng.run(max_steps=500)
+        assert eng.metrics.completed == len(prompts)
+        for p, m, r in zip(prompts, budgets, rids):
+            ref = np.asarray(
+                generate(model, params, jnp.asarray(p)[None], m)
+            )[0]
+            np.testing.assert_array_equal(np.asarray(out[r].tokens), ref)
+        decode = eng.metrics.snapshot()["decode"]
+        assert decode["steps"] > 0
+        assert decode["kernel_steps"] == decode["steps"]
+        assert decode["kernel_share"] == 1.0
+
+    def test_tp2_one_token_chunks_run_the_kernel_per_device(
+        self, no_fault_plan
+    ):
+        """A prefill chunk of ONE token is a decode call to the model
+        (L == 1), so it takes the kernel too — under a tp mesh inside
+        the same `partitioned_over` context as the step, or GSPMD would
+        be handed a Mosaic call it cannot partition on the chip. Here:
+        the chunk program lowers with the kernel inside a `shard_map`,
+        and the engine's tokens stay `generate()`'s."""
+        import jax.numpy as jnp
+
+        from pytorch_distributed_example_tpu.models import generate
+        from pytorch_distributed_example_tpu.serve import ServeEngine
+
+        model, params = self._wide_model()
+        eng = ServeEngine(
+            model, params, slots=2, min_bucket=4, block_size=8,
+            prefill_chunk_tokens=1, mesh=_tp_mesh(2),
+        )
+        text = eng._prefill_chunk.lower(
+            eng.params, eng.cache.tree, np.zeros((1, 1), np.int32),
+            eng.cache.block_tables[:1], 0,
+        ).as_text(debug_info=True)
+        assert "paged_decode_attention" in text and "shard_map" in text
+        (prompt,) = _prompts(6)
+        rid = eng.submit(prompt, 5)
+        out = eng.run(max_steps=100)
+        ref = np.asarray(generate(model, params, jnp.asarray(prompt)[None], 5))
+        np.testing.assert_array_equal(np.asarray(out[rid].tokens), ref[0])
+
+    @pytest.mark.parametrize("head", ["tiny_head", "kernel_head"])
+    def test_precompiled_engine_runs_the_saved_programs(
+        self, no_fault_plan, monkeypatch, head
+    ):
+        """The resize fast path (`serve/prewarm.py`): an engine given
+        pre-warmed executables serves through them — no call reaches
+        the jit quadruple behind them — and emits the tokens of an engine
+        without them, kernel step or gather step. The executables are
+        the ones `prewarm_engine_programs` hands to be saved, keyed as
+        `load_precompiled` returns them; they skip the round trip
+        through a file because under this harness (8 virtual devices,
+        fusion emitters off) XLA:CPU refuses to run an executable it
+        deserialized ("Function ..._fusion not found"); the chip runs
+        them (PERF.md, PR 25)."""
+        from pytorch_distributed_example_tpu.serve import ServeEngine, prewarm
+
+        model, params = _model() if head == "tiny_head" else self._wide_model()
+        kw = dict(slots=2, min_bucket=4, block_size=8, prefill_chunk_tokens=8)
+        cold = ServeEngine(model, params, **kw)
+        assert cold._decode_kernel == (head == "kernel_head")
+        saved = {}
+        monkeypatch.setattr(
+            prewarm, "_save_precompiled",
+            lambda compiled, save_dir, tp=1: saved.update(compiled),
+        )
+        prewarm.prewarm_engine_programs(cold, save_dir="unused")
+        assert set(saved) == {
+            ("prefill_chunk", 8), ("first_token", 8), ("attach", 2),
+            ("step", 2),
+        }
+        warm = ServeEngine(model, params, precompiled=saved, **kw)
+
+        def traced(*args):
+            raise AssertionError("a call fell through to the jit program")
+
+        for prog in (
+            warm._prefill_chunk, warm._first_token, warm._attach, warm._step
+        ):
+            assert isinstance(prog, prewarm._ChunkDispatch)
+            prog._fallback = traced
+        # lengths whose every chunk is the pre-warmed width (a shorter
+        # tail takes its own bucket's program, unwarmed by design)
+        prompts = _prompts(5, 13)
+        rids = [warm.submit(p, 6) for p in prompts]
+        out = warm.run(max_steps=100)
+        assert warm.metrics.completed == 2
+        ref_rids = [cold.submit(p, 6) for p in prompts]
+        ref = cold.run(max_steps=100)
+        for r, rr in zip(rids, ref_rids):
+            assert out[r].tokens == ref[rr].tokens
+
+    @pytest.mark.parametrize("engine", ["tiny_head", "int8_pool"])
+    def test_other_engines_count_no_kernel_step(self, no_fault_plan, engine):
+        """The counter names the path the step program took: the tiny
+        heads of the other tests and an int8 pool keep the gather."""
+        from pytorch_distributed_example_tpu.serve import ServeEngine
+
+        if engine == "tiny_head":
+            model, params = _model()
+            eng = ServeEngine(model, params, slots=2, min_bucket=4)
+        else:
+            model, params = self._wide_model()
+            eng = ServeEngine(
+                model, params, slots=2, min_bucket=4, block_size=8,
+                kv_quant=True,
+            )
+        eng.submit(_prompts(5)[0], 4)
+        eng.run(max_steps=100)
+        decode = eng.metrics.snapshot()["decode"]
+        assert decode["steps"] > 0 and decode["kernel_steps"] == 0
+        assert decode["kernel_share"] == 0.0
+
+
 _TRAINED_CACHE = {}
 
 
