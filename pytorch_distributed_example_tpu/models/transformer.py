@@ -28,6 +28,31 @@ import jax.numpy as jnp
 
 
 @dataclass(frozen=True)
+class RopeSpec:
+    """One rotary embedding: its base, the leading share of a head it
+    rotates (the rest passes unrotated), and YaRN's five numbers
+    (factor, original_max_position_embeddings, beta_fast, beta_slow,
+    attention_factor) where the frequencies are stretched."""
+
+    theta: float = 10000.0
+    rotary_fraction: float = 1.0
+    yarn: Optional[Tuple[float, int, float, float, float]] = None
+
+
+@dataclass(frozen=True)
+class LayerSpec:
+    """What one layer of a patterned model is. `attention` is "full" or
+    "window" (the last `cfg.window` keys); `n_heads` and `rope` default
+    to the model's; `mlp` is "dense" (SwiGLU at `cfg.ffn_dim`) or
+    "sparse" (`SparseMoE` at the `sparse_*` sizes)."""
+
+    attention: str = "full"
+    n_heads: Optional[int] = None
+    rope: Optional[RopeSpec] = None
+    mlp: str = "dense"
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 512
@@ -45,6 +70,49 @@ class TransformerConfig:
     n_experts: int = 0  # > 0 switches the MLP to a top-k MoE
     moe_capacity_factor: float = 1.25
     moe_top_k: int = 1  # 1 = Switch, 2 = GShard/Mixtral-style
+    # -- a layer pattern (all None/0: n_layers blocks alike, as above) -----
+    head_size: Optional[int] = None  # None = d_model // n_heads
+    layers: Optional[Tuple[LayerSpec, ...]] = None  # one entry a layer
+    window: Optional[int] = None  # keys a "window" layer attends
+    attn_gate: bool = False  # per-head sigmoid gate on the attention output
+    rope_pairs: str = "interleaved"  # or "halves" (the Hugging Face port)
+    # the "sparse" MLP: dropless top-k over `sparse_experts` SwiGLU experts
+    # of width `sparse_d_ff`, weights normalised then times `routed_scale`,
+    # plus one shared SwiGLU of width `shared_d_ff` (0: none) unweighted.
+    # `experts_held` = (first, count): the contiguous experts this chip
+    # holds of a layer (None: all) - it routes over all of them and
+    # computes its own experts' part.
+    sparse_experts: int = 0
+    sparse_top_k: int = 0
+    sparse_d_ff: int = 0
+    shared_d_ff: int = 0
+    routed_scale: float = 1.0
+    experts_held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        if self.rope_pairs not in ("interleaved", "halves"):
+            raise ValueError(f"rope_pairs {self.rope_pairs!r}")
+        if self.layers is None:
+            return
+        if len(self.layers) != self.n_layers:
+            raise ValueError(
+                f"{len(self.layers)} layer specs for n_layers={self.n_layers}"
+            )
+        for i, spec in enumerate(self.layers):
+            if spec.attention not in ("full", "window") or spec.mlp not in (
+                "dense", "sparse"
+            ):
+                raise ValueError(f"layer {i}: {spec}")
+            if spec.attention == "window" and not self.window:
+                raise ValueError(f"layer {i} is a window layer and window is unset")
+            if (spec.n_heads or self.n_heads) % self.kv_heads:
+                raise ValueError(f"layer {i}: heads do not divide over kv_heads")
+            if spec.mlp == "sparse" and not (
+                self.sparse_experts >= self.sparse_top_k > 0 and self.sparse_d_ff
+            ):
+                raise ValueError(
+                    f"layer {i} is sparse and sparse_experts/top_k/d_ff are unset"
+                )
 
     @property
     def kv_heads(self) -> int:
@@ -52,7 +120,32 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
+
+    def layer(self, i: int) -> Optional[LayerSpec]:
+        """Layer i's spec with the model's defaults filled in, or None for
+        a model without a pattern (every consumer then takes the path it
+        took before patterns existed)."""
+        if self.layers is None:
+            return None
+        spec = self.layers[i]
+        return LayerSpec(
+            spec.attention, spec.n_heads or self.n_heads,
+            spec.rope or RopeSpec(self.rope_theta), spec.mlp,
+        )
+
+    @property
+    def window_layers(self) -> Tuple[bool, ...]:
+        """Per layer: does it keep a window of K/V only."""
+        if self.layers is None:
+            return (False,) * self.n_layers
+        return tuple(spec.attention == "window" for spec in self.layers)
+
+    @property
+    def sparse_layers(self) -> Tuple[int, ...]:
+        if self.layers is None:
+            return ()
+        return tuple(i for i, spec in enumerate(self.layers) if spec.mlp == "sparse")
 
     @property
     def ffn_dim(self) -> int:
@@ -82,30 +175,70 @@ def rope_freqs(head_dim: int, max_len: int, theta: float):
     return jnp.cos(ang), jnp.sin(ang)
 
 
-def apply_rope(x, cos, sin):
-    """x: (B, L, H, D); rotate pairs (even, odd) by position angle."""
+def rope_table(spec: RopeSpec, head_dim: int, max_len: int):
+    """(cos, sin), each (max_len, r / 2), of one `RopeSpec`: r =
+    `rotary_fraction * head_dim` leading values of a head rotate. With
+    `yarn` the inverse frequencies are blended between theta^(-2i/r) and
+    that over `factor` by the linear ramp between the two correction
+    dimensions (where `original_max` positions make `beta_fast` and
+    `beta_slow` turns), and cos and sin carry `attention_factor`."""
+    import math
+
+    r = int(head_dim * spec.rotary_fraction)
+    if spec.yarn is None:
+        return rope_freqs(r, max_len, spec.theta)
+    factor, original_max, beta_fast, beta_slow, attention_factor = spec.yarn
+
+    def correction_dim(turns):
+        return r * math.log(original_max / (turns * 2 * math.pi)) / (
+            2 * math.log(spec.theta)
+        )
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), r - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip(
+        (jnp.arange(r // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0
+    )
+    plain = 1.0 / (spec.theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    inv = plain / factor * ramp + plain * (1.0 - ramp)
+    ang = jnp.outer(jnp.arange(max_len, dtype=jnp.float32), inv)
+    return jnp.cos(ang) * attention_factor, jnp.sin(ang) * attention_factor
+
+
+def _rotate(x, c, s, halves: bool):
+    """Rotate the leading `2 * c.shape[-1]` values of every head of x by
+    the angles whose cos/sin are c/s (already broadcastable to x's
+    pairs); values past them pass. Pairs are (even, odd) neighbours, or
+    with `halves` value j and value j + r/2."""
+    half, D = c.shape[-1], x.shape[-1]
+    whole = 2 * half == D
+    xr = x if whole else x[..., : 2 * half]
+    if halves:
+        x1, x2 = xr[..., :half], xr[..., half:]
+        rot = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    else:
+        x1, x2 = xr[..., 0::2], xr[..., 1::2]
+        rot = jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).reshape(
+            xr.shape
+        )
+    rot = rot.astype(x.dtype)
+    return rot if whole else jnp.concatenate([rot, x[..., 2 * half:]], axis=-1)
+
+
+def apply_rope(x, cos, sin, halves: bool = False):
+    """x: (B, L, H, D); rotate pairs by position angle (see `_rotate`)."""
     with jax.named_scope("rope"):
-        x1, x2 = x[..., 0::2], x[..., 1::2]
-        c = cos[None, :, None, :]
-        s = sin[None, :, None, :]
-        r1 = x1 * c - x2 * s
-        r2 = x2 * c + x1 * s
-        out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
-        return out.astype(x.dtype)
+        return _rotate(x, cos[None, :, None, :], sin[None, :, None, :], halves)
 
 
-def apply_rope_batched(x, cos, sin):
-    """x: (B, L, H, D); cos/sin: (B, L, D/2) — per-SAMPLE position
+def apply_rope_batched(x, cos, sin, halves: bool = False):
+    """x: (B, L, H, D); cos/sin: (B, L, r/2) — per-SAMPLE position
     angles, for decode batches where every row sits at its own absolute
     position (the serve engine's slot batch)."""
     with jax.named_scope("rope"):
-        x1, x2 = x[..., 0::2], x[..., 1::2]
-        c = cos[:, :, None, :]
-        s = sin[:, :, None, :]
-        r1 = x1 * c - x2 * s
-        r2 = x2 * c + x1 * s
-        out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
-        return out.astype(x.dtype)
+        return _rotate(x, cos[:, :, None, :], sin[:, :, None, :], halves)
 
 
 def _dense_attention(q, k, v, causal, scale):
@@ -115,8 +248,69 @@ def _dense_attention(q, k, v, causal, scale):
         return dense_attention(q, k, v, causal=causal, scale=scale)
 
 
+def _position_mask(q_pos, key_pos, window=None):
+    """(..., L, M) bool: key j is attended by query i when j <= i and,
+    with a window, i - window < j. Positions are absolute; leading axes
+    of `q_pos` (..., L) and `key_pos` (..., M) broadcast."""
+    mask = key_pos[..., None, :] <= q_pos[..., :, None]
+    if window is not None:
+        mask &= key_pos[..., None, :] > q_pos[..., :, None] - window
+    return mask
+
+
+def _grouped_attention(q, k, v, scale, mask):
+    """Masked softmax attention against UN-repeated K/V: q (B, L, H, Dh),
+    k/v (B, M, KV, Dh), mask (B or 1, L, M). Scores and softmax in
+    float32, probabilities cast to v's dtype; returns (B, L, H * Dh)."""
+    B, L, H, Dh = q.shape
+    KV = k.shape[2]
+    qg = q.reshape(B, L, KV, H // KV, Dh)
+    s = jnp.einsum("blkrd,bmkd->bkrlm", qg, k) * scale  # (B,KV,rep,L,M)
+    s = jnp.where(mask[:, None, None], s.astype(jnp.float32), -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bkrlm,bmkd->blkrd", p, v).reshape(B, L, H * Dh)
+
+
 class Attention(nn.Module):
+    """`spec` (a resolved `LayerSpec`, `cfg.layer(i)`) says what this
+    layer is in a patterned model: its query heads, and whether it
+    attends a window. None is a model without a pattern: `cfg.n_heads`
+    heads, full attention, no gate, and nothing below reads a pattern
+    field of `cfg`."""
+
     cfg: TransformerConfig
+    spec: Optional[LayerSpec] = None
+
+    @property
+    def heads(self) -> int:
+        return self.cfg.n_heads if self.spec is None else self.spec.n_heads
+
+    @property
+    def window(self) -> Optional[int]:
+        """Keys a query attends, itself included: position i sees j with
+        i - window < j <= i. None: every j <= i."""
+        if self.spec is None or self.spec.attention != "window":
+            return None
+        return self.cfg.window
+
+    @property
+    def rope_halves(self) -> bool:
+        return self.spec is not None and self.cfg.rope_pairs == "halves"
+
+    @nn.nowrap
+    def _project_out(self, o, x, dense):
+        """(B, L, H * Dh) attention output -> the block's residual term:
+        each head times its sigmoid gate where the model has one, then
+        `o_proj`."""
+        if self.spec is not None and self.cfg.attn_gate:
+            B, L, _ = o.shape
+            H = self.heads
+            with jax.named_scope("attn_gate"):
+                g = jax.nn.sigmoid(dense(H, "head_gate")(x))  # (B, L, H)
+                o = (o.reshape(B, L, H, -1) * g[..., None].astype(o.dtype)).reshape(
+                    B, L, -1
+                )
+        return dense(self.cfg.d_model, "o_proj")(o)
 
     @nn.compact
     def __call__(
@@ -125,7 +319,7 @@ class Attention(nn.Module):
     ):
         cfg = self.cfg
         B, L, _ = x.shape
-        H, KV, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        H, KV, Dh = self.heads, cfg.kv_heads, cfg.head_dim
         dense = lambda feats, name: nn.Dense(
             feats, use_bias=False, dtype=cfg.dtype, name=name
         )
@@ -135,12 +329,24 @@ class Attention(nn.Module):
         scale = 1.0 / (Dh ** 0.5)
 
         if decode:
-            return self._decode(
-                q, k, v, cos, sin, scale, dense, positions, block_tables
+            o = self._decode(
+                q, k, v, cos, sin, scale, positions, block_tables
             )
+            return self._project_out(o, x, dense)
 
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q = apply_rope(q, cos, sin, self.rope_halves)
+        k = apply_rope(k, cos, sin, self.rope_halves)
+        if self.window is not None:
+            # the dense masked path; a window inside the flash kernel
+            # comes with the training cell that needs it
+            if cfg.use_flash:
+                _flash_ok(L, Dh, self.window)
+            with jax.named_scope("window_attention"):
+                o = _grouped_attention(
+                    q, k, v, scale,
+                    _position_mask(jnp.arange(L), jnp.arange(L), self.window)[None],
+                )
+            return self._project_out(o, x, dense)
         if KV != H:  # GQA: repeat kv groups to full heads
             rep = H // KV
             k = jnp.repeat(k, rep, axis=2)
@@ -153,11 +359,10 @@ class Attention(nn.Module):
         else:
             o = _dense_attention(q, k, v, cfg.causal, scale)
         o = o.reshape(B, L, H * Dh)
-        return dense(cfg.d_model, "o_proj")(o)
+        return self._project_out(o, x, dense)
 
     def _decode(
-        self, q, k, v, cos, sin, scale, dense, positions=None,
-        block_tables=None,
+        self, q, k, v, cos, sin, scale, positions=None, block_tables=None,
     ):
         """KV-cache step: write this call's K/V at the running index into
         static (B, max_seq_len) buffers (flax "cache" collection), attend
@@ -187,7 +392,11 @@ class Attention(nn.Module):
         the row's logical layout under the same absolute-position causal
         mask, by one of the two paths `_decode_paged` describes; there is
         no "index" variable on this path (the pool has no per-row
-        cursor)."""
+        cursor).
+
+        Returns the attention output (B, L, H * Dh), before the gate and
+        `o_proj`. A window layer masks the same buffers by
+        `i - window < j <= i`."""
         from jax import lax
 
         cfg = self.cfg
@@ -198,8 +407,9 @@ class Attention(nn.Module):
                 "no autoregressive decode"
             )
         B, L, KV, Dh = k.shape
-        H = cfg.n_heads
+        H = self.heads
         M = cfg.max_seq_len
+        halves = self.rope_halves
         # flax decode-cache convention: during init (variables not yet
         # present) only CREATE them — persisting the write would hand the
         # caller a cache whose index already advanced past the init input
@@ -214,7 +424,7 @@ class Attention(nn.Module):
                     "the module cannot size the pool from the batch"
                 )
             return self._decode_paged(
-                q, k, v, cos, sin, scale, dense, positions, block_tables
+                q, k, v, cos, sin, scale, positions, block_tables
             )
         ck = self.variable(
             "cache", "k", jnp.zeros, (B, M, KV, Dh), k.dtype
@@ -230,8 +440,8 @@ class Attention(nn.Module):
             idx = ci.value
             pos_cos = lax.dynamic_slice_in_dim(cos, idx, L, axis=0)
             pos_sin = lax.dynamic_slice_in_dim(sin, idx, L, axis=0)
-            q = apply_rope(q, pos_cos, pos_sin)
-            k = apply_rope(k, pos_cos, pos_sin)
+            q = apply_rope(q, pos_cos, pos_sin, halves)
+            k = apply_rope(k, pos_cos, pos_sin, halves)
             with jax.named_scope("kv_scatter"):
                 kf = lax.dynamic_update_slice_in_dim(ck.value, k, idx, axis=1)
                 vf = lax.dynamic_update_slice_in_dim(cv.value, v, idx, axis=1)
@@ -240,13 +450,13 @@ class Attention(nn.Module):
                 cv.value = vf
                 ci.value = idx + L
             q_pos = idx + jnp.arange(L)
-            mask = key_pos[None, :] <= q_pos[:, None]  # causal over cache
-            mask = mask[None]  # (1, L, M) broadcast over batch
+            # causal over cache; (1, L, M) broadcast over batch
+            mask = _position_mask(q_pos, key_pos, self.window)[None]
         else:
             idx = positions.astype(jnp.int32)  # (B,)
             pos = idx[:, None] + jnp.arange(L)[None, :]  # (B, L) absolute
-            q = apply_rope_batched(q, cos[pos], sin[pos])
-            k = apply_rope_batched(k, cos[pos], sin[pos])
+            q = apply_rope_batched(q, cos[pos], sin[pos], halves)
+            k = apply_rope_batched(k, cos[pos], sin[pos], halves)
             write = jax.vmap(
                 lambda buf, upd, i: lax.dynamic_update_slice_in_dim(
                     buf, upd, i, axis=0
@@ -258,21 +468,15 @@ class Attention(nn.Module):
             if is_initialized:
                 ck.value = kf
                 cv.value = vf
-            mask = key_pos[None, None, :] <= pos[:, :, None]  # (B, L, M)
+            mask = _position_mask(pos, key_pos[None], self.window)  # (B, L, M)
         # GQA: group the query heads and attend against the UN-repeated
         # cache — repeating the (B, M, KV, Dh) buffers up to H heads per
         # step would forfeit the KV-cache bandwidth saving GQA exists for
-        rep = H // KV
         with jax.named_scope("cache_attention"):
-            qg = q.reshape(B, L, KV, rep, Dh)
-            s = jnp.einsum("blkrd,bmkd->bkrlm", qg, kf) * scale  # (B,KV,rep,L,M)
-            s = jnp.where(mask[:, None, None], s.astype(jnp.float32), -1e30)
-            p = jax.nn.softmax(s, axis=-1).astype(vf.dtype)
-            o = jnp.einsum("bkrlm,bmkd->blkrd", p, vf).reshape(B, L, H * Dh)
-        return dense(cfg.d_model, "o_proj")(o)
+            return _grouped_attention(q, kf, vf, scale, mask)
 
     def _decode_paged(
-        self, q, k, v, cos, sin, scale, dense, positions, block_tables
+        self, q, k, v, cos, sin, scale, positions, block_tables
     ):
         """Paged-pool variant of the per-sample decode path (see _decode).
 
@@ -306,18 +510,30 @@ class Attention(nn.Module):
         vector per kv-head (`ops.quant.quantize_kv`) and scatter value
         and scale through the SAME flat index (same drop semantics);
         reads dequantize inside `ops.gather_paged_kv`, so the scores/
-        softmax/output math below is identical in both modes."""
+        softmax/output math below is identical in both modes.
+
+        A WINDOW layer (`self.window`) runs the same two paths over its
+        own pool and table (`serve/cache.py` frees a row's blocks behind
+        the window while the request lives, so leading table entries may
+        be invalid): the kernel starts each row at its first attended
+        page, and the gather takes the `window + L` keys the call can
+        attend (`ops.paged_window_span`) and not the table's span. Both
+        sit under a `window_attention` scope around `cache_attention`."""
+        import contextlib
+
         from ..ops import (
             gather_paged_kv,
             paged_decode_attention,
             paged_decode_ok,
+            paged_window_span,
         )
         from ..ops.quant import quantize_kv
 
         cfg = self.cfg
         B, L, KV, Dh = k.shape
-        H = cfg.n_heads
+        H = self.heads
         M = cfg.max_seq_len
+        window, halves = self.window, self.rope_halves
         quantized = self.has_variable("cache", "k_scale")
         ck = self.variable("cache", "k", lambda: None)
         cv = self.variable("cache", "v", lambda: None)
@@ -330,8 +546,8 @@ class Attention(nn.Module):
         idx = positions.astype(jnp.int32)  # (B,) absolute start positions
         pos = idx[:, None] + jnp.arange(L)[None, :]  # (B, L) absolute
         safe = jnp.clip(pos, 0, M - 1)  # RoPE table bound; overshoot is
-        q = apply_rope_batched(q, cos[safe], sin[safe])  # dropped below
-        k = apply_rope_batched(k, cos[safe], sin[safe])
+        q = apply_rope_batched(q, cos[safe], sin[safe], halves)  # dropped below
+        k = apply_rope_batched(k, cos[safe], sin[safe], halves)
 
         lb = pos // bs  # (B, L) logical block
         off = pos % bs
@@ -369,34 +585,41 @@ class Attention(nn.Module):
             else:
                 ck.value = scatter(ck.value, k)
                 cv.value = scatter(cv.value, v)
+        scope = (
+            jax.named_scope("window_attention") if window is not None
+            else contextlib.nullcontext()
+        )
         if paged_decode_ok(L, ck.value, block_tables):
-            with jax.named_scope("cache_attention"):
-                o = paged_decode_attention(
-                    q[:, 0], ck.value, cv.value, block_tables, idx, scale
+            with scope, jax.named_scope("cache_attention"):
+                return paged_decode_attention(
+                    q[:, 0], ck.value, cv.value, block_tables, idx, scale,
+                    window=window,
                 ).reshape(B, L, H * Dh)
-            return dense(cfg.d_model, "o_proj")(o)
-        with jax.named_scope("kv_gather"):
-            kf, vf = gather_paged_kv(
-                ck.value, cv.value, block_tables,
-                k_scale=cks.value if quantized else None,
-                v_scale=cvs.value if quantized else None,
-                out_dtype=cfg.dtype,
-            )
-        with jax.named_scope("cache_attention"):
-            Mb = nb * bs  # logical key span the tables cover (>= M)
-            key_pos = jnp.arange(Mb)
-            mask = key_pos[None, None, :] <= pos[:, :, None]  # (B, L, Mb)
-            rep = H // KV
-            qg = q.reshape(B, L, KV, rep, Dh)
-            s = jnp.einsum("blkrd,bmkd->bkrlm", qg, kf) * scale
-            s = jnp.where(mask[:, None, None], s.astype(jnp.float32), -1e30)
-            p = jax.nn.softmax(s, axis=-1).astype(vf.dtype)
-            o = jnp.einsum("bkrlm,bmkd->blkrd", p, vf).reshape(B, L, H * Dh)
-        return dense(cfg.d_model, "o_proj")(o)
+        first_block = n_blocks = None
+        key0 = jnp.zeros((B,), jnp.int32)
+        if window is not None:
+            first_block, n_blocks = paged_window_span(idx, L, window, bs, nb)
+            key0 = first_block * bs
+        with scope:
+            with jax.named_scope("kv_gather"):
+                kf, vf = gather_paged_kv(
+                    ck.value, cv.value, block_tables,
+                    k_scale=cks.value if quantized else None,
+                    v_scale=cvs.value if quantized else None,
+                    out_dtype=cfg.dtype,
+                    first_block=first_block, n_blocks=n_blocks,
+                )
+            with jax.named_scope("cache_attention"):
+                # logical key span gathered: the tables' (>= M), or a
+                # window layer's `n_blocks` from its first attended block
+                key_pos = key0[:, None] + jnp.arange(kf.shape[1])[None, :]
+                mask = _position_mask(pos, key_pos, window)  # (B, L, Mb)
+                return _grouped_attention(q, kf, vf, scale, mask)
 
-
-def _flash_ok(L: int, Dh: int) -> bool:
-    """Whether the flash kernel can take this shape: L divisible by the
+def _flash_ok(L: int, Dh: int, window: Optional[int] = None) -> bool:
+    """Whether the flash kernel can take this call. A window layer never
+    is (the kernel has no window yet) and says so once, like an
+    untileable shape: L divisible by the
     EFFECTIVE block sizes (`resolved_block_sizes` fits env/table
     candidates so they tile L whenever possible) and head_dim within the
     kernel's VMEM tile. A `use_flash=True` model that lands on dense
@@ -404,6 +627,16 @@ def _flash_ok(L: int, Dh: int) -> bool:
     different memory and speed regime, not a detail)."""
     from ..ops.flash_attention import resolved_block_sizes
 
+    if window is not None:
+        import warnings
+
+        warnings.warn(
+            f"use_flash=True but the flash kernel has no window: this "
+            f"window-{window} layer runs DENSE masked attention",
+            RuntimeWarning,
+            stacklevel=2,
+        )
+        return False
     bq, bk = resolved_block_sizes(L)
     ok = L % bq == 0 and L % bk == 0 and Dh <= 256
     if not ok:
@@ -421,11 +654,12 @@ def _flash_ok(L: int, Dh: int) -> bool:
 
 class MLP(nn.Module):
     cfg: TransformerConfig
+    width: Optional[int] = None  # None: cfg.ffn_dim
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        F = cfg.ffn_dim
+        F = self.width or cfg.ffn_dim
         dense = lambda feats, name: nn.Dense(
             feats, use_bias=False, dtype=cfg.dtype, name=name
         )
@@ -468,22 +702,74 @@ class MoE(nn.Module):
         return y.reshape(B, L, D)
 
 
+class SparseMoE(nn.Module):
+    """The "sparse" MLP of a layer pattern: dropless top-k routing over
+    `cfg.sparse_experts` SwiGLU experts (`parallel/expert_parallel.py::
+    dropless_moe`: every assignment is computed, none dropped, no
+    capacity), plus one shared SwiGLU expert added unweighted. Experts
+    are stacked (held, D, F) x 2 and (held, F, D), `held` the contiguous
+    range `cfg.experts_held` gives this chip (all of them by default);
+    the router keeps its full width.
+
+    `row_mask` ((B, L) bool) marks the rows that are real tokens: the
+    others (a parked lane of the serve step, the padding of a prefill
+    chunk) route nowhere and count nowhere. Per call the layer sows
+    `intermediates/moe_stats`: int32 (assignments computed here,
+    distinct experts here with at least one row), and `moe_chosen`, the
+    (B, L, top_k) experts the router picked."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, row_mask=None):
+        from ..parallel.expert_parallel import dropless_moe
+
+        cfg = self.cfg
+        B, L, D = x.shape
+        E, F = cfg.sparse_experts, cfg.sparse_d_ff
+        first, held = cfg.experts_held or (0, E)
+        # fan-in of ONE expert, whatever the stack holds
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        router = self.param("router", nn.initializers.lecun_normal(), (D, E))
+        w_gate = self.param("experts_gate", init, (held, D, F))
+        w_up = self.param("experts_up", init, (held, D, F))
+        w_down = self.param("experts_down", init, (held, F, D))
+        with jax.named_scope("moe"):
+            y, stats, chosen = dropless_moe(
+                x.reshape(B * L, D).astype(cfg.dtype), router,
+                w_gate.astype(cfg.dtype), w_up.astype(cfg.dtype),
+                w_down.astype(cfg.dtype),
+                n_experts=E, top_k=cfg.sparse_top_k, scale=cfg.routed_scale,
+                first_expert=first,
+                row_mask=None if row_mask is None else row_mask.reshape(B * L),
+            )
+            self.sow("intermediates", "moe_stats", stats)
+            self.sow("intermediates", "moe_chosen", chosen.reshape(B, L, -1))
+            y = y.reshape(B, L, D)
+            if cfg.shared_d_ff:
+                y = y + MLP(cfg, cfg.shared_d_ff, name="shared_expert")(x)
+        return y
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
+    spec: Optional[LayerSpec] = None  # `cfg.layer(i)`; None: no pattern
 
     @nn.compact
     def __call__(
         self, x, cos, sin, decode: bool = False, positions=None,
-        block_tables=None,
+        block_tables=None, row_mask=None,
     ):
         cfg = self.cfg
-        x = x + Attention(cfg, name="attn")(
+        x = x + Attention(cfg, self.spec, name="attn")(
             RMSNorm(cfg.norm_eps, name="attn_norm")(x), cos, sin, decode,
             positions, block_tables,
         )
+        h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
+        if self.spec is not None and self.spec.mlp == "sparse":
+            return x + SparseMoE(cfg, name="mlp")(h, row_mask)
         mlp_cls = MoE if cfg.n_experts > 0 else MLP
-        x = x + mlp_cls(cfg, name="mlp")(RMSNorm(cfg.norm_eps, name="mlp_norm")(x))
-        return x
+        return x + mlp_cls(cfg, name="mlp")(h)
 
 
 class TransformerLM(nn.Module):
@@ -492,7 +778,7 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(
         self, tokens, decode: bool = False, positions=None,
-        block_tables=None,
+        block_tables=None, row_mask=None,
     ):
         """tokens: (B, L) int32 → logits (B, L, vocab) fp32.
 
@@ -506,12 +792,24 @@ class TransformerLM(nn.Module):
         int32, with `positions`) additionally switches the cache to the
         serve engine's PAGED block pool (`serve/cache.py`): one
         (num_blocks, block_size, kv_heads, head_dim) K/V pool per layer
-        shared by all rows, indexed through per-row block tables."""
+        shared by all rows, indexed through per-row block tables.
+
+        A model with a layer pattern (`cfg.layers`) builds each block
+        from its `LayerSpec` and hands it the rope table of its own
+        `RopeSpec`. Where some layers keep a window of K/V only,
+        `block_tables` is the PAIR (full layers' tables, window layers'
+        tables) of `serve/cache.py` and each layer takes its kind's.
+        `row_mask` ((B, L) bool, optional) marks the rows that are real
+        tokens for the sparse MLPs (see `SparseMoE`)."""
         cfg = self.cfg
         x = nn.Embed(
             cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="tok_embed"
         )(tokens)
         rope_len = cfg.max_seq_len if decode else tokens.shape[1]
+        if cfg.layers is not None:
+            return self._patterned(
+                x, rope_len, decode, positions, block_tables, row_mask
+            )
         cos, sin = rope_freqs(cfg.head_dim, rope_len, cfg.rope_theta)
         # remat path: `decode` must NOT flow through nn.remat as a traced
         # positional (TracerBoolConversionError at `if decode:`); the
@@ -525,11 +823,40 @@ class TransformerLM(nn.Module):
                 x = block_cls(cfg, name=f"layers_{i}")(
                     x, cos, sin, decode, positions, block_tables
                 )
+        return self._head(x)
+
+    @nn.nowrap
+    def _head(self, x):
+        cfg = self.cfg
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
         logits = nn.Dense(
             cfg.vocab_size, use_bias=False, dtype=cfg.dtype, name="lm_head"
         )(x)
         return logits.astype(jnp.float32)
+
+    @nn.nowrap
+    def _patterned(self, x, rope_len, decode, positions, block_tables, row_mask):
+        """The blocks of a model with a layer pattern, then the head."""
+        cfg = self.cfg
+        specs = [cfg.layer(i) for i in range(cfg.n_layers)]
+        tables = {
+            rope: rope_table(rope, cfg.head_dim, rope_len)
+            for rope in {spec.rope for spec in specs}
+        }
+        paired = isinstance(block_tables, (tuple, list))
+        use_remat = cfg.remat and not decode
+        block_cls = nn.remat(Block) if use_remat else Block
+        for i, spec in enumerate(specs):
+            cos, sin = tables[spec.rope]
+            block = block_cls(cfg, spec, name=f"layers_{i}")
+            if use_remat:  # see __call__: `decode` stays a Python default
+                x = block(x, cos, sin)
+                continue
+            bt = block_tables
+            if paired:
+                bt = block_tables[int(spec.attention == "window")]
+            x = block(x, cos, sin, decode, positions, bt, row_mask)
+        return self._head(x)
 
 
 def sharding_rules(

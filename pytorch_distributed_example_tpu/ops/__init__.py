@@ -7,6 +7,7 @@ from . import quant  # noqa: F401
 from .flash_attention import (  # noqa: F401
     flash_attention,
     gather_paged_kv,
+    paged_window_span,
     partitioned_over,
 )
 from .paged_attention import (  # noqa: F401

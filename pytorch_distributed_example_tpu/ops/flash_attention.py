@@ -874,9 +874,23 @@ def flash_attention(
     return shard_map_fn(local, jmesh, (spec, spec, spec), spec)(q, k, v)
 
 
+def paged_window_span(positions, L: int, window: int, bs: int, nb: int):
+    """Which blocks of its table a window layer's call has to gather:
+    (first_block (B,) int32, n_blocks). Row b's L queries sit at
+    `positions[b] .. positions[b] + L - 1` and attend keys from
+    `positions[b] - window + 1` on, so `n_blocks` blocks from the one
+    that holds that key cover them all: `window + L - 1` keys and the
+    partial blocks at both ends. `first_block` is cut so that the span
+    stays inside the table's `nb` blocks; key j of the gathered layout
+    is absolute position `first_block * bs + j`."""
+    n_blocks = min(nb, -(-(window + L - 1) // bs) + 1)
+    first_key = jnp.maximum(positions.astype(jnp.int32) - (window - 1), 0)
+    return jnp.minimum(first_key // bs, nb - n_blocks), n_blocks
+
+
 def gather_paged_kv(
     pool_k, pool_v, block_tables, k_scale=None, v_scale=None,
-    out_dtype=None,
+    out_dtype=None, first_block=None, n_blocks=None,
 ):
     """Materialize each row's LOGICAL K/V layout from a paged block pool.
 
@@ -903,8 +917,18 @@ def gather_paged_kv(
     payload back to `out_dtype` (the attention math dtype), so nothing
     downstream ever sees quantized values. The scale gather shards the
     same way on the KV-head axis under TP.
+
+    `first_block` ((B,) int32) with `n_blocks` (static) gathers only
+    that span of each row's table — a window layer's chunk moves the
+    `window + chunk` keys it can attend (`paged_window_span`), not the
+    table's span. Entries of the span that are invalid (freed behind the
+    window, or not yet allocated) clamp like any other.
     """
     nblk, bs, KV, Dh = pool_k.shape
+    if first_block is not None:
+        block_tables = jax.vmap(
+            lambda row, start: jax.lax.dynamic_slice(row, (start,), (n_blocks,))
+        )(block_tables, first_block)
     B, nb = block_tables.shape
 
     def one(pool, scale):

@@ -24,6 +24,13 @@ Shape of the kernel (design per /opt/skills/guides/pallas_guide.md):
   length: a parked lane (all-invalid table row, length M-1) has no work
   item, reads no page and returns zeros; an invalid entry past a live
   row's length is never read.
+* A WINDOW (`window=`, a layer that attends the last `window` keys):
+  the row's first page is the one that holds its first attended key
+  (`page0`), keys before that key in the page are masked (`lo`), and the
+  row costs min(length, window) keys. Entries before `page0` are never
+  read and may be invalid (the serve cache frees them while the request
+  lives). Without a window the scalars, the body and the compiled
+  kernel are what they were before windows existed.
 * All query heads meet all KV heads of a page in one MXU call: scores
   are (H, keys * KV) with column c = key * KV + kv_head, and the columns
   of another group's KV head are masked like keys past the length. That
@@ -97,8 +104,9 @@ def paged_decode_ok(L: int, pool, block_tables) -> bool:
     a multiple of the 128 lanes; a page whose `bs * KV` rows of `Dh` (KV
     as one device holds it) fill whole sublane tiles of the pool dtype
     (8 rows of float32, 16 of bfloat16), so page copies land
-    tile-aligned in the VMEM buffer; tables and work list within the
-    scalar memory they are prefetched into."""
+    tile-aligned in the VMEM buffer; tables and work list (with the two
+    scalars a row that a window layer adds) within the scalar memory
+    they are prefetched into."""
     _, bs, KV, Dh = pool.shape
     B, nb = block_tables.shape
     itemsize = jnp.dtype(pool.dtype).itemsize
@@ -110,39 +118,50 @@ def paged_decode_ok(L: int, pool, block_tables) -> bool:
         L == 1
         and Dh % 128 == 0
         and rows % (32 // itemsize) == 0
-        and 4 * (B * nb + 2 * items + 2 * B + 1) <= SMEM_BYTES
+        and 4 * (B * nb + 2 * items + 4 * B + 1) <= SMEM_BYTES
     )
 
 
-def _work_list(block_tables, lengths, nblk, bs, P):
+def _work_list(block_tables, lengths, nblk, bs, P, window=None):
     """Scalar side of the kernel, in plain XLA (tiny, identical in every
-    layer of a step, so the compiler keeps one copy): per row the pages
-    to read — bounded by the length AND by the leading valid entries —
-    and the last position attended; then the flat (row, block) list."""
+    layer of a kind in a step, so the compiler keeps one copy): per row
+    the pages to read — bounded by the length AND by the leading valid
+    entries — and the last position attended; then the flat (row, block)
+    list. With a `window`, also each row's first page `page0` (pages are
+    counted from it, entries before it count as valid whatever they
+    hold) and `lo`, the first attended key's offset in that page."""
     B, nb = block_tables.shape
     block_tables = block_tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
     valid = block_tables < nblk
+    if window is not None:
+        first = jnp.clip(lengths - (window - 1), 0, nb * bs - 1)
+        page0 = first // bs
+        valid |= jnp.arange(nb)[None, :] < page0[:, None]
     lead = jnp.sum(jnp.cumprod(valid, axis=1, dtype=jnp.int32), axis=1)
     n_pages = jnp.minimum(lead, jnp.clip(lengths // bs + 1, 0, nb))
     last = jnp.minimum(lengths, n_pages * bs - 1)  # -1 on a parked row
+    if window is not None:
+        n_pages = jnp.maximum(n_pages - page0, 0)  # from page0 on
+        last = last - page0 * bs  # relative to page0's first key
+        extra = (page0, first - page0 * bs)
     n_blocks = (n_pages + P - 1) // P
     ends = jnp.cumsum(n_blocks)
     ids = jnp.arange(B * -(-nb // P), dtype=jnp.int32)
     row = jnp.sum(ids[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
     row = jnp.minimum(row, B - 1)  # ids past the list: never read
     blk = ids - (ends - n_blocks)[row]
-    return block_tables.reshape(B * nb), n_pages, last, row, blk, ends[-1:]
+    scalars = (block_tables.reshape(B * nb), n_pages, last, row, blk, ends[-1:])
+    return scalars if window is None else scalars + extra
 
 
-def _kernel(
-    tables_ref, n_pages_ref, last_ref, item_row_ref, item_blk_ref,
-    n_items_ref,  # scalar prefetch
-    q_ref, k_hbm, v_hbm,  # inputs
-    o_ref,  # output
-    kbuf, vbuf, sems, colpos, m_s, l_s, acc_s,  # scratch
-    *, scale, nb, P, KV,
-):
+def _kernel(*refs, scale, nb, P, KV, windowed):
+    # scalar prefetch (two more with a window), inputs, output, scratch
+    (tables_ref, n_pages_ref, last_ref, item_row_ref, item_blk_ref,
+     n_items_ref) = refs[:6]
+    page0_ref, lo_ref = refs[6:8] if windowed else (None, None)
+    (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, colpos, m_s, l_s,
+     acc_s) = refs[8 if windowed else 6:]
     H = q_ref.shape[1]
     rows = k_hbm.shape[1]  # bs * KV rows of Dh a page
     R = P * rows
@@ -176,6 +195,8 @@ def _kernel(
         row = item_row_ref[item]
         first = item_blk_ref[item] * P
         have = jnp.minimum(n_pages_ref[row] - first, P)
+        if windowed:
+            first = first + page0_ref[row]
 
         def one(i, carry):
             page = tables_ref[row * nb + first + i]
@@ -217,7 +238,12 @@ def _kernel(
             preferred_element_type=jnp.float32,
         ) * scale  # (H, R)
         limit = (last_ref[row] - blk * T + 1) * KV
-        s = jnp.where(colpos[...] < limit, s, NEG_INF)
+        keep = colpos[...] < limit
+        if windowed:
+            # the first block starts before the window: its leading keys
+            # (a foreign head's sentinel passes here and fails `limit`)
+            keep &= colpos[...] >= jnp.where(blk == 0, lo_ref[row], 0) * KV
+        s = jnp.where(keep, s, NEG_INF)
         m_prev = m_s[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)  # masked: exp(-1e30 - m) == 0 exactly
@@ -238,8 +264,10 @@ def _kernel(
     lax.fori_loop(0, n_items, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _per_device(q, pool_k, pool_v, block_tables, lengths, *, scale, interpret):
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
+def _per_device(
+    q, pool_k, pool_v, block_tables, lengths, *, scale, interpret, window=None
+):
     """The kernel call on one device's operands. A `jax.jit` of its own
     so that the layers of a step share ONE trace and ONE lowering of the
     kernel: traced per layer, 16 layers cost 25 s of host time in every
@@ -251,11 +279,13 @@ def _per_device(q, pool_k, pool_v, block_tables, lengths, *, scale, interpret):
     nb = block_tables.shape[1]
     P = _pages_per_block(bs, nb)
     rows = bs * KV
-    scalars = _work_list(block_tables, lengths, nblk, bs, P)
+    scalars = _work_list(block_tables, lengths, nblk, bs, P, window)
     vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = lambda: pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, nb=nb, P=P, KV=KV),
+        functools.partial(
+            _kernel, scale=scale, nb=nb, P=P, KV=KV, windowed=window is not None
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(1,),
@@ -284,7 +314,8 @@ def _per_device(q, pool_k, pool_v, block_tables, lengths, *, scale, interpret):
 
 
 def paged_decode_attention(
-    q, pool_k, pool_v, block_tables, lengths, scale=None, *, interpret=None
+    q, pool_k, pool_v, block_tables, lengths, scale=None, *, interpret=None,
+    window=None,
 ):
     """One decode token a row against the paged block pool.
 
@@ -295,6 +326,9 @@ def paged_decode_attention(
     step's own token included, which `kv_scatter` wrote first — through
     its leading valid table entries; returns (B, H, Dh) in q's dtype.
     GQA: query head j reads KV head j // (H // KV), un-repeated.
+    `window` (static int): row b attends only the last `window` of those
+    keys, positions > lengths[b] - window; table entries wholly before
+    them are not read and may be invalid.
 
     Under `ops.partitioned_over(mesh, batch_axes, head_axes)` the call
     runs per device through a `shard_map` — q split on heads and the
@@ -310,7 +344,9 @@ def paged_decode_attention(
         scale = 1.0 / math.sqrt(Dh)
     if interpret is None:
         interpret = _interpret_default()
-    local = functools.partial(_per_device, scale=scale, interpret=interpret)
+    local = functools.partial(
+        _per_device, scale=scale, interpret=interpret, window=window
+    )
     if _partition.spec is None:
         return local(q, pool_k, pool_v, block_tables, lengths)
     # rows stay whole on every device (a serve step shards no batch axis)

@@ -15,6 +15,13 @@ follows the standard top-k token-choice recipe (Switch/GShard family):
   load-balancing auxiliary loss from the Switch Transformer.
 
 Entry points:
+  * `dropless_moe(...)` — the DROPLESS layer of a patterned model
+    (`models/transformer.py::SparseMoE`): every assignment is computed,
+    grouped by expert, through a grouped matmul (`grouped_swiglu`: the
+    Pallas `gmm` kernel where `grouped_kernel_ok`, `jax.lax.ragged_dot`
+    elsewhere); told which experts it holds, it routes over all and
+    returns its own experts' part. No capacity, no exchange (one chip
+    holds what it is told it holds);
   * `moe_mlp(...)` — plain function usable inside any shard_map over an
     ``ep`` axis (what `dryrun_multichip` and the tests exercise);
   * `make_ep_moe(mesh, ...)` — jit-ready sharded wrapper;
@@ -146,6 +153,153 @@ def moe_mlp(
     if axis_name is not None and ep > 1:
         aux = lax.pmean(aux, axis_name)  # replicated aux for the loss term
     return out.astype(x.dtype), aux
+
+
+# Tiles of the grouped-matmul kernel: rows of one group, contraction,
+# columns. Measured on a TPU v5 lite (PERF.md section 6, PR 27) at 256
+# experts of 2048 x 512 in bfloat16: (128, 512, 512) read the hit experts'
+# weights at 427 GB/s with 256 rows over 162 experts and 423 GB/s with 4096
+# rows over 256, `ragged_dot` (XLA's own call at 256-row tiles) at 360 and
+# 275; (128, 2048, 512) gave 388 / 392, (512, 512, 512) 271.
+GMM_TILING = (128, 512, 512)
+
+
+def grouped_kernel_ok(rows: int, d_in: int, d_mid: int, dtype) -> bool:
+    """Whether `grouped_swiglu` runs the Pallas grouped-matmul kernel —
+    THE predicate, from shapes and dtype alone: whole row tiles (the
+    kernel refuses a ragged last one), both products' contraction and
+    column widths (`d_in` x `d_mid`, then `d_mid` x `d_in`) whole tiles,
+    and the 2-byte operands the tiling was measured with."""
+    import jax.numpy as jnp
+
+    tm, tk, tn = GMM_TILING
+    return (
+        rows % tm == 0
+        and d_in % tk == 0 and d_in % tn == 0
+        and d_mid % tk == 0 and d_mid % tn == 0
+        and jnp.dtype(dtype).itemsize == 2
+    )
+
+
+def grouped_swiglu(rows, w_gate, w_up, w_down, sizes):
+    """SwiGLU of each group's rows through its own expert: `rows` (N, D)
+    sorted by group, group g the next `sizes[g]` of them, through
+    `w_gate[g]`, `w_up[g]` (D, F) and `w_down[g]` (F, D). Float32 (N, D);
+    rows past the last group hold nothing to read.
+
+    Where `grouped_kernel_ok`, the three products are the Pallas `gmm`
+    kernel of `jax.experimental.pallas.ops.tpu.megablox` (a tile of
+    `GMM_TILING[0]` rows a group and row tile, only the groups that have
+    rows; a Mosaic call that keeps the caller's scope path in a device
+    trace, which XLA's rewrite of `ragged_dot` on a TPU does not);
+    elsewhere `jax.lax.ragged_dot`. Both accumulate in float32."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    if grouped_kernel_ok(rows.shape[0], w_gate.shape[1], w_gate.shape[2], rows.dtype):
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        from ..ops.flash_attention import _interpret_default
+
+        interpret = _interpret_default()
+
+        def dot(a, w):
+            # the kernel's products are one pass over 2-byte operands into
+            # float32, exact; an ambient `jax_default_matmul_precision`
+            # (the test harness pins "highest") would ask Mosaic for a
+            # float32 contraction of them, which it refuses
+            with jax.default_matmul_precision("default"):
+                return gmm(
+                    a, w, sizes, preferred_element_type=jnp.float32,
+                    tiling=GMM_TILING, interpret=interpret,
+                )
+    else:
+        dot = lambda a, w: lax.ragged_dot(
+            a, w, sizes, preferred_element_type=jnp.float32
+        )
+    h = jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up)
+    return dot(h.astype(rows.dtype), w_down)
+
+
+def dropless_moe(
+    x,
+    router_w,
+    w_gate,
+    w_up,
+    w_down,
+    *,
+    n_experts: int,
+    top_k: int,
+    scale: float = 1.0,
+    first_expert: int = 0,
+    row_mask=None,
+):
+    """Dropless top-k MoE over gated (SwiGLU) experts.
+
+    x: (T, D). router_w: (D, n_experts), the router's FULL width.
+    w_gate / w_up: (held, D, F), w_down: (held, F, D): the experts
+    `first_expert .. first_expert + held - 1`, the contiguous range this
+    caller holds. Every row scores all `n_experts` (softmax in float32),
+    takes its `top_k`, and weighs them by their probabilities normalised
+    to sum to 1, times `scale`; the result is the part of
+    sum_e w_e * SwiGLU_e(x) that the held experts give (all of it when
+    all are held; the parts of disjoint ranges add up to the whole).
+    Weights multiply expert OUTPUTS.
+
+    Nothing is dropped and there is no capacity: the T * top_k
+    assignments are sorted by expert and the three products run grouped
+    (`grouped_swiglu`), so an expert costs the rows it was given and an
+    expert no row chose is not read.
+
+    `row_mask` ((T,) bool): rows that are False have NO assignment: they
+    reach no expert, widen no group and count in no counter; their
+    output is zero.
+
+    Returns (y (T, D) in x's dtype, stats, chosen): stats is int32 (2,)
+    = (assignments computed here, distinct held experts with >= 1 row);
+    chosen is the (T, top_k) experts each row's router picked, masked
+    rows included (a comparison with a reference counts routing flips
+    from it; a program that does not fetch it pays nothing).
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    T, D = x.shape
+    held = w_gate.shape[0]
+    with jax.named_scope("router"):
+        logits = jnp.dot(
+            x.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST,
+        )  # (T, n_experts)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = lax.top_k(probs, top_k)  # (T, k)
+        weight = scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    with jax.named_scope("dispatch"):
+        local = top_e - first_expert
+        mine = (local >= 0) & (local < held)
+        if row_mask is not None:
+            mine &= row_mask[:, None]
+        # group `held` is nowhere: sorted last, in no group's size
+        group = jnp.where(mine, local, held).reshape(T * top_k)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+        rows = x[order // top_k]  # (T * k, D), by expert
+        w_sorted = jnp.where(mine, weight, 0.0).reshape(T * top_k)[order]
+        placed = jnp.arange(T * top_k) < jnp.sum(sizes)
+    with jax.named_scope("experts"):
+        out = grouped_swiglu(rows, w_gate, w_up, w_down, sizes)  # float32
+    with jax.named_scope("dispatch"):
+        # rows past the last group belong to no product: whatever the
+        # grouped product left there is not a number to weigh
+        out = jnp.where(placed[:, None], out, 0.0) * w_sorted[:, None]
+        back = jnp.zeros((T * top_k,), jnp.int32).at[order].set(
+            jnp.arange(T * top_k, dtype=jnp.int32)
+        )
+        y = out[back].reshape(T, top_k, D).sum(axis=1)
+        stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return y.astype(x.dtype), stats, top_e
 
 
 def make_ep_moe(

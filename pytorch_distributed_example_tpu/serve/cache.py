@@ -20,6 +20,20 @@ Two layouts live here:
   write, dequant inside `ops.gather_paged_kv` so attention math stays
   full precision.
 
+  TWO KINDS of K/V state (a model whose `cfg.window_layers` marks some
+  layers as keeping a window): the full layers share the pool and the
+  tables described above; the WINDOW layers share a second, small pool
+  (`window_num_blocks` blocks, sized by the manager from the slots, the
+  window, the prefill chunk and the block size: a row never holds more
+  than `window_blocks_per_slot`) under a second table of the same
+  logical shape. A window-layer block wholly behind `position - window`
+  goes back to the window free list WHILE the request runs
+  (`ensure_blocks(..., first_pos=)`), its table entry turns invalid, and
+  the attention paths never read before a row's first attended block.
+  `tables()` hands the programs the pair; `free`, `bytes_live` and the
+  live-block counts account both kinds. A model with no window layer
+  gets exactly the single-kind tree, tables and accounting.
+
   Physical blocks are REFCOUNTED (ISSUE 12): `attach_prefix` lets a
   slot reference blocks another request already filled (the prefix
   cache, `serve/prefix.py`), `free()` DECREMENTS instead of releasing
@@ -215,10 +229,19 @@ class SlotKVCache:
         )
 
 
+def window_layers_of(cfg) -> tuple:
+    """Per layer, whether it keeps a window of K/V only (a model
+    configuration that says nothing keeps the whole context in all)."""
+    return tuple(getattr(cfg, "window_layers", ())) or (False,) * cfg.n_layers
+
+
 def init_paged_cache(model, num_blocks: int, block_size: int,
-                     quantized: bool = False):
+                     quantized: bool = False,
+                     window_blocks: Optional[int] = None):
     """Empty paged K/V pool tree for `model`: per layer one
-    (num_blocks, block_size, kv_heads, head_dim) K and V. Mirrors
+    (num_blocks, block_size, kv_heads, head_dim) K and V — of
+    `window_blocks` blocks instead in a layer the model's pattern marks
+    as a window layer. Mirrors
     `models.generate.init_cache`'s structure minus the scalar "index"
     leaf (a shared pool has no per-row cursor).
 
@@ -234,8 +257,11 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
 
     cfg = model.cfg
     KV, Dh = cfg.kv_heads, cfg.head_dim
+    windowed = window_layers_of(cfg)
+    if any(windowed) and window_blocks is None:
+        raise ValueError("a model with window layers needs window_blocks")
 
-    def one_layer():
+    def one_layer(num_blocks):
         if quantized:
             return {
                 "attn": {
@@ -260,7 +286,10 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
             }
         }
 
-    return {f"layers_{i}": one_layer() for i in range(cfg.n_layers)}
+    return {
+        f"layers_{i}": one_layer(window_blocks if windowed[i] else num_blocks)
+        for i in range(cfg.n_layers)
+    }
 
 
 class PagedKVCache:
@@ -299,6 +328,7 @@ class PagedKVCache:
         num_blocks: Optional[int] = None,
         block_size: int = 16,
         quantized: bool = False,
+        chunk_tokens: Optional[int] = None,
     ):
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
@@ -306,6 +336,9 @@ class PagedKVCache:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         cfg = model.cfg
         M = cfg.max_seq_len
+        windowed = window_layers_of(cfg)
+        self.window_layers = sum(windowed)
+        self.full_layers = cfg.n_layers - self.window_layers
         self.model = model
         self.slots = slots
         self.block_size = block_size
@@ -323,9 +356,32 @@ class PagedKVCache:
             )
         self.num_blocks = num_blocks
         self.invalid_block = num_blocks  # OOB sentinel the paged path drops
+        # the window kind: a row holds the blocks of the `window - 1` keys
+        # behind its next write and of the longest write (`chunk_tokens`;
+        # None = a whole prompt in one program), a partial block at each
+        # end: one more block than the longest span a gather takes
+        self.window = cfg.window if self.window_layers else None
+        self.window_blocks_per_slot = self.window_num_blocks = 0
+        if self.window_layers:
+            span = self.window + min(chunk_tokens or M, M)
+            self.window_blocks_per_slot = min(
+                -(-span // block_size) + 2, self.blocks_per_seq
+            )
+            self.window_num_blocks = slots * self.window_blocks_per_slot
+        self.window_invalid_block = self.window_num_blocks
         self.tree = init_paged_cache(
-            model, num_blocks, block_size, quantized=quantized
+            model, num_blocks, block_size, quantized=quantized,
+            window_blocks=self.window_num_blocks or None,
         )
+        self.window_tables = np.full(
+            (slots, self.blocks_per_seq), self.window_invalid_block, np.int32
+        )
+        self._window_free: List[int] = list(range(self.window_num_blocks))
+        # per slot: logical block -> physical window block, in order
+        self._window_slot_blocks: List[Dict[int, int]] = [
+            {} for _ in range(slots)
+        ]
+        self.window_blocks_recycled = 0  # freed while their request ran
         self.block_tables = np.full(
             (slots, self.blocks_per_seq), self.invalid_block, np.int32
         )
@@ -369,6 +425,9 @@ class PagedKVCache:
             n += self._decref(b)
         self._slot_blocks[slot] = []
         self.block_tables[slot, :] = self.invalid_block
+        self._window_free.extend(self._window_slot_blocks[slot].values())
+        self._window_slot_blocks[slot] = {}
+        self.window_tables[slot, :] = self.window_invalid_block
         self._in_use[slot] = False
         self.lengths[slot] = 0
         self._free_slots.append(slot)
@@ -387,12 +446,20 @@ class PagedKVCache:
         """Blocks needed to hold `tokens` positions."""
         return -(-tokens // self.block_size)
 
-    def ensure_blocks(self, slot: int, upto_pos: int) -> bool:
+    def ensure_blocks(
+        self, slot: int, upto_pos: int, first_pos: Optional[int] = None
+    ) -> bool:
         """Grow `slot`'s table so position `upto_pos` is writable
         (allocate-on-write). All-or-nothing: returns False — allocating
         NOTHING — when the reclaimable set (plain free list + cached
         prefix blocks) can't cover the growth; the engine turns that
-        into backpressure or preemption."""
+        into backpressure or preemption.
+
+        With window layers the window table grows to `upto_pos` too;
+        `first_pos` is the position of the first query of the write this
+        call prepares (the chunk's start, the decode token's position):
+        window blocks wholly behind `first_pos - window` are recycled
+        first. The window pool is sized so that this never fails."""
         if not self._in_use[slot]:
             raise ValueError(f"slot {slot} is not allocated")
         if not 0 <= upto_pos < self.blocks_per_seq * self.block_size:
@@ -402,8 +469,6 @@ class PagedKVCache:
             )
         have = len(self._slot_blocks[slot])
         need = upto_pos // self.block_size + 1 - have
-        if need <= 0:
-            return True
         if need > self.free_blocks:
             return False
         for j in range(have, have + need):
@@ -411,7 +476,46 @@ class PagedKVCache:
             self._refcount[b] = 1
             self._slot_blocks[slot].append(b)
             self.block_tables[slot, j] = b
+        if self.window_layers:
+            self._ensure_window(slot, upto_pos, first_pos)
         return True
+
+    def _ensure_window(self, slot: int, upto_pos: int, first_pos) -> None:
+        """The window kind's half of `ensure_blocks`: recycle, then grow."""
+        bs, held = self.block_size, self._window_slot_blocks[slot]
+        # the earliest key any query from `first_pos` on attends is
+        # first_pos - window + 1: blocks wholly before it are not needed
+        keep_from = 0
+        if first_pos is not None:
+            keep_from = max(first_pos - self.window + 1, 0) // bs
+        for j in [j for j in held if j < keep_from]:
+            self._window_free.append(held.pop(j))
+            self.window_tables[slot, j] = self.window_invalid_block
+            self.window_blocks_recycled += 1
+        for j in range(max(max(held, default=-1) + 1, keep_from), upto_pos // bs + 1):
+            if len(held) >= self.window_blocks_per_slot or not self._window_free:
+                raise RuntimeError(
+                    f"slot {slot} would hold more than "
+                    f"{self.window_blocks_per_slot} window blocks: a write "
+                    "longer than the chunk the pool was sized for"
+                )
+            held[j] = b = self._window_free.pop(0)
+            self.window_tables[slot, j] = b
+
+    def tables(self, rows=slice(None), parked=()):
+        """The block tables the programs take for the given slots: the
+        (n, nb) table, or with window layers the pair (full layers',
+        window layers'). `parked` slots' rows are handed over all-invalid
+        (copies; the manager's tables are untouched)."""
+        out = [self.block_tables[rows]]
+        if self.window_layers:
+            out.append(self.window_tables[rows])
+        if len(parked):
+            out = [t.copy() for t in out]
+            out[0][parked] = self.invalid_block
+            if self.window_layers:
+                out[1][parked] = self.window_invalid_block
+        return tuple(out) if self.window_layers else out[0]
 
     # -- refcount plumbing -------------------------------------------------
     def _take_block(self) -> int:
@@ -563,6 +667,15 @@ class PagedKVCache:
         return self.num_blocks - self.free_blocks
 
     @property
+    def window_live_blocks(self) -> int:
+        """Window-layer blocks some slot holds (0 with no window layer)."""
+        return self.window_num_blocks - len(self._window_free)
+
+    def window_slot_blocks(self, slot: int) -> Dict[int, int]:
+        """logical block -> physical window block of a slot."""
+        return dict(self._window_slot_blocks[slot])
+
+    @property
     def cached_free_blocks(self) -> int:
         """Refcount-0 blocks kept alive only for the prefix index."""
         return len(self._cached_blocks)
@@ -613,9 +726,18 @@ class PagedKVCache:
             1 if self.quantized else np.dtype(cfg.dtype).itemsize
         )
         return (
-            2 * cfg.n_layers * self.block_size * cfg.kv_heads
+            2 * self.full_layers * self.block_size * cfg.kv_heads
             * cfg.head_dim * itemsize
         ) + self.scale_bytes_per_block
+
+    @functools.cached_property
+    def window_bytes_per_block(self) -> int:
+        """HBM bytes one window-kind block pins across the window layers."""
+        cfg = self.model.cfg
+        return (
+            2 * self.window_layers * self.block_size * cfg.kv_heads
+            * cfg.head_dim * np.dtype(cfg.dtype).itemsize
+        )
 
     @functools.cached_property
     def scale_bytes_per_block(self) -> int:
@@ -643,7 +765,10 @@ class PagedKVCache:
 
     @property
     def bytes_live(self) -> int:
-        return self.live_blocks * self.bytes_per_block
+        return (
+            self.live_blocks * self.bytes_per_block
+            + self.window_live_blocks * self.window_bytes_per_block
+        )
 
     @functools.cached_property
     def dense_bytes_per_request(self) -> int:

@@ -259,24 +259,46 @@ def paged_programs(
       rows are all-invalid, so their garbage writes are scatter-DROPPED
       (never in any live block), the kernel reads no page for them, and
       their sampled tokens are ignored by the scheduler.
+
+    Where some layers keep a window of K/V (`serve/cache.py`), `bt_row`
+    and `bt` are the pair (full layers' tables, window layers' tables).
+
+    A model with SPARSE layers (`cfg.sparse_layers`) is told which rows
+    are real, because a row that is not must route to no expert: in
+    `prefill_chunk` the engine pads a chunk with token id -1 (the rows
+    `chunk >= 0` are real; the padding embeds as token 0), in `step` a
+    row is live when its table row holds a valid block (the engine hands
+    parked and mid-prefill lanes over all-invalid).
+    Its `step` returns a FIFTH value, the step's one host readback:
+    int32 (S + 2 * sparse layers,) = the next tokens, then per sparse
+    layer (assignments computed, distinct experts with a row) — the
+    counters ride the transfer the scheduler makes anyway.
     """
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     M = model.cfg.max_seq_len
+    sparse = tuple(getattr(model.cfg, "sparse_layers", ()))
 
-    def apply_paged(params, tree, tokens, positions, bt):
+    def apply_paged(params, tree, tokens, positions, bt, row_mask=None):
+        kw, mutable = {}, ["cache"]
+        if sparse:
+            kw, mutable = {"row_mask": row_mask}, ["cache", "intermediates"]
         with _kernel_partition(mesh, tp_axis):
             return model.apply(
                 {"params": params, "cache": tree}, tokens, decode=True,
-                positions=positions, block_tables=bt, mutable=["cache"],
+                positions=positions, block_tables=bt, mutable=mutable, **kw,
             )
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill_chunk(params, tree, chunk, bt_row, start):
+        row_mask = None
+        if sparse:
+            row_mask, chunk = chunk >= 0, jnp.maximum(chunk, 0)
         logits, vars2 = apply_paged(
-            params, tree, chunk, jnp.asarray(start, jnp.int32)[None], bt_row
+            params, tree, chunk, jnp.asarray(start, jnp.int32)[None], bt_row,
+            row_mask,
         )
         return vars2["cache"], logits[0]  # (C, V)
 
@@ -312,20 +334,34 @@ def paged_programs(
         with jax.named_scope("sample"):
             split = jax.vmap(jax.random.split)(rngs)  # (S, 2, 2)
             subs, new_rngs = split[:, 0], split[:, 1]
+        row_mask = None
+        if sparse:
+            # a live row holds a block; any layer's kind of table says so
+            kinds = model.cfg.window_layers
+            table = bt[int(kinds[0])] if isinstance(bt, (tuple, list)) else bt
+            pool = tree["layers_0"]["attn"]["k"]
+            row_mask = jnp.any(table < pool.shape[0], axis=1, keepdims=True)
         logits, vars2 = apply_paged(
-            params, tree, tokens[:, None], lengths, bt
+            params, tree, tokens[:, None], lengths, bt, row_mask
         )
         lg = logits[:, -1]  # (S, V)
         with jax.named_scope("sample"):
             nxt = jax.vmap(
                 lambda row, key: sample_logits(row, key, temperature, top_k)
             )(lg, subs)
-        return (
+        out = (
             vars2["cache"],
             jnp.minimum(lengths + 1, M - 1),
             nxt,
             new_rngs,
         )
+        if not sparse:
+            return out
+        stats = [
+            vars2["intermediates"][f"layers_{i}"]["mlp"]["moe_stats"][0]
+            for i in sparse
+        ]
+        return out + (jnp.concatenate([nxt.astype(jnp.int32), *stats]),)
 
     return _register_programs(
         "paged",

@@ -58,6 +58,19 @@ list. Outputs are token-exact with sharing on or off: a cached block
 holds exactly the K/V the attaching request would have recomputed
 (same tokens, same absolute positions, same params).
 
+Layer patterns (`TransformerConfig.layers`): a model whose pattern
+keeps a WINDOW of K/V in some layers is served from two pools under two
+tables (`serve/cache.py`): the window pool is sized here from `slots`,
+the window, `prefill_chunk_tokens` and `block_size`, and a row's window
+blocks behind `position - window` are recycled while it runs. A model
+with SPARSE (dropless MoE) layers has its parked lanes and chunk padding
+route nowhere, and every decode step brings back, in its one readback,
+the assignments computed and the distinct experts hit per sparse layer
+(`ServeMetrics.record_moe_step`; also a `serve:moe_step` host annotation
+while a profiler trace is on). What is not carried with window layers is
+refused at construction: prefix sharing, the int8 pool, a tp mesh,
+disaggregated roles and pre-warmed executables.
+
 Fault surface: `serve.admit` before each admission, `serve.
 prefix_attach` before a prefix-cache attach, `serve.prefill_chunk`
 before each prompt chunk, `serve.step` before each decode batch,
@@ -110,7 +123,7 @@ from .. import faults
 from ..numerics import numerics_contract
 from ..types import DistError
 from .bucketing import bucket_for, bucket_lengths
-from .cache import PagedKVCache
+from .cache import PagedKVCache, window_layers_of
 from .decode import paged_programs, step_runs_kernel, sync_slot_lanes
 from .metrics import ServeMetrics
 from .queue import (
@@ -200,13 +213,34 @@ class ServeEngine:
         self.model = model
         self.params = params["params"] if "params" in params else params
         self.cfg = model.cfg
+        if any(window_layers_of(self.cfg)):
+            refused = {
+                "prefix_cache=True (a shared prefix's window-layer blocks "
+                "are recycled under its other holders)": prefix_cache,
+                "kv_quant=True (an int8 pool of two kinds of blocks is untested)":
+                    kv_quant,
+                "mesh= (the window pool and the windowed decode kernel are "
+                "not partitioned over tp)": mesh is not None,
+                f"role={role!r} (block migration moves one kind of block)":
+                    role != "both",
+                "precompiled= (pre-warmed programs take one table)":
+                    bool(precompiled),
+            }
+            for what, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"a model with window layers cannot be served with {what}"
+                    )
+        # sparse (dropless MoE) layers: padding routes nowhere, and the
+        # decode step's readback carries two counters a layer
+        self._sparse_layers = len(getattr(self.cfg, "sparse_layers", ()))
         self.temperature = temperature
         self.top_k = top_k
         self.eos_id = eos_id
         self.clock = clock
         self.cache = PagedKVCache(
             model, slots, num_blocks=pool_blocks, block_size=block_size,
-            quantized=kv_quant,
+            quantized=kv_quant, chunk_tokens=prefill_chunk_tokens,
         )
         # prefix sharing: radix index over the refcounted pool — OPT-IN
         # (off keeps PR 6 pool semantics and accounting bit-for-bit)
@@ -665,7 +699,7 @@ class ServeEngine:
                 if spent and spent + C > budget:
                     return  # budget spent: yield to decode
             end = min(pf.pos + C, L)
-            if not self._ensure_or_preempt(slot, end - 1):
+            if not self._ensure_or_preempt(slot, end - 1, pf.pos):
                 continue  # the prefilling request itself got evicted
             if not self._cow_or_preempt(slot, pf.pos):
                 continue  # ditto, while claiming a copy-on-write block
@@ -674,13 +708,15 @@ class ServeEngine:
             except _TRANSIENT:
                 self._evict(slot, requeue_counter=True)
                 continue
-            chunk = np.zeros((1, C), np.int32)
+            # padding is token 0, or -1 where sparse layers must tell it
+            # from a token (`serve/decode.py::paged_programs`)
+            chunk = np.full((1, C), -1 if self._sparse_layers else 0, np.int32)
             chunk[0, : end - pf.pos] = req.prompt[pf.pos:end]
             self.cache.tree, logits = self._prefill_chunk(
                 self.params,
                 self.cache.tree,
                 jnp.asarray(chunk),
-                self.cache.block_tables[slot : slot + 1],
+                self.cache.tables(slice(slot, slot + 1)),
                 pf.pos,
             )
             start = pf.pos
@@ -784,13 +820,17 @@ class ServeEngine:
         self.metrics.record_preempt(klass=klass)
         return victim != slot
 
-    def _ensure_or_preempt(self, slot: int, upto_pos: int) -> bool:
+    def _ensure_or_preempt(
+        self, slot: int, upto_pos: int, first_pos: int
+    ) -> bool:
         """Grow `slot`'s block table to cover `upto_pos`, evicting via
         `_preempt_for_pool` while the pool is dry. Returns False when
         the grower itself got evicted. Deadlock-free: submit()
         guarantees any single request's worst case fits the pool, so
-        the oldest request of the best class always wins."""
-        while not self.cache.ensure_blocks(slot, upto_pos):
+        the oldest request of the best class always wins. `first_pos`
+        is where the write being prepared starts: a window layer's
+        blocks behind its window are recycled (`serve/cache.py`)."""
+        while not self.cache.ensure_blocks(slot, upto_pos, first_pos):
             if not self._preempt_for_pool(slot):
                 return False
         return True
@@ -863,6 +903,9 @@ class ServeEngine:
             cow_copies=self.cache.cow_copies,
             bytes_deduplicated=self.cache.bytes_deduplicated,
             prefix_stats=self.prefix.stats() if self.prefix else None,
+            window_blocks_live=self.cache.window_live_blocks,
+            window_blocks_recycled=self.cache.window_blocks_recycled,
+            window_bytes_per_block=self.cache.window_bytes_per_block,
         )
         while True:
             self._prefill_tick()
@@ -886,7 +929,8 @@ class ServeEngine:
         for s in sorted(self._decoding):
             if s not in self._decoding:  # evicted by an earlier growth
                 continue
-            if not self._ensure_or_preempt(s, int(self.cache.lengths[s])):
+            at = int(self.cache.lengths[s])
+            if not self._ensure_or_preempt(s, at, at):
                 continue
             # first decode write past a shared/indexed prefix boundary
             # must own a private copy of that block (CoW)
@@ -902,29 +946,30 @@ class ServeEngine:
         # higher stakes: their blocks are the migration payload, and a
         # parked-lane write would corrupt KV mid-flight. Retired rows
         # are already all-invalid via free().
-        bt = self.cache.block_tables
         frozen = sorted(self._prefilling) + sorted(
             h.slot for h in self._handoff
         )
-        if frozen:
-            bt = bt.copy()
-            bt[frozen] = self.cache.invalid_block
         (
             self.cache.tree,
             self._dev_lengths,
             nxt,
             self._dev_rngs,
+            *readback,
         ) = self._step(
             self.params,
             self.cache.tree,
             self._dev_lengths,
             self._dev_tokens,
             self._dev_rngs,
-            bt,
+            self.cache.tables(parked=frozen),
         )
         self._dev_tokens = nxt
         self.metrics.record_decode_step(self._decode_kernel)
-        nxt_h = np.asarray(nxt)  # the hot path's one host readback
+        # the hot path's one host readback: the next tokens, and behind
+        # them a sparse model's counters
+        nxt_h = np.asarray(readback[0] if readback else nxt)
+        if readback:
+            self._record_moe_step(nxt_h[len(self._slot_req):], len(active))
         now = self.clock()
         for s in active:
             req = self._slot_req[s]
@@ -940,6 +985,22 @@ class ServeEngine:
             or bool(self._prefilling)
             or bool(self.queue)
         )
+
+    def _record_moe_step(self, counters, rows: int) -> None:
+        """One decode step's sparse-layer counters, (assignments, experts
+        hit) a layer: into the metrics, and — for a reader that pairs
+        them with the same steps' device time — onto the host line of a
+        profiler trace, where one is being taken (free otherwise)."""
+        import jax.profiler
+
+        assignments = counters[0::2].tolist()
+        hit = counters[1::2].tolist()
+        self.metrics.record_moe_step(sum(assignments), hit)
+        with jax.profiler.TraceAnnotation(
+            "serve:moe_step", rows=rows, assignments=sum(assignments),
+            **{f"hit{i}": h for i, h in enumerate(hit)},  # per sparse layer
+        ):
+            pass
 
     def run(self, max_steps: Optional[int] = None) -> Dict[str, Completion]:
         """Drive step() until the queue and slots drain (or max_steps);

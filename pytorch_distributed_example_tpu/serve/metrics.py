@@ -43,7 +43,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Dict, Iterable, Optional
+from typing import Dict, Iterable, List, Optional
 
 from .queue import DEFAULT_CLASS, ClassSpec
 
@@ -162,6 +162,22 @@ class ServeMetrics:
         # engine's pool: 100 % or 0 % for one engine's lifetime)
         self.decode_steps = 0
         self.decode_kernel_steps = 0
+        # sparse (dropless MoE) layers, per decode step, from the step's
+        # own readback: assignments computed (top_k x live rows x sparse
+        # layers: nothing is dropped) and the distinct experts with at
+        # least one row, a list with one entry per sparse layer
+        self.moe_steps = 0
+        self.moe_assignments = 0  # last step
+        self.moe_experts_hit: List[int] = []  # last step, per sparse layer
+        self.moe_assignments_total = 0
+        self._moe_hit_sum = 0.0  # of per-step means over the layers
+        # the two kinds of K/V state (`serve/cache.py`): the full layers'
+        # live blocks are `pool_blocks_live` (`full_blocks_live` on
+        # `/serve`); the window layers' gauge, and the window blocks handed
+        # back while their request ran (cumulative)
+        self.window_blocks_live = 0
+        self.window_bytes_per_block = 0
+        self.window_blocks_recycled = 0
         # paged-pool gauges (last observation) + time-mean accumulators
         self.pool_blocks_live = 0
         self.pool_blocks_total = 0
@@ -269,6 +285,15 @@ class ServeMetrics:
             self.decode_steps += 1
             self.decode_kernel_steps += bool(kernel)
 
+    def record_moe_step(self, assignments: int, experts_hit) -> None:
+        """One decode step of a model with sparse layers."""
+        with self._lock:
+            self.moe_steps += 1
+            self.moe_assignments = int(assignments)
+            self.moe_experts_hit = [int(h) for h in experts_hit]
+            self.moe_assignments_total += int(assignments)
+            self._moe_hit_sum += sum(experts_hit) / max(len(experts_hit), 1)
+
     def record_requeue(self, n: int = 1) -> None:
         with self._lock:
             self.requeued += n
@@ -330,6 +355,9 @@ class ServeMetrics:
         cow_copies: int = 0,
         bytes_deduplicated: int = 0,
         prefix_stats: Optional[Dict] = None,
+        window_blocks_live: int = 0,
+        window_blocks_recycled: int = 0,
+        window_bytes_per_block: int = 0,
     ) -> None:
         """Per-step paged-pool observation. Gauges keep the LAST value;
         utilization and bytes-per-live-request also accumulate a
@@ -345,6 +373,9 @@ class ServeMetrics:
         index is the ONE place hit/miss/reuse counting lives, so the
         two surfaces can never drift."""
         with self._lock:
+            self.window_blocks_live = window_blocks_live
+            self.window_blocks_recycled = window_blocks_recycled
+            self.window_bytes_per_block = window_bytes_per_block
             self.pool_blocks_live = blocks_live
             self.pool_blocks_total = blocks_total
             self.pool_bytes_per_block = bytes_per_block
@@ -377,8 +408,9 @@ class ServeMetrics:
                 )
             if live_requests > 0:
                 self._bytes_per_req_sum += (
-                    blocks_live * bytes_per_block / live_requests
-                )
+                    blocks_live * bytes_per_block
+                    + window_blocks_live * window_bytes_per_block
+                ) / live_requests
                 self._bytes_per_req_samples += 1
 
     def record_complete(
@@ -622,6 +654,15 @@ class ServeMetrics:
                         self.decode_kernel_steps / self.decode_steps, 4
                     ) if self.decode_steps else 0.0,
                 },
+                "moe": {
+                    "steps": self.moe_steps,
+                    "assignments": self.moe_assignments,
+                    "experts_hit": list(self.moe_experts_hit),
+                    "assignments_total": self.moe_assignments_total,
+                    "experts_hit_mean": round(
+                        self._moe_hit_sum / self.moe_steps, 3
+                    ) if self.moe_steps else 0.0,
+                },
                 "queue_depth": self.queue_depth,
                 "slots": self.slots,
                 "slots_active": self.slots_active,
@@ -643,6 +684,7 @@ class ServeMetrics:
                     "mean_utilization": round(mean_util, 4),
                     "bytes_live": (
                         self.pool_blocks_live * self.pool_bytes_per_block
+                        + self.window_blocks_live * self.window_bytes_per_block
                     ),
                     "bytes_per_live_request_mean": round(mean_bpr, 1),
                     "dense_bytes_per_request": self.dense_bytes_per_request,
@@ -657,6 +699,9 @@ class ServeMetrics:
                         self.scale_bytes_per_block * self.pool_blocks_total
                     ),
                     "effective_slots": self.effective_slots,
+                    "full_blocks_live": self.pool_blocks_live,
+                    "window_blocks_live": self.window_blocks_live,
+                    "window_blocks_recycled": self.window_blocks_recycled,
                 },
                 # prefix sharing (ISSUE 12): hit rate + tokens whose
                 # prefill compute/pool writes were skipped, block-level

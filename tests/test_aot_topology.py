@@ -177,6 +177,75 @@ def test_paged_decode_kernel_compiles_at_mistral_widths(topo, monkeypatch):
     assert len(pool_ops) == 2 and all(" bitcast(" in l for l in pool_ops)
 
 
+@pytest.mark.parametrize("heads,window,nblk", [(48, None, 16384), (64, 512, 2112)])
+def test_paged_decode_kernel_compiles_at_the_patterned_cell_s_widths(
+    topo, monkeypatch, heads, window, nblk
+):
+    """The same kernel as `serve_laguna_mixed_c32` calls it: 48 query heads
+    over the full layers' 16384-block pool, 64 with a 512-key window over
+    the window layers' 2112 blocks, 8 KV heads of 128, 32 rows."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_example_tpu.ops import paged_decode_attention
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    B, KV, Dh, bs, nb = 32, 8, 128, 16, 512
+    pool = sd((nblk, bs, KV, Dh), jnp.bfloat16)
+    hlo = jax.jit(functools.partial(paged_decode_attention, window=window)).lower(
+        sd((B, heads, Dh), jnp.bfloat16), pool, pool,
+        sd((B, nb), jnp.int32), sd((B,), jnp.int32),
+    ).compile().as_text()
+    (call,) = _custom_calls(hlo)
+    assert f"bf16[{B},{heads},{Dh}]" in call.split("custom-call(")[0]
+
+
+@pytest.mark.parametrize("rows", [32, 128, 256, 512])
+def test_the_grouped_expert_kernel_compiles_and_keeps_its_scope(topo, monkeypatch, rows):
+    """The sparse MLP as `serve_laguna_mixed_c32` runs it (256 experts of
+    2048 x 512 in bfloat16, top 8; a decode step's 32 rows and the three
+    chunk buckets): the three grouped products are Mosaic calls for v5e, and
+    each keeps `moe/experts` in its path, which the cell's MoE metrics
+    read. (`jax.lax.ragged_dot` compiles too, to XLA's own `ragged-dot-none`
+    call with no path: three quarters of the step would read as unscoped.)"""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_example_tpu.parallel.expert_parallel import (
+        dropless_moe,
+        grouped_kernel_ok,
+    )
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    D, F, E, K = 2048, 512, 256, 8
+    assert grouped_kernel_ok(rows * K, D, F, jnp.bfloat16)
+
+    def moe(x, router, w_gate, w_up, w_down, mask):
+        with jax.named_scope("moe"):
+            return dropless_moe(x, router, w_gate, w_up, w_down, n_experts=E, top_k=K,
+                                scale=2.5, row_mask=mask)
+
+    hlo = jax.jit(moe).lower(
+        sd((rows, D), jnp.bfloat16), sd((D, E), jnp.bfloat16),
+        sd((E, D, F), jnp.bfloat16), sd((E, D, F), jnp.bfloat16),
+        sd((E, F, D), jnp.bfloat16), sd((rows,), jnp.bool_),
+    ).compile().as_text()
+    calls = _custom_calls(hlo)
+    assert len(calls) == 3 and "ragged-dot" not in hlo
+    for call in calls:
+        assert re.search(r'op_name="[^"]*/moe/experts/[^"]*pallas_call', call), call[:300]
+
+
 def test_tp2_paged_decode_step_compiles_under_mesh(topo, monkeypatch):
     """A tp=2 engine's decode step: GSPMD partitions everything but the
     decode attention kernel, which the step's `ops.partitioned_over`

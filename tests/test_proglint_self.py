@@ -48,8 +48,18 @@ _CACHE = []
 
 
 def _pairs(world):
-    """One build per test session (traces + two tiny ddp steps)."""
+    """One build per test session (traces + two tiny ddp steps). The
+    planner's state outlives a test: the session's group keeps a
+    `planner_override` and the agreed table is process-global, so a planner
+    test that ran earlier in this worker can leave the ZeRO step tracing
+    ring `ppermute`s where its fingerprint expects `psum_scatter` /
+    `all_gather` (ROADMAP D0: which files share a worker is the
+    scheduler's choice). Build from the defaults."""
     if not _CACHE:
+        from pytorch_distributed_example_tpu import plan
+
+        plan.enable_for_group(world, None)  # defer to the environment: off
+        plan.traced.reset()
         _CACHE.append(proglint.build_repo_programs())
     return _CACHE[0]
 

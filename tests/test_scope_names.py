@@ -22,6 +22,7 @@ from pytorch_distributed_example_tpu.models.transformer import (
 
 # Flax names a method other than __call__ `<module>.<method>` in the path
 LAYER = r"TransformerLM\)*/layers_\d+/attn/(attn\.\w+/)*"
+MLP = r"TransformerLM\)*/layers_\d+/mlp/"
 # program -> scope -> where it must appear (a regex on the whole path)
 EXPECTED = {
     "step": {
@@ -50,6 +51,37 @@ EXPECTED = {
         "cache_attention": LAYER + r"cache_attention/.*dot_general",
     },
     "first_token": {"sample": r"^jit\(first_token\)/sample/"},
+    # a model with a layer pattern (window and full attention, a gate on the
+    # attention output, dropless sparse MLPs with a shared expert), at a
+    # small size: every scope its per-layer metrics read
+    "pattern_step": {
+        "moe": MLP + r"moe/",
+        "router": MLP + r"moe/router/dot_general",
+        "dispatch": MLP + r"moe/dispatch/",
+        "experts": MLP + r"moe/experts/ragged_dot",
+        "shared_expert": MLP + r"moe/shared_expert/(gate|up|down)_proj/dot_general",
+        "attn_gate": LAYER + r"attn_gate/",
+        "window_attention": LAYER + r"window_attention/cache_attention/.*dot_general",
+        "window_gather": LAYER + r"window_attention/kv_gather/",
+        "cache_attention": LAYER + r"cache_attention/.*dot_general",
+        "rope": LAYER + r"rope/",
+        "sample": r"^jit\(step\)/sample/",
+    },
+    "pattern_prefill_chunk": {
+        "moe": MLP + r"moe/",
+        "experts": MLP + r"moe/experts/ragged_dot",
+        "shared_expert": MLP + r"moe/shared_expert/",
+        "attn_gate": LAYER + r"attn_gate/",
+        "window_attention": LAYER + r"window_attention/cache_attention/.*dot_general",
+        "kv_scatter": LAYER + r"kv_scatter/scatter",
+    },
+    # the same model at a head size the decode kernel takes: a window
+    # layer's call sits under window_attention/cache_attention
+    "pattern_step_kernel": {
+        "window_attention":
+            LAYER + r"window_attention/cache_attention/jit\(_per_device\)$",
+        "cache_attention": LAYER + r"cache_attention/jit\(_per_device\)$",
+    },
     "ddp": {
         "rope": LAYER + r"rope/",
         "flash_attention": LAYER + r"flash_attention/",
@@ -87,6 +119,23 @@ def _model(d_model=32, **kw):
     return model, model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
 
 
+def _pattern_model(head_size):
+    from pytorch_distributed_example_tpu.models.transformer import LayerSpec, RopeSpec
+
+    full = RopeSpec(5e5, 0.5, (8.0, 16, 64.0, 1.0, 1.4))
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=3, d_ff=64,
+        max_seq_len=64, head_size=head_size, window=8, attn_gate=True,
+        rope_pairs="halves", sparse_experts=4, sparse_top_k=2, sparse_d_ff=16,
+        shared_d_ff=16, routed_scale=2.5, use_flash=False,
+        layers=(LayerSpec("full", 4, full, "dense"),
+                LayerSpec("window", 8, RopeSpec(1e4), "sparse"),
+                LayerSpec("full", 4, full, "sparse")))
+    model = TransformerLM(cfg)
+    return model, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+
+
 def _loss(logits, y):
     return optax.softmax_cross_entropy_with_integer_labels(
         logits[:, :-1], y[:, 1:]).mean()
@@ -107,7 +156,20 @@ def serve_paths():
     rngs = jnp.zeros((S, 2), jnp.uint32)
     wide, wide_vars = _model(d_model=512)  # 4 heads of 128
     wide_step = paged_programs(wide, 0.0, None)[3]
+    out = {}
+    for name, head in (("pattern_step", 16), ("pattern_step_kernel", 128)):
+        pattern, pvars = _pattern_model(head)
+        pchunk, _, _, pstep = paged_programs(pattern, 0.0, None)
+        ptree = init_paged_cache(pattern, nblk, bs, window_blocks=8)
+        pair = (bt, bt)  # the full layers' tables and the window layers'
+        out[name] = _paths(pstep.lower(
+            pvars["params"], ptree, lanes, lanes, rngs, pair))
+        if head == 16:
+            out["pattern_prefill_chunk"] = _paths(pchunk.lower(
+                pvars["params"], ptree, jnp.zeros((1, 16), jnp.int32),
+                (bt[:1], bt[:1]), 0))
     return {
+        **out,
         "step": _paths(step.lower(params, tree, lanes, lanes, rngs, bt)),
         "step_kernel": _paths(wide_step.lower(
             wide_vars["params"], init_paged_cache(wide, nblk, bs),
@@ -157,12 +219,14 @@ def train_paths(world):
     return out
 
 
+SERVE = ("step", "step_kernel", "prefill_chunk", "first_token", "pattern_step",
+         "pattern_prefill_chunk", "pattern_step_kernel")
 CASES = [(prog, scope) for prog, scopes_ in EXPECTED.items() for scope in scopes_]
 
 
 @pytest.mark.parametrize("program,scope", CASES, ids=[f"{p}-{s}" for p, s in CASES])
 def test_scope_sits_under_its_flax_path(program, scope, request):
-    serve = program in ("step", "step_kernel", "prefill_chunk", "first_token")
+    serve = program in SERVE
     paths = request.getfixturevalue(
         "serve_paths" if serve else "train_paths")[program]["paths"]
     rx = re.compile(EXPECTED[program][scope])
@@ -173,8 +237,7 @@ def test_scope_sits_under_its_flax_path(program, scope, request):
 
 def test_the_kernel_step_gathers_nothing(serve_paths):
     """With the decode kernel in the step no operation is traced under
-    `kv_gather` (the queued `decode_kv_gather_ms` then reads nothing in
-    `jit_step`), and the old path's step keeps both scopes."""
+    `kv_gather`, and the old path's step keeps both scopes."""
     assert serve_paths["step_kernel"]["program"] == "jit_step"
     assert not [p for p in serve_paths["step_kernel"]["paths"] if "kv_gather" in p]
     calls = [p for p in serve_paths["step_kernel"]["paths"]
@@ -186,12 +249,14 @@ def test_the_kernel_step_gathers_nothing(serve_paths):
                 if "paged_decode_attention" in p]
 
 
-# the metric files' own patterns, against the same lowerings (queued: the
-# harness takes a cell's metrics from its own file, a `benchmark` PR's to edit)
-METRICS = Path(__file__).resolve().parents[1] / "bench_matrix" / "layer_metrics_queued"
+# the metric files' own patterns, against the same lowerings
+METRICS = Path(__file__).resolve().parents[1] / "bench_matrix" / "layer_metrics"
 READ_BY = {
-    "decode_kv_gather_ms": ["step"],
-    "decode_cache_attention_ms": ["step", "step_kernel"],
+    "decode_cache_attention_ms": ["step", "step_kernel", "pattern_step"],
+    "decode_moe_ms": ["pattern_step"],
+    "prefill_moe_ms": ["pattern_prefill_chunk"],
+    "decode_window_attention_ms": ["pattern_step", "pattern_step_kernel"],
+    "moe_decode_roofline": ["pattern_step"],
     "prefill_cache_attention_ms": ["prefill_chunk"],
     "train_mlp_ms": ["ddp", "fsdp"],
     "train_attention_ms": ["ddp", "fsdp"],
@@ -206,7 +271,7 @@ def test_metric_file_finds_operations_in_the_program_it_names(metric, request):
 
     args = json.loads((METRICS / f"{metric}.json").read_text())["args"]
     for program in READ_BY[metric]:
-        serve = program in ("step", "step_kernel", "prefill_chunk")
+        serve = program in SERVE
         low = request.getfixturevalue("serve_paths" if serve else "train_paths")[program]
         assert re.search(args["program"], low["program"]), (metric, low["program"])
         rx = re.compile(args["scope"])
