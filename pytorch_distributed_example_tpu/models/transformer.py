@@ -390,7 +390,7 @@ class Attention(nn.Module):
         (retired) slot lane and a padded prefill chunk ride through the
         step without touching any live request's blocks. Reads attend
         the row's logical layout under the same absolute-position causal
-        mask, by one of the two paths `_decode_paged` describes; there is
+        mask, by one of the paths `_decode_paged` describes; there is
         no "index" variable on this path (the pool has no per-row
         cursor).
 
@@ -489,19 +489,22 @@ class Attention(nn.Module):
         the flat index out of bounds, where `mode="drop"` discards the
         write.
 
-        Attention then takes one of two paths, chosen from what this
-        call can see (`ops.paged_decode_ok`: query length, pool shape
-        and dtype, table shape — no option names it). A DECODE call
-        (L == 1) on a pool that is not quantized, at a head size Mosaic
-        tiles, runs `ops.paged_decode_attention`: one kernel that reads
-        each row's pages out of the pool, as many as the row's length
-        and leading valid table entries give it. Every other call (prefill chunks,
-        int8 pools, the tiny head sizes of the CPU tests) gathers the
-        row's logical K/V layout (`ops.gather_paged_kv`) and masks a
-        dense einsum by absolute position. On both paths dropped or
-        garbage regions are never attended (every key <= a live row's
-        position sits in an allocated block — the engine allocates
-        before it writes), and a parked row's output is finite and
+        Attention then takes one of three paths, chosen from what this
+        call can see (`ops.paged_kernel`: query length, pool shape and
+        dtype, table shape, the layer's window — no option names it). On
+        a pool that is not quantized, at a head size Mosaic tiles, a
+        DECODE call (L == 1) runs `ops.paged_decode_attention` and a
+        PREFILL CHUNK (L > 1, no window, a query length that fills
+        sublane tiles) `ops.paged_chunk_attention`: kernels that read
+        each row's pages out of the pool, as many as the row's last
+        position and leading valid table entries give it. Every other
+        call (int8 pools, the tiny head sizes and 4-token buckets of the
+        CPU tests, a window layer's chunk) gathers the row's logical K/V
+        layout (`ops.gather_paged_kv`) and masks a dense einsum by
+        absolute position. On every path dropped or garbage regions are
+        never attended (every key <= a live row's position sits in an
+        allocated block — the engine allocates before it writes), and
+        the output of a parked row or a padded query is finite and
         ignored by the scheduler.
 
         A QUANTIZED pool (int8 k/v plus `k_scale`/`v_scale` planes —
@@ -512,19 +515,21 @@ class Attention(nn.Module):
         reads dequantize inside `ops.gather_paged_kv`, so the scores/
         softmax/output math below is identical in both modes.
 
-        A WINDOW layer (`self.window`) runs the same two paths over its
-        own pool and table (`serve/cache.py` frees a row's blocks behind
-        the window while the request lives, so leading table entries may
-        be invalid): the kernel starts each row at its first attended
-        page, and the gather takes the `window + L` keys the call can
-        attend (`ops.paged_window_span`) and not the table's span. Both
-        sit under a `window_attention` scope around `cache_attention`."""
+        A WINDOW layer (`self.window`) runs the decode kernel and the
+        gather over its own pool and table (`serve/cache.py` frees a
+        row's blocks behind the window while the request lives, so
+        leading table entries may be invalid): the kernel starts each
+        row at its first attended page, and the gather takes the
+        `window + L` keys the call can attend (`ops.paged_window_span`)
+        and not the table's span. Both sit under a `window_attention`
+        scope around `cache_attention`."""
         import contextlib
 
         from ..ops import (
             gather_paged_kv,
+            paged_chunk_attention,
             paged_decode_attention,
-            paged_decode_ok,
+            paged_kernel,
             paged_window_span,
         )
         from ..ops.quant import quantize_kv
@@ -589,12 +594,19 @@ class Attention(nn.Module):
             jax.named_scope("window_attention") if window is not None
             else contextlib.nullcontext()
         )
-        if paged_decode_ok(L, ck.value, block_tables):
+        kernel = paged_kernel(L, ck.value, block_tables, window)
+        if kernel is not None:
             with scope, jax.named_scope("cache_attention"):
-                return paged_decode_attention(
-                    q[:, 0], ck.value, cv.value, block_tables, idx, scale,
-                    window=window,
-                ).reshape(B, L, H * Dh)
+                if kernel == "decode":
+                    o = paged_decode_attention(
+                        q[:, 0], ck.value, cv.value, block_tables, idx,
+                        scale, window=window,
+                    )
+                else:
+                    o = paged_chunk_attention(
+                        q, ck.value, cv.value, block_tables, idx, scale
+                    )
+                return o.reshape(B, L, H * Dh)
         first_block = n_blocks = None
         key0 = jnp.zeros((B,), jnp.int32)
         if window is not None:

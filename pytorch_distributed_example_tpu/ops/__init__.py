@@ -11,8 +11,9 @@ from .flash_attention import (  # noqa: F401
     partitioned_over,
 )
 from .paged_attention import (  # noqa: F401
+    paged_chunk_attention,
     paged_decode_attention,
-    paged_decode_ok,
+    paged_kernel,
 )
 from .quant import (  # noqa: F401
     dequantize_blockwise,

@@ -904,10 +904,11 @@ def gather_paged_kv(
     position order, so downstream attention indexes keys by absolute
     position. It lowers to an XLA gather feeding the cache-attention
     einsum, and it moves the whole `nb * block_size` span of every row
-    whatever the row's length: the path of prefill chunks (one row),
-    int8 pools and head sizes Mosaic cannot tile. A decode step on a
-    plain pool reads its pages in `ops.paged_decode_attention` instead
-    and never calls this (`ops.paged_decode_ok` decides). The KV-head
+    whatever the row's length: the path of int8 pools, head sizes Mosaic
+    cannot tile and a window layer's chunk. A decode step and a prefill
+    chunk on a plain pool read their pages in the kernels of
+    `ops/paged_attention.py` instead and never call this
+    (`ops.paged_kernel` decides). The KV-head
     axis passes through untouched, so a TP-sharded pool stays sharded
     through the gather.
 

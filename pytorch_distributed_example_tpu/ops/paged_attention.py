@@ -1,14 +1,17 @@
-"""Paged decode attention — one Pallas TPU kernel over the serve block pool.
+"""Paged attention — two Pallas TPU kernels over the serve block pool.
 
-The decode step's attention (`models/transformer.py::Attention.
-_decode_paged` at L == 1) reads each row's K/V pages STRAIGHT out of the
-shared block pool (`serve/cache.py`) and runs an online softmax over
-them, so a row costs the pages it has — not the `nb * bs` keys its
-table could address, which is what `gather_paged_kv` + the dense einsum
-move (that pair stays the path of prefill chunks, int8 pools and shapes
-Mosaic cannot tile: `paged_decode_ok` is the one predicate).
+The serve programs' attention (`models/transformer.py::Attention.
+_decode_paged`) reads each row's K/V pages STRAIGHT out of the shared
+block pool (`serve/cache.py`) and runs an online softmax over them, so a
+row costs the pages it has — not the `nb * bs` keys its table could
+address, which is what `gather_paged_kv` + the dense einsum move.
+`paged_decode_attention` takes the decode step (one query token a row),
+`paged_chunk_attention` a prefill chunk (L query tokens a row, causal
+among themselves); the gather + einsum stays the path of int8 pools,
+shapes Mosaic cannot tile and a window layer's chunk, and the reference
+both kernels are tested against: `paged_kernel` is the one predicate.
 
-Shape of the kernel (design per /opt/skills/guides/pallas_guide.md):
+Shape of the decode kernel (design per /opt/skills/guides/pallas_guide.md):
 
 * ONE program, static shapes. Block tables, each row's page count and
   last attended position, and a flat WORK LIST of (row, compute block)
@@ -37,6 +40,28 @@ Shape of the kernel (design per /opt/skills/guides/pallas_guide.md):
   spends KV times the needed MXU work on a memory-bound step instead of
   strided sub-tile loads of single heads out of a packed page.
 
+The chunk kernel is the same design carried to L queries a row, where
+the work is compute and not memory (`_chunk_kernel`):
+
+* A grid step is one (row, block of `CHUNK_QUERY_BLOCK` queries); its
+  trip count — not a shape — follows the pages that hold a position <=
+  its last query, of the row's leading valid table entries: a later
+  query block's keys are not visited by an earlier one, and one compiled
+  program a chunk length serves every `start`.
+* Each KV head meets only its own group's queries. A block's pages are
+  copied once for all KV heads (double-buffered as above), then read
+  back one head at a time by strided 32-bit loads into (KV, keys, Dh).
+  Contracting every query head against every KV head, as the decode
+  kernel does, would spend KV times the FLOPs where FLOPs are the cost.
+* Scores are held transposed, (keys, rep * queries) a KV head: the
+  softmax reduces down the sublanes and its running max and sum are
+  lane-dense rows, which is what lets a 256-key block pay for itself.
+* The causal mask is work: only a key block that crosses the diagonal
+  (or the row's last page) applies it; blocks wholly below a query
+  block's first position run unmasked.
+* Queries ride in grouped by KV head, (row, query block, KV, rep *
+  queries, Dh): two XLA transposes a call around the kernel.
+
 Tolerance contract (tests/test_paged_attention.py tests to it). Scores
 and the running max / sum are float32, probabilities are cast to the
 value dtype before the value product, the accumulator is float32 and
@@ -44,7 +69,7 @@ the output is cast once — the dense path's recipe, with two
 differences that both err on the side of precision: scores are NOT
 rounded to the pool dtype before the softmax (the dense einsum's output
 is), and normalisation happens after the value product. Against
-`gather_paged_kv` + the dense einsum on the same operands the kernel
+`gather_paged_kv` + the dense einsum on the same operands each kernel
 therefore agrees to float32 reassociation in float32 (max abs error
 <= 2e-5 at unit-scale inputs) and to bfloat16 rounding of scores and
 probabilities in bfloat16 (max abs error <= 2e-2 on outputs of unit
@@ -65,10 +90,18 @@ from jax.experimental.pallas import tpu as pltpu
 from .._compat import shard_map_fn
 from .flash_attention import NEG_INF, _interpret_default, _partition
 
-#: keys of one compute block (pages_per_block = KEYS_PER_BLOCK // bs):
-#: 256 read 566 GB/s of live K/V at the decode cell's depths on a v5e,
-#: 512 the same (555), 128 less (475) — PERF.md, PR 25.
+#: keys of one compute block (pages_per_block = KEYS_PER_BLOCK // bs). The
+#: decode kernel: 256 read 566 GB/s of live K/V at the decode cell's
+#: depths on a v5e, 512 the same (555), 128 less (475) — PERF.md, PR 25.
+#: The chunk kernel: 256 / 512 / 1024 take 0.222 / 0.224 / 0.231 ms a
+#: layer at 3072 keys and 0.051 / 0.053 / 0.087 at 512 — PERF.md, PR 28.
 KEYS_PER_BLOCK = 256
+#: queries of one grid step of the chunk kernel: the serve cells' largest
+#: bucket whole (512 against 256: 0.222 against 0.255 ms, as above)
+CHUNK_QUERY_BLOCK = 512
+#: VMEM the chunk kernel may take of a v5e TensorCore's 128 MiB: a query
+#: block of every head with its float32 accumulators stays resident
+CHUNK_VMEM_BYTES = 96 * 1024 * 1024
 #: what the prefetched scalars (tables, work list) may take of the 1 MiB
 #: of scalar memory of a TensorCore; the compiler keeps the rest.
 SMEM_BYTES = 768 * 1024
@@ -90,36 +123,93 @@ def _pages_per_block(bs: int, nb: int) -> int:
     return max(1, min(KEYS_PER_BLOCK // bs, nb))
 
 
-def paged_decode_ok(L: int, pool, block_tables) -> bool:
-    """Whether `paged_decode_attention` takes this call — THE predicate,
-    read by `Attention._decode_paged` (which path to trace) and by
-    `serve.decode.step_runs_kernel` (which path the engine's step
-    counter names), from what both can see: the query length, the K
-    pool and the block tables (arrays or `ShapeDtypeStruct`s; shapes
-    and dtype alone are read), and the `partitioned_over` context a tp
-    engine's programs apply the model under.
+def _leading(valid):
+    """Per row, how many leading entries of `valid` are true."""
+    return jnp.sum(jnp.cumprod(valid, axis=1, dtype=jnp.int32), axis=1)
 
-    One query token a row (decode, not a prefill chunk); a floating
-    pool of 2 or 4 bytes (the int8 pool dequantises in the gather); `Dh`
-    a multiple of the 128 lanes; a page whose `bs * KV` rows of `Dh` (KV
-    as one device holds it) fill whole sublane tiles of the pool dtype
-    (8 rows of float32, 16 of bfloat16), so page copies land
-    tile-aligned in the VMEM buffer; tables and work list (with the two
-    scalars a row that a window layer adds) within the scalar memory
-    they are prefetched into."""
+
+def _precision(dtype):
+    """bfloat16 products are exact in one MXU pass; float32 pools take
+    the multi-pass product. Named so that an ambient
+    `jax_default_matmul_precision` (the test harness pins "highest")
+    cannot ask Mosaic for a float32 contraction of bfloat16 operands."""
+    return (
+        lax.Precision.HIGHEST if dtype == jnp.float32
+        else lax.Precision.DEFAULT
+    )
+
+
+def _page_copies(fn, tables_ref, first, have, pools, bufs, sems, slot):
+    """Apply `fn` (start or wait) to the K and V copy of each page
+    `tables_ref[first : first + have]` names, out of the pools in HBM
+    into consecutive rows of the buffers' `slot`."""
+    rows = pools[0].shape[1]  # bs * KV rows of Dh a page
+
+    def one(i, carry):
+        page = tables_ref[first + i]
+        dst = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        for s, (pool, buf) in enumerate(zip(pools, bufs)):
+            fn(pltpu.make_async_copy(
+                pool.at[page], buf.at[slot, dst], sems.at[s, slot]
+            ))
+        return carry
+
+    lax.fori_loop(0, have, one, 0)
+
+
+def paged_kernel(L: int, pool, block_tables, window=None):
+    """Which kernel of this module takes the call: "decode"
+    (`paged_decode_attention`), "chunk" (`paged_chunk_attention`) or
+    None (the caller gathers the row's layout and runs the dense
+    einsum) — THE predicate, read by `Attention._decode_paged` (which
+    path to trace) and by `serve.decode.kernel_layers` (which path the
+    engine's counters name), from what both can see: the query length,
+    the K pool and the block tables (arrays or `ShapeDtypeStruct`s;
+    shapes and dtype alone are read), the layer's window, and the
+    `partitioned_over` context a tp engine's programs apply the model
+    under.
+
+    Either kernel needs a floating pool of 2 or 4 bytes (the int8 pool
+    dequantises in the gather); `Dh` a multiple of the 128 lanes; a page
+    whose `bs * KV` rows of `Dh` (KV as one device holds it) fill whole
+    sublane tiles of the pool dtype (8 rows of float32, 16 of bfloat16),
+    so page copies land tile-aligned in the VMEM buffer; and its
+    prefetched scalars within scalar memory (tables and work list, with
+    the two scalars a row that a window layer adds to the decode
+    kernel's).
+
+    "decode": one query token a row, with or without a window.
+    "chunk": more than one, of a layer WITHOUT a window (a window
+    layer's chunk gathers `window + L` keys: nothing to win), where the
+    query length fills whole sublane tiles too (a 4-token bucket of the
+    CPU tests is refused, not padded) and splits into query blocks of
+    `CHUNK_QUERY_BLOCK`, on a float32 or bfloat16 pool whose KV heads
+    (as one device holds them) fill 32-bit words — one head, or an even
+    number of bfloat16 ones: the kernel separates the heads of a page
+    by strided 32-bit reads."""
     _, bs, KV, Dh = pool.shape
     B, nb = block_tables.shape
     itemsize = jnp.dtype(pool.dtype).itemsize
     if not jnp.issubdtype(pool.dtype, jnp.floating) or itemsize not in (2, 4):
-        return False
-    rows = bs * (KV // _head_shards(KV))
-    items = B * -(-nb // _pages_per_block(bs, nb))
-    return (
-        L == 1
-        and Dh % 128 == 0
-        and rows % (32 // itemsize) == 0
-        and 4 * (B * nb + 2 * items + 4 * B + 1) <= SMEM_BYTES
-    )
+        return None
+    tile = 32 // itemsize
+    kv = KV // _head_shards(KV)
+    if Dh % 128 or (bs * kv) % tile:
+        return None
+    if L == 1:
+        items = B * -(-nb // _pages_per_block(bs, nb))
+        fits = 4 * (B * nb + 2 * items + 4 * B + 1) <= SMEM_BYTES
+        return "decode" if fits else None
+    if (
+        window is None
+        and pool.dtype in (jnp.float32, jnp.bfloat16)
+        and (kv == 1 or kv % (4 // itemsize) == 0)
+        and L % tile == 0
+        and L % min(L, CHUNK_QUERY_BLOCK) == 0
+        and 4 * (B * nb + 2 * B) <= SMEM_BYTES
+    ):
+        return "chunk"
+    return None
 
 
 def _work_list(block_tables, lengths, nblk, bs, P, window=None):
@@ -138,8 +228,7 @@ def _work_list(block_tables, lengths, nblk, bs, P, window=None):
         first = jnp.clip(lengths - (window - 1), 0, nb * bs - 1)
         page0 = first // bs
         valid |= jnp.arange(nb)[None, :] < page0[:, None]
-    lead = jnp.sum(jnp.cumprod(valid, axis=1, dtype=jnp.int32), axis=1)
-    n_pages = jnp.minimum(lead, jnp.clip(lengths // bs + 1, 0, nb))
+    n_pages = jnp.minimum(_leading(valid), jnp.clip(lengths // bs + 1, 0, nb))
     last = jnp.minimum(lengths, n_pages * bs - 1)  # -1 on a parked row
     if window is not None:
         n_pages = jnp.maximum(n_pages - page0, 0)  # from page0 on
@@ -168,14 +257,7 @@ def _kernel(*refs, scale, nb, P, KV, windowed):
     T = R // KV  # keys a compute block
     rep = H // KV
     n_items = n_items_ref[0]
-    # bfloat16 products are exact in one MXU pass; float32 pools take the
-    # multi-pass product. Named here so that an ambient
-    # `jax_default_matmul_precision` (the test harness pins "highest")
-    # cannot ask Mosaic for a float32 contraction of bfloat16 operands.
-    precision = (
-        lax.Precision.HIGHEST if kbuf.dtype == jnp.float32
-        else lax.Precision.DEFAULT
-    )
+    precision = _precision(kbuf.dtype)
 
     # Column c of a score tile is (key c // KV, kv head c % KV). `colpos`
     # holds c where that head is the query head's group and a sentinel
@@ -197,17 +279,10 @@ def _kernel(*refs, scale, nb, P, KV, windowed):
         have = jnp.minimum(n_pages_ref[row] - first, P)
         if windowed:
             first = first + page0_ref[row]
-
-        def one(i, carry):
-            page = tables_ref[row * nb + first + i]
-            dst = pl.ds(pl.multiple_of(i * rows, rows), rows)
-            for pool, buf, s in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
-                fn(pltpu.make_async_copy(
-                    pool.at[page], buf.at[slot, dst], sems.at[s, slot]
-                ))
-            return carry
-
-        lax.fori_loop(0, have, one, 0)
+        _page_copies(
+            fn, tables_ref, row * nb + first, have, (k_hbm, v_hbm),
+            (kbuf, vbuf), sems, slot,
+        )
 
     @pl.when(n_items > 0)
     def _():
@@ -313,6 +388,22 @@ def _per_device(
     )
 
 
+def _on_kv_shards(local, q, pool):
+    """`local(q, pool_k, pool_v, tables, per-row scalars)` as it is, or,
+    under `ops.partitioned_over`, per device through a `shard_map`: q
+    (heads next to last) and the pools split on KV heads over the head
+    axes, rows whole on every device (a serve program shards no batch
+    axis), tables and scalars whole, no collective."""
+    if _partition.spec is None:
+        return local
+    jmesh, _, head_axes = _partition.spec
+    P = jax.sharding.PartitionSpec
+    h = head_axes if _head_shards(pool.shape[2]) > 1 else None
+    heads = P(*[None] * (q.ndim - 2), h, None)
+    kv = P(None, None, h, None)
+    return shard_map_fn(local, jmesh, (heads, kv, kv, P(), P()), heads)
+
+
 def paged_decode_attention(
     q, pool_k, pool_v, block_tables, lengths, scale=None, *, interpret=None,
     window=None,
@@ -336,7 +427,7 @@ def paged_decode_attention(
     collective — because a Mosaic kernel is a custom call GSPMD cannot
     partition (the serve step opens the context for a tp engine).
 
-    Callers check `paged_decode_ok` first; precision contract in the
+    Callers check `paged_kernel` first; precision contract in the
     module docstring.
     """
     Dh = q.shape[-1]
@@ -347,14 +438,236 @@ def paged_decode_attention(
     local = functools.partial(
         _per_device, scale=scale, interpret=interpret, window=window
     )
-    if _partition.spec is None:
-        return local(q, pool_k, pool_v, block_tables, lengths)
-    # rows stay whole on every device (a serve step shards no batch axis)
-    jmesh, _, head_axes = _partition.spec
-    P = jax.sharding.PartitionSpec
-    h = head_axes if _head_shards(pool_k.shape[2]) > 1 else None
-    pool = P(None, None, h, None)
-    return shard_map_fn(
-        local, jmesh, (P(None, h, None), pool, pool, P(), P()),
-        P(None, h, None),
-    )(q, pool_k, pool_v, block_tables, lengths)
+    return _on_kv_shards(local, q, pool_k)(
+        q, pool_k, pool_v, block_tables, lengths
+    )
+
+
+def _chunk_kernel(
+    tables_ref, n_pages_ref, start_ref, q_ref, k_hbm, v_hbm, o_ref,
+    kbuf, vbuf, sems, kd, vd, m_s, l_s, acc_s, *, scale, nb, P, KV, bs, bq,
+):
+    """Grid step (b, i): queries `start[b] + i * bq ...` of row b, grouped
+    by KV head as (KV, rep * bq, Dh) with row r * bq + t = (query head
+    g * rep + r, query t), against the key blocks that hold a position
+    <= the last of them."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    N = q_ref.shape[1]  # rep * bq queries a KV head
+    T = P * bs  # keys a compute block
+    precision = _precision(kbuf.dtype)
+    first_q = start_ref[b] + i * bq
+    # pages that hold a position <= the last query's, of the row's
+    # leading valid ones: what this step reads, and no entry behind them
+    n_pages = jnp.minimum(n_pages_ref[b], (first_q + bq + bs - 1) // bs)
+    n_blocks = (n_pages + P - 1) // P
+
+    @pl.when((b == 0) & (i == 0))
+    def _():
+        # pages a block does not have keep what the buffer held: zero V
+        # once so that 0 * stale is never 0 * NaN
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+    def page_copies(blk, slot, fn):
+        _page_copies(
+            fn, tables_ref, b * nb + blk * P, jnp.minimum(n_pages - blk * P, P),
+            (k_hbm, v_hbm), (kbuf, vbuf), sems, slot,
+        )
+
+    @pl.when(n_blocks > 0)
+    def _():
+        page_copies(0, 0, lambda cp: cp.start())
+
+    def split_heads(buf, slot, dst):
+        """Row key * KV + g of a buffer is KV head g's key: strided reads
+        turn the packed pages into (KV, T, Dh), so that a head meets its
+        own group's queries and no other's. Mosaic strides 32-bit rows
+        only: a bfloat16 buffer is read as words that hold heads (2w,
+        2w + 1) of a key, the even head in the low half, and a bfloat16
+        is the high half of the float32 of its value."""
+        if KV == 1:
+            dst[0] = buf[slot]
+        elif buf.dtype == jnp.float32:
+            for g in range(KV):
+                dst[g] = buf[slot, pl.ds(g, T, stride=KV), :]
+        else:
+            words = buf.bitcast(jnp.uint32)
+            for w in range(KV // 2):
+                x = words[slot, pl.ds(w, T, stride=KV // 2), :]
+                for h, bits in enumerate((x << 16, x & jnp.uint32(0xFFFF0000))):
+                    dst[2 * w + h] = lax.bitcast_convert_type(
+                        bits, jnp.float32
+                    ).astype(dst.dtype)
+
+    def attend(key0, masked):
+        """Every KV head's keys of one block against its query group.
+        Scores are TRANSPOSED, (keys, N): the softmax's max and sum run
+        down the sublanes (elementwise across vregs, one short reduction
+        at the end) and its running state is (1, N) rows, where (N, keys)
+        scores pay a cross-lane reduction and a column of N / 8 vregs an
+        operation in every block: 26 us of a 34 us block (PERF.md, PR 28)."""
+        if masked:
+            key = lax.broadcasted_iota(jnp.int32, (T, N), 0)
+            t = lax.rem(lax.broadcasted_iota(jnp.int32, (T, N), 1), bq)
+            keep = key <= jnp.minimum(first_q + t, n_pages * bs - 1) - key0
+
+        def head(g, carry):
+            s = lax.dot_general(
+                kd[g], q_ref[g], (((1,), (1,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32,
+            ) * scale  # (T, N)
+            if masked:
+                s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_s[g]  # (1, N)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            # masked: exp(-1e30 - m) == 0 exactly, key 0 is in every
+            # query's first block so m is a score from there on
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_s[g] = alpha * l_s[g] + jnp.sum(p, axis=0, keepdims=True)
+            acc_s[g] = alpha * acc_s[g] + lax.dot_general(
+                vd[g], p.astype(vd.dtype), (((0,), (0,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32,
+            )  # (Dh, N)
+            m_s[g] = m_new
+            return carry
+
+        lax.fori_loop(0, KV, head, 0)
+
+    def body(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            page_copies(blk + 1, 1 - slot, lambda cp: cp.start())
+
+        page_copies(blk, slot, lambda cp: cp.wait())
+        split_heads(kbuf, slot, kd)
+        split_heads(vbuf, slot, vd)
+        key0 = blk * T
+        # the mask is work: only a block that crosses the diagonal (a
+        # key past the first query) or the row's last valid page pays it
+        masked = (key0 + T - 1 > first_q) | (key0 + T > n_pages * bs)
+
+        @pl.when(masked)
+        def _():
+            attend(key0, True)
+
+        @pl.when(jnp.logical_not(masked))
+        def _():
+            attend(key0, False)
+
+        return carry
+
+    lax.fori_loop(0, n_blocks, body, 0)
+    for g in range(KV):
+        l = l_s[g]  # 0 on a row with no valid page: zeros, not 0 / 0
+        o_ref[g] = jnp.where(l > 0, acc_s[g] / l, 0.0).T.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _chunk_per_device(
+    q, pool_k, pool_v, block_tables, starts, *, scale, interpret
+):
+    """The chunk kernel on one device's operands; a `jax.jit` of its own
+    for the reason `_per_device` is one."""
+    B, L, H, Dh = q.shape
+    nblk, bs, KV, _ = pool_k.shape
+    nb = block_tables.shape[1]
+    rep = H // KV
+    bq = min(L, CHUNK_QUERY_BLOCK)
+    # q and the output double-buffered, the accumulators: within VMEM
+    while H * bq * Dh * (4 * q.dtype.itemsize + 4) > CHUNK_VMEM_BYTES // 2:
+        bq //= 2
+    nq = L // bq
+    P = _pages_per_block(bs, nb)
+    rows = bs * KV
+    block_tables = block_tables.astype(jnp.int32)
+    scalars = (
+        block_tables.reshape(B * nb), _leading(block_tables < nblk),
+        starts.astype(jnp.int32),
+    )
+    # query head g * rep + r of query i * bq + t -> [i, g, r * bq + t]
+    grouped = (B, nq, KV, rep * bq, Dh)
+    qg = q.reshape(B, nq, bq, KV, rep, Dh).transpose(0, 1, 3, 4, 2, 5)
+    block = pl.BlockSpec(
+        (None, None, KV, rep * bq, Dh), lambda b, i, *_: (b, i, 0, 0, 0)
+    )
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    f32 = jnp.float32
+    out = pl.pallas_call(
+        functools.partial(
+            _chunk_kernel, scale=scale, nb=nb, P=P, KV=KV, bs=bs, bq=bq
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(B, nq),
+            in_specs=[block, hbm, hbm],
+            out_specs=block,
+            scratch_shapes=[
+                pltpu.VMEM((2, P * rows, Dh), pool_k.dtype),
+                pltpu.VMEM((2, P * rows, Dh), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((KV, P * bs, Dh), pool_k.dtype),
+                pltpu.VMEM((KV, P * bs, Dh), pool_v.dtype),
+                pltpu.VMEM((KV, 1, rep * bq), f32),
+                pltpu.VMEM((KV, 1, rep * bq), f32),
+                pltpu.VMEM((KV, Dh, rep * bq), f32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(grouped, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY),
+            vmem_limit_bytes=CHUNK_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="paged_chunk_attention",
+    )(
+        *scalars, qg.reshape(grouped),
+        pool_k.reshape(nblk, rows, Dh), pool_v.reshape(nblk, rows, Dh),
+    )
+    out = out.reshape(B, nq, KV, rep, bq, Dh).transpose(0, 1, 4, 2, 3, 5)
+    return out.reshape(B, L, H, Dh)
+
+
+def paged_chunk_attention(
+    q, pool_k, pool_v, block_tables, starts, scale=None, *, interpret=None
+):
+    """A prefill chunk of L query tokens a row against the paged block
+    pool: the decode kernel's design carried to L queries.
+
+    q: (B, L, H, Dh); pools and block_tables as `paged_decode_attention`
+    takes them; starts: (B,) int32. Query i of row b sits at absolute
+    position `starts[b] + i` and attends the keys at positions <= it —
+    the chunk's own, which `kv_scatter` wrote first, included — through
+    the row's leading valid table entries; returns (B, L, H, Dh) in q's
+    dtype. A padded query past the row's valid pages attends what the
+    row has and stays finite; a row with no valid page returns zeros.
+
+    Work follows the live keys: a (row, query block) grid step walks
+    `ceil(min(position of its last query + 1, valid keys) /
+    KEYS_PER_BLOCK)` key blocks, a trip count and not a shape, so
+    one compiled program serves every `start`. Each block's pages are
+    copied once for all KV heads, double-buffered, and read back one KV
+    head at a time (a strided read), so a KV head is multiplied with its
+    own `rep` query heads alone. Blocks wholly below a query block's
+    first position run without a mask.
+
+    Under `ops.partitioned_over` it runs per device on its KV-head shard
+    as the decode kernel does. Callers check `paged_kernel` first;
+    precision contract in the module docstring.
+    """
+    Dh = q.shape[-1]
+    if scale is None:
+        scale = 1.0 / math.sqrt(Dh)
+    if interpret is None:
+        interpret = _interpret_default()
+    local = functools.partial(
+        _chunk_per_device, scale=scale, interpret=interpret
+    )
+    return _on_kv_shards(local, q, pool_k)(
+        q, pool_k, pool_v, block_tables, starts
+    )

@@ -43,7 +43,7 @@ from .cache import land_slot
 __all__ = [
     "slot_programs",
     "paged_programs",
-    "step_runs_kernel",
+    "kernel_layers",
     "sync_slot_lanes",
     "carry_key",
 ]
@@ -95,18 +95,28 @@ def _kernel_partition(mesh, tp_axis: str):
     return partitioned_over(mesh, (), (tp_axis,))
 
 
-def step_runs_kernel(
-    k_pool, block_tables, mesh=None, tp_axis: str = "tp"
-) -> bool:
-    """Whether `paged_programs(..., mesh, tp_axis)`'s `step` traces
-    `ops.paged_decode_attention` for this K pool and these tables
-    (arrays or `ShapeDtypeStruct`s): `ops.paged_decode_ok` at one query
-    token, under the context the step applies the model under — the
-    fact `ServeMetrics`' kernel-step counter names."""
-    from ..ops import paged_decode_ok
+def kernel_layers(
+    cache, rows: int, L: int, mesh=None, tp_axis: str = "tp"
+) -> int:
+    """Of the model's layers, those whose attention call traces a kernel
+    of `ops/paged_attention.py` and not the gather + einsum when a
+    program of `paged_programs(..., mesh, tp_axis)` applies the model to
+    `rows` rows of `L` tokens (`step`: every slot, one token;
+    `prefill_chunk`: one row, the chunk): `ops.paged_kernel` for each
+    kind of layer `cache` (a `PagedKVCache`) holds state for, under the
+    context the programs apply the model under — the fact `ServeMetrics`'
+    kernel counters name."""
+    from ..ops import paged_kernel
 
     with _kernel_partition(mesh, tp_axis):
-        return paged_decode_ok(1, k_pool, block_tables)
+        n = cache.full_layers * bool(
+            paged_kernel(L, cache.pool_aval, cache.block_tables[:rows])
+        )
+        if cache.window_layers:
+            n += cache.window_layers * bool(paged_kernel(
+                L, cache.pool_aval, cache.window_tables[:rows], cache.window
+            ))
+    return n
 
 
 def sync_slot_lanes(lengths, tokens, rngs):
@@ -228,7 +238,12 @@ def paged_programs(
 
     * ``prefill_chunk(params, tree, chunk (1, C), bt_row (1, nb),
       start)`` — one prompt chunk through the paged decode path at
-      absolute offset `start`; returns (tree', logits (C, V)). Compiles
+      absolute offset `start`; returns (tree', logits (C, V)). The
+      chunk's K/V scatter into the pool, then `ops.paged_chunk_attention`
+      reads the row's pages up to `start + C` out of it (the gather +
+      dense einsum over the table's span where `ops.paged_kernel` says
+      no kernel takes the call: int8 pools, tiny heads, a window layer's
+      chunk; `kernel_layers` counts which). Compiles
       once per CHUNK length C: with `prefill_chunk_tokens` set that is
       ONE program for every prompt; unchunked it is one per bucket,
       exactly like PR 4. `start` is NONZERO both for later chunks of a
@@ -253,7 +268,7 @@ def paged_programs(
       slot one token through the paged attention path: the write
       scatters into the pool, then `ops.paged_decode_attention` reads
       each row's pages out of it (the gather + dense einsum where
-      `ops.paged_decode_ok` says the kernel cannot take the shape or
+      `ops.paged_kernel` says the kernel cannot take the shape or
       the pool is int8). Compiles ONCE for the engine's lifetime;
       retired/prefilling slots ride along as parked lanes whose table
       rows are all-invalid, so their garbage writes are scatter-DROPPED

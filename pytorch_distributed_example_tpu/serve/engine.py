@@ -124,7 +124,7 @@ from ..numerics import numerics_contract
 from ..types import DistError
 from .bucketing import bucket_for, bucket_lengths
 from .cache import PagedKVCache, window_layers_of
-from .decode import paged_programs, step_runs_kernel, sync_slot_lanes
+from .decode import kernel_layers, paged_programs, sync_slot_lanes
 from .metrics import ServeMetrics
 from .queue import (
     DEFAULT_CLASS,
@@ -299,11 +299,18 @@ class ServeEngine:
             self._attach,
             self._step,
         ) = paged_programs(model, temperature, top_k, jmesh, tp_axis)
-        # whether that step program runs the paged decode attention
-        # kernel, for the metrics: one fact for the engine's lifetime
-        self._decode_kernel = step_runs_kernel(
-            self.cache.pool_aval, self.cache.block_tables, jmesh, tp_axis
+        # how many layers' attention calls take a kernel of
+        # `ops/paged_attention.py`, for the metrics: in the step, and in
+        # a chunk of each length the engine dispatches — facts of the
+        # engine's lifetime
+        layers = model.cfg.n_layers
+        self._decode_kernel = (
+            kernel_layers(self.cache, slots, 1, jmesh, tp_axis) == layers
         )
+        self._chunk_kernel_layers = {
+            C: kernel_layers(self.cache, 1, C, jmesh, tp_axis)
+            for C in {*self.buckets, prefill_chunk_tokens} - {None}
+        }
         if precompiled:
             # resize fast path (serve/prewarm.py): overlay pre-warmed
             # executables — matching shapes skip trace AND compile,
@@ -718,6 +725,9 @@ class ServeEngine:
                 jnp.asarray(chunk),
                 self.cache.tables(slice(slot, slot + 1)),
                 pf.pos,
+            )
+            self.metrics.record_prefill_chunk(
+                self._chunk_kernel_layers[C], self.model.cfg.n_layers
             )
             start = pf.pos
             pf.pos = end

@@ -158,10 +158,16 @@ class ServeMetrics:
         self._queue_class_depths: Dict[str, int] = {}
         self.steps = 0
         # decode steps dispatched, and those whose step program runs the
-        # paged decode attention kernel (`ops.paged_decode_ok` for the
+        # paged decode attention kernel (`ops.paged_kernel` for the
         # engine's pool: 100 % or 0 % for one engine's lifetime)
         self.decode_steps = 0
         self.decode_kernel_steps = 0
+        # prefill chunks dispatched, their attention calls (one a layer)
+        # and those of them that took a kernel of `ops/paged_attention.py`
+        # (`serve.decode.kernel_layers` at each chunk's length)
+        self.prefill_chunks = 0
+        self.prefill_attention_calls = 0
+        self.prefill_kernel_calls = 0
         # sparse (dropless MoE) layers, per decode step, from the step's
         # own readback: assignments computed (top_k x live rows x sparse
         # layers: nothing is dropped) and the distinct experts with at
@@ -284,6 +290,12 @@ class ServeMetrics:
         with self._lock:
             self.decode_steps += 1
             self.decode_kernel_steps += bool(kernel)
+
+    def record_prefill_chunk(self, kernel_layers: int, layers: int) -> None:
+        with self._lock:
+            self.prefill_chunks += 1
+            self.prefill_attention_calls += layers
+            self.prefill_kernel_calls += kernel_layers
 
     def record_moe_step(self, assignments: int, experts_hit) -> None:
         """One decode step of a model with sparse layers."""
@@ -653,6 +665,14 @@ class ServeMetrics:
                     "kernel_share": round(
                         self.decode_kernel_steps / self.decode_steps, 4
                     ) if self.decode_steps else 0.0,
+                },
+                "prefill": {
+                    "chunks": self.prefill_chunks,
+                    "kernel_calls": self.prefill_kernel_calls,
+                    "kernel_share": round(
+                        self.prefill_kernel_calls
+                        / self.prefill_attention_calls, 4
+                    ) if self.prefill_attention_calls else 0.0,
                 },
                 "moe": {
                     "steps": self.moe_steps,

@@ -205,6 +205,41 @@ def test_paged_decode_kernel_compiles_at_the_patterned_cell_s_widths(
     assert f"bf16[{B},{heads},{Dh}]" in call.split("custom-call(")[0]
 
 
+@pytest.mark.parametrize("L,heads", [(512, 32), (128, 32), (512, 48)])
+def test_paged_chunk_kernel_compiles_at_the_serve_cells_widths(
+    topo, monkeypatch, L, heads
+):
+    """`ops.paged_chunk_attention` as the serve cells' `prefill_chunk`
+    calls it (one row, the largest and smallest bucket, 32 query heads
+    and the patterned cell's 48 over 8 KV heads of 128): Mosaic takes the
+    strided 32-bit reads of a bfloat16 buffer and the VMEM the kernel
+    asks for, under a name the decode rooflines' pattern does not match,
+    and the pools still go in as bitcasts."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_example_tpu.ops import paged_chunk_attention
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    KV, Dh, bs, nblk, nb = 8, 128, 16, 4096, 512
+    pool = sd((nblk, bs, KV, Dh), jnp.bfloat16)
+    hlo = jax.jit(paged_chunk_attention).lower(
+        sd((1, L, heads, Dh), jnp.bfloat16), pool, pool,
+        sd((1, nb), jnp.int32), sd((1,), jnp.int32),
+    ).compile().as_text()
+    (call,) = _custom_calls(hlo)
+    assert "paged_chunk_attention" in call
+    assert "paged_decode_attention" not in call
+    pool_ops = [
+        line for line in hlo.splitlines()
+        if f" = bf16[{nblk},{bs * KV},{Dh}]" in line
+    ]
+    assert len(pool_ops) == 2 and all(" bitcast(" in l for l in pool_ops)
+
+
 @pytest.mark.parametrize("rows", [32, 128, 256, 512])
 def test_the_grouped_expert_kernel_compiles_and_keeps_its_scope(topo, monkeypatch, rows):
     """The sparse MLP as `serve_laguna_mixed_c32` runs it (256 experts of
@@ -318,3 +353,11 @@ def test_tp2_paged_decode_step_compiles_under_mesh(topo, monkeypatch):
         whole((1, M // bs), jnp.int32), whole((), jnp.int32),
     ).compile().as_text()
     assert len(_custom_calls(hlo)) == cfg.n_layers
+    # and a chunk of 16 tokens the chunk kernel, on one KV head a device
+    hlo = prefill_chunk.lower(
+        params_in, tree_in, whole((1, 16), jnp.int32),
+        whole((1, M // bs), jnp.int32), whole((), jnp.int32),
+    ).compile().as_text()
+    calls = _custom_calls(hlo)
+    assert len(calls) == cfg.n_layers
+    assert all("paged_chunk_attention" in line for line in calls)

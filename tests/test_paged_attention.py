@@ -1,8 +1,8 @@
-"""`ops.paged_decode_attention` (the Pallas kernel, interpreted on the
-CPU) against `ops.gather_paged_kv` + the dense einsum of
-`Attention._decode_paged`, to the tolerance contract stated at the
-kernel's definition: float32 reassociation in float32, bfloat16
-rounding of scores and probabilities in bfloat16."""
+"""`ops.paged_decode_attention` and `ops.paged_chunk_attention` (the
+Pallas kernels, interpreted on the CPU) against `ops.gather_paged_kv` +
+the dense einsum of `Attention._decode_paged`, to the tolerance contract
+stated at the kernels' definition: float32 reassociation in float32,
+bfloat16 rounding of scores and probabilities in bfloat16."""
 
 import functools
 
@@ -12,11 +12,16 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_example_tpu.models.transformer import (
+    _grouped_attention,
+    _position_mask,
+)
 from pytorch_distributed_example_tpu.ops import (
     gather_paged_kv,
     paged_attention,
+    paged_chunk_attention,
     paged_decode_attention,
-    paged_decode_ok,
+    paged_kernel,
     partitioned_over,
 )
 
@@ -260,34 +265,54 @@ def test_heads_that_do_not_divide_the_axis_run_replicated():
     assert np.abs(np.asarray(got - want)).max() <= TOL[jnp.float32]
 
 
-def _facts(L, Dh, bs, KV, dtype, rows=32, entries=512):
+def _facts(L, Dh, bs, KV, dtype, rows=32, entries=512, window=None):
     struct = jax.ShapeDtypeStruct
-    return L, struct((64, bs, KV, Dh), dtype), struct((rows, entries), jnp.int32)
+    return (L, struct((64, bs, KV, Dh), dtype),
+            struct((rows, entries), jnp.int32), window)
 
 
 OK_CASES = {
-    # name: ((L, Dh, bs, KV, pool dtype[, rows, table entries]), expected)
-    "mistral_decode": ((1, 128, 16, 8, jnp.bfloat16), True),
-    "float32_pool": ((1, 128, 16, 2, jnp.float32), True),
-    "wide_head": ((1, 256, 16, 8, jnp.bfloat16), True),
-    "prefill_chunk": ((512, 128, 16, 8, jnp.bfloat16), False),
-    "two_tokens": ((2, 128, 16, 8, jnp.bfloat16), False),
-    "tiny_head": ((1, 8, 4, 2, jnp.float32), False),
-    "head_dim_64": ((1, 64, 16, 8, jnp.bfloat16), False),
-    "int8_pool": ((1, 128, 16, 8, jnp.int8), False),
-    "half_tile_page": ((1, 128, 4, 2, jnp.bfloat16), False),
-    "one_tile_page_f32": ((1, 128, 4, 2, jnp.float32), True),
+    # name: ((L, Dh, bs, KV, pool dtype[, rows, table entries, window]),
+    #        the kernel that takes the call)
+    "mistral_decode": ((1, 128, 16, 8, jnp.bfloat16), "decode"),
+    "float32_pool": ((1, 128, 16, 2, jnp.float32), "decode"),
+    "wide_head": ((1, 256, 16, 8, jnp.bfloat16), "decode"),
+    "window_decode": ((1, 128, 16, 8, jnp.bfloat16, 32, 512, 512), "decode"),
+    "prefill_chunk": ((512, 128, 16, 8, jnp.bfloat16, 1), "chunk"),
+    "smallest_bucket": ((128, 128, 16, 8, jnp.bfloat16, 1), "chunk"),
+    "chunk_of_two_rows": ((256, 128, 16, 8, jnp.float32, 2), "chunk"),
+    "one_tile_chunk_f32": ((8, 128, 8, 2, jnp.float32, 1), "chunk"),
+    "half_tile_chunk_bf16": ((8, 128, 8, 2, jnp.bfloat16, 1), None),
+    "two_tokens": ((2, 128, 16, 8, jnp.bfloat16), None),
+    "not_whole_query_blocks": ((768, 128, 16, 8, jnp.bfloat16, 1), None),
+    "two_query_blocks": ((1024, 128, 16, 8, jnp.bfloat16, 1), "chunk"),
+    "window_chunk": ((512, 128, 16, 8, jnp.bfloat16, 1, 512, 512), None),
+    "float16_chunk": ((512, 128, 16, 8, jnp.float16, 1), None),
+    "float16_decode": ((1, 128, 16, 8, jnp.float16), "decode"),
+    "odd_bf16_heads_chunk": ((512, 128, 16, 3, jnp.bfloat16, 1), None),
+    "one_bf16_head_chunk": ((512, 128, 16, 1, jnp.bfloat16, 1), "chunk"),
+    "tiny_head": ((1, 8, 4, 2, jnp.float32), None),
+    "tiny_head_chunk": ((8, 8, 4, 2, jnp.float32, 1), None),
+    "head_dim_64": ((1, 64, 16, 8, jnp.bfloat16), None),
+    "head_dim_64_chunk": ((512, 64, 16, 8, jnp.bfloat16, 1), None),
+    "int8_pool": ((1, 128, 16, 8, jnp.int8), None),
+    "int8_pool_chunk": ((512, 128, 16, 8, jnp.int8, 1), None),
+    "half_tile_page": ((1, 128, 4, 2, jnp.bfloat16), None),
+    "half_tile_page_chunk": ((512, 128, 4, 2, jnp.bfloat16, 1), None),
+    "one_tile_page_f32": ((1, 128, 4, 2, jnp.float32), "decode"),
     # 128 x 1024 table entries are 512 KiB of scalar memory, 256 x 1024
     # the whole MiB (the v5e compiler refuses it: PERF.md, PR 25)
-    "tables_fit_smem": ((1, 128, 16, 8, jnp.bfloat16, 128, 1024), True),
-    "tables_exceed_smem": ((1, 128, 16, 8, jnp.bfloat16, 256, 1024), False),
+    "tables_fit_smem": ((1, 128, 16, 8, jnp.bfloat16, 128, 1024), "decode"),
+    "tables_exceed_smem": ((1, 128, 16, 8, jnp.bfloat16, 256, 1024), None),
+    "chunk_tables_fit_smem": ((512, 128, 16, 8, jnp.bfloat16, 128, 1024), "chunk"),
+    "chunk_tables_exceed_smem": ((512, 128, 16, 8, jnp.bfloat16, 256, 1024), None),
 }
 
 
 @pytest.mark.parametrize("case", list(OK_CASES))
 def test_the_predicate(case):
     facts, expected = OK_CASES[case]
-    assert paged_decode_ok(*_facts(*facts)) is expected
+    assert paged_kernel(*_facts(*facts)) == expected
 
 
 def test_the_predicate_sees_the_kv_heads_one_device_holds():
@@ -295,10 +320,14 @@ def test_the_predicate_sees_the_kv_heads_one_device_holds():
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
     facts = _facts(1, 128, 8, 2, jnp.bfloat16)  # 16 rows a page, 8 a shard
-    assert paged_decode_ok(*facts)
+    chunk = _facts(16, 128, 16, 6, jnp.bfloat16)  # 6 heads, 3 a shard
+    assert paged_kernel(*facts) == "decode"
+    assert paged_kernel(*chunk) == "chunk"
     with partitioned_over(mesh, (), ("tp",)):
-        assert not paged_decode_ok(*facts)
-        assert paged_decode_ok(*_facts(1, 128, 16, 2, jnp.bfloat16))
+        assert paged_kernel(*facts) is None
+        assert paged_kernel(*_facts(1, 128, 16, 2, jnp.bfloat16)) == "decode"
+        assert paged_kernel(*chunk) is None
+        assert paged_kernel(*_facts(16, 128, 16, 2, jnp.bfloat16)) == "chunk"
 
 
 def test_scale_defaults_to_the_head_size():
@@ -309,4 +338,200 @@ def test_scale_defaults_to_the_head_size():
     assert np.array_equal(
         np.asarray(paged_decode_attention(*a, interpret=True)),
         np.asarray(programs()[0](*a)),
+    )
+
+
+# -- the chunk kernel: L query tokens a row ---------------------------------
+
+
+def chunk_reference(q, pool_k, pool_v, tables, starts):
+    """The gather + einsum path of `_decode_paged` at L > 1, by its own
+    functions."""
+    B, L, H, _ = q.shape
+    kf, vf = gather_paged_kv(pool_k, pool_v, tables)
+    pos = starts[:, None] + jnp.arange(L)[None, :]
+    mask = _position_mask(pos, jnp.arange(kf.shape[1])[None], None)
+    return _grouped_attention(q, kf, vf, SCALE, mask).reshape(B, L, H, DH)
+
+
+@functools.lru_cache(maxsize=None)
+def chunk_programs():
+    kernel = jax.jit(
+        lambda *a: paged_chunk_attention(*a, SCALE, interpret=True)
+    )
+    return kernel, jax.jit(chunk_reference)
+
+
+@functools.lru_cache(maxsize=None)
+def chunk_operands(dtype, H, KV, B, L):
+    _, pool_k, pool_v = operands(dtype, H, KV)
+    q = jax.random.normal(jax.random.PRNGKey(H + L), (B, L, H, DH), dtype)
+    return q, pool_k, pool_v
+
+
+def chunk_tables(starts, L, seed=0, shuffled=True, tokens=None):
+    """Row b holds the pages of positions < starts[b] + tokens[b] (the
+    whole chunk unless a padded final chunk is asked for)."""
+    ends = [s + (L if tokens is None else tokens[b]) - 1
+            for b, s in enumerate(starts)]
+    return tables_for(ends, seed, shuffled)
+
+
+def chunk_check(dtype, H, KV, L, starts, tables, real=None):
+    """Kernel against reference on the rows (b, :real[b]) a prompt holds;
+    every row finite."""
+    kernel, reference = chunk_programs()
+    q, pool_k, pool_v = chunk_operands(dtype, H, KV, len(starts), L)
+    args = (q, pool_k, pool_v, jnp.asarray(tables),
+            jnp.asarray(starts, jnp.int32))
+    got = np.asarray(kernel(*args), np.float32)
+    want = np.asarray(reference(*args), np.float32)
+    assert got.shape == want.shape and np.isfinite(got).all()
+    for b in range(len(starts)):
+        n = L if real is None else real[b]
+        err = np.abs(got[b, :n] - want[b, :n]).max(initial=0.0)
+        assert err <= TOL[dtype], (err, b, starts)
+    return got
+
+
+KEYS = BLOCK  # the chunk kernel walks the same key blocks
+assert BS < KEYS < SPAN  # the cases below cross a page and a key block
+CHUNKS = {
+    # name: (H, KV, L, starts): rep 4 is 32/8 and 8/2, rep 6 is 48/8
+    "start_0": (8, 2, 128, [0, 0]),
+    "start_inside_a_page": (8, 2, 128, [37, BS + 1]),
+    "start_inside_a_key_block": (8, 2, 128, [KEYS - 200, 3 * BS]),
+    "chunk_crosses_a_key_block": (8, 2, 128, [KEYS - 40, KEYS - 127]),
+    "chunk_starts_at_a_key_block": (8, 2, 128, [KEYS, 0]),
+    "rows_at_different_depths": (32, 8, 128, [5, KEYS - 32]),
+    "rep_6_512": (48, 8, 512, [77]),
+    "one_query_block_rep_6": (48, 8, 256, [KEYS - 150]),
+    "three_buckets_512": (32, 8, 512, [100]),
+    "three_buckets_256": (32, 8, 256, [SPAN - 256]),
+}
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(CHUNKS))
+def test_chunk_matches_the_dense_path(dtype, case):
+    H, KV, L, starts = CHUNKS[case]
+    chunk_check(dtype, H, KV, L, starts, chunk_tables(starts, L, len(case)))
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_chunk_of_two_query_blocks(dtype):
+    """Longer than `CHUNK_QUERY_BLOCK` (an unchunked prefill's bucket):
+    the second grid step's queries start where the first's end, and the
+    first visits no key block past its own last query."""
+    L = 2 * paged_attention.CHUNK_QUERY_BLOCK
+    starts = [100]
+    assert SPAN < starts[0] + L <= NBLK * BS
+    tables = np.full((1, 2 * NB), NBLK, np.int32)
+    pages = -(-(starts[0] + L) // BS)
+    tables[0, :pages] = np.random.default_rng(5).permutation(NBLK)[:pages]
+    chunk_check(dtype, 8, 2, L, starts, tables)
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+@pytest.mark.parametrize("shuffled", [False, True], ids=["in_order", "shuffled"])
+def test_chunk_physical_block_order_does_not_matter(dtype, shuffled):
+    starts = [300, 17]
+    got = chunk_check(
+        dtype, 8, 2, 128, starts, chunk_tables(starts, 128, 3, shuffled)
+    )
+    assert np.abs(got).max() > 0.01
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_chunk_padded_past_the_prompt(dtype):
+    """The last chunk of a prompt is padded to its bucket and the table
+    holds the prompt's pages alone: the prompt's queries are exact, the
+    padded ones (whose own positions have no page) finite."""
+    starts, tokens = [KEYS - 60, 40], [70, 3]
+    tables = chunk_tables(starts, 128, seed=6, tokens=tokens)
+    assert (tables[0, (starts[0] + 70 - 1) // BS + 1:] == NBLK).all()
+    chunk_check(dtype, 8, 2, 128, starts, tables, real=tokens)
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_chunk_reads_no_page_past_the_chunk(dtype):
+    """Past the pages of `start + L` positions the table may hold stale
+    ids: poison every page the chunk does not need and nothing moves."""
+    starts, L = [KEYS - 100, 20], 128
+    tables = chunk_tables(starts, L, seed=8)
+    needed = tables[tables < NBLK]
+    tables[0, (starts[0] + L - 1) // BS + 1:] = 7  # stale ids behind it
+    tables[1, (starts[1] + L - 1) // BS + 2:] = 11  # a hole, then stale ids
+    q, pool_k, pool_v = chunk_operands(dtype, 8, 2, 2, L)
+    poison = np.ones((NBLK, 1, 1, 1), bool)
+    poison[needed] = False
+    args = (jnp.asarray(tables), jnp.asarray(starts, jnp.int32))
+    kernel, reference = chunk_programs()
+    got = kernel(q, jnp.where(poison, jnp.nan, pool_k),
+                 jnp.where(poison, jnp.nan, pool_v), *args)
+    want = reference(q, pool_k, pool_v, *args)
+    err = np.abs(np.asarray(got - want, np.float32)).max()
+    assert err <= TOL[dtype]
+
+
+def test_chunk_row_is_bounded_by_its_leading_valid_entries():
+    """A row with no valid page returns zeros; a hole inside the chunk's
+    span (the engine never makes one) ends the row there."""
+    starts, L = [200, 100], 128
+    tables = chunk_tables(starts, L, seed=2)
+    tables[0] = NBLK
+    hole = 9  # row 1 keeps positions < 9 * BS = 144 of its 228
+    tables[1, hole] = NBLK
+    kernel, reference = chunk_programs()
+    q, pool_k, pool_v = chunk_operands(jnp.float32, 8, 2, 2, L)
+    a = (jnp.asarray(tables), jnp.asarray(starts, jnp.int32))
+    got = np.asarray(kernel(q, pool_k, pool_v, *a))
+    assert (got[0] == 0).all() and np.isfinite(got).all()
+    want = np.asarray(reference(q, pool_k, pool_v, *a))
+    n = hole * BS - starts[1]  # queries whose every key is before the hole
+    assert np.abs(got[1, :n] - want[1, :n]).max() <= TOL[jnp.float32]
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_chunk_two_device_shard_map_on_kv_heads(dtype):
+    """As the decode kernel: per device on its KV-head shard (one head of
+    two here, so each shard's pages need no de-interleaving)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    starts, L = [KEYS - 70, 9], 128
+    tables = chunk_tables(starts, L, seed=4)
+    q, pool_k, pool_v = chunk_operands(dtype, 8, 2, 2, L)
+    put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
+    heads = P(None, None, "tp", None)
+    args = (
+        put(q, heads), put(pool_k, heads), put(pool_v, heads),
+        put(jnp.asarray(tables), P()), put(jnp.asarray(starts, jnp.int32), P()),
+    )
+
+    @jax.jit
+    def sharded(*a):
+        with partitioned_over(mesh, (), ("tp",)):
+            return paged_chunk_attention(*a, SCALE, interpret=True)
+
+    assert "shard_map" in str(jax.make_jaxpr(sharded)(*args))
+    got = sharded(*args)
+    assert got.sharding.spec == heads
+    plain = (q, pool_k, pool_v, jnp.asarray(tables),
+             jnp.asarray(starts, jnp.int32))
+    want = chunk_programs()[1](*plain)
+    assert np.abs(np.asarray(got - want, np.float32)).max() <= TOL[dtype]
+    one = chunk_programs()[0](*plain)
+    same = 1e-6 if dtype == jnp.float32 else TOL[dtype]
+    assert np.abs(np.asarray(got - one, np.float32)).max() <= same
+
+
+def test_chunk_scale_defaults_to_the_head_size():
+    starts, L = [33, 2], 128
+    q, pool_k, pool_v = chunk_operands(jnp.float32, 8, 2, 2, L)
+    a = (q, pool_k, pool_v, jnp.asarray(chunk_tables(starts, L)),
+         jnp.asarray(starts, jnp.int32))
+    assert np.array_equal(
+        np.asarray(paged_chunk_attention(*a, interpret=True)),
+        np.asarray(chunk_programs()[0](*a)),
     )

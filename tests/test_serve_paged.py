@@ -521,9 +521,10 @@ class TestTensorParallelDecode:
         assert "tp" in (q.sharding.spec[-1] or ())
 
 class TestDecodeKernel:
-    """The decode step at a head size `ops.paged_decode_ok` accepts
+    """The decode step at a head size `ops.paged_kernel` accepts
     (Dh = 128): the step program runs `ops.paged_decode_attention`
-    (interpreted here) while prefill chunks keep the gather + einsum."""
+    (interpreted here), and prefill chunks that fill a sublane tile
+    `ops.paged_chunk_attention`."""
 
     @staticmethod
     def _wide_model():
@@ -574,10 +575,56 @@ class TestDecodeKernel:
                 generate(model, params, jnp.asarray(p)[None], m)
             )[0]
             np.testing.assert_array_equal(np.asarray(out[r].tokens), ref)
-        decode = eng.metrics.snapshot()["decode"]
+        snap = eng.metrics.snapshot()
+        decode, prefill = snap["decode"], snap["prefill"]
         assert decode["steps"] > 0
         assert decode["kernel_steps"] == decode["steps"]
         assert decode["kernel_share"] == 1.0
+        # the 8-token chunks fill a float32 sublane tile and take the
+        # chunk kernel; a prompt's 4-token tail bucket fills none, and
+        # `ops.paged_kernel` leaves it to the gather + einsum
+        assert prefill["chunks"] > len(prompts)
+        assert 0.0 < prefill["kernel_share"] < 1.0
+
+    @pytest.mark.parametrize("engine", ["tp1", "tp2", "prefix_cache"])
+    def test_every_chunk_takes_the_chunk_kernel(self, no_fault_plan, engine):
+        """Buckets from 8 tokens up: every prefill chunk fills a sublane
+        tile, so every (chunk, layer) attention call is
+        `ops.paged_chunk_attention` — later chunks at a nonzero `start`,
+        under tp per device on its KV-head shard, and with the prefix
+        cache a FIRST chunk that starts behind another request's blocks.
+        Tokens stay `generate()`'s, exactly."""
+        import jax.numpy as jnp
+
+        from pytorch_distributed_example_tpu.models import generate
+        from pytorch_distributed_example_tpu.serve import ServeEngine
+
+        model, params = self._wide_model()
+        prompts = _prompts(21, 8, 30, 13)
+        if engine == "prefix_cache":
+            # the same 16 leading tokens: two whole blocks to share
+            prompts = [np.concatenate([prompts[2][:16], p]) for p in prompts]
+        eng = ServeEngine(
+            model, params, slots=2, min_bucket=8, block_size=8,
+            prefill_chunk_tokens=8, mesh=_tp_mesh(2) if engine == "tp2" else None,
+            prefix_cache=engine == "prefix_cache",
+        )
+        rids = [eng.submit(p, 6) for p in prompts]
+        out = eng.run(max_steps=500)
+        assert eng.metrics.completed == len(prompts)
+        for p, r in zip(prompts, rids):
+            ref = np.asarray(generate(model, params, jnp.asarray(p)[None], 6))
+            np.testing.assert_array_equal(np.asarray(out[r].tokens), ref[0])
+        snap = eng.metrics.snapshot()
+        prefill = snap["prefill"]
+        assert prefill["kernel_share"] == 1.0
+        assert prefill["kernel_calls"] == prefill["chunks"] * model.cfg.n_layers
+        whole = sum(-(-len(p) // 8) for p in prompts)
+        if engine == "prefix_cache":
+            assert snap["prefix_cache"]["hits"] > 0
+            assert prefill["chunks"] < whole  # shared blocks were not prefilled
+        else:
+            assert prefill["chunks"] == whole
 
     def test_tp2_one_token_chunks_run_the_kernel_per_device(
         self, no_fault_plan
@@ -677,9 +724,12 @@ class TestDecodeKernel:
             )
         eng.submit(_prompts(5)[0], 4)
         eng.run(max_steps=100)
-        decode = eng.metrics.snapshot()["decode"]
+        snap = eng.metrics.snapshot()
+        decode, prefill = snap["decode"], snap["prefill"]
         assert decode["steps"] > 0 and decode["kernel_steps"] == 0
         assert decode["kernel_share"] == 0.0
+        assert prefill["chunks"] > 0 and prefill["kernel_calls"] == 0
+        assert prefill["kernel_share"] == 0.0
 
 
 _TRAINED_CACHE = {}
