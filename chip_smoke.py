@@ -46,7 +46,7 @@ import traceback
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 PRESETS = {
-    # the widths of benchmarks/llama_scaled.py CFG_1B; see module docstring
+    # a ~1B Llama block's widths; see module docstring
     "chip": dict(
         mnist=dict(batch_per_chip=64, single_steps=24, fused_steps=8),
         lm=dict(
@@ -184,7 +184,7 @@ def _check_all_hold(label, tree, devices, max_fraction=None):
 
 def _lm(lm, n_layers, seed=0):
     """(model, bf16 params) at the preset's widths — the fit-on-one-chip
-    layout of benchmarks/llama_scaled.py: bf16 master weights."""
+    layout: bf16 master weights."""
     import jax
     import jax.numpy as jnp
 

@@ -95,8 +95,7 @@ class Trainer:
         # --steps-per-call K: K full optimizer steps fused (unrolled)
         # into one compiled program — identical math to K sequential
         # steps (tests/test_ddp.py pins it), host dispatch paid once per
-        # K. This is the mode behind the headline bench number; the
-        # single-step path still handles the epoch's ragged tail.
+        # K. The single-step path still handles the epoch's ragged tail.
         self.steps_per_call = steps_per_call
         if steps_per_call > 1:
             self.train_step_k = ddp.make_train_step(
@@ -243,7 +242,7 @@ def main():
                         "shared-memory return path (GIL-bound decode)")
     p.add_argument("--steps-per-call", type=int, default=1,
                    help="fuse K full optimizer steps into one compiled "
-                        "program (the headline-bench mode; math identical "
+                        "program (math identical "
                         "to K sequential steps)")
     p.add_argument("--quant-hook", action="store_true",
                    help="all-reduce gradients through the blockwise "

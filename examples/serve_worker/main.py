@@ -15,8 +15,7 @@ Launch under the agent (single node, elastic 1-3 workers):
         examples/serve_worker/main.py --slots 4
 
 then drive traffic/resizes from a controller process via
-`serve.worker.GangRouter` + `serve.worker.ElasticGangScaler` (or
-`benchmarks/load_harness.py --gang`).
+`serve.worker.GangRouter` + `serve.worker.ElasticGangScaler`.
 
 Pre-warm knobs: ``TDX_COMPILE_CACHE=<dir>`` points every incarnation
 at a shared persistent compilation cache and AOT-warms the engine's
@@ -25,8 +24,7 @@ cache read instead of a compile. ``TDX_PREWARM_DIR=<dir>`` goes
 further: the first incarnation to arrive serializes its compiled
 executables there, and every later incarnation (any gang width)
 restores them with the engine's ``precompiled=`` knob — no re-trace,
-no re-compile (`benchmarks/serve_resize.py` measures the difference;
->= 5x on the first token, ~40x on the CI model). ``TDX_SERVE_CPU=1``
+no re-compile. ``TDX_SERVE_CPU=1``
 pins a 1-device CPU backend.
 """
 

@@ -45,7 +45,7 @@ def enable_compile_cache(
     ``cache_dir``. Otherwise the cache lives at ``cache_dir`` if the
     caller names one (a gang-shared pre-warm directory), else at
     `COMPILE_CACHE_DIR`. Every entry point (examples, `chip_smoke.py`,
-    `bench.py`, the test harness, serve pre-warm) comes through here, so
+    the test harness, serve pre-warm) comes through here, so
     a machine that pins the variable gets one cache for all of them.
     `min_compile_secs` keeps trivial programs off the disk (JAX has no
     eviction). Scope paths and source locations are part of the cache key

@@ -11,8 +11,7 @@ the way torch's worker processes do, in one of two worker models:
 * ``worker_mode="process"``: real worker processes with a shared-memory
   return path (`worker_pool.py`) — torch's `num_workers` design
   (torch/utils/data/dataloader.py), for Python-heavy per-sample decode
-  that the GIL serializes in threads (measured ceiling 1.33x;
-  benchmarks/results.json loader_scaling). Deterministic dispatch and
+  that the GIL serializes in threads. Deterministic dispatch and
   per-(epoch, worker) seeding; `get_worker_info()` works inside
   workers.
 

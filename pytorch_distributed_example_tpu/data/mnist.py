@@ -4,7 +4,7 @@ The reference's mnist/main.py loads MNIST via torchvision [RECONSTRUCTED,
 SURVEY.md §2.0 E2]; torchvision is not in this environment (SURVEY.md §0),
 so this module reads the raw IDX files directly (same on-disk format
 torchvision downloads) and falls back to a deterministic synthetic set when
-no data directory is present (tests, benchmarks).
+no data directory is present (tests, examples, `chip_smoke.py`).
 
 Normalization matches the canonical torch MNIST example:
 mean 0.1307, std 0.3081.
@@ -72,7 +72,7 @@ class MNIST:
 
 
 def SyntheticMNIST(n: int = 4096, seed: int = 0, normalize: bool = True) -> MNIST:
-    """Deterministic fake MNIST (28×28 uint8, 10 classes) for tests/bench.
+    """Deterministic fake MNIST (28×28 uint8, 10 classes) for tests and smoke runs.
 
     Class-dependent structure so a ConvNet can actually fit it (loss falls).
     """

@@ -1,7 +1,6 @@
 """Process-based DataLoader workers with a shared-memory return path.
 
-Round-3 VERDICT #4: the thread pool's GIL ceiling is measured at 1.33x
-on Python-decode workloads (benchmarks/results.json: loader_scaling) —
+The GIL serializes Python-decode workloads in a thread pool —
 torch's DataLoader forks worker PROCESSES precisely to escape this
 (torch/utils/data/dataloader.py, the `num_workers` semantics the
 reference example relies on). This module is that design, tpu-shaped:

@@ -370,20 +370,16 @@ class Attention(nn.Module):
         length at index 0) and decode (L = 1) — static shapes throughout,
         so XLA compiles exactly two programs for the whole generate loop.
         cos/sin must cover max_seq_len; RoPE uses ABSOLUTE positions via a
-        dynamic slice at the cache index.
+        dynamic slice at the cache index. This is `generate()`'s cache and
+        the serve tests' token-exact reference.
 
-        `positions` ((B,) int32, optional) switches to PER-SAMPLE cache
-        indices: row b's K/V land at positions[b] and row b attends keys
-        <= its own position — the serve engine's slot batch, where every
-        row is an independent request at its own depth. The scalar cache
-        index is neither read nor advanced on this path (per-slot lengths
-        live with the caller).
-
-        `block_tables` ((B, nb) int32, requires `positions`) switches the
-        cache variables from per-row dense buffers to a PAGED block pool
-        shared by every row: k/v are (num_blocks, block_size, KV, Dh) and
+        `block_tables` ((B, nb) int32) with `positions` ((B,) int32)
+        switches the cache variables to the serving layout: a PAGED block
+        pool shared by every row, each row an independent request at its
+        own depth. k/v are (num_blocks, block_size, KV, Dh) and
         row b's logical block j lives at physical block
-        `block_tables[b, j]`. Writes scatter each token to
+        `block_tables[b, j]`; row b's K/V land at positions[b] and row b
+        attends keys <= its own position. Writes scatter each token to
         (block, offset) through a flat view — positions whose logical
         block is unallocated (table entry == num_blocks) or out of range
         fall out of bounds and are DROPPED, which is what lets a parked
@@ -392,7 +388,7 @@ class Attention(nn.Module):
         the row's logical layout under the same absolute-position causal
         mask, by one of the paths `_decode_paged` describes; there is
         no "index" variable on this path (the pool has no per-row
-        cursor).
+        cursor). Either argument without the other is refused.
 
         Returns the attention output (B, L, H * Dh), before the gate and
         `o_proj`. A window layer masks the same buffers by
@@ -426,6 +422,12 @@ class Attention(nn.Module):
             return self._decode_paged(
                 q, k, v, cos, sin, scale, positions, block_tables
             )
+        if positions is not None:
+            raise ValueError(
+                "positions without block_tables: per-row cache positions "
+                "exist only on the paged pool (pass block_tables=); the "
+                "dense cache keeps one scalar index"
+            )
         ck = self.variable(
             "cache", "k", jnp.zeros, (B, M, KV, Dh), k.dtype
         )
@@ -436,39 +438,21 @@ class Attention(nn.Module):
             "cache", "index", lambda: jnp.zeros((), jnp.int32)
         )
         key_pos = jnp.arange(M)
-        if positions is None:
-            idx = ci.value
-            pos_cos = lax.dynamic_slice_in_dim(cos, idx, L, axis=0)
-            pos_sin = lax.dynamic_slice_in_dim(sin, idx, L, axis=0)
-            q = apply_rope(q, pos_cos, pos_sin, halves)
-            k = apply_rope(k, pos_cos, pos_sin, halves)
-            with jax.named_scope("kv_scatter"):
-                kf = lax.dynamic_update_slice_in_dim(ck.value, k, idx, axis=1)
-                vf = lax.dynamic_update_slice_in_dim(cv.value, v, idx, axis=1)
-            if is_initialized:
-                ck.value = kf
-                cv.value = vf
-                ci.value = idx + L
-            q_pos = idx + jnp.arange(L)
-            # causal over cache; (1, L, M) broadcast over batch
-            mask = _position_mask(q_pos, key_pos, self.window)[None]
-        else:
-            idx = positions.astype(jnp.int32)  # (B,)
-            pos = idx[:, None] + jnp.arange(L)[None, :]  # (B, L) absolute
-            q = apply_rope_batched(q, cos[pos], sin[pos], halves)
-            k = apply_rope_batched(k, cos[pos], sin[pos], halves)
-            write = jax.vmap(
-                lambda buf, upd, i: lax.dynamic_update_slice_in_dim(
-                    buf, upd, i, axis=0
-                )
-            )
-            with jax.named_scope("kv_scatter"):
-                kf = write(ck.value, k, idx)
-                vf = write(cv.value, v, idx)
-            if is_initialized:
-                ck.value = kf
-                cv.value = vf
-            mask = _position_mask(pos, key_pos[None], self.window)  # (B, L, M)
+        idx = ci.value
+        pos_cos = lax.dynamic_slice_in_dim(cos, idx, L, axis=0)
+        pos_sin = lax.dynamic_slice_in_dim(sin, idx, L, axis=0)
+        q = apply_rope(q, pos_cos, pos_sin, halves)
+        k = apply_rope(k, pos_cos, pos_sin, halves)
+        with jax.named_scope("kv_scatter"):
+            kf = lax.dynamic_update_slice_in_dim(ck.value, k, idx, axis=1)
+            vf = lax.dynamic_update_slice_in_dim(cv.value, v, idx, axis=1)
+        if is_initialized:
+            ck.value = kf
+            cv.value = vf
+            ci.value = idx + L
+        q_pos = idx + jnp.arange(L)
+        # causal over cache; (1, L, M) broadcast over batch
+        mask = _position_mask(q_pos, key_pos, self.window)[None]
         # GQA: group the query heads and attend against the UN-repeated
         # cache — repeating the (B, M, KV, Dh) buffers up to H heads per
         # step would forfeit the KV-cache bandwidth saving GQA exists for
@@ -478,7 +462,7 @@ class Attention(nn.Module):
     def _decode_paged(
         self, q, k, v, cos, sin, scale, positions, block_tables
     ):
-        """Paged-pool variant of the per-sample decode path (see _decode).
+        """The paged-pool cache step (see _decode).
 
         The cache collection holds ONE (num_blocks, block_size, KV, Dh)
         K/V pool shared by all B rows; `block_tables` (B, nb) maps each
@@ -797,14 +781,12 @@ class TransformerLM(nn.Module):
         `decode=True` switches attention to the KV-cache path (flax
         "cache" collection; apply with `mutable=["cache"]`): call once
         with the prompt (prefill), then with one token at a time —
-        `models/generate.py` wraps the loop. `positions` ((B,) int32)
-        selects PER-SAMPLE cache indices instead of the shared scalar
-        index — the serve engine's slot-batch decode (`serve/`), where
-        each row advances from its own depth. `block_tables` ((B, nb)
-        int32, with `positions`) additionally switches the cache to the
+        `models/generate.py` wraps the loop. `block_tables` ((B, nb)
+        int32) with `positions` ((B,) int32) switches the cache to the
         serve engine's PAGED block pool (`serve/cache.py`): one
         (num_blocks, block_size, kv_heads, head_dim) K/V pool per layer
-        shared by all rows, indexed through per-row block tables.
+        shared by all rows, indexed through per-row block tables, each
+        row advancing from its own depth `positions[b]`.
 
         A model with a layer pattern (`cfg.layers`) builds each block
         from its `LayerSpec` and hands it the rope table of its own

@@ -58,7 +58,7 @@ def _interpret_default() -> bool:
     On a TPU backend the answer is always "compile": no setting reaches
     the interpreter there. `TDX_FLASH_INTERPRET=0` exists for one case
     only — AOT-compiling for a DEVICELESS TPU topology from a CPU-pinned
-    process (`benchmarks/tpu_aot_check.py`), where the backend is not
+    process (`tests/test_aot_topology.py`), where the backend is not
     "tpu" but the target is.
     """
     import os
@@ -672,12 +672,14 @@ flash_with_lse.defvjp(_fwl_fwd, _fwl_bwd)
 
 @functools.lru_cache(maxsize=1)
 def _tuned_table() -> dict:
-    """Checked-in block-size tuning table, measured on real TPU hardware
-    by `benchmarks/flash_bench.py` and baked by
-    `benchmarks/bake_flash_defaults.py` (the cuDNN-heuristic pattern:
-    sweep once per geometry on hardware, ship the winners). Keys are
-    "L{seq}" plus "default". The file is tracked, so one that is missing
-    or does not parse is a broken checkout and raises."""
+    """Checked-in block-size table (`flash_tuned.json`): per-geometry
+    winners of a sweep on an earlier machine, whose generator is no
+    longer in the tree. Keys are "L{seq}" plus "default"; there is no
+    row for seq 4096, the only length a benchmark cell runs, so the
+    train cells take "default" (ROADMAP S8 re-derives block sizes
+    against `flash_roofline` / `flash_time_pct`). The file is tracked,
+    so one that is missing or does not parse is a broken checkout and
+    raises."""
     import json
     import os
 
@@ -823,8 +825,7 @@ def flash_attention(
     """Flash attention over (B, L, H, D) tensors; differentiable.
 
     Block sizes default to 128 (one MXU tile) and can be overridden per
-    call or fleet-wide via `TDX_FLASH_BLOCK_Q` / `TDX_FLASH_BLOCK_K` —
-    `benchmarks/flash_bench.py` sweeps them on real hardware.
+    call or fleet-wide via `TDX_FLASH_BLOCK_Q` / `TDX_FLASH_BLOCK_K`.
 
     Constraints: L divisible by block sizes (pad upstream). Sequence
     length is otherwise unbounded: past ~L·D·itemsize ≈ 3 MB per

@@ -152,8 +152,7 @@ def allreduce_wire_bytes(
     n: int, world: int, wire: Optional[str], block_size: int = DEFAULT_BLOCK_SIZE
 ) -> int:
     """Per-rank wire bytes one all-reduce of n elements moves under the
-    ring model (2 (W-1)/W traffic): the analytic accounting the
-    `allreduce_bw.py --op quant` rows report next to wall time. `wire`
+    ring model (2 (W-1)/W traffic), computed from shapes. `wire`
     None/'f32' = 4-byte, 'bf16' = 2-byte dense; quantized formats pay
     `wire_itemsize` per element plus 4 bytes per block of scale in both
     phases."""
@@ -280,7 +279,7 @@ def quantized_all_reduce(
     "tolerance",
     note="per-(token, kv-head) int8 KV round-trip: |dq - x| <= vector "
     "amax / qmax (PR 11; token-match-rate claims live on the serve "
-    "plane, see benchmarks/serve_bench.py)",
+    "plane, see tests/test_serve_paged.py)",
 )
 def quantize_kv(x, bits: int = 8):
     """Quantize K/V vectors for the paged cache: x (..., Dh) ->

@@ -606,14 +606,11 @@ def make_ddp_train_step(
             # sequential schedule — XLA just never returns to the host
             # in between.
             # unroll_steps inlines all K bodies as a python loop with
-            # STATIC input slices — measured on the sub-ms ConvNet step
-            # (benchmarks/scan_overhead_probe.py): looped scan 14.6
-            # ms/step vs 0.69 manually unrolled vs 4.3 per-dispatch.
-            # scan's per-iteration machinery (dynamic slicing, carry
-            # shuffling) dwarfs small bodies — and lax.scan(unroll=K)
-            # keeps that machinery, measured at ~4.5 ms/step, so the
-            # unroll here is a real python loop. Big bodies (the ~0.5 s
-            # 1B step) amortize the loop and save compile time looped.
+            # STATIC input slices: scan's per-iteration machinery
+            # (dynamic slicing, carry shuffling) dwarfs a sub-ms body,
+            # and lax.scan(unroll=K) keeps that machinery, so the
+            # unroll here is a real python loop. Big bodies amortize
+            # the loop and save compile time looped.
             if unroll_steps:
                 import jax.numpy as jnp
 

@@ -305,7 +305,7 @@ def make_fsdp_train_step(
     sharded params — the constraint makes that a contract instead of a
     propagation accident) and attaches `step.init_opt_state(params)`.
     "off" constrains the state REPLICATED — the world-x-redundant
-    baseline the memory bench A/Bs against.
+    baseline.
     """
     import jax
     from jax.sharding import NamedSharding
@@ -448,7 +448,7 @@ def make_zero2_train_step(
     described above, with the opt-in `shard_optimizer_only` placement
     internalized as `step.init_opt_state(params)`; "off" reverts to the
     replicated update (grads all-reduced, state replicated — a GSPMD
-    DDP step, the memory bench's baseline).
+    DDP step).
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -262,8 +262,8 @@ class Reducer:
     def _fused_prog(self, idx_list, leaves):
         """ONE jitted program per bucket spec: pack, mean-allreduce, and
         unpack in a single XLA dispatch (vs the generic path's
-        concat + backend allreduce + per-leaf slice chain — measured
-        8-30x dispatch tax in benchmarks/reducer_bench.py). The psum
+        concat + backend allreduce + per-leaf slice chain, one
+        dispatch each). The psum
         still lowers to the same ICI collective; XLA fuses the
         pack/unpack copies around it."""
         import jax

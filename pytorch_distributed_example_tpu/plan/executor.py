@@ -45,7 +45,7 @@ _ENV_PIPE = "TDX_PLAN_PIPELINE_CHUNKS"
 
 def default_pipeline_chunks() -> int:
     """Sub-chunk count for pipelined rounds (the "ring_pipe" execution
-    variant); >= 2 to overlap, env-tunable for the bench A/B."""
+    variant); >= 2 to overlap, env-tunable for an A/B."""
     try:
         return max(2, int(os.environ.get(_ENV_PIPE, "4")))
     except ValueError:

@@ -12,8 +12,7 @@ operators):
 
 * `TDX_PLANNER_PROBE_CACHE=<path>` points the artifact somewhere else;
   setting it to the EMPTY string disables persistence (probe every
-  process, write nothing) — the `--no-probe-cache` bench flag sets
-  exactly this;
+  process, write nothing);
 * a cache file whose recorded topology keys no longer include the live
   gang's key warns ONCE per process (the table is stale for this
   topology — e.g. the gang grew, or moved from CPU to TPU) and fresh
@@ -45,8 +44,7 @@ _MIN_BUCKET = 1 << 10
 
 
 def bucket_bytes(nbytes: int) -> int:
-    """Power-of-4 size bucket (ceiling), floored at 1 KB — matches the
-    bench sweep's ×4 size ladder so probe rows and bench rows align."""
+    """Power-of-4 size bucket (ceiling), floored at 1 KB."""
     b = _MIN_BUCKET
     n = max(int(nbytes), 1)
     while b < n:

@@ -9,9 +9,8 @@ preemption and optional tensor-parallel placement over a device mesh
 (`engine.py`), a bounded request queue with explicit shed (`queue.py`),
 bucketed prefill shapes (`bucketing.py`), and a metrics block — cache-
 pool utilization included — exposed over the debug HTTP frontend
-(`metrics.py`). `benchmarks/serve_bench.py` measures goodput vs a
-static-batch baseline, paged-vs-dense cache memory per request, chunked
-vs unchunked long-prompt-burst TTFT, and 1→N-chip TP goodput scaling.
+(`metrics.py`). The serve cells of `bench_matrix/` measure it on the
+chip.
 
 Prefix sharing (ISSUE 12): the pool's physical blocks are refcounted
 with copy-on-write divergence (`cache.py`), and a radix prefix index
@@ -19,8 +18,7 @@ with copy-on-write divergence (`cache.py`), and a radix prefix index
 already-filled blocks — admission attaches them by reference and
 prefill starts at the first uncached position, so TTFT and pool bytes
 scale with UNIQUE tokens. Cross-tenant sharing is opt-in per
-`ClassSpec.share_prefix`; `benchmarks/serve_prefix.py` is the
-shared-preamble TTFT/pool-bytes row.
+`ClassSpec.share_prefix`.
 
 Multi-tenant + elastic (ROADMAP item 5): priority classes with
 weighted admission, class-ordered overload shedding and cross-class
@@ -39,18 +37,17 @@ pool pressure (`metrics.py::window_view`) and drives drain-backed
 scale-out/scale-in with hysteresis bands, breach streaks, cooldowns,
 and a max-step clamp — every decision logged with the metric view
 that justified it, `TDX_AUTOSCALE_FORCE` for operators.
-`benchmarks/load_harness.py` is the 10-100x open-loop proof.
 """
 
 from .bucketing import bucket_for, bucket_lengths  # noqa: F401
 from .cache import (  # noqa: F401
     PagedKVCache,
-    SlotKVCache,
     init_paged_cache,
 )
 from .decode import (  # noqa: F401
+    carry_key,
+    kernel_layers,
     paged_programs,
-    slot_programs,
     sync_slot_lanes,
 )
 from .elastic import (  # noqa: F401

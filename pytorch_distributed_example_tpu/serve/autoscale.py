@@ -64,7 +64,7 @@ _TRANSIENT = (ConnectionResetError, faults.FaultTimeout)
 
 @dataclass(frozen=True)
 class AutoscalePolicy:
-    """Controller knobs. Defaults are the bench's diurnal-swing tuning;
+    """Controller knobs. Defaults were tuned on a 10x diurnal swing;
     real deployments should size the window to a few multiples of the
     target class's TTFT SLO."""
 
@@ -282,7 +282,7 @@ class Autoscaler:
     # -- the loop body -----------------------------------------------------
     def poll(self) -> Decision:
         """One control iteration: read the merged window, decide, act.
-        Call it from the serve loop every poll interval (the bench uses
+        Call it from the serve loop every poll interval (tests use
         a virtual clock; real loops use wall time). Transient chaos
         faults at the scale seams abort the resize cleanly — the
         decision records the abort and the next poll retries."""

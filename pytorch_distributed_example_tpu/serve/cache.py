@@ -1,63 +1,56 @@
-"""KV cache memory managers for the serve engine.
+"""KV cache memory manager for the serve engine.
 
-Two layouts live here:
+`PagedKVCache` is THE engine's cache (`serve/engine.py`): a fixed pool
+of ``(num_blocks, block_size, kv_heads, head_dim)`` K/V blocks per
+layer plus a per-slot block table mapping logical blocks to physical
+ones. Blocks are allocated ON WRITE (as prefill chunks land and as
+decode crosses block boundaries) and freed at retire, so cache memory
+per request tracks LIVE tokens — not ``slots x max_seq_len`` the way
+a dense per-slot layout would. Entries equal to ``num_blocks`` mark
+unallocated logical blocks; the paged attention path
+(`models/transformer.py::_decode_paged`) turns writes through them
+into out-of-bounds scatter drops, which is how parked lanes and
+padded chunks stay harmless. The pool is exhaustible by design: a
+failed `ensure_blocks` is the engine's backpressure/preemption
+signal. `quantized=True` stores K/V as INT8 with per-(token,
+kv-head) f32 scales (`ops/quant.py`) — ~(4 / (1 + 4/head_dim))x
+more blocks at fixed pool bytes, quantize-on-scatter in the paged
+write, dequant inside `ops.gather_paged_kv` so attention math stays
+full precision.
 
-* `PagedKVCache` — THE engine's cache (`serve/engine.py`): a fixed pool
-  of ``(num_blocks, block_size, kv_heads, head_dim)`` K/V blocks per
-  layer plus a per-slot block table mapping logical blocks to physical
-  ones. Blocks are allocated ON WRITE (as prefill chunks land and as
-  decode crosses block boundaries) and freed at retire, so cache memory
-  per request tracks LIVE tokens — not ``slots x max_seq_len`` the way
-  the dense layout does. Entries equal to ``num_blocks`` mark
-  unallocated logical blocks; the paged attention path
-  (`models/transformer.py::_decode_paged`) turns writes through them
-  into out-of-bounds scatter drops, which is how parked lanes and
-  padded chunks stay harmless. The pool is exhaustible by design: a
-  failed `ensure_blocks` is the engine's backpressure/preemption
-  signal. `quantized=True` stores K/V as INT8 with per-(token,
-  kv-head) f32 scales (`ops/quant.py`) — ~(4 / (1 + 4/head_dim))x
-  more blocks at fixed pool bytes, quantize-on-scatter in the paged
-  write, dequant inside `ops.gather_paged_kv` so attention math stays
-  full precision.
+TWO KINDS of K/V state (a model whose `cfg.window_layers` marks some
+layers as keeping a window): the full layers share the pool and the
+tables described above; the WINDOW layers share a second, small pool
+(`window_num_blocks` blocks, sized by the manager from the slots, the
+window, the prefill chunk and the block size: a row never holds more
+than `window_blocks_per_slot`) under a second table of the same
+logical shape. A window-layer block wholly behind `position - window`
+goes back to the window free list WHILE the request runs
+(`ensure_blocks(..., first_pos=)`), its table entry turns invalid, and
+the attention paths never read before a row's first attended block.
+`tables()` hands the programs the pair; `free`, `bytes_live` and the
+live-block counts account both kinds. A model with no window layer
+gets exactly the single-kind tree, tables and accounting.
 
-  TWO KINDS of K/V state (a model whose `cfg.window_layers` marks some
-  layers as keeping a window): the full layers share the pool and the
-  tables described above; the WINDOW layers share a second, small pool
-  (`window_num_blocks` blocks, sized by the manager from the slots, the
-  window, the prefill chunk and the block size: a row never holds more
-  than `window_blocks_per_slot`) under a second table of the same
-  logical shape. A window-layer block wholly behind `position - window`
-  goes back to the window free list WHILE the request runs
-  (`ensure_blocks(..., first_pos=)`), its table entry turns invalid, and
-  the attention paths never read before a row's first attended block.
-  `tables()` hands the programs the pair; `free`, `bytes_live` and the
-  live-block counts account both kinds. A model with no window layer
-  gets exactly the single-kind tree, tables and accounting.
+Physical blocks are REFCOUNTED (ISSUE 12): `attach_prefix` lets a
+slot reference blocks another request already filled (the prefix
+cache, `serve/prefix.py`), `free()` DECREMENTS instead of releasing
+(a block returns to the reusable set only when its last reference
+drops), and writes go copy-on-write — `cow_block(slot, pos)` copies
+a block (pool K/V AND the int8 scale planes, one jitted
+gather/scatter per layer tree) before the slot may write into it
+while it is shared (refcount > 1) or pinned by a prefix-index entry.
+Shared physical blocks are counted ONCE everywhere (`live_blocks`,
+`bytes_live`, `pool_utilization`); `bytes_deduplicated` is the pool
+memory sharing saves vs a no-sharing layout. Blocks whose refcount
+hits zero while a prefix-index entry still names them move to a
+CACHED free list: they stay reclaimable (counted in `free_blocks`,
+handed out LRU after the plain free list drains, invalidating their
+index entry through `evict_hook`) but keep their content until then,
+which is what lets a retired request's prompt prefix serve later
+identical prompts for free.
 
-  Physical blocks are REFCOUNTED (ISSUE 12): `attach_prefix` lets a
-  slot reference blocks another request already filled (the prefix
-  cache, `serve/prefix.py`), `free()` DECREMENTS instead of releasing
-  (a block returns to the reusable set only when its last reference
-  drops), and writes go copy-on-write — `cow_block(slot, pos)` copies
-  a block (pool K/V AND the int8 scale planes, one jitted
-  gather/scatter per layer tree) before the slot may write into it
-  while it is shared (refcount > 1) or pinned by a prefix-index entry.
-  Shared physical blocks are counted ONCE everywhere (`live_blocks`,
-  `bytes_live`, `pool_utilization`); `bytes_deduplicated` is the pool
-  memory sharing saves vs a no-sharing layout. Blocks whose refcount
-  hits zero while a prefix-index entry still names them move to a
-  CACHED free list: they stay reclaimable (counted in `free_blocks`,
-  handed out LRU after the plain free list drains, invalidating their
-  index entry through `evict_hook`) but keep their content until then,
-  which is what lets a retired request's prompt prefix serve later
-  identical prompts for free.
-
-* `SlotKVCache` — the PR 4 dense per-slot layout, kept as the
-  reference/baseline the bench and the parity tests compare against:
-  one ``(slots, max_seq_len, kv_heads, head_dim)`` buffer per layer,
-  whole-buffer prefill-into-slot.
-
-Both keep per-slot lengths host-side and reuse/replace their device
+The manager keeps per-slot lengths host-side and replaces its device
 tree functionally — callers own exactly one live version.
 """
 
@@ -69,40 +62,10 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ..models.generate import init_cache
-
 __all__ = [
-    "SlotKVCache",
     "PagedKVCache",
     "init_paged_cache",
-    "land_slot",
 ]
-
-
-def land_slot(tree, pre, slot):
-    """Pure slot landing: write a B=1 cache tree `pre` into slot `slot`
-    of the slot tree (full-buffer overwrite). Scalar flax `index` leaves
-    pass through untouched (per-slot lengths live with the caller, not
-    in the tree). The ONE copy of this logic — `write_prefill` jits it
-    standalone and `serve/decode.py`'s fused `write_slot` traces it
-    inside the donated state-lane write."""
-    import jax
-    from jax import lax
-
-    def leaf(buf, upd):
-        if buf.ndim == 0:
-            return buf
-        return lax.dynamic_update_slice_in_dim(buf, upd, slot, axis=0)
-
-    return jax.tree_util.tree_map(leaf, tree, pre)
-
-
-@functools.lru_cache(maxsize=8)
-def _write_slot_fn():
-    """Jitted standalone `land_slot` (compiles once per tree shapes)."""
-    import jax
-
-    return jax.jit(land_slot)
 
 
 @functools.lru_cache(maxsize=8)
@@ -153,80 +116,6 @@ def _import_blocks_fn():
         return jax.tree_util.tree_map(leaf, tree, payload)
 
     return jax.jit(imp, donate_argnums=(0,))
-
-
-class SlotKVCache:
-    """Slot-managed KV cache over `model`'s decode path.
-
-    `tree` is the live flax cache tree ((slots, M, KV, Dh) K/V per
-    layer); `lengths` is the host-side per-slot position vector (how
-    many cache positions are valid — also the position the NEXT token
-    will be written at). Free slots keep length 0.
-    """
-
-    def __init__(self, model, slots: int):
-        if slots < 1:
-            raise ValueError(f"slots must be >= 1, got {slots}")
-        self.model = model
-        self.slots = slots
-        self.tree = init_cache(model, slots)
-        self.lengths = np.zeros((slots,), np.int32)
-        self._in_use = np.zeros((slots,), bool)
-        self._free: List[int] = list(range(slots))
-
-    # -- slot lifecycle ----------------------------------------------------
-    def allocate(self) -> Optional[int]:
-        """A free slot index, or None when the cache is full."""
-        if not self._free:
-            return None
-        s = self._free.pop(0)
-        self._in_use[s] = True
-        return s
-
-    def free(self, slot: int) -> None:
-        if not self._in_use[slot]:
-            raise ValueError(f"slot {slot} is not allocated")
-        self._in_use[slot] = False
-        self.lengths[slot] = 0
-        self._free.append(slot)
-
-    def reset(self) -> None:
-        """Free every slot. The device buffers are NOT cleared — a
-        prefill overwrites a slot's full buffer before reuse, so stale
-        K/V is unreachable by construction."""
-        self._in_use[:] = False
-        self.lengths[:] = 0
-        self._free = list(range(self.slots))
-
-    # -- data plane --------------------------------------------------------
-    def write_prefill(self, slot: int, prefill_tree, length: int) -> None:
-        """Land a B=1 prefill cache into `slot` (full-buffer overwrite)
-        and set its length. One compiled program for every slot/request."""
-        if not self._in_use[slot]:
-            raise ValueError(f"slot {slot} is not allocated")
-        if not 0 < length <= self.model.cfg.max_seq_len:
-            raise ValueError(
-                f"prefill length {length} outside (0, "
-                f"{self.model.cfg.max_seq_len}]"
-            )
-        self.tree = _write_slot_fn()(self.tree, prefill_tree, slot)
-        self.lengths[slot] = length
-
-    # -- introspection -----------------------------------------------------
-    @property
-    def active_slots(self) -> List[int]:
-        return [s for s in range(self.slots) if self._in_use[s]]
-
-    @property
-    def occupancy(self) -> float:
-        return float(self._in_use.sum()) / self.slots
-
-    def __repr__(self) -> str:
-        return (
-            f"SlotKVCache(slots={self.slots}, "
-            f"active={int(self._in_use.sum())}, "
-            f"lengths={self.lengths.tolist()})"
-        )
 
 
 def window_layers_of(cfg) -> tuple:

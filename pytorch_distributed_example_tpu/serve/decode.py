@@ -1,33 +1,17 @@
-"""Batched slot decode + bucketed prefill — the serve engine's compiled
+"""Batched slot decode + chunked prefill — the serve engine's compiled
 programs, refactored out of `models/generate.py`'s run-to-completion
 loop into a continuous-batching step.
 
 Hot-path discipline (this is what lets the per-token step compete with
-`generate()`'s fused scan): ALL mutable serving state — the slot KV
-cache tree plus the per-slot (lengths, last-token, rng-key) vectors —
+`generate()`'s fused scan): ALL mutable serving state — the paged K/V
+pool tree plus the per-slot (lengths, last-token, rng-key) vectors —
 lives on DEVICE and is buffer-DONATED through every step, so the
-multi-MB cache is updated in place instead of memcpy'd per token; the
-only host traffic per step is the one (S,) next-token readback the
+multi-GB pool is updated in place instead of memcpy'd per token; the
+only host traffic per step is the one next-token readback the
 scheduler genuinely needs for EOS/budget retirement. Programs are
-cached per (model, sampling knobs) exactly like `generate._programs`
-(flax Modules are frozen dataclasses — hashable, equal by config).
-
-* ``prefill(params, prompt (1, Lb), length, seed)`` — whole-prompt pass
-  through a fresh B=1 cache; compiles once per BUCKET length Lb
-  (`serve/bucketing.py`). Builds the request's sampling stream from
-  `seed` on device, samples the first token, and returns
-  ``(cache, first_logits (V,), first_token, carry_key)`` — the logits
-  row is taken at the TRUE prompt end, so padding never leaks.
-* ``write_slot(tree, lengths, tokens, rngs, pre, slot, length, first,
-  key)`` — land the prefill into slot `slot` (full-buffer overwrite)
-  and set that slot's state lanes; tree+state donated.
-* ``step(params, cache, lengths, tokens, rngs)`` — advance EVERY slot
-  one token: per-slot absolute positions (`positions=` decode path in
-  `models/transformer.py`), per-slot causal masks over the slot cache,
-  per-slot sampling RNG (vmapped key split). Compiles ONCE for the
-  engine's lifetime; retired slots ride along as masked lanes (their
-  lengths park at max_seq_len-1, beyond any live request's last write)
-  until a prefill reclaims them.
+cached per (model, sampling knobs, mesh) exactly like
+`generate._programs` (flax Modules are frozen dataclasses — hashable,
+equal by config). `paged_programs` describes the quadruple.
 """
 
 from __future__ import annotations
@@ -37,11 +21,9 @@ import functools
 import os
 from typing import Optional
 
-from ..models.generate import init_cache, sample_logits
-from .cache import land_slot
+from ..models.generate import sample_logits
 
 __all__ = [
-    "slot_programs",
     "paged_programs",
     "kernel_layers",
     "sync_slot_lanes",
@@ -137,85 +119,6 @@ def sync_slot_lanes(lengths, tokens, rngs):
 
 
 @functools.lru_cache(maxsize=32)
-def slot_programs(model, temperature: float, top_k: Optional[int]):
-    """(prefill, write_slot, step) jitted triple for `model` at the
-    given sampling knobs."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    M = model.cfg.max_seq_len
-
-    @jax.jit
-    def prefill(params, prompt, length, seed):
-        cache = init_cache(model, 1)
-        logits, vars2 = model.apply(
-            {"params": params, "cache": cache}, prompt, decode=True,
-            mutable=["cache"],
-        )
-        first_logits = lax.dynamic_index_in_dim(
-            logits[0], length - 1, axis=0, keepdims=False
-        )
-        # per-request stream off the seed, one split consumed by the
-        # first sample — mirrors generate()'s prefill rng discipline
-        with jax.named_scope("sample"):
-            key = jax.random.PRNGKey(seed)
-            key, sub = jax.random.split(key)
-            first = sample_logits(
-                first_logits[None], sub, temperature, top_k
-            )[0]
-        return vars2["cache"], first_logits, first, key
-
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-    def write_slot(tree, lengths, tokens, rngs, pre, slot, length, first, key):
-        tree = land_slot(tree, pre, slot)
-        return (
-            tree,
-            lengths.at[slot].set(length),
-            tokens.at[slot].set(first),
-            rngs.at[slot].set(key),
-        )
-
-    @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4))
-    def step(params, cache, lengths, tokens, rngs):
-        """One continuous-batching decode step over all S slots.
-
-        lengths: (S,) int32 — each slot's current depth (= write
-        position for this step's token); tokens: (S,) int32 — each
-        slot's last emitted token; rngs: (S, 2) uint32 per-slot keys.
-        Returns (cache', lengths', next_tokens (S,), rngs').
-        """
-        with jax.named_scope("sample"):
-            split = jax.vmap(jax.random.split)(rngs)  # (S, 2, 2)
-            subs, new_rngs = split[:, 0], split[:, 1]
-        logits, vars2 = model.apply(
-            {"params": params, "cache": cache}, tokens[:, None],
-            decode=True, positions=lengths, mutable=["cache"],
-        )
-        lg = logits[:, -1]  # (S, V)
-        # sample_logits branches on the Python temperature at trace time
-        # (greedy at 0.0, keys trace away), so one vmap covers both modes
-        with jax.named_scope("sample"):
-            nxt = jax.vmap(
-                lambda row, key: sample_logits(row, key, temperature, top_k)
-            )(lg, subs)
-        # clamp: a retired slot's lane keeps stepping until backfilled;
-        # parking it at M-1 keeps its garbage writes in-bounds and off
-        # any live request's positions (live writes end at <= M-2, the
-        # submit-time budget check)
-        return (
-            vars2["cache"],
-            jnp.minimum(lengths + 1, M - 1),
-            nxt,
-            new_rngs,
-        )
-
-    return _register_programs(
-        "slot", prefill=prefill, write_slot=write_slot, step=step
-    )
-
-
-@functools.lru_cache(maxsize=32)
 def paged_programs(
     model, temperature: float, top_k: Optional[int], mesh=None,
     tp_axis: str = "tp",
@@ -230,8 +133,7 @@ def paged_programs(
     the programs is partitioned by GSPMD from the operands' shardings,
     as before.
 
-    Same hot-path discipline as `slot_programs`, adapted to the block
-    pool: the pool tree and the per-slot (lengths, last-token, rng)
+    The pool tree and the per-slot (lengths, last-token, rng)
     lanes are device-resident and DONATED through every program; block
     tables stay HOST-side numpy and ride in per call (tiny, mutated
     only at admission/growth/retire — see `serve/cache.py`).
@@ -245,8 +147,7 @@ def paged_programs(
       no kernel takes the call: int8 pools, tiny heads, a window layer's
       chunk; `kernel_layers` counts which). Compiles
       once per CHUNK length C: with `prefill_chunk_tokens` set that is
-      ONE program for every prompt; unchunked it is one per bucket,
-      exactly like PR 4. `start` is NONZERO both for later chunks of a
+      ONE program for every prompt; unchunked it is one per bucket. `start` is NONZERO both for later chunks of a
       long prompt and for the FIRST chunk after a prefix-cache attach
       (ISSUE 12): the engine hands the program a table row whose
       leading blocks hold another request's identical prompt prefix,
@@ -360,6 +261,8 @@ def paged_programs(
             params, tree, tokens[:, None], lengths, bt, row_mask
         )
         lg = logits[:, -1]  # (S, V)
+        # sample_logits branches on the Python temperature at trace time
+        # (greedy at 0.0, keys trace away), so one vmap covers both modes
         with jax.named_scope("sample"):
             nxt = jax.vmap(
                 lambda row, key: sample_logits(row, key, temperature, top_k)

@@ -23,7 +23,7 @@ enforce it.
 
 Pool membership at PROCESS granularity is a generation-scoped store
 claim (`serve/worker.py::claim_role`); this package is the in-process
-plane the deterministic tests and benchmarks drive.
+plane the deterministic tests drive.
 """
 
 from .migrate import (

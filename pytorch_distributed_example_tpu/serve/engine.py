@@ -27,8 +27,8 @@ request's first chunk, so nothing thrashes at the door.
 (`serve/cache.py` quantized mode): ~4x the blocks per pool byte (minus
 the per-(token, kv-head) scale overhead), quantize-on-scatter in the
 paged write, dequant-in-gather so decode math is unchanged — at fixed
-pool bytes this roughly doubles the concurrently servable requests
-(the `serve_bench.py --trace capacity` row). Scheduling, preemption,
+pool bytes this roughly doubles the concurrently servable requests.
+Scheduling, preemption,
 and replay are dtype-blind: a preempted quantized request replays
 token-identically because quantization is deterministic.
 
@@ -93,7 +93,7 @@ requeues and replays token-identically off its seed, exactly like a
 pool-pressure preemption — and pool-pressure eviction itself becomes
 class-aware (worst class first, youngest within it). Together these
 protect the high class's p99 TTFT under overload while the low class
-absorbs the sheds (the `serve_bench.py --trace multitenant` row).
+absorbs the sheds.
 
 Elastic serving: `drain()` stops at a step boundary — quiesces the
 device lanes through the `serve/decode.py` drain seam, requeues all
@@ -287,7 +287,7 @@ class ServeEngine:
         # to completion and pool-pressure preemption never fires —
         # trades pool utilization for churn-free scheduling (and makes
         # "concurrently admitted requests" a direct measure of pool
-        # capacity, the serve_bench capacity row). `_reserved` tracks
+        # capacity). `_reserved` tracks
         # the active set's worst-case total.
         self.conservative_admission = conservative_admission
         self._reserved = 0
@@ -392,8 +392,7 @@ class ServeEngine:
         a single-threaded replay driver can only call submit() between
         steps, so stamping the clock would erase the queueing delay a
         request already served before the driver got to it — pass the
-        TRUE front-door arrival and TTFT/e2e account for it (the static
-        baseline in serve_bench measures from trace arrival too)."""
+        TRUE front-door arrival and TTFT/e2e account for it."""
         req = Request(
             prompt=np.asarray(prompt, np.int32).reshape(-1),
             max_new_tokens=max_new_tokens,

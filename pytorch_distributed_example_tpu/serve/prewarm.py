@@ -23,9 +23,7 @@ Two layers make that cheap:
   ``jit.lower(args).compile()`` — lowering with the engine's own
   params/pool/lane arrays traces WITHOUT executing (donation included:
   nothing is consumed), and compiling populates the persistent cache
-  with byte-identical HLO to what the serving loop will request. The
-  `benchmarks/tpu_aot_check.py` seam proved this lower-then-compile
-  path deviceless; here it runs on the live backend.
+  with byte-identical HLO to what the serving loop will request.
 
 The persistent cache alone is not "milliseconds": it skips XLA
 compilation but a respawned worker still re-TRACES every program
@@ -44,8 +42,6 @@ replica runs the SAME single-chip programs, so one warmed cache entry
 serves all worlds in the autoscaler's band — `reachable_geometries`
 returns the (world, tp, bucket) tuples for planning/reporting, and
 the warm pass dedups them down to the distinct (tp, bucket) programs.
-`benchmarks/serve_resize.py` measures the payoff: decision-to-first-
-token at the new width, pre-warmed vs cold.
 """
 
 from __future__ import annotations

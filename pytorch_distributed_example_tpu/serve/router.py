@@ -45,9 +45,9 @@ that router, plus the scale seams the autoscale controller
   mid-swing because every request carries its seed.
 
 Chip-seconds accounting: `step()` integrates `replicas x wall-time`
-(the router's clock — a virtual clock in the load harness makes the
-integral deterministic), which is the figure the autoscale bench
-compares against static peak provisioning.
+(the router's clock — a virtual clock in the tests makes the
+integral deterministic), the figure to compare against static peak
+provisioning.
 
 Threading: single-owner like the engine — ONE thread calls `submit` /
 `step` / scale methods. `_lock` exists for the concurrent READERS

@@ -19,8 +19,8 @@ a bitwise-contracted function is itself on a bitwise path):
   N001  matmul-family call without pinned `precision=` /
         `preferred_element_type=` on a bitwise-contract path in a
         module with low-precision evidence (bf16/fp16/fp8); the repo
-        pins `jax_default_matmul_precision` only in conftest.py and
-        the bench harness, so library code must pin per call
+        pins `jax_default_matmul_precision` only in conftest.py, so
+        library code must pin per call
   N002  geometry-dependent reduction-order decomposition
         (psum_scatter / all_gather / all_to_all / ppermute — the
         psum -> reduce-scatter+all-gather class, plan-executor chunk
@@ -490,7 +490,7 @@ def _rule_n001_n002(
                             "`precision=`/`preferred_element_type=` in a "
                             "module that mixes precisions; the repo-wide "
                             "jax_default_matmul_precision pin covers only "
-                            "conftest.py and the bench harness, not "
+                            "conftest.py, not "
                             "library callers",
                             chain,
                         )

@@ -40,7 +40,7 @@ Rules on top of the fingerprint:
         into a compile-time failure
 
 Register-on-compile seams (`TDX_PROGLINT=1`): `serve/decode.py`
-slot/paged programs, `parallel/ddp.py` train steps (replicated and
+paged programs, `parallel/ddp.py` train steps (replicated and
 ZeRO), `plan/driver.py` compiled schedule bodies — each wraps its
 jitted program in `instrument()`, which fingerprints on first call and
 runs the J005 agreement. The CLI
@@ -763,7 +763,7 @@ def instrument(
         # registration is a HOST effect (trace + lower + a blocking
         # store agreement) — exactly the class R011/TraceGuard police.
         # An instrumented program can itself be called from inside an
-        # enclosing jit trace (benchmarks re-wrap the ddp step's
+        # enclosing jit trace (a caller that re-wraps the ddp step's
         # programs); registering there would block the trace, so defer
         # to the first EAGER call instead of firing mid-trace.
         if not done and not traceguard.under_tracing():
@@ -833,7 +833,6 @@ def _serve_programs() -> List[Tuple[ProgramFingerprint, ProgramMeta]]:
     import jax
     import jax.numpy as jnp
 
-    from ..models.generate import init_cache
     from ..serve import decode as _decode
     from ..serve.cache import PagedKVCache
 
@@ -843,36 +842,10 @@ def _serve_programs() -> List[Tuple[ProgramFingerprint, ProgramMeta]]:
     S = 2
     out: List[Tuple[ProgramFingerprint, ProgramMeta]] = []
 
-    prefill, write_slot, step = map(
-        _unwrap, _decode.slot_programs(model, 0.0, None)
-    )
-    prompt = jnp.zeros((1, 8), jnp.int32)
     lengths = jnp.zeros((S,), jnp.int32)
     tokens = jnp.zeros((S,), jnp.int32)
     rngs = jnp.zeros((S, 2), jnp.uint32)
     key = jnp.zeros((2,), jnp.uint32)
-    slot_tree = init_cache(model, S)
-    pre = init_cache(model, 1)
-    for name, fn, args in (
-        ("serve.slot.prefill", prefill, (params, prompt, 8, 0)),
-        (
-            "serve.slot.write_slot",
-            write_slot,
-            (slot_tree, lengths, tokens, rngs, pre, 0, 8,
-             jnp.int32(0), key),
-        ),
-        (
-            "serve.slot.step",
-            step,
-            (params, init_cache(model, S), lengths, tokens, rngs),
-        ),
-    ):
-        out.append(
-            (
-                fingerprint_program(name, fn, args, path=path),
-                ProgramMeta(),
-            )
-        )
 
     pool = PagedKVCache(model, slots=S, num_blocks=8, block_size=4)
     nb = pool.block_tables.shape[1]

@@ -6,9 +6,9 @@ number, not a vibe: these helpers walk a pytree and report how many
 bytes ONE device holds for it, honoring shardings — a replicated leaf
 costs its full size per device, a dim-0-sharded leaf 1/W. Pure host
 arithmetic over `sharding.shard_shape` (no device sync, no allocation),
-so train steps and benches can call it every step for peaks.
+so train steps can call it every step for peaks.
 
-`train_memory_report` is the bench-JSON shape: global + per-device
+`train_memory_report` is the JSON shape: global + per-device
 bytes for params / optimizer state / grads plus the reduction ratio
 the sharded layout buys.
 """
@@ -74,7 +74,7 @@ def tree_device_bytes(tree) -> int:
 def train_memory_report(
     params, opt_state, grads: Optional[Any] = None
 ) -> Dict[str, Any]:
-    """The bench-JSON memory block: global and per-device bytes for each
+    """The JSON memory block: global and per-device bytes for each
     train-state component. ``opt_state_reduction_x`` is global/per-device
     for the optimizer state — ≈ world under ZeRO weight-update sharding,
     1.0 replicated."""

@@ -2,8 +2,7 @@
 
 Every client path to shared infrastructure (store ops, rendezvous,
 p2p connect) retries through this one module so backoff behavior cannot
-drift between call sites — the same reasoning that put `device_sync` in
-benchmarks/common.py. The taxonomy contract (types.py):
+drift between call sites. The taxonomy contract (types.py):
 
   * retryable — transient connection-level failures: `ConnectionError`,
     `socket.timeout`, `OSError` (refused/reset/unreachable),

@@ -1,6 +1,4 @@
-"""Deviceless TPU-target AOT compilation — the path the round-4 memory
-and ceiling evidence rides (benchmarks/llama_scaled.py --target tpu,
-benchmarks/tpu_aot_check.py).
+"""Deviceless TPU-target AOT compilation.
 
 jax.experimental.topologies gives a compile-only TPU client: the real
 PJRT TPU compiler runs on the host with no chip attached, so XLA's

@@ -741,7 +741,7 @@ class TestScaleChaos:
 
 class TestLoadHarness:
     def test_trace_replayable_by_seed(self):
-        from benchmarks.load_harness import make_trace
+        from tests._load_trace import make_trace
 
         a = make_trace(7, 20.0, 10.0, 100, 4, 64)
         b = make_trace(7, 20.0, 10.0, 100, 4, 64)
@@ -762,7 +762,7 @@ class TestLoadHarness:
     def test_trace_is_diurnal(self):
         """The rate curve actually swings: the mid-trace bin is several
         times the edge bins."""
-        from benchmarks.load_harness import make_trace
+        from tests._load_trace import make_trace
 
         ev = make_trace(0, 40.0, 10.0, 2000, 4, 64)
         bins, _ = np.histogram(
@@ -771,10 +771,10 @@ class TestLoadHarness:
         assert max(bins[3], bins[4]) >= 4 * max(bins[0], bins[-1])
 
     def test_mini_swing_end_to_end(self, no_fault_plan):
-        """A shrunken serve_autoscale row as a regression guard: the
+        """A small diurnal swing as the regression guard: the
         controller rides a small burst out AND back in, everything
         completes, and chip-seconds beat an always-peak gang."""
-        from benchmarks.load_harness import make_trace, replay
+        from tests._load_trace import make_trace, replay
 
         model, params = _model(max_seq_len=32)
         events = make_trace(3, 12.0, 8.0, 120, 3, 64)

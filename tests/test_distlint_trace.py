@@ -221,7 +221,7 @@ class TestRealRepoGraph:
         proj = _package_project()
         mod = proj.modules["pytorch_distributed_example_tpu.serve.decode"]
         for name in (
-            "slot_programs.<locals>.step",
+            "paged_programs.<locals>.step",
             "paged_programs.<locals>.prefill_chunk",
         ):
             fi = mod.functions[name]
@@ -231,9 +231,10 @@ class TestRealRepoGraph:
     def test_decode_step_donation_sets_harvested(self):
         proj = _package_project()
         mod = proj.modules["pytorch_distributed_example_tpu.serve.decode"]
-        assert mod.functions["slot_programs.<locals>.step"].donates == {
-            1, 2, 3, 4,
-        }
+        fns = mod.functions
+        assert fns["paged_programs.<locals>.step"].donates == {1, 2, 3, 4}
+        assert fns["paged_programs.<locals>.prefill_chunk"].donates == {1}
+        assert fns["paged_programs.<locals>.attach"].donates == {0, 1, 2}
 
     def test_planner_bodies_are_configured_trace_roots(self):
         proj = _package_project()

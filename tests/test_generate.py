@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 
-def _model(n_kv_heads=None, max_seq_len=32):
+def _model(n_kv_heads=None, max_seq_len=32, **pattern):
     import jax
     import jax.numpy as jnp
 
@@ -25,6 +25,7 @@ def _model(n_kv_heads=None, max_seq_len=32):
         n_kv_heads=n_kv_heads,
         max_seq_len=max_seq_len,
         use_flash=False,
+        **pattern,
     )
     model = TransformerLM(cfg)
     toks = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, 8)))
@@ -32,14 +33,60 @@ def _model(n_kv_heads=None, max_seq_len=32):
     return model, params, toks
 
 
+def _layer_kinds():
+    """Toy two-layer configurations, one per mechanism a layer pattern
+    adds to the cached attention step, and one with all of them."""
+    from pytorch_distributed_example_tpu.models.transformer import (
+        LayerSpec,
+        RopeSpec,
+    )
+
+    sparse = dict(
+        sparse_experts=4, sparse_top_k=2, sparse_d_ff=16, shared_d_ff=16
+    )
+    return dict(
+        plain={},
+        window=dict(layers=(LayerSpec("window"), LayerSpec()), window=3),
+        head_gate=dict(layers=(LayerSpec(), LayerSpec()), attn_gate=True),
+        halves_partial_rope=dict(
+            layers=(
+                LayerSpec(rope=RopeSpec(10000.0, 0.5)),
+                LayerSpec(rope=RopeSpec(500000.0, 0.5)),
+            ),
+            rope_pairs="halves",
+        ),
+        per_layer_heads=dict(
+            n_kv_heads=2, head_size=8,
+            layers=(LayerSpec(n_heads=4), LayerSpec(n_heads=6)),
+        ),
+        sparse_mlp=dict(layers=(LayerSpec(), LayerSpec(mlp="sparse")), **sparse),
+        every=dict(
+            n_kv_heads=2, head_size=8, window=3, attn_gate=True,
+            rope_pairs="halves", **sparse,
+            layers=(
+                LayerSpec("window", 6, RopeSpec(10000.0, 1.0), "sparse"),
+                LayerSpec("full", 4, RopeSpec(500000.0, 0.5), "dense"),
+            ),
+        ),
+    )
+
+
 class TestDecodeParity:
-    @pytest.mark.slow  # heavy compile/convergence; full suite only
-    def test_incremental_decode_matches_full_forward(self):
-        """Prefill(prompt[:4]) + 4 single-token steps == causal forward."""
+    @pytest.mark.parametrize(
+        "kind",
+        ["plain", "window", "head_gate", "halves_partial_rope",
+         "per_layer_heads", "sparse_mlp"],
+    )
+    def test_incremental_decode_matches_full_forward(self, kind):
+        """Prefill(prompt[:4]) + 4 single-token steps == causal forward,
+        for each mechanism a layer can carry: what entitles `generate()`
+        to be the serve tests' reference for a patterned model. The
+        window (3) is shorter than the prompt, so keys fall out of it
+        both in the prefill and while decoding."""
         import jax
         import jax.numpy as jnp
 
-        model, params, toks = _model()
+        model, params, toks = _model(**_layer_kinds()[kind])
         p = params["params"]
         full = model.apply(params, toks)  # (2, 8, 64) causal logits
 
@@ -104,6 +151,24 @@ class TestGenerate:
             nxt = np.argmax(np.asarray(lg[:, -1]), axis=-1)
             seq = np.concatenate([seq, nxt[:, None]], axis=1)
         np.testing.assert_array_equal(np.asarray(out), seq[:, 5:])
+
+    def test_greedy_on_a_patterned_model_matches_stepwise_argmax(self):
+        """The same oracle for a model that carries every mechanism of
+        `_layer_kinds` at once: `generate()`'s two programs against the
+        cache-free forward, token for token. The model is causal, so one
+        forward over prompt + output gives every step's logits."""
+        import jax.numpy as jnp
+
+        from pytorch_distributed_example_tpu.models import generate
+
+        model, params, toks = _model(**_layer_kinds()["every"])
+        prompt = toks[:, :5]
+        out = np.asarray(generate(model, params, prompt, max_new_tokens=6))
+
+        seq = np.concatenate([np.asarray(prompt), out], axis=1)
+        lg = np.asarray(model.apply(params, jnp.asarray(seq)))
+        # logits at position t choose token t + 1
+        np.testing.assert_array_equal(np.argmax(lg[:, 4:-1], axis=-1), out)
 
     def test_sampling_reproducible_and_topk_bounded(self):
         import jax

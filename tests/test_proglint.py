@@ -437,7 +437,7 @@ class TestInstrumentation:
         from pytorch_distributed_example_tpu.serve import decode
 
         # a config distinct from every other test's so the lru_cache
-        # cannot hand back a pre-armed (unwrapped) program triple
+        # cannot hand back a pre-armed (unwrapped) program quadruple
         cfg = TransformerConfig(
             vocab_size=16, d_model=8, n_layers=1, n_heads=2,
             max_seq_len=8, use_flash=False,
@@ -446,12 +446,22 @@ class TestInstrumentation:
         params = model.init(
             jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
         )["params"]
-        prefill, write_slot, step = decode.slot_programs(model, 0.0, None)
-        assert hasattr(prefill, "_proglint_wrapped")
-        prefill(params, jnp.zeros((1, 4), jnp.int32), 4, 0)
+        from pytorch_distributed_example_tpu.serve import PagedKVCache
+
+        prefill_chunk, _first, _attach, _step = decode.paged_programs(
+            model, 0.0, None
+        )
+        assert hasattr(prefill_chunk, "_proglint_wrapped")
+        pool = PagedKVCache(model, slots=1, num_blocks=2, block_size=4)
+        pool.allocate()
+        assert pool.ensure_blocks(0, 3)
+        prefill_chunk(
+            params, pool.tree, jnp.zeros((1, 4), jnp.int32),
+            pool.tables(slice(0, 1)), 0,
+        )
         names = [n for n, _, _ in clean_registry.entries()]
-        assert names == ["serve.slot.prefill"]
-        fp = clean_registry.get("serve.slot.prefill")[0]
+        assert names == ["serve.paged.prefill_chunk"]
+        fp = clean_registry.get("serve.paged.prefill_chunk")[0]
         assert fp.path.endswith("serve/decode.py")
 
     def test_plan_seam_registers_and_reregisters_ordinal(

@@ -75,7 +75,6 @@ class TestRepoProgramsClean:
     def test_catalog_covers_the_registered_surfaces(self, world):
         names = {fp.name for fp, _ in _pairs(world)}
         assert {
-            "serve.slot.step",
             "serve.paged.step",
             "serve.paged.prefill_chunk",
             "ddp.train_step.zero",

@@ -1,5 +1,5 @@
-"""Serve subsystem tests (`serve/`): slot KV cache lifecycle,
-slot-prefill parity vs the whole-batch decode path, continuous-batching
+"""Serve subsystem tests (`serve/`): the paged cache's slot lifecycle,
+the two cache modes, paged prefill isolation, continuous-batching
 engine correctness (token-exact greedy parity vs `generate()`,
 mid-stream retire+backfill determinism), fake-clock TTFT/TPOT
 accounting, chaos requeue (serve.* fault points), and the /serve debug
@@ -78,12 +78,16 @@ class TestBucketing:
         assert bucket_lengths(64, min_bucket=16) == (16, 32, 64)
 
 
-class TestSlotCache:
-    def test_allocate_free_reset(self):
-        from pytorch_distributed_example_tpu.serve import SlotKVCache
+class TestPagedSlotLifecycle:
+    def test_full_double_free_reset(self):
+        """The slot side of the paged cache (the block side is
+        `tests/test_serve_paged.py::TestPagedPoolLifecycle`): a full
+        cache grants nothing, a freed slot is recycled, a double free is
+        refused, and reset returns every slot and block."""
+        from pytorch_distributed_example_tpu.serve import PagedKVCache
 
         model, _ = _model()
-        c = SlotKVCache(model, 3)
+        c = PagedKVCache(model, slots=3, num_blocks=12, block_size=4)
         s0, s1, s2 = c.allocate(), c.allocate(), c.allocate()
         assert sorted([s0, s1, s2]) == [0, 1, 2]
         assert c.allocate() is None  # full
@@ -93,85 +97,143 @@ class TestSlotCache:
         c.free(s2)
         with pytest.raises(ValueError, match="not allocated"):
             c.free(s2)  # double free
+        assert c.ensure_blocks(s0, 9)
         c.reset()
         assert c.active_slots == [] and c.occupancy == 0.0
         assert (c.lengths == 0).all()
-
-    def test_write_prefill_validates(self):
-        from pytorch_distributed_example_tpu.serve import SlotKVCache
-        from pytorch_distributed_example_tpu.models import init_cache
-
-        model, _ = _model()
-        c = SlotKVCache(model, 2)
-        pre = init_cache(model, 1)
-        with pytest.raises(ValueError, match="not allocated"):
-            c.write_prefill(0, pre, 4)
-        s = c.allocate()
-        with pytest.raises(ValueError, match="outside"):
-            c.write_prefill(s, pre, 0)
-        with pytest.raises(ValueError, match="outside"):
-            c.write_prefill(s, pre, model.cfg.max_seq_len + 1)
+        assert c.live_blocks == 0 and c.free_blocks == 12
+        assert (c.block_tables == c.invalid_block).all()
 
 
-class TestSlotPrefillParity:
-    def test_prefill_into_slot_matches_whole_batch_prefill(self):
-        """Bucket-padded prefill-into-slot == the unpadded whole-batch
-        decode prefill: first-token logits AND the cache's valid region
-        are identical; other slots stay untouched."""
+class TestCacheModes:
+    @pytest.mark.parametrize(
+        "given, match",
+        [
+            pytest.param(
+                "positions", "block_tables", id="positions_without_tables"
+            ),
+            pytest.param(
+                "block_tables", "requires positions",
+                id="tables_without_positions",
+            ),
+        ],
+    )
+    def test_refused(self, given, match):
+        """Cached attention has two modes: the scalar-index dense cache
+        (neither argument) and the paged pool (both). One argument
+        without the other is refused by name."""
         import jax.numpy as jnp
 
-        from pytorch_distributed_example_tpu.serve import SlotKVCache
-        from pytorch_distributed_example_tpu.serve.decode import (
-            slot_programs,
+        from pytorch_distributed_example_tpu.models import init_cache
+
+        model, params = _model()
+        kw = {
+            "positions": jnp.zeros((2,), jnp.int32),
+            "block_tables": jnp.zeros((2, 8), jnp.int32),
+        }
+        with pytest.raises(ValueError, match=match):
+            model.apply(
+                {"params": params["params"], "cache": init_cache(model, 2)},
+                jnp.zeros((2, 1), jnp.int32),
+                decode=True,
+                mutable=["cache"],
+                **{given: kw[given]},
+            )
+
+
+class TestPagedPrefillIsolation:
+    @pytest.mark.parametrize(
+        "quantized", [False, True], ids=["plain", "int8"]
+    )
+    def test_only_the_row_s_blocks_change(self, quantized):
+        """A 5-token prompt in an 8-token chunk into slot 1 of 3: every
+        pool block outside slot 1's table (K, V and, for the int8 pool,
+        the scale planes) is bit-unchanged, another live row's blocks
+        among them; for the plain pool the first-token logits are those
+        of the scalar-index prefill on the UNPADDED prompt."""
+        import jax
+        import jax.numpy as jnp
+
+        from pytorch_distributed_example_tpu.models import init_cache
+        from pytorch_distributed_example_tpu.serve import (
+            PagedKVCache,
+            paged_programs,
         )
 
         model, params = _model()
         p = params["params"]
         (prompt,) = _prompts(5)
-        L = len(prompt)
+        L, C = len(prompt), 8
 
-        prefill, _write, _step = slot_programs(model, 0.0, None)
-        padded = np.zeros((1, 8), np.int32)  # bucket 8 > L=5
+        cache = PagedKVCache(
+            model, slots=3, num_blocks=12, block_size=4, quantized=quantized
+        )
+        assert (cache.allocate(), cache.allocate()) == (0, 1)
+        assert cache.ensure_blocks(0, 7)  # a live neighbour's blocks
+        assert cache.ensure_blocks(1, L - 1)
+        # a pool of recognisable values, so an unchanged block shows
+        cache.tree = jax.tree_util.tree_map(
+            lambda x: (jnp.arange(x.size).reshape(x.shape) % 7 + 1).astype(
+                x.dtype
+            ),
+            cache.tree,
+        )
+        before = jax.tree_util.tree_map(np.array, cache.tree)  # donated below
+
+        prefill_chunk, first_token, _attach, _step = paged_programs(
+            model, 0.0, None
+        )
+        padded = np.zeros((1, C), np.int32)
         padded[0, :L] = prompt
-        pre_cache, first_logits, first, _key = prefill(
-            p, jnp.asarray(padded), L, 0
+        tree, logits = prefill_chunk(
+            p, cache.tree, jnp.asarray(padded),
+            cache.tables(slice(1, 2)), 0,
         )
 
-        # oracle: the existing scalar-index prefill on the UNPADDED prompt
-        import jax
+        mine = cache.slot_blocks(1)
+        others = [b for b in range(12) if b not in mine]
+        leaves = set()
+        for layer in tree:
+            for name, leaf in tree[layer]["attn"].items():
+                got, was = np.asarray(leaf), before[layer]["attn"][name]
+                np.testing.assert_array_equal(got[others], was[others])
+                assert (got[mine] != was[mine]).any(), (layer, name)
+                leaves.add(name)
+        want = {"k", "v"} | ({"k_scale", "v_scale"} if quantized else set())
+        assert leaves == want
+        if quantized:
+            return
 
-        oracle_cache = model.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32), decode=True
-        )["cache"]
-        logits, v2 = model.apply(
-            {"params": p, "cache": oracle_cache},
+        # oracle: the scalar-index prefill on the UNPADDED prompt
+        ref, _ = model.apply(
+            {"params": p, "cache": init_cache(model, 1)},
             jnp.asarray(prompt)[None],
             decode=True,
             mutable=["cache"],
         )
         np.testing.assert_allclose(
-            np.asarray(first_logits), np.asarray(logits[0, -1]),
+            np.asarray(logits[L - 1]), np.asarray(ref[0, -1]),
             rtol=1e-6, atol=1e-6,
         )
-        assert int(first) == int(np.argmax(np.asarray(logits[0, -1])))
-        for layer in pre_cache:
-            for kv in ("k", "v"):
-                np.testing.assert_allclose(
-                    np.asarray(pre_cache[layer]["attn"][kv][:, :L]),
-                    np.asarray(v2["cache"][layer]["attn"][kv][:, :L]),
-                    rtol=1e-6, atol=1e-6,
-                )
+        first, _key = first_token(logits, L - 1, 0)
+        assert int(first) == int(np.argmax(np.asarray(ref[0, -1])))
 
-        # landing it in slot 1 of 3 touches ONLY slot 1
-        cache = SlotKVCache(model, 3)
-        cache.allocate(), cache.allocate()  # slots 0, 1
-        cache.write_prefill(1, pre_cache, L)
-        assert cache.lengths.tolist() == [0, L, 0]
-        for layer in cache.tree:
-            got = np.asarray(cache.tree[layer]["attn"]["k"])
-            want = np.asarray(pre_cache[layer]["attn"]["k"])
-            np.testing.assert_array_equal(got[1], want[0])
-            assert (got[0] == 0).all() and (got[2] == 0).all()
+
+def test_public_surface():
+    """Every public name of the cache and program modules resolves and
+    is re-exported by `serve`, and the two modules export exactly the
+    paged cache and its programs: one serving layout, no second."""
+    from pytorch_distributed_example_tpu import serve
+    from pytorch_distributed_example_tpu.serve import cache, decode
+
+    for mod in (cache, decode):
+        for name in mod.__all__:
+            assert getattr(serve, name) is getattr(mod, name), name
+    assert sorted(cache.__all__) == ["PagedKVCache", "init_paged_cache"]
+    assert sorted(decode.__all__) == [
+        "carry_key", "kernel_layers", "paged_programs", "sync_slot_lanes",
+    ]
+    assert [n for n in vars(serve) if n.endswith("KVCache")] == ["PagedKVCache"]
 
 
 class TestEngineParity:

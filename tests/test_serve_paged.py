@@ -737,12 +737,12 @@ _TRAINED_CACHE = {}
 
 def _trained_model(max_seq_len=48, steps=150):
     """Tiny LM briefly pretrained on the deterministic bigram chain via
-    the shared `benchmarks.common.chain_pretrain` recipe (see its
+    the `tests._recipes.chain_pretrain` recipe (see its
     docstring: greedy decode on random-init weights argmaxes over
     near-tied logits, so a match-rate test there measures argmax noise,
     not cache fidelity — trained margins make token flips attributable
     to quantization)."""
-    from benchmarks.common import chain_pretrain
+    from tests._recipes import chain_pretrain
 
     if (max_seq_len, steps) in _TRAINED_CACHE:
         return _TRAINED_CACHE[(max_seq_len, steps)]
