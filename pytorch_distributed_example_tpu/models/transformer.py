@@ -13,8 +13,13 @@ FSDP full-shard → GSPMD"; SURVEY.md §6). TPU-native design:
   (scaling-book recipe): attention/MLP in-features over ``fsdp``,
   head/ffn out-features over ``tp`` — XLA inserts the one all-reduce per
   block pair that Megatron hand-codes;
-* `nn.remat` per block when `remat=True` (HBM ↔ FLOPs trade, SURVEY task
-  note on `jax.checkpoint`).
+* `nn.remat` per block when `remat=True`: the backward recomputes a
+  block's forward except the values a rung of `utils/remat.py`'s ladder
+  keeps (the flash output and log-sum-exp; + q/k/v and the mid residual;
+  + the MLP's gate and up products), named here where they are made. The
+  rung is the richest at which the TPU compiler says the trainer's step
+  fits the chip (`utils.remat.fitted`); with no trainer around the
+  trace, or no limit reported, a block keeps nothing.
 """
 
 from __future__ import annotations
@@ -25,6 +30,9 @@ from typing import Any, Optional, Sequence, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from ..utils import remat as _remat
 
 
 @dataclass(frozen=True)
@@ -66,6 +74,8 @@ class TransformerConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.float32
     use_flash: bool = True
+    # per-block jax.checkpoint; WHAT a block keeps is the most the chip has
+    # room for, found by the trainer that builds the step (utils/remat.py)
     remat: bool = False
     n_experts: int = 0  # > 0 switches the MLP to a top-k MoE
     moe_capacity_factor: float = 1.25
@@ -336,6 +346,11 @@ class Attention(nn.Module):
 
         q = apply_rope(q, cos, sin, self.rope_halves)
         k = apply_rope(k, cos, sin, self.rope_halves)
+        # named BEFORE the GQA repeat: the repeat is a copy and may be
+        # recomputed, so a saved k/v costs KV heads and not H
+        q = checkpoint_name(q, _remat.ATTN_Q)
+        k = checkpoint_name(k, _remat.ATTN_K)
+        v = checkpoint_name(v, _remat.ATTN_V)
         if self.window is not None:
             # the dense masked path; a window inside the flash kernel
             # comes with the training cell that needs it
@@ -659,8 +674,8 @@ class MLP(nn.Module):
         dense = lambda feats, name: nn.Dense(
             feats, use_bias=False, dtype=cfg.dtype, name=name
         )
-        gate = dense(F, "gate_proj")(x)
-        up = dense(F, "up_proj")(x)
+        gate = checkpoint_name(dense(F, "gate_proj")(x), _remat.MLP_GATE)
+        up = checkpoint_name(dense(F, "up_proj")(x), _remat.MLP_UP)
         return dense(cfg.d_model, "down_proj")(nn.silu(gate) * up)
 
 
@@ -761,11 +776,19 @@ class Block(nn.Module):
             RMSNorm(cfg.norm_eps, name="attn_norm")(x), cos, sin, decode,
             positions, block_tables,
         )
+        x = checkpoint_name(x, _remat.BLOCK_MID)
         h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
         if self.spec is not None and self.spec.mlp == "sparse":
             return x + SparseMoE(cfg, name="mlp")(h, row_mask)
         mlp_cls = MoE if cfg.n_experts > 0 else MLP
         return x + mlp_cls(cfg, name="mlp")(h)
+
+
+def _remat_block():
+    """`Block` under `jax.checkpoint`, keeping the rung of
+    `utils/remat.LADDER` the trainer's step is being traced at; with no
+    trainer around the trace, the plain `nn.remat(Block)`."""
+    return nn.remat(Block, policy=_remat.save_policy(_remat.rung()))
 
 
 class TransformerLM(nn.Module):
@@ -809,7 +832,7 @@ class TransformerLM(nn.Module):
         # positional (TracerBoolConversionError at `if decode:`); the
         # rematted path is always decode=False, so rely on the default
         use_remat = cfg.remat and not decode
-        block_cls = nn.remat(Block) if use_remat else Block
+        block_cls = _remat_block() if use_remat else Block
         for i in range(cfg.n_layers):
             if use_remat:
                 x = block_cls(cfg, name=f"layers_{i}")(x, cos, sin)
@@ -839,7 +862,7 @@ class TransformerLM(nn.Module):
         }
         paired = isinstance(block_tables, (tuple, list))
         use_remat = cfg.remat and not decode
-        block_cls = nn.remat(Block) if use_remat else Block
+        block_cls = _remat_block() if use_remat else Block
         for i, spec in enumerate(specs):
             cos, sin = tables[spec.rope]
             block = block_cls(cfg, spec, name=f"layers_{i}")

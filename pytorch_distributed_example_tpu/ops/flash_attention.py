@@ -36,9 +36,11 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from .._compat import shard_map_fn
+from ..utils.remat import FLASH_LSE, FLASH_OUT
 
 NEG_INF = -1e30
 
@@ -648,17 +650,27 @@ def flash_with_lse(q, k, v, scale, causal, block_q, block_k, interpret):
     `delta -> delta - dlse` (dlogits = p*(dp - delta + dlse_row)), so
     the same three bwd kernels serve both VJPs. Shapes as `_fwd`:
     (BH, L, D) in, ((BH, L, D), (BH, L, 1)) out.
+
+    The backward's residuals `o` and `lse` carry names (`utils.remat`):
+    under a `jax.checkpoint` whose policy saves them the kernel never
+    runs a second time; anywhere else a name is nothing.
     """
     return _fwd(q, k, v, scale, causal, block_q, block_k, interpret)
 
 
 def _fwl_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     o, lse = _fwd(q, k, v, scale, causal, block_q, block_k, interpret)
-    return (o, lse), (q, k, v, o, lse)
+    # named HERE, inside the forward rule, and both: a name on the call's
+    # output alone would save `o` and still re-run the kernel for `lse`.
+    # lse is kept as (BH, L): the kernel writes (BH, L, 1), one float32 a
+    # 128-lane row on the chip, 128 times the bytes
+    o = checkpoint_name(o, FLASH_OUT)
+    return (o, lse), (q, k, v, o, checkpoint_name(lse.squeeze(-1), FLASH_LSE))
 
 
 def _fwl_bwd(scale, causal, block_q, block_k, interpret, res, cts):
     q, k, v, o, lse = res
+    lse = lse[..., None]
     do, dlse = cts
     dq, dk, dv = _bwd(
         q, k, v, o, lse, do, scale, causal, block_q, block_k, interpret,

@@ -46,6 +46,7 @@ from . import comm_hooks, zero
 
 
 from .._compat import shard_map_fn as _shard_map_fn
+from ..utils import remat as _remat
 
 
 def _named_leaves(params):
@@ -638,9 +639,9 @@ def make_ddp_train_step(
     # the dp shard moves to axis 1; per-step rngs stay replicated
     data_spec = P(None, axis) if steps_per_call > 1 else P(axis)
 
-    def _build_jitted(opt_spec):
+    def _jit_program(opt_spec, trace):
         mapped = _shard_map_fn(
-            local_step,
+            trace(local_step),
             mesh=mesh,
             in_specs=(P(), opt_spec, P(axis), data_spec, data_spec, P()),
             out_specs=(P(), opt_spec, P(axis), P(), P()),
@@ -657,7 +658,14 @@ def make_ddp_train_step(
         donate = zero.assert_donation_contract(
             donate, sharded_opt_state=zero_update
         )
-        jitted = jax.jit(mapped, donate_argnums=donate)
+        return jax.jit(mapped, donate_argnums=donate)
+
+    def _build_jitted(opt_spec):
+        # a model with per-block remat keeps, of each block, the most the
+        # TPU compiler says the chip has room for (utils/remat.py)
+        jitted = _remat.fitted(
+            lambda trace: _jit_program(opt_spec, trace), mesh.devices.flat
+        )
         if os.environ.get("TDX_PROGLINT", "0") == "1":
             # register-on-compile (tools/proglint.py): first call
             # fingerprints the compiled collective sequence + donation
@@ -871,7 +879,9 @@ def make_ddp_train_step(
             # build the plain program on demand
             jitted = _build_jitted(P())
             step._jitted = jitted
-        return _finish(jitted(params, opt_state, hook_state, x, y, rng))
+        out = _finish(jitted(params, opt_state, hook_state, x, y, rng))
+        step.remat_plan = getattr(jitted, "remat_plan", None)
+        return out
 
     def _finish(out):
         # remember the returned opt-state object: threading it back is
@@ -1003,6 +1013,11 @@ def make_ddp_train_step(
     step.init_opt_state = init_opt_state
     step.shard_opt_state = shard_opt_state
     step.unshard_opt_state = unshard_opt_state
+    # the `utils.remat.RematPlan` of the live program: the rung a model
+    # with per-block remat took and the compiler's counts it took it from
+    # (None until the first call, for a model that has no such blocks, and
+    # on a device that reports no memory limit)
+    step.remat_plan = None
 
     def memory_report(params, opt_state, grads=None):
         """Per-device + global bytes for params / optimizer state /

@@ -19,6 +19,7 @@ import functools
 import re
 from typing import Any, Callable, Optional, Sequence
 
+from ..utils import remat as _remat
 from . import sharding as shd
 
 
@@ -261,12 +262,18 @@ def _make_constrained_train_step(
 
     xshard = NamedSharding(jmesh, batch_spec)
     rep = NamedSharding(jmesh, P())
-    return jax.jit(
-        step,
-        in_shardings=(param_sharding, None, xshard, xshard)
-        + ((rep,) if has_rng else ()),
-        out_shardings=(param_sharding, None, rep),
-        donate_argnums=(0, 1) if donate else (),
+    # a model with per-block remat keeps, of each block, the most the TPU
+    # compiler says the chip has room for (utils/remat.py); `.remat_plan`
+    # says which rung and from what counts
+    return _remat.fitted(
+        lambda trace: jax.jit(
+            trace(step),
+            in_shardings=(param_sharding, None, xshard, xshard)
+            + ((rep,) if has_rng else ()),
+            out_shardings=(param_sharding, None, rep),
+            donate_argnums=(0, 1) if donate else (),
+        ),
+        jmesh.devices.flat,
     )
 
 
@@ -542,8 +549,11 @@ def make_zero2_train_step(
                     traced.prepare_for_params(
                         dist._get_default_group(), params
                     )
-            return inner_step(params, opt_state, x, y, *rng)
+            out = inner_step(params, opt_state, x, y, *rng)
+            _prepared_step.remat_plan = inner_step.remat_plan
+            return out
 
+        _prepared_step.remat_plan = None
         step = _prepared_step
 
     def init_opt_state(params):

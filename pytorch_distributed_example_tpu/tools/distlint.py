@@ -1202,7 +1202,9 @@ class Project:
         jit/pmap/shard_map (covers `@jax.jit` and
         `@functools.partial(jax.jit, ...)` alike), (b) it is passed by
         name to a jit/pmap/*shard_map* wrapper or as a lax
-        scan/cond/while_loop/fori_loop/remat body, or (c) it matches a
+        scan/cond/while_loop/fori_loop/remat body, bare or through one
+        call that takes it alone (`jax.jit(trace(step), ...)`: a
+        decorator applied by hand), or (c) it matches a
         configured `path-glob::name-glob` seam. Donation declarations
         (`donate_argnums`/`donate_argnames`) are read off the same
         decorators and wrap-call sites."""
@@ -1247,11 +1249,18 @@ class Project:
                 else:
                     continue
                 for i in positions:
-                    if i >= len(node.args) or not isinstance(
-                        node.args[i], ast.Name
-                    ):
+                    if i >= len(node.args):
                         continue
-                    for fi in by_leaf.get(node.args[i].id, []):
+                    arg = node.args[i]
+                    if (
+                        isinstance(arg, ast.Call)
+                        and len(arg.args) == 1
+                        and not arg.keywords
+                    ):
+                        arg = arg.args[0]  # a decorator applied by hand
+                    if not isinstance(arg, ast.Name):
+                        continue
+                    for fi in by_leaf.get(arg.id, []):
                         if fi.trace_root is None:
                             fi.trace_root = how
                         if donating:

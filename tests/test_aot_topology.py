@@ -70,14 +70,26 @@ def test_sharded_mesh_compile_memory_analysis(topo):
     assert int(ma.argument_size_in_bytes) == 512 * 512 * 2 // 2
 
 
-def test_fully_shard_lm_step_with_flash_compiles_under_mesh(topo, monkeypatch):
+@pytest.mark.parametrize("rung,kernel_calls", [(0, 4), (3, 3)])
+def test_fully_shard_lm_step_with_flash_compiles_under_mesh(
+    topo, monkeypatch, rung, kernel_calls
+):
     """The only check in a chipless sandbox that sees a Mosaic kernel under
     a multi-chip GSPMD trainer: the (fsdp, tp) = (2, 2) `fully_shard` LM
     step with flash ON compiles for v5e:2x2, and every custom call runs on
     a LOCAL (batch-shard x head-shard) slice. On the CPU mesh the kernel is
     interpreted — plain HLO that GSPMD partitions — so tier-1 cannot tell;
     without `ops.partitioned_over` this lowering raises "Mosaic kernels
-    cannot be automatically partitioned"."""
+    cannot be automatically partitioned".
+
+    A described device cannot be asked for its memory limit
+    (`utils.remat.device_limit_bytes` is None, nothing steered here), so
+    the step is the plain program at rung 0 of the remat ladder: the
+    layer's forward kernel runs twice (forward, recomputed, dK/dV, dQ).
+    With a limit handed in, the fit runs as on a chip (lower, compile for
+    v5e, the compiler's count), takes the top rung, and the names inside
+    the kernel's forward rule hold through the `shard_map`: the compiled
+    step runs it once."""
     import re
 
     import jax
@@ -95,7 +107,11 @@ def test_fully_shard_lm_step_with_flash_compiles_under_mesh(topo, monkeypatch):
         make_fsdp_train_step,
     )
 
+    from pytorch_distributed_example_tpu.utils import remat
+
     monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    if rung:
+        monkeypatch.setattr(remat, "device_limit_bytes", lambda devices: 10**15)
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("fsdp", "tp"))
     B, L, H, Dh = 4, 1024, 8, 128
     cfg = TransformerConfig(
@@ -129,7 +145,13 @@ def test_fully_shard_lm_step_with_flash_compiles_under_mesh(topo, monkeypatch):
     hlo = step.lower(p_abs, o_abs, x, x).compile().as_text()
 
     calls = _custom_calls(hlo)
-    assert calls, "no Mosaic custom call in the compiled step"
+    assert len(calls) == kernel_calls
+    plan = step.remat_plan
+    if rung == 0:
+        assert plan is None
+    else:
+        # the first rung compiled fitted: 16 GB of HBM hold it by the count
+        assert plan.rung == rung and 0 < plan.held[0][1] < 16e9
     # kernels see (B*H, L, Dh) per device: batch/2 rows x heads/2 heads
     local_bh = (B // 2) * (H // 2)
     for line in calls:

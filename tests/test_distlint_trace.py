@@ -249,6 +249,39 @@ class TestRealRepoGraph:
         fi = mod.functions["make_ddp_train_step.<locals>.local_step"]
         assert fi.trace_root is not None
 
+    def test_fsdp_step_is_trace_root_through_the_rung_decorator(self):
+        """`jax.jit(trace(step), ...)`: the trainers hand their step to
+        `utils.remat.fitted` through a decorator applied by hand."""
+        proj = _package_project()
+        mod = proj.modules["pytorch_distributed_example_tpu.parallel.fsdp"]
+        fi = mod.functions["_make_constrained_train_step.<locals>.step"]
+        assert fi.trace_root is not None
+
+    def test_a_function_wrapped_by_hand_keeps_its_host_effects_policed(self):
+        import textwrap
+
+        from pytorch_distributed_example_tpu.tools.distlint import (
+            lint_source,
+        )
+
+        src = textwrap.dedent(
+            """
+            import jax
+
+
+            def build(trace):
+                def step(x):
+                    return x + x.sum().item()  # a host effect inside a traced body
+
+                return jax.jit(trace(step), donate_argnums=(0,))
+            """
+        )
+        bare = src.replace("trace(step)", "step")
+        found = lambda text: sorted(
+            (f.rule, f.line) for f in lint_source(text, "x.py")
+        )
+        assert found(src) == found(bare) and found(bare)
+
     def test_mesh_axis_registry_holds_repo_axes(self):
         # the package itself constructs `dp` meshes (TP/serve meshes are
         # caller-provided and harvested from tests/examples in the full
