@@ -105,7 +105,19 @@ def init_cache(model, batch_size: int):
             }
         }
 
-    return {f"layers_{i}": one_layer() for i in range(cfg.n_layers)}
+    def state_layer():  # a linear layer: a recurrent state, no K/V
+        from .transformer import linear_state_shapes
+
+        return {"linear_attn": {
+            leaf: jnp.zeros((B,) + shape, dtype)
+            for leaf, (shape, dtype) in linear_state_shapes(cfg).items()
+        }}
+
+    linear = getattr(cfg, "linear_layers", ())
+    return {
+        f"layers_{i}": state_layer() if i in linear else one_layer()
+        for i in range(cfg.n_layers)
+    }
 
 
 def generate(

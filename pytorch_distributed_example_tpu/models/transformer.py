@@ -24,6 +24,7 @@ FSDP full-shard → GSPMD"; SURVEY.md §6). TPU-native design:
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence, Tuple
 
@@ -38,7 +39,8 @@ from ..utils import remat as _remat
 @dataclass(frozen=True)
 class RopeSpec:
     """One rotary embedding: its base, the leading share of a head it
-    rotates (the rest passes unrotated), and YaRN's five numbers
+    rotates (the rest passes unrotated; 0.0 rotates nothing: a model
+    whose attention takes no position), and YaRN's five numbers
     (factor, original_max_position_embeddings, beta_fast, beta_slow,
     attention_factor) where the frequencies are stretched."""
 
@@ -47,12 +49,22 @@ class RopeSpec:
     yarn: Optional[Tuple[float, int, float, float, float]] = None
 
 
+# what a layer's mixer keeps between calls: every key and value, those of
+# a window, or one recurrent state a row
+CACHE_KINDS = ("full", "window", "linear")
+# tokens of one sub-chunk of a linear layer's chunked scan
+# (`gated_delta_chunked`): a power of two that divides every prefill
+# bucket of the serve cells (128, 256, 512)
+LINEAR_CHUNK = 64
+
+
 @dataclass(frozen=True)
 class LayerSpec:
-    """What one layer of a patterned model is. `attention` is "full" or
-    "window" (the last `cfg.window` keys); `n_heads` and `rope` default
-    to the model's; `mlp` is "dense" (SwiGLU at `cfg.ffn_dim`) or
-    "sparse" (`SparseMoE` at the `sparse_*` sizes)."""
+    """What one layer of a patterned model is. `attention` is "full",
+    "window" (the last `cfg.window` keys) or "linear" (`LinearAttention`
+    at the `linear_*` sizes: a recurrent state, no keys and values);
+    `n_heads` and `rope` default to the model's; `mlp` is "dense" (SwiGLU
+    at `cfg.ffn_dim`) or "sparse" (`SparseMoE` at the `sparse_*` sizes)."""
 
     attention: str = "full"
     n_heads: Optional[int] = None
@@ -98,6 +110,23 @@ class TransformerConfig:
     shared_d_ff: int = 0
     routed_scale: float = 1.0
     experts_held: Optional[Tuple[int, int]] = None
+    # the "linear" mixer (`LinearAttention`, a gated delta rule):
+    # `linear_heads` heads of `linear_key_dim`-wide queries and keys and
+    # `linear_value_dim`-wide values behind a causal depthwise conv of
+    # `linear_conv` taps; beta reaches (0, 2) with `linear_neg_eigval`,
+    # else (0, 1); more than one token is scanned in sub-chunks of
+    # `LINEAR_CHUNK`
+    linear_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 4
+    linear_neg_eigval: bool = False
+    # each sublayer's OUTPUT is normed before the residual add,
+    # h = x + norm(mixer(x)), and nothing norms its input
+    post_norm: bool = False
+    # q and k of an attention layer are RMS-normed over the whole
+    # projection, before the heads are split
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.rope_pairs not in ("interleaved", "halves"):
@@ -109,13 +138,22 @@ class TransformerConfig:
                 f"{len(self.layers)} layer specs for n_layers={self.n_layers}"
             )
         for i, spec in enumerate(self.layers):
-            if spec.attention not in ("full", "window") or spec.mlp not in (
+            if spec.attention not in CACHE_KINDS or spec.mlp not in (
                 "dense", "sparse"
             ):
                 raise ValueError(f"layer {i}: {spec}")
             if spec.attention == "window" and not self.window:
                 raise ValueError(f"layer {i} is a window layer and window is unset")
-            if (spec.n_heads or self.n_heads) % self.kv_heads:
+            if spec.attention == "linear":
+                if not (
+                    self.linear_heads and self.linear_key_dim
+                    and self.linear_value_dim and self.linear_conv >= 1
+                ):
+                    raise ValueError(
+                        f"layer {i} is linear and linear_heads/key_dim/"
+                        "value_dim/conv are unset"
+                    )
+            elif (spec.n_heads or self.n_heads) % self.kv_heads:
                 raise ValueError(f"layer {i}: heads do not divide over kv_heads")
             if spec.mlp == "sparse" and not (
                 self.sparse_experts >= self.sparse_top_k > 0 and self.sparse_d_ff
@@ -150,6 +188,25 @@ class TransformerConfig:
         if self.layers is None:
             return (False,) * self.n_layers
         return tuple(spec.attention == "window" for spec in self.layers)
+
+    @property
+    def linear_layers(self) -> Tuple[int, ...]:
+        """The layers that keep a recurrent state and no K/V."""
+        if self.layers is None:
+            return ()
+        return tuple(
+            i for i, spec in enumerate(self.layers) if spec.attention == "linear"
+        )
+
+    @property
+    def cache_kinds(self) -> Tuple[str, ...]:
+        """The kinds of cached state the layers keep, in `CACHE_KINDS`'
+        order: where there is more than one, a paged call's
+        `block_tables` is the tuple of one table a kind, in this order."""
+        if self.layers is None:
+            return ("full",)
+        have = {spec.attention for spec in self.layers}
+        return tuple(kind for kind in CACHE_KINDS if kind in have)
 
     @property
     def sparse_layers(self) -> Tuple[int, ...]:
@@ -223,6 +280,8 @@ def _rotate(x, c, s, halves: bool):
     pairs); values past them pass. Pairs are (even, odd) neighbours, or
     with `halves` value j and value j + r/2."""
     half, D = c.shape[-1], x.shape[-1]
+    if not half:  # a `RopeSpec` that rotates nothing
+        return x
     whole = 2 * half == D
     xr = x if whole else x[..., : 2 * half]
     if halves:
@@ -333,8 +392,13 @@ class Attention(nn.Module):
         dense = lambda feats, name: nn.Dense(
             feats, use_bias=False, dtype=cfg.dtype, name=name
         )
-        q = dense(H * Dh, "q_proj")(x).reshape(B, L, H, Dh)
-        k = dense(KV * Dh, "k_proj")(x).reshape(B, L, KV, Dh)
+        # q and k normed over the whole projection where the pattern says so
+        qk_norm = (
+            (lambda name, a: RMSNorm(cfg.norm_eps, name=name)(a))
+            if self.spec is not None and cfg.qk_norm else (lambda name, a: a)
+        )
+        q = qk_norm("q_norm", dense(H * Dh, "q_proj")(x)).reshape(B, L, H, Dh)
+        k = qk_norm("k_norm", dense(KV * Dh, "k_proj")(x)).reshape(B, L, KV, Dh)
         v = dense(KV * Dh, "v_proj")(x).reshape(B, L, KV, Dh)
         scale = 1.0 / (Dh ** 0.5)
 
@@ -434,9 +498,16 @@ class Attention(nn.Module):
                     "(serve.cache.init_paged_cache) passed via apply(); "
                     "the module cannot size the pool from the batch"
                 )
-            return self._decode_paged(
+            from ..ops.paged_attention import from_pool_heads, to_pool_heads
+
+            # the pool may hold more KV heads than the model has
+            # (`ops.paged_attention.pool_kv_heads`)
+            held = self.get_variable("cache", "k").shape[2]
+            q, k, v = to_pool_heads(q, k, v, held)
+            o = self._decode_paged(
                 q, k, v, cos, sin, scale, positions, block_tables
             )
+            return from_pool_heads(o, KV, held)
         if positions is not None:
             raise ValueError(
                 "positions without block_tables: per-row cache positions "
@@ -535,7 +606,7 @@ class Attention(nn.Module):
 
         cfg = self.cfg
         B, L, KV, Dh = k.shape
-        H = self.heads
+        H = q.shape[2]  # the layer's, or with a padded pool its groups'
         M = cfg.max_seq_len
         window, halves = self.window, self.rope_halves
         quantized = self.has_variable("cache", "k_scale")
@@ -663,6 +734,306 @@ def _flash_ok(L: int, Dh: int, window: Optional[int] = None) -> bool:
     return ok
 
 
+def linear_state_shapes(cfg) -> dict:
+    """leaf -> (shape, dtype) of what ONE row keeps in a linear layer, as
+    `LinearAttention` reads and writes it: the recurrent `state` and the
+    pre-conv inputs behind the row's last token, `conv`."""
+    H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    return {
+        "state": ((H, dk, dv), jnp.float32),
+        "conv": ((cfg.linear_conv - 1, H * (2 * dk + dv)), cfg.dtype),
+    }
+
+
+def _unit_lower_inverse(m):
+    """Inverse of unit lower-triangular matrices (..., C, C), C a power of
+    two, by blocks: T holds the inverses of the diagonal blocks of size s,
+    and [[a, 0], [b, d]]^-1 = [[a', 0], [-d' b a', d']] gives those of size
+    2s as T - T B T, B the lower-left quarters of m's diagonal blocks of
+    size 2s. log2(C) rounds of two float32 products; no power of the matrix
+    is ever formed (a run of equal keys makes those cancel by many orders
+    of magnitude)."""
+    C = m.shape[-1]
+    rows, cols = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    hi = jax.lax.Precision.HIGHEST
+    T = jnp.broadcast_to(jnp.eye(C, dtype=m.dtype), m.shape)
+    s = 1
+    while s < C:
+        quarter = ((rows // s) % 2 == 1) & (cols // s == rows // s - 1)
+        B = jnp.where(quarter, m, 0.0)
+        T = T - jnp.matmul(jnp.matmul(T, B, precision=hi), T, precision=hi)
+        s *= 2
+    return T
+
+
+def gated_delta_chunked(q, k, v, g, beta, state, chunk: int):
+    """The gated delta rule over L tokens, from `state`, in its chunked
+    (WY) form. q, k: (B, L, H, dk), L2-normalised, q scaled; v: (B, L, H,
+    dv); g = log(alpha) <= 0 and beta: (B, L, H); state: (B, H, dk, dv);
+    all float32. Returns (o (B, L, H, dv), the state after token L - 1).
+
+    Per token S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T
+    k_t)^T, o_t = S_t^T q_t. Inside a sub-chunk of `chunk` tokens, with G
+    the running sum of g, the rule's corrections solve a unit
+    lower-triangular system (I + A) u = beta v - (beta k e^G) S_in, A_ij
+    = beta_i (k_i . k_j) e^(G_i - G_j) for j < i; the state moves
+    sub-chunk by sub-chunk in a `lax.scan`. Everything is float32: the
+    products at `Precision.HIGH`, the triangular inverse at `HIGHEST`,
+    the rest elementwise. A position with g = 0 and beta = 0 (padding)
+    leaves the state as it found it; L is padded up to whole sub-chunks
+    with such positions."""
+    B, L, H, dk = q.shape
+    dv = v.shape[-1]
+    C = chunk
+    pad = -L % C
+    if pad:
+        widen = lambda a: jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        q, k, v, g, beta = (widen(a) for a in (q, k, v, g, beta))
+    N = (L + pad) // C
+    # (B, H, N, C, ...)
+    split = lambda a: jnp.moveaxis(a.reshape((B, N, C) + a.shape[2:]), 3, 1)
+    q, k, v, g, beta = (split(a) for a in (q, k, v, g, beta))
+    # float32 operands in three bfloat16 passes: at one pass (bfloat16
+    # operands) the scan alone was a third of the error variance of a served
+    # prefill against the float32 reference, which could then not be told
+    # from a state kept in bfloat16 (PERF.md section 6, PR 31)
+    mm = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGH)
+    G = jnp.cumsum(g, axis=-1)  # (B, H, N, C)
+    rows, cols = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    decay = jnp.exp(
+        jnp.where(cols <= rows, G[..., :, None] - G[..., None, :], -jnp.inf)
+    )  # e^(G_i - G_j) for j <= i, else 0
+    kb, into = k * beta[..., None], jnp.exp(G)[..., None]  # e^G: from S_in to token i
+    A = jnp.where(cols < rows, mm("bhnid,bhnjd->bhnij", kb, k) * decay, 0.0)
+    T = _unit_lower_inverse(A + jnp.eye(C, dtype=A.dtype))
+    u = mm("bhnij,bhnjd->bhnid", T, v * beta[..., None])  # corrected values
+    w = mm("bhnij,bhnjd->bhnid", T, kb * into)  # what S_in costs them
+    qk = jnp.where(cols <= rows, mm("bhnid,bhnjd->bhnij", q, k) * decay, 0.0)
+    q_in = q * into
+    last = G[..., -1:]  # (B, H, N, 1)
+    k_out = k * jnp.exp(last - G)[..., None]
+
+    def sub_chunk(S, xs):
+        u_i, w_i, qk_i, q_i, k_i, decay_i = xs
+        new = u_i - mm("bhid,bhde->bhie", w_i, S)  # (B, H, C, dv)
+        o = mm("bhid,bhde->bhie", q_i, S) + mm("bhij,bhje->bhie", qk_i, new)
+        S = S * decay_i[..., None] + mm("bhid,bhie->bhde", k_i, new)
+        return S, o
+
+    per_chunk = lambda a: jnp.moveaxis(a, 2, 0)  # N leads: what the scan walks
+    S, o = jax.lax.scan(sub_chunk, state, tuple(
+        per_chunk(a) for a in (u, w, qk, q_in, k_out, jnp.exp(last))
+    ))
+    # (N, B, H, C, dv) -> (B, L, H, dv)
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(B, N * C, H, dv)
+    return o[:, :L], S
+
+
+class LinearAttention(nn.Module):
+    """The "linear" mixer of a layer pattern: a Gated DeltaNet layer
+    (Yang, Kautz, Hatamizadeh, arXiv:2412.06464). Per token, with H =
+    `cfg.linear_heads` heads of key width dk and value width dv:
+
+        q, k, v = silu(conv(W_q x)), silu(conv(W_k x)), silu(conv(W_v x))
+            (a causal depthwise conv over time, `cfg.linear_conv` taps)
+        per head: q <- q / |q| * dk^-1/2,  k <- k / |k|
+        beta = (2 with `linear_neg_eigval`) * sigmoid(W_b x)
+        g = -exp(A_log) * softplus(W_a x + dt_bias),  alpha = exp(g)
+        S <- alpha S + beta k (v - alpha S^T k)^T     (dk x dv a head, float32)
+        o = S^T q
+        y = W_o [RMSNorm_dv(o) * w_norm * silu(W_g x)]
+
+    Everything the rule is computed from is float32: the projections of q,
+    k, v and the gates keep their products' float32 (the rule multiplies a
+    rounding of them by ten on its way to the logits), the conv, the
+    norms and the state too; the mixer's input and output are `cfg.dtype`.
+
+    What it keeps between calls is one recurrent state a row, `state`
+    (H, dk, dv) float32, and the `linear_conv - 1` pre-conv inputs behind
+    the row's last token, `conv` (taps - 1, H * (2 dk + dv)): no keys, no
+    values, nothing that grows with the context.
+
+    * With no cache (`decode=False`: training, the tests) the sequence
+      runs from a zero state through `gated_delta_chunked`.
+    * `decode=True` without tables is `generate()`'s cache: `state` and
+      `conv` are (B, ...) variables of the "cache" collection.
+    * With `block_tables` ((B, 1): each row's state block, `serve/
+      cache.py`) and `positions` ((B,): where each row's first token
+      stands) the variables are pools of blocks shared by every row. A
+      row at position 0 reads a zero state and a zero conv tail whatever
+      its block held; an invalid table entry (== the pool's blocks)
+      drops the write, so a parked lane changes nothing. One token a row
+      (the decode step) takes the recurrence itself: `ops.delta_recurrence.
+      paged_delta_step`, a kernel that reads, updates and writes each live
+      row's block in place, where `delta_kernel_ok` says it takes the pool,
+      else the same update in `jax.numpy` (`_delta_step` between a gather
+      and a scatter). More than one token (a prefill chunk) takes the
+      chunked form from the row's state.
+
+    `row_mask` ((B, L) bool) marks real tokens; the others must be a
+    row's trailing positions (the padding of a prefill chunk) and leave
+    the state (alpha = 1, beta = 0) and the conv tail (taken behind the
+    last real token) as that token left them."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(
+        self, x, decode: bool = False, positions=None, block_tables=None,
+        row_mask=None,
+    ):
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+        taps, width = cfg.linear_conv, H * (2 * dk + dv)
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=cfg.dtype, name=name
+        )
+        # what the recurrence is computed from keeps the products' float32
+        # (bfloat16 operands, no rounding of the result): the rule turns a
+        # rounding of q, k, v or a gate into ten times as much in the logits
+        wide = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=cfg.dtype, name=name,
+            dot_general=functools.partial(
+                jax.lax.dot_general, preferred_element_type=jnp.float32
+            ),
+        )
+        f32 = jnp.float32
+        qkv = jnp.concatenate(
+            [wide(H * dk, "q_proj")(x), wide(H * dk, "k_proj")(x),
+             wide(H * dv, "v_proj")(x)], axis=-1,
+        ).astype(f32)  # (B, L, width), before the conv
+        beta = jax.nn.sigmoid(wide(H, "b_proj")(x).astype(f32))
+        if cfg.linear_neg_eigval:
+            beta = 2.0 * beta
+        a_log = self.param("A_log", _a_log_init, (H,))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (H,))
+        g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+            wide(H, "a_proj")(x).astype(f32) + dt_bias.astype(f32)
+        )  # log(alpha), (B, L, H)
+        real = L
+        if row_mask is not None:
+            beta = jnp.where(row_mask[..., None], beta, 0.0)
+            g = jnp.where(row_mask[..., None], g, 0.0)
+            real = jnp.sum(row_mask, axis=1).astype(jnp.int32)  # (B,)
+
+        # -- the state this call starts from, and where it leaves it ------
+        paged = block_tables is not None
+        if paged and (positions is None or not self.has_variable("cache", "state")):
+            raise ValueError(
+                "a paged linear layer needs positions and a pre-built state "
+                "pool (serve.cache.init_paged_cache) passed via apply()"
+            )
+        state = jnp.zeros((B, H, dk, dv), f32)
+        tail = jnp.zeros((B, taps - 1, width), qkv.dtype)
+        if decode:
+            cs, cc = (
+                self.variable("cache", leaf, jnp.zeros, (B,) + shape, dtype)
+                for leaf, (shape, dtype) in linear_state_shapes(cfg).items()
+            )
+        scope = "recurrence" if decode and L == 1 else "chunk_scan"
+        kernel = False
+        if paged:
+            from ..ops.delta_recurrence import delta_kernel_ok, paged_delta_step
+
+            block = block_tables[:, 0]  # (B,), == the pool's blocks: none
+            fresh = positions == 0
+            # one token a row over a pool the kernel takes: it reads and
+            # writes the rows' state blocks itself, in place
+            kernel = scope == "recurrence" and delta_kernel_ok(cs.value)
+            with jax.named_scope(scope):  # the row's block, read once
+                at = lambda pool: jnp.take(pool, block, axis=0, mode="clip")
+                tail = jnp.where(fresh[:, None, None], 0, at(cc.value)).astype(
+                    qkv.dtype
+                )
+                if not kernel:
+                    state = jnp.where(fresh[:, None, None, None], 0.0, at(cs.value))
+        elif decode:
+            state, tail = cs.value, cc.value.astype(qkv.dtype)
+
+        with jax.named_scope("short_conv"):
+            seq = jnp.concatenate([tail, qkv], axis=1)  # (B, taps - 1 + L, width)
+            taps_weight = self.param(
+                "conv", nn.initializers.lecun_normal(in_axis=0, out_axis=1),
+                (taps, width),
+            ).astype(f32)
+            qkv = jax.nn.silu(
+                sum(taps_weight[i] * seq[:, i:i + L] for i in range(taps))
+            )
+            if row_mask is None:
+                tail = seq[:, L:]
+            else:  # behind each row's last real token
+                tail = jax.vmap(
+                    lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, taps - 1)
+                )(seq, real)
+        heads = lambda a, d: a.reshape(B, L, H, d)
+        q = heads(qkv[..., : H * dk], dk)
+        k = heads(qkv[..., H * dk: 2 * H * dk], dk)
+        v = heads(qkv[..., 2 * H * dk:], dv)
+        unit = lambda a: a * jax.lax.rsqrt(
+            jnp.maximum(jnp.sum(a * a, axis=-1, keepdims=True), 1e-12)
+        )
+        q, k = unit(q) * dk ** -0.5, unit(k)
+
+        with jax.named_scope(scope):
+            if scope == "recurrence":
+                step = (q[:, 0], k[:, 0], v[:, 0], jnp.exp(g[:, 0]), beta[:, 0])
+                if kernel:
+                    o, cs.value = paged_delta_step(cs.value, block, fresh, *step)
+                else:
+                    o, state = _delta_step(*step, state)
+                o = o[:, None]
+            else:
+                o, state = gated_delta_chunked(
+                    q, k, v, g, beta, state, LINEAR_CHUNK
+                )
+            # and written once; during init the variables are only created
+            # (flax's convention)
+            if paged:
+                if not kernel:
+                    cs.value = cs.value.at[block].set(state, mode="drop")
+                cc.value = cc.value.at[block].set(tail.astype(cfg.dtype), mode="drop")
+            elif decode and not self.is_initializing():
+                cs.value, cc.value = state, tail.astype(cfg.dtype)
+
+        with jax.named_scope("gated_norm"):
+            w_norm = self.param("norm", nn.initializers.ones, (dv,))
+            var = jnp.mean(o * o, axis=-1, keepdims=True)
+            o = o * jax.lax.rsqrt(var + cfg.norm_eps) * w_norm.astype(f32)
+            gate = wide(H * dv, "g_proj")(x).astype(f32).reshape(B, L, H, dv)
+            o = (o * jax.nn.silu(gate)).astype(cfg.dtype).reshape(B, L, H * dv)
+        return dense(cfg.d_model, "o_proj")(o)
+
+
+def _delta_step(q, k, v, alpha, beta, S):
+    """One token of the gated delta rule for every row: q, k (B, H, dk),
+    v (B, H, dv), alpha, beta (B, H), S (B, H, dk, dv), float32. Returns
+    (o (B, H, dv), the new S). Both S^T k and S^T q come from the one
+    read of S (o = alpha S^T q + (k . q) d, d the rule's correction), the
+    update is the second and only other pass: elementwise and reductions,
+    nothing rounds the state."""
+    Sk = jnp.sum(S * k[..., None], axis=-2)
+    Sq = jnp.sum(S * q[..., None], axis=-2)
+    a = alpha[..., None]
+    d = beta[..., None] * (v - a * Sk)  # (B, H, dv)
+    kq = jnp.sum(k * q, axis=-1, keepdims=True)
+    new = a[..., None] * S + k[..., None] * d[..., None, :]
+    return a * Sq + kq * d, new
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """log of a decay rate drawn from (0, 16), as the layer's source does."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 2.0 ** -10, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of a step drawn log-uniformly from (1e-3, 1e-1)."""
+    import math
+
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3), math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
     width: Optional[int] = None  # None: cfg.ffn_dim
@@ -772,16 +1143,27 @@ class Block(nn.Module):
         block_tables=None, row_mask=None,
     ):
         cfg = self.cfg
-        x = x + Attention(cfg, self.spec, name="attn")(
-            RMSNorm(cfg.norm_eps, name="attn_norm")(x), cos, sin, decode,
-            positions, block_tables,
-        )
+        # where the two norms stand: before each sublayer, or (a pattern
+        # with `post_norm`) on its output, before the residual add
+        post = self.spec is not None and cfg.post_norm
+        norm_in = lambda name, h: h if post else RMSNorm(cfg.norm_eps, name=name)(h)
+        norm_out = lambda name, y: RMSNorm(cfg.norm_eps, name=name)(y) if post else y
+        h = norm_in("attn_norm", x)
+        if self.spec is not None and self.spec.attention == "linear":
+            mixed = LinearAttention(cfg, name="linear_attn")(
+                h, decode, positions, block_tables, row_mask
+            )
+        else:
+            mixed = Attention(cfg, self.spec, name="attn")(
+                h, cos, sin, decode, positions, block_tables
+            )
+        x = x + norm_out("attn_norm", mixed)
         x = checkpoint_name(x, _remat.BLOCK_MID)
-        h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
+        h = norm_in("mlp_norm", x)
         if self.spec is not None and self.spec.mlp == "sparse":
-            return x + SparseMoE(cfg, name="mlp")(h, row_mask)
+            return x + norm_out("mlp_norm", SparseMoE(cfg, name="mlp")(h, row_mask))
         mlp_cls = MoE if cfg.n_experts > 0 else MLP
-        return x + mlp_cls(cfg, name="mlp")(h)
+        return x + norm_out("mlp_norm", mlp_cls(cfg, name="mlp")(h))
 
 
 def _remat_block():
@@ -813,11 +1195,13 @@ class TransformerLM(nn.Module):
 
         A model with a layer pattern (`cfg.layers`) builds each block
         from its `LayerSpec` and hands it the rope table of its own
-        `RopeSpec`. Where some layers keep a window of K/V only,
-        `block_tables` is the PAIR (full layers' tables, window layers'
-        tables) of `serve/cache.py` and each layer takes its kind's.
+        `RopeSpec`. Where the layers keep more than one kind of state
+        (`cfg.cache_kinds`: every key and value, a window of them, a
+        recurrent state), `block_tables` is the TUPLE of `serve/cache.py`'s
+        tables, one a kind in that order, and each layer takes its kind's.
         `row_mask` ((B, L) bool, optional) marks the rows that are real
-        tokens for the sparse MLPs (see `SparseMoE`)."""
+        tokens, for the sparse MLPs (see `SparseMoE`) and the linear
+        mixers (see `LinearAttention`)."""
         cfg = self.cfg
         x = nn.Embed(
             cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="tok_embed"
@@ -861,6 +1245,7 @@ class TransformerLM(nn.Module):
             for rope in {spec.rope for spec in specs}
         }
         paired = isinstance(block_tables, (tuple, list))
+        kinds = cfg.cache_kinds
         use_remat = cfg.remat and not decode
         block_cls = _remat_block() if use_remat else Block
         for i, spec in enumerate(specs):
@@ -871,7 +1256,7 @@ class TransformerLM(nn.Module):
                 continue
             bt = block_tables
             if paired:
-                bt = block_tables[int(spec.attention == "window")]
+                bt = block_tables[kinds.index(spec.attention)]
             x = block(x, cos, sin, decode, positions, bt, row_mask)
         return self._head(x)
 

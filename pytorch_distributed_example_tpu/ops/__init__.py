@@ -1,9 +1,11 @@
 """Pallas TPU kernels for the framework's hot ops (flash attention for
-training, paged decode attention over the serve block pool) — plus the
+training, paged decode and chunk attention over the serve block pool, the
+decode step's gated delta rule over the pool of recurrent state blocks) — plus the
 jnp-level block-scaled quantization codec (`quant.py`) shared by the
 quantized collectives and the int8 paged KV cache."""
 
 from . import quant  # noqa: F401
+from .delta_recurrence import delta_kernel_ok, paged_delta_step  # noqa: F401
 from .flash_attention import (  # noqa: F401
     flash_attention,
     gather_paged_kv,
@@ -14,6 +16,7 @@ from .paged_attention import (  # noqa: F401
     paged_chunk_attention,
     paged_decode_attention,
     paged_kernel,
+    pool_kv_heads,
 )
 from .quant import (  # noqa: F401
     dequantize_blockwise,

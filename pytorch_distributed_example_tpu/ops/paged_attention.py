@@ -157,12 +157,49 @@ def _page_copies(fn, tables_ref, first, have, pools, bufs, sems, slot):
     lax.fori_loop(0, have, one, 0)
 
 
+def pool_kv_heads(kv_heads: int) -> int:
+    """KV heads a paged pool holds for a model with `kv_heads` of them
+    (`serve/cache.py` asks): as many, or — past one sublane tile of 8 and
+    not in whole tiles — the next multiple of 8, the extra heads never
+    written. The device pads a (KV, Dh) plane to whole tiles whatever it
+    is told, and the kernels' view of a page as `bs * KV` rows is then a
+    copy of the whole pool in every call (30 heads: eight 480 MB copies a
+    decode step, compiled for v5e); in whole tiles it is the same bytes."""
+    if kv_heads <= 8 or kv_heads % 8 == 0:
+        return kv_heads
+    return -(-kv_heads // 8) * 8
+
+
+def to_pool_heads(q, k, v, held: int):
+    """q (B, L, H, Dh), k and v (B, L, KV, Dh) as a pool of `held` >= KV
+    heads takes them (`pool_kv_heads`): zero heads behind k's and v's
+    and zero query groups behind q's, so that query head h still meets
+    KV head h // (H // KV). With held == KV, the operands themselves."""
+    B, L, KV, Dh = k.shape
+    if held == KV:
+        return q, k, v
+    grow = lambda a: jnp.pad(
+        a, [(0, held - KV) if i == 2 else (0, 0) for i in range(a.ndim)]
+    )
+    q = grow(q.reshape(B, L, KV, -1, Dh)).reshape(B, L, -1, Dh)
+    return q, grow(k), grow(v)
+
+
+def from_pool_heads(o, kv_heads: int, held: int):
+    """The attention output (B, L, heads * Dh) of `to_pool_heads`'
+    operands without the padded groups: (B, L, H * Dh)."""
+    if held == kv_heads:
+        return o
+    B, L, _ = o.shape
+    return o.reshape(B, L, held, -1)[:, :, :kv_heads].reshape(B, L, -1)
+
+
 def paged_kernel(L: int, pool, block_tables, window=None):
     """Which kernel of this module takes the call: "decode"
     (`paged_decode_attention`), "chunk" (`paged_chunk_attention`) or
     None (the caller gathers the row's layout and runs the dense
     einsum) — THE predicate, read by `Attention._decode_paged` (which
-    path to trace) and by `serve.decode.kernel_layers` (which path the
+    path to trace) and by `serve.decode.layer_paths` (which path the
     engine's counters name), from what both can see: the query length,
     the K pool and the block tables (arrays or `ShapeDtypeStruct`s;
     shapes and dtype alone are read), the layer's window, and the

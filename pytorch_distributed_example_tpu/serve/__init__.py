@@ -12,6 +12,18 @@ pool utilization included — exposed over the debug HTTP frontend
 (`metrics.py`). The serve cells of `bench_matrix/` measure it on the
 chip.
 
+Three kinds of state, one manager (`cache.py::PagedKVCache`; the programs
+take one block table a kind the model has, `cfg.cache_kinds`): FULL K/V
+blocks (every key and value, paged and refcounted; nothing is refused
+with them), WINDOW K/V blocks (a window layer's last `window + chunk`
+keys in a second pool, recycled while the request runs) and ONE
+RECURRENT BLOCK a request (a linear-attention layer keeps no K/V and
+gets no K/V pool: a float32 state and a conv tail, held from admission
+to retirement, zero for a row at position 0, untouched by padding and
+parked lanes). With window layers or with linear layers the engine
+refuses `prefix_cache`, `kv_quant`, `mesh=`, disaggregated roles and
+`precompiled=`; a preempted linear-layer request prefills again from 0.
+
 Prefix sharing (ISSUE 12): the pool's physical blocks are refcounted
 with copy-on-write divergence (`cache.py`), and a radix prefix index
 (`prefix.py`) maps a new request's longest cached prompt prefix to
@@ -47,6 +59,7 @@ from .cache import (  # noqa: F401
 from .decode import (  # noqa: F401
     carry_key,
     kernel_layers,
+    layer_paths,
     paged_programs,
     sync_slot_lanes,
 )

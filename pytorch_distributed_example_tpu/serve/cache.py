@@ -32,6 +32,21 @@ the attention paths never read before a row's first attended block.
 live-block counts account both kinds. A model with no window layer
 gets exactly the single-kind tree, tables and accounting.
 
+A THIRD KIND, recurrent state (a model whose `cfg.linear_layers` are
+linear-attention mixers, `models/transformer.py::LinearAttention`): such a
+layer keeps NO keys and values, so it gets no K/V pool; it gets a pool
+of `slots` STATE BLOCKS, each the layer's whole memory of one request
+(`state` (H, dk, dv) float32 and `conv`, the few pre-conv inputs behind
+the last token). A request holds exactly one state block from
+`allocate()` to `free()`, whatever its length, addressed through a
+(slots, 1) table of its own that rides with the K/V tables (`tables()`:
+one table a kind the model has, in `cfg.cache_kinds`' order). An invalid
+entry drops the write, as for K/V, and a block is never cleared: the
+mixer reads zero for a row whose first token stands at position 0, so a
+block taken over from a retired or preempted request starts clean. What
+is not carried for it: snapshots (a preempted request prefills again
+from 0; a prefix cannot be shared).
+
 Physical blocks are REFCOUNTED (ISSUE 12): `attach_prefix` lets a
 slot reference blocks another request already filled (the prefix
 cache, `serve/prefix.py`), `free()` DECREMENTS instead of releasing
@@ -124,13 +139,23 @@ def window_layers_of(cfg) -> tuple:
     return tuple(getattr(cfg, "window_layers", ())) or (False,) * cfg.n_layers
 
 
+def linear_layers_of(cfg) -> tuple:
+    """The layers that keep a recurrent state and no K/V (a model
+    configuration that says nothing has none)."""
+    return tuple(getattr(cfg, "linear_layers", ()))
+
+
 def init_paged_cache(model, num_blocks: int, block_size: int,
                      quantized: bool = False,
-                     window_blocks: Optional[int] = None):
+                     window_blocks: Optional[int] = None,
+                     state_blocks: Optional[int] = None):
     """Empty paged K/V pool tree for `model`: per layer one
-    (num_blocks, block_size, kv_heads, head_dim) K and V — of
+    (num_blocks, block_size, kv_heads, head_dim) K and V (kv_heads in
+    whole sublane tiles: `ops.paged_attention.pool_kv_heads`) — of
     `window_blocks` blocks instead in a layer the model's pattern marks
-    as a window layer. Mirrors
+    as a window layer, and NONE in a linear layer, which gets
+    `state_blocks` state blocks (`models.transformer.linear_state_shapes`) under its mixer's
+    name. Mirrors
     `models.generate.init_cache`'s structure minus the scalar "index"
     leaf (a shared pool has no per-row cursor).
 
@@ -144,11 +169,17 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
     `ops.gather_paged_kv`, so the attention math stays cfg.dtype."""
     import jax.numpy as jnp
 
+    from ..models.transformer import linear_state_shapes
+    from ..ops.paged_attention import pool_kv_heads
+
     cfg = model.cfg
-    KV, Dh = cfg.kv_heads, cfg.head_dim
+    KV, Dh = pool_kv_heads(cfg.kv_heads), cfg.head_dim
     windowed = window_layers_of(cfg)
     if any(windowed) and window_blocks is None:
         raise ValueError("a model with window layers needs window_blocks")
+    linear = linear_layers_of(cfg)
+    if linear and state_blocks is None:
+        raise ValueError("a model with linear layers needs state_blocks")
 
     def one_layer(num_blocks):
         if quantized:
@@ -175,8 +206,15 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
             }
         }
 
+    def state_layer():
+        return {"linear_attn": {
+            leaf: jnp.zeros((state_blocks,) + shape, dtype)
+            for leaf, (shape, dtype) in linear_state_shapes(cfg).items()
+        }}
+
     return {
-        f"layers_{i}": one_layer(window_blocks if windowed[i] else num_blocks)
+        f"layers_{i}": state_layer() if i in linear else one_layer(
+            window_blocks if windowed[i] else num_blocks)
         for i in range(cfg.n_layers)
     }
 
@@ -227,7 +265,17 @@ class PagedKVCache:
         M = cfg.max_seq_len
         windowed = window_layers_of(cfg)
         self.window_layers = sum(windowed)
-        self.full_layers = cfg.n_layers - self.window_layers
+        self.linear_layers = len(linear_layers_of(cfg))
+        from ..models.transformer import linear_state_shapes
+        from ..ops.paged_attention import pool_kv_heads
+
+        self.pool_kv_heads = pool_kv_heads(cfg.kv_heads)  # as the pool holds them
+        # leaf -> (shape, dtype) of one state block of one linear layer
+        self._state_shapes = linear_state_shapes(cfg) if self.linear_layers else {}
+        self.full_layers = cfg.n_layers - self.window_layers - self.linear_layers
+        # the kinds of state the model's layers keep, in the order the
+        # programs take their tables
+        self.kinds = tuple(getattr(cfg, "cache_kinds", ("full",)))
         self.model = model
         self.slots = slots
         self.block_size = block_size
@@ -258,9 +306,15 @@ class PagedKVCache:
             )
             self.window_num_blocks = slots * self.window_blocks_per_slot
         self.window_invalid_block = self.window_num_blocks
+        # the state kind: one block a slot, held from allocate() to free()
+        self.state_num_blocks = slots if self.linear_layers else 0
+        self.state_invalid_block = self.state_num_blocks
+        self.state_table = np.full((slots, 1), self.state_invalid_block, np.int32)
+        self._state_free: List[int] = list(range(self.state_num_blocks))
         self.tree = init_paged_cache(
             model, num_blocks, block_size, quantized=quantized,
             window_blocks=self.window_num_blocks or None,
+            state_blocks=self.state_num_blocks or None,
         )
         self.window_tables = np.full(
             (slots, self.blocks_per_seq), self.window_invalid_block, np.int32
@@ -291,12 +345,15 @@ class PagedKVCache:
 
     # -- slot lifecycle ----------------------------------------------------
     def allocate(self) -> Optional[int]:
-        """A free slot index (no blocks yet — those come on write), or
-        None when every slot is taken."""
+        """A free slot index (no K/V blocks yet — those come on write;
+        with linear layers, its one state block), or None when every
+        slot is taken."""
         if not self._free_slots:
             return None
         s = self._free_slots.pop(0)
         self._in_use[s] = True
+        if self.linear_layers:  # as many state blocks as slots: never dry
+            self.state_table[s, 0] = self._state_free.pop(0)
         return s
 
     def free(self, slot: int) -> int:
@@ -317,6 +374,9 @@ class PagedKVCache:
         self._window_free.extend(self._window_slot_blocks[slot].values())
         self._window_slot_blocks[slot] = {}
         self.window_tables[slot, :] = self.window_invalid_block
+        if self.linear_layers:
+            self._state_free.append(int(self.state_table[slot, 0]))
+            self.state_table[slot, 0] = self.state_invalid_block
         self._in_use[slot] = False
         self.lengths[slot] = 0
         self._free_slots.append(slot)
@@ -393,18 +453,22 @@ class PagedKVCache:
 
     def tables(self, rows=slice(None), parked=()):
         """The block tables the programs take for the given slots: the
-        (n, nb) table, or with window layers the pair (full layers',
-        window layers'). `parked` slots' rows are handed over all-invalid
-        (copies; the manager's tables are untouched)."""
-        out = [self.block_tables[rows]]
-        if self.window_layers:
-            out.append(self.window_tables[rows])
+        (n, nb) table, or where the model's layers keep more than one
+        kind of state the tuple of one table a kind (full layers' (n,
+        nb), window layers' (n, nb), linear layers' (n, 1) state table),
+        in `cfg.cache_kinds`' order. `parked` slots' rows are handed
+        over all-invalid (copies; the manager's tables are untouched)."""
+        have = {
+            "full": (self.block_tables, self.invalid_block),
+            "window": (self.window_tables, self.window_invalid_block),
+            "linear": (self.state_table, self.state_invalid_block),
+        }
+        out = [have[kind][0][rows] for kind in self.kinds]
         if len(parked):
             out = [t.copy() for t in out]
-            out[0][parked] = self.invalid_block
-            if self.window_layers:
-                out[1][parked] = self.window_invalid_block
-        return tuple(out) if self.window_layers else out[0]
+            for t, kind in zip(out, self.kinds):
+                t[parked] = have[kind][1]
+        return tuple(out) if len(out) > 1 else out[0]
 
     # -- refcount plumbing -------------------------------------------------
     def _take_block(self) -> int:
@@ -560,6 +624,15 @@ class PagedKVCache:
         """Window-layer blocks some slot holds (0 with no window layer)."""
         return self.window_num_blocks - len(self._window_free)
 
+    @property
+    def state_live_blocks(self) -> int:
+        """State blocks some slot holds (0 with no linear layer)."""
+        return self.state_num_blocks - len(self._state_free)
+
+    def state_block(self, slot: int) -> int:
+        """The slot's state block (== `state_invalid_block`: none)."""
+        return int(self.state_table[slot, 0])
+
     def window_slot_blocks(self, slot: int) -> Dict[int, int]:
         """logical block -> physical window block of a slot."""
         return dict(self._window_slot_blocks[slot])
@@ -601,9 +674,21 @@ class PagedKVCache:
 
         cfg = self.model.cfg
         return jax.ShapeDtypeStruct(
-            (self.num_blocks, self.block_size, cfg.kv_heads, cfg.head_dim),
+            (self.num_blocks, self.block_size, self.pool_kv_heads, cfg.head_dim),
             np.int8 if self.quantized else cfg.dtype,
         )
+
+    @property
+    def state_aval(self):
+        """Shape and dtype of one linear layer's `state` pool (None with
+        no linear layer), for callers that ask about it without naming
+        the tree's keys."""
+        if not self.linear_layers:
+            return None
+        import jax
+
+        shape, dtype = self._state_shapes["state"]
+        return jax.ShapeDtypeStruct((self.state_num_blocks,) + shape, dtype)
 
     @functools.cached_property
     def bytes_per_block(self) -> int:
@@ -615,7 +700,7 @@ class PagedKVCache:
             1 if self.quantized else np.dtype(cfg.dtype).itemsize
         )
         return (
-            2 * self.full_layers * self.block_size * cfg.kv_heads
+            2 * self.full_layers * self.block_size * self.pool_kv_heads
             * cfg.head_dim * itemsize
         ) + self.scale_bytes_per_block
 
@@ -624,8 +709,16 @@ class PagedKVCache:
         """HBM bytes one window-kind block pins across the window layers."""
         cfg = self.model.cfg
         return (
-            2 * self.window_layers * self.block_size * cfg.kv_heads
+            2 * self.window_layers * self.block_size * self.pool_kv_heads
             * cfg.head_dim * np.dtype(cfg.dtype).itemsize
+        )
+
+    @functools.cached_property
+    def state_bytes_per_block(self) -> int:
+        """HBM bytes one state block pins across the linear layers."""
+        return self.linear_layers * sum(
+            int(np.prod(shape)) * np.dtype(dtype).itemsize
+            for shape, dtype in self._state_shapes.values()
         )
 
     @functools.cached_property
@@ -635,7 +728,7 @@ class PagedKVCache:
         if not self.quantized:
             return 0
         cfg = self.model.cfg
-        return 2 * cfg.n_layers * self.block_size * cfg.kv_heads * 4
+        return 2 * cfg.n_layers * self.block_size * self.pool_kv_heads * 4
 
     @property
     def wire_dtype(self) -> str:
@@ -657,18 +750,20 @@ class PagedKVCache:
         return (
             self.live_blocks * self.bytes_per_block
             + self.window_live_blocks * self.window_bytes_per_block
+            + self.state_live_blocks * self.state_bytes_per_block
         )
 
     @functools.cached_property
     def dense_bytes_per_request(self) -> int:
         """What ONE slot costs in the dense (slots, max_seq_len, ...)
-        layout — the paged-vs-dense comparison baseline."""
+        layout — the paged-vs-dense comparison baseline (a linear
+        layer's share is its state block in either layout)."""
         cfg = self.model.cfg
         itemsize = np.dtype(cfg.dtype).itemsize
         return (
-            2 * cfg.n_layers * cfg.max_seq_len * cfg.kv_heads
-            * cfg.head_dim * itemsize
-        )
+            2 * (cfg.n_layers - self.linear_layers) * cfg.max_seq_len
+            * cfg.kv_heads * cfg.head_dim * itemsize
+        ) + self.state_bytes_per_block
 
     def slot_blocks(self, slot: int) -> List[int]:
         return list(self._slot_blocks[slot])

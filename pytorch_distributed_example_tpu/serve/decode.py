@@ -26,6 +26,7 @@ from ..models.generate import sample_logits
 __all__ = [
     "paged_programs",
     "kernel_layers",
+    "layer_paths",
     "sync_slot_lanes",
     "carry_key",
 ]
@@ -77,28 +78,44 @@ def _kernel_partition(mesh, tp_axis: str):
     return partitioned_over(mesh, (), (tp_axis,))
 
 
-def kernel_layers(
+def layer_paths(
     cache, rows: int, L: int, mesh=None, tp_axis: str = "tp"
-) -> int:
-    """Of the model's layers, those whose attention call traces a kernel
-    of `ops/paged_attention.py` and not the gather + einsum when a
+) -> dict:
+    """kind -> (layers of that kind, the path their mixer traces) when a
     program of `paged_programs(..., mesh, tp_axis)` applies the model to
     `rows` rows of `L` tokens (`step`: every slot, one token;
-    `prefill_chunk`: one row, the chunk): `ops.paged_kernel` for each
-    kind of layer `cache` (a `PagedKVCache`) holds state for, under the
-    context the programs apply the model under — the fact `ServeMetrics`'
-    kernel counters name."""
+    `prefill_chunk`: one row, the chunk), for each kind of state `cache`
+    (a `PagedKVCache`) holds. An attention layer takes "decode_kernel"
+    or "chunk_kernel" (`ops/paged_attention.py`) or "gather" (the gather
+    + einsum), as `ops.paged_kernel` says under the context the programs
+    apply the model under; a linear layer takes, for one token a row,
+    its "recurrence_kernel" (`ops/delta_recurrence.py`, where
+    `delta_kernel_ok` says so) or the plain "recurrence", and for a chunk
+    its "chunk_scan"."""
     from ..ops import paged_kernel
+    from ..ops.delta_recurrence import delta_kernel_ok
 
+    paths = {}
     with _kernel_partition(mesh, tp_axis):
-        n = cache.full_layers * bool(
-            paged_kernel(L, cache.pool_aval, cache.block_tables[:rows])
-        )
-        if cache.window_layers:
-            n += cache.window_layers * bool(paged_kernel(
-                L, cache.pool_aval, cache.window_tables[:rows], cache.window
-            ))
-    return n
+        for kind, layers, table, window in (
+            ("full", cache.full_layers, cache.block_tables, None),
+            ("window", cache.window_layers, cache.window_tables, cache.window),
+        ):
+            if layers:
+                kernel = paged_kernel(L, cache.pool_aval, table[:rows], window)
+                paths[kind] = (layers, f"{kernel}_kernel" if kernel else "gather")
+        if cache.linear_layers:
+            step = "recurrence_kernel" if delta_kernel_ok(cache.state_aval) else "recurrence"
+            paths["linear"] = (cache.linear_layers, step if L == 1 else "chunk_scan")
+    return paths
+
+
+def kernel_layers(paths: dict) -> int:
+    """Of the layers `layer_paths` gave the paths of, those whose mixer
+    traces a Pallas kernel (an attention layer one of
+    `ops/paged_attention.py`, a linear layer `ops/delta_recurrence.py`'s)
+    — the fact `ServeMetrics`' kernel counters name."""
+    return sum(n for n, path in paths.values() if path.endswith("_kernel"))
 
 
 def sync_slot_lanes(lengths, tokens, rngs):
@@ -176,15 +193,20 @@ def paged_programs(
       (never in any live block), the kernel reads no page for them, and
       their sampled tokens are ignored by the scheduler.
 
-    Where some layers keep a window of K/V (`serve/cache.py`), `bt_row`
-    and `bt` are the pair (full layers' tables, window layers' tables).
+    Where the layers keep more than one kind of state (`serve/cache.py`:
+    every key and value, a window of them, a recurrent state), `bt_row`
+    and `bt` are the tuple of one table a kind, in `cfg.cache_kinds`'
+    order.
 
     A model with SPARSE layers (`cfg.sparse_layers`) is told which rows
-    are real, because a row that is not must route to no expert: in
+    are real, because a row that is not must route to no expert, and so
+    is a model with LINEAR layers (`cfg.linear_layers`), whose padding
+    must leave a row's recurrent state as its last token left it: in
     `prefill_chunk` the engine pads a chunk with token id -1 (the rows
     `chunk >= 0` are real; the padding embeds as token 0), in `step` a
     row is live when its table row holds a valid block (the engine hands
-    parked and mid-prefill lanes over all-invalid).
+    parked and mid-prefill lanes over all-invalid; the linear layers
+    need no telling there: an invalid state block drops the write).
     Its `step` returns a FIFTH value, the step's one host readback:
     int32 (S + 2 * sparse layers,) = the next tokens, then per sparse
     layer (assignments computed, distinct experts with a row) — the
@@ -196,11 +218,14 @@ def paged_programs(
 
     M = model.cfg.max_seq_len
     sparse = tuple(getattr(model.cfg, "sparse_layers", ()))
+    masked = bool(sparse or getattr(model.cfg, "linear_layers", ()))
 
     def apply_paged(params, tree, tokens, positions, bt, row_mask=None):
         kw, mutable = {}, ["cache"]
+        if masked:
+            kw = {"row_mask": row_mask}
         if sparse:
-            kw, mutable = {"row_mask": row_mask}, ["cache", "intermediates"]
+            mutable = ["cache", "intermediates"]
         with _kernel_partition(mesh, tp_axis):
             return model.apply(
                 {"params": params, "cache": tree}, tokens, decode=True,
@@ -210,7 +235,7 @@ def paged_programs(
     @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill_chunk(params, tree, chunk, bt_row, start):
         row_mask = None
-        if sparse:
+        if masked:
             row_mask, chunk = chunk >= 0, jnp.maximum(chunk, 0)
         logits, vars2 = apply_paged(
             params, tree, chunk, jnp.asarray(start, jnp.int32)[None], bt_row,
@@ -252,11 +277,13 @@ def paged_programs(
             subs, new_rngs = split[:, 0], split[:, 1]
         row_mask = None
         if sparse:
-            # a live row holds a block; any layer's kind of table says so
-            kinds = model.cfg.window_layers
-            table = bt[int(kinds[0])] if isinstance(bt, (tuple, list)) else bt
-            pool = tree["layers_0"]["attn"]["k"]
-            row_mask = jnp.any(table < pool.shape[0], axis=1, keepdims=True)
+            # a live row holds a block; any layer's kind of table says so:
+            # layer 0's, whose every leaf leads with its pool's blocks
+            kinds = model.cfg.cache_kinds
+            kind = model.cfg.layers[0].attention
+            table = bt[kinds.index(kind)] if isinstance(bt, (tuple, list)) else bt
+            blocks = jax.tree_util.tree_leaves(tree["layers_0"])[0].shape[0]
+            row_mask = jnp.any(table < blocks, axis=1, keepdims=True)
         logits, vars2 = apply_paged(
             params, tree, tokens[:, None], lengths, bt, row_mask
         )

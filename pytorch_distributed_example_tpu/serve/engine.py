@@ -69,7 +69,13 @@ the assignments computed and the distinct experts hit per sparse layer
 (`ServeMetrics.record_moe_step`; also a `serve:moe_step` host annotation
 while a profiler trace is on). What is not carried with window layers is
 refused at construction: prefix sharing, the int8 pool, a tp mesh,
-disaggregated roles and pre-warmed executables.
+disaggregated roles and pre-warmed executables. A model with LINEAR
+(recurrent-state) layers holds one state block a request beside its K/V
+blocks from admission to retirement (`serve/cache.py`): its chunk
+padding is told apart from tokens and leaves the state as the last real
+token left it, a chunk that starts at position 0 reads a zero state
+(so preemption frees the block and the requeued request prefills again
+from 0: no snapshot is kept), and the same five things are refused.
 
 Fault surface: `serve.admit` before each admission, `serve.
 prefix_attach` before a prefix-cache attach, `serve.prefill_chunk`
@@ -123,8 +129,8 @@ from .. import faults
 from ..numerics import numerics_contract
 from ..types import DistError
 from .bucketing import bucket_for, bucket_lengths
-from .cache import PagedKVCache, window_layers_of
-from .decode import kernel_layers, paged_programs, sync_slot_lanes
+from .cache import PagedKVCache, linear_layers_of, window_layers_of
+from .decode import kernel_layers, layer_paths, paged_programs, sync_slot_lanes
 from .metrics import ServeMetrics
 from .queue import (
     DEFAULT_CLASS,
@@ -213,8 +219,10 @@ class ServeEngine:
         self.model = model
         self.params = params["params"] if "params" in params else params
         self.cfg = model.cfg
+        # what is not carried with window layers, and with linear layers
+        refusals = {}
         if any(window_layers_of(self.cfg)):
-            refused = {
+            refusals["window"] = {
                 "prefix_cache=True (a shared prefix's window-layer blocks "
                 "are recycled under its other holders)": prefix_cache,
                 "kv_quant=True (an int8 pool of two kinds of blocks is untested)":
@@ -226,14 +234,33 @@ class ServeEngine:
                 "precompiled= (pre-warmed programs take one table)":
                     bool(precompiled),
             }
+        if linear_layers_of(self.cfg):
+            refusals["linear"] = {
+                "prefix_cache=True (a shared prefix's recurrent state is "
+                "not snapshotted at the prefix's end)": prefix_cache,
+                "kv_quant=True (an int8 pool beside float32 state blocks is "
+                "untested)": kv_quant,
+                "mesh= (the state pool and the recurrence are not "
+                "partitioned over tp)": mesh is not None,
+                f"role={role!r} (block migration moves K/V blocks, not a "
+                "state block)": role != "both",
+                "precompiled= (pre-warmed programs take one table)":
+                    bool(precompiled),
+            }
+        for kind, refused in refusals.items():
             for what, asked in refused.items():
                 if asked:
                     raise ValueError(
-                        f"a model with window layers cannot be served with {what}"
+                        f"a model with {kind} layers cannot be served with {what}"
                     )
         # sparse (dropless MoE) layers: padding routes nowhere, and the
         # decode step's readback carries two counters a layer
         self._sparse_layers = len(getattr(self.cfg, "sparse_layers", ()))
+        # sparse and linear layers are told which positions of a chunk
+        # are padding (`serve/decode.py::paged_programs`)
+        self._pad_id = (
+            -1 if self._sparse_layers or linear_layers_of(self.cfg) else 0
+        )
         self.temperature = temperature
         self.top_k = top_k
         self.eos_id = eos_id
@@ -299,18 +326,24 @@ class ServeEngine:
             self._attach,
             self._step,
         ) = paged_programs(model, temperature, top_k, jmesh, tp_axis)
-        # how many layers' attention calls take a kernel of
-        # `ops/paged_attention.py`, for the metrics: in the step, and in
-        # a chunk of each length the engine dispatches — facts of the
-        # engine's lifetime
+        # which path each kind of layer takes (`serve/decode.py::
+        # layer_paths`), for the metrics: in the step, and in a chunk of
+        # each length the engine dispatches — facts of the engine's
+        # lifetime
         layers = model.cfg.n_layers
-        self._decode_kernel = (
-            kernel_layers(self.cache, slots, 1, jmesh, tp_axis) == layers
-        )
-        self._chunk_kernel_layers = {
-            C: kernel_layers(self.cache, 1, C, jmesh, tp_axis)
+        step_paths = layer_paths(self.cache, slots, 1, jmesh, tp_axis)
+        chunk_paths = {
+            C: layer_paths(self.cache, 1, C, jmesh, tp_axis)
             for C in {*self.buckets, prefill_chunk_tokens} - {None}
         }
+        self._decode_kernel = kernel_layers(step_paths) == layers
+        self._chunk_kernel_layers = {
+            C: kernel_layers(paths) for C, paths in chunk_paths.items()
+        }
+        self.metrics.record_layer_paths(
+            step_paths,
+            chunk_paths[prefill_chunk_tokens or max(chunk_paths)],
+        )
         if precompiled:
             # resize fast path (serve/prewarm.py): overlay pre-warmed
             # executables — matching shapes skip trace AND compile,
@@ -714,9 +747,9 @@ class ServeEngine:
             except _TRANSIENT:
                 self._evict(slot, requeue_counter=True)
                 continue
-            # padding is token 0, or -1 where sparse layers must tell it
-            # from a token (`serve/decode.py::paged_programs`)
-            chunk = np.full((1, C), -1 if self._sparse_layers else 0, np.int32)
+            # padding is token 0, or -1 where sparse or linear layers must
+            # tell it from a token (`serve/decode.py::paged_programs`)
+            chunk = np.full((1, C), self._pad_id, np.int32)
             chunk[0, : end - pf.pos] = req.prompt[pf.pos:end]
             self.cache.tree, logits = self._prefill_chunk(
                 self.params,
@@ -915,6 +948,8 @@ class ServeEngine:
             window_blocks_live=self.cache.window_live_blocks,
             window_blocks_recycled=self.cache.window_blocks_recycled,
             window_bytes_per_block=self.cache.window_bytes_per_block,
+            state_blocks_live=self.cache.state_live_blocks,
+            state_bytes_per_block=self.cache.state_bytes_per_block,
         )
         while True:
             self._prefill_tick()

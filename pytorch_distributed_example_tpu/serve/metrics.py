@@ -164,7 +164,7 @@ class ServeMetrics:
         self.decode_kernel_steps = 0
         # prefill chunks dispatched, their attention calls (one a layer)
         # and those of them that took a kernel of `ops/paged_attention.py`
-        # (`serve.decode.kernel_layers` at each chunk's length)
+        # (`serve.decode.layer_paths` at each chunk's length)
         self.prefill_chunks = 0
         self.prefill_attention_calls = 0
         self.prefill_kernel_calls = 0
@@ -184,6 +184,14 @@ class ServeMetrics:
         self.window_blocks_live = 0
         self.window_bytes_per_block = 0
         self.window_blocks_recycled = 0
+        # the third kind, a linear layer's recurrent state: one block a
+        # live request (gauge), and the bytes a block pins
+        self.state_blocks_live = 0
+        self.state_bytes_per_block = 0
+        # kind of layer -> [layers, the path their mixer takes] in the
+        # decode step and in a prefill chunk (`serve.decode.layer_paths`)
+        self.decode_layer_paths: Dict[str, list] = {}
+        self.prefill_layer_paths: Dict[str, list] = {}
         # paged-pool gauges (last observation) + time-mean accumulators
         self.pool_blocks_live = 0
         self.pool_blocks_total = 0
@@ -291,6 +299,18 @@ class ServeMetrics:
             self.decode_steps += 1
             self.decode_kernel_steps += bool(kernel)
 
+    @property
+    def state_bytes_live(self) -> int:
+        """Bytes the live requests' recurrent state blocks pin."""
+        return self.state_blocks_live * self.state_bytes_per_block
+
+    def record_layer_paths(self, decode: Dict, prefill: Dict) -> None:
+        """Which path each kind of layer takes in the engine's step and
+        in its chunk of the budget's length: facts of its lifetime."""
+        with self._lock:
+            self.decode_layer_paths = {k: list(v) for k, v in decode.items()}
+            self.prefill_layer_paths = {k: list(v) for k, v in prefill.items()}
+
     def record_prefill_chunk(self, kernel_layers: int, layers: int) -> None:
         with self._lock:
             self.prefill_chunks += 1
@@ -370,6 +390,8 @@ class ServeMetrics:
         window_blocks_live: int = 0,
         window_blocks_recycled: int = 0,
         window_bytes_per_block: int = 0,
+        state_blocks_live: int = 0,
+        state_bytes_per_block: int = 0,
     ) -> None:
         """Per-step paged-pool observation. Gauges keep the LAST value;
         utilization and bytes-per-live-request also accumulate a
@@ -388,6 +410,8 @@ class ServeMetrics:
             self.window_blocks_live = window_blocks_live
             self.window_blocks_recycled = window_blocks_recycled
             self.window_bytes_per_block = window_bytes_per_block
+            self.state_blocks_live = state_blocks_live
+            self.state_bytes_per_block = state_bytes_per_block
             self.pool_blocks_live = blocks_live
             self.pool_blocks_total = blocks_total
             self.pool_bytes_per_block = bytes_per_block
@@ -422,6 +446,7 @@ class ServeMetrics:
                 self._bytes_per_req_sum += (
                     blocks_live * bytes_per_block
                     + window_blocks_live * window_bytes_per_block
+                    + state_blocks_live * state_bytes_per_block
                 ) / live_requests
                 self._bytes_per_req_samples += 1
 
@@ -665,6 +690,7 @@ class ServeMetrics:
                     "kernel_share": round(
                         self.decode_kernel_steps / self.decode_steps, 4
                     ) if self.decode_steps else 0.0,
+                    "layer_paths": dict(self.decode_layer_paths),
                 },
                 "prefill": {
                     "chunks": self.prefill_chunks,
@@ -673,6 +699,7 @@ class ServeMetrics:
                         self.prefill_kernel_calls
                         / self.prefill_attention_calls, 4
                     ) if self.prefill_attention_calls else 0.0,
+                    "layer_paths": dict(self.prefill_layer_paths),
                 },
                 "moe": {
                     "steps": self.moe_steps,
@@ -705,6 +732,7 @@ class ServeMetrics:
                     "bytes_live": (
                         self.pool_blocks_live * self.pool_bytes_per_block
                         + self.window_blocks_live * self.window_bytes_per_block
+                        + self.state_bytes_live
                     ),
                     "bytes_per_live_request_mean": round(mean_bpr, 1),
                     "dense_bytes_per_request": self.dense_bytes_per_request,
@@ -722,6 +750,8 @@ class ServeMetrics:
                     "full_blocks_live": self.pool_blocks_live,
                     "window_blocks_live": self.window_blocks_live,
                     "window_blocks_recycled": self.window_blocks_recycled,
+                    "state_blocks_live": self.state_blocks_live,
+                    "state_bytes_live": self.state_bytes_live,
                 },
                 # prefix sharing (ISSUE 12): hit rate + tokens whose
                 # prefill compute/pool writes were skipped, block-level

@@ -197,6 +197,67 @@ def test_paged_decode_kernel_compiles_at_mistral_widths(topo, monkeypatch):
     assert len(pool_ops) == 2 and all(" bitcast(" in l for l in pool_ops)
 
 
+def test_paged_decode_kernel_reads_a_30_head_model_s_pool_without_a_copy(topo, monkeypatch):
+    """30 KV heads (a query group of one): the device pads a (30, 128)
+    plane to 32 rows, so over a pool of 30 heads the kernel's (num_blocks,
+    bs * KV, Dh) view is a COPY of the pool in every call (eight 480 MB
+    copies a step: refused for memory). `pool_kv_heads` gives the pool 32,
+    and then the view is a bitcast again."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_example_tpu.ops.paged_attention import pool_kv_heads
+    from pytorch_distributed_example_tpu.ops import paged_decode_attention
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    B, Dh, bs, nblk, nb = 32, 128, 16, 4096, 512
+    assert pool_kv_heads(30) == 32 and pool_kv_heads(8) == 8 and pool_kv_heads(2) == 2
+    copies = {}
+    for KV in (30, pool_kv_heads(30)):
+        pool = sd((nblk, bs, KV, Dh), jnp.bfloat16)
+        hlo = jax.jit(paged_decode_attention).lower(
+            sd((B, KV, Dh), jnp.bfloat16), pool, pool,
+            sd((B, nb), jnp.int32), sd((B,), jnp.int32),
+        ).compile().as_text()
+        assert len(_custom_calls(hlo)) == 1
+        views = [l for l in hlo.splitlines() if f" = bf16[{nblk},{bs * KV},{Dh}]" in l]
+        copies[KV] = sum(" bitcast(" not in l for l in views)
+    assert copies == {30: 2, 32: 0}
+
+
+def test_the_recurrence_kernel_compiles_at_the_hybrid_cell_s_widths(topo, monkeypatch):
+    """`ops.delta_recurrence.paged_delta_step` at the hybrid serve cell's
+    shape (32 rows over a pool of 32 state blocks of 30 heads x 96 x 192
+    float32) goes through Mosaic for a v5e, with the pool aliased from its
+    input to its output: no copy of it, no temporary."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_example_tpu.ops.delta_recurrence import (
+        delta_kernel_ok, paged_delta_step)
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    nblk, B, H, dk, dv = 32, 32, 30, 96, 192
+    pool = sd((nblk, H, dk, dv), jnp.float32)
+    assert delta_kernel_ok(pool)
+    vec = lambda d: sd((B, H, d), jnp.float32)
+    compiled = jax.jit(paged_delta_step, donate_argnums=(0,)).lower(
+        pool, sd((B,), jnp.int32), sd((B,), jnp.bool_), vec(dk), vec(dk), vec(dv),
+        sd((B, H), jnp.float32), sd((B, H), jnp.float32),
+    ).compile()
+    (call,) = _custom_calls(compiled.as_text())
+    assert "paged_delta_step" in call
+    mem = compiled.memory_analysis()
+    nbytes = nblk * H * dk * dv * 4
+    assert mem.alias_size_in_bytes >= nbytes and mem.temp_size_in_bytes < nbytes // 8
+
+
 @pytest.mark.parametrize("heads,window,nblk", [(48, None, 16384), (64, 512, 2112)])
 def test_paged_decode_kernel_compiles_at_the_patterned_cell_s_widths(
     topo, monkeypatch, heads, window, nblk

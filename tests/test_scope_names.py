@@ -23,6 +23,7 @@ from pytorch_distributed_example_tpu.models.transformer import (
 # Flax names a method other than __call__ `<module>.<method>` in the path
 LAYER = r"TransformerLM\)*/layers_\d+/attn/(attn\.\w+/)*"
 MLP = r"TransformerLM\)*/layers_\d+/mlp/"
+LINEAR = r"TransformerLM\)*/layers_\d+/linear_attn/"
 # program -> scope -> where it must appear (a regex on the whole path)
 EXPECTED = {
     "step": {
@@ -82,6 +83,35 @@ EXPECTED = {
             LAYER + r"window_attention/cache_attention/jit\(_per_device\)$",
         "cache_attention": LAYER + r"cache_attention/jit\(_per_device\)$",
     },
+    # a model that mixes linear-attention layers with full attention: the
+    # decode step runs the recurrence, a prefill chunk the chunked scan, and
+    # each sits under the mixer's Flax name with the conv and the gated norm
+    "hybrid_step": {
+        "linear_attn": LINEAR + r"(q|k|v|g|o|a|b)_proj/dot_general",
+        "short_conv": LINEAR + r"short_conv/",
+        "recurrence": LINEAR + r"recurrence/",
+        "state_read": LINEAR + r"recurrence/jit\(_take\)",
+        "state_write": LINEAR + r"recurrence/scatter",
+        "gated_norm": LINEAR + r"gated_norm/",
+        "cache_attention": LAYER + r"cache_attention/.*dot_general",
+        "sample": r"^jit\(step\)/sample/",
+    },
+    # the same model at widths the decode kernel of the recurrence takes:
+    # every linear layer calls the kernel's one jitted body under recurrence
+    # (on the chip `.../recurrence/jit(_call)/paged_delta_step/pallas_call`),
+    # and its state is neither gathered nor scattered by XLA
+    "hybrid_step_kernel": {
+        "recurrence": LINEAR + r"recurrence/jit\(_call\)$",
+        "kernel_body": r"^paged_delta_step/",
+        "tail_write": LINEAR + r"recurrence/scatter",
+    },
+    "hybrid_prefill_chunk": {
+        "short_conv": LINEAR + r"short_conv/",
+        "chunk_scan": LINEAR + r"chunk_scan/.*dot_general",
+        "state_write": LINEAR + r"chunk_scan/scatter",
+        "gated_norm": LINEAR + r"gated_norm/",
+        "kv_scatter": LAYER + r"kv_scatter/scatter",
+    },
     "ddp": {
         "rope": LAYER + r"rope/",
         "flash_attention": LAYER + r"flash_attention/",
@@ -136,6 +166,20 @@ def _pattern_model(head_size):
         model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
 
 
+def _hybrid_model(key_dim=8, value_dim=12):
+    from pytorch_distributed_example_tpu.models.transformer import LayerSpec, RopeSpec
+
+    full = LayerSpec("full", rope=RopeSpec(rotary_fraction=0.0))
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64, max_seq_len=64,
+        use_flash=False, post_norm=True, qk_norm=True, linear_heads=2,
+        linear_key_dim=key_dim, linear_value_dim=value_dim, linear_neg_eigval=True,
+        layers=(LayerSpec("linear"),) * 3 + (full,))
+    model = TransformerLM(cfg)
+    return model, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+
+
 def _loss(logits, y):
     return optax.softmax_cross_entropy_with_integer_labels(
         logits[:, :-1], y[:, 1:]).mean()
@@ -168,6 +212,18 @@ def serve_paths():
             out["pattern_prefill_chunk"] = _paths(pchunk.lower(
                 pvars["params"], ptree, jnp.zeros((1, 16), jnp.int32),
                 (bt[:1], bt[:1]), 0))
+    hybrid, hvars = _hybrid_model()
+    hchunk, _, _, hstep = paged_programs(hybrid, 0.0, None)
+    htree = init_paged_cache(hybrid, nblk, bs, state_blocks=S)
+    state = jnp.zeros((S, 1), jnp.int32)  # each row's state block
+    out["hybrid_step"] = _paths(hstep.lower(
+        hvars["params"], htree, lanes, lanes, rngs, (bt, state)))
+    out["hybrid_prefill_chunk"] = _paths(hchunk.lower(
+        hvars["params"], htree, jnp.zeros((1, 16), jnp.int32), (bt[:1], state[:1]), 0))
+    wide_hybrid, wvars = _hybrid_model(16, 64)
+    out["hybrid_step_kernel"] = _paths(paged_programs(wide_hybrid, 0.0, None)[3].lower(
+        wvars["params"], init_paged_cache(wide_hybrid, nblk, bs, state_blocks=S),
+        lanes, lanes, rngs, (bt, state)))
     return {
         **out,
         "step": _paths(step.lower(params, tree, lanes, lanes, rngs, bt)),
@@ -220,7 +276,8 @@ def train_paths(world):
 
 
 SERVE = ("step", "step_kernel", "prefill_chunk", "first_token", "pattern_step",
-         "pattern_prefill_chunk", "pattern_step_kernel")
+         "pattern_prefill_chunk", "pattern_step_kernel", "hybrid_step",
+         "hybrid_step_kernel", "hybrid_prefill_chunk")
 CASES = [(prog, scope) for prog, scopes_ in EXPECTED.items() for scope in scopes_]
 
 
@@ -233,6 +290,24 @@ def test_scope_sits_under_its_flax_path(program, scope, request):
     assert any(rx.search(p) for p in paths), (
         f"no operation of {program} is traced under {rx.pattern}; paths with "
         f"{scope!r}: {sorted(p for p in paths if scope in p)[:5]}")
+
+
+def test_the_decode_step_scans_nothing_and_a_chunk_runs_no_recurrence(serve_paths):
+    """The two cached forms of a linear layer are told apart by the call's
+    shape, and each program holds one of them only."""
+    step, chunk = serve_paths["hybrid_step"], serve_paths["hybrid_prefill_chunk"]
+    assert step["program"] == "jit_step" and chunk["program"] == "jit_prefill_chunk"
+    assert not [p for p in step["paths"] if "chunk_scan" in p]
+    assert not [p for p in chunk["paths"] if "/recurrence/" in p]
+    # a layer that is not linear traces nothing under the mixer's scopes
+    assert not [p for p in step["paths"] if "layers_3/linear_attn" in p]
+    assert not [p for p in step["paths"] if re.search(r"layers_[012]/attn/", p)]
+    # with the kernel in the step the state is read and written by it alone:
+    # one gather (the conv tail's) a layer where the plain update has two
+    kernel = serve_paths["hybrid_step_kernel"]["paths"]
+    calls = [p for p in kernel if p.endswith("recurrence/jit(_call)")]
+    assert len(calls) == 3  # one a linear layer, each under its own layer's scope
+    assert not [p for p in step["paths"] if "paged_delta_step" in p]
 
 
 def test_the_kernel_step_gathers_nothing(serve_paths):
@@ -252,7 +327,12 @@ def test_the_kernel_step_gathers_nothing(serve_paths):
 # the metric files' own patterns, against the same lowerings
 METRICS = Path(__file__).resolve().parents[1] / "bench_matrix" / "layer_metrics"
 READ_BY = {
-    "decode_cache_attention_ms": ["step", "step_kernel", "pattern_step"],
+    "decode_cache_attention_ms": ["step", "step_kernel", "pattern_step", "hybrid_step"],
+    "decode_linear_attention_ms": ["hybrid_step", "hybrid_step_kernel"],
+    "decode_recurrence_ms": ["hybrid_step", "hybrid_step_kernel"],
+    "recurrence_decode_roofline": ["hybrid_step", "hybrid_step_kernel"],
+    "prefill_linear_attention_ms": ["hybrid_prefill_chunk"],
+    "prefill_chunk_scan_ms": ["hybrid_prefill_chunk"],
     "decode_moe_ms": ["pattern_step"],
     "prefill_moe_ms": ["pattern_prefill_chunk"],
     "decode_window_attention_ms": ["pattern_step", "pattern_step_kernel"],

@@ -231,7 +231,8 @@ def test_public_surface():
             assert getattr(serve, name) is getattr(mod, name), name
     assert sorted(cache.__all__) == ["PagedKVCache", "init_paged_cache"]
     assert sorted(decode.__all__) == [
-        "carry_key", "kernel_layers", "paged_programs", "sync_slot_lanes",
+        "carry_key", "kernel_layers", "layer_paths", "paged_programs",
+        "sync_slot_lanes",
     ]
     assert [n for n in vars(serve) if n.endswith("KVCache")] == ["PagedKVCache"]
 
