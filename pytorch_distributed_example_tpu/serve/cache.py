@@ -457,17 +457,19 @@ class PagedKVCache:
         kind of state the tuple of one table a kind (full layers' (n,
         nb), window layers' (n, nb), linear layers' (n, 1) state table),
         in `cfg.cache_kinds`' order. `parked` slots' rows are handed
-        over all-invalid (copies; the manager's tables are untouched)."""
+        over all-invalid. Always COPIES: the engine goes on growing and
+        freeing rows while the program it handed a table to is still
+        queued, and a program may read its host arguments late (the CPU
+        client aliases them, a device client copies them behind the
+        dispatch)."""
         have = {
             "full": (self.block_tables, self.invalid_block),
             "window": (self.window_tables, self.window_invalid_block),
             "linear": (self.state_table, self.state_invalid_block),
         }
-        out = [have[kind][0][rows] for kind in self.kinds]
-        if len(parked):
-            out = [t.copy() for t in out]
-            for t, kind in zip(out, self.kinds):
-                t[parked] = have[kind][1]
+        out = [have[kind][0][rows].copy() for kind in self.kinds]
+        for t, kind in zip(out, self.kinds):
+            t[list(parked)] = have[kind][1]
         return tuple(out) if len(out) > 1 else out[0]
 
     # -- refcount plumbing -------------------------------------------------

@@ -8,7 +8,9 @@ pool tree plus the per-slot (lengths, last-token, rng-key) vectors —
 lives on DEVICE and is buffer-DONATED through every step, so the
 multi-GB pool is updated in place instead of memcpy'd per token; the
 only host traffic per step is the one next-token readback the
-scheduler genuinely needs for EOS/budget retirement. Programs are
+scheduler genuinely needs for EOS/budget retirement, and that is an
+output of its own, so the engine can read it AFTER the next step has
+taken the donated lanes (`ServeEngine.step`). Programs are
 cached per (model, sampling knobs, mesh) exactly like
 `generate._programs` (flax Modules are frozen dataclasses — hashable,
 equal by config). `paged_programs` describes the quadruple.
@@ -126,8 +128,12 @@ def sync_slot_lanes(lengths, tokens, rngs):
     writing": a drain that serializes engine state while the last
     dispatch is still in flight would snapshot a boundary that never
     existed. Blocking on the lanes (the step's final outputs) orders
-    the drain after everything the step wrote, pool included — after
-    this returns, the engine's host-side bookkeeping IS the state.
+    the drain after everything the step wrote, pool included. The
+    engine keeps one call's results unread (`ServeEngine.step`), so the
+    drain seam has a second half: `ServeEngine.drain` first reads back
+    and books every outstanding result (`ServeEngine.flush`), then
+    blocks here — after this returns, the engine's host-side
+    bookkeeping IS the state.
     Returns the same (lengths, tokens, rngs) triple, materialized."""
     import jax
 
@@ -183,7 +189,12 @@ def paged_programs(
       finished request's state lanes into the donated slot vectors (the
       block table row was already built host-side chunk by chunk).
     * ``step(params, tree, lengths, tokens, rngs, bt)`` — advance EVERY
-      slot one token through the paged attention path: the write
+      slot one token through the paged attention path; returns (tree',
+      lengths', tokens', rngs', readback). The first four are the next
+      step's donated inputs. `readback` is the step's one host read,
+      int32 (S,) = the next tokens again in a buffer no later program
+      takes, so the engine may dispatch the next step (or an `attach`)
+      before it reads this one. The write
       scatters into the pool, then `ops.paged_decode_attention` reads
       each row's pages out of it (the gather + dense einsum where
       `ops.paged_kernel` says the kernel cannot take the shape or
@@ -207,10 +218,10 @@ def paged_programs(
     row is live when its table row holds a valid block (the engine hands
     parked and mid-prefill lanes over all-invalid; the linear layers
     need no telling there: an invalid state block drops the write).
-    Its `step` returns a FIFTH value, the step's one host readback:
-    int32 (S + 2 * sparse layers,) = the next tokens, then per sparse
-    layer (assignments computed, distinct experts with a row) — the
-    counters ride the transfer the scheduler makes anyway.
+    Its `step`'s readback is longer, int32 (S + 2 * sparse layers,):
+    the next tokens, then per sparse layer (assignments computed,
+    distinct experts with a row) — the counters ride the transfer the
+    scheduler makes anyway.
     """
     import jax
     import jax.numpy as jnp
@@ -269,7 +280,7 @@ def paged_programs(
         lengths: (S,) int32 current depths (= this step's write
         positions); tokens: (S,) last emitted; rngs: (S, 2) per-slot
         keys; bt: (S, nb) block tables. Returns
-        (tree', lengths', next_tokens (S,), rngs'). Parked lanes clamp
+        (tree', lengths', next_tokens (S,), rngs', readback). Parked lanes clamp
         at M-1 (in-bounds RoPE/mask); their invalid table rows drop the
         write and give the decode attention kernel no page to read."""
         with jax.named_scope("sample"):
@@ -294,19 +305,17 @@ def paged_programs(
             nxt = jax.vmap(
                 lambda row, key: sample_logits(row, key, temperature, top_k)
             )(lg, subs)
-        out = (
-            vars2["cache"],
-            jnp.minimum(lengths + 1, M - 1),
-            nxt,
-            new_rngs,
-        )
-        if not sparse:
-            return out
         stats = [
             vars2["intermediates"][f"layers_{i}"]["mlp"]["moe_stats"][0]
             for i in sparse
         ]
-        return out + (jnp.concatenate([nxt.astype(jnp.int32), *stats]),)
+        return (
+            vars2["cache"],
+            jnp.minimum(lengths + 1, M - 1),
+            nxt,
+            new_rngs,
+            jnp.concatenate([nxt.astype(jnp.int32), *stats]),
+        )
 
     return _register_programs(
         "paged",

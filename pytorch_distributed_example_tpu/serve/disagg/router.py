@@ -74,8 +74,10 @@ class PoolRouter:
     prefill engine's warmth matters for one chunked prefill, not a
     session) and no loss ledger (process-level recovery is the worker
     ledger's job; in-process scale-in drains token-exact through the
-    PR 8 seam). `redistribute(state)` receives every drained victim's
-    snapshot — the `DisaggRouter` lands BOTH pools' drained work back
+    PR 8 seam). `redistribute(state, done)` receives every drained
+    victim's snapshot and the completions its drain still finished
+    (`drain()` reads back the engine's outstanding results) — the
+    `DisaggRouter` lands BOTH pools' drained work back
     in the prefill pool, because a decode-pool resident request can
     only re-enter through prefill (its KV died with the drain)."""
 
@@ -85,7 +87,7 @@ class PoolRouter:
         engine_factory: Callable[[int], object],
         replicas: int = 1,
         clock=time.monotonic,
-        redistribute: Optional[Callable[[Dict], int]] = None,
+        redistribute: Optional[Callable[[Dict, Dict], int]] = None,
     ):
         if name not in ("prefill", "decode"):
             raise ValueError(f"unknown pool name {name!r}")
@@ -177,7 +179,7 @@ class PoolRouter:
         state = eng.drain()
         del self._engines[victim]
         moved = (
-            self._redistribute(state)
+            self._redistribute(state, eng.completions)
             if self._redistribute is not None
             else 0
         )
@@ -377,19 +379,24 @@ class DisaggRouter:
                 if eng.completions:
                     done = eng.completions
                     eng.completions = {}
-                    self.completions.update(done)
-                    # completed-migration orphan sweep: a landing that
-                    # crashed between attach and reclaim left keys
-                    for rid in done:
-                        gc_migration(self.store, rid)
+                    self._settle(done)
 
-    def _absorb_into_prefill(self, state: Dict) -> int:
+    def _settle(self, done: Dict[str, Completion]) -> None:
+        self.completions.update(done)
+        # completed-migration orphan sweep: a landing that
+        # crashed between attach and reclaim left keys
+        for rid in done:
+            gc_migration(self.store, rid)
+
+    def _absorb_into_prefill(self, state: Dict, done: Dict) -> int:
         """A drained replica's snapshot (EITHER pool) re-enters through
         the prefill pool: accepted work at the head (bounds-exempt),
         backlog at the sheddable tail. Decode-side residents replay
         from seed — their migrated KV died with the drain, and their
         published migration keys are reclaimed on the sweep that
-        requeued them."""
+        requeued them. What the drain still finished (`done`) is
+        collected like any live engine's completions."""
+        self._settle(done)
         accepted = [
             Request.from_state(d) for d in state.get("requests", [])
         ]

@@ -13,6 +13,13 @@ decode program (`serve/decode.py`). Retirement frees the slot AND its
 blocks and admission backfills MID-STREAM — no run-to-completion
 barrier.
 
+One call's device work is always in flight: `step()` dispatches its
+own programs and only then reads back what the previous call
+dispatched, so the device never waits for the host's bookkeeping and
+the host's one round-trip a token is hidden behind the next step
+(`step`'s docstring has the contract: what a call returns, what reads
+everything back first, the `pipeline` counters).
+
 Pool pressure resolves by PREEMPTION, youngest-request-first: when a
 slot must grow into a block and the pool is dry, the youngest active
 request (possibly the grower itself) is evicted — blocks freed, request
@@ -67,7 +74,7 @@ with SPARSE (dropless MoE) layers has its parked lanes and chunk padding
 route nowhere, and every decode step brings back, in its one readback,
 the assignments computed and the distinct experts hit per sparse layer
 (`ServeMetrics.record_moe_step`; also a `serve:moe_step` host annotation
-while a profiler trace is on). What is not carried with window layers is
+while a profiler trace is on, written when the step is read back). What is not carried with window layers is
 refused at construction: prefix sharing, the int8 pool, a tp mesh,
 disaggregated roles and pre-warmed executables. A model with LINEAR
 (recurrent-state) layers holds one state block a request beside its K/V
@@ -112,7 +119,7 @@ different world size / TP degree, since replay-from-seed carries no
 device state. The restored engine reports a first-class RECOVERY
 metric (drain → first post-restore token) on `/serve`.
 
-Synchronous single-owner design: one thread calls `submit()`/`step()`/
+Single-owner design: one thread calls `submit()`/`step()`/
 `run()`; `ServeMetrics` is internally locked so the debug HTTP frontend
 can snapshot concurrently.
 """
@@ -120,8 +127,9 @@ can snapshot concurrently.
 from __future__ import annotations
 
 import time
+from collections import deque
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -161,6 +169,22 @@ class _Prefill:
 
 
 @dataclass
+class _InFlight:
+    """A device result the host has not read yet: `value` is a decode
+    step's readback (the next token of every lane, then a sparse
+    model's counters) or, with `first`, the one token a finished
+    prefill sampled. `rows` ties it to the requests it was dispatched
+    for: (slot, the token list of the request that held the slot then).
+    A slot's token list is replaced whenever the slot changes hands, so
+    a row whose list is no longer the slot's was evicted in between and
+    its token is dropped."""
+
+    value: object
+    rows: List[Tuple[int, List[int]]]
+    first: bool = False
+
+
+@dataclass
 class Handoff:
     """A finished prefill FROZEN for migration (``role="prefill"``
     engines, `serve/disagg/`): the slot keeps its blocks and request
@@ -170,12 +194,13 @@ class Handoff:
     sampled (its one key-split off `req.seed`), so the decode pool
     starts FROM the migrated first token with the carry key
     reconstructed purely from the seed (`serve/decode.py::carry_key`)
-    — no device RNG state crosses the wire."""
+    — no device RNG state crosses the wire. It is None until the engine
+    has read the token back, which `pop_handoffs` sees to."""
 
     req: Request
     slot: int
     length: int
-    first: int
+    first: Optional[int] = None
 
 
 class ServeEngine:
@@ -370,10 +395,15 @@ class ServeEngine:
         self._slot_tokens: List[List[int]] = [[] for _ in range(S)]
         self._prefilling: Dict[int, _Prefill] = {}
         self._decoding: set = set()
+        # results queued on the device and not read back, oldest first:
+        # a `step()` call reads what earlier calls left here only after
+        # it has dispatched its own programs
+        self._inflight: Deque[_InFlight] = deque()
         # device-resident per-slot state, donated through every step —
         # the per-token hot path touches the host only for the (S,)
-        # next-token readback; block tables stay host-side numpy and
-        # ride into each program call (see serve/decode.py)
+        # next-token readback, one call late; block tables stay
+        # host-side numpy and ride into each program call as copies
+        # (see serve/decode.py)
         import jax.numpy as jnp
 
         self._dev_lengths = jnp.zeros((S,), jnp.int32)
@@ -769,11 +799,12 @@ class ServeEngine:
                     return  # budget spent: yield to decode
                 continue
             # final chunk: sample the first token at the TRUE prompt end
-            # and fuse the request's lanes into the donated slot vectors
+            # and fuse the request's lanes into the donated slot vectors.
+            # The token stays on the device (`attach` takes it from
+            # there); the host reads it with the next call's resolve
             first_dev, key = self._first_token(
                 logits, (L - 1) - start, req.seed
             )
-            first = int(first_dev)
             (
                 self._dev_lengths,
                 self._dev_tokens,
@@ -800,35 +831,19 @@ class ServeEngine:
                     self.cache.slot_blocks(slot),
                 )
             del self._prefilling[slot]
-            self._slot_tokens[slot] = [first]
-            now = self.clock()
-            req.first_token_time = now
-            self._note_recovery(now)
-            done = (
-                "eos"
-                if self.eos_id is not None and first == self.eos_id
-                else "length"
-                if req.max_new_tokens == 1
-                else None
-            )
-            if done is not None:
-                # single-token completions finish HERE regardless of
-                # role — there is nothing left to decode, so migrating
-                # would move blocks only to free them
-                self._decoding.add(slot)
-                self._retire(slot, now, done)
+            self._await(first_dev, [slot], first=True)
+            if req.max_new_tokens == 1:
+                # nothing left to decode, whatever the role (migrating
+                # would move blocks only to free them): the slot waits,
+                # parked, for `_resolve` to read the token and retire it
+                pass
             elif self.role == "prefill":
                 # freeze for migration: the slot keeps its request and
                 # blocks (the migration plane exports them), the lane
-                # stays parked. TTFT is DONE — the first token exists —
-                # so it lands in this pool's window now; completion
-                # (and TPOT) will land in the decode pool's.
-                self._handoff.append(
-                    Handoff(req=req, slot=slot, length=L, first=first)
-                )
-                self.metrics.record_first_token(
-                    now, now - req.arrival_time, klass=req.klass
-                )
+                # stays parked. `_resolve` fills `first` and lands the
+                # TTFT in this pool's window; completion (and TPOT)
+                # will land in the decode pool's.
+                self._handoff.append(Handoff(req=req, slot=slot, length=L))
             else:
                 self._decoding.add(slot)
             if budget is not None and spent >= budget:
@@ -919,10 +934,42 @@ class ServeEngine:
         "per-request seeds + fold_in discipline make replay exact)",
     )
     def step(self) -> bool:
-        """One engine iteration: admit, advance prefills (one chunk when
-        chunking is on), grow/preempt blocks, advance every decoding
-        slot one token, retire finished requests. Returns True while
-        work remains (active slots, prefills, or queued requests)."""
+        """One engine iteration, with one call's device work in flight.
+
+        The call admits and schedules from what the host already knows,
+        dispatches its programs — prefill chunks (one budget's worth
+        when chunking is on), then one decode step over every decoding
+        slot — and only THEN reads back what the PREVIOUS call
+        dispatched: the first token of each prefill that call finished,
+        then its decode step's tokens. It appends them, stamps TTFT,
+        and retires what an EOS or its budget ends. So a call returns
+        the previous call's tokens, the device always has the next
+        programs queued while the host works, and no host read of a
+        device value stands between two dispatches of one call.
+
+        What follows from the lag: a row that spends its budget leaves
+        the decoding set at that dispatch (a count: tokens emitted plus
+        tokens in flight) and keeps its slot, parked, until the next
+        call reads its last token. A row whose EOS is still in flight
+        decodes one more lane; that token is dropped, and its write
+        lands in a block the row still owns. A result is appended only
+        to the request it was dispatched for (`_InFlight`): eviction in
+        between drops it, and the replay is token-identical.
+
+        `snapshot_state`, `drain`, `requeue_inflight`, `pop_handoffs`,
+        `release_handoff` and `attach_migrated` read back everything
+        outstanding first (`flush`); so does a call with nothing to
+        dispatch, which is how `run()` ends. Nothing else does: whoever
+        needs the host's state between calls, or whole steps inside a
+        profiler trace (`stop_trace` cuts what is in flight), calls
+        `flush()` itself. `ServeMetrics` counts both sides under
+        `pipeline`: `overlap_share` (decode steps dispatched over an
+        outstanding result, of all decode steps) and `flushes` by cause.
+
+        Returns True while work remains: a result outstanding, active
+        slots, prefills, or queued requests."""
+        outstanding = len(self._inflight)
+        dispatched = self.metrics.prefill_chunks + self.metrics.decode_steps
         self._admit()
         self.metrics.record_step(
             self.queue.depth,
@@ -935,7 +982,9 @@ class ServeEngine:
             self.cache.live_blocks,
             self.cache.num_blocks,
             self.cache.bytes_per_block,
-            len(self._decoding) + len(self._prefilling),
+            # requests that hold blocks and will use them here: frozen
+            # handoffs are the migration plane's
+            sum(r is not None for r in self._slot_req) - len(self._handoff),
             self.cache.dense_bytes_per_request,
             wire_dtype=self.cache.wire_dtype,
             scale_bytes_per_block=self.cache.scale_bytes_per_block,
@@ -953,20 +1002,35 @@ class ServeEngine:
         )
         while True:
             self._prefill_tick()
-            # a prefill-finish retire (eos / budget 1) frees a slot
-            # MID-STEP; unchunked keeps PR 4's semantics by backfilling
-            # and prefilling it in the same iteration. Chunked mode
-            # still grants the slot (next step's tick prefills it) but
-            # spends no further chunk budget.
+            # an eviction under pool pressure frees a slot MID-STEP;
+            # unchunked keeps PR 4's semantics by backfilling and
+            # prefilling it in the same iteration. Chunked mode still
+            # grants the slot (next step's tick prefills it) but spends
+            # no further chunk budget.
             if self._admit() == 0 or self.prefill_chunk_tokens is not None:
                 break
-        if not self._decoding:
-            return bool(self._prefilling) or bool(self.queue)
-        try:
-            faults.fire("serve.step", n_active=len(self._decoding))
-        except _TRANSIENT:
-            self.requeue_inflight()
-            return True
+        if self._decoding:
+            try:
+                faults.fire("serve.step", n_active=len(self._decoding))
+            except _TRANSIENT:
+                self.requeue_inflight()
+                return True
+            self._decode_tick(overlapped=outstanding > 0)
+        if outstanding and dispatched == (
+            self.metrics.prefill_chunks + self.metrics.decode_steps
+        ):
+            self.metrics.record_flush("idle")  # nothing to run ahead of it
+        self._resolve(outstanding)
+        return (
+            bool(self._inflight)
+            or bool(self._decoding)
+            or bool(self._prefilling)
+            or bool(self.queue)
+        )
+
+    def _decode_tick(self, overlapped: bool) -> None:
+        """Dispatch one decode step over every decoding slot; its tokens
+        are read by a later `_resolve`."""
         # allocate-on-write: every decoding slot must own the block its
         # next token lands in BEFORE the batched write (preemption may
         # shrink the decoding set here)
@@ -978,63 +1042,112 @@ class ServeEngine:
                 continue
             # first decode write past a shared/indexed prefix boundary
             # must own a private copy of that block (CoW)
-            self._cow_or_preempt(s, int(self.cache.lengths[s]))
+            self._cow_or_preempt(s, at)
         active = sorted(self._decoding)
         if not active:
-            return bool(self._prefilling) or bool(self.queue)
-        # a MID-PREFILL slot's lane is parked but its table row already
-        # holds real blocks (chunks land as they arrive) — hand the step
-        # a view with those rows invalidated so the parked lane's
-        # garbage write drops instead of scattering into the request's
-        # own block 0. FROZEN handoff slots are the same hazard with
-        # higher stakes: their blocks are the migration payload, and a
-        # parked-lane write would corrupt KV mid-flight. Retired rows
-        # are already all-invalid via free().
-        frozen = sorted(self._prefilling) + sorted(
-            h.slot for h in self._handoff
-        )
+            return
+        # every held slot that does not decode rides along PARKED, its
+        # table row handed over all-invalid. A MID-PREFILL slot's row
+        # already holds real blocks (chunks land as they arrive): the
+        # parked lane's garbage write must drop instead of scattering
+        # into the request's own block 0. FROZEN handoff slots are the
+        # same hazard with higher stakes: their blocks are the migration
+        # payload. A row that waits for its last token to be read costs
+        # the attention kernel nothing this way. Retired rows are
+        # already all-invalid via free().
+        parked = [
+            s
+            for s, req in enumerate(self._slot_req)
+            if req is not None and s not in self._decoding
+        ]
         (
             self.cache.tree,
             self._dev_lengths,
-            nxt,
+            self._dev_tokens,
             self._dev_rngs,
-            *readback,
+            readback,
         ) = self._step(
             self.params,
             self.cache.tree,
             self._dev_lengths,
             self._dev_tokens,
             self._dev_rngs,
-            self.cache.tables(parked=frozen),
+            self.cache.tables(parked=parked),
         )
-        self._dev_tokens = nxt
-        self.metrics.record_decode_step(self._decode_kernel)
-        # the hot path's one host readback: the next tokens, and behind
-        # them a sparse model's counters
-        nxt_h = np.asarray(readback[0] if readback else nxt)
-        if readback:
-            self._record_moe_step(nxt_h[len(self._slot_req):], len(active))
-        now = self.clock()
+        self.metrics.record_decode_step(self._decode_kernel, overlapped)
+        self._await(readback, active)
+        # the host mirror advances at dispatch: the next call grows
+        # blocks and counts budgets from it before these tokens are read
+        self.cache.lengths[active] += 1
         for s in active:
             req = self._slot_req[s]
-            tok = int(nxt_h[s])
-            self._slot_tokens[s].append(tok)
-            self.cache.lengths[s] += 1
-            if self.eos_id is not None and tok == self.eos_id:
-                self._retire(s, now, "eos")
-            elif len(self._slot_tokens[s]) >= req.max_new_tokens:
-                self._retire(s, now, "length")
-        return (
-            bool(self._decoding)
-            or bool(self._prefilling)
-            or bool(self.queue)
+            sent = self.cache.lengths[s] - len(req.prompt) + 1
+            if sent >= req.max_new_tokens:
+                self._decoding.discard(s)  # by count; retired at resolve
+
+    def _await(self, value, slots, first: bool = False) -> None:
+        """Queue a device result for a later `_resolve`, tied to the
+        requests that hold `slots` now; its copy to the host starts as
+        soon as the device has it."""
+        value.copy_to_host_async()
+        self._inflight.append(
+            _InFlight(value, [(s, self._slot_tokens[s]) for s in slots], first)
         )
+
+    def _resolve(self, n: int) -> None:
+        """Read back the `n` oldest outstanding results, in dispatch
+        order (a finished prefill's first token before the tokens of
+        the decode step behind it: TTFT is stamped when the host holds
+        the token, and it is ready a decode step sooner), and do the
+        bookkeeping the host needs the tokens for."""
+        for _ in range(n):
+            res = self._inflight.popleft()
+            host = np.asarray(res.value)  # blocks until the device is there
+            if self._sparse_layers and not res.first:
+                self._record_moe_step(
+                    host[len(self._slot_req):], len(res.rows)
+                )
+            now = self.clock()
+            for slot, toks in res.rows:
+                if self._slot_tokens[slot] is not toks:
+                    continue  # evicted since: not the next tenant's token
+                req = self._slot_req[slot]
+                tok = int(host) if res.first else int(host[slot])
+                toks.append(tok)
+                if res.first:
+                    req.first_token_time = now
+                    self._note_recovery(now)
+                if self.eos_id is not None and tok == self.eos_id:
+                    self._retire(slot, now, "eos")
+                elif len(toks) >= req.max_new_tokens:
+                    self._retire(slot, now, "length")
+                elif res.first and self.role == "prefill":
+                    # TTFT is DONE — the first token exists — so it
+                    # lands in this pool's window now
+                    (handoff,) = (h for h in self._handoff if h.slot == slot)
+                    handoff.first = tok
+                    self.metrics.record_first_token(
+                        now, now - req.arrival_time, klass=req.klass
+                    )
+
+    def flush(self, cause: str = "caller") -> None:
+        """Read back everything outstanding, so that the host's state
+        (`completions`, token lists, `Handoff.first`) holds every token
+        dispatched: the seams that read or hand out that state call this
+        first, each under its own `cause` in `pipeline.flushes`. The
+        device is then idle until the next `step()`."""
+        if self._inflight:
+            self.metrics.record_flush(cause)
+            self._resolve(len(self._inflight))
 
     def _record_moe_step(self, counters, rows: int) -> None:
         """One decode step's sparse-layer counters, (assignments, experts
         hit) a layer: into the metrics, and — for a reader that pairs
         them with the same steps' device time — onto the host line of a
-        profiler trace, where one is being taken (free otherwise)."""
+        profiler trace, where one is being taken (free otherwise). The
+        annotation is written when the step is READ BACK, a call after
+        its dispatch: a reader that wants one a step of its slice calls
+        `flush()` before the trace starts and before it stops."""
         import jax.profiler
 
         assignments = counters[0::2].tolist()
@@ -1047,8 +1160,8 @@ class ServeEngine:
             pass
 
     def run(self, max_steps: Optional[int] = None) -> Dict[str, Completion]:
-        """Drive step() until the queue and slots drain (or max_steps);
-        returns the completion map."""
+        """Drive step() until the queue and slots drain and the last
+        result is read back (or max_steps); returns the completion map."""
         n = 0
         while self.step():
             n += 1
@@ -1087,6 +1200,9 @@ class ServeEngine:
         self._slot_req[slot] = None
         self._slot_tokens[slot] = []
         self._decoding.discard(slot)
+        # a prefill pool's request whose FIRST token was the EOS was
+        # frozen for migration before the host read it: nothing to move
+        self._handoff = [h for h in self._handoff if h.slot != slot]
         self.cache.free(slot)  # slot AND its blocks return to the pool
         self._reserved -= self._worst_blocks(req)
 
@@ -1105,12 +1221,17 @@ class ServeEngine:
         recovery-time metric.
 
         `serve.drain` fires BEFORE any state is read: a transient
-        injected fault aborts the snapshot with the engine untouched."""
+        injected fault aborts the snapshot with the engine untouched.
+        Then what is outstanding on the device is read back, so the
+        ledger counts every token dispatched. That may FINISH requests: they are in
+        `completions` and not in the snapshot, so a caller that
+        persists the snapshot delivers the completions first."""
         faults.fire(
             "serve.drain",
             queued=self.queue.depth,
             active=self.num_active,
         )
+        self.flush("snapshot")
         inflight = sorted(
             (
                 self._slot_req[s]
@@ -1140,13 +1261,15 @@ class ServeEngine:
         """Stop serving at a step boundary and capture restartable
         state — the elastic-agent restart/resize path.
 
-        `snapshot_state()` plus the terminal half: quiesce the device
-        lanes through the `serve/decode.py` drain seam (every donated
+        The outstanding results are read back, then `snapshot_state()`
+        plus the terminal half: quiesce the device lanes through the
+        `serve/decode.py` drain seam (every donated
         buffer materialized — no program may still be writing the pool
         when the process exits) and requeue all in-flight work (each
         request replays token-identically from its seed, so dropping
         device state loses nothing but the replay time). The engine
         itself stays usable — a cancelled drain just keeps serving."""
+        self.flush("drain")
         state = self.snapshot_state()
         (
             self._dev_lengths,
@@ -1175,7 +1298,9 @@ class ServeEngine:
         to the queue HEAD in ARRIVAL order and free slots + blocks — the
         mid-stream kill/restart path. Each request replays from scratch
         off its own seed, so greedy outputs are unchanged by any number
-        of requeues."""
+        of requeues. Outstanding results are read back first: a request
+        they complete is done, not replayed."""
+        self.flush("requeue")
         inflight = sorted(
             (
                 s
@@ -1203,13 +1328,16 @@ class ServeEngine:
 
     # -- disaggregated handoff / landing (serve/disagg/) -------------------
     def pop_handoffs(self) -> List[Handoff]:
-        """Drain the frozen-handoff list (``role="prefill"``). The
+        """Drain the frozen-handoff list (``role="prefill"``), after
+        reading back what is outstanding: every record handed out holds
+        its `first` token. The
         slots stay frozen — blocks pinned, lanes parked — until the
         caller exports each payload and calls `release_handoff`; an
         engine step between pop and release is safe (frozen rows are
         invalidated in `step`), but an eviction in that window makes
         the record stale, which `release_handoff` detects by request
         identity."""
+        self.flush("handoff")
         out, self._handoff = self._handoff, []
         return out
 
@@ -1219,6 +1347,7 @@ class ServeEngine:
         discipline: a crash between publish and release just re-sends
         identical bytes). No-op when the slot no longer holds `h.req`
         (evicted since the pop — the request is replaying anyway)."""
+        self.flush("handoff")
         if self._slot_req[h.slot] is not h.req:
             return
         self._slot_req[h.slot] = None
@@ -1244,6 +1373,7 @@ class ServeEngine:
 
         if self.role == "prefill":
             raise DistError("prefill-pool engines cannot land migrations")
+        self.flush("handoff")  # a row that is done frees its slot first
         worst = self._worst_blocks(req)
         if self.conservative_admission and (
             self._reserved + worst > self.cache.num_blocks

@@ -162,6 +162,14 @@ class ServeMetrics:
         # engine's pool: 100 % or 0 % for one engine's lifetime)
         self.decode_steps = 0
         self.decode_kernel_steps = 0
+        # the engine keeps one call's device work in flight
+        # (`ServeEngine.step`): decode steps dispatched while an earlier
+        # call's result was still unread, and the times the engine read
+        # everything back with nothing dispatched ahead of it, by cause
+        # (drain, snapshot, handoff, requeue, idle; "caller" for a
+        # `flush()` from outside the engine)
+        self.decode_overlapped_steps = 0
+        self.pipeline_flushes: Dict[str, int] = {}
         # prefill chunks dispatched, their attention calls (one a layer)
         # and those of them that took a kernel of `ops/paged_attention.py`
         # (`serve.decode.layer_paths` at each chunk's length)
@@ -294,10 +302,15 @@ class ServeMetrics:
             if self.slots:
                 self._occupancy_steps += slots_active / self.slots
 
-    def record_decode_step(self, kernel: bool) -> None:
+    def record_decode_step(self, kernel: bool, overlapped: bool) -> None:
         with self._lock:
             self.decode_steps += 1
             self.decode_kernel_steps += bool(kernel)
+            self.decode_overlapped_steps += bool(overlapped)
+
+    def record_flush(self, cause: str) -> None:
+        with self._lock:
+            self.pipeline_flushes[cause] = self.pipeline_flushes.get(cause, 0) + 1
 
     @property
     def state_bytes_live(self) -> int:
@@ -691,6 +704,12 @@ class ServeMetrics:
                         self.decode_kernel_steps / self.decode_steps, 4
                     ) if self.decode_steps else 0.0,
                     "layer_paths": dict(self.decode_layer_paths),
+                },
+                "pipeline": {
+                    "overlap_share": round(
+                        self.decode_overlapped_steps / self.decode_steps, 4
+                    ) if self.decode_steps else 0.0,
+                    "flushes": dict(self.pipeline_flushes),
                 },
                 "prefill": {
                     "chunks": self.prefill_chunks,

@@ -625,8 +625,11 @@ class ServeWorker:
         """The teardown half of the lifecycle: stop at a step boundary,
         seal the drain snapshot into this rank's plane, leave. Runs
         inside `serve_drain_grace_s` — the agent SIGTERMs laggards."""
-        self._publish_completions()
+        # the drain reads back the engine's outstanding results first,
+        # which may finish requests: publish AFTER it, or they would be
+        # in neither the ledger nor the snapshot
         state = self.engine.drain()
+        self._publish_completions()
         save_serve_state(
             self.store,
             self.gen,

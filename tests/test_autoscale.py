@@ -793,7 +793,12 @@ class TestLoadHarness:
             clock=lambda: t[0],
             window_s=3.0,
         )
-        replay(events, r, t, 0.05, autoscaler=a, poll_every_s=0.25)
+        # 0.04 virtual seconds a call where 0.05 used to stand: an engine
+        # call hands out the PREVIOUS call's tokens, so a request of 3-7
+        # tokens holds its slot seven calls where it held six, and the
+        # same service time a request keeps the falling edge's queue
+        # under the policy's `queue_low`
+        replay(events, r, t, 0.04, autoscaler=a, poll_every_s=0.25)
         assert len(r.completions) == len(events)
         kinds = {e.kind for e in r.events}
         assert "add" in kinds and "remove" in kinds

@@ -286,13 +286,19 @@ def flush_completions():
 
 while True:
     worked = engine.step()
-    flush_completions()
     # periodic incarnation-scoped checkpoint: a crash between
-    # checkpoints costs only the replay the snapshot already covers
-    save_serve_state(store, gen, engine.snapshot_state())
+    # checkpoints costs only the replay the snapshot already covers.
+    # A snapshot reads back the engine's outstanding tokens first, which
+    # may finish requests: they are delivered before the snapshot that
+    # no longer lists them is saved
+    state = engine.snapshot_state()
+    flush_completions()
+    save_serve_state(store, gen, state)
     store.set("serve/started", b"1")  # distlint: disable=R007 -- test-gang sequencing marker, store is throwaway
     if drain_requested(store, gen):
-        save_serve_state(store, gen, engine.drain())
+        state = engine.drain()
+        flush_completions()
+        save_serve_state(store, gen, state)
         store.close()
         sys.exit(0)  # drained: the agent re-forms the gang
     if not worked:

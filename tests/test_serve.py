@@ -392,10 +392,13 @@ class TestMetricsAccounting:
         )
         fc.t = 1.0
         rid = eng.submit(prompt, 3)
+        fc.t = 3.0
+        eng.step()  # admit, prefill and decode step 1 dispatched: nothing read
         fc.t = 5.0
-        eng.step()  # admit (first token at t=5) + decode (token 2 at t=5)
+        eng.step()  # decode step 2 dispatched; first token, token 2 read at t=5
+        assert rid not in eng.completions
         fc.t = 7.0
-        eng.step()  # token 3 at t=7 -> completes (budget 3)
+        assert not eng.step()  # token 3 read at t=7 -> completes (budget 3)
         comp = eng.completions[rid]
         assert comp.ttft_s == pytest.approx(4.0)  # 5 - 1
         assert comp.e2e_s == pytest.approx(6.0)  # 7 - 1
@@ -521,7 +524,10 @@ class TestServeChaos:
         eng.submit(pa, 1, rid="A")  # retires at admission (budget 1)
         rb = eng.submit(pb, 12, rid="B")
         rc = eng.submit(pc, 12, rid="C")
-        eng.step()  # A done, B in slot 1, C backfilled into slot 0
+        eng.step()  # A and B prefilled; A's one token is still on the device
+        assert "A" not in eng.completions and eng.num_active == 2
+        eng.step()  # A's token read: A done, its slot free at the call's end
+        eng.step()  # B in slot 1, C backfilled into slot 0
         assert "A" in eng.completions and eng.num_active == 2
         assert eng.requeue_inflight() == 2
         drained = [eng.queue.pop().rid for _ in range(2)]

@@ -476,8 +476,9 @@ class TestElasticServe:
         assert rec["tokens_replayed"] == mid_flight
         assert rec["restored_generation"] == 3
         # drain stamped t=1.5; the gang was dark until t=5.5, when the
-        # first post-restore step prefills and emits a token -> 4.0
-        assert rec["last_recovery_s"] == pytest.approx(4.0)
+        # first post-restore step prefills; the call after it, at t=6.0,
+        # reads that prefill's token back and holds it -> 4.5
+        assert rec["last_recovery_s"] == pytest.approx(4.5)
 
     def test_restore_into_different_tp_degree(self, no_fault_plan):
         """The resize claim: gen0 serves UNSHARDED, the re-formed gang
