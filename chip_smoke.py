@@ -66,6 +66,10 @@ PRESETS = {
             new_tokens=(32, 40, 48, 56, 64, 36, 44),
             prefix_len=512, prefix_tail=300, check_len=640,
             quant_prompt_lens=(300, 420, 812, 1400), quant_new_tokens=32,
+            # a toy latent-attention layer at widths both latent kernels
+            # take (rows of 128 + 64 values, held as 256)
+            latent=dict(d_model=512, n_heads=8, q_rank=128, kv_rank=128,
+                        nope=64, rope=64, v=64, prompt_len=300, new_tokens=12),
         ),
         four=dict(
             lm_batch=8, lm_meshes=((2, 2), (4, 1)),
@@ -91,6 +95,8 @@ PRESETS = {
             prompt_lens=(10, 20, 40, 70), new_tokens=(4, 5, 6, 4),
             prefix_len=32, prefix_tail=20, check_len=40,
             quant_prompt_lens=(20, 40), quant_new_tokens=4,
+            latent=dict(d_model=64, n_heads=4, q_rank=24, kv_rank=32, nope=16,
+                        rope=8, v=16, prompt_len=45, new_tokens=4),
         ),
         four=dict(
             lm_batch=4, lm_meshes=((2, 2),),
@@ -644,10 +650,70 @@ def _serve_traffic(engine, sv, vocab, lens, new_tokens, shared_prefix):
     return len(want)
 
 
+def _latent_serve(lm, sv):
+    """A toy latent-attention model (two layers, sandwich norms, a dense
+    and a sigmoid-routed sparse MLP) through ServeEngine: a prompt that ends
+    inside a bucket prefilled in chunks over the latent paged cache, then
+    decoded; the first token's logits and every decoded token against the
+    cache-free forward, which up-projects keys and values where the cached
+    paths run absorbed. A broken latent kernel shows here, before a 13 GB
+    model is built around it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_distributed_example_tpu.models.transformer import (
+        LayerSpec, TransformerConfig, TransformerLM,
+    )
+
+    la = sv["latent"]
+    cfg = TransformerConfig(
+        vocab_size=lm["vocab"], d_model=la["d_model"], n_layers=2,
+        n_heads=la["n_heads"], d_ff=2 * la["d_model"], max_seq_len=sv["max_seq_len"],
+        dtype=jnp.bfloat16, use_flash=False, sandwich_norm=True,
+        layers=(LayerSpec("latent"), LayerSpec("latent", mlp="sparse")),
+        latent_q_rank=la["q_rank"], latent_kv_rank=la["kv_rank"],
+        latent_nope_dim=la["nope"], latent_rope_dim=la["rope"], latent_v_dim=la["v"],
+        sparse_score="sigmoid", sparse_experts=8, sparse_top_k=2,
+        sparse_d_ff=la["d_model"], shared_d_ff=la["d_model"], routed_scale=2.5,
+    )
+    model = TransformerLM(cfg)
+    params = jax.jit(lambda key: jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16),
+        model.init(key, jnp.zeros((1, 8), jnp.int32))))(jax.random.PRNGKey(3))["params"]
+    engine = _serve_engine(model, params, sv)
+    probe = engine._prefill_chunk = _PrefillProbe(engine._prefill_chunk)
+    paths = (engine.metrics.decode_layer_paths["latent"][1],
+             engine.metrics.prefill_layer_paths["latent"][1])
+    if jax.default_backend() == "tpu":
+        _check(paths == ("latent_decode_kernel", "latent_chunk_kernel"),
+               f"the latent layers take {paths} on a TPU, not their kernels")
+    toks = np.random.default_rng(5).integers(
+        0, lm["vocab"], (la["prompt_len"],)).astype(np.int32)
+    n_new = la["new_tokens"]
+    rid = engine.submit(toks, n_new, rid="latent")
+    done = engine.run(max_steps=2000)
+    _check_completions(done, {rid: n_new})
+    seq = np.concatenate([toks, np.asarray(done[rid].tokens[:-1], np.int32)])
+    want = np.asarray(jax.jit(lambda p, t: model.apply({"params": p}, t))(
+        params, jnp.asarray(seq)[None])[0, -n_new:], np.float32)
+    start, chunk_logits = probe.last
+    err = _rel_err(chunk_logits[(len(toks) - 1) - start], want[0])
+    picked = want[np.arange(n_new), done[rid].tokens]
+    gap = float(((want.max(axis=1) - picked) / np.abs(want).max()).max())
+    print(f"  latent layers ({paths[0]}, {paths[1]}): first-token logits rel err "
+          f"{err:.2e}, decoded tokens within {gap:.2e} of the forward's best logit")
+    _check(err <= 1e-1 and gap <= 1e-1,
+           f"the latent cached paths disagree with the forward: {err}, {gap}")
+    _check(engine.cache.live_blocks == 0, "latent blocks were not freed at retirement")
+    return err
+
+
 def phase_serve(preset):
     """ServeEngine on the full-width bf16 model: paged cache, chunked
     prefill, prefix sharing; first-token logits against a plain
-    full-sequence model.apply; then int8 KV for completion."""
+    full-sequence model.apply; a toy latent-attention model through the
+    same engine (`_latent_serve`); then int8 KV for completion."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -704,6 +770,7 @@ def phase_serve(preset):
                     jax.devices()[:1])
     del engine, probe
     gc.collect()
+    latent_err = _latent_serve(lm, sv)
 
     t0 = time.perf_counter()
     engine = _serve_engine(model, params, sv, kv_quant=True)
@@ -715,7 +782,8 @@ def phase_serve(preset):
     print(f"  int8 KV: {nq} requests completed in "
           f"{time.perf_counter() - t0:.1f} s (smoke timing)")
     return (f"depth={depth} plain={n + 2} int8={nq} "
-            f"logits_rel_err={max(errs):.1e} prefix_reused={reused}")
+            f"logits_rel_err={max(errs):.1e} prefix_reused={reused} "
+            f"latent_rel_err={latent_err:.1e}")
 
 
 def phase_four_chip(preset):

@@ -113,11 +113,21 @@ def init_cache(model, batch_size: int):
             for leaf, (shape, dtype) in linear_state_shapes(cfg).items()
         }}
 
+    def latent_layer():  # one row a token: the latent and its rotary key
+        return {"latent_attn": {
+            "latent": jnp.zeros((B, M, cfg.latent_width), cfg.dtype),
+            "index": jnp.zeros((), jnp.int32),
+        }}
+
     linear = getattr(cfg, "linear_layers", ())
-    return {
-        f"layers_{i}": state_layer() if i in linear else one_layer()
-        for i in range(cfg.n_layers)
-    }
+    latent = getattr(cfg, "latent_layers", ())
+
+    def layer(i):
+        if i in linear:
+            return state_layer()
+        return latent_layer() if i in latent else one_layer()
+
+    return {f"layers_{i}": layer(i) for i in range(cfg.n_layers)}
 
 
 def generate(

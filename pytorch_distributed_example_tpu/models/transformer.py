@@ -50,8 +50,8 @@ class RopeSpec:
 
 
 # what a layer's mixer keeps between calls: every key and value, those of
-# a window, or one recurrent state a row
-CACHE_KINDS = ("full", "window", "linear")
+# a window, one recurrent state a row, or one compressed latent a token
+CACHE_KINDS = ("full", "window", "linear", "latent")
 # tokens of one sub-chunk of a linear layer's chunked scan
 # (`gated_delta_chunked`): a power of two that divides every prefill
 # bucket of the serve cells (128, 256, 512)
@@ -61,8 +61,10 @@ LINEAR_CHUNK = 64
 @dataclass(frozen=True)
 class LayerSpec:
     """What one layer of a patterned model is. `attention` is "full",
-    "window" (the last `cfg.window` keys) or "linear" (`LinearAttention`
-    at the `linear_*` sizes: a recurrent state, no keys and values);
+    "window" (the last `cfg.window` keys), "linear" (`LinearAttention`
+    at the `linear_*` sizes: a recurrent state, no keys and values) or
+    "latent" (`LatentAttention` at the `latent_*` sizes: one compressed
+    latent and one shared rotary key a token, no K and V heads);
     `n_heads` and `rope` default to the model's; `mlp` is "dense" (SwiGLU
     at `cfg.ffn_dim`) or "sparse" (`SparseMoE` at the `sparse_*` sizes)."""
 
@@ -127,10 +129,32 @@ class TransformerConfig:
     # q and k of an attention layer are RMS-normed over the whole
     # projection, before the heads are split
     qk_norm: bool = False
+    # each sublayer is normed on its input AND on its output,
+    # h = x + post_norm(mixer(norm(x))): four norms a block
+    sandwich_norm: bool = False
+    # how the "sparse" MLP's router scores the experts: "softmax" over all
+    # of them, or "sigmoid" of each (both: the top k, normalised to sum to
+    # 1, times `routed_scale`)
+    sparse_score: str = "softmax"
+    # the "latent" mixer (`LatentAttention`, multi-head latent attention):
+    # queries through a normed bottleneck of `latent_q_rank`; a token's
+    # keys and values through ONE normed latent of `latent_kv_rank` values
+    # that every head up-projects (`latent_nope_dim` of a key,
+    # `latent_v_dim` of a value), and ONE rotary key of `latent_rope_dim`
+    # values that every head shares
+    latent_q_rank: int = 0
+    latent_kv_rank: int = 0
+    latent_nope_dim: int = 0
+    latent_rope_dim: int = 0
+    latent_v_dim: int = 0
 
     def __post_init__(self):
         if self.rope_pairs not in ("interleaved", "halves"):
             raise ValueError(f"rope_pairs {self.rope_pairs!r}")
+        if self.sparse_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"sparse_score {self.sparse_score!r}")
+        if self.post_norm and self.sandwich_norm:
+            raise ValueError("post_norm and sandwich_norm are two placements")
         if self.layers is None:
             return
         if len(self.layers) != self.n_layers:
@@ -152,6 +176,16 @@ class TransformerConfig:
                     raise ValueError(
                         f"layer {i} is linear and linear_heads/key_dim/"
                         "value_dim/conv are unset"
+                    )
+            elif spec.attention == "latent":
+                if not (
+                    self.latent_q_rank and self.latent_kv_rank
+                    and self.latent_nope_dim and self.latent_v_dim
+                    and self.latent_rope_dim and self.latent_rope_dim % 2 == 0
+                ):
+                    raise ValueError(
+                        f"layer {i} is latent and latent_q_rank/kv_rank/"
+                        "nope_dim/rope_dim/v_dim are unset"
                     )
             elif (spec.n_heads or self.n_heads) % self.kv_heads:
                 raise ValueError(f"layer {i}: heads do not divide over kv_heads")
@@ -197,6 +231,21 @@ class TransformerConfig:
         return tuple(
             i for i, spec in enumerate(self.layers) if spec.attention == "linear"
         )
+
+    @property
+    def latent_layers(self) -> Tuple[int, ...]:
+        """The layers that keep one latent a token and no K and V heads."""
+        if self.layers is None:
+            return ()
+        return tuple(
+            i for i, spec in enumerate(self.layers) if spec.attention == "latent"
+        )
+
+    @property
+    def latent_width(self) -> int:
+        """Values a latent layer caches a token: the latent and the
+        shared rotary key."""
+        return self.latent_kv_rank + self.latent_rope_dim
 
     @property
     def cache_kinds(self) -> Tuple[str, ...]:
@@ -315,6 +364,22 @@ def _dense_attention(q, k, v, causal, scale):
 
     with jax.named_scope("dense_attention"):
         return dense_attention(q, k, v, causal=causal, scale=scale)
+
+
+def _paged_write_index(pos, block_tables, nblk: int, bs: int):
+    """(B * L,) flat pool row of each token at absolute position `pos`
+    (B, L) under `block_tables` (B, nb): physical block times `bs` plus the
+    offset in it, or `nblk * bs` (out of bounds: a `mode="drop"` scatter
+    discards the write) where the logical block is unallocated (entry ==
+    nblk) or past the table."""
+    nb = block_tables.shape[1]
+    lb = pos // bs  # (B, L) logical block
+    off = pos % bs
+    phys = jnp.take_along_axis(
+        block_tables, jnp.clip(lb, 0, nb - 1), axis=1
+    )  # (B, L) physical block id, == nblk when unallocated
+    flat = jnp.where(lb < nb, phys * bs + off, nblk * bs)  # OOB sentinel
+    return flat.reshape(-1)
 
 
 def _position_mask(q_pos, key_pos, window=None):
@@ -624,13 +689,7 @@ class Attention(nn.Module):
         q = apply_rope_batched(q, cos[safe], sin[safe], halves)  # dropped below
         k = apply_rope_batched(k, cos[safe], sin[safe], halves)
 
-        lb = pos // bs  # (B, L) logical block
-        off = pos % bs
-        phys = jnp.take_along_axis(
-            block_tables, jnp.clip(lb, 0, nb - 1), axis=1
-        )  # (B, L) physical block id, == nblk when unallocated
-        flat = jnp.where(lb < nb, phys * bs + off, nblk * bs)  # OOB sentinel
-        flat = flat.reshape(B * L)
+        flat = _paged_write_index(pos, block_tables, nblk, bs)
 
         def scatter(pool, upd):
             flat_pool = pool.reshape(nblk * bs, KV, Dh)
@@ -697,6 +756,194 @@ class Attention(nn.Module):
                 key_pos = key0[:, None] + jnp.arange(kf.shape[1])[None, :]
                 mask = _position_mask(pos, key_pos, window)  # (B, L, Mb)
                 return _grouped_attention(q, kf, vf, scale, mask)
+
+def _latent_attention(q, latents, rank, scale, mask):
+    """Masked softmax attention in the ABSORBED form of a latent layer:
+    q (B, L, H, W) against the cached rows `latents` (B, M, W), whose
+    first `rank` values are also the values; mask (B or 1, L, M). Scores
+    and softmax in float32, probabilities cast to the latents' dtype;
+    returns (B, L, H, rank)."""
+    s = jnp.einsum("blhw,bmw->bhlm", q, latents) * scale
+    s = jnp.where(mask[:, None], s.astype(jnp.float32), -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(latents.dtype)
+    return jnp.einsum("bhlm,bmr->blhr", p, latents[..., :rank])
+
+
+class LatentAttention(nn.Module):
+    """The "latent" mixer of a layer pattern: multi-head latent attention
+    (DeepSeek-V2, arXiv:2405.04434, section 2.1). Per token, with H heads,
+    r = `latent_kv_rank`, dn / dr / dv = `latent_nope_dim` / `rope_dim` /
+    `v_dim`:
+
+        c_q = RMSNorm(x W_qa);  q = c_q W_qb, a head [q_nope (dn); q_rope (dr)]
+        [c_kv (r); k_r (dr)] = x W_kva;  c_kv = RMSNorm(c_kv)
+        q_rope = RoPE(q_rope);  k_rope = RoPE(k_r), ONE for all heads
+        a head: k_nope = c_kv W_uk, v = c_kv W_uv, W_kvb = [W_uk; W_uv]
+        s(i, j) = (q_nope_i . k_nope_j + q_rope_i . k_rope_j) / sqrt(dn + dr)
+        o_i = sum_j softmax_j(s)(i, j) v_j;  y = concat(o) W_o
+
+    What it keeps between calls is ONE row of r + dr values a token:
+    `c_kv` after its norm and `k_rope` after its rotation. No K and V
+    heads exist in the cache, so every cached call runs ABSORBED: a
+    head's up-projections move onto the query and the output,
+
+        qt = q_nope W_uk^T (r);  s(i, j) = (qt_i . c_kv_j + q_rope_i . k_rope_j) / sqrt(dn + dr)
+        ot_i = sum_j p(i, j) c_kv_j (r);  o_i = ot_i W_uv
+
+    the same numbers, with every head attending the one shared row.
+
+    * With no cache (`decode=False`: training, the tests) the layer runs
+      as first written: keys and values up-projected, dense causal softmax.
+    * `decode=True` without tables is `generate()`'s cache: `latent`
+      (B, max_seq_len, r + dr) and `index` in the "cache" collection.
+    * With `block_tables` ((B, nb)) and `positions` ((B,)) `latent` is the
+      serve engine's pool of blocks, (num_blocks, block_size, r + dr)
+      (rows in whole lane tiles past 128: `ops.pool_latent_width`),
+      shared by every row (`serve/cache.py`): writes scatter through the
+      tables and an invalid entry drops them, as for K/V
+      (`Attention._decode`). One token a row reads its pages in
+      `ops.latent_decode_attention`, a prefill chunk in
+      `ops.latent_chunk_attention`, where `ops.paged_kernel` says the
+      kernel takes the pool; else the row's logical layout is gathered
+      and a dense einsum masked by absolute position."""
+
+    cfg: TransformerConfig
+    spec: LayerSpec
+
+    @nn.compact
+    def __call__(
+        self, x, cos, sin, decode: bool = False, positions=None,
+        block_tables=None,
+    ):
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, r = self.spec.n_heads, cfg.latent_kv_rank
+        dn, dr, dv = cfg.latent_nope_dim, cfg.latent_rope_dim, cfg.latent_v_dim
+        scale = 1.0 / ((dn + dr) ** 0.5)
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=cfg.dtype, name=name
+        )
+        with jax.named_scope("q_down"):
+            c_q = RMSNorm(cfg.norm_eps, name="q_a_norm")(
+                dense(cfg.latent_q_rank, "q_a_proj")(x)
+            )
+        with jax.named_scope("q_up"):
+            q = dense(H * (dn + dr), "q_b_proj")(c_q).reshape(B, L, H, dn + dr)
+            q_nope, q_rope = q[..., :dn], q[..., dn:]
+        with jax.named_scope("kv_down"):
+            kv = dense(r + dr, "kv_a_proj")(x)
+            c_kv = RMSNorm(cfg.norm_eps, name="kv_a_norm")(kv[..., :r])
+            k_rope = kv[..., None, r:]  # (B, L, 1, dr): every head's
+        # a head's [W_uk (dn); W_uv (dv)], the columns of one (r, ...) matrix
+        w_kvb = self.param(
+            "kv_b_proj", nn.initializers.lecun_normal(), (r, H * (dn + dv))
+        ).astype(cfg.dtype).reshape(r, H, dn + dv)
+        w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
+
+        if not decode:
+            q_rope = apply_rope(q_rope, cos, sin)
+            k_rope = apply_rope(k_rope, cos, sin)
+            with jax.named_scope("kv_up"):
+                k_nope = jnp.einsum("blr,rhd->blhd", c_kv, w_uk)
+                v = jnp.einsum("blr,rhd->blhd", c_kv, w_uv)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (B, L, H, dr))], axis=-1
+            )
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            o = _dense_attention(q, k, v, cfg.causal, scale)
+            return dense(cfg.d_model, "o_proj")(o.reshape(B, L, H * dv))
+
+        if not cfg.causal:
+            raise ValueError("decode=True requires a causal model")
+        if (block_tables is None) != (positions is None):
+            raise ValueError(
+                "a latent layer's paged cache takes block_tables and "
+                "positions together; the dense cache keeps one scalar index"
+            )
+        M = cfg.max_seq_len
+        if block_tables is None:
+            cl = self.variable(
+                "cache", "latent", jnp.zeros, (B, M, r + dr), cfg.dtype
+            )
+            ci = self.variable("cache", "index", lambda: jnp.zeros((), jnp.int32))
+            idx = ci.value
+            pos = jnp.broadcast_to(idx + jnp.arange(L), (B, L))
+            pos_cos = jax.lax.dynamic_slice_in_dim(cos, idx, L, axis=0)
+            pos_sin = jax.lax.dynamic_slice_in_dim(sin, idx, L, axis=0)
+            q_rope = apply_rope(q_rope, pos_cos, pos_sin)
+            k_rope = apply_rope(k_rope, pos_cos, pos_sin)
+        else:
+            if not self.has_variable("cache", "latent"):
+                raise ValueError(
+                    "a paged latent layer needs a pre-built block-pool cache "
+                    "tree (serve.cache.init_paged_cache) passed via apply()"
+                )
+            cl = self.variable("cache", "latent", lambda: None)
+            idx = positions.astype(jnp.int32)  # (B,) absolute start positions
+            pos = idx[:, None] + jnp.arange(L)[None, :]  # (B, L) absolute
+            safe = jnp.clip(pos, 0, M - 1)  # overshoot is dropped below
+            q_rope = apply_rope_batched(q_rope, cos[safe], sin[safe])
+            k_rope = apply_rope_batched(k_rope, cos[safe], sin[safe])
+        row = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)  # (B, L, r + dr)
+        with jax.named_scope("absorb_q"):
+            qt = jnp.einsum("blhd,rhd->blhr", q_nope, w_uk)
+            q = jnp.concatenate([qt, q_rope], axis=-1)  # (B, L, H, r + dr)
+
+        if block_tables is None:
+            with jax.named_scope("kv_scatter"):
+                held = jax.lax.dynamic_update_slice_in_dim(cl.value, row, idx, axis=1)
+            if not self.is_initializing():  # flax: init only creates
+                cl.value, ci.value = held, idx + L
+            with jax.named_scope("cache_attention"):
+                mask = _position_mask(pos[0], jnp.arange(M))[None]
+                ot = _latent_attention(q, held, r, scale, mask)
+        else:
+            ot = self._paged(q, row, cl, pos, idx, block_tables, scale)
+        with jax.named_scope("absorb_out"):
+            o = jnp.einsum("blhr,rhd->blhd", ot, w_uv)
+        return dense(cfg.d_model, "o_proj")(o.reshape(B, L, H * dv))
+
+    def _paged(self, q, row, pool, pos, idx, block_tables, scale):
+        """Write the call's rows into the pool, then attend each row's
+        pages: q (B, L, H, r + dr) absorbed, row (B, L, r + dr), pos
+        (B, L) absolute. Returns (B, L, H, r) in q's dtype."""
+        from ..ops import (
+            gather_paged_latent,
+            latent_chunk_attention,
+            latent_decode_attention,
+            paged_kernel,
+        )
+
+        B, L, _ = row.shape
+        r = self.cfg.latent_kv_rank
+        nblk, bs, W = pool.value.shape
+        # the pool may hold wider rows than the model caches
+        # (`ops.paged_attention.pool_latent_width`): zeros behind the
+        # row's values and behind the query's add nothing to a score
+        grow = lambda a: jnp.pad(
+            a, [(0, 0)] * (a.ndim - 1) + [(0, W - a.shape[-1])]
+        )
+        q, row = grow(q), grow(row)
+        flat = _paged_write_index(pos, block_tables, nblk, bs)
+        with jax.named_scope("kv_scatter"):
+            pool.value = pool.value.reshape(nblk * bs, W).at[flat].set(
+                row.reshape(B * L, W), mode="drop"
+            ).reshape(nblk, bs, W)
+        kernel = paged_kernel(L, pool.value, block_tables, rank=r)
+        with jax.named_scope("cache_attention"):
+            if kernel == "latent_decode":
+                return latent_decode_attention(
+                    q[:, 0], pool.value, block_tables, idx, scale, rank=r
+                )[:, None]
+            if kernel == "latent_chunk":
+                return latent_chunk_attention(
+                    q, pool.value, block_tables, idx, scale, rank=r
+                )
+            with jax.named_scope("kv_gather"):
+                held = gather_paged_latent(pool.value, block_tables)
+            mask = _position_mask(pos, jnp.arange(held.shape[1])[None])
+            return _latent_attention(q, held, r, scale, mask)
+
 
 def _flash_ok(L: int, Dh: int, window: Optional[int] = None) -> bool:
     """Whether the flash kernel can take this call. A window layer never
@@ -1122,7 +1369,7 @@ class SparseMoE(nn.Module):
                 w_gate.astype(cfg.dtype), w_up.astype(cfg.dtype),
                 w_down.astype(cfg.dtype),
                 n_experts=E, top_k=cfg.sparse_top_k, scale=cfg.routed_scale,
-                first_expert=first,
+                first_expert=first, score=cfg.sparse_score,
                 row_mask=None if row_mask is None else row_mask.reshape(B * L),
             )
             self.sow("intermediates", "moe_stats", stats)
@@ -1144,14 +1391,26 @@ class Block(nn.Module):
     ):
         cfg = self.cfg
         # where the two norms stand: before each sublayer, or (a pattern
-        # with `post_norm`) on its output, before the residual add
+        # with `post_norm`) on its output, before the residual add, or
+        # (`sandwich_norm`) on both: `attn_norm` / `mlp_norm` on the input,
+        # `attn_post_norm` / `mlp_post_norm` on the output
         post = self.spec is not None and cfg.post_norm
+        sandwich = self.spec is not None and cfg.sandwich_norm
         norm_in = lambda name, h: h if post else RMSNorm(cfg.norm_eps, name=name)(h)
-        norm_out = lambda name, y: RMSNorm(cfg.norm_eps, name=name)(y) if post else y
+
+        def norm_out(name, y):
+            if sandwich:
+                name = name.replace("_norm", "_post_norm")
+            return RMSNorm(cfg.norm_eps, name=name)(y) if post or sandwich else y
+
         h = norm_in("attn_norm", x)
         if self.spec is not None and self.spec.attention == "linear":
             mixed = LinearAttention(cfg, name="linear_attn")(
                 h, decode, positions, block_tables, row_mask
+            )
+        elif self.spec is not None and self.spec.attention == "latent":
+            mixed = LatentAttention(cfg, self.spec, name="latent_attn")(
+                h, cos, sin, decode, positions, block_tables
             )
         else:
             mixed = Attention(cfg, self.spec, name="attn")(
@@ -1240,16 +1499,22 @@ class TransformerLM(nn.Module):
         """The blocks of a model with a layer pattern, then the head."""
         cfg = self.cfg
         specs = [cfg.layer(i) for i in range(cfg.n_layers)]
+        latent = lambda spec: spec.attention == "latent"
         tables = {
             rope: rope_table(rope, cfg.head_dim, rope_len)
-            for rope in {spec.rope for spec in specs}
+            for rope in {spec.rope for spec in specs if not latent(spec)}
+        }
+        # a latent layer rotates its `latent_rope_dim` values, not a head's
+        latent_tables = {
+            rope: rope_table(rope, cfg.latent_rope_dim, rope_len)
+            for rope in {spec.rope for spec in specs if latent(spec)}
         }
         paired = isinstance(block_tables, (tuple, list))
         kinds = cfg.cache_kinds
         use_remat = cfg.remat and not decode
         block_cls = _remat_block() if use_remat else Block
         for i, spec in enumerate(specs):
-            cos, sin = tables[spec.rope]
+            cos, sin = (latent_tables if latent(spec) else tables)[spec.rope]
             block = block_cls(cfg, spec, name=f"layers_{i}")
             if use_remat:  # see __call__: `decode` stays a Python default
                 x = block(x, cos, sin)

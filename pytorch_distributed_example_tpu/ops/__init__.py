@@ -13,10 +13,14 @@ from .flash_attention import (  # noqa: F401
     partitioned_over,
 )
 from .paged_attention import (  # noqa: F401
+    gather_paged_latent,
+    latent_chunk_attention,
+    latent_decode_attention,
     paged_chunk_attention,
     paged_decode_attention,
     paged_kernel,
     pool_kv_heads,
+    pool_latent_width,
 )
 from .quant import (  # noqa: F401
     dequantize_blockwise,

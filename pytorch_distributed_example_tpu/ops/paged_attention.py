@@ -102,6 +102,10 @@ CHUNK_QUERY_BLOCK = 512
 #: VMEM the chunk kernel may take of a v5e TensorCore's 128 MiB: a query
 #: block of every head with its float32 accumulators stays resident
 CHUNK_VMEM_BYTES = 96 * 1024 * 1024
+#: queries of one grid step of the latent chunk kernel: every query meets
+#: the one shared row with all its heads, so a step's transposed score block
+#: is (keys, heads * queries): 256 x 4096 float32 at 128 heads and 32 queries
+LATENT_QUERY_BLOCK = 32
 #: what the prefetched scalars (tables, work list) may take of the 1 MiB
 #: of scalar memory of a TensorCore; the compiler keeps the rest.
 SMEM_BYTES = 768 * 1024
@@ -170,6 +174,20 @@ def pool_kv_heads(kv_heads: int) -> int:
     return -(-kv_heads // 8) * 8
 
 
+def pool_latent_width(width: int) -> int:
+    """Values of a row a latent pool holds for a model that caches `width`
+    of them a token (`serve/cache.py` asks): as many, or — past one lane
+    tile of 128 and not in whole tiles — the next multiple of 128, the
+    extra values zero. The device pads a row to whole lane tiles whatever
+    it is told (576 values are held as 640), and Mosaic refuses to copy a
+    page out of such a pool by the width it was told (compiled for v5e);
+    in whole tiles the pool holds the same bytes and a page is one
+    aligned copy."""
+    if width <= 128 or width % 128 == 0:
+        return width
+    return -(-width // 128) * 128
+
+
 def to_pool_heads(q, k, v, held: int):
     """q (B, L, H, Dh), k and v (B, L, KV, Dh) as a pool of `held` >= KV
     heads takes them (`pool_kv_heads`): zero heads behind k's and v's
@@ -194,7 +212,7 @@ def from_pool_heads(o, kv_heads: int, held: int):
     return o.reshape(B, L, held, -1)[:, :, :kv_heads].reshape(B, L, -1)
 
 
-def paged_kernel(L: int, pool, block_tables, window=None):
+def paged_kernel(L: int, pool, block_tables, window=None, rank=None):
     """Which kernel of this module takes the call: "decode"
     (`paged_decode_attention`), "chunk" (`paged_chunk_attention`) or
     None (the caller gathers the row's layout and runs the dense
@@ -223,7 +241,17 @@ def paged_kernel(L: int, pool, block_tables, window=None):
     `CHUNK_QUERY_BLOCK`, on a float32 or bfloat16 pool whose KV heads
     (as one device holds them) fill 32-bit words — one head, or an even
     number of bfloat16 ones: the kernel separates the heads of a page
-    by strided 32-bit reads."""
+    by strided 32-bit reads.
+
+    A LATENT pool ((num_blocks, bs, W): one row of W values a token, no
+    KV heads; `models/transformer.py::LatentAttention`) answers
+    "latent_decode" (`latent_decode_attention`), "latent_chunk"
+    (`latent_chunk_attention`) or None, from the same shapes (W as the
+    pool holds it: `pool_latent_width`) and `rank`,
+    the leading values of a row that are its values: see
+    `_latent_kernel_for`."""
+    if len(pool.shape) == 3:
+        return _latent_kernel_for(L, pool, block_tables, rank)
     _, bs, KV, Dh = pool.shape
     B, nb = block_tables.shape
     itemsize = jnp.dtype(pool.dtype).itemsize
@@ -246,6 +274,38 @@ def paged_kernel(L: int, pool, block_tables, window=None):
         and 4 * (B * nb + 2 * B) <= SMEM_BYTES
     ):
         return "chunk"
+    return None
+
+
+def _latent_kernel_for(L: int, pool, block_tables, rank):
+    """`paged_kernel` for a latent pool (num_blocks, bs, W), of which the
+    leading `rank` values of a row are also its values. Either kernel
+    needs a float32 or bfloat16 pool outside any `partitioned_over`
+    context (a latent pool is not partitioned: every head reads the whole
+    row), pages of whole sublane tiles, rows and values that fill whole
+    lane tiles (W and `rank` multiples of 128: a page is one aligned copy
+    and the value product reads a lane-aligned slice of the page it
+    scored), and its prefetched scalars within scalar memory.
+    "latent_decode":
+    one query token a row. "latent_chunk": more, in whole sublane tiles
+    and whole query blocks of `LATENT_QUERY_BLOCK`."""
+    _, bs, W = pool.shape
+    B, nb = block_tables.shape
+    if pool.dtype not in (jnp.float32, jnp.bfloat16) or _partition.spec is not None:
+        return None
+    tile = 32 // jnp.dtype(pool.dtype).itemsize
+    if bs % tile or W % 128 or not rank or rank % 128:
+        return None
+    if L == 1:
+        items = B * -(-nb // _pages_per_block(bs, nb))
+        fits = 4 * (B * nb + 2 * items + 2 * B + 1) <= SMEM_BYTES
+        return "latent_decode" if fits else None
+    if (
+        L % tile == 0
+        and L % min(L, LATENT_QUERY_BLOCK) == 0
+        and 4 * (B * nb + 2 * B) <= SMEM_BYTES
+    ):
+        return "latent_chunk"
     return None
 
 
@@ -707,4 +767,319 @@ def paged_chunk_attention(
     )
     return _on_kv_shards(local, q, pool_k)(
         q, pool_k, pool_v, block_tables, starts
+    )
+
+
+# --- a latent pool: one shared row a token, every head's key and value -------
+
+def gather_paged_latent(pool, block_tables):
+    """Each row's LOGICAL layout out of a latent pool: pool (num_blocks,
+    bs, W), block_tables (B, nb) -> (B, nb * bs, W) in position order.
+    Invalid entries clamp to a real block and the caller's mask hides
+    them, as in `gather_paged_kv`: the path of the shapes no kernel takes
+    and the reference both latent kernels are tested against."""
+    nblk, bs, W = pool.shape
+    B, nb = block_tables.shape
+    return pool[block_tables].reshape(B, nb * bs, W)
+
+
+def _latent_decode_kernel(
+    tables_ref, n_pages_ref, last_ref, item_row_ref, item_blk_ref,
+    n_items_ref, q_ref, pool_hbm, o_ref, buf, sems, m_s, l_s, acc_s,
+    *, scale, nb, P, rank,
+):
+    """`_kernel` for a latent pool: a page is `bs` rows of W values that
+    every query head scores whole and whose leading `rank` values are its
+    values too, so ONE copy a page serves both products and no column
+    belongs to a foreign head."""
+    H = q_ref.shape[1]
+    bs = pool_hbm.shape[1]
+    T = P * bs  # keys a compute block
+    n_items = n_items_ref[0]
+    precision = _precision(buf.dtype)
+    col = lax.broadcasted_iota(jnp.int32, (H, T), 1)
+    # pages a block does not have keep what the buffer held, and the rows
+    # are values too: zero once so that 0 * stale is never 0 * NaN; rows
+    # with no work item return zeros
+    buf[...] = jnp.zeros_like(buf)
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def page_copies(item, slot, fn):
+        row = item_row_ref[item]
+        first = item_blk_ref[item] * P
+        have = jnp.minimum(n_pages_ref[row] - first, P)
+        _page_copies(
+            fn, tables_ref, row * nb + first, have, (pool_hbm,), (buf,),
+            sems, slot,
+        )
+
+    @pl.when(n_items > 0)
+    def _():
+        page_copies(0, 0, lambda cp: cp.start())
+
+    def body(item, carry):
+        slot = item % 2
+
+        @pl.when(item + 1 < n_items)
+        def _():
+            page_copies(item + 1, 1 - slot, lambda cp: cp.start())
+
+        row = item_row_ref[item]
+        blk = item_blk_ref[item]
+
+        @pl.when(blk == 0)
+        def _():
+            m_s[...] = jnp.full_like(m_s, NEG_INF)
+            l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
+
+        page_copies(item, slot, lambda cp: cp.wait())
+        q = q_ref[row]  # (H, W)
+        k = buf[slot]  # (T, W)
+        s = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32,
+        ) * scale  # (H, T)
+        s = jnp.where(col < last_ref[row] - blk * T + 1, s, NEG_INF)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)  # masked: exp(-1e30 - m) == 0 exactly
+        alpha = jnp.exp(m_prev - m_new)
+        l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[...] = alpha * acc_s[...] + jnp.dot(
+            p.astype(k.dtype), k[:, :rank], precision=precision,
+            preferred_element_type=jnp.float32,
+        )
+        m_s[...] = m_new
+
+        @pl.when((blk + 1) * P >= n_pages_ref[row])
+        def _():
+            o_ref[row] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+
+        return carry
+
+    lax.fori_loop(0, n_items, body, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "rank", "interpret"))
+def _latent_decode_device(q, pool, block_tables, lengths, *, scale, rank, interpret):
+    """The latent decode kernel's call; a `jax.jit` of its own for the
+    reason `_per_device` is one."""
+    B, H, W = q.shape
+    nblk, bs, _ = pool.shape
+    nb = block_tables.shape[1]
+    P = _pages_per_block(bs, nb)
+    scalars = _work_list(block_tables, lengths, nblk, bs, P)
+    vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
+    with jax.named_scope("latent_decode_kernel"):
+        return pl.pallas_call(
+            functools.partial(
+                _latent_decode_kernel, scale=scale, nb=nb, P=P, rank=rank
+            ),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(scalars),
+                grid=(1,),
+                in_specs=[vmem(), pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=vmem(),
+                scratch_shapes=[
+                    pltpu.VMEM((2, P * bs, W), pool.dtype),
+                    pltpu.SemaphoreType.DMA((1, 2)),
+                    pltpu.VMEM((H, 1), jnp.float32),
+                    pltpu.VMEM((H, 1), jnp.float32),
+                    pltpu.VMEM((H, rank), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((B, H, rank), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=(pltpu.ARBITRARY,),
+            ),
+            interpret=interpret,
+            name="latent_decode_attention",
+        )(*scalars, q, pool)
+
+
+def latent_decode_attention(
+    q, pool, block_tables, lengths, scale, *, rank: int, interpret=None
+):
+    """One decode token a row against a paged LATENT pool, in the absorbed
+    form of multi-head latent attention.
+
+    q: (B, H, W), a head's query already moved onto the latent (`rank`
+    values) beside its rotary part; pool: (num_blocks, bs, W), a token's
+    normed latent beside its one rotated key; block_tables, lengths as
+    `paged_decode_attention` takes them. Every head of row b scores the
+    rows at positions <= lengths[b] over all W values and sums their
+    leading `rank` values: returns (B, H, rank) in q's dtype, which the
+    caller up-projects a head at a time. A page is copied once for both
+    products. Callers check `paged_kernel` first; design, bounds and
+    precision contract are `paged_decode_attention`'s."""
+    if interpret is None:
+        interpret = _interpret_default()
+    return _latent_decode_device(
+        q, pool, block_tables, lengths, scale=scale, rank=rank,
+        interpret=interpret,
+    )
+
+
+def _latent_chunk_kernel(
+    tables_ref, n_pages_ref, start_ref, q_ref, pool_hbm, o_ref,
+    buf, sems, m_s, l_s, acc_s, *, scale, nb, P, bq, rank,
+):
+    """Grid step (b, i): queries `start[b] + i * bq ...` of row b with all
+    their heads, as (H * bq, W) with row h * bq + t = (head h, query t),
+    against the key blocks that hold a position <= the last of them:
+    `_chunk_kernel` with one shared row in the place of the KV heads."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    N = q_ref.shape[0]  # H * bq query rows
+    bs = pool_hbm.shape[1]
+    T = P * bs  # keys a compute block
+    precision = _precision(buf.dtype)
+    first_q = start_ref[b] + i * bq
+    n_pages = jnp.minimum(n_pages_ref[b], (first_q + bq + bs - 1) // bs)
+    n_blocks = (n_pages + P - 1) // P
+
+    @pl.when((b == 0) & (i == 0))
+    def _():
+        buf[...] = jnp.zeros_like(buf)  # see `_latent_decode_kernel`
+
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+    def page_copies(blk, slot, fn):
+        _page_copies(
+            fn, tables_ref, b * nb + blk * P, jnp.minimum(n_pages - blk * P, P),
+            (pool_hbm,), (buf,), sems, slot,
+        )
+
+    @pl.when(n_blocks > 0)
+    def _():
+        page_copies(0, 0, lambda cp: cp.start())
+
+    def attend(slot, key0, masked):
+        """Scores TRANSPOSED, (keys, N), for the reasons `_chunk_kernel`
+        gives: the softmax reduces down the sublanes and its running
+        state is lane-dense rows."""
+        k = buf[slot]  # (T, W)
+        s = lax.dot_general(
+            k, q_ref[...], (((1,), (1,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32,
+        ) * scale  # (T, N)
+        if masked:
+            key = lax.broadcasted_iota(jnp.int32, (T, N), 0)
+            t = lax.rem(lax.broadcasted_iota(jnp.int32, (T, N), 1), bq)
+            keep = key <= jnp.minimum(first_q + t, n_pages * bs - 1) - key0
+            s = jnp.where(keep, s, NEG_INF)
+        m_prev = m_s[...]  # (1, N)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=0, keepdims=True)
+        acc_s[...] = alpha * acc_s[...] + lax.dot_general(
+            k[:, :rank], p.astype(k.dtype), (((0,), (0,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32,
+        )  # (rank, N)
+        m_s[...] = m_new
+
+    def body(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            page_copies(blk + 1, 1 - slot, lambda cp: cp.start())
+
+        page_copies(blk, slot, lambda cp: cp.wait())
+        key0 = blk * T
+        # the mask is work: only a block that crosses the diagonal (a
+        # key past the first query) or the row's last valid page pays it
+        masked = (key0 + T - 1 > first_q) | (key0 + T > n_pages * bs)
+
+        @pl.when(masked)
+        def _():
+            attend(slot, key0, True)
+
+        @pl.when(jnp.logical_not(masked))
+        def _():
+            attend(slot, key0, False)
+
+        return carry
+
+    lax.fori_loop(0, n_blocks, body, 0)
+    l = l_s[...]  # 0 on a row with no valid page: zeros, not 0 / 0
+    o_ref[...] = jnp.where(l > 0, acc_s[...] / l, 0.0).T.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "rank", "interpret"))
+def _latent_chunk_device(q, pool, block_tables, starts, *, scale, rank, interpret):
+    """The latent chunk kernel's call; a `jax.jit` of its own for the
+    reason `_per_device` is one."""
+    B, L, H, W = q.shape
+    nblk, bs, _ = pool.shape
+    nb = block_tables.shape[1]
+    bq = min(L, LATENT_QUERY_BLOCK)
+    nq = L // bq
+    P = _pages_per_block(bs, nb)
+    block_tables = block_tables.astype(jnp.int32)
+    scalars = (
+        block_tables.reshape(B * nb), _leading(block_tables < nblk),
+        starts.astype(jnp.int32),
+    )
+    # head h of query i * bq + t -> [i, h * bq + t]
+    qg = q.reshape(B, nq, bq, H, W).transpose(0, 1, 3, 2, 4).reshape(B, nq, H * bq, W)
+    block = lambda width: pl.BlockSpec(
+        (None, None, H * bq, width), lambda b, i, *_: (b, i, 0, 0)
+    )
+    f32 = jnp.float32
+    with jax.named_scope("latent_chunk_kernel"):
+        out = pl.pallas_call(
+            functools.partial(
+                _latent_chunk_kernel, scale=scale, nb=nb, P=P, bq=bq, rank=rank
+            ),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(scalars),
+                grid=(B, nq),
+                in_specs=[block(W), pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=block(rank),
+                scratch_shapes=[
+                    pltpu.VMEM((2, P * bs, W), pool.dtype),
+                    pltpu.SemaphoreType.DMA((1, 2)),
+                    pltpu.VMEM((1, H * bq), f32),
+                    pltpu.VMEM((1, H * bq), f32),
+                    pltpu.VMEM((rank, H * bq), f32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((B, nq, H * bq, rank), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY),
+                vmem_limit_bytes=CHUNK_VMEM_BYTES,
+            ),
+            interpret=interpret,
+            name="latent_chunk_attention",
+        )(*scalars, qg, pool)
+    out = out.reshape(B, nq, H, bq, rank).transpose(0, 1, 3, 2, 4)
+    return out.reshape(B, L, H, rank)
+
+
+def latent_chunk_attention(
+    q, pool, block_tables, starts, scale, *, rank: int, interpret=None
+):
+    """A prefill chunk of L query tokens a row against a paged LATENT
+    pool, absorbed: `latent_decode_attention` carried to L queries as
+    `paged_chunk_attention` carries the decode kernel.
+
+    q: (B, L, H, W); pool, block_tables as `latent_decode_attention`
+    takes them; starts: (B,) int32. Query i of row b sits at absolute
+    position `starts[b] + i` and attends the rows at positions <= it (the
+    chunk's own, written first, included); returns (B, L, H, rank). A
+    grid step takes `LATENT_QUERY_BLOCK` queries with all their heads
+    (the one shared row is every head's key and value, so the heads of a
+    query block are one operand of H * bq rows) and walks the key blocks
+    up to its last query; a block is copied once and read as keys (W
+    values) and as values (the leading `rank`). Callers check
+    `paged_kernel` first; precision contract in the module docstring."""
+    if interpret is None:
+        interpret = _interpret_default()
+    return _latent_chunk_device(
+        q, pool, block_tables, starts, scale=scale, rank=rank,
+        interpret=interpret,
     )

@@ -222,6 +222,57 @@ def grouped_swiglu(rows, w_gate, w_up, w_down, sizes):
     return dot(h.astype(rows.dtype), w_down)
 
 
+def _share_of_assignments(x, order, sizes, weights, experts, top_k: int, few: int):
+    """`dropless_moe`'s grouped products and their sum back onto the
+    tokens for a caller that holds a SHARE of the experts: it is given
+    about that share of the T * top_k assignments, and they lead the sorted
+    `order` (the rest sort behind every held group). Where all of them lie
+    in its first `few`, only those rows are gathered, multiplied and
+    summed back (a one-hot product, `few` rows onto T tokens: exact in
+    float32); a call that places more computes every assignment, as a chip
+    that holds every expert does. Nothing is dropped either way, and the
+    result does not depend on `few`. Returns (y (T, D) float32, stats)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    T, D = x.shape
+    with jax.named_scope("dispatch"):
+        total = jnp.sum(sizes)
+
+    def grouped(n):
+        with jax.named_scope("dispatch"):
+            first = order[:n]
+            rows = x[first // top_k]  # (n, D), by expert
+            placed = jnp.arange(n) < total
+        with jax.named_scope("experts"):
+            out = grouped_swiglu(rows, *experts, sizes)  # float32
+        with jax.named_scope("dispatch"):
+            # rows past the last group belong to no product
+            return jnp.where(placed[:, None], out, 0.0) * weights[first][:, None], first
+
+    def leading():
+        out, first = grouped(few)
+        with jax.named_scope("dispatch"):
+            onto = (first // top_k)[None, :] == jnp.arange(T)[:, None]  # (T, few)
+            return jnp.dot(
+                onto.astype(jnp.float32), out, precision=lax.Precision.HIGHEST
+            )
+
+    def every():
+        out, _ = grouped(T * top_k)
+        with jax.named_scope("dispatch"):
+            back = jnp.zeros((T * top_k,), jnp.int32).at[order].set(
+                jnp.arange(T * top_k, dtype=jnp.int32)
+            )
+            return out[back].reshape(T, top_k, D).sum(axis=1)
+
+    y = lax.cond(total <= few, leading, every)
+    with jax.named_scope("dispatch"):
+        stats = jnp.stack([total, jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return y, stats
+
+
 def dropless_moe(
     x,
     router_w,
@@ -234,15 +285,17 @@ def dropless_moe(
     scale: float = 1.0,
     first_expert: int = 0,
     row_mask=None,
+    score: str = "softmax",
 ):
     """Dropless top-k MoE over gated (SwiGLU) experts.
 
     x: (T, D). router_w: (D, n_experts), the router's FULL width.
     w_gate / w_up: (held, D, F), w_down: (held, F, D): the experts
     `first_expert .. first_expert + held - 1`, the contiguous range this
-    caller holds. Every row scores all `n_experts` (softmax in float32),
-    takes its `top_k`, and weighs them by their probabilities normalised
-    to sum to 1, times `scale`; the result is the part of
+    caller holds. Every row scores all `n_experts` in float32 (`score`:
+    "softmax" over them, or "sigmoid" of each: no expert's score depends
+    on another's), takes its `top_k`, and weighs them by their scores
+    normalised to sum to 1, times `scale`; the result is the part of
     sum_e w_e * SwiGLU_e(x) that the held experts give (all of it when
     all are held; the parts of disjoint ranges add up to the whole).
     Weights multiply expert OUTPUTS.
@@ -250,7 +303,11 @@ def dropless_moe(
     Nothing is dropped and there is no capacity: the T * top_k
     assignments are sorted by expert and the three products run grouped
     (`grouped_swiglu`), so an expert costs the rows it was given and an
-    expert no row chose is not read.
+    expert no row chose is not read. A caller that holds a SHARE of the
+    experts is given about that share of the assignments: where four
+    times a uniform router's share (in whole row tiles) is fewer rows
+    than T * top_k, `_share_of_assignments` gathers and multiplies only
+    that many where they suffice and every one where they do not.
 
     `row_mask` ((T,) bool): rows that are False have NO assignment: they
     reach no expert, widen no group and count in no counter; their
@@ -273,9 +330,13 @@ def dropless_moe(
             x.astype(jnp.float32), router_w.astype(jnp.float32),
             precision=lax.Precision.HIGHEST,
         )  # (T, n_experts)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_e = lax.top_k(probs, top_k)  # (T, k)
-        weight = scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        if score == "sigmoid":
+            top_p, top_e = lax.top_k(jax.nn.sigmoid(logits), top_k)  # (T, k)
+            weight = scale * top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            top_p, top_e = lax.top_k(probs, top_k)  # (T, k)
+            weight = scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     with jax.named_scope("dispatch"):
         local = top_e - first_expert
         mine = (local >= 0) & (local < held)
@@ -285,6 +346,16 @@ def dropless_moe(
         group = jnp.where(mine, local, held).reshape(T * top_k)
         order = jnp.argsort(group, stable=True)
         sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+    # four times what a uniform router would place here, in whole row tiles
+    # of the grouped kernel
+    few = -(-4 * T * top_k * held // (n_experts * GMM_TILING[0])) * GMM_TILING[0]
+    if few < T * top_k:
+        y, stats = _share_of_assignments(
+            x, order, sizes, jnp.where(mine, weight, 0.0).reshape(T * top_k),
+            (w_gate, w_up, w_down), top_k, few,
+        )
+        return y.astype(x.dtype), stats, top_e
+    with jax.named_scope("dispatch"):
         rows = x[order // top_k]  # (T * k, D), by expert
         w_sorted = jnp.where(mine, weight, 0.0).reshape(T * top_k)[order]
         placed = jnp.arange(T * top_k) < jnp.sum(sizes)

@@ -47,6 +47,21 @@ block taken over from a retired or preempted request starts clean. What
 is not carried for it: snapshots (a preempted request prefills again
 from 0; a prefix cannot be shared).
 
+A FOURTH KIND, the latent (a model whose `cfg.latent_layers` are
+multi-head latent attention mixers, `models/transformer.py::
+LatentAttention`): such a layer keeps ONE row of `cfg.latent_width` values a
+token (a compressed latent and one shared rotary key) where a full layer
+keeps K and V heads, so it gets ONE pool, (num_blocks, block_size,
+latent_width), and no V pool. It keeps every token, as a full layer does:
+latent blocks are allocated, refcounted and freed through the SAME tables
+and free lists as the full kind's (a block id names the same tokens in
+every layer that keeps every token), and `tables()` hands the programs
+that table under the kind's name. A row of more than 128 values is held
+in whole 128-value lane tiles (`ops.pool_latent_width`: 576 as 640, the
+bytes the device would pad it to anyway), and `latent_bytes_per_block`
+counts the rows as held. What is not carried for it: an int8 pool, tp, block migration,
+prefix sharing (`serve/engine.py` refuses them).
+
 Physical blocks are REFCOUNTED (ISSUE 12): `attach_prefix` lets a
 slot reference blocks another request already filled (the prefix
 cache, `serve/prefix.py`), `free()` DECREMENTS instead of releasing
@@ -145,6 +160,12 @@ def linear_layers_of(cfg) -> tuple:
     return tuple(getattr(cfg, "linear_layers", ()))
 
 
+def latent_layers_of(cfg) -> tuple:
+    """The layers that keep one latent row a token and no K and V heads
+    (a model configuration that says nothing has none)."""
+    return tuple(getattr(cfg, "latent_layers", ()))
+
+
 def init_paged_cache(model, num_blocks: int, block_size: int,
                      quantized: bool = False,
                      window_blocks: Optional[int] = None,
@@ -155,7 +176,8 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
     `window_blocks` blocks instead in a layer the model's pattern marks
     as a window layer, and NONE in a linear layer, which gets
     `state_blocks` state blocks (`models.transformer.linear_state_shapes`) under its mixer's
-    name. Mirrors
+    name; a latent layer gets ONE (num_blocks, block_size, latent_width)
+    pool, `latent`, under its mixer's. Mirrors
     `models.generate.init_cache`'s structure minus the scalar "index"
     leaf (a shared pool has no per-row cursor).
 
@@ -170,7 +192,7 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
     import jax.numpy as jnp
 
     from ..models.transformer import linear_state_shapes
-    from ..ops.paged_attention import pool_kv_heads
+    from ..ops.paged_attention import pool_kv_heads, pool_latent_width
 
     cfg = model.cfg
     KV, Dh = pool_kv_heads(cfg.kv_heads), cfg.head_dim
@@ -180,6 +202,9 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
     linear = linear_layers_of(cfg)
     if linear and state_blocks is None:
         raise ValueError("a model with linear layers needs state_blocks")
+    latent = latent_layers_of(cfg)
+    if latent and quantized:
+        raise ValueError("a latent pool has no int8 form")
 
     def one_layer(num_blocks):
         if quantized:
@@ -212,11 +237,20 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
             for leaf, (shape, dtype) in linear_state_shapes(cfg).items()
         }}
 
-    return {
-        f"layers_{i}": state_layer() if i in linear else one_layer(
-            window_blocks if windowed[i] else num_blocks)
-        for i in range(cfg.n_layers)
-    }
+    def latent_layer():
+        return {"latent_attn": {"latent": jnp.zeros(
+            (num_blocks, block_size, pool_latent_width(cfg.latent_width)),
+            cfg.dtype,
+        )}}
+
+    def layer(i):
+        if i in linear:
+            return state_layer()
+        if i in latent:
+            return latent_layer()
+        return one_layer(window_blocks if windowed[i] else num_blocks)
+
+    return {f"layers_{i}": layer(i) for i in range(cfg.n_layers)}
 
 
 class PagedKVCache:
@@ -267,12 +301,23 @@ class PagedKVCache:
         self.window_layers = sum(windowed)
         self.linear_layers = len(linear_layers_of(cfg))
         from ..models.transformer import linear_state_shapes
-        from ..ops.paged_attention import pool_kv_heads
+        from ..ops.paged_attention import pool_kv_heads, pool_latent_width
 
         self.pool_kv_heads = pool_kv_heads(cfg.kv_heads)  # as the pool holds them
         # leaf -> (shape, dtype) of one state block of one linear layer
         self._state_shapes = linear_state_shapes(cfg) if self.linear_layers else {}
-        self.full_layers = cfg.n_layers - self.window_layers - self.linear_layers
+        # the latent kind: one row a token in one pool a layer, under the
+        # full kind's tables and free lists
+        self.latent_layers = len(latent_layers_of(cfg))
+        # values of a row as the model caches it, and as the pool holds
+        # it: whole lane tiles past 128 values
+        self.latent_values = cfg.latent_width if self.latent_layers else 0
+        self.latent_width = pool_latent_width(self.latent_values)
+        self.latent_rank = cfg.latent_kv_rank if self.latent_layers else 0
+        self.full_layers = (
+            cfg.n_layers - self.window_layers - self.linear_layers
+            - self.latent_layers
+        )
         # the kinds of state the model's layers keep, in the order the
         # programs take their tables
         self.kinds = tuple(getattr(cfg, "cache_kinds", ("full",)))
@@ -455,8 +500,9 @@ class PagedKVCache:
         """The block tables the programs take for the given slots: the
         (n, nb) table, or where the model's layers keep more than one
         kind of state the tuple of one table a kind (full layers' (n,
-        nb), window layers' (n, nb), linear layers' (n, 1) state table),
-        in `cfg.cache_kinds`' order. `parked` slots' rows are handed
+        nb), window layers' (n, nb), linear layers' (n, 1) state table,
+        latent layers' (n, nb): the full kind's), in `cfg.cache_kinds`'
+        order. `parked` slots' rows are handed
         over all-invalid. Always COPIES: the engine goes on growing and
         freeing rows while the program it handed a table to is still
         queued, and a program may read its host arguments late (the CPU
@@ -466,6 +512,7 @@ class PagedKVCache:
             "full": (self.block_tables, self.invalid_block),
             "window": (self.window_tables, self.window_invalid_block),
             "linear": (self.state_table, self.state_invalid_block),
+            "latent": (self.block_tables, self.invalid_block),
         }
         out = [have[kind][0][rows].copy() for kind in self.kinds]
         for t, kind in zip(out, self.kinds):
@@ -692,9 +739,23 @@ class PagedKVCache:
         shape, dtype = self._state_shapes["state"]
         return jax.ShapeDtypeStruct((self.state_num_blocks,) + shape, dtype)
 
+    @property
+    def latent_aval(self):
+        """Shape and dtype of one latent layer's pool (None with no
+        latent layer), as `pool_aval` is a full layer's."""
+        if not self.latent_layers:
+            return None
+        import jax
+
+        return jax.ShapeDtypeStruct(
+            (self.num_blocks, self.block_size, self.latent_width),
+            self.model.cfg.dtype,
+        )
+
     @functools.cached_property
     def bytes_per_block(self) -> int:
-        """HBM bytes one block pins across every layer (K + V, PLUS the
+        """HBM bytes one block pins across every layer that keeps every
+        token (a full layer's K + V, a latent layer's rows, PLUS the
         per-token scale planes when quantized — the true pool cost, so
         fixed-pool-bytes comparisons account the scale overhead)."""
         cfg = self.model.cfg
@@ -704,7 +765,22 @@ class PagedKVCache:
         return (
             2 * self.full_layers * self.block_size * self.pool_kv_heads
             * cfg.head_dim * itemsize
-        ) + self.scale_bytes_per_block
+        ) + self.latent_bytes_per_block + self.scale_bytes_per_block
+
+    @functools.cached_property
+    def latent_bytes_per_block(self) -> int:
+        """HBM bytes one block pins across the latent layers, AS HELD:
+        rows of `latent_width` values (`ops.pool_latent_width`: 576
+        cached values are held as 640)."""
+        return (
+            self.latent_layers * self.block_size * self.latent_width
+            * np.dtype(self.model.cfg.dtype).itemsize
+        )
+
+    @property
+    def latent_live_blocks(self) -> int:
+        """Blocks some slot holds latent rows in (0 with no latent layer)."""
+        return self.live_blocks if self.latent_layers else 0
 
     @functools.cached_property
     def window_bytes_per_block(self) -> int:
@@ -762,10 +838,11 @@ class PagedKVCache:
         layer's share is its state block in either layout)."""
         cfg = self.model.cfg
         itemsize = np.dtype(cfg.dtype).itemsize
+        kv_layers = cfg.n_layers - self.linear_layers - self.latent_layers
         return (
-            2 * (cfg.n_layers - self.linear_layers) * cfg.max_seq_len
-            * cfg.kv_heads * cfg.head_dim * itemsize
-        ) + self.state_bytes_per_block
+            2 * kv_layers * cfg.max_seq_len * cfg.kv_heads * cfg.head_dim
+            + self.latent_layers * cfg.max_seq_len * self.latent_values
+        ) * itemsize + self.state_bytes_per_block
 
     def slot_blocks(self, slot: int) -> List[int]:
         return list(self._slot_blocks[slot])

@@ -93,7 +93,8 @@ def layer_paths(
     apply the model under; a linear layer takes, for one token a row,
     its "recurrence_kernel" (`ops/delta_recurrence.py`, where
     `delta_kernel_ok` says so) or the plain "recurrence", and for a chunk
-    its "chunk_scan"."""
+    its "chunk_scan"; a latent layer takes "latent_decode_kernel" or
+    "latent_chunk_kernel" (`ops/paged_attention.py`) or "gather"."""
     from ..ops import paged_kernel
     from ..ops.delta_recurrence import delta_kernel_ok
 
@@ -106,6 +107,14 @@ def layer_paths(
             if layers:
                 kernel = paged_kernel(L, cache.pool_aval, table[:rows], window)
                 paths[kind] = (layers, f"{kernel}_kernel" if kernel else "gather")
+        if cache.latent_layers:
+            kernel = paged_kernel(
+                L, cache.latent_aval, cache.block_tables[:rows],
+                rank=cache.latent_rank,
+            )
+            paths["latent"] = (
+                cache.latent_layers, f"{kernel}_kernel" if kernel else "gather"
+            )
         if cache.linear_layers:
             step = "recurrence_kernel" if delta_kernel_ok(cache.state_aval) else "recurrence"
             paths["linear"] = (cache.linear_layers, step if L == 1 else "chunk_scan")
@@ -205,7 +214,8 @@ def paged_programs(
       their sampled tokens are ignored by the scheduler.
 
     Where the layers keep more than one kind of state (`serve/cache.py`:
-    every key and value, a window of them, a recurrent state), `bt_row`
+    every key and value, a window of them, a recurrent state, a latent
+    row a token), `bt_row`
     and `bt` are the tuple of one table a kind, in `cfg.cache_kinds`'
     order.
 

@@ -83,6 +83,14 @@ padding is told apart from tokens and leaves the state as the last real
 token left it, a chunk that starts at position 0 reads a zero state
 (so preemption frees the block and the requeued request prefills again
 from 0: no snapshot is kept), and the same five things are refused.
+A model with LATENT layers (multi-head latent attention) keeps one row
+of `latent_width` values a token in one pool a layer, under the full
+kind's tables, free lists and refcounts; its cached calls run absorbed
+(`models/transformer.py::LatentAttention`), and the same five things are
+refused. Every decode step and every prefill chunk is dispatched under a
+host annotation (`serve:decode_step`: rows, keys attended;
+`serve:prefill_chunk`: slot, start, tokens, bucket) that a profiler
+trace keeps and nothing in the engine reads.
 
 Fault surface: `serve.admit` before each admission, `serve.
 prefix_attach` before a prefix-cache attach, `serve.prefill_chunk`
@@ -137,7 +145,12 @@ from .. import faults
 from ..numerics import numerics_contract
 from ..types import DistError
 from .bucketing import bucket_for, bucket_lengths
-from .cache import PagedKVCache, linear_layers_of, window_layers_of
+from .cache import (
+    PagedKVCache,
+    latent_layers_of,
+    linear_layers_of,
+    window_layers_of,
+)
 from .decode import kernel_layers, layer_paths, paged_programs, sync_slot_lanes
 from .metrics import ServeMetrics
 from .queue import (
@@ -244,7 +257,8 @@ class ServeEngine:
         self.model = model
         self.params = params["params"] if "params" in params else params
         self.cfg = model.cfg
-        # what is not carried with window layers, and with linear layers
+        # what is not carried with window layers, with linear layers and
+        # with latent layers
         refusals = {}
         if any(window_layers_of(self.cfg)):
             refusals["window"] = {
@@ -270,6 +284,18 @@ class ServeEngine:
                 f"role={role!r} (block migration moves K/V blocks, not a "
                 "state block)": role != "both",
                 "precompiled= (pre-warmed programs take one table)":
+                    bool(precompiled),
+            }
+        if latent_layers_of(self.cfg):
+            refusals["latent"] = {
+                "prefix_cache=True (a shared prefix's latent blocks under "
+                "copy-on-write are untested)": prefix_cache,
+                "kv_quant=True (a latent pool has no int8 form)": kv_quant,
+                "mesh= (a latent pool has no KV heads to partition over tp)":
+                    mesh is not None,
+                f"role={role!r} (block migration moves K/V blocks, not "
+                "latent ones)": role != "both",
+                "precompiled= (pre-warmed programs take a K/V pool)":
                     bool(precompiled),
             }
         for kind, refused in refusals.items():
@@ -732,6 +758,7 @@ class ServeEngine:
         step. At least one program runs per tick, so a budget below the
         smallest bucket still makes progress."""
         import jax.numpy as jnp
+        import jax.profiler
 
         budget = self.prefill_chunk_tokens
         spent = 0
@@ -781,13 +808,19 @@ class ServeEngine:
             # tell it from a token (`serve/decode.py::paged_programs`)
             chunk = np.full((1, C), self._pad_id, np.int32)
             chunk[0, : end - pf.pos] = req.prompt[pf.pos:end]
-            self.cache.tree, logits = self._prefill_chunk(
-                self.params,
-                self.cache.tree,
-                jnp.asarray(chunk),
-                self.cache.tables(slice(slot, slot + 1)),
-                pf.pos,
-            )
+            # what the chunk is, on the host line of a profiler trace
+            # (free without one; nothing here reads it back)
+            with jax.profiler.TraceAnnotation(
+                "serve:prefill_chunk", slot=slot, start=pf.pos,
+                tokens=end - pf.pos, bucket=C,
+            ):
+                self.cache.tree, logits = self._prefill_chunk(
+                    self.params,
+                    self.cache.tree,
+                    jnp.asarray(chunk),
+                    self.cache.tables(slice(slot, slot + 1)),
+                    pf.pos,
+                )
             self.metrics.record_prefill_chunk(
                 self._chunk_kernel_layers[C], self.model.cfg.n_layers
             )
@@ -999,6 +1032,8 @@ class ServeEngine:
             window_bytes_per_block=self.cache.window_bytes_per_block,
             state_blocks_live=self.cache.state_live_blocks,
             state_bytes_per_block=self.cache.state_bytes_per_block,
+            latent_blocks_live=self.cache.latent_live_blocks,
+            latent_bytes_per_block=self.cache.latent_bytes_per_block,
         )
         while True:
             self._prefill_tick()
@@ -1060,20 +1095,29 @@ class ServeEngine:
             for s, req in enumerate(self._slot_req)
             if req is not None and s not in self._decoding
         ]
-        (
-            self.cache.tree,
-            self._dev_lengths,
-            self._dev_tokens,
-            self._dev_rngs,
-            readback,
-        ) = self._step(
-            self.params,
-            self.cache.tree,
-            self._dev_lengths,
-            self._dev_tokens,
-            self._dev_rngs,
-            self.cache.tables(parked=parked),
-        )
+        import jax.profiler
+
+        # the rows that decode and the keys they attend (each row's cached
+        # keys and the one this step writes), on the host line of a
+        # profiler trace (free without one; nothing here reads it back)
+        with jax.profiler.TraceAnnotation(
+            "serve:decode_step", rows=len(active),
+            keys=int(self.cache.lengths[active].sum()) + len(active),
+        ):
+            (
+                self.cache.tree,
+                self._dev_lengths,
+                self._dev_tokens,
+                self._dev_rngs,
+                readback,
+            ) = self._step(
+                self.params,
+                self.cache.tree,
+                self._dev_lengths,
+                self._dev_tokens,
+                self._dev_rngs,
+                self.cache.tables(parked=parked),
+            )
         self.metrics.record_decode_step(self._decode_kernel, overlapped)
         self._await(readback, active)
         # the host mirror advances at dispatch: the next call grows
@@ -1152,9 +1196,13 @@ class ServeEngine:
 
         assignments = counters[0::2].tolist()
         hit = counters[1::2].tolist()
-        self.metrics.record_moe_step(sum(assignments), hit)
+        # what the routers chose: `top_k` experts a live row and sparse
+        # layer, of which this chip computed its held experts' share
+        routed = rows * self.cfg.sparse_top_k * self._sparse_layers
+        self.metrics.record_moe_step(sum(assignments), hit, routed)
         with jax.profiler.TraceAnnotation(
             "serve:moe_step", rows=rows, assignments=sum(assignments),
+            routed=routed,
             **{f"hit{i}": h for i, h in enumerate(hit)},  # per sparse layer
         ):
             pass

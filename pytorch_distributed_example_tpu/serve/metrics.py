@@ -196,6 +196,17 @@ class ServeMetrics:
         # live request (gauge), and the bytes a block pins
         self.state_blocks_live = 0
         self.state_bytes_per_block = 0
+        # the fourth kind, a latent layer's rows: the blocks that hold
+        # them (the full kind's table names them) and the bytes a block
+        # pins in the latent pools AS HELD (the device's tiling included);
+        # those bytes are part of `pool_bytes_per_block`
+        self.latent_blocks_live = 0
+        self.latent_bytes_per_block = 0
+        # (row, expert) pairs the routers chose, last step and in all: a
+        # chip that holds a share of the experts computes that share of
+        # them (`moe_assignments`)
+        self.moe_routed = 0
+        self.moe_routed_total = 0
         # kind of layer -> [layers, the path their mixer takes] in the
         # decode step and in a prefill chunk (`serve.decode.layer_paths`)
         self.decode_layer_paths: Dict[str, list] = {}
@@ -317,6 +328,11 @@ class ServeMetrics:
         """Bytes the live requests' recurrent state blocks pin."""
         return self.state_blocks_live * self.state_bytes_per_block
 
+    @property
+    def latent_bytes_live(self) -> int:
+        """Bytes the live latent blocks pin, as the device holds them."""
+        return self.latent_blocks_live * self.latent_bytes_per_block
+
     def record_layer_paths(self, decode: Dict, prefill: Dict) -> None:
         """Which path each kind of layer takes in the engine's step and
         in its chunk of the budget's length: facts of its lifetime."""
@@ -330,10 +346,13 @@ class ServeMetrics:
             self.prefill_attention_calls += layers
             self.prefill_kernel_calls += kernel_layers
 
-    def record_moe_step(self, assignments: int, experts_hit) -> None:
-        """One decode step of a model with sparse layers."""
+    def record_moe_step(self, assignments: int, experts_hit, routed: int = 0) -> None:
+        """One decode step of a model with sparse layers: the assignments
+        computed here, of the `routed` the routers chose."""
         with self._lock:
             self.moe_steps += 1
+            self.moe_routed = int(routed)
+            self.moe_routed_total += int(routed)
             self.moe_assignments = int(assignments)
             self.moe_experts_hit = [int(h) for h in experts_hit]
             self.moe_assignments_total += int(assignments)
@@ -405,6 +424,8 @@ class ServeMetrics:
         window_bytes_per_block: int = 0,
         state_blocks_live: int = 0,
         state_bytes_per_block: int = 0,
+        latent_blocks_live: int = 0,
+        latent_bytes_per_block: int = 0,
     ) -> None:
         """Per-step paged-pool observation. Gauges keep the LAST value;
         utilization and bytes-per-live-request also accumulate a
@@ -425,6 +446,8 @@ class ServeMetrics:
             self.window_bytes_per_block = window_bytes_per_block
             self.state_blocks_live = state_blocks_live
             self.state_bytes_per_block = state_bytes_per_block
+            self.latent_blocks_live = latent_blocks_live
+            self.latent_bytes_per_block = latent_bytes_per_block
             self.pool_blocks_live = blocks_live
             self.pool_blocks_total = blocks_total
             self.pool_bytes_per_block = bytes_per_block
@@ -725,6 +748,8 @@ class ServeMetrics:
                     "assignments": self.moe_assignments,
                     "experts_hit": list(self.moe_experts_hit),
                     "assignments_total": self.moe_assignments_total,
+                    "routed": self.moe_routed,
+                    "routed_total": self.moe_routed_total,
                     "experts_hit_mean": round(
                         self._moe_hit_sum / self.moe_steps, 3
                     ) if self.moe_steps else 0.0,
@@ -771,6 +796,8 @@ class ServeMetrics:
                     "window_blocks_recycled": self.window_blocks_recycled,
                     "state_blocks_live": self.state_blocks_live,
                     "state_bytes_live": self.state_bytes_live,
+                    "latent_blocks_live": self.latent_blocks_live,
+                    "latent_bytes_live": self.latent_bytes_live,
                 },
                 # prefix sharing (ISSUE 12): hit rate + tokens whose
                 # prefill compute/pool writes were skipped, block-level

@@ -321,6 +321,55 @@ def test_paged_chunk_kernel_compiles_at_the_serve_cells_widths(
     assert len(pool_ops) == 2 and all(" bitcast(" in l for l in pool_ops)
 
 
+@pytest.mark.parametrize("L", [1, 512, 128])
+def test_the_latent_kernels_compile_at_the_long_document_cell_s_shapes(topo, monkeypatch, L):
+    """`ops.latent_decode_attention` and `ops.latent_chunk_attention` as
+    `serve_pangu_longdoc_c8` calls them: 8 rows x 1024 pages of the
+    8192-block latent pool for the step, one row for a chunk (the largest
+    and the smallest bucket), 128 heads on rows of 576 values held as 640,
+    of which 512 are values. Mosaic takes the page copies (it refused a pool
+    told 576: `Slice shape along dimension 2 must be aligned to tiling
+    (128)`), the 640-wide contraction and the chunk kernel's VMEM, and the
+    pool goes into the call as it is: no copy of its 1.17 GB in any call."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_example_tpu.ops import (
+        latent_chunk_attention, latent_decode_attention, paged_kernel, pool_latent_width,
+    )
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    nblk, bs, nb, H, rank = 8192, 16, 1024, 128, 512
+    W = pool_latent_width(rank + 64)
+    assert W == 640
+    pool = sd((nblk, bs, W), jnp.bfloat16)
+    B = 8 if L == 1 else 1
+    tables = sd((B, nb), jnp.int32)
+    if L == 1:
+        assert paged_kernel(1, pool, tables, rank=rank) == "latent_decode"
+        call, q, name = latent_decode_attention, sd((B, H, W), jnp.bfloat16), "latent_decode"
+    else:
+        assert paged_kernel(L, pool, tables, rank=rank) == "latent_chunk"
+        call, q, name = latent_chunk_attention, sd((B, L, H, W), jnp.bfloat16), "latent_chunk"
+    compiled = jax.jit(functools.partial(call, scale=192 ** -0.5, rank=rank)).lower(
+        q, pool, tables, sd((B,), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    (custom,) = _custom_calls(hlo)
+    assert f"{name}_attention" in custom
+    # the pool is an operand of the call itself, and nothing else has its shape
+    assert "%pool" in custom.split("custom-call(")[1]
+    assert not [l for l in hlo.splitlines()
+                if f" = bf16[{nblk},{bs},{W}]" in l and "parameter(" not in l]
+    # a chunk's temporaries are its queries and outputs regrouped by head
+    # (2 x 512 x 128 x (640 + 512) x 2 B = 151 MB), never the pool's 168 MB
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * L * H * (W + rank) * 2 + 2**20
+
+
 @pytest.mark.parametrize("rows", [32, 128, 256, 512])
 def test_the_grouped_expert_kernel_compiles_and_keeps_its_scope(topo, monkeypatch, rows):
     """The sparse MLP as `serve_laguna_mixed_c32` runs it (256 experts of

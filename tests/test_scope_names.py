@@ -24,6 +24,7 @@ from pytorch_distributed_example_tpu.models.transformer import (
 LAYER = r"TransformerLM\)*/layers_\d+/attn/(attn\.\w+/)*"
 MLP = r"TransformerLM\)*/layers_\d+/mlp/"
 LINEAR = r"TransformerLM\)*/layers_\d+/linear_attn/"
+LATENT = r"TransformerLM\)*/layers_\d+/latent_attn/(latent_attn\.\w+/)*"
 # program -> scope -> where it must appear (a regex on the whole path)
 EXPECTED = {
     "step": {
@@ -112,6 +113,43 @@ EXPECTED = {
         "gated_norm": LINEAR + r"gated_norm/",
         "kv_scatter": LAYER + r"kv_scatter/scatter",
     },
+    # a model of latent-attention layers with sandwich norms and a sigmoid
+    # router: the mixer's seven scopes under its Flax name, the cached
+    # attention under `cache_attention` (which the listed
+    # `decode_cache_attention_ms` reads), dense here
+    "latent_step": {
+        "q_down": LATENT + r"q_down/q_a_proj/dot_general",
+        "q_up": LATENT + r"q_up/q_b_proj/dot_general",
+        "kv_down": LATENT + r"kv_down/kv_a_proj/dot_general",
+        "absorb_q": LATENT + r"absorb_q/.*dot_general",
+        "absorb_out": LATENT + r"absorb_out/.*dot_general",
+        "rope": LATENT + r"rope/",
+        "kv_scatter": LATENT + r"kv_scatter/scatter",
+        "kv_gather": LATENT + r"cache_attention/kv_gather/",
+        "cache_attention": LATENT + r"cache_attention/.*dot_general",
+        "moe": MLP + r"moe/router/logistic",
+        "sample": r"^jit\(step\)/sample/",
+    },
+    "latent_prefill_chunk": {
+        "absorb_q": LATENT + r"absorb_q/.*dot_general",
+        "absorb_out": LATENT + r"absorb_out/.*dot_general",
+        "kv_scatter": LATENT + r"kv_scatter/scatter",
+        "cache_attention": LATENT + r"cache_attention/.*dot_general",
+        "moe": MLP + r"moe/experts/ragged_dot",
+    },
+    # the same model with a latent of 128 values: every layer calls its
+    # kernel's one jitted body under cache_attention (on the chip the custom
+    # call's path ends `.../cache_attention/jit(_latent_decode_device)/
+    # latent_decode_kernel/pallas_call`, which the kernels' rooflines read)
+    "latent_step_kernel": {
+        "cache_attention": LATENT + r"cache_attention/jit\(_latent_decode_device\)$",
+        "kernel_scope": r"^latent_decode_kernel/",
+        "kv_scatter": LATENT + r"kv_scatter/scatter",
+    },
+    "latent_prefill_chunk_kernel": {
+        "cache_attention": LATENT + r"cache_attention/jit\(_latent_chunk_device\)$",
+        "kernel_scope": r"^latent_chunk_kernel/",
+    },
     "ddp": {
         "rope": LAYER + r"rope/",
         "flash_attention": LAYER + r"flash_attention/",
@@ -180,6 +218,20 @@ def _hybrid_model(key_dim=8, value_dim=12):
         model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
 
 
+def _latent_model(rank=16):
+    from pytorch_distributed_example_tpu.models.transformer import LayerSpec
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64, max_seq_len=64,
+        use_flash=False, sandwich_norm=True, sparse_score="sigmoid", sparse_experts=4,
+        sparse_top_k=2, sparse_d_ff=16, shared_d_ff=16, routed_scale=2.5,
+        latent_q_rank=8, latent_kv_rank=rank, latent_nope_dim=8, latent_rope_dim=4,
+        latent_v_dim=8, layers=(LayerSpec("latent"), LayerSpec("latent", mlp="sparse")))
+    model = TransformerLM(cfg)
+    return model, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+
+
 def _loss(logits, y):
     return optax.softmax_cross_entropy_with_integer_labels(
         logits[:, :-1], y[:, 1:]).mean()
@@ -220,6 +272,14 @@ def serve_paths():
         hvars["params"], htree, lanes, lanes, rngs, (bt, state)))
     out["hybrid_prefill_chunk"] = _paths(hchunk.lower(
         hvars["params"], htree, jnp.zeros((1, 16), jnp.int32), (bt[:1], state[:1]), 0))
+    for suffix, rank in (("", 16), ("_kernel", 128)):
+        latent, lvars = _latent_model(rank)
+        lchunk, _, _, lstep = paged_programs(latent, 0.0, None)
+        ltree = init_paged_cache(latent, nblk, bs)
+        out["latent_step" + suffix] = _paths(lstep.lower(
+            lvars["params"], ltree, lanes, lanes, rngs, bt))
+        out["latent_prefill_chunk" + suffix] = _paths(lchunk.lower(
+            lvars["params"], ltree, jnp.zeros((1, 16), jnp.int32), bt[:1], 0))
     wide_hybrid, wvars = _hybrid_model(16, 64)
     out["hybrid_step_kernel"] = _paths(paged_programs(wide_hybrid, 0.0, None)[3].lower(
         wvars["params"], init_paged_cache(wide_hybrid, nblk, bs, state_blocks=S),
@@ -277,7 +337,8 @@ def train_paths(world):
 
 SERVE = ("step", "step_kernel", "prefill_chunk", "first_token", "pattern_step",
          "pattern_prefill_chunk", "pattern_step_kernel", "hybrid_step",
-         "hybrid_step_kernel", "hybrid_prefill_chunk")
+         "hybrid_step_kernel", "hybrid_prefill_chunk", "latent_step",
+         "latent_prefill_chunk", "latent_step_kernel", "latent_prefill_chunk_kernel")
 CASES = [(prog, scope) for prog, scopes_ in EXPECTED.items() for scope in scopes_]
 
 
@@ -327,14 +388,21 @@ def test_the_kernel_step_gathers_nothing(serve_paths):
 # the metric files' own patterns, against the same lowerings
 METRICS = Path(__file__).resolve().parents[1] / "bench_matrix" / "layer_metrics"
 READ_BY = {
-    "decode_cache_attention_ms": ["step", "step_kernel", "pattern_step", "hybrid_step"],
+    "decode_cache_attention_ms": ["step", "step_kernel", "pattern_step", "hybrid_step",
+                                  "latent_step", "latent_step_kernel"],
+    "decode_latent_attention_ms": ["latent_step", "latent_step_kernel"],
+    "prefill_latent_attention_ms": ["latent_prefill_chunk", "latent_prefill_chunk_kernel"],
+    "prefill_latent_cache_attention_ms": ["latent_prefill_chunk",
+                                          "latent_prefill_chunk_kernel"],
+    "latent_decode_roofline": ["latent_step_kernel"],
+    "latent_chunk_roofline": ["latent_prefill_chunk_kernel"],
     "decode_linear_attention_ms": ["hybrid_step", "hybrid_step_kernel"],
     "decode_recurrence_ms": ["hybrid_step", "hybrid_step_kernel"],
     "recurrence_decode_roofline": ["hybrid_step", "hybrid_step_kernel"],
     "prefill_linear_attention_ms": ["hybrid_prefill_chunk"],
     "prefill_chunk_scan_ms": ["hybrid_prefill_chunk"],
-    "decode_moe_ms": ["pattern_step"],
-    "prefill_moe_ms": ["pattern_prefill_chunk"],
+    "decode_moe_ms": ["pattern_step", "latent_step"],
+    "prefill_moe_ms": ["pattern_prefill_chunk", "latent_prefill_chunk"],
     "decode_window_attention_ms": ["pattern_step", "pattern_step_kernel"],
     "moe_decode_roofline": ["pattern_step"],
     "prefill_cache_attention_ms": ["prefill_chunk"],
