@@ -5,18 +5,26 @@ long-context obligation; used standalone, under Ulysses, and as the block
 kernel behind sequence parallelism). Design per the TPU kernel playbook
 (/opt/skills/guides/pallas_guide.md):
 
-* forward: one grid step per (batch·head, q-block); K/V stream through a
-  `fori_loop` of `block_k` slices held in VMEM; online-softmax accumulator
-  in fp32; logits never materialize in HBM (O(L) memory, not O(L²)).
-  The MXU sees (block_q, D) @ (D, block_k) matmuls with
-  `preferred_element_type=float32`.
+* forward (`flash_fwd`): one grid step per (batch·head, q-block); K/V
+  stream through a `fori_loop` of `block_k` slices held in VMEM;
+  online-softmax accumulator in fp32; logits never materialize in HBM
+  (O(L) memory, not O(L²)).
 * backward: flash-style recomputation — saves only (O, LSE) residuals;
-  one kernel produces dK/dV (grid over k-blocks, loop over q-blocks), a
-  second produces dQ (grid over q-blocks, loop over k-blocks). `delta =
-  rowsum(dO·O)` is a cheap jnp preprocess.
-* causal masking by global positions; diagonal blocks are masked
-  elementwise, blocks strictly above the diagonal are skipped by bounding
-  the k-loop (upper-triangular work never executes).
+  `delta = rowsum(dO·O)` is a cheap jnp preprocess. While q and dO fit
+  VMEM whole, ONE kernel (`flash_bwd`: grid over k-blocks, loop over
+  q-blocks) computes the scores, p and dlogits of a block pair once and
+  does all three products from them, dQ accumulating in an fp32 VMEM
+  scratch across the k-blocks: five MXU products a pair. Past that
+  (`_use_streaming`) two kernels (`flash_bwd_dkdv`, `flash_bwd_dq`) ride
+  the counterpart blocks on the grid and each recompute p: seven.
+* the MXU takes its operands in the dtype they have in memory (a product
+  of two bf16 values is exact in fp32, and Mosaic rounds an fp32 operand
+  to bf16 at default precision anyway: measured bit for bit on v5e,
+  PERF.md §6, PR 34); softmax statistics and accumulators are fp32.
+* causal masking by global positions; blocks strictly above the diagonal
+  are skipped by bounding the loop (upper-triangular work never
+  executes), and of the visited pairs only those the diagonal crosses
+  build and apply the mask.
 
 On non-TPU backends (the 8-device CPU test mesh) the kernels run in
 interpreter mode automatically — same code path, bitwise-comparable math.
@@ -54,6 +62,16 @@ def _compiler_params(pltpu):
     )
 
 
+def _vmem_limit(buffered: int, scratch: int, block_q: int, block_k: int) -> int:
+    """Scoped VMEM a resident kernel asks for: its operands' blocks as
+    Pallas double-buffers them, its scratch, and room for eight live
+    (block_q, block_k) fp32 tiles; never under the 16 MB a v5e kernel gets
+    unasked, which hold both kernels at the train cells' shape (L 4096,
+    Dh 128, bf16, blocks of 512) but not fp32 inputs, L 8192 or blocks of
+    1024."""
+    return max(16 << 20, 2 * buffered + scratch + 8 * block_q * block_k * 4)
+
+
 def _interpret_default() -> bool:
     """Compile where Mosaic can lower (a TPU backend); interpret elsewhere.
 
@@ -75,41 +93,80 @@ def _interpret_default() -> bool:
 # ---------------------------------------------------------------------------
 
 
+# a @ b, a @ b.T and a.T @ b as `lax.dot_general` dimension numbers
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _dot(a, b, dims=_NN):
+    """One MXU product into fp32. bf16 operands have no precision to ask
+    for (their products are exact in fp32), and Mosaic refuses the request
+    that an ambient `jax_default_matmul_precision="highest"` would make
+    of them; fp32 operands keep following it."""
+    precision = lax.Precision.DEFAULT if a.dtype == jnp.bfloat16 else None
+    return lax.dot_general(
+        a, b, dims, precision=precision, preferred_element_type=jnp.float32
+    )
+
+
+def _crossing_pairs(causal, rows, cols):
+    """How many of the block pairs a `rows`-long block visits along the
+    other axis (blocks of `cols`) the causal diagonal crosses: a static
+    count when one size divides the other (every row of the tuned table
+    and every caller in the tree), and those pairs alone take the mask.
+    None: every visited pair takes it."""
+    if not causal:
+        return 0
+    if rows % cols and cols % rows:
+        return None
+    return max(1, rows // cols)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k, seq_len):
     D = q_ref.shape[-1]
     i = pl.program_id(1)
     q_start = i * block_q
-    q = q_ref[0].astype(jnp.float32) * scale  # (block_q, D)
+    # scaled in fp32, handed to the MXU in the input's dtype
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)  # (block_q, D)
 
-    num_k = seq_len // block_k
-    if causal:
-        # last k-block that intersects the triangle for this q block
-        num_k_eff = (q_start + block_q - 1) // block_k + 1
-    else:
-        num_k_eff = num_k
-
-    def body(j, carry):
+    def pair(j, carry, masked):
         m, l, acc = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (bq, bk)
-        if causal:
+        cols = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
+        s = _dot(q, k, _NT)  # (bq, bk)
+        if masked:
             q_pos = q_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             k_pos = j * block_k + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         m_blk = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m, m_blk)
-        m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+        # a row with nothing visible yet (only under the mask) keeps exp finite
+        m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new) if masked else m_new
         p = jnp.exp(s - m_safe[:, None])
         alpha = jnp.exp(m - m_new)  # finite: both -1e30 → exp(0)=1, acc is 0
         l = l * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[:, None] + jnp.dot(p, v, preferred_element_type=jnp.float32)
+        acc = acc * alpha[:, None] + _dot(p.astype(v.dtype), v)
         return m_new, l, acc
 
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, D), jnp.float32)
-    m, l, acc = lax.fori_loop(0, num_k_eff, body, (m0, l0, acc0))
+    carry = (
+        jnp.full((block_q,), NEG_INF, jnp.float32),
+        jnp.zeros((block_q,), jnp.float32),
+        jnp.zeros((block_q, D), jnp.float32),
+    )
+    under = functools.partial(pair, masked=False)
+    crossing = functools.partial(pair, masked=True)
+    # k-blocks that intersect the triangle for this q block
+    num_k = (q_start + block_q - 1) // block_k + 1 if causal else seq_len // block_k
+    n_cross = _crossing_pairs(causal, block_q, block_k)
+    if n_cross is None:
+        carry = lax.fori_loop(0, num_k, crossing, carry)
+    else:
+        carry = lax.fori_loop(0, num_k - n_cross, under, carry)
+        for t in range(n_cross):  # unrolled: a second loop costs more than the mask
+            carry = crossing(num_k - n_cross + t, carry)
+    m, l, acc = carry
 
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
@@ -124,8 +181,11 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret,
     dtype (default q.dtype): the ring-attention combine requests f32 so
     per-shard partials come straight from the kernel's f32 accumulator
     instead of a bf16-rounded output (ADVICE r5 #2)."""
+    from jax.experimental.pallas import tpu as pltpu
+
     BH, L, D = q.shape
-    if _use_streaming(L, D, q.dtype.itemsize):
+    itemsize = q.dtype.itemsize
+    if _use_streaming(L, D, itemsize):
         return _fwd_streamed(q, k, v, scale, causal, block_q, block_k,
                              interpret, out_dtype)
     grid = (BH, L // block_q)
@@ -154,6 +214,15 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((BH, L, D), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((BH, L, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL),
+            # k and v whole; q, o and lse (a value a 128-lane row) by block
+            vmem_limit_bytes=_vmem_limit(
+                2 * L * D * itemsize + block_q * (D * 2 * itemsize + 512), 0,
+                block_q, block_k,
+            ),
+        ),
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return o, lse
@@ -161,9 +230,10 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret,
 
 # ---------------------------------------------------------------------------
 # streamed variants: k/v blocks ride the GRID instead of sitting whole in
-# VMEM. The resident kernels above hold the full counterpart operand in
-# VMEM (k/v for fwd/dq, q/do for dkdv), which is fastest while it fits but
-# exceeds the ~16 MB scoped-VMEM limit near L·D ≈ 1.5M elements (measured:
+# VMEM. The resident kernels hold the full counterpart operand in VMEM
+# (k/v for the forward above, q/do and dQ's accumulator for the one-pass
+# backward below), which is fastest while it fits but exceeds the ~16 MB
+# scoped-VMEM limit near L·D ≈ 1.5M elements (measured on the forward:
 # L=16384, D=128 OOMs at 16.75M needed). Past `_stream_threshold` the
 # pallas grid gains a third dimension over counterpart blocks; the online
 # accumulators live in VMEM scratch that persists across the innermost
@@ -292,6 +362,7 @@ def _fwd_streamed(q, k, v, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         compiler_params=_compiler_params(pltpu),
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return o, lse
@@ -302,77 +373,69 @@ def _fwd_streamed(q, k, v, scale, causal, block_q, block_k, interpret,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dkdv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    *, scale, causal, block_q, block_k, seq_len
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_acc, *, scale, causal, block_q, block_k, seq_len
 ):
+    """dQ, dK and dV in one pass over the block pairs of key block `j`.
+
+    Scores are held TRANSPOSED, (block_k, block_q): lse and delta are then
+    rows, (1, block_q), that broadcast down the sublanes (as columns they
+    tile to 128 lanes a value: 2 MB each at L 4096), dV and dK are plain
+    products and only dQ's takes a transposed operand. dQ accumulates in
+    `dq_acc`, (L, D) fp32, across the key blocks (the grid's ARBITRARY
+    axis) in the order the two-kernel form adds them, so the three
+    gradients are that form's, bit for bit (v5e, PERF.md §6, PR 34)."""
     D = q_ref.shape[-1]
     j = pl.program_id(1)
     k_start = j * block_k
-    k = k_ref[0].astype(jnp.float32)  # (block_k, D)
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0]  # (block_k, D)
+    v = v_ref[0]
 
-    num_q = seq_len // block_q
-    if causal:
-        first_q = k_start // block_q  # first q-block intersecting the triangle
-    else:
-        first_q = 0
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def body(i, carry):
+    def pair(i, carry, masked):
         dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * block_q, block_q), 0]
-        delta = delta_ref[0, pl.ds(i * block_q, block_q), 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = i * block_q + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])  # (bq, bk); masked → exp(NEG_INF-lse)=0
-        dv = dv + jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        dlogits = p * (dp - delta[:, None])
-        dk = dk + jnp.dot(dlogits.T, q, preferred_element_type=jnp.float32) * scale
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, :, rows]  # (1, block_q)
+        delta = delta_ref[0, :, rows]
+        st = _dot(k, q, _NT) * scale
+        if masked:
+            k_pos = k_start + lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+            q_pos = i * block_q + lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
+            st = jnp.where(q_pos >= k_pos, st, NEG_INF)
+        pt = jnp.exp(st - lse)  # (bk, bq); masked → exp(NEG_INF-lse)=0
+        dpt = _dot(v, do, _NT)
+        dst = (pt * (dpt - delta)).astype(q.dtype)  # dlogits, transposed
+        dv = dv + _dot(pt.astype(do.dtype), do)
+        dk = dk + _dot(dst, q) * scale
+        dq_acc[rows, :] += _dot(dst, k, _TN) * scale
         return dk, dv
 
-    dk0 = jnp.zeros((block_k, D), jnp.float32)
-    dv0 = jnp.zeros((block_k, D), jnp.float32)
-    dk, dv = lax.fori_loop(first_q, num_q, body, (dk0, dv0))
+    carry = (jnp.zeros((block_k, D), jnp.float32), jnp.zeros((block_k, D), jnp.float32))
+    under = functools.partial(pair, masked=False)
+    crossing = functools.partial(pair, masked=True)
+    num_q = seq_len // block_q
+    # first q-block intersecting the triangle
+    first_q = k_start // block_q if causal else 0
+    n_cross = _crossing_pairs(causal, block_k, block_q)
+    if n_cross is None:
+        carry = lax.fori_loop(first_q, num_q, crossing, carry)
+    else:
+        for t in range(n_cross):
+            carry = crossing(first_q + t, carry)
+        carry = lax.fori_loop(first_q + n_cross, num_q, under, carry)
+    dk, dv = carry
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
-
-def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-    *, scale, causal, block_q, block_k, seq_len
-):
-    D = q_ref.shape[-1]
-    i = pl.program_id(1)
-    q_start = i * block_q
-    q = q_ref[0].astype(jnp.float32)  # (block_q, D)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, :, 0]
-    delta = delta_ref[0, :, 0]
-
-    num_k = seq_len // block_k
-    num_k_eff = (q_start + block_q - 1) // block_k + 1 if causal else num_k
-
-    def body(j, dq):
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = q_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = j * block_k + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        dlogits = p * (dp - delta[:, None])
-        return dq + jnp.dot(dlogits, k, preferred_element_type=jnp.float32) * scale
-
-    dq = lax.fori_loop(0, num_k_eff, body, jnp.zeros((block_q, D), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkdv_kernel_streamed(
@@ -475,125 +538,101 @@ def _bwd_dq_kernel_streamed(
         dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
 
 
-def _dkdv_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
+def _bwd_calls(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
                interpret):
-    """dK/dV for one (q-set, kv-set) pair given PRECOMPUTED lse/delta.
+    """(dQ, dK, dV) for one (q-set, kv-set) pair given PRECOMPUTED
+    lse/delta, both (BH, L, 1) fp32.
 
-    Chooses the resident or streamed lowering by operand size. Exposed
-    (delta-taking) so the ring backward can reuse it per kv shard with
-    the ring's FINAL lse/delta."""
+    The form follows the operand size as `_fwd`'s does: one kernel while
+    q and dO (and dQ's fp32 accumulator) sit whole in VMEM, the two
+    streamed kernels past that. Delta-taking so that the ring backward
+    reuses it per kv shard with the ring's FINAL lse/delta."""
+    from jax.experimental.pallas import tpu as pltpu
+
     BH, L, D = q.shape
-    if _use_streaming(L, D, q.dtype.itemsize):
-        from jax.experimental.pallas import tpu as pltpu
-
-        sem = _compiler_params(pltpu)
+    itemsize = q.dtype.itemsize
+    if not _use_streaming(L, D, itemsize):
+        whole = pl.BlockSpec((1, L, D), lambda b, j: (b, 0, 0))
+        block = pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0))
+        row = pl.BlockSpec((1, 1, L), lambda b, j: (b, 0, 0))
         return pl.pallas_call(
             functools.partial(
-                _bwd_dkdv_kernel_streamed,
-                scale=scale, causal=causal, block_q=block_q,
-                block_k=block_k,
+                _bwd_kernel,
+                scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+                seq_len=L,
             ),
-            grid=(BH, L // block_k, L // block_q),
-            in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((BH, L, D), q.dtype),
-                jax.ShapeDtypeStruct((BH, L, D), q.dtype),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
-            ],
-            compiler_params=sem,
+            grid=(BH, L // block_k),
+            in_specs=[whole, block, block, whole, row, row],
+            out_specs=[whole, block, block],
+            out_shape=[jax.ShapeDtypeStruct((BH, L, D), q.dtype)] * 3,
+            scratch_shapes=[pltpu.VMEM((L, D), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY),
+                # q, dO and dQ whole, four (block_k, D) blocks, lse and
+                # delta as 8-sublane rows; dQ's accumulator
+                vmem_limit_bytes=_vmem_limit(
+                    (3 * L + 4 * block_k) * D * itemsize + 2 * 8 * L * 4,
+                    L * D * 4, block_q, block_k,
+                ),
+            ),
+            name="flash_bwd",
             interpret=interpret,
-        )(q, k, v, do, lse, delta)
-    return pl.pallas_call(
+        )(q, k, v, do, lse.reshape(BH, 1, L), delta.reshape(BH, 1, L))
+
+    sem = _compiler_params(pltpu)
+    q_blk = lambda b, j, i: (b, i, 0)  # dK/dV: grid (BH, k blocks, q blocks)
+    k_blk = lambda b, j, i: (b, j, 0)
+    dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dkdv_kernel,
+            _bwd_dkdv_kernel_streamed,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-            seq_len=L,
         ),
-        grid=(BH, L // block_k),
+        grid=(BH, L // block_k, L // block_q),
         in_specs=[
-            pl.BlockSpec((1, L, D), lambda b, j: (b, 0, 0)),        # q (full)
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),  # k block
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),  # v block
-            pl.BlockSpec((1, L, D), lambda b, j: (b, 0, 0)),        # do (full)
-            pl.BlockSpec((1, L, 1), lambda b, j: (b, 0, 0)),        # lse (full)
-            pl.BlockSpec((1, L, 1), lambda b, j: (b, 0, 0)),        # delta
+            pl.BlockSpec((1, block_q, D), q_blk),
+            pl.BlockSpec((1, block_k, D), k_blk),
+            pl.BlockSpec((1, block_k, D), k_blk),
+            pl.BlockSpec((1, block_q, D), q_blk),
+            pl.BlockSpec((1, block_q, 1), q_blk),
+            pl.BlockSpec((1, block_q, 1), q_blk),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), k_blk),
+            pl.BlockSpec((1, block_k, D), k_blk),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, L, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, L, D), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((BH, L, D), q.dtype)] * 2,
+        scratch_shapes=[
+            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, D), jnp.float32),
         ],
+        compiler_params=sem,
+        name="flash_bwd_dkdv",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
-
-
-def _dq_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
-             interpret):
-    """dQ for one (q-set, kv-set) pair given PRECOMPUTED lse/delta."""
-    BH, L, D = q.shape
-    if _use_streaming(L, D, q.dtype.itemsize):
-        from jax.experimental.pallas import tpu as pltpu
-
-        sem = _compiler_params(pltpu)
-        return pl.pallas_call(
-            functools.partial(
-                _bwd_dq_kernel_streamed,
-                scale=scale, causal=causal, block_q=block_q,
-                block_k=block_k,
-            ),
-            grid=(BH, L // block_q, L // block_k),
-            in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, block_q, D), lambda b, i, j: (b, i, 0)
-            ),
-            out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-            compiler_params=sem,
-            interpret=interpret,
-        )(q, k, v, do, lse, delta)
-    return pl.pallas_call(
+    q_blk = lambda b, i, j: (b, i, 0)  # dQ: grid (BH, q blocks, k blocks)
+    k_blk = lambda b, i, j: (b, j, 0)
+    dq = pl.pallas_call(
         functools.partial(
-            _bwd_dq_kernel,
+            _bwd_dq_kernel_streamed,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-            seq_len=L,
         ),
-        grid=(BH, L // block_q),
+        grid=(BH, L // block_q, L // block_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),  # q block
-            pl.BlockSpec((1, L, D), lambda b, i: (b, 0, 0)),        # k (full)
-            pl.BlockSpec((1, L, D), lambda b, i: (b, 0, 0)),        # v (full)
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),  # do block
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),  # lse
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),  # delta
+            pl.BlockSpec((1, block_q, D), q_blk),
+            pl.BlockSpec((1, block_k, D), k_blk),
+            pl.BlockSpec((1, block_k, D), k_blk),
+            pl.BlockSpec((1, block_q, D), q_blk),
+            pl.BlockSpec((1, block_q, 1), q_blk),
+            pl.BlockSpec((1, block_q, 1), q_blk),
         ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, block_q, D), q_blk),
         out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        compiler_params=sem,
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
+    return dq, dk, dv
 
 
 def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k, interpret,
@@ -608,11 +647,8 @@ def _bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k, interpret,
         # generalizes to p*(dp - delta + dlse_row), since
         # d(lse)/d(logits) = softmax(logits) = p
         delta = delta - dlse.astype(jnp.float32)
-    dk, dv = _dkdv_call(q, k, v, do, lse, delta, scale, causal, block_q,
-                        block_k, interpret)
-    dq = _dq_call(q, k, v, do, lse, delta, scale, causal, block_q,
-                  block_k, interpret)
-    return dq, dk, dv
+    return _bwd_calls(q, k, v, do, lse, delta, scale, causal, block_q,
+                      block_k, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -648,7 +684,7 @@ def flash_with_lse(q, k, v, scale, causal, block_q, block_k, interpret):
     cotangent must reach the kernels: since d(lse)/d(logits) =
     softmax(logits) = p, it folds into the existing backward as
     `delta -> delta - dlse` (dlogits = p*(dp - delta + dlse_row)), so
-    the same three bwd kernels serve both VJPs. Shapes as `_fwd`:
+    the same bwd kernels serve both VJPs. Shapes as `_fwd`:
     (BH, L, D) in, ((BH, L, D), (BH, L, 1)) out.
 
     The backward's residuals `o` and `lse` carry names (`utils.remat`):
@@ -685,13 +721,12 @@ flash_with_lse.defvjp(_fwl_fwd, _fwl_bwd)
 @functools.lru_cache(maxsize=1)
 def _tuned_table() -> dict:
     """Checked-in block-size table (`flash_tuned.json`): per-geometry
-    winners of a sweep on an earlier machine, whose generator is no
-    longer in the tree. Keys are "L{seq}" plus "default"; there is no
-    row for seq 4096, the only length a benchmark cell runs, so the
-    train cells take "default" (ROADMAP S8 re-derives block sizes
-    against `flash_roofline` / `flash_time_pct`). The file is tracked,
-    so one that is missing or does not parse is a broken checkout and
-    raises."""
+    winners of chip sweeps. Keys are "L{seq}" plus "default"; the L4096
+    row, the one length a benchmark cell runs, is PR 34's sweep of these
+    kernels on a v5e (kernel ms a call in the row), the others come from
+    an earlier machine and kernels, by a generator no longer in the tree.
+    The file is tracked, so one that is missing or does not parse is a
+    broken checkout and raises."""
     import json
     import os
 

@@ -273,7 +273,7 @@ def _ring_core_bwd(axis_name, causal, scale, bq, bk, interpret, res, do):
     import jax.numpy as jnp
     from jax import lax
 
-    from ..ops.flash_attention import _dkdv_call, _dq_call
+    from ..ops.flash_attention import _bwd_calls
 
     q, k, v, o, lse = res
     W = _axis_size(axis_name)
@@ -288,12 +288,8 @@ def _ring_core_bwd(axis_name, causal, scale, bq, bk, interpret, res, do):
     def grads_for(k_cur, v_cur, src):
         def mk(causal_flag):
             def run(_):
-                dq_p = _dq_call(q, k_cur, v_cur, do, lse, delta, scale,
-                                causal_flag, bq, bk, interpret)
-                dk_p, dv_p = _dkdv_call(q, k_cur, v_cur, do, lse, delta,
-                                        scale, causal_flag, bq, bk,
-                                        interpret)
-                return dq_p, dk_p, dv_p
+                return _bwd_calls(q, k_cur, v_cur, do, lse, delta, scale,
+                                  causal_flag, bq, bk, interpret)
             return run
 
         def skip(_):

@@ -8,6 +8,8 @@ compiles succeed, the analyses expose the fields the benches read) so
 a JAX upgrade can't silently rot the evidence path.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -70,7 +72,7 @@ def test_sharded_mesh_compile_memory_analysis(topo):
     assert int(ma.argument_size_in_bytes) == 512 * 512 * 2 // 2
 
 
-@pytest.mark.parametrize("rung,kernel_calls", [(0, 4), (3, 3)])
+@pytest.mark.parametrize("rung,kernel_calls", [(0, 3), (3, 2)])
 def test_fully_shard_lm_step_with_flash_compiles_under_mesh(
     topo, monkeypatch, rung, kernel_calls
 ):
@@ -85,13 +87,11 @@ def test_fully_shard_lm_step_with_flash_compiles_under_mesh(
     A described device cannot be asked for its memory limit
     (`utils.remat.device_limit_bytes` is None, nothing steered here), so
     the step is the plain program at rung 0 of the remat ladder: the
-    layer's forward kernel runs twice (forward, recomputed, dK/dV, dQ).
+    layer's forward kernel runs twice (forward, recomputed, the one backward).
     With a limit handed in, the fit runs as on a chip (lower, compile for
     v5e, the compiler's count), takes the top rung, and the names inside
     the kernel's forward rule hold through the `shard_map`: the compiled
     step runs it once."""
-    import re
-
     import jax
     import jax.numpy as jnp
     import optax
@@ -146,6 +146,9 @@ def test_fully_shard_lm_step_with_flash_compiles_under_mesh(
 
     calls = _custom_calls(hlo)
     assert len(calls) == kernel_calls
+    # under the shard_map too the calls are named after their kernels
+    names = _call_names(calls)
+    assert names == ["flash_bwd"] + ["flash_fwd"] * (kernel_calls - 1), names
     plan = step.remat_plan
     if rung == 0:
         assert plan is None
@@ -165,6 +168,47 @@ def _custom_calls(hlo):
         line for line in hlo.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line
     ]
+
+
+def _call_names(calls):
+    """The instructions' names without their numbers, sorted."""
+    return sorted(re.search(r"%(\w+?)(\.\d+)? = ", line).group(1) for line in calls)
+
+
+@pytest.mark.parametrize("BH", [64, 32])
+def test_flash_kernels_compile_at_the_train_cells_shape(topo, monkeypatch, BH):
+    """Forward and the one-pass backward at what the train cells run (L 4096,
+    Dh 128, bf16, causal; 64 rows of (batch, head) a chip in `lm_train_1chip`,
+    32 in `lm_fsdp_4chip`) with the tuned table's blocks: Mosaic takes them
+    inside the scoped VMEM the call asks for, q and dO whole beside dQ's fp32
+    accumulator, and the custom calls carry the kernels' names."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_example_tpu.ops.flash_attention import (
+        _use_streaming, flash_with_lse, resolved_block_sizes,
+    )
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")
+    L, D = 4096, 128
+    bq, bk = resolved_block_sizes(L)
+    assert not _use_streaming(L, D, 2)
+    x = jax.ShapeDtypeStruct(
+        (BH, L, D), jnp.bfloat16, sharding=SingleDeviceSharding(topo.devices[0])
+    )
+
+    def loss(q, k, v):
+        o, lse = flash_with_lse(q, k, v, D ** -0.5, True, bq, bk, False)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
+    calls = _custom_calls(hlo)
+    assert len(calls) == 2
+    # the instruction takes the innermost scope: the kernel's name, here
+    # inside `jvp(...)` and its transpose (in a train step: `flash_fwd.3`)
+    names = _call_names(calls)
+    assert len(names) == 2 and "flash_fwd" in names[0] and "flash_bwd" in names[1], names
 
 
 def test_paged_decode_kernel_compiles_at_mistral_widths(topo, monkeypatch):

@@ -25,12 +25,16 @@ def _dense(q, k, v, causal, scale=None):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _rand_qkv(seed, B=2, L=256, H=2, D=32):
+def _rand_qkv(seed, B=2, L=256, H=2, D=32, dtype="float32"):
     import jax.numpy as jnp
 
     gen = np.random.default_rng(seed)
-    mk = lambda: jnp.asarray(gen.standard_normal((B, L, H, D)), jnp.float32)
+    mk = lambda: jnp.asarray(gen.standard_normal((B, L, H, D)), dtype)
     return mk(), mk(), mk()
+
+
+def _f32(x):
+    return np.asarray(x, dtype=np.float32)
 
 
 class TestFlashForward:
@@ -48,6 +52,24 @@ class TestFlashForward:
         want = _dense(q, k, v, True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("bq,bk", [(64, 64), (128, 64), (64, 128)])
+    def test_mask_is_exact_where_the_diagonal_crosses(self, bq, bk):
+        """Only the block pairs the diagonal crosses take the mask, and the
+        split must not move a value: row 0 attends itself alone, so its
+        output IS v[0]; a block pair wholly over the diagonal is never
+        visited, so NaNs in the last key block reach no earlier row (a
+        visited pair would add 0 * NaN)."""
+        import jax.numpy as jnp
+
+        q, k, v = _rand_qkv(21, B=1, L=256)
+        poison = jnp.arange(256)[None, :, None, None] >= 256 - bk
+        k, v = jnp.where(poison, jnp.nan, k), jnp.where(poison, jnp.nan, v)
+        got = np.asarray(flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk))
+        np.testing.assert_array_equal(got[:, 0], np.asarray(v)[:, 0])
+        clean = 256 - max(bq, bk)  # the q blocks that end before the NaNs
+        want = _dense(q[:, :clean], k[:, :clean], v[:, :clean], True)
+        np.testing.assert_allclose(got[:, :clean], np.asarray(want), rtol=2e-4, atol=2e-4)
+
     def test_small_seq_clamps_blocks(self):
         q, k, v = _rand_qkv(2, L=32)
         got = flash_attention(q, k, v, causal=False)
@@ -62,29 +84,98 @@ class TestFlashForward:
             flash_attention(q, q, q, block_q=64, block_k=64)
 
 
+def _tol(dtype):
+    return dict(rtol=5e-4, atol=5e-4) if dtype == "float32" else dict(rtol=5e-2, atol=5e-2)
+
+
 class TestFlashBackward:
+    """The resident regime's ONE backward kernel (scores, p and dlogits of a
+    block pair computed once, dQ in a VMEM accumulator across key blocks)
+    against the dense reference and against the two streamed kernels."""
+
+    # L = 4 x 64: pairs wholly under, on and over the diagonal all occur
+    @pytest.mark.parametrize("dtype,L", [("float32", 128), ("float32", 256), ("bfloat16", 256)])
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("bq,bk", [(64, 64), (64, 32), (32, 64)])
-    def test_grads_match_dense(self, causal, bq, bk):
+    def test_grads_match_dense(self, causal, bq, bk, dtype, L):
         import jax
 
-        q, k, v = _rand_qkv(3, B=1, L=128, H=2, D=16)
+        q, k, v = _rand_qkv(3, B=1, L=L, H=2, D=16, dtype=dtype)
 
         def loss_flash(q, k, v):
             o = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
-            return (o * o).sum()
+            return (o.astype("float32") ** 2).sum()
 
         def loss_dense(q, k, v):
-            o = _dense(q, k, v, causal)
+            o = _dense(*(x.astype("float32") for x in (q, k, v)), causal)
             return (o * o).sum()
 
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
         gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
         for a, b, name in zip(gf, gd, "qkv"):
+            assert a.dtype == q.dtype
             np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4,
-                err_msg=f"d{name} mismatch",
+                _f32(a), _f32(b), **_tol(dtype), err_msg=f"d{name} mismatch",
             )
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("bq,bk", [(64, 64), (128, 64), (64, 128), (96, 64)])
+    def test_one_pass_matches_the_two_kernel_form(self, causal, bq, bk, dtype, monkeypatch):
+        """Same residuals, same delta (with a dlse term in it), both
+        lowerings of `_bwd`: the forms differ in where dQ is summed, not in
+        what is summed. (96, 64): neither block size divides the other, so
+        every visited pair takes the mask."""
+        import jax.numpy as jnp
+
+        from pytorch_distributed_example_tpu.ops.flash_attention import (
+            _bwd, _fwd, _to_bh as _bh,
+        )
+
+        L = 384 if bq == 96 else 256
+        q, k, v = (_bh(x) for x in _rand_qkv(17, B=1, L=L, H=2, D=32, dtype=dtype))
+        do, dlse, _ = (_bh(x) for x in _rand_qkv(18, B=1, L=L, H=2, D=32, dtype=dtype))
+        dlse = dlse[..., :1].astype(jnp.float32)
+        scale = 32 ** -0.5
+        o, lse = _fwd(q, k, v, scale, causal, bq, bk, True)
+        forms = {}
+        for stream in ("0", "1"):
+            monkeypatch.setenv("TDX_FLASH_STREAM", stream)
+            forms[stream] = _bwd(q, k, v, o, lse, do, scale, causal, bq, bk, True, dlse=dlse)
+        tol = dict(rtol=2e-5, atol=2e-5) if dtype == "float32" else _tol(dtype)
+        for one, two, name in zip(forms["0"], forms["1"], "qkv"):
+            assert one.dtype == two.dtype == q.dtype
+            np.testing.assert_allclose(_f32(one), _f32(two), **tol, err_msg=f"d{name}")
+
+    @pytest.mark.parametrize("poisoned", ["last_keys", "first_queries"])
+    def test_no_gradient_crosses_a_pair_over_the_diagonal(self, poisoned):
+        """The backward's loop starts at the first q block that sees the key
+        block. NaNs in the last key block reach dQ of no earlier row, NaNs
+        in the first q block reach dK, dV of no later key block (a visited
+        pair would add 0 * NaN or exp(NaN)); row 0 attends itself alone, so
+        its dQ is 0."""
+        import jax
+        import jax.numpy as jnp
+
+        q, k, v = _rand_qkv(23, B=1, L=256, H=1, D=16)
+        pos = jnp.arange(256)[None, :, None, None]
+        if poisoned == "last_keys":
+            k, v = jnp.where(pos >= 192, jnp.nan, k), jnp.where(pos >= 192, jnp.nan, v)
+        else:
+            q = jnp.where(pos < 64, jnp.nan, q)
+
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+            return (o ** 2).sum()
+
+        dq, dk, dv = (np.asarray(g) for g in jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+        if poisoned == "last_keys":
+            assert np.isfinite(dq[:, :192]).all() and not np.isfinite(dq[:, 192:]).any()
+            np.testing.assert_array_equal(dq[:, 0], 0.0)
+        else:
+            assert np.isfinite(dq[:, 64:]).all()
+            assert np.isfinite(dk[:, 64:]).all() and np.isfinite(dv[:, 64:]).all()
+            assert not np.isfinite(dk[:, :64]).any()
 
 
 class TestFwdOutDtype:
@@ -164,8 +255,9 @@ class TestFlashStreamed:
                 err_msg=f"d{name} mismatch (streamed)",
             )
 
+    @pytest.mark.parametrize("bq,bk", [(128, 128), (64, 128), (128, 64)])
     @pytest.mark.parametrize("stream", [False, True])
-    def test_flash_with_lse_dlse_gradient(self, stream, monkeypatch):
+    def test_flash_with_lse_dlse_gradient(self, stream, bq, bk, monkeypatch):
         """`flash_with_lse`'s VJP propagates the LSE cotangent (folded
         into the bwd kernels as `delta - dlse`) — pinned directly, both
         lowerings, against a dense (o, logsumexp) reference whose loss
@@ -185,7 +277,7 @@ class TestFlashStreamed:
             o, lse = flash_with_lse(q.transpose(0, 2, 1, 3).reshape(2, 256, 64),
                                     k.transpose(0, 2, 1, 3).reshape(2, 256, 64),
                                     v.transpose(0, 2, 1, 3).reshape(2, 256, 64),
-                                    scale, True, 128, 128, True)
+                                    scale, True, bq, bk, True)
             return (o.astype(jnp.float32) ** 2).sum() + (lse ** 2).sum()
 
         def loss_dense(q, k, v):

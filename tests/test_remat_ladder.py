@@ -287,7 +287,7 @@ def _paths(lowered):
 
 
 def _kernel_calls(jaxpr):
-    """How often each Pallas kernel is called, by the kernel function's name."""
+    """How often each Pallas kernel is called, by the `name` its call gives it."""
     out = {}
     for eqn in _eqns(jaxpr):
         if eqn.primitive.name == "pallas_call":
@@ -324,10 +324,11 @@ def test_the_top_rung_runs_the_kernel_and_the_mlp_products_once_a_layer(
     layers = cfg.n_layers
     kernels0, products0, projections0 = found[0]
     kernels3, products3, projections3 = found[TOP]
-    # forward, recomputed forward, dK/dV and dQ a layer; then one forward
-    assert sum(kernels0.values()) == 4 * layers
-    assert sum(kernels3.values()) == 3 * layers
-    assert (kernels0["_fwd_kernel"], kernels3["_fwd_kernel"]) == (2 * layers, layers)
+    # forward, recomputed forward and the one backward a layer; then one forward
+    assert sum(kernels0.values()) == 3 * layers
+    assert sum(kernels3.values()) == 2 * layers
+    assert (kernels0["flash_fwd"], kernels3["flash_fwd"]) == (2 * layers, layers)
+    assert kernels0["flash_bwd"] == kernels3["flash_bwd"] == layers
     assert products0 == {(str(i), p) for i in range(layers) for p in ("gate", "up")}
     assert projections0 == {str(i) for i in range(layers)}
     assert products3 == set() and projections3 == set()

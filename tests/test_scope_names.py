@@ -150,9 +150,14 @@ EXPECTED = {
         "cache_attention": LATENT + r"cache_attention/jit\(_latent_chunk_device\)$",
         "kernel_scope": r"^latent_chunk_kernel/",
     },
+    # the kernels' calls carry their names (`name=` on the `pallas_call`), so
+    # the trace says which form of the backward a step ran: in the resident
+    # regime ONE call a layer (the backward's path goes through `checkpoint`)
     "ddp": {
         "rope": LAYER + r"rope/",
         "flash_attention": LAYER + r"flash_attention/",
+        "flash_fwd": LAYER + r"flash_attention/flash_fwd/pallas_call$",
+        "flash_bwd": r"/layers_\d+/attn/(attn\.\w+/)*flash_attention/flash_bwd/pallas_call$",
         "loss": r"(^|/)jvp\(loss\)/",
         "grad_reduce": r"(^|/)grad_reduce/(reduce_scatter|all_gather)",
         "optimizer": r"(^|/)optimizer/",
@@ -369,6 +374,17 @@ def test_the_decode_step_scans_nothing_and_a_chunk_runs_no_recurrence(serve_path
     calls = [p for p in kernel if p.endswith("recurrence/jit(_call)")]
     assert len(calls) == 3  # one a linear layer, each under its own layer's scope
     assert not [p for p in step["paths"] if "paged_delta_step" in p]
+
+
+def test_the_train_step_runs_one_backward_kernel_a_layer(train_paths):
+    """Both flash kernels sit under every layer's `flash_attention` scope,
+    and nothing of the streamed regime's two-kernel backward is traced."""
+    paths = train_paths["ddp"]["paths"]
+    for kernel in ("flash_fwd", "flash_bwd"):
+        calls = {re.search(r"layers_\d+", p).group() for p in paths
+                 if p.endswith(f"flash_attention/{kernel}/pallas_call")}
+        assert calls == {"layers_0", "layers_1"}, (kernel, calls)
+    assert not [p for p in paths if "flash_bwd_dkdv" in p or "flash_bwd_dq" in p]
 
 
 def test_the_kernel_step_gathers_nothing(serve_paths):
