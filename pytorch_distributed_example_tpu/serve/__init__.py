@@ -10,7 +10,12 @@ preemption and optional tensor-parallel placement over a device mesh
 bucketed prefill shapes (`bucketing.py`), and a metrics block — cache-
 pool utilization included — exposed over the debug HTTP frontend
 (`metrics.py`). The serve cells of `bench_matrix/` measure it on the
-chip.
+chip. What a `step()` call did is `engine.last_step` (a `StepRecord`:
+what it admitted, dispatched, read back and retired, and the host
+seconds of each of its phases), written onto the host line of a
+profiler trace as `serve:*` annotations and reduced on `/serve` to a
+`host` block (`window.host`: ms a phase a call, `wait_share`, the
+longest call with its split).
 
 Three kinds of state, one manager (`cache.py::PagedKVCache`; the programs
 take one block table a kind the model has, `cfg.cache_kinds`): FULL K/V
@@ -76,7 +81,7 @@ from .autoscale import (  # noqa: F401
     Autoscaler,
     Decision,
 )
-from .engine import ServeEngine  # noqa: F401
+from .engine import PHASES, ServeEngine, StepRecord  # noqa: F401
 from .metrics import ServeMetrics, percentile  # noqa: F401
 from .prefix import PrefixIndex, prefix_scope  # noqa: F401
 from .router import ScaleEvent, ServeRouter  # noqa: F401

@@ -73,10 +73,11 @@ blocks behind `position - window` are recycled while it runs. A model
 with SPARSE (dropless MoE) layers has its parked lanes and chunk padding
 route nowhere, and every decode step brings back, in its one readback,
 the assignments computed and the distinct experts hit per sparse layer
-(`ServeMetrics.record_moe_step`; also a `serve:moe_step` host annotation
-while a profiler trace is on, written when the step is read back). What is not carried with window layers is
-refused at construction: prefix sharing, the int8 pool, a tp mesh,
-disaggregated roles and pre-warmed executables. A model with LINEAR
+(`ServeMetrics.record_moe_step`, `StepRecord.moe` and a `serve:moe_step`
+host annotation, all written when the step is read back). What is not
+carried with window layers is refused at construction: prefix sharing,
+the int8 pool, a tp mesh, disaggregated roles and pre-warmed
+executables. A model with LINEAR
 (recurrent-state) layers holds one state block a request beside its K/V
 blocks from admission to retirement (`serve/cache.py`): its chunk
 padding is told apart from tokens and leaves the state as the last real
@@ -87,10 +88,28 @@ A model with LATENT layers (multi-head latent attention) keeps one row
 of `latent_width` values a token in one pool a layer, under the full
 kind's tables, free lists and refcounts; its cached calls run absorbed
 (`models/transformer.py::LatentAttention`), and the same five things are
-refused. Every decode step and every prefill chunk is dispatched under a
-host annotation (`serve:decode_step`: rows, keys attended;
-`serve:prefill_chunk`: slot, start, tokens, bucket) that a profiler
-trace keeps and nothing in the engine reads.
+refused.
+
+What a call did (`StepRecord`, public as `engine.last_step` when the
+call returns): `step()` fills one small record as it goes, from values
+it computes anyway — what it admitted, the chunks and the decode step
+it dispatched, what it read back, booked and retired, and the host
+seconds of each phase by the engine's clock. The same record is written
+onto the host line of a profiler trace, as `jax.profiler.
+TraceAnnotation`s that share the device trace's clock and cost nothing
+without one: a `serve:step` span a call with the phases nested inside
+(`serve:admit`, `serve:gauges`, `serve:prefill_tick`,
+`serve:decode_tick`, `serve:wait`, `serve:book`), every dispatch under
+its own span (`serve:decode_step`: rows, keys attended;
+`serve:prefill_chunk`: slot, start, tokens, bucket), a zero-length
+`serve:step_done` that carries the record's counts, and a zero-length
+`serve:admitted` an admission (prompt tokens, tokens attached from the
+prefix index, microseconds queued: the values the record adds up a
+call). What a call's annotations say is what its record holds; nothing
+in the engine reads one back, asks whether a trace is running, or does
+anything differently when one is. A `flush()` between two calls writes
+its `serve:wait`, `serve:book` and `serve:step_done` under no
+`serve:step`.
 
 Fault surface: `serve.admit` before each admission, `serve.
 prefix_attach` before a prefix-cache attach, `serve.prefill_chunk`
@@ -134,11 +153,13 @@ can snapshot concurrently.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from collections import deque
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Tuple
 
+import jax.profiler
 import numpy as np
 
 from .. import faults
@@ -162,7 +183,7 @@ from .queue import (
     RequestQueue,
 )
 
-__all__ = ["Handoff", "ServeEngine"]
+__all__ = ["Handoff", "PHASES", "ServeEngine", "StepRecord"]
 
 # Faults the engine absorbs by requeueing work (the retry layer's
 # transient taxonomy): injected connection resets and dropped requests.
@@ -195,6 +216,53 @@ class _InFlight:
     value: object
     rows: List[Tuple[int, List[int]]]
     first: bool = False
+
+
+# the host phases of a call, in the order a call runs them; each is a
+# `serve:<phase>` span in a profiler trace and a key of `StepRecord.host_s`
+PHASES = ("admit", "gauges", "prefill_tick", "decode_tick", "wait", "book")
+
+
+@dataclass
+class StepRecord:
+    """What one `ServeEngine.step` call did (or one `flush()` outside a
+    call), filled as the call goes and public as `engine.last_step` when
+    it returns. Counts are of THIS call: what it dispatched (`chunks`,
+    `decode_keys`) is read back by the next call, and what it read back
+    (`resolved`, `tokens_booked`, `retired`, `moe`) was dispatched by the
+    call before. No device value is kept: logits stay the caller's to ask
+    for."""
+
+    call: int  # index over the engine's life, from 1
+    t0: Optional[float] = None  # the engine's clock when the call began
+    queue_depth: int = 0  # after the call's first admission round
+    admitted: int = 0
+    prompt_tokens_admitted: int = 0
+    prefix_tokens_attached: int = 0  # of them, matched in the prefix cache
+    # (slot, start, tokens, bucket) of every prefill chunk, in dispatch order
+    chunks: Tuple[Tuple[int, int, int, int], ...] = ()
+    # one entry a decoding row, in slot order: the keys it attends (cached
+    # and the one this step writes); empty when no decode step was dispatched
+    decode_keys: Tuple[int, ...] = ()
+    resolved: int = 0  # device results read back
+    tokens_booked: int = 0  # tokens appended to the request they were for
+    retired: int = 0
+    # requests that lost their slot in this call and went back to the queue
+    # (pool pressure, a better class, a transient fault)
+    preempted: int = 0
+    flush: Optional[str] = None  # why everything was read back, if it was
+    # a sparse model's counters of the decode step this call read back:
+    # rows, assignments, routed, experts_hit (a list, one a sparse layer)
+    moe: Optional[Dict] = None
+    # host seconds by the engine's clock: each phase, and the whole call
+    host_s: Dict[str, float] = field(
+        default_factory=lambda: dict.fromkeys(PHASES, 0.0)
+    )
+    step_s: float = 0.0
+
+    @property
+    def decode_rows(self) -> int:
+        return len(self.decode_keys)
 
 
 @dataclass
@@ -458,6 +526,47 @@ class ServeEngine:
                 (self._dev_lengths, self._dev_tokens, self._dev_rngs), mesh
             )
         self.completions: Dict[str, Completion] = {}
+        # what the last call did (`StepRecord`). `_rec` is the record of
+        # the call that is running or, between calls (`t0` None), of the
+        # next one: what happens to a request between two calls (a seam's
+        # eviction, a migrated landing) is booked to the call that follows
+        self.last_step: Optional[StepRecord] = None
+        self._rec = StepRecord(call=1)
+
+    # -- the call's record -------------------------------------------------
+    def _open(self) -> StepRecord:
+        self._rec.t0 = self.clock()
+        return self._rec
+
+    def _close(self, rec: StepRecord) -> None:
+        """End the call's record: its whole time, the metrics' one
+        `record_host`, and the zero-length `serve:step_done` that carries
+        its counts onto the host line of a profiler trace."""
+        rec.step_s = self.clock() - rec.t0
+        self.metrics.record_host(rec)
+        with jax.profiler.TraceAnnotation(
+            "serve:step_done", call=rec.call, queue=rec.queue_depth,
+            admitted=rec.admitted, prompt=rec.prompt_tokens_admitted,
+            attached=rec.prefix_tokens_attached, chunks=len(rec.chunks),
+            chunk_tokens=sum(c[2] for c in rec.chunks),
+            rows=rec.decode_rows, keys=sum(rec.decode_keys),
+            resolved=rec.resolved, booked=rec.tokens_booked,
+            retired=rec.retired, preempted=rec.preempted,
+        ):
+            pass
+        self.last_step, self._rec = rec, StepRecord(call=rec.call + 1)
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **args):
+        """One host phase of the running call: a `serve:<name>` span on
+        the profiler's clock and its seconds, by the engine's clock, added
+        to the record's."""
+        t = self.clock()
+        try:
+            with jax.profiler.TraceAnnotation("serve:" + name, **args):
+                yield
+        finally:
+            self._rec.host_s[name] += self.clock() - t
 
     # -- admission ---------------------------------------------------------
     def submit(
@@ -693,6 +802,18 @@ class ServeEngine:
         self._prefilling[slot] = _Prefill(req, pos=pos0)
         self._reserved += self._worst_blocks(req)
         self.metrics.record_admit()
+        # the request changes hands: queue -> slot. A replay's stamps are
+        # its own: every admission starts them again
+        req.admit_time, req.token_times = self.clock(), []
+        rec = self._rec
+        rec.admitted += 1
+        rec.prompt_tokens_admitted += head_len
+        rec.prefix_tokens_attached += pos0
+        with jax.profiler.TraceAnnotation(
+            "serve:admitted", prompt=head_len, attached=pos0,
+            queue_us=int(1e6 * (req.admit_time - req.arrival_time)),
+        ):
+            pass
         return "admitted"
 
     def _prefix_scope(self, req: Request):
@@ -758,8 +879,6 @@ class ServeEngine:
         step. At least one program runs per tick, so a budget below the
         smallest bucket still makes progress."""
         import jax.numpy as jnp
-        import jax.profiler
-
         budget = self.prefill_chunk_tokens
         spent = 0
         while self._prefilling:
@@ -808,11 +927,13 @@ class ServeEngine:
             # tell it from a token (`serve/decode.py::paged_programs`)
             chunk = np.full((1, C), self._pad_id, np.int32)
             chunk[0, : end - pf.pos] = req.prompt[pf.pos:end]
-            # what the chunk is, on the host line of a profiler trace
-            # (free without one; nothing here reads it back)
+            # what the chunk is: into the call's record, and from there
+            # onto the host line of a profiler trace
+            start, tokens = pf.pos, end - pf.pos
+            self._rec.chunks += ((slot, start, tokens, C),)
             with jax.profiler.TraceAnnotation(
-                "serve:prefill_chunk", slot=slot, start=pf.pos,
-                tokens=end - pf.pos, bucket=C,
+                "serve:prefill_chunk", slot=slot, start=start,
+                tokens=tokens, bucket=C,
             ):
                 self.cache.tree, logits = self._prefill_chunk(
                     self.params,
@@ -824,7 +945,6 @@ class ServeEngine:
             self.metrics.record_prefill_chunk(
                 self._chunk_kernel_layers[C], self.model.cfg.n_layers
             )
-            start = pf.pos
             pf.pos = end
             spent += C
             if end < L:
@@ -945,6 +1065,7 @@ class ServeEngine:
         req = self._slot_req[slot]
         req.requeues += 1
         req.first_token_time = None
+        self._rec.preempted += 1
         self._slot_req[slot] = None
         self._slot_tokens[slot] = []
         self._prefilling.pop(slot, None)
@@ -999,13 +1120,62 @@ class ServeEngine:
         `pipeline`: `overlap_share` (decode steps dispatched over an
         outstanding result, of all decode steps) and `flushes` by cause.
 
+        What the call did is `engine.last_step` when it returns (a
+        `StepRecord`; the module docstring says what is in it and which
+        spans a profiler trace keeps of it).
+
         Returns True while work remains: a result outstanding, active
         slots, prefills, or queued requests."""
-        outstanding = len(self._inflight)
-        dispatched = self.metrics.prefill_chunks + self.metrics.decode_steps
-        self._admit()
+        outstanding, faulted = len(self._inflight), False
+        rec = self._open()
+        with jax.profiler.TraceAnnotation("serve:step"):
+            try:
+                with self._phase("admit"):
+                    self._admit()
+                with self._phase("gauges"):
+                    self._gauges(rec)
+                while True:
+                    with self._phase("prefill_tick"):
+                        self._prefill_tick()
+                    # an eviction under pool pressure frees a slot MID-STEP;
+                    # unchunked keeps PR 4's semantics by backfilling and
+                    # prefilling it in the same iteration. Chunked mode still
+                    # grants the slot (next step's tick prefills it) but spends
+                    # no further chunk budget.
+                    with self._phase("admit"):
+                        backfilled = self._admit()
+                    if backfilled == 0 or self.prefill_chunk_tokens is not None:
+                        break
+                if self._decoding:
+                    try:
+                        faults.fire("serve.step", n_active=len(self._decoding))
+                    except _TRANSIENT:
+                        self.requeue_inflight()
+                        faulted = True
+                    else:
+                        with self._phase("decode_tick"):
+                            self._decode_tick(overlapped=outstanding > 0)
+                if not faulted:
+                    if outstanding and not (rec.chunks or rec.decode_keys):
+                        # nothing to run ahead of it
+                        rec.flush = "idle"
+                        self.metrics.record_flush("idle")
+                    self._resolve(outstanding)
+            finally:
+                self._close(rec)
+        return (
+            faulted
+            or bool(self._inflight)
+            or bool(self._decoding)
+            or bool(self._prefilling)
+            or bool(self.queue)
+        )
+
+    def _gauges(self, rec: StepRecord) -> None:
+        """The metrics' per-call gauges: queue and slots, then the pool."""
+        rec.queue_depth = self.queue.depth
         self.metrics.record_step(
-            self.queue.depth,
+            rec.queue_depth,
             len(self.cache.active_slots),
             class_depths=(
                 self.queue.class_depths() if self.classes else None
@@ -1034,33 +1204,6 @@ class ServeEngine:
             state_bytes_per_block=self.cache.state_bytes_per_block,
             latent_blocks_live=self.cache.latent_live_blocks,
             latent_bytes_per_block=self.cache.latent_bytes_per_block,
-        )
-        while True:
-            self._prefill_tick()
-            # an eviction under pool pressure frees a slot MID-STEP;
-            # unchunked keeps PR 4's semantics by backfilling and
-            # prefilling it in the same iteration. Chunked mode still
-            # grants the slot (next step's tick prefills it) but spends
-            # no further chunk budget.
-            if self._admit() == 0 or self.prefill_chunk_tokens is not None:
-                break
-        if self._decoding:
-            try:
-                faults.fire("serve.step", n_active=len(self._decoding))
-            except _TRANSIENT:
-                self.requeue_inflight()
-                return True
-            self._decode_tick(overlapped=outstanding > 0)
-        if outstanding and dispatched == (
-            self.metrics.prefill_chunks + self.metrics.decode_steps
-        ):
-            self.metrics.record_flush("idle")  # nothing to run ahead of it
-        self._resolve(outstanding)
-        return (
-            bool(self._inflight)
-            or bool(self._decoding)
-            or bool(self._prefilling)
-            or bool(self.queue)
         )
 
     def _decode_tick(self, overlapped: bool) -> None:
@@ -1095,14 +1238,14 @@ class ServeEngine:
             for s, req in enumerate(self._slot_req)
             if req is not None and s not in self._decoding
         ]
-        import jax.profiler
-
         # the rows that decode and the keys they attend (each row's cached
-        # keys and the one this step writes), on the host line of a
-        # profiler trace (free without one; nothing here reads it back)
+        # keys and the one this step writes): into the call's record, and
+        # from there onto the host line of a profiler trace
+        rec = self._rec
+        rec.decode_keys = tuple((self.cache.lengths[active] + 1).tolist())
         with jax.profiler.TraceAnnotation(
-            "serve:decode_step", rows=len(active),
-            keys=int(self.cache.lengths[active].sum()) + len(active),
+            "serve:decode_step", rows=rec.decode_rows,
+            keys=sum(rec.decode_keys),
         ):
             (
                 self.cache.tree,
@@ -1146,64 +1289,85 @@ class ServeEngine:
         bookkeeping the host needs the tokens for."""
         for _ in range(n):
             res = self._inflight.popleft()
-            host = np.asarray(res.value)  # blocks until the device is there
-            if self._sparse_layers and not res.first:
-                self._record_moe_step(
-                    host[len(self._slot_req):], len(res.rows)
+            with self._phase("wait", first=int(res.first)):
+                host = np.asarray(res.value)  # blocks until the device is there
+            with self._phase("book"):
+                self._book(res, host)
+
+    def _book(self, res: _InFlight, host) -> None:
+        """One result's bookkeeping, once the host holds it: append and
+        stamp each token, retire what it ends."""
+        rec = self._rec
+        rec.resolved += 1
+        if self._sparse_layers and not res.first:
+            self._record_moe_step(host[len(self._slot_req):], len(res.rows))
+        now = self.clock()
+        for slot, toks in res.rows:
+            if self._slot_tokens[slot] is not toks:
+                continue  # evicted since: not the next tenant's token
+            req = self._slot_req[slot]
+            tok = int(host) if res.first else int(host[slot])
+            toks.append(tok)
+            req.token_times.append(now)
+            rec.tokens_booked += 1
+            if res.first:
+                req.first_token_time = now
+                self._note_recovery(now)
+            if self.eos_id is not None and tok == self.eos_id:
+                self._retire(slot, now, "eos")
+            elif len(toks) >= req.max_new_tokens:
+                self._retire(slot, now, "length")
+            elif res.first and self.role == "prefill":
+                # TTFT is DONE — the first token exists — so it
+                # lands in this pool's window now
+                (handoff,) = (h for h in self._handoff if h.slot == slot)
+                handoff.first = tok
+                self.metrics.record_first_token(
+                    now, now - req.arrival_time, klass=req.klass
                 )
-            now = self.clock()
-            for slot, toks in res.rows:
-                if self._slot_tokens[slot] is not toks:
-                    continue  # evicted since: not the next tenant's token
-                req = self._slot_req[slot]
-                tok = int(host) if res.first else int(host[slot])
-                toks.append(tok)
-                if res.first:
-                    req.first_token_time = now
-                    self._note_recovery(now)
-                if self.eos_id is not None and tok == self.eos_id:
-                    self._retire(slot, now, "eos")
-                elif len(toks) >= req.max_new_tokens:
-                    self._retire(slot, now, "length")
-                elif res.first and self.role == "prefill":
-                    # TTFT is DONE — the first token exists — so it
-                    # lands in this pool's window now
-                    (handoff,) = (h for h in self._handoff if h.slot == slot)
-                    handoff.first = tok
-                    self.metrics.record_first_token(
-                        now, now - req.arrival_time, klass=req.klass
-                    )
 
     def flush(self, cause: str = "caller") -> None:
         """Read back everything outstanding, so that the host's state
         (`completions`, token lists, `Handoff.first`) holds every token
         dispatched: the seams that read or hand out that state call this
         first, each under its own `cause` in `pipeline.flushes`. The
-        device is then idle until the next `step()`."""
-        if self._inflight:
-            self.metrics.record_flush(cause)
+        device is then idle until the next `step()`. Outside a call it
+        fills a `StepRecord` of its own (`flush` = the cause)."""
+        if not self._inflight:
+            return
+        own = self._rec.t0 is None  # no call is running
+        rec = self._open() if own else self._rec
+        rec.flush = cause
+        self.metrics.record_flush(cause)
+        try:
             self._resolve(len(self._inflight))
+        finally:
+            if own:
+                self._close(rec)
 
     def _record_moe_step(self, counters, rows: int) -> None:
         """One decode step's sparse-layer counters, (assignments, experts
-        hit) a layer: into the metrics, and — for a reader that pairs
-        them with the same steps' device time — onto the host line of a
-        profiler trace, where one is being taken (free otherwise). The
-        annotation is written when the step is READ BACK, a call after
-        its dispatch: a reader that wants one a step of its slice calls
+        hit) a layer: into the call's record and the metrics, and from
+        the record — for a reader that pairs them with the same steps'
+        device time — onto the host line of a profiler trace. All three
+        are written when the step is READ BACK, a call after its
+        dispatch: a reader that wants one a step of its slice calls
         `flush()` before the trace starts and before it stops."""
-        import jax.profiler
-
-        assignments = counters[0::2].tolist()
-        hit = counters[1::2].tolist()
-        # what the routers chose: `top_k` experts a live row and sparse
-        # layer, of which this chip computed its held experts' share
-        routed = rows * self.cfg.sparse_top_k * self._sparse_layers
-        self.metrics.record_moe_step(sum(assignments), hit, routed)
+        moe = self._rec.moe = {
+            "rows": rows,
+            "assignments": int(counters[0::2].sum()),
+            # what the routers chose: `top_k` experts a live row and sparse
+            # layer, of which this chip computed its held experts' share
+            "routed": rows * self.cfg.sparse_top_k * self._sparse_layers,
+            "experts_hit": counters[1::2].tolist(),  # per sparse layer
+        }
+        self.metrics.record_moe_step(
+            moe["assignments"], moe["experts_hit"], moe["routed"]
+        )
         with jax.profiler.TraceAnnotation(
-            "serve:moe_step", rows=rows, assignments=sum(assignments),
-            routed=routed,
-            **{f"hit{i}": h for i, h in enumerate(hit)},  # per sparse layer
+            "serve:moe_step", rows=rows, assignments=moe["assignments"],
+            routed=moe["routed"],
+            **{f"hit{i}": h for i, h in enumerate(moe["experts_hit"])},
         ):
             pass
 
@@ -1240,11 +1404,15 @@ class ServeEngine:
             requeues=req.requeues,
             tenant=req.tenant,
             klass=req.klass,
+            queue_s=req.admit_time - req.arrival_time,
+            token_times=list(req.token_times),
         )
         self.completions[req.rid] = comp
         self.metrics.record_complete(
-            now, n, comp.ttft_s, tpot, comp.e2e_s, klass=req.klass
+            now, n, comp.ttft_s, tpot, comp.e2e_s, klass=req.klass,
+            queue_s=comp.queue_s, token_times=comp.token_times,
         )
+        self._rec.retired += 1
         self._slot_req[slot] = None
         self._slot_tokens[slot] = []
         self._decoding.discard(slot)
@@ -1372,6 +1540,7 @@ class ServeEngine:
         # — requeued above; drop the stale migration records
         self._handoff = []
         self.metrics.record_requeue(len(inflight))
+        self._rec.preempted += len(inflight)
         return len(inflight)
 
     # -- disaggregated handoff / landing (serve/disagg/) -------------------
@@ -1453,11 +1622,15 @@ class ServeEngine:
         self._decoding.add(slot)
         self._reserved += worst
         self.metrics.record_admit()
+        now = self.clock()
         if req.first_token_time is None:
             # migration meta normally carries the prefill-side stamp;
             # fall back to "now" so TPOT stays finite either way
-            req.first_token_time = self.clock()
-        self._note_recovery(self.clock())
+            req.first_token_time = now
+        # admitted HERE now, holding the one token the prefill pool stamped
+        req.admit_time, req.token_times = now, [req.first_token_time]
+        self._rec.admitted += 1
+        self._note_recovery(now)
         return slot
 
     # -- introspection -----------------------------------------------------

@@ -36,6 +36,21 @@ inside the trailing window: per-class completed / shed / SLO
 attainment / TTFT percentiles, queue-depth mean+max, slot occupancy,
 and pool utilization. `snapshot()` exposes the default-window view
 under ``window`` so ``/serve`` shows the controller's own evidence.
+
+THE HOST'S SHARE OF A CALL (``window.host``): since the engine keeps one
+call's device work in flight, the host's work a token hides behind the
+device's and no idle time shows it. The engine hands over each call's
+`StepRecord` (`record_host`), and the window reduces them to the mean ms
+a call of each phase, `wait_share` (the share of a call the host spends
+blocked on the readback: its headroom; at 0 the host sets the pace) and
+the longest call with its split and its counts (queue depth, tokens
+admitted and attached, a sparse model's counters), which names a one-off
+stall after the fact without a trace. ``queue_ms`` and ``itl_ms`` beside
+it come from the engine's stamps: arrival to admission, and the gap
+between consecutive tokens of one request, booked once a request when it
+retires. The three are reduced in `snapshot()` alone, for the operator's
+page of ONE engine: `window_view`, which the autoscale controller polls
+a replica and `merge_window_views` merges, does not hold them.
 """
 
 from __future__ import annotations
@@ -43,7 +58,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Dict, Iterable, List, Optional
+from typing import Dict, Iterable, List, Optional, Sequence
 
 from .queue import DEFAULT_CLASS, ClassSpec
 
@@ -251,6 +266,12 @@ class ServeMetrics:
         # than what maxlen samples span simply reports what it has.
         self._step_win: deque = deque(maxlen=2 * max_latency_samples)
         self._pool_win: deque = deque(maxlen=2 * max_latency_samples)
+        # one `StepRecord` a call; (completion time, arrival -> admission)
+        # a completion; (token time, gap to the request's token before) a
+        # token after a request's first
+        self._host_win: deque = deque(maxlen=2 * max_latency_samples)
+        self._queue_win: deque = deque(maxlen=max_latency_samples)
+        self._itl_win: deque = deque(maxlen=8 * max_latency_samples)
         self._first_submit: Optional[float] = None
         self._last_complete: Optional[float] = None
 
@@ -318,6 +339,12 @@ class ServeMetrics:
             self.decode_steps += 1
             self.decode_kernel_steps += bool(kernel)
             self.decode_overlapped_steps += bool(overlapped)
+
+    def record_host(self, record) -> None:
+        """One `StepRecord` a call (`ServeEngine.step`, or a `flush()`
+        outside one), when the call ends: the window keeps the record."""
+        with self._lock:
+            self._host_win.append(record)
 
     def record_flush(self, cause: str) -> None:
         with self._lock:
@@ -494,11 +521,20 @@ class ServeMetrics:
         tpot_s: float,
         e2e_s: float,
         klass: str = DEFAULT_CLASS,
+        queue_s: float = 0.0,
+        token_times: Sequence[float] = (),
     ) -> None:
         """All latency samples land here, at COMPLETION — an admission
         attempt aborted by a mid-stream requeue leaves no sample, so the
-        percentiles describe only requests that actually finished."""
+        percentiles describe only requests that actually finished.
+        `queue_s` and `token_times` are the engine's stamps (arrival to
+        admission; the clock as the host booked each token): the
+        inter-token gaps are booked here, once a request."""
         with self._lock:
+            self._queue_win.append((t, queue_s))
+            self._itl_win.extend(
+                (b, b - a) for a, b in zip(token_times, token_times[1:])
+            )
             self.completed += 1
             self.tokens_completed += n_tokens
             self.ttft_s.append(ttft_s)
@@ -621,6 +657,70 @@ class ServeMetrics:
             ),
         }
 
+    def _stamps_view_locked(self, window_s: float, now: float) -> Dict:
+        """What the engine's own stamps say of the same window (caller
+        holds the lock): `host`, `queue_ms`, `itl_ms`. For the operator's
+        page alone (`snapshot()`): the controller's poll (`window_view`)
+        neither reads nor pays for it."""
+        cutoff = now - window_s
+
+        def tails(samples):
+            xs = [x for t, x in samples if cutoff <= t <= now]
+            return {
+                "p50": round(percentile(xs, 50) * 1e3, 3),
+                "p90": round(percentile(xs, 90) * 1e3, 3),
+                "n": len(xs),
+            }
+
+        return {
+            "host": self._host_view(
+                [r for r in self._host_win if cutoff <= r.t0 + r.step_s <= now]
+            ),
+            "queue_ms": tails(self._queue_win),
+            "itl_ms": tails(self._itl_win),
+        }
+
+    @staticmethod
+    def _host_view(records) -> Dict:
+        """The host's side of the calls a window holds: mean ms a call of
+        each phase and of the whole call, the share of the calls' time
+        spent blocked on the readback, and the longest call's split."""
+        n = len(records)
+        if not n:
+            return {"calls": 0}
+        total = sum(r.step_s for r in records)
+        phases = {
+            k: sum(r.host_s[k] for r in records) for k in records[0].host_s
+        }
+
+        def ms(seconds, over=1):
+            return round(1e3 * seconds / over, 4)
+
+        longest = max(records, key=lambda r: r.step_s)
+        return {
+            "calls": n,
+            "step_ms": ms(total, n),
+            "work_ms": ms(total - phases["wait"], n),
+            "wait_share": round(phases["wait"] / total, 4) if total else 0.0,
+            "phase_ms": {k: ms(v, n) for k, v in phases.items()},
+            "longest": {
+                "call": longest.call,
+                "step_ms": ms(longest.step_s),
+                "phase_ms": {k: ms(v) for k, v in longest.host_s.items()},
+                "queue_depth": longest.queue_depth,
+                "admitted": longest.admitted,
+                "prompt_tokens_admitted": longest.prompt_tokens_admitted,
+                "prefix_tokens_attached": longest.prefix_tokens_attached,
+                "chunks": len(longest.chunks),
+                "decode_rows": longest.decode_rows,
+                "resolved": longest.resolved,
+                "retired": longest.retired,
+                "preempted": longest.preempted,
+                "flush": longest.flush,
+                "moe": longest.moe,
+            },
+        }
+
     def window_view(
         self,
         window_s: Optional[float] = None,
@@ -652,6 +752,7 @@ class ServeMetrics:
 
     def snapshot(self) -> Dict:
         with self._lock:
+            now = self.clock()
             lat = {
                 name: {
                     "p50_ms": round(percentile(xs, 50) * 1e3, 3),
@@ -762,9 +863,10 @@ class ServeMetrics:
                 "tokens_completed": self.tokens_completed,
                 # the controller's evidence, on the same surface it
                 # polls — lifetime aggregates above, trailing window here
-                "window": self._window_view_locked(
-                    self.window_s, self.clock()
-                ),
+                "window": {
+                    **self._window_view_locked(self.window_s, now),
+                    **self._stamps_view_locked(self.window_s, now),
+                },
                 "latency": lat,
                 "cache_pool": {
                     "blocks_live": self.pool_blocks_live,

@@ -113,7 +113,12 @@ class Request:
     tenant: str = ""
     klass: str = DEFAULT_CLASS
     arrival_time: float = 0.0  # stamped by the engine's clock at submit
+    # stamped where the request changes hands, by the engine's clock: at
+    # every admission (a replay's are its own), and one a token as the
+    # host books it (`token_times[0]` is `first_token_time`)
+    admit_time: Optional[float] = None
     first_token_time: Optional[float] = None
+    token_times: List[float] = field(default_factory=list)
     requeues: int = 0
 
     def __post_init__(self):
@@ -167,6 +172,9 @@ class Completion:
     requeues: int = 0
     tenant: str = ""
     klass: str = DEFAULT_CLASS
+    queue_s: float = 0.0  # arrival -> the admission that completed
+    # the engine's clock as the host booked each token, one a token
+    token_times: List[float] = field(default_factory=list)
 
 
 class RequestQueue:
