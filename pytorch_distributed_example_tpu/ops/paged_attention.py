@@ -14,15 +14,42 @@ both kernels are tested against: `paged_kernel` is the one predicate.
 Shape of the decode kernel (design per /opt/skills/guides/pallas_guide.md):
 
 * ONE program, static shapes. Block tables, each row's page count and
-  last attended position, and a flat WORK LIST of (row, compute block)
-  items ride in as scalar prefetch (SMEM); the list is as long as the
-  live pages need, so the trip count — not a shape — follows the
-  lengths, and nothing recompiles when they change.
+  last attended position, and a flat WORK LIST ride in as scalar
+  prefetch (SMEM); the list is as long as the live pages need, so the
+  trip count — not a shape — follows the lengths, and nothing recompiles
+  when they change.
+* The list has TWO kinds of item. A (row, compute block) item is one
+  row's queries against a block of its pages. A SHARED item is (group,
+  compute block): a block that several rows' tables hold in common (a
+  prefix the serve engine attached to each of them, `serve/prefix.py`)
+  is copied from HBM ONCE a layer a step, and the group's rows meet that
+  one copy, `SHARED_ROWS` of them at a time with their queries stacked:
+  the KV heads one 32-bit row of a page holds (one of float32, two of
+  bfloat16: a strided read, no value converted) against those heads'
+  queries of all the stacked rows, one product a read (the key tiles go
+  into the MXU once for all of them, and a query head meets no group's
+  keys but its own and its word-mate's). What is shared is read from
+  the tables on the device (`shared_runs`, in `_work_list`): two rows
+  share a block when its pages are the same physical pages and it is
+  FULL for both (no key of it past either row's last), in their LEADING
+  run of such blocks. The shared items come first; each
+  row's running max, sum and accumulator wait in VMEM ((B, H, .)
+  float32, "nothing attended" when the call starts) for the first (row,
+  block) item of its own, which goes on from them — the same online
+  softmax over the same keys, in another order — and between two shared
+  items, unless the second is the next block of the same rows: those
+  stay stacked. Tables in which no two rows hold one page (an engine
+  without `prefix_cache`) make the shared list EMPTY: a loop of zero
+  trips, and the (row, block) items are every block, in row order, as
+  they were before there was a shared list. The engine counts the same
+  rule on the host (`shared_decode_keys`:
+  `StepRecord.decode_shared_keys`).
 * The pools stay in HBM (`memory_space=ANY`), viewed as
   (num_blocks, bs * KV, Dh): a page of all KV heads is one contiguous
   DMA. A compute block is `pages_per_block` pages copied, as many as the
   row has there, into one of two VMEM buffers; item i+1's pages (the
-  next row's first block included) are in flight while item i computes.
+  first (row, block) item behind the last shared one, and the next
+  row's first block, included) are in flight while item i computes.
 * A row is bounded by its LEADING VALID table entries as well as its
   length: a parked lane (all-invalid table row, length M-1) has no work
   item, reads no page and returns zeros; an invalid entry past a live
@@ -32,13 +59,15 @@ Shape of the decode kernel (design per /opt/skills/guides/pallas_guide.md):
   (`page0`), keys before that key in the page are masked (`lo`), and the
   row costs min(length, window) keys. Entries before `page0` are never
   read and may be invalid (the serve cache frees them while the request
-  lives). Without a window the scalars, the body and the compiled
-  kernel are what they were before windows existed.
-* All query heads meet all KV heads of a page in one MXU call: scores
-  are (H, keys * KV) with column c = key * KV + kv_head, and the columns
-  of another group's KV head are masked like keys past the length. That
-  spends KV times the needed MXU work on a memory-bound step instead of
-  strided sub-tile loads of single heads out of a packed page.
+  lives). A window layer does not share: its scalars, body and compiled
+  kernel are what they were before there was a shared list, and a layer
+  without a window has nothing of the window's.
+* In a (row, block) item all query heads meet all KV heads of a page in
+  one MXU call: scores are (H, keys * KV) with column c = key * KV +
+  kv_head, and the columns of another group's KV head are masked like
+  keys past the length. That spends KV times the needed MXU work on a
+  memory-bound step instead of strided sub-tile loads of single heads
+  out of a packed page.
 
 The chunk kernel is the same design carried to L queries a row, where
 the work is compute and not memory (`_chunk_kernel`):
@@ -83,6 +112,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -106,6 +136,10 @@ CHUNK_VMEM_BYTES = 96 * 1024 * 1024
 #: the one shared row with all its heads, so a step's transposed score block
 #: is (keys, heads * queries): 256 x 4096 float32 at 128 heads and 32 queries
 LATENT_QUERY_BLOCK = 32
+#: rows of a group that meet a shared block of the decode kernel at a time:
+#: one float32 sublane tile, so that ONE query head of all of them is one
+#: strided vector of the stacked group
+SHARED_ROWS = 8
 #: what the prefetched scalars (tables, work list) may take of the 1 MiB
 #: of scalar memory of a TensorCore; the compiler keeps the rest.
 SMEM_BYTES = 768 * 1024
@@ -212,6 +246,31 @@ def from_pool_heads(o, kv_heads: int, held: int):
     return o.reshape(B, L, held, -1)[:, :, :kv_heads].reshape(B, L, -1)
 
 
+def _heads_split(dtype, kv: int) -> bool:
+    """Whether strided reads of 32-bit rows can separate the `kv` heads of
+    a page of this dtype (the chunk kernel's `split_heads`, the decode
+    kernel's `_head_slab`): one head, float32, or bfloat16 heads in whole
+    32-bit words."""
+    if dtype not in (jnp.float32, jnp.bfloat16):
+        return False
+    return kv == 1 or kv % (4 // jnp.dtype(dtype).itemsize) == 0
+
+
+def decode_shares(pool, window=None) -> bool:
+    """THE predicate of the shared pass: whether the decode kernel reads a
+    block that several rows' tables hold ONCE a group (`shared_runs`), for
+    this K pool as `paged_kernel` sees it (shape and dtype; under the
+    `partitioned_over` context of the programs). Not a window layer's, and
+    only where `_head_slab` can separate the heads of a page as one device
+    holds them; everywhere else the kernel is what it was before there
+    was a shared list (`_kernel(..., share=False)`: the only other
+    variant). `paged_kernel` (the scalars to count), `paged_decode_
+    attention` (which body to trace) and `serve.decode.step_shares_blocks`
+    (whether the engine counts) all ask here."""
+    kv = pool.shape[2] // _head_shards(pool.shape[2])
+    return window is None and _heads_split(pool.dtype, kv)
+
+
 def paged_kernel(L: int, pool, block_tables, window=None, rank=None):
     """Which kernel of this module takes the call: "decode"
     (`paged_decode_attention`), "chunk" (`paged_chunk_attention`) or
@@ -231,7 +290,7 @@ def paged_kernel(L: int, pool, block_tables, window=None, rank=None):
     so page copies land tile-aligned in the VMEM buffer; and its
     prefetched scalars within scalar memory (tables and work list, with
     the two scalars a row that a window layer adds to the decode
-    kernel's).
+    kernel's, or the shared list a layer without one does).
 
     "decode": one query token a row, with or without a window.
     "chunk": more than one, of a layer WITHOUT a window (a window
@@ -262,13 +321,16 @@ def paged_kernel(L: int, pool, block_tables, window=None, rank=None):
     if Dh % 128 or (bs * kv) % tile:
         return None
     if L == 1:
-        items = B * -(-nb // _pages_per_block(bs, nb))
-        fits = 4 * (B * nb + 2 * items + 4 * B + 1) <= SMEM_BYTES
+        P = _pages_per_block(bs, nb)
+        items = B * -(-nb // P)
+        # the shared list: a row's skipped blocks and first item, an item,
+        # a flag and a next row a (row, whole block), the list's length
+        shared = 2 * B + 3 * B * (nb // P) + 1 if decode_shares(pool, window) else 0
+        fits = 4 * (B * nb + 2 * items + 4 * B + 1 + shared) <= SMEM_BYTES
         return "decode" if fits else None
     if (
         window is None
-        and pool.dtype in (jnp.float32, jnp.bfloat16)
-        and (kv == 1 or kv % (4 // itemsize) == 0)
+        and _heads_split(pool.dtype, kv)
         and L % tile == 0
         and L % min(L, CHUNK_QUERY_BLOCK) == 0
         and 4 * (B * nb + 2 * B) <= SMEM_BYTES
@@ -309,14 +371,127 @@ def _latent_kernel_for(L: int, pool, block_tables, rank):
     return None
 
 
-def _work_list(block_tables, lengths, nblk, bs, P, window=None):
+def shared_runs(block_tables, last, bs, P):
+    """THE rule of what the decode kernel reads once for several rows:
+    `run` (B, B), the leading compute blocks that rows r and r' read from
+    ONE copy. Block j of the two is one block when its P table entries are
+    equal and it is FULL for both (its last key is at or before each row's
+    `last`: every entry valid, no key masked); `run[r, r']` counts such
+    blocks from block 0 up to the first that is not one (a prefix cache
+    attaches leading whole pages; a run may end inside a compute block,
+    which is then each row's own), and is 0 on the diagonal. Row r's shared
+    blocks are its longest run with any other row; rows r and r' are of one
+    group at block j when j < run[r, r'] — an equivalence a block, each
+    block's groups parts of the block before's. Tables in which no two rows
+    hold one page (an engine without `prefix_cache`) give all 0.
+
+    By pairs, because that is ONE reduction on the device; the host counts
+    the same rule by sorting (`shared_decode_keys`)."""
+    B, nb = block_tables.shape
+    differ = block_tables[:, None, :] != block_tables[None, :, :]
+    first = jnp.min(jnp.where(differ, jnp.arange(nb, dtype=jnp.int32), nb), axis=2)
+    full = (last + 1) // (P * bs)  # a row's leading blocks with no key past `last`
+    run = jnp.minimum(first // P, jnp.minimum(full[:, None], full[None, :]))
+    return jnp.where(jnp.eye(B, dtype=bool), 0, run)
+
+
+def shared_decode_keys(block_tables, lengths, num_blocks: int, bs: int) -> int:
+    """Of the keys a decode step attends, how many its kernel reads from a
+    copy that another row uses too: the host's count of what `_work_list`
+    puts in the shared list for the same operands (`block_tables` (B, nb)
+    with parked rows all-invalid, `lengths` (B,) the position each row
+    writes), numpy arrays. `shared_runs`' rule by another road, because
+    numpy pays by the call and not by the element: a row's longest run with
+    any other row is its run with a NEIGHBOUR once the rows are sorted (the
+    pages behind a row's full blocks replaced by a value no other row
+    holds), so 2 (B - 1) comparisons of rows stand for the B * B pairs."""
+    B, nb = block_tables.shape
+    P = _pages_per_block(bs, nb)
+    valid = block_tables < num_blocks
+    lead = valid.argmin(axis=1)  # 0 where every entry is valid, too
+    lead[valid[:, 0] & (lead == 0)] = nb
+    n_pages = np.minimum(lead, np.clip(lengths // bs + 1, 0, nb))
+    # pages of a row's full blocks: no key of them past its last
+    own = (np.minimum(lengths, n_pages * bs - 1) + 1) // (P * bs) * P
+    live = int(own.max(initial=0))
+    if not live:
+        return 0
+    pages = np.where(
+        np.arange(live)[None, :] < own[:, None], block_tables[:, :live],
+        -1 - np.arange(B, dtype=block_tables.dtype)[:, None],
+    )
+    as_one = np.dtype((np.void, live * pages.dtype.itemsize))
+    pages = pages[np.argsort(pages.view(as_one).ravel())]
+    differ = pages[1:] != pages[:-1]
+    run = differ.argmax(axis=1)  # pages a row and the next have in common
+    run[~differ[:, 0] & (run == 0)] = live  # the same in every page
+    run = np.maximum(np.append(run, 0), np.append(0, run))
+    return int((run // P).sum()) * P * bs
+
+
+def _shared_list(block_tables, last, bs, P):
+    """The decode kernel's SHARED list for these tables, six scalar
+    arrays: `skip` (B,), the blocks of a row's leading shared run, which
+    leave its (row, block) items; the list, one item a group and block:
+    the group's first row an item (B * nbs,), `off` (B,) — item i of row
+    r is r's block i - off[r] — and the items' count (1,); `nxt`
+    (B * nbs,), at [row, block] the group's next row after `row` (B at its
+    last), by which the kernel walks a group; `cont` (B * nbs,), 1 at
+    the [row, block] of an item whose rows are the rows of the item before
+    it, one block on, and fit one stacked pass: the kernel leaves them
+    stacked between the two.
+    What is shared is `shared_runs`' to say; everything here is read off
+    its (B, B) answer. A row is the FIRST of its group from the block at
+    which its runs with every earlier row have ended to the end of its own
+    run, so its items are consecutive blocks, in (row, block) order.
+    Plain operations, few (a gather is three fusions: the kernel
+    subtracts `off` itself), and no `lax.cond` around them: the compiler
+    merges the layers' copies of these into one a step, and does not
+    merge conditionals (16 layers: 38 fusions against 536, compiled for
+    v5e)."""
+    B, nb = block_tables.shape
+    nbs = nb // P
+    rows = jnp.arange(B, dtype=jnp.int32)
+    blks = jnp.arange(nbs, dtype=jnp.int32)
+    earlier = np.tri(B, k=-1, dtype=bool)  # [r, r']: r' < r
+    run = shared_runs(block_tables, last, bs, P)
+    skip = jnp.max(run, axis=1)
+    # the blocks a row reads behind an earlier row of its group
+    behind = jnp.max(jnp.where(earlier, run, 0), axis=1)
+    mate = run[:, :, None] > blks  # (B, B, nbs): r' reads r's block j with it
+    nxt = jnp.min(
+        jnp.where(mate & earlier.T[:, :, None], rows[None, :, None], B), axis=1
+    )
+    # the item before (row, j) is (row, j - 1) with the same rows when the
+    # row led block j - 1 too and no mate's run ends at j (the diagonal's
+    # 0 "ends" at block 0, which continues nothing)
+    leaves = jnp.sum(run[:, :, None] == blks, axis=1)
+    cont = (
+        (blks[None, :] > behind[:, None]) & (leaves == 0)
+        & (jnp.sum(mate, axis=1) < SHARED_ROWS)
+    )
+    n = skip - behind  # items a row leads
+    ends = jnp.cumsum(n)
+    ids = jnp.arange(B * nbs, dtype=jnp.int32)
+    row = jnp.sum(ids[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    row = jnp.minimum(row, B - 1)  # ids past the list: never read
+    return (
+        skip, row, ends - n - behind, ends[-1:],
+        nxt.astype(jnp.int32).reshape(B * nbs),
+        cont.astype(jnp.int32).reshape(B * nbs),
+    )
+
+
+def _work_list(block_tables, lengths, nblk, bs, P, window=None, share=False):
     """Scalar side of the kernel, in plain XLA (tiny, identical in every
     layer of a kind in a step, so the compiler keeps one copy): per row
     the pages to read — bounded by the length AND by the leading valid
     entries — and the last position attended; then the flat (row, block)
-    list. With a `window`, also each row's first page `page0` (pages are
-    counted from it, entries before it count as valid whatever they
-    hold) and `lo`, the first attended key's offset in that page."""
+    list. With a `window`, also each row's first
+    page `page0` (pages are counted from it, entries before it count as
+    valid whatever they hold) and `lo`, the first attended key's offset in
+    that page. With `share`, a row's items start behind its shared run,
+    and `_shared_list`'s six arrays follow."""
     B, nb = block_tables.shape
     block_tables = block_tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
@@ -332,29 +507,80 @@ def _work_list(block_tables, lengths, nblk, bs, P, window=None):
         last = last - page0 * bs  # relative to page0's first key
         extra = (page0, first - page0 * bs)
     n_blocks = (n_pages + P - 1) // P
+    if share:
+        extra = _shared_list(block_tables, last, bs, P)
+        n_blocks = n_blocks - extra[0]
     ends = jnp.cumsum(n_blocks)
     ids = jnp.arange(B * -(-nb // P), dtype=jnp.int32)
     row = jnp.sum(ids[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
     row = jnp.minimum(row, B - 1)  # ids past the list: never read
-    blk = ids - (ends - n_blocks)[row]
+    first = ends - n_blocks  # a row's first item
+    if share:
+        first = first - extra[0]  # is the block behind its shared run
+    blk = ids - first[row]
     scalars = (block_tables.reshape(B * nb), n_pages, last, row, blk, ends[-1:])
-    return scalars if window is None else scalars + extra
+    return scalars + extra if share or window is not None else scalars
 
 
-def _kernel(*refs, scale, nb, P, KV, windowed):
-    # scalar prefetch (two more with a window), inputs, output, scratch
+def _online_softmax(s, v, m_prev, l_prev, acc_prev, precision):
+    """One block's step of the online softmax on VALUES, for a shared
+    item's slabs (a (row, block) item does the same on its scratch): the
+    module's recipe, float32 scores `s` (rows, keys) against the running
+    max, sum and accumulator, probabilities cast to the value dtype before
+    the value product, a float32 accumulator. Returns the three, advanced."""
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)  # masked: exp(-1e30 - m) == 0 exactly
+    alpha = jnp.exp(m_prev - m_new)
+    return (
+        m_new,
+        alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
+        alpha * acc_prev + jnp.dot(
+            p.astype(v.dtype), v, precision=precision,
+            preferred_element_type=jnp.float32,
+        ),
+    )
+
+
+def _head_slab(buf, slot, w, KV, T):
+    """Row key * KV + g of a page buffer is KV head g's key. One strided
+    read of 32-bit rows takes the `pack` heads that share a word — one of
+    float32, two of bfloat16: heads w * pack ... — of every key of
+    `buf[slot]`: (pack * T, Dh) whose row t * pack + h is key t of head
+    w * pack + h. No value is converted: a bfloat16 slab is the words
+    read, seen as the two rows each of them is."""
+    if KV == 1:
+        return buf[slot]
+    if buf.dtype == jnp.float32:
+        return buf[slot, pl.ds(w, T, stride=KV), :]
+    words = buf.bitcast(jnp.uint32)[slot, pl.ds(w, T, stride=KV // 2), :]
+    return pltpu.bitcast(words, buf.dtype)
+
+
+def _kernel(*refs, scale, nb, P, KV, windowed, share):
+    # scalar prefetch (six; a window's two or the shared list's six
+    # behind them), inputs, output, scratch (the shared pass's behind the
+    # rest)
     (tables_ref, n_pages_ref, last_ref, item_row_ref, item_blk_ref,
      n_items_ref) = refs[:6]
-    page0_ref, lo_ref = refs[6:8] if windowed else (None, None)
+    refs = refs[6:]
+    if windowed:
+        (page0_ref, lo_ref), refs = refs[:2], refs[2:]
+    if share:
+        (skip_ref, sh_row_ref, sh_off_ref, n_shared_ref, nxt_ref,
+         cont_ref) = refs[:6]
+        (q_g, m_g, l_g, acc_g, m_p, l_p, acc_p, member, count) = refs[17:]
+        refs = refs[6:17]
     (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, colpos, m_s, l_s,
-     acc_s) = refs[8 if windowed else 6:]
-    H = q_ref.shape[1]
+     acc_s) = refs
+    B, H = q_ref.shape[:2]
     rows = k_hbm.shape[1]  # bs * KV rows of Dh a page
     R = P * rows
     T = R // KV  # keys a compute block
     rep = H // KV
     n_items = n_items_ref[0]
+    n_shared = n_shared_ref[0] if share else 0
     precision = _precision(kbuf.dtype)
+    start, wait = (lambda cp: cp.start()), (lambda cp: cp.wait())
 
     # Column c of a score tile is (key c // KV, kv head c % KV). `colpos`
     # holds c where that head is the query head's group and a sentinel
@@ -367,10 +593,14 @@ def _kernel(*refs, scale, nb, P, KV, windowed):
     # so 0 * stale is never 0 * NaN; rows with no work item return zeros
     vbuf[...] = jnp.zeros_like(vbuf)
     o_ref[...] = jnp.zeros_like(o_ref)
+    if share:  # a row that has attended nothing yet
+        m_p[...] = jnp.full(m_p.shape, NEG_INF, jnp.float32)
+        l_p[...] = jnp.zeros(l_p.shape, jnp.float32)
+        acc_p[...] = jnp.zeros(acc_p.shape, jnp.float32)
 
     def page_copies(item, slot, fn):
         """Apply `fn` (start or wait) to the K and V copy of every page
-        compute block `item` has."""
+        compute block `item` of the (row, block) list has."""
         row = item_row_ref[item]
         first = item_blk_ref[item] * P
         have = jnp.minimum(n_pages_ref[row] - first, P)
@@ -381,27 +611,188 @@ def _kernel(*refs, scale, nb, P, KV, windowed):
             (kbuf, vbuf), sems, slot,
         )
 
-    @pl.when(n_items > 0)
+    def shared_item(item):
+        """(the group's first row, block) of item `item` of the shared
+        list, and where `nxt` and `cont` have them."""
+        row = sh_row_ref[item]
+        blk = item - sh_off_ref[row]
+        return row, blk, row * (nxt_ref.shape[0] // B) + blk
+
+    def shared_copies(item, slot, fn):
+        """The same for block `item` of the shared list: whole, out of
+        its group's first row's table."""
+        row, blk, _ = shared_item(item)
+        first = row * nb + blk * P
+        _page_copies(
+            fn, tables_ref, first, P, (k_hbm, v_hbm), (kbuf, vbuf), sems, slot,
+        )
+
+    # the two lists are one queue of copies, the shared list first: item
+    # i + 1's pages are in flight while item i computes
+    if share:
+        @pl.when(n_shared > 0)
+        def _():
+            shared_copies(0, 0, start)
+
+    @pl.when((n_shared == 0) & (n_items > 0))
     def _():
-        page_copies(0, 0, lambda cp: cp.start())
+        page_copies(0, 0, start)
+
+    def shared_body(item, carry):
+        """Item `item` of the shared list, a block of the group whose
+        first row is `sh_row[item]`: ONE copy of its pages, and the rows of
+        the group — walked by `nxt`, `SHARED_ROWS` at a time — meet it
+        stacked: the
+        keys of the KV heads one 32-bit row holds (`_head_slab`: one head,
+        or two of bfloat16 with the other's columns masked) against those
+        heads' queries of all of them in one MXU call, and no other's (a
+        (row, block) item contracts every query head with every KV head,
+        which is free only while it waits for its copy; a group's later
+        rows wait for none). Every key of a shared block is attended by
+        every row (the block is full): nothing is masked. A row's running
+        max, sum and accumulator wait in `m_p`, `l_p`, `acc_p` (set to
+        "nothing attended" when the call starts) for its first item of the
+        (row, block) list, and between two shared items unless the second
+        continues the first (`cont`): then the rows stay stacked."""
+        slot = item % 2
+
+        @pl.when(item + 1 < n_shared)
+        def _():
+            shared_copies(item + 1, 1 - slot, start)
+
+        @pl.when((item + 1 == n_shared) & (n_items > 0))
+        def _():
+            page_copies(0, 1 - slot, start)
+
+        first_row, blk, at = shared_item(item)
+        shared_copies(item, slot, wait)
+        nbs = nxt_ref.shape[0] // B
+        # a row's H query heads sit in a slot of whole float32 tiles
+        Hs = q_g.shape[0] // SHARED_ROWS
+        slot_of = lambda i: pl.ds(pl.multiple_of(i * Hs, Hs), H)
+        state = (m_g, l_g, acc_g)
+        # KV heads a 32-bit row of a page holds: read together
+        pack = 1 if KV == 1 else 4 // kbuf.dtype.itemsize
+
+        # the rows are stacked already (the item before left them so) /
+        # are to stay so for the item behind
+        stay = cont_ref[at] == 1
+        keep = cont_ref[shared_item(jnp.minimum(item + 1, n_shared - 1))[2]] == 1
+        keep &= item + 1 < n_shared
+
+        def some_rows(row):
+            def stack(carry):
+                row, n = carry
+                member[n] = row
+                at = slot_of(n)
+                q_g[at, :] = q_ref[row].astype(jnp.float32)
+                m_g[at, :] = m_p[row]
+                l_g[at, :] = l_p[row]
+                acc_g[at, :] = acc_p[row]
+                return nxt_ref[row * nbs + blk], n + 1
+
+            row, n = lax.while_loop(
+                lambda c: (c[0] < B) & (c[1] < SHARED_ROWS), stack,
+                (jnp.where(stay, B, row), jnp.where(stay, count[0], 0)),
+            )
+            count[0] = n
+            # slots past n hold an earlier group's rows: computed, not kept
+            slabs = range(KV // pack)
+            # query head j of every slot is one strided vector; a slab's
+            # heads' queries: head h's at rows h * rep * SHARED_ROWS
+            heads = [[
+                pl.ds(w * pack * rep + j, SHARED_ROWS, stride=Hs)
+                for j in range(pack * rep)
+            ] for w in slabs]
+            take = lambda ref, w: jnp.concatenate(
+                [ref[at, :] for at in heads[w]], axis=0
+            )
+            shape = (pack * rep * SHARED_ROWS, pack * T)
+            if pack > 1:  # column c is head c % pack's key
+                mine = lax.broadcasted_iota(jnp.int32, shape, 1) % pack == (
+                    lax.broadcasted_iota(jnp.int32, shape, 0)
+                    // (rep * SHARED_ROWS)
+                )
+
+            def scores(w, q):
+                s = lax.dot_general(
+                    q, _head_slab(kbuf, slot, w, KV, T),
+                    (((1,), (1,)), ((), ())), precision=precision,
+                    preferred_element_type=jnp.float32,
+                ) * scale  # (pack * rep * SHARED_ROWS, pack * T)
+                return jnp.where(mine, s, NEG_INF) if pack > 1 else s
+
+            def put(w, new):
+                for ref, value in zip(state, new):
+                    for j, at in enumerate(heads[w]):
+                        ref[at, :] = value[
+                            j * SHARED_ROWS:(j + 1) * SHARED_ROWS
+                        ]
+
+            # every slab's loads, then their chains (product, row max,
+            # `exp`, product), then every store: the chains are independent
+            # and overlap only when no store to the state lies between
+            # them (3.6 against 4.3 us an item: PERF.md, PR 36)
+            qs = [take(q_g, w).astype(kbuf.dtype) for w in slabs]
+            olds = [[take(ref, w) for ref in state] for w in slabs]
+            ss = [scores(w, qs[w]) for w in slabs]
+            news = [
+                _online_softmax(
+                    ss[w], _head_slab(vbuf, slot, w, KV, T), *olds[w],
+                    precision,
+                ) for w in slabs
+            ]
+            for w in slabs:
+                put(w, news[w])
+
+            def unstack(i, carry):
+                r = member[i]
+                at = slot_of(i)
+                m_p[r] = m_g[at, :]
+                l_p[r] = l_g[at, :]
+                acc_p[r] = acc_g[at, :]
+
+                # a row with no item of its own (every page shared and
+                # whole) is finished by its last shared block
+                @pl.when(
+                    (blk + 1 == skip_ref[r])
+                    & ((blk + 1) * P >= n_pages_ref[r])
+                )
+                def _():
+                    o_ref[r] = (acc_g[at, :] / l_g[at, :]).astype(o_ref.dtype)
+
+                return carry
+
+            lax.fori_loop(0, jnp.where(keep, 0, n), unstack, 0)
+            return row
+
+        lax.while_loop(lambda row: row < B, some_rows, first_row)
+        return carry
 
     def body(item, carry):
-        slot = item % 2
+        slot = (n_shared + item) % 2
 
         @pl.when(item + 1 < n_items)
         def _():
-            page_copies(item + 1, 1 - slot, lambda cp: cp.start())
+            page_copies(item + 1, 1 - slot, start)
 
         row = item_row_ref[item]
         blk = item_blk_ref[item]
 
-        @pl.when(blk == 0)
-        def _():
-            m_s[...] = jnp.full_like(m_s, NEG_INF)
-            l_s[...] = jnp.zeros_like(l_s)
-            acc_s[...] = jnp.zeros_like(acc_s)
+        if share:
+            @pl.when(blk == skip_ref[row])
+            def _():  # the row goes on from what its shared blocks left
+                m_s[...] = m_p[row]
+                l_s[...] = l_p[row]
+                acc_s[...] = acc_p[row]
+        else:
+            @pl.when(blk == 0)
+            def _():
+                m_s[...] = jnp.full_like(m_s, NEG_INF)
+                l_s[...] = jnp.zeros_like(l_s)
+                acc_s[...] = jnp.zeros_like(acc_s)
 
-        page_copies(item, slot, lambda cp: cp.wait())
+        page_copies(item, slot, wait)
         q = q_ref[row]  # (H, Dh)
         k = kbuf[slot]  # (R, Dh)
         v = vbuf[slot]
@@ -433,12 +824,33 @@ def _kernel(*refs, scale, nb, P, KV, windowed):
 
         return carry
 
+    if share:
+        lax.fori_loop(0, n_shared, shared_body, 0)
     lax.fori_loop(0, n_items, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
+def _shared_vmem_limit(B, H, Dh, R, itemsize) -> int:
+    """Scoped VMEM the kernel with a shared pass asks for: q and the output
+    whole, two page buffers of R rows each for K and V, `colpos` and room
+    for eight live (H, R) float32 tiles, and a (max, sum, accumulator) —
+    the max and the sum a 128-lane row a head — for every row, for the
+    `SHARED_ROWS` stacked ones (with their queries) and for the running
+    one; never under the 16 MB a v5e kernel gets unasked, which hold it at
+    the serve cells' 32 rows (9 MB) but not at 256 (22 MB), where the
+    kernel without a shared pass still fits them."""
+    state = -(-H // 8) * 8 * 2 * 512 + H * Dh * 4
+    return max(16 << 20, (
+        2 * B * H * Dh * itemsize + 4 * R * Dh * itemsize + 9 * H * R * 4
+        + (B + SHARED_ROWS + 1) * state + SHARED_ROWS * H * Dh * 4
+    ))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "interpret", "window", "share")
+)
 def _per_device(
-    q, pool_k, pool_v, block_tables, lengths, *, scale, interpret, window=None
+    q, pool_k, pool_v, block_tables, lengths, *, scale, interpret, window=None,
+    share=False,
 ):
     """The kernel call on one device's operands. A `jax.jit` of its own
     so that the layers of a step share ONE trace and ONE lowering of the
@@ -451,31 +863,52 @@ def _per_device(
     nb = block_tables.shape[1]
     P = _pages_per_block(bs, nb)
     rows = bs * KV
-    scalars = _work_list(block_tables, lengths, nblk, bs, P, window)
+    scalars = _work_list(block_tables, lengths, nblk, bs, P, window, share)
     vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = lambda: pl.BlockSpec(memory_space=pl.ANY)
+    f32 = jnp.float32
+    scratch = [
+        pltpu.VMEM((2, P * rows, Dh), pool_k.dtype),
+        pltpu.VMEM((2, P * rows, Dh), pool_v.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((H, P * rows), jnp.int32),
+        pltpu.VMEM((H, 1), f32),
+        pltpu.VMEM((H, 1), f32),
+        pltpu.VMEM((H, Dh), f32),
+    ]
+    if share:
+        # the stacked rows' queries and state, a row in whole float32
+        # tiles; every row's waiting state
+        G = SHARED_ROWS * -(-H // 8) * 8
+        scratch += [
+            pltpu.VMEM((G, Dh), f32),
+            pltpu.VMEM((G, 1), f32),
+            pltpu.VMEM((G, 1), f32),
+            pltpu.VMEM((G, Dh), f32),
+            pltpu.VMEM((B, H, 1), f32),
+            pltpu.VMEM((B, H, 1), f32),
+            pltpu.VMEM((B, H, Dh), f32),
+            pltpu.SMEM((SHARED_ROWS,), jnp.int32),
+            pltpu.SMEM((1,), jnp.int32),
+        ]
     return pl.pallas_call(
         functools.partial(
-            _kernel, scale=scale, nb=nb, P=P, KV=KV, windowed=window is not None
+            _kernel, scale=scale, nb=nb, P=P, KV=KV,
+            windowed=window is not None, share=share,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(1,),
             in_specs=[vmem(), hbm(), hbm()],
             out_specs=vmem(),
-            scratch_shapes=[
-                pltpu.VMEM((2, P * rows, Dh), pool_k.dtype),
-                pltpu.VMEM((2, P * rows, Dh), pool_v.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((H, P * rows), jnp.int32),
-                pltpu.VMEM((H, 1), jnp.float32),
-                pltpu.VMEM((H, 1), jnp.float32),
-                pltpu.VMEM((H, Dh), jnp.float32),
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.ARBITRARY,),
+            vmem_limit_bytes=_shared_vmem_limit(
+                B, H, Dh, P * rows, pool_k.dtype.itemsize
+            ) if share else None,
         ),
         interpret=interpret,
         name="paged_decode_attention",
@@ -533,7 +966,8 @@ def paged_decode_attention(
     if interpret is None:
         interpret = _interpret_default()
     local = functools.partial(
-        _per_device, scale=scale, interpret=interpret, window=window
+        _per_device, scale=scale, interpret=interpret, window=window,
+        share=decode_shares(pool_k, window),
     )
     return _on_kv_shards(local, q, pool_k)(
         q, pool_k, pool_v, block_tables, lengths

@@ -121,6 +121,21 @@ def layer_paths(
     return paths
 
 
+def step_shares_blocks(cache, paths: dict, mesh=None, tp_axis: str = "tp") -> bool:
+    """Whether the step whose `layer_paths` are `paths` reads a block that
+    several of its rows' tables hold ONCE a group: its full layers take
+    the decode kernel and that kernel shares for this pool
+    (`ops.paged_attention.decode_shares`: THE predicate, asked under the
+    context the programs apply the model under). The engine counts
+    `StepRecord.decode_shared_keys` where this says so."""
+    from ..ops.paged_attention import decode_shares
+
+    if paths.get("full", (0, None))[1] != "decode_kernel":
+        return False
+    with _kernel_partition(mesh, tp_axis):
+        return decode_shares(cache.pool_aval)
+
+
 def kernel_layers(paths: dict) -> int:
     """Of the layers `layer_paths` gave the paths of, those whose mixer
     traces a Pallas kernel (an attention layer one of

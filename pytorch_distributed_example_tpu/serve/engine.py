@@ -100,7 +100,8 @@ TraceAnnotation`s that share the device trace's clock and cost nothing
 without one: a `serve:step` span a call with the phases nested inside
 (`serve:admit`, `serve:gauges`, `serve:prefill_tick`,
 `serve:decode_tick`, `serve:wait`, `serve:book`), every dispatch under
-its own span (`serve:decode_step`: rows, keys attended;
+its own span (`serve:decode_step`: rows, keys attended, `shared`: those
+of them the kernel reads once for several rows;
 `serve:prefill_chunk`: slot, start, tokens, bucket), a zero-length
 `serve:step_done` that carries the record's counts, and a zero-length
 `serve:admitted` an admission (prompt tokens, tokens attached from the
@@ -164,6 +165,7 @@ import numpy as np
 
 from .. import faults
 from ..numerics import numerics_contract
+from ..ops.paged_attention import shared_decode_keys
 from ..types import DistError
 from .bucketing import bucket_for, bucket_lengths
 from .cache import (
@@ -172,7 +174,10 @@ from .cache import (
     linear_layers_of,
     window_layers_of,
 )
-from .decode import kernel_layers, layer_paths, paged_programs, sync_slot_lanes
+from .decode import (
+    kernel_layers, layer_paths, paged_programs, step_shares_blocks,
+    sync_slot_lanes,
+)
 from .metrics import ServeMetrics
 from .queue import (
     DEFAULT_CLASS,
@@ -244,6 +249,11 @@ class StepRecord:
     # one entry a decoding row, in slot order: the keys it attends (cached
     # and the one this step writes); empty when no decode step was dispatched
     decode_keys: Tuple[int, ...] = ()
+    # of sum(decode_keys), the keys the step's attention kernel reads from
+    # a copy another row uses too (`ops.paged_attention.shared_runs`:
+    # whole compute blocks that several rows' tables hold); 0 without
+    # `prefix_cache`, where no two rows hold one block
+    decode_shared_keys: int = 0
     resolved: int = 0  # device results read back
     tokens_booked: int = 0  # tokens appended to the request they were for
     retired: int = 0
@@ -456,6 +466,10 @@ class ServeEngine:
             for C in {*self.buckets, prefill_chunk_tokens} - {None}
         }
         self._decode_kernel = kernel_layers(step_paths) == layers
+        # only an attached prefix puts one block in two rows' tables
+        self._decode_shares = self.prefix is not None and step_shares_blocks(
+            self.cache, step_paths, jmesh, tp_axis
+        )
         self._chunk_kernel_layers = {
             C: kernel_layers(paths) for C, paths in chunk_paths.items()
         }
@@ -1243,9 +1257,15 @@ class ServeEngine:
         # from there onto the host line of a profiler trace
         rec = self._rec
         rec.decode_keys = tuple((self.cache.lengths[active] + 1).tolist())
+        tables = self.cache.tables(parked=parked)
+        if self._decode_shares:
+            rec.decode_shared_keys = shared_decode_keys(
+                tables, self.cache.lengths, self.cache.invalid_block,
+                self.cache.block_size,
+            )
         with jax.profiler.TraceAnnotation(
             "serve:decode_step", rows=rec.decode_rows,
-            keys=sum(rec.decode_keys),
+            keys=sum(rec.decode_keys), shared=rec.decode_shared_keys,
         ):
             (
                 self.cache.tree,
@@ -1259,9 +1279,12 @@ class ServeEngine:
                 self._dev_lengths,
                 self._dev_tokens,
                 self._dev_rngs,
-                self.cache.tables(parked=parked),
+                tables,
             )
-        self.metrics.record_decode_step(self._decode_kernel, overlapped)
+        self.metrics.record_decode_step(
+            self._decode_kernel, overlapped, sum(rec.decode_keys),
+            rec.decode_shared_keys,
+        )
         self._await(readback, active)
         # the host mirror advances at dispatch: the next call grows
         # blocks and counts budgets from it before these tokens are read

@@ -177,6 +177,11 @@ class ServeMetrics:
         # engine's pool: 100 % or 0 % for one engine's lifetime)
         self.decode_steps = 0
         self.decode_kernel_steps = 0
+        # keys the decode steps' rows attended, and those of them the
+        # kernel read once for several rows (a prefix several requests
+        # hold: `ops.paged_attention.shared_runs`)
+        self.decode_keys = 0
+        self.decode_shared_keys = 0
         # the engine keeps one call's device work in flight
         # (`ServeEngine.step`): decode steps dispatched while an earlier
         # call's result was still unread, and the times the engine read
@@ -334,11 +339,18 @@ class ServeMetrics:
             if self.slots:
                 self._occupancy_steps += slots_active / self.slots
 
-    def record_decode_step(self, kernel: bool, overlapped: bool) -> None:
+    def record_decode_step(
+        self, kernel: bool, overlapped: bool, keys: int = 0, shared_keys: int = 0
+    ) -> None:
+        """`keys`: the keys the step's rows attend; `shared_keys`: those of
+        them its kernel reads from a copy another row uses too
+        (`StepRecord.decode_shared_keys`)."""
         with self._lock:
             self.decode_steps += 1
             self.decode_kernel_steps += bool(kernel)
             self.decode_overlapped_steps += bool(overlapped)
+            self.decode_keys += keys
+            self.decode_shared_keys += shared_keys
 
     def record_host(self, record) -> None:
         """One `StepRecord` a call (`ServeEngine.step`, or a `flush()`
@@ -827,6 +839,9 @@ class ServeMetrics:
                     "kernel_share": round(
                         self.decode_kernel_steps / self.decode_steps, 4
                     ) if self.decode_steps else 0.0,
+                    "shared_key_share": round(
+                        self.decode_shared_keys / self.decode_keys, 4
+                    ) if self.decode_keys else 0.0,
                     "layer_paths": dict(self.decode_layer_paths),
                 },
                 "pipeline": {

@@ -211,23 +211,28 @@ def test_flash_kernels_compile_at_the_train_cells_shape(topo, monkeypatch, BH):
     assert len(names) == 2 and "flash_fwd" in names[0] and "flash_bwd" in names[1], names
 
 
-def test_paged_decode_kernel_compiles_at_mistral_widths(topo, monkeypatch):
+@pytest.mark.parametrize("B", [32, 256], ids=["32_rows", "256_rows"])
+def test_paged_decode_kernel_compiles_at_mistral_widths(topo, monkeypatch, B):
     """`ops.paged_decode_attention` at the serve cells' shape (32 rows, 32
     query heads over 8 KV heads of 128, 4096 pages of 16 tokens, 512-entry
     tables) goes through Mosaic for a v5e, and the (num_blocks, bs * KV,
     Dh) view it reads the pools through is a bitcast: a layout change
-    would copy the whole pool in every layer."""
+    would copy the whole pool in every layer. At 256 rows, the most whose
+    scalars fit, every row's waiting softmax state is past the VMEM a
+    kernel gets unasked: the call asks for what it holds
+    (`_shared_vmem_limit`)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from pytorch_distributed_example_tpu.ops import paged_decode_attention
+    from pytorch_distributed_example_tpu.ops import paged_decode_attention, paged_kernel
 
     monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
     one = SingleDeviceSharding(topo.devices[0])
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
-    B, H, KV, Dh, bs, nblk, nb = 32, 32, 8, 128, 16, 4096, 512
+    H, KV, Dh, bs, nblk, nb = 32, 8, 128, 16, 4096, 512
     pool = sd((nblk, bs, KV, Dh), jnp.bfloat16)
+    assert paged_kernel(1, pool, sd((B, nb), jnp.int32)) == "decode"
     hlo = jax.jit(paged_decode_attention).lower(
         sd((B, H, Dh), jnp.bfloat16), pool, pool,
         sd((B, nb), jnp.int32), sd((B,), jnp.int32),

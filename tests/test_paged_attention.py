@@ -341,6 +341,360 @@ def test_scale_defaults_to_the_head_size():
     )
 
 
+# -- the shared pass: a block several rows hold is read once -----------------
+
+P16 = BLOCK // BS  # pages of a compute block
+WIDE = dict(nb=144, nblk=420)  # tables that span 2304 keys: nine blocks
+
+
+def share_tables(groups, lengths, nb=NB, nblk=NBLK, seed=0, parked=()):
+    """`groups`: [(shared pages, rows)]: the rows of a group open with the
+    same physical pages; every row then holds pages of its own up to its
+    length. A parked row is all-invalid."""
+    rng = np.random.default_rng(seed)
+    ids = rng.permutation(nblk).tolist()
+    tables = np.full((len(lengths), nb), nblk, np.int32)
+    opened = {}
+    for pages, rows in groups:
+        head = [ids.pop() for _ in range(pages)]
+        for r in rows:
+            opened[r] = head
+    for r, n in enumerate(lengths):
+        if r in parked:
+            continue
+        m = n // BS + 1
+        head = opened.get(r, [])[:m]
+        tables[r, :m] = head + [ids.pop() for _ in range(m - len(head))]
+    return tables
+
+
+def work_list(tables, lengths, nblk=NBLK):
+    """`_work_list`'s shared half: (skip a row, the shared list's (row,
+    block) items, every item's rows as the kernel walks them)."""
+    sc = paged_attention._work_list(
+        jnp.asarray(tables), jnp.asarray(lengths, jnp.int32), nblk, BS, P16,
+        share=True,
+    )
+    assert len(sc) == 12
+    skip, sh_row, off, n, nxt, _ = map(np.asarray, sc[6:])
+    B, nbs = len(lengths), tables.shape[1] // P16
+    items, members = [], []
+    for i in range(int(n[0])):
+        blk = i - int(off[sh_row[i]])
+        items.append((int(sh_row[i]), blk))
+        rows, r = [], int(sh_row[i])
+        while r < B:
+            rows.append(r)
+            r = int(nxt[r * nbs + blk])
+        members.append(rows)
+    return skip.tolist(), items, members
+
+
+@functools.lru_cache(maxsize=None)
+def wide_operands(dtype, B):
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(B), 3)
+    shape = (WIDE["nblk"], BS, 2, DH)
+    return (jax.random.normal(k3, (B, 8, DH), dtype),
+            jax.random.normal(k1, shape, dtype), jax.random.normal(k2, shape, dtype))
+
+
+def shared_check(dtype, lengths, tables, live=None, wide=False):
+    """Kernel against the dense path on tables that share pages, and the
+    host's count against the device's list."""
+    if wide:
+        kernel, reference = programs()
+        q, pool_k, pool_v = wide_operands(dtype, len(lengths))
+        args = (q, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32))
+        got = np.asarray(kernel(*args), np.float32)
+        want = np.asarray(reference(*args), np.float32)
+        rows = list(range(len(lengths))) if live is None else live
+        assert np.isfinite(got).all()
+        assert np.abs(got[rows] - want[rows]).max() <= TOL[dtype]
+    else:
+        check(dtype, 8, 2, lengths, tables, live)
+    nblk = WIDE["nblk"] if wide else NBLK
+    skip, items, members = work_list(tables, lengths, nblk)
+    keys = paged_attention.shared_decode_keys(
+        np.asarray(tables), np.asarray(lengths), nblk, BS)
+    assert keys == sum(skip) * BLOCK == sum(len(m) for m in members) * BLOCK
+    return skip, items, members
+
+
+GROUPS = {"1": 1, "2": 2, "8": 8, "all_rows": 9}
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+@pytest.mark.parametrize("size", list(GROUPS))
+def test_a_group_reads_its_shared_blocks_once(dtype, size):
+    """Nine rows, the first `size` of them behind one 512-key head (two
+    whole compute blocks), every row with keys of its own behind it: a
+    group of one shares nothing, the others are one item a block whose
+    rows the kernel walks `SHARED_ROWS` at a time (eight: one full pass;
+    nine: a second with one row)."""
+    g = GROUPS[size]
+    rng = np.random.default_rng(g)
+    lengths = [int(n) for n in rng.integers(2 * BLOCK, SPAN, 9)]
+    for r in range(g, 9):
+        lengths[r] = int(rng.integers(0, 200))
+    tables = share_tables([(2 * P16, range(g))], lengths, seed=g)
+    skip, items, members = shared_check(dtype, lengths, tables)
+    if g == 1:
+        assert skip == [0] * 9 and not items
+    else:
+        assert skip == [2] * g + [0] * (9 - g)
+        assert items == [(0, 0), (0, 1)] and members == [list(range(g))] * 2
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_two_groups_of_different_shared_lengths(dtype):
+    """Rows 1, 3, 4 share two blocks, rows 0 and 5 one; row 2 nothing."""
+    lengths = [300, 600, 77, 520, 639, 511]
+    tables = share_tables([(2 * P16, (1, 3, 4)), (P16, (0, 5))], lengths, seed=3)
+    skip, items, members = shared_check(dtype, lengths, tables)
+    assert skip == [1, 2, 0, 2, 2, 1]
+    assert items == [(0, 0), (1, 0), (1, 1)]
+    assert members == [[0, 5], [1, 3, 4], [1, 3, 4]]
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_a_shared_run_that_ends_inside_a_compute_block(dtype):
+    """129 pages shared (the benchmark's check: a 2056-token head): eight
+    whole blocks are read once, the block the run ends in is each row's
+    own, its first page the same physical page in every row."""
+    lengths = [2100, 2303, 2064]
+    tables = share_tables([(129, (0, 1, 2))], lengths, seed=1, **WIDE)
+    assert len({int(t) for t in tables[:, 128]}) == 1
+    skip, items, members = shared_check(dtype, lengths, tables, wide=True)
+    assert skip == [8, 8, 8] and len(items) == 8
+    assert all(m == [0, 1, 2] for m in members)
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_a_row_whose_context_is_shared_but_for_the_token_it_writes(dtype):
+    """Row 0 writes position 512, the first key of a page of its own
+    behind two shared blocks; row 2's every page is shared and whole (its
+    length is the head's last key), so it has no item of its own and its
+    last shared block finishes it."""
+    lengths = [2 * BLOCK, 2 * BLOCK + 90, 2 * BLOCK - 1]
+    tables = share_tables([(2 * P16, (0, 1, 2))], lengths, seed=4)
+    skip, items, members = shared_check(dtype, lengths, tables)
+    assert skip == [2, 2, 2] and members == [[0, 1, 2]] * 2
+    n_items = int(np.asarray(paged_attention._work_list(
+        jnp.asarray(tables), jnp.asarray(lengths, jnp.int32), NBLK, BS, P16,
+        share=True)[5])[0])
+    assert n_items == 2  # rows 0 and 1: one block of their own each
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_a_parked_member_and_one_that_ends_inside_the_run(dtype):
+    """Of five rows behind one 512-key head, row 1 is parked (all-invalid,
+    length M-1: no item at all) and row 3's length ends inside the second
+    block: that block is not full for it, so it reads it as its own,
+    masked at its length, and shares only the first."""
+    lengths = [600, SPAN - 1, 639, BLOCK + 100, 530]
+    tables = share_tables([(2 * P16, range(5))], lengths, seed=6, parked=(1,))
+    skip, items, members = shared_check(dtype, lengths, tables, live=[0, 2, 3, 4])
+    assert skip == [2, 0, 2, 1, 2]
+    assert members == [[0, 2, 3, 4], [0, 2, 4]]
+
+
+def continues(tables, lengths, nblk=NBLK):
+    """`_work_list`'s flag an item of the shared list: 1 where the item's
+    rows are the rows of the item before it, one block on."""
+    sc = paged_attention._work_list(
+        jnp.asarray(tables), jnp.asarray(lengths, jnp.int32), nblk, BS, P16,
+        share=True,
+    )
+    sh_row, off, n, cont = (np.asarray(sc[i]) for i in (7, 8, 9, 11))
+    nbs = tables.shape[1] // P16
+    return [
+        int(cont[r * nbs + i - off[r]]) for i, r in enumerate(sh_row[:int(n[0])])
+    ]
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_rows_stay_stacked_from_a_block_to_the_next_of_the_same_rows(dtype):
+    """Three blocks behind rows 0, 1, 3 and the first two of them behind
+    row 2 as well: the group's second item continues its first (the same
+    four rows: their state stays stacked), the third does not (row 2 has
+    left), and the pair behind rows 4 and 5 continues from its first
+    block. A group of more rows than one stacked pass never continues."""
+    lengths = [900, 2303, 1000, 800, 600, 639]
+    wide = [(3 * P16, (0, 1, 3)), (2 * P16, (4, 5))]
+    tables = share_tables(wide, lengths, seed=11, **WIDE)
+    tables[2, :2 * P16] = tables[0, :2 * P16]
+    skip, items, members = shared_check(dtype, lengths, tables, wide=True)
+    assert skip == [3, 3, 2, 3, 2, 2]
+    assert items == [(0, 0), (0, 1), (0, 2), (4, 0), (4, 1)]
+    assert members == [[0, 1, 2, 3]] * 2 + [[0, 1, 3]] + [[4, 5]] * 2
+    assert continues(tables, lengths, WIDE["nblk"]) == [0, 1, 0, 0, 1]
+    many = paged_attention.SHARED_ROWS + 1
+    lengths = [2 * BLOCK + 10 * r for r in range(many)]
+    tables = share_tables([(2 * P16, range(many))], lengths, seed=12)
+    assert continues(tables, lengths) == [0, 0]
+
+
+def test_equal_entries_that_are_invalid_form_no_group():
+    """Parked rows are equal everywhere (the sentinel), and two live rows
+    are equal behind their lengths (sentinels, stale ids): no group."""
+    lengths = [SPAN - 1, 300, SPAN - 1, 200]
+    tables = tables_for(lengths, seed=2, parked=(0, 2))
+    tables[1, 19:] = 7
+    tables[3, 19:] = 7  # the same stale ids behind both rows' pages
+    skip, items, _ = shared_check(jnp.float32, lengths, tables, live=[1, 3])
+    assert skip == [0] * 4 and not items
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_the_host_sorts_and_the_device_pairs_to_one_rule(seed):
+    """Tables no prefix cache would write: every block of a row is one of
+    three candidates of its position (two apart, a third that parts from
+    the first in its LAST page), lengths anywhere, stale ids or the
+    sentinel behind them. Rows agree and part at any block, and agree
+    again behind a block that differs, which shares nothing. The device's
+    list (by pairs), the host's count (by sorting) and a count by hand
+    agree, and the kernel attends what the dense path attends."""
+    rng = np.random.default_rng(100 + seed)
+    nb, nblk = WIDE["nb"], WIDE["nblk"]
+    ids = rng.permutation(nblk).tolist()
+    B, nbs = 7, nb // P16
+    candidates = []
+    for _ in range(nbs):
+        a = [ids.pop() for _ in range(P16)]
+        candidates.append([a, [ids.pop() for _ in range(P16)], a[:-1] + [ids.pop()]])
+    tables = np.array([
+        sum((candidates[j][rng.choice(3, p=[0.6, 0.2, 0.2])] for j in range(nbs)), [])
+        for _ in range(B)
+    ], np.int32)
+    lengths = [int(n) for n in rng.integers(0, nb * BS, B)]
+    lengths[0] = nb * BS - 1  # a row whose every block is full
+    for r in range(0, B, 2):
+        tables[r, lengths[r] // BS + 1:] = nblk
+    full = [(n + 1) // BLOCK for n in lengths]
+    blocks = tables.reshape(B, nbs, P16)
+    want = []
+    for r in range(B):
+        runs = [0]
+        for o in range(B):
+            same = [bool((blocks[r, j] == blocks[o, j]).all())
+                    for j in range(min(full[r], full[o]))]
+            runs.append((same + [False]).index(False) if o != r else 0)
+        want.append(max(runs))
+    skip, _, members = shared_check(jnp.float32, lengths, tables, wide=True)
+    assert skip == want and sum(want) > 0
+    assert all(len(m) > 1 for m in members)
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_without_sharing_the_kernel_is_the_group_free_kernel(dtype):
+    """Tables in which no two rows hold one page: the shared list is empty
+    and the output is bit-equal to the kernel traced without a shared pass
+    (a window as long as the tables span attends the same keys)."""
+    lengths = [2 * BLOCK + 37, 5, BLOCK + 3, SPAN - 1]
+    tables = tables_for(lengths, seed=8)
+    skip, items, _ = work_list(tables, lengths)
+    assert skip == [0] * 4 and not items
+    q, pool_k, pool_v = operands(dtype, 8, 2, 4)
+    args = (q, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32))
+    got = paged_decode_attention(*args, SCALE, interpret=True)
+    free = paged_decode_attention(*args, SCALE, interpret=True, window=SPAN)
+    assert np.array_equal(np.asarray(got), np.asarray(free))
+    assert np.abs(np.asarray(got, np.float32)).max() > 0.01
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+def test_a_window_layer_does_not_share(dtype):
+    """With a window the scalars are the eight they were (no shared list,
+    every block in the (row, block) list) and rows that hold the same
+    pages each read them: the output is that of rows holding copies."""
+    lengths = [600, 639, 530]
+    window = 2 * BLOCK
+    shared = share_tables([(2 * P16, range(3))], lengths, seed=5)
+    apart = shared.copy()
+    q, pool_k, pool_v = operands(dtype, 8, 2)
+    spare = [b for b in range(NBLK) if b not in set(shared.ravel().tolist())]
+    for r in (1, 2):  # rows 1 and 2 get copies of the head's pages
+        mine = [spare.pop() for _ in range(2 * P16)]
+        pool_k = pool_k.at[jnp.asarray(mine)].set(pool_k[shared[0, :2 * P16]])
+        pool_v = pool_v.at[jnp.asarray(mine)].set(pool_v[shared[0, :2 * P16]])
+        apart[r, :2 * P16] = mine
+    lens = jnp.asarray(lengths, jnp.int32)
+    scalars = paged_attention._work_list(
+        jnp.asarray(shared), lens, NBLK, BS, P16, window)
+    assert len(scalars) == 8
+    n_pages, n_items = np.asarray(scalars[1]), int(np.asarray(scalars[5])[0])
+    assert n_items == sum(-(-int(n) // P16) for n in n_pages)
+    run = lambda t: np.asarray(paged_decode_attention(
+        q, pool_k, pool_v, jnp.asarray(t), lens, SCALE, interpret=True,
+        window=window))
+    assert np.array_equal(run(shared), run(apart))
+    # and without a window the same rows do share, to the same numbers
+    both = [np.asarray(paged_decode_attention(
+        q, pool_k, pool_v, jnp.asarray(t), lens, SCALE, interpret=True), np.float32)
+        for t in (shared, apart)]
+    assert work_list(shared, lengths)[0] == [2, 2, 2]
+    assert work_list(apart, lengths)[0] == [0, 0, 0]
+    assert np.abs(both[0] - both[1]).max() <= TOL[dtype]
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+@pytest.mark.parametrize("H,KV", [(8, 4), (8, 8), (4, 1)], ids=["rep2", "rep1", "one_kv_head"])
+def test_a_shared_block_by_the_heads_a_word_holds(dtype, H, KV):
+    """A shared block is read by the KV heads one 32-bit row of a page
+    holds (one of float32, two of bfloat16): several such reads a block
+    at four and eight KV heads, a query group of two and of one, and the
+    whole page at once where there is one KV head."""
+    lengths = [600, 639, 530, 40]
+    tables = share_tables([(2 * P16, range(3))], lengths, seed=H + KV)
+    check(dtype, H, KV, lengths, tables)
+    assert work_list(tables, lengths)[0] == [2, 2, 2, 0]
+
+
+@pytest.mark.parametrize("dtype,shares", [
+    (jnp.float32, True), (jnp.bfloat16, False), (jnp.float16, False),
+], ids=["f32", "bf16", "f16"])
+def test_heads_the_kernel_cannot_separate_do_not_share(dtype, shares):
+    """Three KV heads: float32 pages separate by strided rows and share;
+    bfloat16 heads do not fill 32-bit words and float16 is not read by
+    words, so those pools take the (row, block) items alone (six scalars)
+    and rows that hold one head each read it, to the same numbers."""
+    lengths = [600, 639, 530]
+    tables = share_tables([(2 * P16, range(3))], lengths, seed=9)
+    pool = jax.ShapeDtypeStruct((NBLK, BS, 3, DH), dtype)
+    assert paged_attention.decode_shares(pool) is shares
+    assert not paged_attention.decode_shares(pool, window=BLOCK)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(k3, (3, 6, DH), dtype)
+    pool_k, pool_v = (jax.random.normal(k, pool.shape, dtype) for k in (k1, k2))
+    args = (q, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32))
+    got = np.asarray(paged_decode_attention(*args, SCALE, interpret=True), np.float32)
+    want = np.asarray(dense_reference(*args), np.float32)
+    assert np.abs(got - want).max() <= TOL.get(dtype, 2e-2)
+
+
+def test_the_shared_pass_asks_for_the_vmem_its_rows_state_takes():
+    """Every row's waiting (max, sum, accumulator) is scratch that grows
+    with the rows: within what a kernel gets unasked at the serve cells'
+    32 rows, past it at 256, where the call asks for what it holds
+    (compiled for a v5e at both: `tests/test_aot_topology.py`)."""
+    ask = lambda B: paged_attention._shared_vmem_limit(B, 32, 128, 2048, 2)
+    assert ask(32) == 16 << 20
+    state = 32 * (128 * 4 + 2 * 512)  # a row: the max and the sum a lane tile a head
+    assert ask(512) - ask(256) == 256 * (state + 2 * 32 * 128 * 2)  # and q, o
+    assert 16 << 20 < ask(256) < 32 << 20
+
+
+def test_the_predicate_counts_the_shared_list_s_scalars():
+    """The shared list rides in scalar memory beside the (row, block)
+    list: tables that fit with it take the kernel, wider ones the gather
+    path (and a window layer, which has no shared list, still fits)."""
+    fits = _facts(1, 128, 16, 8, jnp.bfloat16, 144, 1024)
+    assert paged_kernel(*fits) == "decode"
+    over = _facts(1, 128, 16, 8, jnp.bfloat16, 152, 1024)
+    assert paged_kernel(*over) is None
+    assert paged_kernel(*over[:3], 512) == "decode"
+
+
 # -- the chunk kernel: L query tokens a row ---------------------------------
 
 

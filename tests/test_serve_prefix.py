@@ -620,3 +620,129 @@ class TestPrefixMetrics:
         pc = eng.metrics.snapshot()["prefix_cache"]
         assert pc["hits"] == 0 and pc["misses"] == 0
         assert pc["cow_copies"] == 0 and pc["bytes_deduplicated"] == 0
+
+
+class TestSharedDecode:
+    """Several rows decoding TOGETHER over one head, at a head size the
+    decode kernel takes (Dh = 128, float32, interpreted here): the kernel
+    reads the head's whole compute block once for the group
+    (`ops/paged_attention.py::shared_runs`), and nothing a request sees
+    changes. The benchmark's check cannot see this case (its one row
+    attaches to a retired request's blocks: a group of one)."""
+
+    HEAD, BS = 272, 16  # 17 pages: one whole 256-key block and a page
+
+    @staticmethod
+    def _wide_model():
+        import jax
+        import jax.numpy as jnp
+
+        from pytorch_distributed_example_tpu.models import (
+            TransformerConfig,
+            TransformerLM,
+        )
+
+        cfg = TransformerConfig(
+            vocab_size=64, d_model=512, n_layers=2, n_heads=4,
+            n_kv_heads=2, d_ff=64, max_seq_len=320, use_flash=False,
+        )
+        model = TransformerLM(cfg)
+        return model, model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
+        )
+
+    def _serve(self, model, params, prompts, prefix_cache, between=None):
+        """The eight requests through one engine: the first alone until it
+        decodes (its head is indexed by then), then the other seven, so
+        all eight decode together. Returns (tokens a request, logits a
+        (request, position), the engine, every decode call's record)."""
+        import jax
+
+        from pytorch_distributed_example_tpu.serve import ServeEngine
+
+        eng = ServeEngine(
+            model, params, slots=8, min_bucket=16, block_size=self.BS,
+            prefill_chunk_tokens=64, prefix_cache=prefix_cache,
+        )
+        assert eng.metrics.snapshot()["decode"]["layer_paths"]
+        logits_of = jax.jit(lambda params, tree, tokens, lengths, bt: model.apply(
+            {"params": params, "cache": tree}, tokens[:, None], decode=True,
+            positions=lengths, block_tables=bt, mutable=["cache"],
+        )[0][:, -1])
+        program, seen = eng._step, {}
+
+        def probe(params, tree, lengths, tokens, rngs, bt):
+            # the step's operands through every layer's `_decode_paged`,
+            # before the step takes (and donates) them
+            rows = np.asarray(logits_of(params, tree, tokens, lengths, bt))
+            at = np.asarray(lengths)
+            for s in eng._decoding:
+                seen[(eng._slot_req[s].rid, int(at[s]))] = rows[s]
+            return program(params, tree, lengths, tokens, rngs, bt)
+
+        eng._step = probe
+        rids = [eng.submit(prompts[0], 16)]
+        records = []
+        for _ in range(200):
+            eng.step()
+            if eng.metrics.decode_steps:
+                break
+        rids += [eng.submit(p, 6) for p in prompts[1:]]
+        for _ in range(400):
+            busy = eng.step()
+            if eng.last_step.decode_keys:
+                records.append(eng.last_step)
+                if between is not None:
+                    between(eng, rids)
+            if not busy:
+                break
+        assert eng.metrics.completed == len(prompts)
+        done = eng.completions
+        tokens = [list(map(int, done[r].tokens)) for r in rids]
+        # by the request's place among the eight: an engine names its own
+        seen = {(rids.index(r), at): row for (r, at), row in seen.items()}
+        return tokens, seen, eng, records
+
+    def test_eight_rows_over_one_head_decode_as_without_sharing(self, no_fault_plan):
+        model, params = self._wide_model()
+        _, prompts = _preamble_prompts(self.HEAD, [3, 9, 20, 5, 14, 7, 11, 17], seed=3)
+        cowed = {}
+
+        def cow_one(eng, rids):
+            """Once all eight decode together: request 3 takes a private
+            copy of the head's first page (what a write into it would
+            force), and leaves the group for that block."""
+            from pytorch_distributed_example_tpu.ops import paged_attention
+
+            if cowed or len(eng._decoding) < 8:
+                return
+            slot = next(s for s in eng._decoding if eng._slot_req[s].rid == rids[3])
+            tables = lambda: eng.cache.tables()
+            shared = lambda: paged_attention.shared_decode_keys(
+                tables(), eng.cache.lengths, eng.cache.invalid_block, self.BS)
+            before, page = shared(), int(tables()[slot, 0])
+            assert eng.cache.cow_block(slot, 5)
+            assert int(tables()[slot, 0]) != page
+            cowed.update(before=before, after=shared(), slot=slot)
+
+        shared_tokens, shared_logits, eng, records = self._serve(
+            model, params, prompts, True, between=cow_one)
+        plain_tokens, plain_logits, plain, plain_records = self._serve(
+            model, params, prompts, False)
+        assert eng.metrics.snapshot()["decode"]["kernel_share"] == 1.0
+        assert shared_tokens == plain_tokens
+        # the other seven attached the head: its whole block is theirs and
+        # the first request's at once, read once a step for all eight
+        assert eng.metrics.snapshot()["prefix_cache"]["hits"] == 7
+        assert max(r.decode_shared_keys for r in records) == 8 * 256
+        assert cowed["before"] == 8 * 256 and cowed["after"] == 7 * 256
+        assert all(r.decode_shared_keys == 0 for r in plain_records)
+        both = sorted(set(shared_logits) & set(plain_logits))
+        assert len(both) >= sum(map(len, shared_tokens)) - 16
+        worst = max(
+            np.abs(shared_logits[k] - plain_logits[k]).max() for k in both
+        )
+        assert worst <= 2e-5, worst
+        # steps after the copy: seven rows share, the eighth reads its own
+        after = [r for r in records if r.decode_shared_keys == 7 * 256]
+        assert after and eng.cache.cow_copies >= 1

@@ -521,6 +521,93 @@ def test_a_prefix_hit_is_in_the_admission_s_record_and_stamp(built):
     assert [c[1] for r in second for c in r.chunks][0] == 16  # prefill starts behind it
 
 
+# --- the keys a decode step reads once for several rows -------------------------------------
+
+def wide(built):
+    """A float32 model at a head size the decode kernel takes (Dh = 128)."""
+    if "wide" not in built:
+        import jax
+        import jax.numpy as jnp
+
+        from pytorch_distributed_example_tpu.models import TransformerConfig, TransformerLM
+
+        model = TransformerLM(TransformerConfig(
+            vocab_size=64, d_model=512, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=64,
+            max_seq_len=320, use_flash=False))
+        built["wide"] = model, model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+    return built["wide"]
+
+
+@pytest.mark.parametrize("prefix_cache", [True, False], ids=["prefix_cache", "no_prefix_cache"])
+def test_the_record_counts_the_keys_the_kernel_reads_once_for_several_rows(
+        built, tmp_path, prefix_cache):
+    """Four requests behind one 272-token head (a whole 256-key compute
+    block and a page) decode together: `decode_shared_keys` of every call is
+    what the kernel's own work list shares for the tables the step was
+    handed (one rule, `ops.paged_attention.shared_runs`), it rides on
+    `serve:decode_step`, and `/serve` gives its share of the keys attended.
+    Without `prefix_cache` no two rows hold one block: the device's shared
+    list is empty and the count 0."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_example_tpu.ops import paged_attention
+    from pytorch_distributed_example_tpu.serve import ServeEngine
+
+    model, variables = wide(built)
+    engine = ServeEngine(model, variables, slots=4, min_bucket=16, block_size=16,
+                         prefill_chunk_tokens=64, prefix_cache=prefix_cache)
+    assert engine._decode_kernel
+    gen = np.random.default_rng(9)
+    head = gen.integers(0, 64, (272,)).astype(np.int32)
+    prompts = [np.concatenate([head, gen.integers(0, 64, (n,)).astype(np.int32)])
+               for n in (4, 11, 7, 18)]
+    program, handed = engine._step, []
+
+    def keeping(params, tree, lengths, tokens, rngs, bt):
+        handed.append((np.array(bt), np.array(lengths)))
+        return program(params, tree, lengths, tokens, rngs, bt)
+
+    engine._step = keeping
+    records = []
+    traced(tmp_path)
+    try:
+        engine.submit(prompts[0], 12)
+        while not engine.metrics.decode_steps:  # its head is indexed by then
+            engine.step()
+            records.append(engine.last_step)
+        for p in prompts[1:]:
+            engine.submit(p, 6)
+        records += drive(engine)
+    finally:
+        jax.profiler.stop_trace()
+    records = [r for r in records if r.decode_keys]
+    assert len(handed) == len(records) == engine.metrics.decode_steps
+    nblk, P = engine.cache.invalid_block, 16
+    listed = []  # (keys the device's list shares, shared items) a step
+    for tables, lengths in handed:
+        scalars = paged_attention._work_list(
+            jnp.asarray(tables), jnp.asarray(lengths), nblk, 16, P, share=True)
+        skip, n_shared = np.asarray(scalars[6]), int(np.asarray(scalars[9])[0])
+        listed.append((int(skip.sum()) * P * 16, n_shared))
+    assert [r.decode_shared_keys for r in records] == [keys for keys, _ in listed]
+    spans = [a for name, _, _, a in host_events(tmp_path) if name == "serve:decode_step"]
+    assert [(a["rows"], a["shared"]) for a in spans] == [
+        (r.decode_rows, r.decode_shared_keys) for r in records]
+    decode = engine.metrics.snapshot()["decode"]
+    if prefix_cache:
+        assert max(r.decode_shared_keys for r in records) == 4 * 256
+        assert all(n == (1 if keys else 0) for keys, n in listed)
+        assert 0.5 < decode["shared_key_share"] < 256 / 272
+    else:
+        assert all(r.decode_shared_keys == 0 for r in records)
+        assert all(n == 0 for _, n in listed)
+        assert decode["shared_key_share"] == 0.0
+    assert engine.metrics.decode_keys == sum(sum(r.decode_keys) for r in records)
+    assert decode["shared_key_share"] == round(
+        sum(r.decode_shared_keys for r in records) / engine.metrics.decode_keys, 4)
+
+
 # --- what an operator reads ---------------------------------------------------------------
 
 def test_the_serve_page_shows_the_host_s_share_of_a_call(built):
