@@ -650,28 +650,40 @@ def _serve_traffic(engine, sv, vocab, lens, new_tokens, shared_prefix):
     return len(want)
 
 
-def _latent_serve(lm, sv):
+def _latent_serve(lm, sv, streams=False):
     """A toy latent-attention model (two layers, sandwich norms, a dense
     and a sigmoid-routed sparse MLP) through ServeEngine: a prompt that ends
     inside a bucket prefilled in chunks over the latent paged cache, then
     decoded; the first token's logits and every decoded token against the
     cache-free forward, which up-projects keys and values where the cached
     paths run absorbed. A broken latent kernel shows here, before a 13 GB
-    model is built around it."""
+    model is built around it.
+
+    With `streams` the blocks carry FOUR residual streams (hyper-connection
+    maps around each sublayer), the router chooses with a bias, the rope is
+    YaRN's with its factor on the softmax scale, and the engine shares
+    prefixes: a request before the checked one prefills and indexes the
+    prompt's head (it ends inside a block), the checked prompt attaches it,
+    copies that block at its first write and prefills its own tail. A broken
+    map or attach shows here, before an 11 GB model is built."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from pytorch_distributed_example_tpu.models.transformer import (
-        LayerSpec, TransformerConfig, TransformerLM,
+        LayerSpec, RopeSpec, TransformerConfig, TransformerLM,
     )
 
     la = sv["latent"]
+    rope, extra = None, dict(sandwich_norm=True)
+    if streams:
+        rope = RopeSpec(10000.0, yarn=(8.0, 64, 32.0, 1.0, 1.0), softmax_factor=1.46)
+        extra = dict(hc_mult=4, sparse_choice_bias=True)
     cfg = TransformerConfig(
         vocab_size=lm["vocab"], d_model=la["d_model"], n_layers=2,
         n_heads=la["n_heads"], d_ff=2 * la["d_model"], max_seq_len=sv["max_seq_len"],
-        dtype=jnp.bfloat16, use_flash=False, sandwich_norm=True,
-        layers=(LayerSpec("latent"), LayerSpec("latent", mlp="sparse")),
+        dtype=jnp.bfloat16, use_flash=False, **extra,
+        layers=(LayerSpec("latent", rope=rope), LayerSpec("latent", rope=rope, mlp="sparse")),
         latent_q_rank=la["q_rank"], latent_kv_rank=la["kv_rank"],
         latent_nope_dim=la["nope"], latent_rope_dim=la["rope"], latent_v_dim=la["v"],
         sparse_score="sigmoid", sparse_experts=8, sparse_top_k=2,
@@ -681,7 +693,7 @@ def _latent_serve(lm, sv):
     params = jax.jit(lambda key: jax.tree_util.tree_map(
         lambda x: x.astype(jnp.bfloat16),
         model.init(key, jnp.zeros((1, 8), jnp.int32))))(jax.random.PRNGKey(3))["params"]
-    engine = _serve_engine(model, params, sv)
+    engine = _serve_engine(model, params, sv, prefix_cache=streams)
     probe = engine._prefill_chunk = _PrefillProbe(engine._prefill_chunk)
     paths = (engine.metrics.decode_layer_paths["latent"][1],
              engine.metrics.prefill_layer_paths["latent"][1])
@@ -691,9 +703,19 @@ def _latent_serve(lm, sv):
     toks = np.random.default_rng(5).integers(
         0, lm["vocab"], (la["prompt_len"],)).astype(np.int32)
     n_new = la["new_tokens"]
+    head = 2 * len(toks) // 3 // sv["block_size"] * sv["block_size"] + sv["block_size"] // 2
+    if streams:
+        holder = np.concatenate([toks[:head], (toks[head:head + 9] + 1) % lm["vocab"]])
+        engine.submit(holder, 2, rid="holder")
+        engine.run(max_steps=2000)
     rid = engine.submit(toks, n_new, rid="latent")
     done = engine.run(max_steps=2000)
     _check_completions(done, {rid: n_new})
+    if streams:
+        reused = engine.prefix.stats()["prefix_tokens_reused"]
+        _check(reused == head and engine.cache.cow_copies >= 1,
+               f"the prompt attached {reused} of its head's {head} tokens "
+               f"({engine.cache.cow_copies} blocks copied on write)")
     seq = np.concatenate([toks, np.asarray(done[rid].tokens[:-1], np.int32)])
     want = np.asarray(jax.jit(lambda p, t: model.apply({"params": p}, t))(
         params, jnp.asarray(seq)[None])[0, -n_new:], np.float32)
@@ -701,7 +723,8 @@ def _latent_serve(lm, sv):
     err = _rel_err(chunk_logits[(len(toks) - 1) - start], want[0])
     picked = want[np.arange(n_new), done[rid].tokens]
     gap = float(((want.max(axis=1) - picked) / np.abs(want).max()).max())
-    print(f"  latent layers ({paths[0]}, {paths[1]}): first-token logits rel err "
+    what = f"four streams, {head} tokens attached; " if streams else ""
+    print(f"  latent layers ({what}{paths[0]}, {paths[1]}): first-token logits rel err "
           f"{err:.2e}, decoded tokens within {gap:.2e} of the forward's best logit")
     _check(err <= 1e-1 and gap <= 1e-1,
            f"the latent cached paths disagree with the forward: {err}, {gap}")
@@ -713,7 +736,8 @@ def phase_serve(preset):
     """ServeEngine on the full-width bf16 model: paged cache, chunked
     prefill, prefix sharing; first-token logits against a plain
     full-sequence model.apply; a toy latent-attention model through the
-    same engine (`_latent_serve`); then int8 KV for completion."""
+    same engine (`_latent_serve`), and one with four residual streams whose
+    prompt attaches a shared head; then int8 KV for completion."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -770,7 +794,7 @@ def phase_serve(preset):
                     jax.devices()[:1])
     del engine, probe
     gc.collect()
-    latent_err = _latent_serve(lm, sv)
+    latent_err = max(_latent_serve(lm, sv), _latent_serve(lm, sv, streams=True))
 
     t0 = time.perf_counter()
     engine = _serve_engine(model, params, sv, kv_quant=True)

@@ -42,11 +42,15 @@ class RopeSpec:
     rotates (the rest passes unrotated; 0.0 rotates nothing: a model
     whose attention takes no position), and YaRN's five numbers
     (factor, original_max_position_embeddings, beta_fast, beta_slow,
-    attention_factor) where the frequencies are stretched."""
+    attention_factor) where the frequencies are stretched. `softmax_factor`
+    multiplies a LATENT layer's softmax scale: the family that stretches a
+    latent layer's rope puts YaRN's factor there, squared
+    ((0.1 mscale_all_dim ln factor + 1)^2), and not on cos and sin."""
 
     theta: float = 10000.0
     rotary_fraction: float = 1.0
     yarn: Optional[Tuple[float, int, float, float, float]] = None
+    softmax_factor: float = 1.0
 
 
 # what a layer's mixer keeps between calls: every key and value, those of
@@ -56,6 +60,9 @@ CACHE_KINDS = ("full", "window", "linear", "latent")
 # (`gated_delta_chunked`): a power of two that divides every prefill
 # bucket of the serve cells (128, 256, 512)
 LINEAR_CHUNK = 64
+# normalisation pairs of a hyper-connection map written out a trip of its
+# loop (`HyperConnection._sinkhorn`)
+SINKHORN_UNROLL = 5
 
 
 @dataclass(frozen=True)
@@ -147,6 +154,21 @@ class TransformerConfig:
     latent_nope_dim: int = 0
     latent_rope_dim: int = 0
     latent_v_dim: int = 0
+    # the router of the "sparse" MLP CHOOSES its top k by score plus a
+    # learned bias an expert; the weights are the scores without it
+    sparse_choice_bias: bool = False
+    # residual streams of a pattern's blocks (`HyperConnection`): 1 is the
+    # one residual add; above it a token's state between sublayers is
+    # (`hc_mult`, d_model) (the blocks pass (`hc_mult`, B, L, d_model)),
+    # each sublayer reads a learned mixture of the
+    # streams and writes back through a second map while a doubly
+    # stochastic matrix (`hc_sinkhorn_iters` row and column normalisations
+    # of exp of logits clipped to `hc_clamp`, `hc_eps` in every
+    # denominator) remixes them
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     def __post_init__(self):
         if self.rope_pairs not in ("interleaved", "halves"):
@@ -155,6 +177,10 @@ class TransformerConfig:
             raise ValueError(f"sparse_score {self.sparse_score!r}")
         if self.post_norm and self.sandwich_norm:
             raise ValueError("post_norm and sandwich_norm are two placements")
+        if self.hc_mult < 1 or (self.hc_mult > 1 and self.layers is None):
+            raise ValueError(
+                f"hc_mult {self.hc_mult}: residual streams belong to a layer pattern"
+            )
         if self.layers is None:
             return
         if len(self.layers) != self.n_layers:
@@ -780,6 +806,7 @@ class LatentAttention(nn.Module):
         q_rope = RoPE(q_rope);  k_rope = RoPE(k_r), ONE for all heads
         a head: k_nope = c_kv W_uk, v = c_kv W_uv, W_kvb = [W_uk; W_uv]
         s(i, j) = (q_nope_i . k_nope_j + q_rope_i . k_rope_j) / sqrt(dn + dr)
+        (times the layer's `RopeSpec.softmax_factor`, 1 without YaRN)
         o_i = sum_j softmax_j(s)(i, j) v_j;  y = concat(o) W_o
 
     What it keeps between calls is ONE row of r + dr values a token:
@@ -819,7 +846,7 @@ class LatentAttention(nn.Module):
         B, L, _ = x.shape
         H, r = self.spec.n_heads, cfg.latent_kv_rank
         dn, dr, dv = cfg.latent_nope_dim, cfg.latent_rope_dim, cfg.latent_v_dim
-        scale = 1.0 / ((dn + dr) ** 0.5)
+        scale = 1.0 / ((dn + dr) ** 0.5) * self.spec.rope.softmax_factor
         dense = lambda feats, name: nn.Dense(
             feats, use_bias=False, dtype=cfg.dtype, name=name
         )
@@ -1338,7 +1365,9 @@ class SparseMoE(nn.Module):
     capacity), plus one shared SwiGLU expert added unweighted. Experts
     are stacked (held, D, F) x 2 and (held, F, D), `held` the contiguous
     range `cfg.experts_held` gives this chip (all of them by default);
-    the router keeps its full width.
+    the router keeps its full width. With `cfg.sparse_choice_bias` the
+    layer owns `router_bias` (E,), added to the scores for the CHOICE of
+    the top k and not for their weights.
 
     `row_mask` ((B, L) bool) marks the rows that are real tokens: the
     others (a parked lane of the serve step, the padding of a prefill
@@ -1363,6 +1392,11 @@ class SparseMoE(nn.Module):
         w_gate = self.param("experts_gate", init, (held, D, F))
         w_up = self.param("experts_up", init, (held, D, F))
         w_down = self.param("experts_down", init, (held, F, D))
+        bias = None
+        if cfg.sparse_choice_bias:
+            # N(0, 0.05): beside sigmoid scores of seeded weights it changes
+            # a few per cent of the choices
+            bias = self.param("router_bias", nn.initializers.normal(0.05), (E,))
         with jax.named_scope("moe"):
             y, stats, chosen = dropless_moe(
                 x.reshape(B * L, D).astype(cfg.dtype), router,
@@ -1371,6 +1405,7 @@ class SparseMoE(nn.Module):
                 n_experts=E, top_k=cfg.sparse_top_k, scale=cfg.routed_scale,
                 first_expert=first, score=cfg.sparse_score,
                 row_mask=None if row_mask is None else row_mask.reshape(B * L),
+                choice_bias=bias,
             )
             self.sow("intermediates", "moe_stats", stats)
             self.sow("intermediates", "moe_chosen", chosen.reshape(B, L, -1))
@@ -1378,6 +1413,146 @@ class SparseMoE(nn.Module):
             if cfg.shared_d_ff:
                 y = y + MLP(cfg, cfg.shared_d_ff, name="shared_expert")(x)
         return y
+
+
+class HyperConnection(nn.Module):
+    """The maps of one sublayer's residual streams: manifold-constrained
+    hyper-connections (mHC, arXiv:2512.24880, on Hyper-Connections,
+    arXiv:2409.19606). A token's state X is (n, C), n = `cfg.hc_mult`; the
+    model carries the streams as the LEADING axis, (n, B, L, C), so that a
+    stream is a contiguous plane. All in float32, whatever `cfg.dtype`:
+
+        x = vec(X) (nC);  rho = (mean(x^2) + norm_eps)^-1/2;  m = rho * (x Phi)
+        h_pre  = sigmoid(a1 m[0:n] + b[0:n]) + hc_eps           (n)
+        h_post = 2 sigmoid(a2 m[n:2n] + b[n:2n])                (n)
+        A = clip(a3 m[2n:] + b[2n:], hc_clamp) as n x n;  M = exp(A);
+        `hc_sinkhorn_iters` times: M /= rowsum(M) + hc_eps, then
+        M /= colsum(M) + hc_eps;  H_res = M  (doubly stochastic)
+        pre:  u = sum_i h_pre[i] X[i]  (C, in X's dtype): the sublayer's input
+        post: X'[i] = h_post[i] y + sum_j H_res[i, j] X[j]
+
+    Phi (nC, 2n + n^2) ~ N(0, 1/(nC)), a = 1, b = 0: m is O(1) and every
+    map depends on the token. With `collapse` the module is the END of the
+    streams: Phi (nC, n), one a, and `pre` gives x_out = sum_i h_out[i] X[i]
+    with h_out as h_pre is; nothing is written back.
+
+    A map is a function of its own token's streams and of nothing else: a
+    chunk's padding and a step's parked rows pass through and touch no
+    real row."""
+
+    cfg: TransformerConfig
+    collapse: bool = False
+
+    @nn.compact
+    def pre(self, X):
+        """X (n, ..., C), the streams LEADING -> (u (..., C), h_post
+        (n, ...), H_res (n, n, ...)); with `collapse` the last two are
+        None."""
+        cfg = self.cfg
+        n, C = X.shape[0], X.shape[-1]
+        lead = X.shape[1:-1]
+        kinds = 1 if self.collapse else 3
+        width = n if self.collapse else 2 * n + n * n
+        phi = self.param(
+            "phi", nn.initializers.normal((n * C) ** -0.5), (n * C, width)
+        )
+        alpha = self.param("alpha", nn.initializers.ones, (kinds,))
+        bias = self.param("bias", nn.initializers.zeros, (width,))
+        with jax.named_scope("hc_pre"):
+            x = X.reshape(n, -1, C)  # (n, T, C)
+            with jax.named_scope("hc_mix"):
+                x32 = x.astype(jnp.float32)
+                rho = jax.lax.rsqrt(
+                    jnp.sum(x32 * x32, axis=(0, 2)) / (n * C) + cfg.norm_eps
+                )  # (T,)
+                m = rho[:, None] * self._product(
+                    x, phi.astype(jnp.float32).reshape(n, C, width)
+                )
+                a = jnp.concatenate([
+                    jnp.broadcast_to(alpha[k].astype(jnp.float32), (size,))
+                    for k, size in enumerate((n, n, n * n)[:kinds])
+                ])
+                # tokens last: the maps are a few values a token, and the
+                # chain below is then elementwise over whole rows of tokens
+                m = (a * m + bias.astype(jnp.float32)).T  # (width, T)
+            h_pre = jax.nn.sigmoid(m[:n]) + cfg.hc_eps
+            # a weighted sum of n streams, elementwise: no product of 4s
+            u = sum(
+                h_pre[i][:, None] * x[i].astype(jnp.float32) for i in range(n)
+            ).astype(X.dtype).reshape(*lead, C)
+            if self.collapse:
+                return u, None, None
+            h_post = 2.0 * jax.nn.sigmoid(m[n:2 * n])
+            with jax.named_scope("hc_sinkhorn"):
+                A = jnp.exp(jnp.clip(m[2 * n:], *cfg.hc_clamp))
+                h_res = self._sinkhorn(
+                    tuple(A[k] for k in range(n * n)), n, cfg.hc_sinkhorn_iters,
+                    cfg.hc_eps,
+                ).reshape(n, n, -1)
+        return u, h_post.reshape(n, *lead), h_res.reshape(n, n, *lead)
+
+    @staticmethod
+    def _sinkhorn(M, n, iters, eps):
+        """`iters` times: rows of the n x n matrix over their sums, then
+        columns over theirs. M is n * n rows of tokens, and every sum is
+        written out, so a normalisation is elementwise over whole rows of
+        tokens and the compiler fuses a run of them into one operation
+        (a `sum` over an axis makes each a small reduction of its own: 80
+        operations a map at a microsecond or two each, a twelfth of a
+        decode step). `SINKHORN_UNROLL` normalisation pairs are written
+        out a trip of a loop: all of them in one body is five times the
+        program to compile for a few microseconds a map."""
+        def pairs(M, count):
+            M = list(M)
+            for _ in range(count):
+                for axis in (0, 1):  # rows, then columns
+                    lines = [
+                        [i * n + j if axis == 0 else j * n + i for j in range(n)]
+                        for i in range(n)
+                    ]
+                    for line in lines:
+                        total = sum(M[k] for k in line) + eps
+                        for k in line:
+                            M[k] = M[k] / total
+            return tuple(M)
+
+        trips, rest = divmod(iters, SINKHORN_UNROLL)
+        M = jax.lax.fori_loop(
+            0, trips, lambda _, M: pairs(M, SINKHORN_UNROLL), tuple(M)
+        )
+        return jnp.stack(pairs(M, rest))
+
+    @staticmethod
+    def _product(x, phi):
+        """x (n, T, C) against the float32 phi (n, C, width), contracted
+        over streams and features, at float32's precision. A bfloat16 x is
+        exact in three bfloat16 pieces of phi (high, middle and low bits,
+        side by side in ONE product that accumulates in float32): the
+        streams are read as they are held, not from a float32 copy."""
+        if x.dtype != jnp.bfloat16:
+            return jnp.einsum(
+                "ntc,ncw->tw", x.astype(jnp.float32), phi,
+                precision=jax.lax.Precision.HIGHEST,
+            )
+        pieces, rest = [], phi
+        for _ in range(3):
+            pieces.append(rest.astype(jnp.bfloat16))
+            rest = rest - pieces[-1].astype(jnp.float32)
+        wide = jnp.einsum(
+            "ntc,ncw->tw", x, jnp.concatenate(pieces, axis=-1),
+            preferred_element_type=jnp.float32,
+        )
+        return sum(jnp.split(wide, 3, axis=1))
+
+    @staticmethod
+    def post(X, y, h_post, h_res):
+        """The sublayer's output y (..., C) written back into the remixed
+        streams X (n, ..., C), in X's dtype."""
+        with jax.named_scope("hc_post"):
+            out = h_post[..., None] * y.astype(jnp.float32)[None]
+            for j in range(X.shape[0]):
+                out = out + h_res[:, j][..., None] * X[j].astype(jnp.float32)[None]
+            return out.astype(X.dtype)
 
 
 class Block(nn.Module):
@@ -1403,26 +1578,42 @@ class Block(nn.Module):
                 name = name.replace("_norm", "_post_norm")
             return RMSNorm(cfg.norm_eps, name=name)(y) if post or sandwich else y
 
-        h = norm_in("attn_norm", x)
-        if self.spec is not None and self.spec.attention == "linear":
-            mixed = LinearAttention(cfg, name="linear_attn")(
-                h, decode, positions, block_tables, row_mask
-            )
-        elif self.spec is not None and self.spec.attention == "latent":
-            mixed = LatentAttention(cfg, self.spec, name="latent_attn")(
-                h, cos, sin, decode, positions, block_tables
-            )
-        else:
-            mixed = Attention(cfg, self.spec, name="attn")(
-                h, cos, sin, decode, positions, block_tables
-            )
-        x = x + norm_out("attn_norm", mixed)
+        def residual(name, x, sublayer):
+            """x + sublayer(x); with residual streams the sublayer reads a
+            mixture of them and its output is written back through the
+            maps of `HyperConnection`."""
+            if cfg.hc_mult == 1:
+                return x + sublayer(x)
+            hc = HyperConnection(cfg, name=name)
+            u, h_post, h_res = hc.pre(x)
+            return hc.post(x, sublayer(u), h_post, h_res)
+
+        def attention(u):
+            h = norm_in("attn_norm", u)
+            if self.spec is not None and self.spec.attention == "linear":
+                mixed = LinearAttention(cfg, name="linear_attn")(
+                    h, decode, positions, block_tables, row_mask
+                )
+            elif self.spec is not None and self.spec.attention == "latent":
+                mixed = LatentAttention(cfg, self.spec, name="latent_attn")(
+                    h, cos, sin, decode, positions, block_tables
+                )
+            else:
+                mixed = Attention(cfg, self.spec, name="attn")(
+                    h, cos, sin, decode, positions, block_tables
+                )
+            return norm_out("attn_norm", mixed)
+
+        def mlp(u):
+            h = norm_in("mlp_norm", u)
+            if self.spec is not None and self.spec.mlp == "sparse":
+                return norm_out("mlp_norm", SparseMoE(cfg, name="mlp")(h, row_mask))
+            mlp_cls = MoE if cfg.n_experts > 0 else MLP
+            return norm_out("mlp_norm", mlp_cls(cfg, name="mlp")(h))
+
+        x = residual("hc_attn", x, attention)
         x = checkpoint_name(x, _remat.BLOCK_MID)
-        h = norm_in("mlp_norm", x)
-        if self.spec is not None and self.spec.mlp == "sparse":
-            return x + norm_out("mlp_norm", SparseMoE(cfg, name="mlp")(h, row_mask))
-        mlp_cls = MoE if cfg.n_experts > 0 else MLP
-        return x + norm_out("mlp_norm", mlp_cls(cfg, name="mlp")(h))
+        return residual("hc_mlp", x, mlp)
 
 
 def _remat_block():
@@ -1513,6 +1704,8 @@ class TransformerLM(nn.Module):
         kinds = cfg.cache_kinds
         use_remat = cfg.remat and not decode
         block_cls = _remat_block() if use_remat else Block
+        if cfg.hc_mult > 1:  # the streams start as copies of the embedding
+            x = jnp.broadcast_to(x[None], (cfg.hc_mult, *x.shape))
         for i, spec in enumerate(specs):
             cos, sin = (latent_tables if latent(spec) else tables)[spec.rope]
             block = block_cls(cfg, spec, name=f"layers_{i}")
@@ -1523,6 +1716,8 @@ class TransformerLM(nn.Module):
             if paired:
                 bt = block_tables[kinds.index(spec.attention)]
             x = block(x, cos, sin, decode, positions, bt, row_mask)
+        if cfg.hc_mult > 1:  # and end in a learned mixture of the four
+            x, _, _ = HyperConnection(cfg, collapse=True, name="hc_out").pre(x)
         return self._head(x)
 
 

@@ -286,6 +286,7 @@ def dropless_moe(
     first_expert: int = 0,
     row_mask=None,
     score: str = "softmax",
+    choice_bias=None,
 ):
     """Dropless top-k MoE over gated (SwiGLU) experts.
 
@@ -294,7 +295,9 @@ def dropless_moe(
     `first_expert .. first_expert + held - 1`, the contiguous range this
     caller holds. Every row scores all `n_experts` in float32 (`score`:
     "softmax" over them, or "sigmoid" of each: no expert's score depends
-    on another's), takes its `top_k`, and weighs them by their scores
+    on another's), takes its `top_k` (with `choice_bias` ((n_experts,)) the
+    `top_k` largest of score + bias: the bias moves the CHOICE and no
+    weight), and weighs them by their scores
     normalised to sum to 1, times `scale`; the result is the part of
     sum_e w_e * SwiGLU_e(x) that the held experts give (all of it when
     all are held; the parts of disjoint ranges add up to the whole).
@@ -330,12 +333,17 @@ def dropless_moe(
             x.astype(jnp.float32), router_w.astype(jnp.float32),
             precision=lax.Precision.HIGHEST,
         )  # (T, n_experts)
+        def top(scores):  # (T, k) scores and experts
+            if choice_bias is None:
+                return lax.top_k(scores, top_k)
+            _, chosen = lax.top_k(scores + choice_bias.astype(jnp.float32), top_k)
+            return jnp.take_along_axis(scores, chosen, axis=-1), chosen
+
         if score == "sigmoid":
-            top_p, top_e = lax.top_k(jax.nn.sigmoid(logits), top_k)  # (T, k)
+            top_p, top_e = top(jax.nn.sigmoid(logits))
             weight = scale * top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
         else:
-            probs = jax.nn.softmax(logits, axis=-1)
-            top_p, top_e = lax.top_k(probs, top_k)  # (T, k)
+            top_p, top_e = top(jax.nn.softmax(logits, axis=-1))
             weight = scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     with jax.named_scope("dispatch"):
         local = top_e - first_expert

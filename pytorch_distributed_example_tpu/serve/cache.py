@@ -59,8 +59,10 @@ every layer that keeps every token), and `tables()` hands the programs
 that table under the kind's name. A row of more than 128 values is held
 in whole 128-value lane tiles (`ops.pool_latent_width`: 576 as 640, the
 bytes the device would pad it to anyway), and `latent_bytes_per_block`
-counts the rows as held. What is not carried for it: an int8 pool, tp, block migration,
-prefix sharing (`serve/engine.py` refuses them).
+counts the rows as held. A shared prefix's latent blocks are attached and
+copied on write with the rest of the tree (`cow_block` copies every leaf).
+What is not carried for it: an int8 pool, tp, block migration
+(`serve/engine.py` refuses them).
 
 Physical blocks are REFCOUNTED (ISSUE 12): `attach_prefix` lets a
 slot reference blocks another request already filled (the prefix

@@ -53,7 +53,10 @@ request's prompt up in a radix prefix index (`serve/prefix.py`) and
 ATTACHES the longest cached prefix's blocks (refcounted, `serve/
 cache.py::attach_prefix`) so chunked prefill starts at the first
 uncached position — skipping both the prefill compute and the pool
-writes for every hit. Prompt blocks are indexed at prefill completion
+writes for every hit (a request that matched nothing looks again before
+its first chunk, `_attach_late`: arrivals behind a head that another
+request is still prefilling wait their turn and attach it, where each
+would have computed it). Prompt blocks are indexed at prefill completion
 (pristine — decoded tokens are never indexed); divergence inside a
 shared or indexed block copies exactly that block (copy-on-write)
 before the write. Sharing crosses TENANTS only when the request's
@@ -87,8 +90,10 @@ from 0: no snapshot is kept), and the same five things are refused.
 A model with LATENT layers (multi-head latent attention) keeps one row
 of `latent_width` values a token in one pool a layer, under the full
 kind's tables, free lists and refcounts; its cached calls run absorbed
-(`models/transformer.py::LatentAttention`), and the same five things are
-refused.
+(`models/transformer.py::LatentAttention`). A shared prefix's latent
+blocks are attached, refcounted and copied on write as K/V blocks are
+(`cow_block` copies every leaf of the tree), so `prefix_cache=True` is
+carried; the other four are refused.
 
 What a call did (`StepRecord`, public as `engine.last_step` when the
 call returns): `step()` fills one small record as it goes, from values
@@ -201,10 +206,12 @@ class _Prefill:
     """A slot mid-prefill: `pos` is the next prompt position to chunk
     (nonzero when a prefix-cache attach covered the prompt head); the
     request is not decoding (its lane stays parked) until the last
-    chunk lands and `attach` seeds its state lanes."""
+    chunk lands and `attach` seeds its state lanes. `seen` is the prefix
+    index's insert count when the request last looked its prompt up."""
 
     req: Request
     pos: int = 0
+    seen: int = 0
 
 
 @dataclass
@@ -366,8 +373,6 @@ class ServeEngine:
             }
         if latent_layers_of(self.cfg):
             refusals["latent"] = {
-                "prefix_cache=True (a shared prefix's latent blocks under "
-                "copy-on-write are untested)": prefix_cache,
                 "kv_quant=True (a latent pool has no int8 form)": kv_quant,
                 "mesh= (a latent pool has no KV heads to partition over tp)":
                     mesh is not None,
@@ -813,7 +818,9 @@ class ServeEngine:
                 pos0 = matched
         self._slot_req[slot] = req
         self._slot_tokens[slot] = []
-        self._prefilling[slot] = _Prefill(req, pos=pos0)
+        self._prefilling[slot] = _Prefill(
+            req, pos=pos0, seen=self.prefix.inserts if self.prefix else 0
+        )
         self._reserved += self._worst_blocks(req)
         self.metrics.record_admit()
         # the request changes hands: queue -> slot. A replay's stamps are
@@ -913,6 +920,8 @@ class ServeEngine:
             pf = self._prefilling[slot]
             req = pf.req
             L = len(req.prompt)
+            if pf.pos == 0 and self.prefix and pf.seen != self.prefix.inserts:
+                self._attach_late(slot, pf)
             if budget is None:
                 # bucket over the REMAINING prompt: a prefix-cache
                 # attach starts the (single, unchunked) program at the
@@ -1015,6 +1024,31 @@ class ServeEngine:
                 self._decoding.add(slot)
             if budget is not None and spent >= budget:
                 return  # budget spent: yield to decode
+
+    def _attach_late(self, slot: int, pf: _Prefill) -> None:
+        """A request that found nothing in the prefix index at admission
+        looks again before its first chunk, if prompts were indexed since:
+        requests that arrive while ANOTHER request is still prefilling their
+        shared head all miss at admission (a cold index and a burst of
+        arrivals: every one of them would compute the whole head), and
+        shortest-remaining-first keeps them at position 0 until that request
+        has indexed it. The slot holds no block yet, so the attach is
+        admission's."""
+        pf.seen = self.prefix.inserts
+        if self.cache.slot_blocks(slot):
+            return
+        blocks, matched = self.prefix.match(
+            self._prefix_scope(pf.req), pf.req.prompt.tolist(), again=True
+        )
+        if not matched:
+            return
+        self.cache.attach_prefix(slot, blocks)
+        pf.pos = matched
+        self._rec.prefix_tokens_attached += matched
+        with jax.profiler.TraceAnnotation(
+            "serve:attached_late", slot=slot, attached=matched
+        ):
+            pass
 
     # -- pool pressure -----------------------------------------------------
     def _preempt_for_pool(self, slot: int) -> bool:

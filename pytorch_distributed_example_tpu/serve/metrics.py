@@ -222,6 +222,9 @@ class ServeMetrics:
         # those bytes are part of `pool_bytes_per_block`
         self.latent_blocks_live = 0
         self.latent_bytes_per_block = 0
+        # of the prefix plane's counters, the blocks that hold latent rows
+        self.prefix_latent_blocks_attached = 0
+        self.prefix_latent_blocks_copied = 0
         # (row, expert) pairs the routers chose, last step and in all: a
         # chip that holds a share of the experts computes that share of
         # them (`moe_assignments`)
@@ -487,6 +490,9 @@ class ServeMetrics:
             self.state_bytes_per_block = state_bytes_per_block
             self.latent_blocks_live = latent_blocks_live
             self.latent_bytes_per_block = latent_bytes_per_block
+            # every block of a model with a latent layer holds a row of it
+            if latent_bytes_per_block:
+                self.prefix_latent_blocks_copied = cow_copies
             self.pool_blocks_live = blocks_live
             self.pool_blocks_total = blocks_total
             self.pool_bytes_per_block = bytes_per_block
@@ -511,6 +517,10 @@ class ServeMetrics:
                     "blocks_attached"
                 ]
                 self.prefix_index_nodes = prefix_stats["nodes"]
+                if latent_bytes_per_block:
+                    self.prefix_latent_blocks_attached = prefix_stats[
+                        "blocks_attached"
+                    ]
             if blocks_total:
                 self._pool_util_sum += blocks_live / blocks_total
                 self._pool_samples += 1
@@ -934,6 +944,12 @@ class ServeMetrics:
                     "cached_blocks": self.prefix_cached_blocks,
                     "index_nodes": self.prefix_index_nodes,
                     "cow_copies": self.cow_copies,
+                    "prefix_latent_blocks_attached": (
+                        self.prefix_latent_blocks_attached
+                    ),
+                    "prefix_latent_blocks_copied": (
+                        self.prefix_latent_blocks_copied
+                    ),
                     "bytes_deduplicated": self.bytes_deduplicated,
                     "peak_bytes_deduplicated": (
                         self.peak_bytes_deduplicated

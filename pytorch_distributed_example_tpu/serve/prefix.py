@@ -120,13 +120,16 @@ class PrefixIndex:
 
     # -- lookup ------------------------------------------------------------
     def match(
-        self, scope: Hashable, tokens: Sequence[int]
+        self, scope: Hashable, tokens: Sequence[int], again: bool = False
     ) -> Tuple[List[int], int]:
         """Longest cached prefix of `tokens` within `scope`: (physical
         block ids in logical order, matched token count). Counts a hit
         (and the reuse stats) when at least one token matched; the
         caller attaches via `PagedKVCache.attach_prefix` and starts
-        prefill at the matched position."""
+        prefill at the matched position. `again`: the caller looked this
+        prompt up before and found nothing (that miss is counted): a
+        second miss is not counted, and a hit takes the miss back, so the
+        counters stay one a request."""
         cap = len(tokens) - 1  # the prompt-end logits row must be live
         children = self._roots.get(scope)
         blocks: List[int] = []
@@ -148,9 +151,10 @@ class PrefixIndex:
             children = best.children
         if matched > 0:
             self.hits += 1
+            self.misses -= again
             self.tokens_reused += matched
             self.blocks_attached += len(blocks)
-        else:
+        elif not again:
             self.misses += 1
         return blocks, matched
 

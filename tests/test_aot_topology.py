@@ -370,16 +370,25 @@ def test_paged_chunk_kernel_compiles_at_the_serve_cells_widths(
     assert len(pool_ops) == 2 and all(" bitcast(" in l for l in pool_ops)
 
 
+# (blocks of the pool, rows of a step, heads): the long-document cell and the
+# agent cell
+LATENT_CELLS = {"pangu_longdoc_c8": (8192, 8, 128), "xing_agent_prefix_c32": (16384, 32, 32)}
+
+
 @pytest.mark.parametrize("L", [1, 512, 128])
-def test_the_latent_kernels_compile_at_the_long_document_cell_s_shapes(topo, monkeypatch, L):
+@pytest.mark.parametrize("cell", sorted(LATENT_CELLS))
+def test_the_latent_kernels_compile_at_the_latent_cells_shapes(topo, monkeypatch, cell, L):
     """`ops.latent_decode_attention` and `ops.latent_chunk_attention` as
-    `serve_pangu_longdoc_c8` calls them: 8 rows x 1024 pages of the
-    8192-block latent pool for the step, one row for a chunk (the largest
-    and the smallest bucket), 128 heads on rows of 576 values held as 640,
-    of which 512 are values. Mosaic takes the page copies (it refused a pool
+    `serve_pangu_longdoc_c8` calls them (8 rows x 1024 pages of the
+    8192-block latent pool for the step, 128 heads) and as
+    `serve_xing_agent_prefix_c32` does (32 rows x 1024 pages of 16384 blocks,
+    32 heads, the softmax scale with YaRN's factor): one row for a chunk (the
+    largest and the smallest bucket), rows of 576 values held as 640, of
+    which 512 are values. Mosaic takes the page copies (it refused a pool
     told 576: `Slice shape along dimension 2 must be aligned to tiling
     (128)`), the 640-wide contraction and the chunk kernel's VMEM, and the
-    pool goes into the call as it is: no copy of its 1.17 GB in any call."""
+    pool goes into the call as it is: no copy of its 1.17 or 2.68 GB in any
+    call."""
     import functools
 
     import jax
@@ -393,11 +402,11 @@ def test_the_latent_kernels_compile_at_the_long_document_cell_s_shapes(topo, mon
     monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
     one = SingleDeviceSharding(topo.devices[0])
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
-    nblk, bs, nb, H, rank = 8192, 16, 1024, 128, 512
+    (nblk, rows, H), bs, nb, rank = LATENT_CELLS[cell], 16, 1024, 512
     W = pool_latent_width(rank + 64)
     assert W == 640
     pool = sd((nblk, bs, W), jnp.bfloat16)
-    B = 8 if L == 1 else 1
+    B = rows if L == 1 else 1
     tables = sd((B, nb), jnp.int32)
     if L == 1:
         assert paged_kernel(1, pool, tables, rank=rank) == "latent_decode"
@@ -405,7 +414,8 @@ def test_the_latent_kernels_compile_at_the_long_document_cell_s_shapes(topo, mon
     else:
         assert paged_kernel(L, pool, tables, rank=rank) == "latent_chunk"
         call, q, name = latent_chunk_attention, sd((B, L, H, W), jnp.bfloat16), "latent_chunk"
-    compiled = jax.jit(functools.partial(call, scale=192 ** -0.5, rank=rank)).lower(
+    scale = 192 ** -0.5 * (2.0047 if H == 32 else 1.0)
+    compiled = jax.jit(functools.partial(call, scale=scale, rank=rank)).lower(
         q, pool, tables, sd((B,), jnp.int32)).compile()
     hlo = compiled.as_text()
     (custom,) = _custom_calls(hlo)
@@ -417,6 +427,56 @@ def test_the_latent_kernels_compile_at_the_long_document_cell_s_shapes(topo, mon
     # a chunk's temporaries are its queries and outputs regrouped by head
     # (2 x 512 x 128 x (640 + 512) x 2 B = 151 MB), never the pool's 168 MB
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * L * H * (W + rank) * 2 + 2**20
+
+
+def test_the_agent_cell_s_step_and_chunk_fit_the_chip_beside_its_pool(topo, monkeypatch):
+    """`serve_xing_agent_prefix_c32` as the engine builds it: the model from
+    the configuration's glue at its published widths, 32 slots, tables of
+    1024 pages, the 16384-block latent pool. The step and the 512-token chunk
+    compile for v5e with both latent kernels inside, the donated pool is
+    updated in place (no second 2.68 GB), and what a call holds (weights
+    11.33 GB + pool 2.68 + its temporaries and, for a chunk, 0.27 GB of
+    logits) is under the chip's 16 GB."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from bench_matrix import modelglue, spec
+    from pytorch_distributed_example_tpu.serve.cache import init_paged_cache
+    from pytorch_distributed_example_tpu.serve.decode import paged_programs
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    placed = lambda tree: jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), tree)
+    cell = spec.load_cell("serve_xing_agent_prefix_c32")
+    config, eng = cell["config"], cell["traffic"]["engine"]
+    model = modelglue.build_model(config, eng["max_seq_len"], remat=False)
+    params = placed(jax.eval_shape(
+        modelglue.init_fn(model, config), jax.random.PRNGKey(0))["params"])
+    S, bs, C = eng["slots"], eng["block_size"], eng["prefill_chunk_tokens"]
+    nb = eng["max_seq_len"] // bs
+    tree = placed(jax.eval_shape(lambda: init_paged_cache(model, eng["pool_blocks"], bs)))
+    pool = eng["pool_blocks"] * bs * 640 * 2 * config["num_hidden_layers"]
+    assert pool == pytest.approx(2.68e9, rel=2e-3)
+    chunk, _, _, step = paged_programs(model, 0.0, None)
+    lowered = {
+        "step": step.lower(params, tree, sd((S,), jnp.int32), sd((S,), jnp.int32),
+                           sd((S, 2), jnp.uint32), sd((S, nb), jnp.int32)),
+        "chunk": chunk.lower(params, tree, sd((1, C), jnp.int32), sd((1, nb), jnp.int32),
+                             sd((), jnp.int32)),
+    }
+    for name, low in lowered.items():
+        compiled = low.compile()
+        calls = _custom_calls(compiled.as_text())
+        kernel = "latent_decode_attention" if name == "step" else "latent_chunk_attention"
+        assert sum(kernel in c for c in calls) == config["num_hidden_layers"], name
+        m = compiled.memory_analysis()
+        assert m.argument_size_in_bytes == pytest.approx(14.016e9, rel=2e-3)
+        assert m.alias_size_in_bytes >= pool  # the pool is written in place
+        held = m.argument_size_in_bytes + m.temp_size_in_bytes + (
+            m.output_size_in_bytes - m.alias_size_in_bytes)
+        assert held < 14.5e9 < 16e9, (name, held)
 
 
 @pytest.mark.parametrize("rows", [32, 128, 256, 512])

@@ -730,7 +730,6 @@ def test_a_model_without_latent_layers_gets_the_tables_it_had():
 # --- (ix) what is not carried is refused --------------------------------------
 
 REFUSED = {
-    "prefix_cache": dict(prefix_cache=True),
     "kv_quant": dict(kv_quant=True),
     "mesh": dict(mesh=object()),
     "role": dict(role="prefill"),
@@ -744,6 +743,25 @@ def test_what_a_latent_model_cannot_be_served_with_is_refused(small, what):
     with pytest.raises(ValueError, match="latent layers cannot be served with " + what):
         ServeEngine(model, variables, slots=2, block_size=BS, pool_blocks=40,
                     prefill_chunk_tokens=16, min_bucket=8, **REFUSED[what])
+
+
+def test_a_latent_model_is_served_with_the_prefix_cache(small):
+    """Carried since PR 38 (`tests/test_hyper_latent_moe.py` holds the
+    attach, the copy and the reclaim): a second prompt adopts the first
+    one's head and decodes what it decodes alone."""
+    model, variables = small
+    head = tokens_of(44, 50)
+    prompts = [np.concatenate([head, tokens_of(9, 51 + i)]) for i in range(2)]
+    tokens = {}
+    for shared in (False, True):
+        engine = ServeEngine(model, variables, slots=2, block_size=BS, pool_blocks=40,
+                             prefill_chunk_tokens=16, min_bucket=8, prefix_cache=shared)
+        for i, prompt in enumerate(prompts):
+            engine.submit(prompt, 4, rid=f"r{i}")
+            engine.run()
+        tokens[shared] = [engine.completions[f"r{i}"].tokens for i in range(2)]
+    assert engine.prefix.stats()["prefix_tokens_reused"] >= 44
+    assert tokens[True] == tokens[False]
 
 
 def test_a_pattern_that_contradicts_itself_is_refused(small):

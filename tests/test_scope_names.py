@@ -25,6 +25,7 @@ LAYER = r"TransformerLM\)*/layers_\d+/attn/(attn\.\w+/)*"
 MLP = r"TransformerLM\)*/layers_\d+/mlp/"
 LINEAR = r"TransformerLM\)*/layers_\d+/linear_attn/"
 LATENT = r"TransformerLM\)*/layers_\d+/latent_attn/(latent_attn\.\w+/)*"
+HC = r"TransformerLM\)*/layers_\d+/hc_(attn|mlp)\.pre/"
 # program -> scope -> where it must appear (a regex on the whole path)
 EXPECTED = {
     "step": {
@@ -150,6 +151,26 @@ EXPECTED = {
         "cache_attention": LATENT + r"cache_attention/jit\(_latent_chunk_device\)$",
         "kernel_scope": r"^latent_chunk_kernel/",
     },
+    # a latent model with four residual streams and a router bias: both
+    # halves of every map under its Flax name (`hc_attn` / `hc_mlp` a block,
+    # `hc_out` at the end), the product and the normalisations inside
+    # `hc_pre`; the mixer's and the MLP's scopes where they were
+    "streams_step": {
+        "hc_pre": HC + r"hc_pre/",
+        "hc_mix": HC + r"hc_pre/hc_mix/.*dot_general",
+        "hc_sinkhorn": HC + r"hc_pre/hc_sinkhorn/while/body/",
+        "hc_post": r"TransformerLM\)*/layers_\d+/hc_post/",
+        "hc_out": r"TransformerLM\)*/hc_out\.pre/hc_pre/hc_mix/.*dot_general",
+        "cache_attention": LATENT + r"cache_attention/.*dot_general",
+        "moe": MLP + r"moe/router/logistic",
+    },
+    "streams_prefill_chunk": {
+        "hc_mix": HC + r"hc_pre/hc_mix/.*dot_general",
+        "hc_sinkhorn": HC + r"hc_pre/hc_sinkhorn/while/body/",
+        "hc_post": r"TransformerLM\)*/layers_\d+/hc_post/",
+        "hc_out": r"TransformerLM\)*/hc_out\.pre/hc_pre/",
+        "absorb_q": LATENT + r"absorb_q/.*dot_general",
+    },
     # the kernels' calls carry their names (`name=` on the `pallas_call`), so
     # the trace says which form of the backward a step ran: in the resident
     # regime ONE call a layer (the backward's path goes through `checkpoint`)
@@ -223,15 +244,17 @@ def _hybrid_model(key_dim=8, value_dim=12):
         model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
 
 
-def _latent_model(rank=16):
+def _latent_model(rank=16, **kw):
     from pytorch_distributed_example_tpu.models.transformer import LayerSpec
 
+    kw = kw or {"sandwich_norm": True}
     cfg = TransformerConfig(
         vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64, max_seq_len=64,
-        use_flash=False, sandwich_norm=True, sparse_score="sigmoid", sparse_experts=4,
+        use_flash=False, sparse_score="sigmoid", sparse_experts=4,
         sparse_top_k=2, sparse_d_ff=16, shared_d_ff=16, routed_scale=2.5,
         latent_q_rank=8, latent_kv_rank=rank, latent_nope_dim=8, latent_rope_dim=4,
-        latent_v_dim=8, layers=(LayerSpec("latent"), LayerSpec("latent", mlp="sparse")))
+        latent_v_dim=8, layers=(LayerSpec("latent"), LayerSpec("latent", mlp="sparse")),
+        **kw)
     model = TransformerLM(cfg)
     return model, jax.eval_shape(
         model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
@@ -285,6 +308,12 @@ def serve_paths():
             lvars["params"], ltree, lanes, lanes, rngs, bt))
         out["latent_prefill_chunk" + suffix] = _paths(lchunk.lower(
             lvars["params"], ltree, jnp.zeros((1, 16), jnp.int32), bt[:1], 0))
+    streams, svars = _latent_model(hc_mult=4, sparse_choice_bias=True)
+    schunk, _, _, sstep = paged_programs(streams, 0.0, None)
+    stree = init_paged_cache(streams, nblk, bs)
+    out["streams_step"] = _paths(sstep.lower(svars["params"], stree, lanes, lanes, rngs, bt))
+    out["streams_prefill_chunk"] = _paths(schunk.lower(
+        svars["params"], stree, jnp.zeros((1, 16), jnp.int32), bt[:1], 0))
     wide_hybrid, wvars = _hybrid_model(16, 64)
     out["hybrid_step_kernel"] = _paths(paged_programs(wide_hybrid, 0.0, None)[3].lower(
         wvars["params"], init_paged_cache(wide_hybrid, nblk, bs, state_blocks=S),
@@ -343,7 +372,8 @@ def train_paths(world):
 SERVE = ("step", "step_kernel", "prefill_chunk", "first_token", "pattern_step",
          "pattern_prefill_chunk", "pattern_step_kernel", "hybrid_step",
          "hybrid_step_kernel", "hybrid_prefill_chunk", "latent_step",
-         "latent_prefill_chunk", "latent_step_kernel", "latent_prefill_chunk_kernel")
+         "latent_prefill_chunk", "latent_step_kernel", "latent_prefill_chunk_kernel",
+         "streams_step", "streams_prefill_chunk")
 CASES = [(prog, scope) for prog, scopes_ in EXPECTED.items() for scope in scopes_]
 
 
@@ -387,6 +417,18 @@ def test_the_train_step_runs_one_backward_kernel_a_layer(train_paths):
     assert not [p for p in paths if "flash_bwd_dkdv" in p or "flash_bwd_dq" in p]
 
 
+def test_a_model_of_one_stream_traces_no_map(serve_paths):
+    """The streams' scopes exist where `hc_mult` is above 1 and nowhere else;
+    there every block has both maps and the model ends in `hc_out`."""
+    for program in ("latent_step", "latent_prefill_chunk", "step", "pattern_step"):
+        assert not [p for p in serve_paths[program]["paths"] if "/hc_" in p], program
+    paths = serve_paths["streams_step"]["paths"]
+    maps = {m.group(1) for p in paths if (m := re.search(r"(layers_\d+/hc_\w+)\.pre/hc_pre", p))}
+    assert maps == {f"layers_{i}/hc_{s}" for i in (0, 1) for s in ("attn", "mlp")}
+    assert [p for p in paths if "hc_out.pre/hc_pre" in p]
+    assert not [p for p in paths if "hc_out" in p and "hc_sinkhorn" in p]  # the end mixes, no more
+
+
 def test_the_kernel_step_gathers_nothing(serve_paths):
     """With the decode kernel in the step no operation is traced under
     `kv_gather`, and the old path's step keeps both scopes."""
@@ -412,6 +454,10 @@ READ_BY = {
                                           "latent_prefill_chunk_kernel"],
     "latent_decode_roofline": ["latent_step_kernel"],
     "latent_chunk_roofline": ["latent_prefill_chunk_kernel"],
+    "decode_hc_ms": ["streams_step"],
+    "decode_hc_sinkhorn_ms": ["streams_step"],
+    "prefill_hc_ms": ["streams_prefill_chunk"],
+    "hc_chunk_roofline": ["streams_prefill_chunk"],
     "decode_linear_attention_ms": ["hybrid_step", "hybrid_step_kernel"],
     "decode_recurrence_ms": ["hybrid_step", "hybrid_step_kernel"],
     "recurrence_decode_roofline": ["hybrid_step", "hybrid_step_kernel"],
