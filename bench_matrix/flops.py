@@ -71,21 +71,44 @@ def flash_call(batch: int, seq: int, heads: int, head_dim: int,
 
 
 def paged_decode_call(keys, heads: int, kv_heads: int, head_dim: int,
-                      itemsize: int = 2) -> dict:
+                      itemsize: int = 2, distinct: int = None) -> dict:
     """What one paged decode attention call (one layer of one engine step)
     has to do. `keys` holds, for every row that DECODES in the step, the
     keys it attends: its cached tokens and the one the step writes. A parked
     or mid-prefill row is not in `keys` and costs nothing. Bytes are the
-    live K and V read once (`kv_heads` heads; the query heads of a group
-    share them); the one query and output vector a row are under 1 % of
-    that from 64 keys on and left out. FLOPs: QK^T and PV for every query
-    head. Pages are not rounded up: a partly filled page is the kernel's
-    cost, not the algorithm's need."""
+    live K and V of the step's DISTINCT keys read once (`distinct_keys`: a
+    block that several rows' tables hold counts once, whether or not the
+    kernel reads it so; None: no two rows hold one block, every row's keys
+    are its own), `kv_heads` heads (the query heads of a group share them);
+    the one query and output vector a row are under 1 % of that from 64 keys
+    on and left out. FLOPs: QK^T and PV for every query head of every (row,
+    key) pair. Pages are not rounded up: a partly filled page is the
+    kernel's cost, not the algorithm's need."""
     n = float(sum(keys))
     return {
-        "bytes": n * kv_heads * head_dim * 2 * itemsize,
+        "bytes": (n if distinct is None else float(distinct)) * kv_heads * head_dim * 2 * itemsize,
         "flops": 4.0 * n * heads * head_dim,
     }
+
+
+def distinct_keys(tables, keys, block_size: int) -> int:
+    """The keys a decode step attends, a key that several rows attend
+    counted once: `tables` (rows, blocks a row) holds the pool block behind
+    each logical block of the rows that decode, `keys` (rows,) the keys each
+    attends (from its first). Two rows attend the same key where their
+    tables hold the same block: a block counts once, at the most keys any
+    row attends in it. Counted from the tables alone, so it is the same
+    whether or not a kernel reads a shared block once."""
+    import numpy as np
+
+    tables, keys = np.asarray(tables), np.asarray(keys, np.int64)
+    if not keys.size:
+        return 0
+    first = np.arange(tables.shape[1], dtype=np.int64) * block_size
+    held = np.clip(keys[:, None] - first[None, :], 0, block_size)  # keys of a row in a block
+    most = np.zeros(int(tables.max()) + 1, np.int64)
+    np.maximum.at(most, tables[held > 0], held[held > 0])
+    return int(most.sum())
 
 
 def roofline_seconds(flops: float, nbytes: float, peaks: dict) -> dict:

@@ -255,28 +255,47 @@ def train_flops_per_token(config: dict, seq: int) -> float:
     return 3.0 * (2.0 * matmuls + attention)
 
 
-def latent_decode_call(config: dict, keys: float, itemsize: int = 2) -> dict:
+def latent_decode_call(config: dict, keys: float, itemsize: int = 2,
+                       distinct: float = None) -> dict:
     """What the latent decode attention calls of ONE engine step have to
     do, over all layers. `keys` is the keys attended, summed over the rows
     that decode in the step (each row's cached keys and the one the step
-    writes; a parked row has none). Bytes: a key's PUBLISHED row (latent and
+    writes; a parked row has none); `distinct` the same with a key that
+    several rows attend counted once (`flops.distinct_keys`; None: no two
+    rows hold one block). Bytes: a distinct key's PUBLISHED row (latent and
     rotary key: 576 values) read once, whatever the pool holds beside it;
-    FLOPs: `pair_flops` a key."""
+    FLOPs: `pair_flops` a (row, key) pair."""
     n = config["num_hidden_layers"]
     row = config["kv_lora_rank"] + config["qk_rope_head_dim"]
-    return {"bytes": float(n * keys * row * itemsize),
+    return {"bytes": float(n * (keys if distinct is None else distinct) * row * itemsize),
             "flops": float(n * keys * pair_flops(config))}
+
+
+def chunk_pair_flops(config: dict, keys: int, pairs: int) -> int:
+    """FLOPs of one layer's attention over `pairs` (query, key) pairs on
+    `keys` cached latents, in the cheaper of the two forms the arithmetic
+    allows. Absorbed: `pair_flops` a pair. Up-projected: every key's heads
+    made from its latent once (`kv_lora_rank` x heads x (`qk_nope_head_dim`
+    + `v_head_dim`) products), then a pair scores `qk_nope_head_dim +
+    qk_rope_head_dim` values a head and sums `v_head_dim`: cheaper from
+    about 170 queries a key up, 147.5 kFLOP a pair against 278.5 at 128
+    heads and a 512-token chunk over a long context."""
+    h, r = config["num_attention_heads"], config["kv_lora_rank"]
+    nope, rot, dv = (config[k] for k in ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
+    up = keys * 2 * r * h * (nope + dv) + pairs * 2 * h * (nope + rot + dv)
+    return min(pairs * pair_flops(config), up)
 
 
 def latent_chunk_call(config: dict, start: int, tokens: int, itemsize: int = 2) -> dict:
     """What the latent chunk attention calls of ONE prefill chunk have to
     do, over all layers: `tokens` real queries at positions `start ...`,
     each attending the keys at positions <= its own (padding attends
-    nothing that counts). FLOPs: `pair_flops` a causal pair; bytes: the
-    published rows of the `start + tokens` keys read ONCE a chunk (the
-    least any blocking of the queries can read)."""
+    nothing that counts). FLOPs: `chunk_pair_flops` of the causal pairs,
+    the cheaper form at this chunk's own size; bytes: the published rows of
+    the `start + tokens` keys read ONCE a chunk (the least any blocking of
+    the queries can read)."""
     n = config["num_hidden_layers"]
     row = config["kv_lora_rank"] + config["qk_rope_head_dim"]
     pairs = tokens * start + tokens * (tokens + 1) // 2
     return {"bytes": float(n * (start + tokens) * row * itemsize),
-            "flops": float(n * pairs * pair_flops(config))}
+            "flops": float(n * chunk_pair_flops(config, start + tokens, pairs))}

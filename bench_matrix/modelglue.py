@@ -9,6 +9,7 @@ and the program's parameter names; it gives
         dicts, final norm, output matrix); a layer dict's keys are a matter
         between the glue and the `reference` module its configuration names
     train_flops_per_token(config, seq)       -> model FLOPs, for `readers/mfu.py`
+        and, through `forward_flops` below, `readers/serve_mfu.py`
 """
 
 from __future__ import annotations
@@ -19,6 +20,11 @@ import jax
 import jax.numpy as jnp
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+
+
+def itemsize(config: dict, what: str) -> int:
+    """Bytes a value of the configuration's `dtype[what]` takes."""
+    return jnp.dtype(DTYPES[config["dtype"][what]]).itemsize
 
 
 def glue(config: dict):
@@ -41,6 +47,25 @@ def train_flops_per_token(config: dict, seq: int) -> float:
     """Model FLOPs a trained token at this sequence length, as the
     configuration's kind counts them."""
     return glue(config).train_flops_per_token(config, seq)
+
+
+def forward_flops(config: dict):
+    """`f(start, tokens)`: model FLOPs of one forward pass over the `tokens`
+    tokens at positions `start ...` of a sequence, each at its own context:
+    the glue's count for a training token, forward third, is the MEAN over
+    the positions of a `seq`-token sequence, so `seq` times it is the whole
+    sequence's and the difference of two prefixes the tokens between them
+    (a decoding token that attends k keys: `f(k - 1, 1)`). Active
+    parameters only, attention by the keys attended, as the glue counts."""
+    per_token = glue(config).train_flops_per_token
+    prefix = {0: 0.0}
+
+    def upto(n):
+        if n not in prefix:
+            prefix[n] = n * per_token(config, n) / 3.0
+        return prefix[n]
+
+    return lambda start, tokens: upto(start + tokens) - upto(start)
 
 
 def init_fn(model, config: dict):

@@ -1,19 +1,25 @@
-"""What the two latent roofline readers share: the host annotations the
-serve engine writes at every dispatch (`serve:decode_step`: rows, keys;
-`serve:prefill_chunk`: slot, start, tokens, bucket), the device time of a
-kernel's calls in every WHOLE run of a program, and the pairing of the two.
+"""What the roofline readers of the serve cells share: the steps and chunks
+the engine DISPATCHED in the traced slice (the runner's record of every call
+of the slice, from `engine.last_step`: `samples["decode_steps"]`; or the host
+annotations the engine writes at a dispatch or a read-back:
+`serve:prefill_chunk`: slot, start, tokens, bucket; `serve:moe_step`), the
+device time under a scope in every WHOLE run of a program, and the pairing of
+the two.
 
-The engine keeps one call's device work in flight, and the runner starts
-and stops the trace between two of its calls, so the slice's first and last
-step are cut: a program's run may lie in the slice while its dispatch lay
-before it, and the last dispatches' runs are cut off by `stop_trace` (a cut
-run leaves no event on the modules line, or not all of its kernel calls).
-Dispatch order is run order, so whole runs and annotated dispatches pair in
-order once the unpaired end is dropped: more whole runs than annotations,
-and the leading runs were dispatched before the slice; fewer, and the
-trailing annotations' runs were cut. Both counts are said.
+The runner reads everything back (`engine.flush()`) before the trace starts
+and again before it stops, so the slice holds whole steps only and runs and
+dispatches pair one to one. Where they do not (a profiler that dropped an
+event, a runner that did not flush: the engine keeps one call's device work
+in flight, so a program's run may lie in the slice while its dispatch lay
+before it, and `stop_trace` cuts the last dispatches' runs: a cut run leaves
+no event on the modules line, or not all of its operations), dispatch order
+is still run order: whole runs and dispatches pair in order once the
+unpaired end is dropped: more whole runs than dispatches, and the leading
+runs were dispatched before the slice; fewer, and the trailing dispatches'
+runs were cut. Both counts are said; "no number" only where nothing pairs.
 """
 
+import bisect
 import os
 import re
 
@@ -60,23 +66,30 @@ def parse(event) -> dict:
     return {k: int(v) for k, v in args.items() if str(v).lstrip("-").isdigit()}
 
 
-def kernel_seconds(sc: scopes.Scopes, program: str, scope: str, calls: int):
+def kernel_seconds(sc: scopes.Scopes, program: str, scope: str, calls: int = None):
     """Per device, the device seconds of the operations under `scope` in
-    every run of the programs named `program` that holds exactly `calls` of
-    them, in run order: [(device, [seconds a whole run], runs seen)]."""
+    every WHOLE run of the programs named `program`, in run order: [(device,
+    [seconds a whole run], runs seen)]. A run is whole when it holds exactly
+    `calls` such operations or, with `calls` None (a scope whose operations
+    are many and differ between the programs of one name), as many as the
+    most any run of ITS program holds: a run cut by the edge of the trace
+    holds fewer, or leaves no event on the modules line."""
     prog_rx, scope_rx = re.compile(program), re.compile(scope)
     out = []
     for device, runs in sc.runs.items():
         mine = [r for r in runs if prog_rx.search(r[0])]
-        if not mine:
-            continue
         hits = [o for o in sc.ops.get(device, [])
                 if scope_rx.search("/".join(scopes.names(o[0])[0]))]
-        whole = []
+        if not mine or not hits:
+            continue
+        starts = [o[2] for o in hits]
+        inside, most = [], {}
         for _, pid, start, dur in mine:
-            inside = [o[3] for o in hits if o[1] == pid and start <= o[2] <= start + dur]
-            if len(inside) == calls:
-                whole.append(sum(inside) / 1e12)
+            lo, hi = bisect.bisect_left(starts, start), bisect.bisect_right(starts, start + dur)
+            inside.append([o[3] for o in hits[lo:hi] if o[1] == pid])
+            most[pid] = max(most.get(pid, 0), len(inside[-1]))
+        whole = [sum(ops) / 1e12 for (_, pid, _, _), ops in zip(mine, inside)
+                 if ops and len(ops) == (most[pid] if calls is None else calls)]
         out.append((device, whole, len(mine)))
     return out
 
@@ -89,39 +102,49 @@ def paired(whole: list, notes: list):
     return whole, notes[:len(whole)]
 
 
-def read(args, env, count, what: str):
-    """A kernel's share of its roofline over the paired steps of the slice:
-    `count(config, note, itemsize)` is the glue's {"bytes", "flops"} of one
-    annotated step. None (the key is left out, never 0) without a trace,
-    annotations, the scope in the program, or a single pair."""
-    import numpy as np
-
-    from .. import flops, modelglue
+def read(args, env, notes, count, what: str, calls: int = None):
+    """A scope's share of its roofline over the paired steps of the slice:
+    `notes` are the dispatches in order, `count(note)` the {"bytes", "flops"}
+    one of them needs (None: not counted, it and its run are left out);
+    `calls` as in `kernel_seconds`. None (the key is left out, never 0)
+    without a trace, dispatches, the scope in the program, or a single pair."""
+    from .. import flops
     from .scope_time import _scopes
 
-    notes = annotations(env, args["annotation"])
-    sc = _scopes(env)
-    if not notes or sc is None:
+    sc = _scopes(env) if notes else None
+    if sc is None:
         return None
-    cfg = env.cell["config"]
-    per_device = kernel_seconds(sc, args["program"], args["scope"], cfg["num_hidden_layers"])
-    itemsize = np.dtype(modelglue.DTYPES[cfg["dtype"]["kv_cache"]]).itemsize
+    per_device = kernel_seconds(sc, args["program"], args["scope"], calls)
     shares = []
     for device, whole, seen in per_device:
         seconds, kept = paired(whole, notes)
         if not seconds:
+            env.say(f"{what} on {device}: {len(notes)} dispatches kept, {seen} runs of "
+                    f"the program in the slice, {len(whole)} of them whole: no number")
             continue
-        calls = [count(cfg, note, itemsize) for note in kept]
-        need_bytes = sum(c["bytes"] for c in calls)
-        need_flops = sum(c["flops"] for c in calls)
+        # a dispatch the runner could not count (`count` gives None) goes
+        # with its run
+        both = [(sec, need) for sec, need in zip(seconds, map(count, kept)) if need is not None]
+        if not both:
+            continue
+        need_bytes = sum(need["bytes"] for _, need in both)
+        need_flops = sum(need["flops"] for _, need in both)
         least = flops.roofline_seconds(need_flops, need_bytes, env.peaks)
-        spent = sum(seconds)
+        spent = sum(sec for sec, _ in both)
         env.say(
-            f"{what} on {device}: {len(notes)} annotated dispatches, {seen} runs of the "
-            f"program in the slice, {len(whole)} of them whole, {len(kept)} paired; the "
-            f"kernel took {spent:.4f} s in them, needed {need_bytes:.3e} bytes of latents "
-            f"and {need_flops:.3e} FLOPs, {least['bound']}-bound, least "
+            f"{what} on {device}: {len(notes)} dispatches kept, {seen} runs of the "
+            f"program in the slice, {len(whole)} of them whole, {len(kept)} paired, "
+            f"{len(both)} counted; the scope took {spent:.4f} s in them, needed "
+            f"{need_bytes:.3e} bytes and {need_flops:.3e} FLOPs, {least['bound']}-bound, least "
             f"{least['seconds']:.4f} s ({need_flops / spent:.3e} FLOP/s, "
             f"{need_bytes / spent:.3e} bytes/s)")
         shares.append(100.0 * least["seconds"] / spent)
     return sum(shares) / len(shares) if shares else None
+
+
+def decode_steps(env):
+    """The decode steps the runner kept for the traced slice, one dict a
+    step in dispatch order ({"keys": [a decoding row's keys, ...],
+    "distinct": keys read once where several rows' tables hold one block});
+    None without a traced slice."""
+    return env.samples.get("decode_steps") or None

@@ -1,46 +1,31 @@
-"""The paged decode attention kernel's share of its roofline: the least
-time the chip could take to read the live K/V of the decode calls in the
-traced slice (from the lengths the runner kept for every engine step of the
-slice, by `flops.paged_decode_call`) over the kernel's time in the trace.
-One engine step calls the kernel once a layer. Only rows that DECODE in a
-step count: a parked or mid-prefill lane has no work item in the kernel and
-reads no page. A slice whose kernel calls are not one a layer for every step
-kept gives no number: bytes and time would not be of the same calls."""
-
-import numpy as np
+"""Paged decode attention's share of its roofline in the decode steps of the
+traced slice: the least time the chip could take to read the live K/V those
+steps attend and to do their scores and sums (`flops.paged_decode_call`, from
+what the runner kept of every step it dispatched: each decoding row's keys
+for the FLOPs, the step's DISTINCT keys for the bytes) over the device time
+of the operations under `args.scope` in the WHOLE runs of `args.program`
+that pair with a kept step (`readers/latent_steps.py`): whatever implements
+the attention, a kernel, its work lists, a gather. Only rows that DECODE in
+a step count: a parked or mid-prefill lane attends nothing. Memory-bound.
+The key is left out (never 0) where there is nothing to read: no trace, no
+step kept, a program without the scope, no whole run."""
 
 from .. import flops, modelglue
-from ..reduce import xplane
+from . import latent_steps
 
 
 def read(args, env):
-    steps = env.samples.get("decode_keys")
-    if env.trace is None or not steps:
-        return None
-    hit = [h for h in xplane.time_matching(env.trace, args["pattern"]).values()
-           if h["events"]]
-    if not hit:
-        return None
     cfg = env.cell["config"]
     layers = cfg["num_hidden_layers"]
-    events = [h["events"] for h in hit]
-    if any(e != len(steps) * layers for e in events):
-        env.say(f"paged decode kernel: {events} calls in the slice against "
-                f"{len(steps)} decode steps x {layers} layers: no number")
-        return None
-    keys = [k for step in steps for k in step]
-    call = flops.paged_decode_call(
-        keys, cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"],
-        np.dtype(modelglue.DTYPES[cfg["dtype"]["kv_cache"]]).itemsize,
-    )
-    need_flops, need_bytes = layers * call["flops"], layers * call["bytes"]
-    least = flops.roofline_seconds(need_flops, need_bytes, env.peaks)
-    kernel_s = sum(h["seconds"] for h in hit) / len(hit)
-    env.say(
-        f"paged decode kernel: {kernel_s:.4f} s in {events[0]} calls "
-        f"({len(steps)} steps of mean {len(keys) / len(steps):.1f} decoding "
-        f"rows, {np.mean(keys):.0f} keys a row), needed {need_bytes:.3e} bytes "
-        f"of live K/V and {need_flops:.3e} FLOPs, {least['bound']}-bound, read "
-        f"{need_bytes / kernel_s:.3e} bytes/s"
-    )
-    return 100.0 * least["seconds"] / kernel_s
+    itemsize = modelglue.itemsize(cfg, "kv_cache")
+
+    def count(step):
+        if step["distinct"] is None:
+            return None
+        call = flops.paged_decode_call(
+            step["keys"], cfg["num_attention_heads"], cfg["num_key_value_heads"],
+            cfg["head_dim"], itemsize, distinct=step["distinct"])
+        return {k: layers * v for k, v in call.items()}
+
+    return latent_steps.read(args, env, latent_steps.decode_steps(env), count,
+                             "paged decode attention")

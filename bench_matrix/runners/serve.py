@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from .. import correctness, modelglue, traffic_gen
+from .. import correctness, flops, modelglue, traffic_gen
 from ..context import Context, Result
 
 
@@ -40,8 +40,11 @@ class _PrefillProbe:
 class _DecodeProbe:
     """Keeps, for every decode step it passes on, the keys each decoding
     row attends: the host's mirror of the row's length and the token the
-    step writes. Rows that are parked or mid-prefill are not in the
-    engine's decoding set and cost the attention kernel nothing."""
+    step writes. NOT used by `run` any more (PR 40: the loop reads
+    `engine.last_step`, and nothing here assigns to `engine._step`): it
+    stays for the program's own tests that hold `engine.last_step` against
+    it (`tests/test_serve_pipeline.py`, `tests/test_serve_step_record.py`),
+    which a benchmark PR may not edit; it goes with them (`PERF.md` section 7)."""
 
     def __init__(self, engine):
         self.engine, self.program, self.keys = engine, engine._step, []
@@ -107,6 +110,11 @@ class _Loop:
         self.stream = traffic_gen.RequestStream(traffic, vocab, ctx.seed)
         self.step_end = []  # harness clock at each engine.step() return
         self.slots = []  # active slots the engine recorded for each step
+        # what each call dispatched, from `engine.last_step`: its prefill
+        # chunks (slot, start, tokens, bucket), its decoding rows' keys and,
+        # while `count_distinct`, the step's `_distinct_keys`
+        self.dispatched = []
+        self.count_distinct = False
         # tokens so far, per step: prompts taken in plus tokens given out, and
         # tokens given out alone (see `_count`)
         self.progress = {"all": [], "generated": []}
@@ -166,6 +174,10 @@ class _Loop:
         now = self.clock()
         self.step_end.append(now)
         self.slots.append(self.engine.metrics.slots_active)
+        rec = self.engine.last_step
+        distinct = (self._distinct_keys(rec.decode_keys)
+                    if self.count_distinct and rec.decode_keys else None)
+        self.dispatched.append((rec.chunks, rec.decode_keys, distinct))
         if self.engine.completions:
             for rid, c in list(self.engine.completions.items()):
                 del self.engine.completions[rid]
@@ -185,6 +197,22 @@ class _Loop:
             # nothing to do: wait for the next arrival
             while self.clock() < self.pending[-1][0]:
                 pass
+
+    def _distinct_keys(self, keys):
+        """The keys the step just dispatched attends, a key that several
+        rows' block tables hold counted once (`flops.distinct_keys`). The
+        rows that decoded are the slots whose length moved in this call and
+        is not 0 (a prefill that completes decodes in the same call; a
+        retired or preempted slot reads 0), and their tables are what the
+        step was handed: growth and copy-on-write come before a dispatch,
+        and a row is freed only once it has left the decoding set. None
+        where those slots' lengths are not the record's keys."""
+        cache = self.engine.cache
+        cur = cache.lengths.astype(np.int64)
+        rows = np.flatnonzero((cur > 0) & (cur != self._held))
+        if tuple(cur[rows].tolist()) != tuple(keys):
+            return None
+        return flops.distinct_keys(cache.block_tables[rows], cur[rows], cache.block_size)
 
     def _count(self):
         """Tokens so far, from the engine's own `cache.lengths`: 0 for a free
@@ -260,15 +288,16 @@ def run(cell: dict, ctx: Context) -> Result:
     t_close = loop.step_end[last]
     late = list(loop.late_s)
 
-    decode_keys = None
+    # the traced slice holds whole steps only: everything outstanding is
+    # read back before the trace starts and before it stops (`stop_trace`
+    # cuts what is in flight); both lie behind the window
+    first_traced = len(loop.dispatched)
     if trace_s:
-        probe = engine._step = _DecodeProbe(engine)
-        try:
-            with ctx.tracing():
-                loop.run_until(clock() + trace_s)
-        finally:
-            engine._step = probe.program
-        decode_keys = probe.keys
+        loop.count_distinct = True
+        engine.flush()
+        with ctx.tracing():
+            loop.run_until(clock() + trace_s)
+            engine.flush()
 
     done = [d for d in loop.done if warm_steps <= d["step"] <= last]
     good = [d for d in done if d["reason"] in ("length", "eos")]
@@ -318,8 +347,20 @@ def run(cell: dict, ctx: Context) -> Result:
             "slots_active": loop.slots[warm_steps:last + 1],
             "ttft_s": ttft.tolist(),
             "tpot_s": [d["tpot_s"] for d in good if d["tokens"] > 1],
-            # traced slice only: per decode step, the keys of each decoding row
-            "decode_keys": decode_keys,
+            # traced slice only: per decode step dispatched, each decoding
+            # row's keys and the step's distinct keys (a shared block once)
+            "decode_steps": [
+                {"keys": list(keys), "distinct": distinct}
+                for _, keys, distinct in loop.dispatched[first_traced:] if keys
+            ],
+            # what the window's calls computed: (start, tokens) of every
+            # prefill chunk, the keys of every decoding row of every step
+            "computed": {
+                "chunks": [[c[1], c[2]] for chunks, _, _ in loop.dispatched[warm_steps:last + 1]
+                           for c in chunks],
+                "decode_keys": [k for _, keys, _ in loop.dispatched[warm_steps:last + 1]
+                                for k in keys],
+            },
             # everything the numbers above were worked out from
             "window": [t_open, t_close], "requests": loop.done,
             "step_end": loop.step_end, "progress": loop.progress,

@@ -37,6 +37,12 @@ def tiny_cell(name: str, arrival: dict = None) -> dict:
     return cell
 
 
+def kept_steps(steps, shared=0) -> dict:
+    """The runner's samples of a traced slice's decode steps: each step's
+    rows' keys, `shared` of a step's keys in blocks another row holds too."""
+    return {"decode_steps": [{"keys": list(k), "distinct": sum(k) - shared} for k in steps]}
+
+
 def context(seconds: float, devices, seed: int = 3, trace_dir: str = "") -> Context:
     return Context(
         seed=seed, seconds=seconds, devices=list(devices),

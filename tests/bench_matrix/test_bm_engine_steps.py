@@ -158,6 +158,71 @@ def test_the_hit_share_is_the_index_s_own_count(found, live):
         (r.call, r.admitted, len(r.chunks), r.decode_rows) for r in records]
 
 
+def test_the_shared_key_share_is_the_records_own_and_0_where_nothing_is_shared(found, live):
+    """`shared_key_share`: of the keys the slice's decode steps attend, those
+    the kernel reads once for several rows, from the `serve:decode_step`
+    annotations. Where the kernel shares nothing (heads shorter than its 256-key
+    blocks here; a latent pool on the chip) it reads 0.0: a count, not a share
+    of a peak, so 0 is a reading and the key stays."""
+    args = spec.load("layer_metrics", "shared_key_share")
+    assert (args["reader"], args["source"], args["layer"], args["moves"]) == (
+        "engine_steps", "program_counter", "serve plane", "serve_tokens_per_s")
+    records = [r for r in live["records"] if r.decode_keys]
+    keys = sum(sum(r.decode_keys) for r in records)
+    shared = sum(r.decode_shared_keys for r in records)
+    steps = [a for name, _, _, a in found if name == "serve:decode_step"]
+    assert [(a["rows"], a["keys"], a["shared"]) for a in steps] == [
+        (r.decode_rows, sum(r.decode_keys), r.decode_shared_keys) for r in records]
+    got = engine_steps.stat(args["args"], engine_steps.calls(found))
+    assert got == pytest.approx(100.0 * shared / keys) and got == 0.0
+    # a slice whose steps share: 3 of 4 keys behind one head
+    call = {"start": 0, "end": 9, "ns": {}, "events": [
+        ("serve:decode_step", 1, 2, {"rows": 8, "keys": 4000, "shared": 3000}),
+        ("serve:decode_step", 3, 4, {"rows": 8, "keys": 4008, "shared": 3006})]}
+    assert engine_steps.stat(args["args"], [call]) == pytest.approx(100.0 * 6006 / 8008)
+
+
+def test_the_loop_counts_a_step_s_distinct_keys_from_the_pool_s_tables():
+    """Three requests decode behind one 32-token head that the prefix index
+    gave them (four blocks of 8): the step's rows attend the head's keys three
+    times and the pool holds them once, whatever the kernel does about it
+    (`decode_shared_keys` is 0 here: the kernel shares whole 256-key blocks
+    only). The runner counts from the tables and lengths the step was handed."""
+    import jax
+
+    from bench_matrix import modelglue
+    from bench_matrix.runners.serve import _Loop
+    from pytorch_distributed_example_tpu.serve import ServeEngine
+
+    cfg = dict(spec.load("configs", "mistral-7b-v0.3-d16"), **TINY_MODEL)
+    model = modelglue.build_model(cfg, 128, remat=False)
+    engine = ServeEngine(model, modelglue.make_variables(model, cfg, 3), slots=4,
+                         block_size=8, pool_blocks=64, prefill_chunk_tokens=32,
+                         min_bucket=16, prefix_cache=True)
+    gen = np.random.default_rng(11)
+    head = gen.integers(0, 256, (32,), dtype=np.int32)
+    engine.submit(np.concatenate([head, [1, 2, 3]]).astype(np.int32), 2, rid="first")
+    engine.run()  # the head is indexed
+    ctx = context(1.0, jax.devices()[:1])
+    ctx.compiles.close()
+    loop = _Loop(engine, tiny_cell(CELL)["traffic"], 256, ctx)
+    loop._count()
+    for i, n in enumerate((5, 9, 14)):
+        engine.submit(np.concatenate([head, gen.integers(0, 256, (n,), dtype=np.int32)]), 6,
+                      rid=f"r{i}")
+    seen = []
+    while engine.step():
+        rec = engine.last_step
+        if rec.decode_keys:
+            seen.append((rec.decode_keys, loop._distinct_keys(rec.decode_keys)))
+        loop._count()
+    assert max(len(k) for k, _ in seen) == 3 and engine.last_step.decode_shared_keys == 0
+    for keys, distinct in seen:
+        assert distinct == sum(keys) - 32 * (len(keys) - 1), (keys, distinct)
+    # lengths that are not the record's: not counted, never guessed
+    assert loop._distinct_keys((1, 2, 3)) is None
+
+
 def test_the_table_names_every_phase_and_the_longest_call(found):
     (phases, longest) = engine_steps.table(found, xplane.Trace())[:2]
     assert phases.startswith("engine steps: ")
@@ -263,13 +328,16 @@ def test_the_seven_metrics_are_the_serve_plane_s_and_the_cell_lists_them():
             "serve plane", "serve_tokens_per_s", "engine_steps")
         assert m["source"] == ("program_counter" if name == "prefix_hit_share"
                                else "program_span")
-        assert listed[name]["workloads"] == [CELL]
-    assert [m["name"] for m in BENCH["per_layer"]][-7:] == list(SEVEN)
-    assert BENCH["workloads"][-1]["name"] == CELL
+        assert CELL in listed[name]["workloads"]
+    assert set(SEVEN) <= set(listed)
+    assert CELL in [w["name"] for w in BENCH["workloads"]]
     # every accepted metric the cell reports lists it, and moves what it reports
     for name, m in cell["per_layer"].items():
         assert CELL in listed[name]["workloads"] and m["moves"] in cell["end_to_end"]
-    assert "paged_decode_roofline" not in cell["per_layer"]
+    # since PR 40 the decode roofline's bytes are the step's DISTINCT keys, so the
+    # cell whose rows share four heads lists it, beside the share of a step's keys
+    # the kernel reads once for several rows and the window's share of the peak
+    assert {"paged_decode_roofline", "shared_key_share", "serve_mfu_pct"} <= set(cell["per_layer"])
     # the check attaches (`runners/serve_prefix.py`), at serve_decode_c32's limits
     mine, accepted = cell["correctness"], spec.load("workloads", "serve_decode_c32")["correctness"]
     assert cell["runner"] == "serve_prefix"

@@ -13,6 +13,7 @@ from bench_matrix.glue import hybrid_linear as glue
 from bench_matrix.readers import ReadEnv, recurrence_decode_roofline
 from bench_matrix.reduce import scopes, xplane
 
+from _tiny import kept_steps as _kept
 from test_bm_specs import check_cut
 
 CFG = spec.load("configs", "olmo-hybrid-7b-d16")
@@ -131,7 +132,7 @@ def test_the_cell_reports_throughput_and_lists_only_what_moves_what_it_reports()
     new = {"decode_linear_attention_ms", "decode_recurrence_ms",
            "prefill_linear_attention_ms", "prefill_chunk_scan_ms",
            "recurrence_decode_roofline"}
-    assert new <= set(cell["per_layer"]) and len(cell["per_layer"]) == 13
+    assert new | {"serve_mfu_pct"} <= set(cell["per_layer"]) and len(cell["per_layer"]) == 14
     assert "paged_decode_roofline" not in cell["per_layer"]
     for name in new - {"recurrence_decode_roofline"}:
         m = cell["per_layer"][name]
@@ -180,21 +181,29 @@ def _scopes(steps, each_ps=2_000_000):
 
 
 def test_the_reader_pairs_the_runs_of_the_step_with_the_steps_kept(monkeypatch):
+    from bench_matrix.readers import scope_time
+
     args = spec.load("layer_metrics", "recurrence_decode_roofline")["args"]
     kept = [[700, 1200, 90], [701, 1201, 91], [702, 1202]]  # 3, 3 and 2 live rows
-    monkeypatch.setattr(recurrence_decode_roofline, "_scopes", lambda env: _scopes(3))
-    env, said = _env({"decode_keys": kept})
+    monkeypatch.setattr(scope_time, "_scopes", lambda env: _scopes(3))
+    env, said = _env(_kept(kept))
     got = recurrence_decode_roofline.read(args, env)
     need = sum(glue.recurrence_decode_call(CFG, len(s))["bytes"] for s in kept)
     assert got == pytest.approx(100 * (need / 819e9) / (3 * 2e-6))
-    assert "memory-bound" in said[-1] and "2.7 live rows" in said[-1]
-    # a step the runner did not keep: bytes and time are not of the same steps
-    env, said = _env({"decode_keys": kept[:-1]})
-    assert recurrence_decode_roofline.read(args, env) is None and "no number" in said[-1]
+    assert "memory-bound" in said[-1] and "3 paired" in said[-1]
+    # a step whose run is not in the slice (the ledger's `null` of PRs 32-38:
+    # one step in flight when the trace stopped): the trailing step goes, both
+    # counts are said, and the share is the two pairs'
+    monkeypatch.setattr(scope_time, "_scopes", lambda env: _scopes(2))
+    env, said = _env(_kept(kept))
+    need = sum(glue.recurrence_decode_call(CFG, len(s))["bytes"] for s in kept[:2])
+    assert recurrence_decode_roofline.read(args, env) == pytest.approx(
+        100 * (need / 819e9) / (2 * 2e-6))
+    assert "3 dispatches kept, 2 runs of the program in the slice" in said[-1]
     # no steps kept; a configuration whose glue has no such count
-    assert recurrence_decode_roofline.read(args, _env({"decode_keys": None})[0]) is None
+    assert recurrence_decode_roofline.read(args, _env({})[0]) is None
     other = spec.load("configs", "mistral-7b-v0.3-d16")
-    env, _ = _env({"decode_keys": kept}, config=other)
+    env, _ = _env(_kept(kept), config=other)
     assert recurrence_decode_roofline.read(args, env) is None
 
 
@@ -207,10 +216,12 @@ def test_a_program_without_the_scope_gives_no_number(monkeypatch):
         ops={"/device:TPU:0": [("jit(step)/TransformerLM/layers_0/mlp/down_proj/dot_general",
                                 7, 0, 1000)]},
         runs={"/device:TPU:0": [("jit_step", 7, 0, 2000)]})
-    monkeypatch.setattr(recurrence_decode_roofline, "_scopes", lambda env: bare)
-    assert recurrence_decode_roofline.read(args, _env({"decode_keys": [[5]]})[0]) is None
+    from bench_matrix.readers import scope_time
+
+    monkeypatch.setattr(scope_time, "_scopes", lambda env: bare)
+    assert recurrence_decode_roofline.read(args, _env(_kept([[5]]))[0]) is None
     monkeypatch.undo()
-    assert recurrence_decode_roofline.read(args, _env({"decode_keys": [[5]]})[0]) is None
+    assert recurrence_decode_roofline.read(args, _env(_kept([[5]]))[0]) is None
     for name in ("decode_linear_attention_ms", "decode_recurrence_ms",
                  "prefill_linear_attention_ms", "prefill_chunk_scan_ms"):
         m = spec.load("layer_metrics", name)

@@ -10,7 +10,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench_matrix import spec, traffic_gen
+from bench_matrix import modelglue, spec, traffic_gen
 from bench_matrix.glue import latent_moe as glue
 from bench_matrix.readers import (
     ReadEnv, latent_chunk_roofline, latent_decode_roofline, latent_steps,
@@ -160,6 +160,9 @@ def test_latent_decode_call_by_hand():
     576 values read once a layer, whatever the pool's rows hold."""
     got = glue.latent_decode_call(CFG, 700 + 1200 + 90)
     assert got["bytes"] == 7 * 1990 * 576 * 2 and got["flops"] == 7 * 1990 * 278_528
+    # rows behind one head of 512 keys: its rows are read once, every pair is computed
+    shared = glue.latent_decode_call(CFG, 700 + 1200 + 90, distinct=1990 - 2 * 90)
+    assert shared["bytes"] == 7 * 1810 * 576 * 2 and shared["flops"] == got["flops"]
     assert glue.latent_decode_call(CFG, 0) == {"bytes": 0.0, "flops": 0.0}
     # the whole pool once: ISSUE 33's 1.06 GB, 1.3 ms at 819 GB/s
     full = glue.latent_decode_call(CFG, 8 * 16384)
@@ -169,17 +172,28 @@ def test_latent_decode_call_by_hand():
 
 def test_latent_chunk_call_by_hand():
     """512 real tokens from position 4096: token t attends 4096 + t + 1 keys;
-    the 4608 keys are read once a chunk and a layer."""
+    the 4608 keys are read once a chunk and a layer. The FLOPs are the
+    cheaper form's at the chunk's own size: absorbed, 278 528 a pair; or each
+    of the 4608 keys' 128 heads up-projected once a chunk (2 x 512 x 128 x
+    (128 + 128) = 33 554 432 a key) and a pair at 2 x 128 x (128 + 64 + 128) =
+    81 920: ROADMAP S14's 147 kFLOP a pair at a long context."""
     got = glue.latent_chunk_call(CFG, 4096, 512)
     pairs = sum(4096 + t + 1 for t in range(512))
     assert pairs == 512 * 4096 + 512 * 513 // 2
-    assert got["flops"] == 7 * pairs * 278_528 and got["bytes"] == 7 * 4608 * 1152
-    # ISSUE 33: ~5 k keys a query, 5.0e12 FLOP a chunk, 25 ms at peak
-    assert glue.latent_chunk_call(CFG, 4864, 512)["flops"] == pytest.approx(5.1e12, rel=2e-2)
+    up = 4608 * 33_554_432 + pairs * 81_920
+    assert up < pairs * 278_528 and glue.chunk_pair_flops(CFG, 4608, pairs) == up
+    assert got["flops"] == 7 * up and got["bytes"] == 7 * 4608 * 1152
+    assert up / pairs == pytest.approx(151.3e3, rel=1e-3)
+    assert 33_554_432 / 512 + 81_920 == 147_456  # as the context grows
+    # ISSUE 33's chunk at ~5 k keys a query: 2.8e12 FLOP where the absorbed form has 5.1e12
+    assert glue.latent_chunk_call(CFG, 4864, 512)["flops"] == pytest.approx(2.79e12, rel=1e-2)
     # a last chunk of 416 tokens in a bucket of 512 counts its 416
     short = glue.latent_chunk_call(CFG, 3584, 416)
-    assert short["flops"] == 7 * (416 * 3584 + 416 * 417 // 2) * 278_528
+    assert short["flops"] == 7 * (4000 * 33_554_432 + (416 * 3584 + 416 * 417 // 2) * 81_920)
+    # few queries a key: up-projecting costs more than it saves, absorbed counts
     assert glue.latent_chunk_call(CFG, 0, 1) == {"bytes": 7.0 * 1152, "flops": 7.0 * 278_528}
+    tail = glue.latent_chunk_call(CFG, 4096, 100)
+    assert tail["flops"] == 7 * (100 * 4096 + 100 * 101 // 2) * 278_528
     # compute-bound by two orders of magnitude
     assert got["flops"] / 197e12 > 100 * got["bytes"] / 819e9
 
@@ -221,17 +235,31 @@ def _reader(monkeypatch, module, sc, notes):
     return module
 
 
+def _kept(env, notes, shared=0):
+    """The runner's record of the decode steps it dispatched: `rows` rows of
+    `keys` keys in all, `shared` of them in blocks another row holds too."""
+    env.samples["decode_steps"] = [
+        {"keys": [n["keys"] // n["rows"]] * (n["rows"] - 1)
+                 + [n["keys"] - n["keys"] // n["rows"] * (n["rows"] - 1)],
+         "distinct": n["keys"] - shared} for n in notes]
+    return env
+
+
 def test_the_decode_reader_pairs_whole_runs_with_annotated_steps(monkeypatch):
     args = spec.load("layer_metrics", "latent_decode_roofline")["args"]
     ops, runs = _runs("jit_step", 7, STEP, 3)
     sc = scopes.Scopes(ops={DEV: sorted(ops, key=lambda o: o[2])}, runs={DEV: runs})
     notes = [{"rows": 3, "keys": 2000}, {"rows": 3, "keys": 2003}, {"rows": 2, "keys": 1500}]
     env, said = _env()
-    got = _reader(monkeypatch, latent_decode_roofline, sc, notes).read(args, env)
+    got = _reader(monkeypatch, latent_decode_roofline, sc, None).read(args, _kept(env, notes))
     need = sum(glue.latent_decode_call(CFG, n["keys"])["flops"] for n in notes)
     assert got == pytest.approx(100 * (need / 197e12) / (3 * 7 * 2e-6))
-    assert "3 annotated dispatches, 3 runs" in said[-1] and "3 paired" in said[-1]
+    assert "3 dispatches kept, 3 runs" in said[-1] and "3 paired" in said[-1]
     assert "compute-bound" in said[-1]
+    # rows behind one head: fewer bytes, the same FLOPs, and the FLOPs bound it
+    env, said = _env()
+    assert latent_decode_roofline.read(args, _kept(env, notes, shared=900)) == pytest.approx(got)
+    assert "compute-bound" in said[-1] and f"{7 * (5503 - 2700) * 1152:.3e} bytes" in said[-1]
 
 
 def test_a_slice_whose_first_and_last_step_are_cut_still_gives_a_number(monkeypatch):
@@ -245,18 +273,18 @@ def test_a_slice_whose_first_and_last_step_are_cut_still_gives_a_number(monkeypa
     sc = scopes.Scopes(ops={DEV: sorted(ops + cut, key=lambda o: o[2])}, runs={DEV: runs})
     notes = [{"rows": 2, "keys": 1000 + i} for i in range(5)]  # the fifth's run was cut
     env, said = _env()
-    got = _reader(monkeypatch, latent_decode_roofline, sc, notes).read(args, env)
+    got = _reader(monkeypatch, latent_decode_roofline, sc, None).read(args, _kept(env, notes))
     need = sum(glue.latent_decode_call(CFG, n["keys"])["flops"] for n in notes[:4])
     assert got == pytest.approx(100 * (need / 197e12) / (4 * 7 * 2e-6))
-    assert "5 annotated dispatches, 4 runs of the program in the slice, 4 of them whole, 4 paired" in said[-1]
+    assert "5 dispatches kept, 4 runs of the program in the slice, 4 of them whole, 4 paired" in said[-1]
     # a run on the modules line that lacks a call is not whole
     short = scopes.Scopes(ops={DEV: sorted(ops[:-4], key=lambda o: o[2])}, runs={DEV: runs})
     env, said = _env()
-    _reader(monkeypatch, latent_decode_roofline, short, notes[:4]).read(args, env)
+    _reader(monkeypatch, latent_decode_roofline, short, None).read(args, _kept(env, notes[:4]))
     assert "4 runs of the program in the slice, 3 of them whole, 3 paired" in said[-1]
     # more whole runs than annotations: the leading run was dispatched before the slice
     env, said = _env()
-    got = _reader(monkeypatch, latent_decode_roofline, sc, notes[:3]).read(args, env)
+    got = _reader(monkeypatch, latent_decode_roofline, sc, None).read(args, _kept(env, notes[:3]))
     need = sum(glue.latent_decode_call(CFG, n["keys"])["flops"] for n in notes[:3])
     assert got == pytest.approx(100 * (need / 197e12) / (3 * 7 * 2e-6))
     assert 0 < got <= 100
@@ -292,16 +320,18 @@ def test_a_program_without_the_scope_or_a_run_without_annotations_gives_no_numbe
         ops={DEV: [("jit(step)/TransformerLM/layers_0/attn/cache_attention/x", 7, 0, 1000)]},
         runs={DEV: [("jit_step", 7, 0, 2000)]})
     notes = [{"rows": 1, "keys": 5}]
-    assert _reader(monkeypatch, latent_decode_roofline, bare, notes).read(dec, _env()[0]) is None
+    assert _reader(monkeypatch, latent_decode_roofline, bare, notes).read(
+        dec, _kept(_env()[0], notes)) is None
     assert _reader(monkeypatch, latent_chunk_roofline, bare, notes).read(chk, _env()[0]) is None
     ops, runs = _runs("jit_step", 7, STEP, 2)
     sc = scopes.Scopes(ops={DEV: ops}, runs={DEV: runs})
     assert _reader(monkeypatch, latent_decode_roofline, sc, []).read(dec, _env()[0]) is None
+    assert _reader(monkeypatch, latent_chunk_roofline, sc, []).read(chk, _env()[0]) is None
     other = spec.load("configs", "mistral-7b-v0.3-d16")
     assert latent_decode_roofline.read(dec, _env(other)[0]) is None
     assert latent_chunk_roofline.read(chk, _env(other)[0]) is None
     monkeypatch.undo()
-    assert latent_decode_roofline.read(dec, _env()[0]) is None  # no trace file
+    assert latent_decode_roofline.read(dec, _kept(_env()[0], notes)) is None  # no trace file
     assert latent_chunk_roofline.read(chk, _env()[0]) is None
     for name in ("decode_latent_attention_ms", "prefill_latent_attention_ms"):
         m = spec.load("layer_metrics", name)
@@ -371,7 +401,7 @@ def test_the_cell_reports_throughput_and_lists_only_what_moves_what_it_reports()
     new = {"decode_latent_attention_ms", "prefill_latent_attention_ms",
            "prefill_latent_cache_attention_ms", "latent_decode_roofline",
            "latent_chunk_roofline"}
-    assert new <= set(cell["per_layer"]) and len(cell["per_layer"]) == 16
+    assert new | {"serve_mfu_pct"} <= set(cell["per_layer"]) and len(cell["per_layer"]) == 17
     assert not {"moe_decode_roofline", "paged_decode_roofline"} & set(cell["per_layer"])
     for name in new:
         m = cell["per_layer"][name]
@@ -380,9 +410,10 @@ def test_the_cell_reports_throughput_and_lists_only_what_moves_what_it_reports()
         assert (m["unit"], m["better"]) == (("%", "higher") if roof else ("ms", "lower"))
         assert m["layer"] == ("kernels" if roof else "model")
         listed = next(x for x in BENCH["per_layer"] if x["name"] == name)
-        assert listed["workloads"] == [CELL]
-    others = [n for n in spec.names("workloads") if n != CELL]
-    assert not [n for n in others if new & set(spec.load("workloads", n)["per_layer"])]
+        assert CELL in listed["workloads"]
+        # every cell that lists one of the five runs a latent configuration
+        for other in listed["workloads"]:
+            assert hasattr(modelglue.glue(spec.load_cell(other)["config"]), "latent_decode_call")
     c = cell["correctness"]
     eng = cell["traffic"]["engine"]
     assert (c["prompt_tokens"], c["decode_positions"], c["last_positions"]) == (4000, 8, 256)

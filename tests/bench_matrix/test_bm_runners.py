@@ -149,6 +149,8 @@ def test_token_progress_counts_prompts_at_prefill_and_tokens_as_emitted():
 
 
 def test_decode_probe_keeps_the_keys_of_the_rows_that_decode_and_of_no_other():
+    """The runner no longer wraps `engine._step` in it; the program's own tests
+    still hold `engine.last_step` against it, so it keeps its meaning."""
     import types
 
     import numpy as np
@@ -168,6 +170,90 @@ def test_decode_probe_keeps_the_keys_of_the_rows_that_decode_and_of_no_other():
     engine._step()
     assert probe.keys == [[17, 100], [18]]
     assert len(calls) == 2
+
+
+def test_the_runner_flushes_on_both_sides_of_the_trace_and_keeps_what_every_call_dispatched(
+        monkeypatch, tmp_path):
+    """The traced slice holds whole steps only: everything outstanding is read
+    back just before the trace starts and again as the last thing inside it
+    (`stop_trace` cuts what is in flight). And what the runner keeps of a call
+    is the engine's own record of it, `engine.last_step`, call for call: the
+    window's chunks and decoding rows (`computed`), the slice's decode steps."""
+    import contextlib
+
+    import jax
+
+    from _tiny import context, tiny_cell
+    from bench_matrix.runners import serve
+    from pytorch_distributed_example_tpu.serve import ServeEngine
+
+    log, records = [], []
+    flush, step = ServeEngine.flush, ServeEngine.step
+
+    def counted_flush(self, cause="caller"):
+        log.append(("flush", len(self._inflight)))
+        return flush(self, cause)
+
+    def recorded_step(self):
+        busy = step(self)
+        if log and log[0] == "loop":
+            records.append((log.count("start"), self.last_step))
+        return busy
+
+    @contextlib.contextmanager
+    def tracing():
+        log.append("start")
+        try:
+            yield
+        finally:
+            log.append("stop")
+
+    class Loop(serve._Loop):
+        def start(self, horizon_s):
+            log[:] = ["loop"]  # the warm-up's and the check's calls are not the loop's
+            super().start(horizon_s)
+
+    monkeypatch.setattr(ServeEngine, "flush", counted_flush)
+    monkeypatch.setattr(ServeEngine, "step", recorded_step)
+    monkeypatch.setattr(serve, "_Loop", Loop)
+    cell = tiny_cell("serve_decode_c32")
+    ctx = context(1.0, jax.devices()[:1], trace_dir=str(tmp_path))
+    ctx.tracing = tracing
+    try:
+        result = serve.run(cell, ctx)
+    finally:
+        ctx.compiles.close()
+    # one flush before the trace with a call's work in flight, one inside it
+    # as its last statement: nothing is outstanding when the trace stops
+    marks = [e for e in log if e in ("start", "stop") or e[0] == "flush"]
+    assert [m if isinstance(m, str) else m[0] for m in marks] == [
+        "flush", "start", "flush", "stop"]
+    assert marks[0][1] >= 1 and marks[2][1] >= 1
+    assert log[-1] == "stop" and log[-2][0] == "flush"
+    # the slice's kept steps are the records of the calls made inside the trace
+    traced = [rec for inside, rec in records if inside and rec.decode_keys]
+    kept = result.samples["decode_steps"]
+    assert len(traced) == len(kept) > 3
+    for rec, step_kept in zip(traced, kept):
+        assert step_kept["keys"] == list(rec.decode_keys)
+        assert step_kept["distinct"] == sum(rec.decode_keys)  # no prefix cache: nothing shared
+    # the window's record is every call's between its first and its last step
+    computed = result.samples["computed"]
+    calls = [rec for inside, rec in records if not inside]
+    chunks = [[c[1], c[2]] for rec in calls for c in rec.chunks]
+    keys = [k for rec in calls for k in rec.decode_keys]
+    n = len(computed["decode_keys"])
+    assert 0 < n < len(keys) and n > len(keys) // 2  # the warm-up's calls are not the window's
+    assert computed["decode_keys"] == keys[-n:]
+    assert computed["chunks"] == chunks[-len(computed["chunks"]):] and computed["chunks"]
+    # without a traced slice the runner keeps no step and flushes nothing
+    log[:] = []
+    ctx = context(0.5, jax.devices()[:1])
+    try:
+        result = serve.run(cell, ctx)
+    finally:
+        ctx.compiles.close()
+    assert result.samples["decode_steps"] == [] and not [e for e in log if e[0] == "flush"]
 
 
 def test_numbers_compared_are_repeated_as_the_last_lines_of_standard_error(

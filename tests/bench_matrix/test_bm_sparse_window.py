@@ -12,8 +12,9 @@ import pytest
 from bench_matrix import flops, spec, traffic_gen
 from bench_matrix.glue import sparse_window as glue
 from bench_matrix.readers import ReadEnv, moe_decode_roofline, moe_steps, window_decode_roofline
-from bench_matrix.reduce import xplane
+from bench_matrix.reduce import scopes, xplane
 
+from _tiny import kept_steps as _kept
 from test_bm_specs import check_cut
 
 CFG = spec.load("configs", "laguna-xs.2-d5")
@@ -110,8 +111,8 @@ def test_the_cell_reports_throughput_and_lists_only_what_moves_what_it_reports()
     assert moved == {"serve_tokens_per_s", "setup_s"}
     assert {"decode_moe_ms", "prefill_moe_ms", "decode_window_attention_ms",
             "moe_experts_hit_mean", "moe_decode_roofline",
-            "window_decode_roofline"} <= set(cell["per_layer"])
-    assert len(cell["per_layer"]) == 14
+            "window_decode_roofline", "serve_mfu_pct"} <= set(cell["per_layer"])
+    assert len(cell["per_layer"]) == 15
 
 
 def test_the_check_s_replay_has_the_shapes_of_every_engine_that_serves_the_configuration():
@@ -181,21 +182,39 @@ def _env(trace, samples):
                    memory_peak_bytes=0, say=said.append), said
 
 
-def test_window_decode_roofline_reads_calls_that_pair_with_steps():
+def _step_runs(n, path, calls, each_ps=1_000_000):
+    """`n` runs of `jit_step`, each with `calls` operations at `path`."""
+    gap = 100_000_000
+    return scopes.Scopes(
+        ops={"/device:TPU:0": [(path.format(k), 7, i * gap + 1000 + k * 5_000_000, each_ps)
+                               for i in range(n) for k in range(calls)]},
+        runs={"/device:TPU:0": [("jit_step", 7, i * gap, gap - 1_000_000) for i in range(n)]})
+
+
+def test_window_decode_roofline_reads_runs_that_pair_with_steps(monkeypatch):
+    from bench_matrix.readers import scope_time
+
     args = spec.load("layer_metrics", "window_decode_roofline")["args"]
     steps = [[100, 512, 3000], [101, 513, 3001]]
-    env, said = _env(_trace(2 * 5), {"decode_keys": steps})
+    # the window layers' calls sit under `window_attention/cache_attention`
+    path = "jit(step)/TransformerLM/layers_{}/attn/window_attention/cache_attention/pallas_call"
+    monkeypatch.setattr(scope_time, "_scopes", lambda env: _step_runs(2, path, 5))
+    env, said = _env(_trace(0), _kept(steps))
     got = window_decode_roofline.read(args, env)
     need = sum(glue.window_decode_call(CFG, s, 2)["bytes"] for s in steps)
-    assert got == pytest.approx(100 * (need / 819e9) / (10 * 1000e-9))
-    assert "windowed decode kernel" in said[0]
-    # calls that are not one a layer for every step kept: no number
-    env, said = _env(_trace(9), {"decode_keys": steps})
-    assert window_decode_roofline.read(args, env) is None and "no number" in said[0]
+    assert got == pytest.approx(100 * (need / 819e9) / (10 * 1e-6))
+    assert "windowed decode attention" in said[0] and "2 paired" in said[0]
+    # one run more than steps kept: the leading run goes, both counts are said
+    monkeypatch.setattr(scope_time, "_scopes", lambda env: _step_runs(3, path, 5))
+    env, said = _env(_trace(0), _kept(steps))
+    assert window_decode_roofline.read(args, env) == pytest.approx(got)
+    assert "2 dispatches kept, 3 runs of the program in the slice" in said[0]
     # no traced slice, no steps kept, a model whose glue has no such count
-    assert window_decode_roofline.read(args, _env(None, {"decode_keys": steps})[0]) is None
-    assert window_decode_roofline.read(args, _env(_trace(10), {"decode_keys": None})[0]) is None
-    env, _ = _env(_trace(10), {"decode_keys": steps})
+    assert window_decode_roofline.read(args, _env(_trace(0), {})[0]) is None
+    assert window_decode_roofline.read(args, _env(_trace(0), {"decode_steps": []})[0]) is None
+    monkeypatch.setattr(scope_time, "_scopes", lambda env: None)
+    assert window_decode_roofline.read(args, _env(None, _kept(steps))[0]) is None
+    env, _ = _env(_trace(0), _kept(steps))
     env.cell = {"config": spec.load("configs", "mistral-7b-v0.3-d16"), "name": "x"}
     assert window_decode_roofline.read(args, env) is None
 
@@ -204,7 +223,7 @@ def test_the_moe_readers_give_nothing_without_a_trace_or_annotations():
     hit = spec.load("layer_metrics", "moe_experts_hit_mean")["args"]
     roof = spec.load("layer_metrics", "moe_decode_roofline")["args"]
     for trace in (None, _trace(10)):  # no slice; a slice whose file is not there
-        env, _ = _env(trace, {"decode_keys": [[5]]})
+        env, _ = _env(trace, _kept([[5]]))
         assert moe_steps.read(hit, env) is None
         assert moe_decode_roofline.read(roof, env) is None
 
@@ -217,7 +236,6 @@ def test_the_annotations_of_a_traced_engine_pair_with_its_steps(tmp_path, monkey
     import jax
 
     from bench_matrix import modelglue, run
-    from bench_matrix.reduce import scopes
     from pytorch_distributed_example_tpu.serve import ServeEngine
 
     small = dict(
@@ -236,16 +254,25 @@ def test_the_annotations_of_a_traced_engine_pair_with_its_steps(tmp_path, monkey
     trace_dir = tmp_path / "trace" / "tiny"
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level, opts.host_tracer_level = 0, 2
+    # as `runners/serve.py::run` does: everything read back before the trace
+    # starts and before it stops, so a step's annotation (written when it is
+    # READ BACK, a call after its dispatch) lies in the slice with its run
     recorded, kept = [], []
+    engine.flush()
     jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
     try:
-        for _ in range(5):
-            kept.append([int(engine.cache.lengths[s]) + 1 for s in sorted(engine._decoding)])
+        for i in range(5):
             engine.step()
-            recorded.append((engine.metrics.moe_assignments, list(engine.metrics.moe_experts_hit)))
+            kept.append(list(engine.last_step.decode_keys))
+            if i:  # the call read the step before it back
+                recorded.append((engine.metrics.moe_assignments,
+                                 list(engine.metrics.moe_experts_hit)))
+        engine.flush()
+        recorded.append((engine.metrics.moe_assignments, list(engine.metrics.moe_experts_hit)))
     finally:
         jax.profiler.stop_trace()
-    env, said = _env(xplane.Trace(devices={"cpu": []}), {"decode_keys": kept})
+    assert all(len(k) == 1 for k in kept)  # every call of the slice dispatched a step
+    env, said = _env(xplane.Trace(devices={"cpu": []}), _kept(kept))
     env.cell = {"config": small, "name": "tiny"}
     got = moe_steps.steps(env)
     assert [(s["assignments"], s["experts_hit"]) for s in got] == recorded
@@ -256,14 +283,19 @@ def test_the_annotations_of_a_traced_engine_pair_with_its_steps(tmp_path, monkey
     assert moe_decode_roofline.read(
         spec.load("layer_metrics", "moe_decode_roofline")["args"], env) is None
     # with the time of five runs handed to it, it pairs steps and counts
-    sc = scopes.Scopes(
-        ops={"/device:TPU:0": [("jit(step)/mlp/moe/experts/ragged_dot", 7, i * 10_000_000,
-                                2_000_000) for i in range(5)]},
-        runs={"/device:TPU:0": [("jit_step", 7, i * 10_000_000, 9_000_000) for i in range(5)]})
-    monkeypatch.setattr(moe_decode_roofline, "_scopes", lambda env: sc)
+    from bench_matrix.readers import scope_time
+
+    path = "jit(step)/mlp/moe/experts/ragged_dot"
+    monkeypatch.setattr(scope_time, "_scopes", lambda env: _step_runs(5, path, 1, 2_000_000))
     args = spec.load("layer_metrics", "moe_decode_roofline")["args"]
     share = moe_decode_roofline.read(args, env)
     need = sum(glue.moe_decode_call(small, 1, a, h, 4)["bytes"] for a, h in recorded)
     assert share == pytest.approx(100 * (need / 819e9) / (5 * 2e-6))
-    env.samples = {"decode_keys": kept[:-1]}  # a step the runner did not keep
-    assert moe_decode_roofline.read(args, env) is None and "no number" in said[-1]
+    assert "5 paired" in said[-2] and "experts hit a layer" in said[-1]
+    # a run fewer than annotated steps (the last one cut): the trailing
+    # annotation goes, both counts are said, the share is the four pairs'
+    monkeypatch.setattr(scope_time, "_scopes", lambda env: _step_runs(4, path, 1, 2_000_000))
+    share = moe_decode_roofline.read(args, env)
+    need = sum(glue.moe_decode_call(small, 1, a, h, 4)["bytes"] for a, h in recorded[:4])
+    assert share == pytest.approx(100 * (need / 819e9) / (4 * 2e-6))
+    assert "5 dispatches kept, 4 runs of the program in the slice" in said[-2]
