@@ -68,6 +68,17 @@ Shape of the decode kernel (design per /opt/skills/guides/pallas_guide.md):
   keys past the length. That spends KV times the needed MXU work on a
   memory-bound step instead of strided sub-tile loads of single heads
   out of a packed page.
+* The LATENT decode kernel (`latent_decode_attention`: a pool of one row
+  of W values a token, which every head scores whole and whose leading
+  `rank` values are its values) is the same program with the same two
+  kinds of item, from the same `_work_list(..., share=True)`, and the
+  easier case: a page has no KV heads to separate, so a (row, block) item
+  is a row's H absorbed query heads against the block's T rows, and a
+  SHARED item stacks `_latent_stacked_rows(H)` rows of its group with all
+  their heads, (rows * H, W) against the ONE copy: score product, softmax
+  update and value product over the same copy, in `LATENT_PASS_CHAINS`
+  independent parts of the stack. The waiting state, `cont` and the empty
+  shared list are as above.
 
 The chunk kernel is the same design carried to L queries a row, where
 the work is compute and not memory (`_chunk_kernel`):
@@ -140,6 +151,17 @@ LATENT_QUERY_BLOCK = 32
 #: one float32 sublane tile, so that ONE query head of all of them is one
 #: strided vector of the stacked group
 SHARED_ROWS = 8
+#: stacked query rows (rows of a group x their heads) a shared block of the
+#: LATENT decode kernel meets in one product (`_latent_stacked_rows`): a pass
+#: of 256 takes 1.87 us on a v5e and one of 512 2.67, so four groups of 8
+#: rows x 32 heads behind 768 pages each cost 0.446 against 0.600 ms a call,
+#: and groups of 12, 9, 6, 5 cost 0.620 against 0.657 — PERF.md, PR 41.
+LATENT_STACKED_QUERIES = 256
+#: independent parts a stacked pass computes its rows in (loads, then the
+#: parts' chains, then stores): 1 / 2 / 4 take 0.619 / 0.604 / 0.595 ms a call
+#: at groups of 12, 9, 6, 5 and 0.446 / 0.436 / 0.430 at four of 8 — PERF.md,
+#: PR 41
+LATENT_PASS_CHAINS = 4
 #: what the prefetched scalars (tables, work list) may take of the 1 MiB
 #: of scalar memory of a TensorCore; the compiler keeps the rest.
 SMEM_BYTES = 768 * 1024
@@ -266,7 +288,11 @@ def decode_shares(pool, window=None) -> bool:
     was a shared list (`_kernel(..., share=False)`: the only other
     variant). `paged_kernel` (the scalars to count), `paged_decode_
     attention` (which body to trace) and `serve.decode.step_shares_blocks`
-    (whether the engine counts) all ask here."""
+    (whether the engine counts) all ask here. A LATENT pool (3-D: no KV
+    heads to separate) shares wherever `_latent_kernel_for` gives its decode
+    kernel: `_latent_decode_kernel` has the one form."""
+    if len(pool.shape) == 3:
+        return window is None
     kv = pool.shape[2] // _head_shards(pool.shape[2])
     return window is None and _heads_split(pool.dtype, kv)
 
@@ -347,7 +373,8 @@ def _latent_kernel_for(L: int, pool, block_tables, rank):
     row), pages of whole sublane tiles, rows and values that fill whole
     lane tiles (W and `rank` multiples of 128: a page is one aligned copy
     and the value product reads a lane-aligned slice of the page it
-    scored), and its prefetched scalars within scalar memory.
+    scored), and its prefetched scalars within scalar memory (the decode
+    kernel's with the shared list).
     "latent_decode":
     one query token a row. "latent_chunk": more, in whole sublane tiles
     and whole query blocks of `LATENT_QUERY_BLOCK`."""
@@ -359,8 +386,11 @@ def _latent_kernel_for(L: int, pool, block_tables, rank):
     if bs % tile or W % 128 or not rank or rank % 128:
         return None
     if L == 1:
-        items = B * -(-nb // _pages_per_block(bs, nb))
-        fits = 4 * (B * nb + 2 * items + 2 * B + 1) <= SMEM_BYTES
+        P = _pages_per_block(bs, nb)
+        items = B * -(-nb // P)
+        # the shared list, as `paged_kernel` counts it for a K/V pool
+        shared = 2 * B + 3 * B * (nb // P) + 1
+        fits = 4 * (B * nb + 2 * items + 2 * B + 1 + shared) <= SMEM_BYTES
         return "latent_decode" if fits else None
     if (
         L % tile == 0
@@ -1217,26 +1247,47 @@ def gather_paged_latent(pool, block_tables):
     return pool[block_tables].reshape(B, nb * bs, W)
 
 
+def _latent_stacked_rows(H: int) -> int:
+    """Rows of a group that meet a shared latent block at a time: whole
+    sublane tiles of `SHARED_ROWS` (what `_shared_list`'s `cont` counts a
+    stacked pass as holding), and enough of them that the rows' H absorbed
+    query heads fill the MXU's passes over a key tile: `LATENT_STACKED_QUERIES`
+    stacked query rows or more. From the head count alone: 8 rows at 32
+    heads and at 128, 16 at 16."""
+    return SHARED_ROWS * max(1, -(-LATENT_STACKED_QUERIES // (SHARED_ROWS * H)))
+
+
 def _latent_decode_kernel(
     tables_ref, n_pages_ref, last_ref, item_row_ref, item_blk_ref,
-    n_items_ref, q_ref, pool_hbm, o_ref, buf, sems, m_s, l_s, acc_s,
+    n_items_ref, skip_ref, sh_row_ref, sh_off_ref, n_shared_ref, nxt_ref,
+    cont_ref, q_ref, pool_hbm, o_ref, buf, sems, m_s, l_s, acc_s,
+    q_g, m_g, l_g, acc_g, m_p, l_p, acc_p, member, count,
     *, scale, nb, P, rank,
 ):
-    """`_kernel` for a latent pool: a page is `bs` rows of W values that
-    every query head scores whole and whose leading `rank` values are its
-    values too, so ONE copy a page serves both products and no column
-    belongs to a foreign head."""
-    H = q_ref.shape[1]
+    """`_kernel(..., share=True)` for a latent pool: a page is `bs` rows of
+    W values that every query head scores whole and whose leading `rank`
+    values are its values too, so ONE copy a page serves both products, no
+    column belongs to a foreign head, and a shared block meets the stacked
+    rows' heads in one product: nothing to separate."""
+    B, H = q_ref.shape[:2]
     bs = pool_hbm.shape[1]
     T = P * bs  # keys a compute block
+    nbs = nxt_ref.shape[0] // B
+    Hs = q_g.shape[0] // member.shape[0]  # a stacked row's slot: whole tiles
     n_items = n_items_ref[0]
+    n_shared = n_shared_ref[0]
     precision = _precision(buf.dtype)
+    start, wait = (lambda cp: cp.start()), (lambda cp: cp.wait())
     col = lax.broadcasted_iota(jnp.int32, (H, T), 1)
     # pages a block does not have keep what the buffer held, and the rows
     # are values too: zero once so that 0 * stale is never 0 * NaN; rows
     # with no work item return zeros
     buf[...] = jnp.zeros_like(buf)
     o_ref[...] = jnp.zeros_like(o_ref)
+    # a row that has attended nothing yet
+    m_p[...] = jnp.full(m_p.shape, NEG_INF, jnp.float32)
+    l_p[...] = jnp.zeros(l_p.shape, jnp.float32)
+    acc_p[...] = jnp.zeros(acc_p.shape, jnp.float32)
 
     def page_copies(item, slot, fn):
         row = item_row_ref[item]
@@ -1247,27 +1298,145 @@ def _latent_decode_kernel(
             sems, slot,
         )
 
-    @pl.when(n_items > 0)
+    def shared_item(item):
+        """(the group's first row, block) of item `item` of the shared
+        list, and where `nxt` and `cont` have them."""
+        row = sh_row_ref[item]
+        blk = item - sh_off_ref[row]
+        return row, blk, row * nbs + blk
+
+    def shared_copies(item, slot, fn):
+        """Block `item` of the shared list: whole, out of its group's first
+        row's table."""
+        row, blk, _ = shared_item(item)
+        _page_copies(
+            fn, tables_ref, row * nb + blk * P, P, (pool_hbm,), (buf,), sems,
+            slot,
+        )
+
+    # the two lists are one queue of copies, the shared list first: item
+    # i + 1's pages are in flight while item i computes
+    @pl.when(n_shared > 0)
     def _():
-        page_copies(0, 0, lambda cp: cp.start())
+        shared_copies(0, 0, start)
+
+    @pl.when((n_shared == 0) & (n_items > 0))
+    def _():
+        page_copies(0, 0, start)
+
+    def shared_body(item, carry):
+        """Item `item` of the shared list, a block of the group whose first
+        row is `sh_row[item]`: ONE copy of its pages, and the rows of the
+        group — walked by `nxt`, as many at a time as `member` holds — meet
+        it stacked, all H heads of each: (rows * H, W) queries against the
+        (T, W) copy, one online-softmax update, one value product over the
+        same copy. Every key of a shared block is attended by every row (the
+        block is full): nothing is masked. A row's running max, sum and
+        accumulator wait in `m_p`, `l_p`, `acc_p` for its first item of the
+        (row, block) list, and between two shared items unless the second
+        continues the first (`cont`): then the rows stay stacked."""
+        slot = item % 2
+
+        @pl.when(item + 1 < n_shared)
+        def _():
+            shared_copies(item + 1, 1 - slot, start)
+
+        @pl.when((item + 1 == n_shared) & (n_items > 0))
+        def _():
+            page_copies(0, 1 - slot, start)
+
+        first_row, blk, at = shared_item(item)
+        shared_copies(item, slot, wait)
+        slot_of = lambda i: pl.ds(pl.multiple_of(i * Hs, Hs), H)
+        # the rows are stacked already (the item before left them so) /
+        # are to stay so for the item behind
+        stay = cont_ref[at] == 1
+        keep = cont_ref[shared_item(jnp.minimum(item + 1, n_shared - 1))[2]] == 1
+        keep &= item + 1 < n_shared
+
+        def some_rows(row):
+            def stack(carry):
+                row, n = carry
+                member[n] = row
+                q_g[slot_of(n), :] = q_ref[row]
+                m_g[slot_of(n), :] = m_p[row]
+                l_g[slot_of(n), :] = l_p[row]
+                acc_g[slot_of(n), :] = acc_p[row]
+                return nxt_ref[row * nbs + blk], n + 1
+
+            row, n = lax.while_loop(
+                lambda c: (c[0] < B) & (c[1] < member.shape[0]), stack,
+                (jnp.where(stay, B, row), jnp.where(stay, count[0], 0)),
+            )
+            count[0] = n
+            # slots past n hold an earlier group's rows: computed, not kept
+            k = buf[slot]  # (T, W)
+            # the stack in `LATENT_PASS_CHAINS` independent parts: every
+            # part's loads, then their chains (product, row max, `exp`,
+            # product), then every store, so that one part's softmax runs
+            # under another's products (`_kernel`'s shared pass: PERF.md,
+            # PR 36)
+            part = q_g.shape[0] // LATENT_PASS_CHAINS
+            parts = [pl.ds(c * part, part) for c in range(LATENT_PASS_CHAINS)]
+            state = (m_g, l_g, acc_g)
+            qs = [q_g[rows, :] for rows in parts]
+            olds = [[ref[rows, :] for ref in state] for rows in parts]
+            ss = [
+                lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())), precision=precision,
+                    preferred_element_type=jnp.float32,
+                ) * scale for q in qs
+            ]  # (rows * Hs / parts, T) each
+            news = [
+                _online_softmax(s, k[:, :rank], *old, precision)
+                for s, old in zip(ss, olds)
+            ]
+            for rows, new in zip(parts, news):
+                for ref, value in zip(state, new):
+                    ref[rows, :] = value
+
+            def unstack(i, carry):
+                r = member[i]
+                m_p[r] = m_g[slot_of(i), :]
+                l_p[r] = l_g[slot_of(i), :]
+                acc_p[r] = acc_g[slot_of(i), :]
+
+                # a row with no item of its own (every page shared and
+                # whole) is finished by its last shared block
+                @pl.when(
+                    (blk + 1 == skip_ref[r])
+                    & ((blk + 1) * P >= n_pages_ref[r])
+                )
+                def _():
+                    o_ref[r] = (
+                        acc_g[slot_of(i), :] / l_g[slot_of(i), :]
+                    ).astype(o_ref.dtype)
+
+                return carry
+
+            lax.fori_loop(0, jnp.where(keep, 0, n), unstack, 0)
+            return row
+
+        lax.while_loop(lambda row: row < B, some_rows, first_row)
+        return carry
 
     def body(item, carry):
-        slot = item % 2
+        slot = (n_shared + item) % 2
 
         @pl.when(item + 1 < n_items)
         def _():
-            page_copies(item + 1, 1 - slot, lambda cp: cp.start())
+            page_copies(item + 1, 1 - slot, start)
 
         row = item_row_ref[item]
         blk = item_blk_ref[item]
 
-        @pl.when(blk == 0)
-        def _():
-            m_s[...] = jnp.full_like(m_s, NEG_INF)
-            l_s[...] = jnp.zeros_like(l_s)
-            acc_s[...] = jnp.zeros_like(acc_s)
+        @pl.when(blk == skip_ref[row])
+        def _():  # the row goes on from what its shared blocks left
+            m_s[...] = m_p[row]
+            l_s[...] = l_p[row]
+            acc_s[...] = acc_p[row]
 
-        page_copies(item, slot, lambda cp: cp.wait())
+        page_copies(item, slot, wait)
         q = q_ref[row]  # (H, W)
         k = buf[slot]  # (T, W)
         s = lax.dot_general(
@@ -1292,19 +1461,27 @@ def _latent_decode_kernel(
 
         return carry
 
+    lax.fori_loop(0, n_shared, shared_body, 0)
     lax.fori_loop(0, n_items, body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "rank", "interpret"))
 def _latent_decode_device(q, pool, block_tables, lengths, *, scale, rank, interpret):
     """The latent decode kernel's call; a `jax.jit` of its own for the
-    reason `_per_device` is one."""
+    reason `_per_device` is one. The work list is plain XLA OUTSIDE the
+    kernel's named scope (the layers' copies merge into one a step); under
+    it there is the one call."""
     B, H, W = q.shape
     nblk, bs, _ = pool.shape
     nb = block_tables.shape[1]
     P = _pages_per_block(bs, nb)
-    scalars = _work_list(block_tables, lengths, nblk, bs, P)
+    scalars = _work_list(block_tables, lengths, nblk, bs, P, share=True)
     vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
+    f32 = jnp.float32
+    R = _latent_stacked_rows(H)
+    # a stacked row's H heads sit in whole tiles of q's dtype and of float32
+    tile = 32 // q.dtype.itemsize
+    G = R * -(-H // tile) * tile
     with jax.named_scope("latent_decode_kernel"):
         return pl.pallas_call(
             functools.partial(
@@ -1318,9 +1495,20 @@ def _latent_decode_device(q, pool, block_tables, lengths, *, scale, rank, interp
                 scratch_shapes=[
                     pltpu.VMEM((2, P * bs, W), pool.dtype),
                     pltpu.SemaphoreType.DMA((1, 2)),
-                    pltpu.VMEM((H, 1), jnp.float32),
-                    pltpu.VMEM((H, 1), jnp.float32),
-                    pltpu.VMEM((H, rank), jnp.float32),
+                    pltpu.VMEM((H, 1), f32),
+                    pltpu.VMEM((H, 1), f32),
+                    pltpu.VMEM((H, rank), f32),
+                    # the stacked rows' queries and state; every row's
+                    # waiting state; who is stacked
+                    pltpu.VMEM((G, W), q.dtype),
+                    pltpu.VMEM((G, 1), f32),
+                    pltpu.VMEM((G, 1), f32),
+                    pltpu.VMEM((G, rank), f32),
+                    pltpu.VMEM((B, H, 1), f32),
+                    pltpu.VMEM((B, H, 1), f32),
+                    pltpu.VMEM((B, H, rank), f32),
+                    pltpu.SMEM((R,), jnp.int32),
+                    pltpu.SMEM((1,), jnp.int32),
                 ],
             ),
             out_shape=jax.ShapeDtypeStruct((B, H, rank), q.dtype),
@@ -1345,8 +1533,10 @@ def latent_decode_attention(
     rows at positions <= lengths[b] over all W values and sums their
     leading `rank` values: returns (B, H, rank) in q's dtype, which the
     caller up-projects a head at a time. A page is copied once for both
-    products. Callers check `paged_kernel` first; design, bounds and
-    precision contract are `paged_decode_attention`'s."""
+    products, and a block that several rows' tables hold once for all of
+    them (`shared_runs`; the module docstring has the two kinds of item).
+    Callers check `paged_kernel` first; design, bounds and precision
+    contract are `paged_decode_attention`'s."""
     if interpret is None:
         interpret = _interpret_default()
     return _latent_decode_device(

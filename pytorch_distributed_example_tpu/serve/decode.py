@@ -123,17 +123,26 @@ def layer_paths(
 
 def step_shares_blocks(cache, paths: dict, mesh=None, tp_axis: str = "tp") -> bool:
     """Whether the step whose `layer_paths` are `paths` reads a block that
-    several of its rows' tables hold ONCE a group: its full layers take
-    the decode kernel and that kernel shares for this pool
-    (`ops.paged_attention.decode_shares`: THE predicate, asked under the
-    context the programs apply the model under). The engine counts
-    `StepRecord.decode_shared_keys` where this says so."""
+    several of its rows' tables hold ONCE a group: every kind of its layers
+    that reads `cache.block_tables` (full, latent; a window layer has
+    tables of its own and shares nothing) takes its decode kernel, and that
+    kernel shares for the kind's pool (`ops.paged_attention.decode_shares`:
+    THE predicate, asked under the context the programs apply the model
+    under). The engine counts `StepRecord.decode_shared_keys` where this
+    says so."""
     from ..ops.paged_attention import decode_shares
 
-    if paths.get("full", (0, None))[1] != "decode_kernel":
-        return False
+    kinds = [
+        (paths[kind][1], kernel, aval)
+        for kind, kernel, aval in (
+            ("full", "decode_kernel", cache.pool_aval),
+            ("latent", "latent_decode_kernel", cache.latent_aval),
+        ) if kind in paths
+    ]
     with _kernel_partition(mesh, tp_axis):
-        return decode_shares(cache.pool_aval)
+        return bool(kinds) and all(
+            path == kernel and decode_shares(aval) for path, kernel, aval in kinds
+        )
 
 
 def kernel_layers(paths: dict) -> int:

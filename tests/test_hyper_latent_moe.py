@@ -8,6 +8,7 @@ published file's own keys, and compared with
 `bench_matrix/reference/hyper_latent_moe.py` (the NON-absorbed equations,
 the Sinkhorn loop a Python loop) on seeded weights in float32."""
 
+import functools
 import json
 from pathlib import Path
 
@@ -24,12 +25,14 @@ from pytorch_distributed_example_tpu.models.transformer import (
     TransformerLM, rope_table,
 )
 from pytorch_distributed_example_tpu.ops import (
-    latent_chunk_attention, latent_decode_attention, paged_kernel, pool_latent_width,
+    latent_chunk_attention, latent_decode_attention, paged_attention, paged_kernel,
+    pool_latent_width,
 )
 from pytorch_distributed_example_tpu.parallel.expert_parallel import dropless_moe
 from pytorch_distributed_example_tpu.serve import ServeEngine
 
 from test_latent_moe import BS, M, _einsum_reference, _pool_and_tables, _serve_by_hand
+from test_paged_attention import NBLK, SPAN, share_tables, work_list  # tables that share pages
 from test_sparse_window import Probe  # keeps every prefill chunk's (start, tokens, logits)
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -399,6 +402,241 @@ def test_the_latent_decode_kernel_at_32_heads_is_the_masked_einsum(lengths, dtyp
     assert not np.asarray(got[3], np.float32).any()
 
 
+# (shared pages, rows of the group), lengths, parked rows -> a row's shared
+# blocks, every shared item's rows; ten rows of 32 heads over tables of 40 pages
+# of 16 keys (2.5 compute blocks): one compiled kernel a dtype, the tables and
+# lengths its operands
+GROUPED = {
+    # rows 0-7 behind one 512-key head: ONE stacked pass a block, the rows
+    # staying stacked from the first block to the second
+    "a_group_of_a_whole_pass": (
+        [(32, range(8))], [600, 639, 530, 512, 513, 555, 620, 599, 77, 300], (),
+        [2] * 8 + [0, 0], [list(range(8))] * 2),
+    # all ten rows: a pass of eight and a pass of two a block
+    "a_group_larger_than_a_pass": (
+        [(32, range(10))], [600, 639, 530, 512, 513, 555, 620, 599, 527, 639], (),
+        [2] * 10, [list(range(10))] * 2),
+    "two_groups_of_different_shared_lengths": (
+        [(32, (1, 3, 4)), (16, (0, 5))], [300, 600, 77, 520, 639, 511, 0, 15, 16, 255], (),
+        [1, 2, 0, 2, 2, 1, 0, 0, 0, 0], [[0, 5], [1, 3, 4], [1, 3, 4]]),
+    # 33 pages shared: two whole blocks are read once, the block the run
+    # ends in is each row's own (its first page the same page in every row)
+    "a_run_that_ends_inside_a_compute_block": (
+        [(33, (0, 1, 2, 9))], [600, 639, 530, 40, 100, 256, 257, 300, 10, 575], (),
+        [2, 2, 2, 0, 0, 0, 0, 0, 0, 2], [[0, 1, 2, 9]] * 2),
+    # row 2's length is the head's last key: no item of its own, finished by
+    # its last shared block; row 0 writes the first key of a page of its own
+    "a_row_shared_but_for_the_token_it_writes": (
+        [(32, (0, 1, 2))], [512, 602, 511, 40, 100, 256, 257, 300, 10, 575], (),
+        [2, 2, 2] + [0] * 7, [[0, 1, 2]] * 2),
+    # row 1 is parked inside the group's range, row 3 ends inside the second
+    # block (its own, masked at its length) and shares only the first
+    "a_parked_row_inside_a_group_s_range": (
+        [(32, range(5))], [600, 639, 639, 356, 530, 40, 100, 256, 257, 300], (1,),
+        [2, 0, 2, 1, 2] + [0] * 5, [[0, 2, 3, 4], [0, 2, 4]]),
+    # four rows of 128 heads: a stacked pass is eight rows there too
+    "128_heads": ([(32, (0, 1, 2))], [600, 639, 511, 40], (), [2, 2, 2, 0], [[0, 1, 2]] * 2),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_programs(dtype, H):
+    scale = 192 ** -0.5 * 2.0048
+    kernel = jax.jit(lambda q, pool, tables, at: latent_decode_attention(
+        q, pool, tables, at, scale, rank=128, interpret=True))
+    reference = jax.jit(lambda q, pool, tables, at: _einsum_reference(
+        q[:, None], pool, tables, at[:, None], 128, scale)[:, 0])
+    rng = np.random.default_rng(H)
+    pool = jnp.asarray(rng.normal(size=(NBLK, 16, 256)), dtype)
+    return kernel, reference, pool, lambda B: jnp.asarray(rng.normal(size=(B, H, 256)), dtype)
+
+
+def grouped_check(dtype, tol, H, groups, lengths, parked):
+    """The kernel against the gather and the masked einsum on tables that
+    share pages; the host's count against the device's list. Returns
+    `work_list`'s (skip a row, the shared items, every item's rows)."""
+    tables = share_tables(groups, lengths, seed=len(lengths), parked=parked)
+    at = np.asarray(lengths, np.int32)
+    at[list(parked)] = SPAN - 1
+    kernel, reference, pool, q_of = _grouped_programs(dtype, H)
+    q = q_of(len(lengths))
+    got = np.asarray(kernel(q, pool, jnp.asarray(tables), jnp.asarray(at)), np.float32)
+    want = np.asarray(reference(q, pool, jnp.asarray(tables), jnp.asarray(at)), np.float32)
+    live = [r for r in range(len(lengths)) if r not in parked]
+    assert np.isfinite(got).all() and not got[list(parked)].any()
+    np.testing.assert_allclose(got[live], want[live], atol=tol)
+    skip, items, members = work_list(tables, at.tolist())
+    keys = paged_attention.shared_decode_keys(tables, at, NBLK, 16)
+    assert keys == sum(skip) * 256 == sum(len(m) for m in members) * 256
+    return skip, items, members
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-5), (jnp.bfloat16, 4e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(GROUPED))
+def test_the_latent_decode_kernel_reads_a_shared_block_once_a_group(case, dtype, tol):
+    """A block that several rows' tables hold is copied once and meets the
+    group's rows stacked, all their heads in one product
+    (`_latent_stacked_rows`: eight rows at 32 heads and at 128); what is
+    shared is `shared_runs`' to say, as for a K/V pool."""
+    H = 128 if case == "128_heads" else 32
+    assert paged_attention._latent_stacked_rows(H) == 8
+    *tables, skip, members = GROUPED[case]
+    assert grouped_check(dtype, tol, H, *tables)[::2] == (skip, members)
+
+
+def _row_block_kernel(
+    tables_ref, n_pages_ref, last_ref, item_row_ref, item_blk_ref, n_items_ref,
+    q_ref, pool_hbm, o_ref, buf, sems, m_s, l_s, acc_s, *, scale, nb, P, rank,
+):
+    """The latent decode kernel as it was before it had a shared list (PR 33's
+    text): every block of every row a (row, block) item."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+
+    H, T = q_ref.shape[1], P * pool_hbm.shape[1]
+    n_items = n_items_ref[0]
+    precision = paged_attention._precision(buf.dtype)
+    col = lax.broadcasted_iota(jnp.int32, (H, T), 1)
+    buf[...] = jnp.zeros_like(buf)
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def page_copies(item, slot, fn):
+        row = item_row_ref[item]
+        first = item_blk_ref[item] * P
+        have = jnp.minimum(n_pages_ref[row] - first, P)
+        paged_attention._page_copies(
+            fn, tables_ref, row * nb + first, have, (pool_hbm,), (buf,), sems, slot)
+
+    @pl.when(n_items > 0)
+    def _():
+        page_copies(0, 0, lambda cp: cp.start())
+
+    def body(item, carry):
+        slot = item % 2
+
+        @pl.when(item + 1 < n_items)
+        def _():
+            page_copies(item + 1, 1 - slot, lambda cp: cp.start())
+
+        row, blk = item_row_ref[item], item_blk_ref[item]
+
+        @pl.when(blk == 0)
+        def _():
+            m_s[...] = jnp.full_like(m_s, paged_attention.NEG_INF)
+            l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
+
+        page_copies(item, slot, lambda cp: cp.wait())
+        q, k = q_ref[row], buf[slot]
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=precision,
+                            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(col < last_ref[row] - blk * T + 1, s, paged_attention.NEG_INF)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[...] = alpha * acc_s[...] + jnp.dot(
+            p.astype(k.dtype), k[:, :rank], precision=precision,
+            preferred_element_type=jnp.float32)
+        m_s[...] = m_new
+
+        @pl.when((blk + 1) * P >= n_pages_ref[row])
+        def _():
+            o_ref[row] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+
+        return carry
+
+    lax.fori_loop(0, n_items, body, 0)
+
+
+def _row_block_call(q, pool, tables, lengths, scale, rank):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (B, H, W), (nblk, bs, _), nb = q.shape, pool.shape, tables.shape[1]
+    P = paged_attention._pages_per_block(bs, nb)
+    scalars = paged_attention._work_list(tables, lengths, nblk, bs, P)
+    vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_row_block_kernel, scale=scale, nb=nb, P=P, rank=rank),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=(1,),
+            in_specs=[vmem(), pl.BlockSpec(memory_space=pl.ANY)], out_specs=vmem(),
+            scratch_shapes=[
+                pltpu.VMEM((2, P * bs, W), pool.dtype), pltpu.SemaphoreType.DMA((1, 2)),
+                pltpu.VMEM((H, 1), jnp.float32), pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, rank), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), q.dtype), interpret=True,
+    )(*scalars, q, pool)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_without_a_common_page_the_latent_kernel_is_the_kernel_it_was(dtype):
+    """Tables in which no two rows hold one page: the shared list is empty,
+    the (row, block) list is what `_work_list` gave without one, and the
+    output is BIT-equal to the kernel that had none."""
+    lengths = (2 * 256 + 37, 5, 256 + 3, SPAN - 1)
+    rng = np.random.default_rng(8)
+    pool, tables = _pool_and_tables(rng, lengths, nblk=NBLK, nb=40, dtype=dtype)
+    q = jnp.asarray(rng.normal(size=(4, 32, 256)), dtype)
+    at, tables = jnp.asarray(lengths, jnp.int32), jnp.asarray(tables)
+    with_list = paged_attention._work_list(tables, at, NBLK, 16, 16, share=True)
+    without = paged_attention._work_list(tables, at, NBLK, 16, 16)
+    assert int(with_list[9][0]) == 0 and not np.asarray(with_list[6]).any()
+    assert all(np.array_equal(a, b) for a, b in zip(with_list[:6], without))
+    got = latent_decode_attention(q, pool, tables, at, 0.1, rank=128, interpret=True)
+    was = _row_block_call(q, pool, tables, at, 0.1, 128)
+    assert np.array_equal(np.asarray(got), np.asarray(was))
+    assert np.abs(np.asarray(got, np.float32)).max() > 0.01
+
+
+def test_the_latent_predicate_counts_the_shared_list_s_scalars():
+    """The shared list rides in scalar memory beside the (row, block) list:
+    32 rows of 4608 pages fit with it, 4800 fit only without (which is what
+    the predicate counted before the kernel had one)."""
+    pool = jax.ShapeDtypeStruct((16384, 16, 640), jnp.bfloat16)
+    tables = lambda nb: jax.ShapeDtypeStruct((32, nb), jnp.int32)
+    assert paged_kernel(1, pool, tables(4608), rank=512) == "latent_decode"
+    assert paged_kernel(1, pool, tables(4800), rank=512) is None
+    without = 4 * (32 * 4800 + 2 * 32 * 300 + 2 * 32 + 1)
+    assert without <= paged_attention.SMEM_BYTES < without + 4 * (2 * 32 + 3 * 32 * 300 + 1)
+    assert paged_attention.decode_shares(pool)
+    assert not paged_attention.decode_shares(pool, window=256)
+    # and the stacked rows follow from the head count: 256 query rows or more
+    assert [paged_attention._latent_stacked_rows(H) for H in (8, 16, 32, 128)] == [32, 16, 8, 8]
+
+
+@pytest.mark.parametrize("B,H", [(32, 32), (8, 128)], ids=["agent", "longdoc"])
+def test_the_latent_decode_kernel_asks_for_no_more_vmem_than_a_kernel_gets(B, H):
+    """Every row's waiting state and the stacked rows' are scratch: at both
+    latent cells' shapes the call names no `vmem_limit_bytes` (a raised
+    limit took XLA's cross-kernel weight prefetch from the whole step:
+    PERF.md, PR 36) and is ONE `pallas_call`; that it fits what a v5e kernel
+    gets unasked is the deviceless compile's to say
+    (`tests/test_aot_topology.py -k latent`)."""
+    sd = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(functools.partial(
+        paged_attention._latent_decode_device.__wrapped__, scale=0.1, rank=512,
+        interpret=False))(
+        sd((B, H, 640), jnp.bfloat16), sd((16384, 16, 640), jnp.bfloat16),
+        sd((B, 1024), jnp.int32), sd((B,), jnp.int32))
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes is None
+
+    def held(shape, dtype):
+        """Bytes in VMEM: the last two dimensions in whole tiles."""
+        *lead, rows, lanes = (1,) + tuple(shape)
+        tile = 32 // dtype.itemsize
+        return int(np.prod(lead)) * -(-rows // tile) * tile * -(-lanes // 128) * 128 * dtype.itemsize
+
+    scratch = sum(held(a.shape, a.dtype) for a in call.params["grid_mapping"].scratch_avals
+                  if jnp.issubdtype(a.dtype, jnp.floating))
+    bf16 = jnp.dtype(jnp.bfloat16)
+    assert scratch + held((B, H, 640), bf16) + held((B, H, 512), bf16) <= 12 << 20
+
+
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-5), (jnp.bfloat16, 7e-2)])
 @pytest.mark.parametrize("L,start", [(64, 0), (64, 200), (32, 250), (16, 37)])
 def test_the_latent_chunk_kernel_at_32_heads_is_the_masked_einsum(L, start, dtype, tol):
@@ -630,6 +868,54 @@ def test_arrivals_behind_a_head_in_prefill_attach_it_and_do_not_compute_it(kinds
     assert starts.count(0) == 1 and starts.count(44) == 2
     assert len(runs[False]._prefill_chunk.chunks) - len(starts) >= 4
     assert runs[True].cache.live_blocks == 0 and runs[True].cache.total_block_refs == 0
+
+
+def test_rows_decoding_over_one_attached_head_read_it_once_and_decode_as_unshared():
+    """What the chip's check cannot see (it decodes a group of ONE beside 31
+    parked rows): four requests behind one 264-token head decode together
+    through the latent decode kernel (a latent of 128 values, tables of 80
+    pages of 8 keys: the head's first 32 pages are one whole compute block).
+    With the prefix cache the three later requests attach the head, the
+    step's kernel reads that block once for the rows decoding behind it, the
+    engine counts those keys by the kernel's own rule, and every request's
+    tokens are the engine's without a prefix cache."""
+    from pytorch_distributed_example_tpu.serve.decode import layer_paths, step_shares_blocks
+
+    config = dict(WIDE, num_hidden_layers=2)  # a dense layer and a sparse one
+    model = modelglue.build_model(config, 640, remat=False)
+    variables = modelglue.make_variables(model, config, seed=7)
+    head = tokens_of(264, 500)
+    prompts = [np.concatenate([head, tokens_of(5 + 4 * i, 501 + i)]) for i in range(4)]
+    runs, records = {}, {}
+    for share in (False, True):
+        engine = ServeEngine(model, variables, slots=4, block_size=BS, pool_blocks=200,
+                             prefill_chunk_tokens=128, min_bucket=8, prefix_cache=share)
+        paths = layer_paths(engine.cache, 4, 1)
+        assert set(paths) == {"latent"} and paths["latent"][1] == "latent_decode_kernel"
+        assert step_shares_blocks(engine.cache, paths) and engine._decode_shares is share
+        step, seen = engine._step, []
+
+        def probe(params, tree, lengths, tokens, rngs, tables, step=step, engine=engine, seen=seen):
+            seen.append(paged_attention.shared_decode_keys(
+                np.asarray(tables), engine.cache.lengths, engine.cache.invalid_block, BS))
+            return step(params, tree, lengths, tokens, rngs, tables)
+
+        engine._step = probe
+        for i, prompt in enumerate(prompts):
+            engine.submit(prompt, 4, rid=f"r{i}")
+        booked = []
+        while engine.step():
+            if engine.last_step.decode_keys:
+                booked.append(engine.last_step.decode_shared_keys)
+        runs[share], records[share] = engine, (booked, seen)
+    assert {r: c.tokens for r, c in runs[True].completions.items()} == {
+        r: c.tokens for r, c in runs[False].completions.items()}
+    assert runs[True].prefix.stats()["prefix_tokens_reused"] == 3 * 264
+    booked, seen = records[True]
+    assert booked == seen and max(booked) == 4 * 256  # all four rows over the one block
+    # without a prefix cache no two tables hold one page, and nothing is counted
+    assert not any(records[False][0]) and not any(records[False][1])
+    assert runs[True].metrics.snapshot()["decode"]["kernel_share"] == 1.0
 
 
 REFUSED = {

@@ -429,6 +429,38 @@ def test_a_model_of_one_stream_traces_no_map(serve_paths):
     assert not [p for p in paths if "hc_out" in p and "hc_sinkhorn" in p]  # the end mixes, no more
 
 
+def test_a_latent_step_holds_one_kernel_call_a_layer_and_its_work_list_outside(serve_paths):
+    """What `bench_matrix/readers/latent_steps.kernel_seconds(calls=)` holds
+    on to: every latent layer calls `jit(_latent_decode_device)` under its own
+    `cache_attention`, and of that call's operations exactly ONE, the
+    `pallas_call`, is traced under `latent_decode_kernel`; the work list
+    (with its shared half: the pairwise runs, the cumulative sums) is plain
+    XLA outside the kernel's scope, where the layers' copies merge."""
+    import functools
+
+    from pytorch_distributed_example_tpu.ops import paged_attention
+
+    paths = serve_paths["latent_step_kernel"]["paths"]
+    calls = [p for p in paths if p.endswith("cache_attention/jit(_latent_decode_device)")]
+    assert sorted(re.search(r"layers_\d+", p).group() for p in calls) == ["layers_0", "layers_1"]
+    # on the CPU the interpreted kernel's operations all sit below the call's name
+    scoped = [p for p in paths if p.startswith("latent_decode_kernel/")]
+    assert scoped and all(
+        p.startswith("latent_decode_kernel/latent_decode_attention/") for p in scoped)
+    assert [p for p in scoped if p.endswith("/pallas_call")] == [
+        "latent_decode_kernel/latent_decode_attention/pallas_call"]
+    sd = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(functools.partial(
+        paged_attention._latent_decode_device.__wrapped__, scale=0.1, rank=128,
+        interpret=False))(
+        sd((2, 4, 256), jnp.float32), sd((16, 8, 256), jnp.float32),
+        sd((2, 8), jnp.int32), sd((2,), jnp.int32)).jaxpr
+    under = [e.primitive.name for e in jaxpr.eqns
+             if "latent_decode_kernel" in str(e.source_info.name_stack)]
+    assert under == ["pallas_call"]
+    assert len(jaxpr.eqns) > 40  # the work list, with its shared half
+
+
 def test_the_kernel_step_gathers_nothing(serve_paths):
     """With the decode kernel in the step no operation is traced under
     `kv_gather`, and the old path's step keeps both scopes."""
