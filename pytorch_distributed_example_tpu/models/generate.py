@@ -93,6 +93,8 @@ def init_cache(model, batch_size: int):
     `model.init(decode=True)` so drift fails loudly."""
     import jax.numpy as jnp
 
+    from .transformer import STATE_KINDS, state_block_shapes
+
     cfg = model.cfg
     B, M, KV, Dh = batch_size, cfg.max_seq_len, cfg.kv_heads, cfg.head_dim
 
@@ -105,12 +107,11 @@ def init_cache(model, batch_size: int):
             }
         }
 
-    def state_layer():  # a linear layer: a recurrent state, no K/V
-        from .transformer import linear_state_shapes
-
-        return {"linear_attn": {
+    def state_layer(kind):  # a mixer that keeps a state block, no K/V
+        mixer, leaves = state_block_shapes(cfg, kind)
+        return {mixer: {
             leaf: jnp.zeros((B,) + shape, dtype)
-            for leaf, (shape, dtype) in linear_state_shapes(cfg).items()
+            for leaf, (shape, dtype) in leaves.items()
         }}
 
     def latent_layer():  # one row a token: the latent and its rotary key
@@ -119,13 +120,11 @@ def init_cache(model, batch_size: int):
             "index": jnp.zeros((), jnp.int32),
         }}
 
-    linear = getattr(cfg, "linear_layers", ())
-    latent = getattr(cfg, "latent_layers", ())
-
     def layer(i):
-        if i in linear:
-            return state_layer()
-        return latent_layer() if i in latent else one_layer()
+        kind = cfg.layers[i].attention if getattr(cfg, "layers", None) else "full"
+        if kind in STATE_KINDS:
+            return state_layer(kind)
+        return latent_layer() if kind == "latent" else one_layer()
 
     return {f"layers_{i}": layer(i) for i in range(cfg.n_layers)}
 

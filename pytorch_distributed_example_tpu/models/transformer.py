@@ -54,8 +54,13 @@ class RopeSpec:
 
 
 # what a layer's mixer keeps between calls: every key and value, those of
-# a window, one recurrent state a row, or one compressed latent a token
-CACHE_KINDS = ("full", "window", "linear", "latent")
+# a window, one STATE BLOCK a row whose leaves the mixer names
+# (`state_block_shapes`: a linear layer's recurrent state and conv tail, a
+# conv layer's tail alone), or one compressed latent a token. A kind's name
+# is the `LayerSpec.attention` of the layers that keep it.
+CACHE_KINDS = ("full", "window", "linear", "latent", "conv")
+# the kinds whose layers keep a state block and no keys
+STATE_KINDS = ("linear", "conv")
 # tokens of one sub-chunk of a linear layer's chunked scan
 # (`gated_delta_chunked`): a power of two that divides every prefill
 # bucket of the serve cells (128, 256, 512)
@@ -71,7 +76,10 @@ class LayerSpec:
     "window" (the last `cfg.window` keys), "linear" (`LinearAttention`
     at the `linear_*` sizes: a recurrent state, no keys and values) or
     "latent" (`LatentAttention` at the `latent_*` sizes: one compressed
-    latent and one shared rotary key a token, no K and V heads);
+    latent and one shared rotary key a token, no K and V heads) or "conv"
+    (`GatedConv`: a gated short convolution of `cfg.conv_taps` taps, which
+    keeps the `conv_taps - 1` inputs behind a row's last token and nothing
+    else);
     `n_heads` and `rope` default to the model's; `mlp` is "dense" (SwiGLU
     at `cfg.ffn_dim`) or "sparse" (`SparseMoE` at the `sparse_*` sizes)."""
 
@@ -136,6 +144,10 @@ class TransformerConfig:
     # q and k of an attention layer are RMS-normed over the whole
     # projection, before the heads are split
     qk_norm: bool = False
+    # q and k of an attention layer are RMS-normed over EACH head's
+    # `head_dim` values, after the heads are split (one scale of
+    # `head_dim` values for q and one for k, every head's)
+    qk_head_norm: bool = False
     # each sublayer is normed on its input AND on its output,
     # h = x + post_norm(mixer(norm(x))): four norms a block
     sandwich_norm: bool = False
@@ -169,6 +181,14 @@ class TransformerConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # the "conv" mixer (`GatedConv`): taps of its causal depthwise conv
+    conv_taps: int = 3
+    # what the "sparse" MLP's router adds to the sum of the chosen scores
+    # before it divides them by it
+    sparse_norm_eps: float = 1e-20
+    # the logits are the final norm's output against the EMBEDDING's
+    # transpose: the model has no `lm_head` of its own
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         if self.rope_pairs not in ("interleaved", "halves"):
@@ -177,6 +197,8 @@ class TransformerConfig:
             raise ValueError(f"sparse_score {self.sparse_score!r}")
         if self.post_norm and self.sandwich_norm:
             raise ValueError("post_norm and sandwich_norm are two placements")
+        if self.qk_norm and self.qk_head_norm:
+            raise ValueError("qk_norm and qk_head_norm are two norms of q and k")
         if self.hc_mult < 1 or (self.hc_mult > 1 and self.layers is None):
             raise ValueError(
                 f"hc_mult {self.hc_mult}: residual streams belong to a layer pattern"
@@ -203,6 +225,9 @@ class TransformerConfig:
                         f"layer {i} is linear and linear_heads/key_dim/"
                         "value_dim/conv are unset"
                     )
+            elif spec.attention == "conv":
+                if self.conv_taps < 2:
+                    raise ValueError(f"layer {i} is conv and conv_taps < 2")
             elif spec.attention == "latent":
                 if not (
                     self.latent_q_rank and self.latent_kv_rank
@@ -265,6 +290,15 @@ class TransformerConfig:
             return ()
         return tuple(
             i for i, spec in enumerate(self.layers) if spec.attention == "latent"
+        )
+
+    @property
+    def conv_layers(self) -> Tuple[int, ...]:
+        """The layers that keep the tail of a short convolution and no K/V."""
+        if self.layers is None:
+            return ()
+        return tuple(
+            i for i, spec in enumerate(self.layers) if spec.attention == "conv"
         )
 
     @property
@@ -491,6 +525,10 @@ class Attention(nn.Module):
         q = qk_norm("q_norm", dense(H * Dh, "q_proj")(x)).reshape(B, L, H, Dh)
         k = qk_norm("k_norm", dense(KV * Dh, "k_proj")(x)).reshape(B, L, KV, Dh)
         v = dense(KV * Dh, "v_proj")(x).reshape(B, L, KV, Dh)
+        if self.spec is not None and cfg.qk_head_norm:
+            # over each head's own values: one scale of Dh for q, one for k
+            q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
         scale = 1.0 / (Dh ** 0.5)
 
         if decode:
@@ -593,7 +631,9 @@ class Attention(nn.Module):
 
             # the pool may hold more KV heads than the model has
             # (`ops.paged_attention.pool_kv_heads`)
-            held = self.get_variable("cache", "k").shape[2]
+            held, width = self.get_variable("cache", "k").shape[2:]
+            if width != Dh:  # several heads a row (`_decode_paged` packs
+                held = KV  # them behind the rope): such a pool pads none
             q, k, v = to_pool_heads(q, k, v, held)
             o = self._decode_paged(
                 q, k, v, cos, sin, scale, positions, block_tables
@@ -653,7 +693,10 @@ class Attention(nn.Module):
         Attention then takes one of three paths, chosen from what this
         call can see (`ops.paged_kernel`: query length, pool shape and
         dtype, table shape, the layer's window — no option names it). On
-        a pool that is not quantized, at a head size Mosaic tiles, a
+        a pool that is not quantized, at a head size Mosaic tiles (128
+        lanes and multiples; a 64-wide head's pool holds two heads a row,
+        `ops.paged_attention.pool_head_pack`: q, k and v take that layout
+        behind the rope and the output is unpacked on the way back), a
         DECODE call (L == 1) runs `ops.paged_decode_attention` and a
         PREFILL CHUNK (L > 1, no window, a query length that fills
         sublane tiles) `ops.paged_chunk_attention`: kernels that read
@@ -693,6 +736,7 @@ class Attention(nn.Module):
             paged_kernel,
             paged_window_span,
         )
+        from ..ops.paged_attention import pack_pool_heads, unpack_pool_heads
         from ..ops.quant import quantize_kv
 
         cfg = self.cfg
@@ -714,6 +758,19 @@ class Attention(nn.Module):
         safe = jnp.clip(pos, 0, M - 1)  # RoPE table bound; overshoot is
         q = apply_rope_batched(q, cos[safe], sin[safe], halves)  # dropped below
         k = apply_rope_batched(k, cos[safe], sin[safe], halves)
+
+        pack = ck.value.shape[3] // Dh
+        if pack > 1:
+            # a pool of 64-wide heads holds two a lane row
+            # (`ops.paged_attention.pool_head_pack`): behind the rope, which
+            # turns a head's own values, the operands take the pool's layout
+            q, k, v = pack_pool_heads(q, k, v, pack)
+            unpacked = functools.partial(
+                unpack_pool_heads, kv_heads=KV, pack=pack, head_dim=Dh
+            )
+            KV, Dh = k.shape[2:]
+        else:
+            unpacked = lambda o: o
 
         flat = _paged_write_index(pos, block_tables, nblk, bs)
 
@@ -761,7 +818,7 @@ class Attention(nn.Module):
                     o = paged_chunk_attention(
                         q, ck.value, cv.value, block_tables, idx, scale
                     )
-                return o.reshape(B, L, H * Dh)
+                return unpacked(o.reshape(B, L, H * Dh))
         first_block = n_blocks = None
         key0 = jnp.zeros((B,), jnp.int32)
         if window is not None:
@@ -781,7 +838,7 @@ class Attention(nn.Module):
                 # window layer's `n_blocks` from its first attended block
                 key_pos = key0[:, None] + jnp.arange(kf.shape[1])[None, :]
                 mask = _position_mask(pos, key_pos, window)  # (B, L, Mb)
-                return _grouped_attention(q, kf, vf, scale, mask)
+                return unpacked(_grouped_attention(q, kf, vf, scale, mask))
 
 def _latent_attention(q, latents, rank, scale, mask):
     """Masked softmax attention in the ABSORBED form of a latent layer:
@@ -1017,6 +1074,26 @@ def linear_state_shapes(cfg) -> dict:
         "state": ((H, dk, dv), jnp.float32),
         "conv": ((cfg.linear_conv - 1, H * (2 * dk + dv)), cfg.dtype),
     }
+
+
+def conv_state_shapes(cfg) -> dict:
+    """leaf -> (shape, dtype) of what ONE row keeps in a conv layer, as
+    `GatedConv` reads and writes it: `tail`, the gated inputs (B * u) of
+    the `conv_taps - 1` tokens behind the row's last."""
+    return {"tail": ((cfg.conv_taps - 1, cfg.d_model), cfg.dtype)}
+
+
+def state_block_shapes(cfg, kind: str):
+    """(the mixer's name in a block, leaf -> (shape, dtype)) of the state
+    block ONE row keeps in a layer of `kind` (one of `STATE_KINDS`): the
+    leaves are the mixer's own, and `serve/cache.py` and
+    `models/generate.py` build a layer's block from them without knowing
+    which mixer it is."""
+    name, shapes = {
+        "linear": ("linear_attn", linear_state_shapes),
+        "conv": ("gated_conv", conv_state_shapes),
+    }[kind]
+    return name, shapes(cfg)
 
 
 def _unit_lower_inverse(m):
@@ -1308,6 +1385,102 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
+class GatedConv(nn.Module):
+    """The "conv" mixer of a layer pattern: a gated short convolution
+    (the LFM2 family's operator). Per token, with D = `cfg.d_model` and
+    T = `cfg.conv_taps`:
+
+        [B, C, u] = split3(W_in x)            (W_in: D -> 3 D, no bias)
+        z_t = sum_{j < T} w_j * (B * u)_{t - (T - 1) + j}   (depthwise, causal)
+        y = W_out (C * z)
+
+    No activation inside, no bias. The three elementwise products and the
+    taps are float32 (`in_proj` keeps its product's float32; `out_proj`
+    takes `cfg.dtype`); the mixer's input and output are `cfg.dtype`.
+
+    What it keeps between calls is `tail`, the gated inputs B * u of the
+    T - 1 tokens behind the row's last, (T - 1, D) in `cfg.dtype`: no keys,
+    no values, nothing that grows with the context.
+
+    * With no cache (`decode=False`: training, the tests) the sequence
+      runs from a zero tail.
+    * `decode=True` without tables is `generate()`'s cache: `tail` is a
+      (B, T - 1, D) variable of the "cache" collection.
+    * With `block_tables` ((B, 1): each row's state block, `serve/
+      cache.py`) and `positions` ((B,): where each row's first token
+      stands) `tail` is a pool of blocks shared by every row. A row at
+      position 0 reads a zero tail whatever its block held; an invalid
+      table entry (== the pool's blocks) drops the write, so a parked lane
+      changes nothing. One token a row is the decode step (scope
+      `conv_step`: tail read, the taps, tail write), more a prefill chunk
+      (`conv_chunk`).
+
+    `row_mask` ((B, L) bool) marks real tokens; the others must be a
+    row's trailing positions (the padding of a prefill chunk): they enter
+    no real token's sum (they stand behind every real one) and the tail
+    is taken behind the last REAL token."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(
+        self, x, decode: bool = False, positions=None, block_tables=None,
+        row_mask=None,
+    ):
+        cfg = self.cfg
+        B, L, D = x.shape
+        taps, f32 = cfg.conv_taps, jnp.float32
+        paged = block_tables is not None
+        if paged and (positions is None or not self.has_variable("cache", "tail")):
+            raise ValueError(
+                "a paged conv layer needs positions and a pre-built state "
+                "pool (serve.cache.init_paged_cache) passed via apply()"
+            )
+        bcu = nn.Dense(
+            3 * D, use_bias=False, dtype=cfg.dtype, name="in_proj",
+            dot_general=functools.partial(
+                jax.lax.dot_general, preferred_element_type=f32
+            ),
+        )(x).astype(f32)
+        weight = self.param(
+            "conv", nn.initializers.lecun_normal(in_axis=0, out_axis=1), (taps, D)
+        ).astype(f32)
+        if decode:
+            (shape, dtype), = conv_state_shapes(cfg).values()
+            ct = self.variable("cache", "tail", jnp.zeros, (B,) + shape, dtype)
+        with jax.named_scope("conv_step" if decode and L == 1 else "conv_chunk"):
+            gate, c, u = jnp.split(bcu, 3, axis=-1)
+            bu = gate * u  # (B, L, D)
+            if paged:
+                block = block_tables[:, 0]  # (B,), == the pool's blocks: none
+                tail = jnp.where(
+                    (positions == 0)[:, None, None], 0,
+                    jnp.take(ct.value, block, axis=0, mode="clip"),
+                ).astype(f32)
+            elif decode:
+                tail = ct.value.astype(f32)
+            else:
+                tail = jnp.zeros((B, taps - 1, D), f32)
+            seq = jnp.concatenate([tail, bu], axis=1)  # (B, taps - 1 + L, D)
+            z = sum(weight[i] * seq[:, i:i + L] for i in range(taps))
+            if decode:
+                if row_mask is None or L == 1:
+                    tail = seq[:, L:]
+                else:  # behind each row's last real token
+                    real = jnp.sum(row_mask, axis=1).astype(jnp.int32)
+                    tail = jax.vmap(
+                        lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, taps - 1)
+                    )(seq, real)
+                tail = tail.astype(cfg.dtype)
+                # during init the variable is only created (flax's convention)
+                if paged:
+                    ct.value = ct.value.at[block].set(tail, mode="drop")
+                elif not self.is_initializing():
+                    ct.value = tail
+            mixed = (c * z).astype(cfg.dtype)
+        return nn.Dense(D, use_bias=False, dtype=cfg.dtype, name="out_proj")(mixed)
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
     width: Optional[int] = None  # None: cfg.ffn_dim
@@ -1405,7 +1578,7 @@ class SparseMoE(nn.Module):
                 n_experts=E, top_k=cfg.sparse_top_k, scale=cfg.routed_scale,
                 first_expert=first, score=cfg.sparse_score,
                 row_mask=None if row_mask is None else row_mask.reshape(B * L),
-                choice_bias=bias,
+                choice_bias=bias, norm_eps=cfg.sparse_norm_eps,
             )
             self.sow("intermediates", "moe_stats", stats)
             self.sow("intermediates", "moe_chosen", chosen.reshape(B, L, -1))
@@ -1594,6 +1767,10 @@ class Block(nn.Module):
                 mixed = LinearAttention(cfg, name="linear_attn")(
                     h, decode, positions, block_tables, row_mask
                 )
+            elif self.spec is not None and self.spec.attention == "conv":
+                mixed = GatedConv(cfg, name="gated_conv")(
+                    h, decode, positions, block_tables, row_mask
+                )
             elif self.spec is not None and self.spec.attention == "latent":
                 mixed = LatentAttention(cfg, self.spec, name="latent_attn")(
                     h, cos, sin, decode, positions, block_tables
@@ -1647,20 +1824,24 @@ class TransformerLM(nn.Module):
         from its `LayerSpec` and hands it the rope table of its own
         `RopeSpec`. Where the layers keep more than one kind of state
         (`cfg.cache_kinds`: every key and value, a window of them, a
-        recurrent state), `block_tables` is the TUPLE of `serve/cache.py`'s
+        state block, a latent row), `block_tables` is the TUPLE of `serve/cache.py`'s
         tables, one a kind in that order, and each layer takes its kind's.
         `row_mask` ((B, L) bool, optional) marks the rows that are real
-        tokens, for the sparse MLPs (see `SparseMoE`) and the linear
-        mixers (see `LinearAttention`)."""
+        tokens, for the sparse MLPs (see `SparseMoE`) and the mixers that
+        keep a state block (see `LinearAttention`, `GatedConv`)."""
         cfg = self.cfg
-        x = nn.Embed(
+        embed = nn.Embed(
             cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="tok_embed"
-        )(tokens)
+        )
+        x = embed(tokens)
         rope_len = cfg.max_seq_len if decode else tokens.shape[1]
         if cfg.layers is not None:
             return self._patterned(
-                x, rope_len, decode, positions, block_tables, row_mask
+                x, rope_len, decode, positions, block_tables, row_mask,
+                embed if cfg.tie_embeddings else None,
             )
+        if cfg.tie_embeddings:
+            raise ValueError("tie_embeddings belongs to a layer pattern")
         cos, sin = rope_freqs(cfg.head_dim, rope_len, cfg.rope_theta)
         # remat path: `decode` must NOT flow through nn.remat as a traced
         # positional (TracerBoolConversionError at `if decode:`); the
@@ -1677,17 +1858,25 @@ class TransformerLM(nn.Module):
         return self._head(x)
 
     @nn.nowrap
-    def _head(self, x):
+    def _head(self, x, embed=None):
+        """Final norm, then the logits: against `lm_head`, or (`embed`: a
+        tied model's `tok_embed`) against the embedding's own array."""
         cfg = self.cfg
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
+        if embed is not None:
+            with jax.named_scope("lm_head"):
+                return embed.attend(x).astype(jnp.float32)
         logits = nn.Dense(
             cfg.vocab_size, use_bias=False, dtype=cfg.dtype, name="lm_head"
         )(x)
         return logits.astype(jnp.float32)
 
     @nn.nowrap
-    def _patterned(self, x, rope_len, decode, positions, block_tables, row_mask):
-        """The blocks of a model with a layer pattern, then the head."""
+    def _patterned(
+        self, x, rope_len, decode, positions, block_tables, row_mask, embed=None,
+    ):
+        """The blocks of a model with a layer pattern, then the head
+        (`embed`: see `_head`)."""
         cfg = self.cfg
         specs = [cfg.layer(i) for i in range(cfg.n_layers)]
         latent = lambda spec: spec.attention == "latent"
@@ -1718,7 +1907,7 @@ class TransformerLM(nn.Module):
             x = block(x, cos, sin, decode, positions, bt, row_mask)
         if cfg.hc_mult > 1:  # and end in a learned mixture of the four
             x, _, _ = HyperConnection(cfg, collapse=True, name="hc_out").pre(x)
-        return self._head(x)
+        return self._head(x, embed)
 
 
 def sharding_rules(

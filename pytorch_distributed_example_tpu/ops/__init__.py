@@ -19,7 +19,9 @@ from .paged_attention import (  # noqa: F401
     paged_chunk_attention,
     paged_decode_attention,
     paged_kernel,
+    pool_head_pack,
     pool_kv_heads,
+    pool_kv_shape,
     pool_latent_width,
 )
 from .quant import (  # noqa: F401

@@ -244,6 +244,33 @@ def pool_latent_width(width: int) -> int:
     return -(-width // 128) * 128
 
 
+def pool_head_pack(kv_heads: int, head_dim: int) -> int:
+    """KV heads that ONE row of a paged pool holds side by side
+    (`serve/cache.py` asks): 2 where a head is half the 128 lanes and the
+    heads pair off into a count `pool_kv_heads` leaves alone, else 1. A
+    pool of 64-wide heads is held as (num_blocks, bs, KV / 2, 128): heads
+    2j and 2j + 1 of a token fill one lane row, the same bytes in the same
+    order as (KV, 64) (the device would pad each 64-value row to 128
+    lanes: twice the pool, and a copy at every view of a page), and both
+    kernels take it as the 128-wide pool of KV / 2 heads it is:
+    `pack_pool_heads` lays a query head against its own half of the row,
+    `unpack_pool_heads` keeps that half of what comes back. Half of either
+    product's terms are zeros, on the memory-bound side of a decode step;
+    the bytes read are the model's own."""
+    if 2 * head_dim != 128 or kv_heads % 2:
+        return 1
+    return 2 if pool_kv_heads(kv_heads // 2) == kv_heads // 2 else 1
+
+
+def pool_kv_shape(kv_heads: int, head_dim: int) -> tuple:
+    """(heads, values a head) of a token's K (or V) row as a paged pool
+    holds it for a model of `kv_heads` heads of `head_dim` values:
+    `pool_head_pack` heads side by side in a row, and as many rows as
+    `pool_kv_heads` gives those. The same bytes a token either way."""
+    pack = pool_head_pack(kv_heads, head_dim)
+    return pool_kv_heads(kv_heads // pack), head_dim * pack
+
+
 def to_pool_heads(q, k, v, held: int):
     """q (B, L, H, Dh), k and v (B, L, KV, Dh) as a pool of `held` >= KV
     heads takes them (`pool_kv_heads`): zero heads behind k's and v's
@@ -266,6 +293,36 @@ def from_pool_heads(o, kv_heads: int, held: int):
         return o
     B, L, _ = o.shape
     return o.reshape(B, L, held, -1)[:, :, :kv_heads].reshape(B, L, -1)
+
+
+def pack_pool_heads(q, k, v, pack: int):
+    """q (B, L, H, Dh), k and v (B, L, KV, Dh), rotated, as a pool that
+    holds `pack` heads a row takes them (`pool_head_pack`): k's and v's
+    heads side by side, (B, L, KV / pack, pack * Dh), and each query head
+    widened to the row with its values against its own KV head's place in
+    it and zeros against its row-mates': query head h still meets KV head
+    h // (H // KV) and no other."""
+    B, L, KV, Dh = k.shape
+    H = q.shape[2]
+    place = (jnp.arange(H) // (H // KV)) % pack  # of a query head's KV head
+    mine = place[:, None] == jnp.arange(pack)[None, :]  # (H, pack)
+    q = jnp.where(
+        mine[:, :, None], q[:, :, :, None, :], jnp.zeros((), q.dtype)
+    ).reshape(B, L, H, pack * Dh)
+    k, v = (a.reshape(B, L, KV // pack, pack * Dh) for a in (k, v))
+    return q, k, v
+
+
+def unpack_pool_heads(o, kv_heads: int, pack: int, head_dim: int):
+    """The attention output (B, L, H * pack * Dh) of `pack_pool_heads`'
+    operands with each query head's own part of its row alone: (B, L,
+    H * Dh). `kv_heads` and `head_dim` are the model's."""
+    B, L, _ = o.shape
+    # (row, place of the KV head, query head of its group, part, Dh): a
+    # head of place p keeps part p of what it summed over the row
+    o = o.reshape(B, L, kv_heads // pack, pack, -1, pack, head_dim)
+    o = jnp.stack([o[:, :, :, p, :, p] for p in range(pack)], axis=3)
+    return o.reshape(B, L, -1)
 
 
 def _heads_split(dtype, kv: int) -> bool:
@@ -310,7 +367,11 @@ def paged_kernel(L: int, pool, block_tables, window=None, rank=None):
     under.
 
     Either kernel needs a floating pool of 2 or 4 bytes (the int8 pool
-    dequantises in the gather); `Dh` a multiple of the 128 lanes; a page
+    dequantises in the gather); `Dh` AS THE POOL HOLDS IT a multiple of
+    the 128 lanes (a model of 64-wide heads is held two heads a row,
+    `pool_head_pack`, and both kernels take that pool as the 128-wide one
+    of half as many heads it is: the caller lays its queries out with
+    `pack_pool_heads`); a page
     whose `bs * KV` rows of `Dh` (KV as one device holds it) fill whole
     sublane tiles of the pool dtype (8 rows of float32, 16 of bfloat16),
     so page copies land tile-aligned in the VMEM buffer; and its

@@ -162,21 +162,40 @@ def moe_mlp(
 # rows over 256, `ragged_dot` (XLA's own call at 256-row tiles) at 360 and
 # 275; (128, 2048, 512) gave 388 / 392, (512, 512, 512) 271.
 GMM_TILING = (128, 512, 512)
+# A matrix side that is not whole tiles of 512 takes ONE tile of whole 256s
+# as wide as divides it, up to this many values (an expert width of 1792 =
+# 7 x 256 is one tile). Measured on a TPU v5 lite (PERF.md section 6,
+# PR 43) at 16 experts of 2048 x 1792 in bfloat16, 256 rows: the gate / up
+# product at column tiles of 256 / 896 / 1792 takes 333 / 222 / 213 us (353
+# / 530 / 551 GB/s of the experts' weights), the down product at
+# contraction tiles of 256 / 896 / 1792 takes 310 / 235 / 216 us; at 2048
+# rows 360 / 234 / 231 and 342 / 265 / 269.
+GMM_WIDEST_TILE = 2048
+
+
+def _gmm_tile(width: int):
+    """The contraction or column tile of the grouped kernel for a matrix
+    side of `width`: `GMM_TILING`'s where it divides the width, else the
+    widest multiple of 256 up to `GMM_WIDEST_TILE` that does; None where
+    none does."""
+    if width % GMM_TILING[1] == 0:
+        return GMM_TILING[1]
+    return next(
+        (t for t in range(GMM_WIDEST_TILE, 0, -256) if width % t == 0), None
+    )
 
 
 def grouped_kernel_ok(rows: int, d_in: int, d_mid: int, dtype) -> bool:
     """Whether `grouped_swiglu` runs the Pallas grouped-matmul kernel —
     THE predicate, from shapes and dtype alone: whole row tiles (the
     kernel refuses a ragged last one), both products' contraction and
-    column widths (`d_in` x `d_mid`, then `d_mid` x `d_in`) whole tiles,
-    and the 2-byte operands the tiling was measured with."""
+    column widths (`d_in` x `d_mid`, then `d_mid` x `d_in`) whole tiles
+    (`_gmm_tile`), and the 2-byte operands the tiling was measured with."""
     import jax.numpy as jnp
 
-    tm, tk, tn = GMM_TILING
-    return (
-        rows % tm == 0
-        and d_in % tk == 0 and d_in % tn == 0
-        and d_mid % tk == 0 and d_mid % tn == 0
+    return bool(
+        rows % GMM_TILING[0] == 0
+        and _gmm_tile(d_in) and _gmm_tile(d_mid)
         and jnp.dtype(dtype).itemsize == 2
     )
 
@@ -212,7 +231,8 @@ def grouped_swiglu(rows, w_gate, w_up, w_down, sizes):
             with jax.default_matmul_precision("default"):
                 return gmm(
                     a, w, sizes, preferred_element_type=jnp.float32,
-                    tiling=GMM_TILING, interpret=interpret,
+                    tiling=(GMM_TILING[0], _gmm_tile(w.shape[1]), _gmm_tile(w.shape[2])),
+                    interpret=interpret,
                 )
     else:
         dot = lambda a, w: lax.ragged_dot(
@@ -287,6 +307,7 @@ def dropless_moe(
     row_mask=None,
     score: str = "softmax",
     choice_bias=None,
+    norm_eps: float = 1e-20,
 ):
     """Dropless top-k MoE over gated (SwiGLU) experts.
 
@@ -298,7 +319,8 @@ def dropless_moe(
     on another's), takes its `top_k` (with `choice_bias` ((n_experts,)) the
     `top_k` largest of score + bias: the bias moves the CHOICE and no
     weight), and weighs them by their scores
-    normalised to sum to 1, times `scale`; the result is the part of
+    normalised to sum to 1 (a "sigmoid" router's over their sum plus
+    `norm_eps`), times `scale`; the result is the part of
     sum_e w_e * SwiGLU_e(x) that the held experts give (all of it when
     all are held; the parts of disjoint ranges add up to the whole).
     Weights multiply expert OUTPUTS.
@@ -341,7 +363,7 @@ def dropless_moe(
 
         if score == "sigmoid":
             top_p, top_e = top(jax.nn.sigmoid(logits))
-            weight = scale * top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+            weight = scale * top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + norm_eps)
         else:
             top_p, top_e = top(jax.nn.softmax(logits, axis=-1))
             weight = scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)

@@ -22,12 +22,15 @@ take one block table a kind the model has, `cfg.cache_kinds`): FULL K/V
 blocks (every key and value, paged and refcounted; nothing is refused
 with them), WINDOW K/V blocks (a window layer's last `window + chunk`
 keys in a second pool, recycled while the request runs) and ONE
-RECURRENT BLOCK a request (a linear-attention layer keeps no K/V and
-gets no K/V pool: a float32 state and a conv tail, held from admission
-to retirement, zero for a row at position 0, untouched by padding and
-parked lanes). With window layers or with linear layers the engine
-refuses `prefix_cache`, `kv_quant`, `mesh=`, disaggregated roles and
-`precompiled=`; a preempted linear-layer request prefills again from 0.
+STATE BLOCK a request (a layer whose mixer keeps a fixed state keeps no
+K/V and gets no K/V pool; the block's leaves are the mixer's own: a
+linear-attention layer's float32 state and conv tail, a gated short
+convolution's two-token tail alone; held from admission to retirement,
+zero for a row at position 0, untouched by padding and parked lanes).
+With window layers or with layers that keep a state block (linear,
+conv) the engine refuses `prefix_cache`, `kv_quant`, `mesh=`,
+disaggregated roles and `precompiled=`; a preempted request of such a
+model prefills again from 0.
 
 Prefix sharing (ISSUE 12): the pool's physical blocks are refcounted
 with copy-on-write divergence (`cache.py`), and a radix prefix index

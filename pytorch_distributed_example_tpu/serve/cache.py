@@ -32,20 +32,27 @@ the attention paths never read before a row's first attended block.
 live-block counts account both kinds. A model with no window layer
 gets exactly the single-kind tree, tables and accounting.
 
-A THIRD KIND, recurrent state (a model whose `cfg.linear_layers` are
-linear-attention mixers, `models/transformer.py::LinearAttention`): such a
-layer keeps NO keys and values, so it gets no K/V pool; it gets a pool
-of `slots` STATE BLOCKS, each the layer's whole memory of one request
-(`state` (H, dk, dv) float32 and `conv`, the few pre-conv inputs behind
-the last token). A request holds exactly one state block from
-`allocate()` to `free()`, whatever its length, addressed through a
+A THIRD KIND, the state block (a model some of whose layers' mixers keep
+a fixed state and NO keys and values: `models/transformer.py::STATE_KINDS`,
+today `LinearAttention` ("linear") and `GatedConv` ("conv")): such a layer
+gets no K/V pool; it gets a pool of `slots` STATE BLOCKS, each the layer's
+whole memory of one request, whose LEAVES ITS OWN MIXER NAMES
+(`state_block_shapes`: a linear layer's `state` (H, dk, dv) float32 and
+`conv`, the few pre-conv inputs behind the last token; a conv layer's
+`tail` alone, the `conv_taps - 1` gated inputs behind it). The manager
+allocates, frees and counts a block without naming a leaf: a layer whose
+state is a tail and a layer whose state is a delta-rule matrix are one
+kind of block in one table. A request
+holds exactly one state block from `allocate()` to `free()`, whatever its
+length, the same block index in every such layer, addressed through a
 (slots, 1) table of its own that rides with the K/V tables (`tables()`:
-one table a kind the model has, in `cfg.cache_kinds`' order). An invalid
-entry drops the write, as for K/V, and a block is never cleared: the
-mixer reads zero for a row whose first token stands at position 0, so a
-block taken over from a retired or preempted request starts clean. What
-is not carried for it: snapshots (a preempted request prefills again
-from 0; a prefix cannot be shared).
+one table a kind the model has, in `cfg.cache_kinds`' order; the "linear"
+and the "conv" kind are handed the same table, as the "latent" kind is
+handed the "full" kind's). An invalid entry drops the write, as for K/V,
+and a block is never cleared: the mixer reads zero for a row whose first
+token stands at position 0, so a block taken over from a retired or
+preempted request starts clean. What is not carried for it: snapshots (a
+preempted request prefills again from 0; a prefix cannot be shared).
 
 A FOURTH KIND, the latent (a model whose `cfg.latent_layers` are
 multi-head latent attention mixers, `models/transformer.py::
@@ -156,10 +163,17 @@ def window_layers_of(cfg) -> tuple:
     return tuple(getattr(cfg, "window_layers", ())) or (False,) * cfg.n_layers
 
 
-def linear_layers_of(cfg) -> tuple:
-    """The layers that keep a recurrent state and no K/V (a model
-    configuration that says nothing has none)."""
-    return tuple(getattr(cfg, "linear_layers", ()))
+def state_layers_of(cfg) -> dict:
+    """layer -> its kind, for the layers whose mixer keeps a state block
+    and no K/V (`models.transformer.STATE_KINDS`; a model configuration
+    that says nothing has none)."""
+    from ..models.transformer import STATE_KINDS
+
+    layers = getattr(cfg, "layers", None) or ()
+    return {
+        i: spec.attention for i, spec in enumerate(layers)
+        if spec.attention in STATE_KINDS
+    }
 
 
 def latent_layers_of(cfg) -> tuple:
@@ -176,9 +190,10 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
     (num_blocks, block_size, kv_heads, head_dim) K and V (kv_heads in
     whole sublane tiles: `ops.paged_attention.pool_kv_heads`) — of
     `window_blocks` blocks instead in a layer the model's pattern marks
-    as a window layer, and NONE in a linear layer, which gets
-    `state_blocks` state blocks (`models.transformer.linear_state_shapes`) under its mixer's
-    name; a latent layer gets ONE (num_blocks, block_size, latent_width)
+    as a window layer, and NONE in a layer whose mixer keeps a state
+    (`state_layers_of`), which gets `state_blocks` state blocks of the
+    leaves its mixer names (`models.transformer.state_block_shapes`) under
+    the mixer's name; a latent layer gets ONE (num_blocks, block_size, latent_width)
     pool, `latent`, under its mixer's. Mirrors
     `models.generate.init_cache`'s structure minus the scalar "index"
     leaf (a shared pool has no per-row cursor).
@@ -193,17 +208,19 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
     `ops.gather_paged_kv`, so the attention math stays cfg.dtype."""
     import jax.numpy as jnp
 
-    from ..models.transformer import linear_state_shapes
-    from ..ops.paged_attention import pool_kv_heads, pool_latent_width
+    from ..models.transformer import state_block_shapes
+    from ..ops.paged_attention import pool_kv_shape, pool_latent_width
 
     cfg = model.cfg
-    KV, Dh = pool_kv_heads(cfg.kv_heads), cfg.head_dim
+    KV, Dh = pool_kv_shape(cfg.kv_heads, cfg.head_dim)
     windowed = window_layers_of(cfg)
     if any(windowed) and window_blocks is None:
         raise ValueError("a model with window layers needs window_blocks")
-    linear = linear_layers_of(cfg)
-    if linear and state_blocks is None:
-        raise ValueError("a model with linear layers needs state_blocks")
+    stateful = state_layers_of(cfg)
+    if stateful and state_blocks is None:
+        raise ValueError(
+            "a model with linear or conv layers needs state_blocks"
+        )
     latent = latent_layers_of(cfg)
     if latent and quantized:
         raise ValueError("a latent pool has no int8 form")
@@ -233,10 +250,11 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
             }
         }
 
-    def state_layer():
-        return {"linear_attn": {
+    def state_layer(kind):
+        mixer, leaves = state_block_shapes(cfg, kind)
+        return {mixer: {
             leaf: jnp.zeros((state_blocks,) + shape, dtype)
-            for leaf, (shape, dtype) in linear_state_shapes(cfg).items()
+            for leaf, (shape, dtype) in leaves.items()
         }}
 
     def latent_layer():
@@ -246,8 +264,8 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
         )}}
 
     def layer(i):
-        if i in linear:
-            return state_layer()
+        if i in stateful:
+            return state_layer(stateful[i])
         if i in latent:
             return latent_layer()
         return one_layer(window_blocks if windowed[i] else num_blocks)
@@ -301,13 +319,22 @@ class PagedKVCache:
         M = cfg.max_seq_len
         windowed = window_layers_of(cfg)
         self.window_layers = sum(windowed)
-        self.linear_layers = len(linear_layers_of(cfg))
-        from ..models.transformer import linear_state_shapes
-        from ..ops.paged_attention import pool_kv_heads, pool_latent_width
+        from ..models.transformer import state_block_shapes
+        from ..ops.paged_attention import pool_kv_shape, pool_latent_width
 
-        self.pool_kv_heads = pool_kv_heads(cfg.kv_heads)  # as the pool holds them
-        # leaf -> (shape, dtype) of one state block of one linear layer
-        self._state_shapes = linear_state_shapes(cfg) if self.linear_layers else {}
+        # heads and values of a token's K (or V) row as the pool holds them
+        self.pool_kv_heads, self.pool_head_dim = pool_kv_shape(
+            cfg.kv_heads, cfg.head_dim
+        )
+        # the layers whose mixer keeps a state block, and for each of them
+        # leaf -> (shape, dtype) of its block: the leaves are the mixer's own
+        stateful = state_layers_of(cfg)
+        self.linear_layers = sum(kind == "linear" for kind in stateful.values())
+        self.conv_layers = sum(kind == "conv" for kind in stateful.values())
+        self.state_layers = len(stateful)
+        self._state_leaves = [
+            state_block_shapes(cfg, kind)[1] for kind in stateful.values()
+        ]
         # the latent kind: one row a token in one pool a layer, under the
         # full kind's tables and free lists
         self.latent_layers = len(latent_layers_of(cfg))
@@ -317,7 +344,7 @@ class PagedKVCache:
         self.latent_width = pool_latent_width(self.latent_values)
         self.latent_rank = cfg.latent_kv_rank if self.latent_layers else 0
         self.full_layers = (
-            cfg.n_layers - self.window_layers - self.linear_layers
+            cfg.n_layers - self.window_layers - self.state_layers
             - self.latent_layers
         )
         # the kinds of state the model's layers keep, in the order the
@@ -354,7 +381,7 @@ class PagedKVCache:
             self.window_num_blocks = slots * self.window_blocks_per_slot
         self.window_invalid_block = self.window_num_blocks
         # the state kind: one block a slot, held from allocate() to free()
-        self.state_num_blocks = slots if self.linear_layers else 0
+        self.state_num_blocks = slots if self.state_layers else 0
         self.state_invalid_block = self.state_num_blocks
         self.state_table = np.full((slots, 1), self.state_invalid_block, np.int32)
         self._state_free: List[int] = list(range(self.state_num_blocks))
@@ -393,13 +420,13 @@ class PagedKVCache:
     # -- slot lifecycle ----------------------------------------------------
     def allocate(self) -> Optional[int]:
         """A free slot index (no K/V blocks yet — those come on write;
-        with linear layers, its one state block), or None when every
-        slot is taken."""
+        with layers that keep a state, its one state block), or None when
+        every slot is taken."""
         if not self._free_slots:
             return None
         s = self._free_slots.pop(0)
         self._in_use[s] = True
-        if self.linear_layers:  # as many state blocks as slots: never dry
+        if self.state_layers:  # as many state blocks as slots: never dry
             self.state_table[s, 0] = self._state_free.pop(0)
         return s
 
@@ -421,7 +448,7 @@ class PagedKVCache:
         self._window_free.extend(self._window_slot_blocks[slot].values())
         self._window_slot_blocks[slot] = {}
         self.window_tables[slot, :] = self.window_invalid_block
-        if self.linear_layers:
+        if self.state_layers:
             self._state_free.append(int(self.state_table[slot, 0]))
             self.state_table[slot, 0] = self.state_invalid_block
         self._in_use[slot] = False
@@ -503,8 +530,8 @@ class PagedKVCache:
         (n, nb) table, or where the model's layers keep more than one
         kind of state the tuple of one table a kind (full layers' (n,
         nb), window layers' (n, nb), linear layers' (n, 1) state table,
-        latent layers' (n, nb): the full kind's), in `cfg.cache_kinds`'
-        order. `parked` slots' rows are handed
+        latent layers' (n, nb): the full kind's, conv layers' (n, 1): the
+        state table again), in `cfg.cache_kinds`' order. `parked` slots' rows are handed
         over all-invalid. Always COPIES: the engine goes on growing and
         freeing rows while the program it handed a table to is still
         queued, and a program may read its host arguments late (the CPU
@@ -515,6 +542,7 @@ class PagedKVCache:
             "window": (self.window_tables, self.window_invalid_block),
             "linear": (self.state_table, self.state_invalid_block),
             "latent": (self.block_tables, self.invalid_block),
+            "conv": (self.state_table, self.state_invalid_block),
         }
         out = [have[kind][0][rows].copy() for kind in self.kinds]
         for t, kind in zip(out, self.kinds):
@@ -677,7 +705,7 @@ class PagedKVCache:
 
     @property
     def state_live_blocks(self) -> int:
-        """State blocks some slot holds (0 with no linear layer)."""
+        """State blocks some slot holds (0 with no layer that keeps one)."""
         return self.state_num_blocks - len(self._state_free)
 
     def state_block(self, slot: int) -> int:
@@ -725,7 +753,7 @@ class PagedKVCache:
 
         cfg = self.model.cfg
         return jax.ShapeDtypeStruct(
-            (self.num_blocks, self.block_size, self.pool_kv_heads, cfg.head_dim),
+            (self.num_blocks, self.block_size, self.pool_kv_heads, self.pool_head_dim),
             np.int8 if self.quantized else cfg.dtype,
         )
 
@@ -738,7 +766,9 @@ class PagedKVCache:
             return None
         import jax
 
-        shape, dtype = self._state_shapes["state"]
+        from ..models.transformer import state_block_shapes
+
+        shape, dtype = state_block_shapes(self.model.cfg, "linear")[1]["state"]
         return jax.ShapeDtypeStruct((self.state_num_blocks,) + shape, dtype)
 
     @property
@@ -766,7 +796,7 @@ class PagedKVCache:
         )
         return (
             2 * self.full_layers * self.block_size * self.pool_kv_heads
-            * cfg.head_dim * itemsize
+            * self.pool_head_dim * itemsize
         ) + self.latent_bytes_per_block + self.scale_bytes_per_block
 
     @functools.cached_property
@@ -790,15 +820,17 @@ class PagedKVCache:
         cfg = self.model.cfg
         return (
             2 * self.window_layers * self.block_size * self.pool_kv_heads
-            * cfg.head_dim * np.dtype(cfg.dtype).itemsize
+            * self.pool_head_dim * np.dtype(cfg.dtype).itemsize
         )
 
     @functools.cached_property
     def state_bytes_per_block(self) -> int:
-        """HBM bytes one state block pins across the linear layers."""
-        return self.linear_layers * sum(
+        """HBM bytes one state block pins across the layers that keep
+        one, each layer's by the leaves its mixer names."""
+        return sum(
             int(np.prod(shape)) * np.dtype(dtype).itemsize
-            for shape, dtype in self._state_shapes.values()
+            for leaves in self._state_leaves
+            for shape, dtype in leaves.values()
         )
 
     @functools.cached_property
@@ -836,11 +868,11 @@ class PagedKVCache:
     @functools.cached_property
     def dense_bytes_per_request(self) -> int:
         """What ONE slot costs in the dense (slots, max_seq_len, ...)
-        layout — the paged-vs-dense comparison baseline (a linear
-        layer's share is its state block in either layout)."""
+        layout — the paged-vs-dense comparison baseline (the share of a
+        layer that keeps a state is its state block in either layout)."""
         cfg = self.model.cfg
         itemsize = np.dtype(cfg.dtype).itemsize
-        kv_layers = cfg.n_layers - self.linear_layers - self.latent_layers
+        kv_layers = cfg.n_layers - self.state_layers - self.latent_layers
         return (
             2 * kv_layers * cfg.max_seq_len * cfg.kv_heads * cfg.head_dim
             + self.latent_layers * cfg.max_seq_len * self.latent_values

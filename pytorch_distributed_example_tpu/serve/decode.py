@@ -94,7 +94,10 @@ def layer_paths(
     its "recurrence_kernel" (`ops/delta_recurrence.py`, where
     `delta_kernel_ok` says so) or the plain "recurrence", and for a chunk
     its "chunk_scan"; a latent layer takes "latent_decode_kernel" or
-    "latent_chunk_kernel" (`ops/paged_attention.py`) or "gather"."""
+    "latent_chunk_kernel" (`ops/paged_attention.py`) or "gather"; a conv
+    layer takes "conv_step" for one token a row and "conv_chunk" for a
+    chunk (`models/transformer.py::GatedConv`: no kernel, a few fused
+    elementwise operations around its two products)."""
     from ..ops import paged_kernel
     from ..ops.delta_recurrence import delta_kernel_ok
 
@@ -118,6 +121,8 @@ def layer_paths(
         if cache.linear_layers:
             step = "recurrence_kernel" if delta_kernel_ok(cache.state_aval) else "recurrence"
             paths["linear"] = (cache.linear_layers, step if L == 1 else "chunk_scan")
+        if cache.conv_layers:
+            paths["conv"] = (cache.conv_layers, "conv_step" if L == 1 else "conv_chunk")
     return paths
 
 
@@ -245,13 +250,14 @@ def paged_programs(
 
     A model with SPARSE layers (`cfg.sparse_layers`) is told which rows
     are real, because a row that is not must route to no expert, and so
-    is a model with LINEAR layers (`cfg.linear_layers`), whose padding
-    must leave a row's recurrent state as its last token left it: in
+    is a model with LINEAR or CONV layers (`cfg.linear_layers`,
+    `cfg.conv_layers`), whose padding must leave a row's state block as
+    its last token left it: in
     `prefill_chunk` the engine pads a chunk with token id -1 (the rows
     `chunk >= 0` are real; the padding embeds as token 0), in `step` a
     row is live when its table row holds a valid block (the engine hands
-    parked and mid-prefill lanes over all-invalid; the linear layers
-    need no telling there: an invalid state block drops the write).
+    parked and mid-prefill lanes over all-invalid; the layers that keep
+    a state block need no telling there: an invalid block drops the write).
     Its `step`'s readback is longer, int32 (S + 2 * sparse layers,):
     the next tokens, then per sparse layer (assignments computed,
     distinct experts with a row) — the counters ride the transfer the
@@ -261,9 +267,11 @@ def paged_programs(
     import jax.numpy as jnp
     from jax import lax
 
+    from .cache import state_layers_of
+
     M = model.cfg.max_seq_len
     sparse = tuple(getattr(model.cfg, "sparse_layers", ()))
-    masked = bool(sparse or getattr(model.cfg, "linear_layers", ()))
+    masked = bool(sparse or state_layers_of(model.cfg))
 
     def apply_paged(params, tree, tokens, positions, bt, row_mask=None):
         kw, mutable = {}, ["cache"]

@@ -176,7 +176,7 @@ from .bucketing import bucket_for, bucket_lengths
 from .cache import (
     PagedKVCache,
     latent_layers_of,
-    linear_layers_of,
+    state_layers_of,
     window_layers_of,
 )
 from .decode import (
@@ -342,8 +342,8 @@ class ServeEngine:
         self.model = model
         self.params = params["params"] if "params" in params else params
         self.cfg = model.cfg
-        # what is not carried with window layers, with linear layers and
-        # with latent layers
+        # what is not carried with window layers, with layers that keep a
+        # state block (linear, conv) and with latent layers
         refusals = {}
         if any(window_layers_of(self.cfg)):
             refusals["window"] = {
@@ -358,8 +358,10 @@ class ServeEngine:
                 "precompiled= (pre-warmed programs take one table)":
                     bool(precompiled),
             }
-        if linear_layers_of(self.cfg):
-            refusals["linear"] = {
+        # a layer that keeps a state block, whichever its mixer (a linear
+        # layer's recurrent state, a conv layer's tail): the same refusals
+        for kind in sorted(set(state_layers_of(self.cfg).values())):
+            refusals[kind] = {
                 "prefix_cache=True (a shared prefix's recurrent state is "
                 "not snapshotted at the prefix's end)": prefix_cache,
                 "kv_quant=True (an int8 pool beside float32 state blocks is "
@@ -390,10 +392,10 @@ class ServeEngine:
         # sparse (dropless MoE) layers: padding routes nowhere, and the
         # decode step's readback carries two counters a layer
         self._sparse_layers = len(getattr(self.cfg, "sparse_layers", ()))
-        # sparse and linear layers are told which positions of a chunk
-        # are padding (`serve/decode.py::paged_programs`)
+        # sparse layers and layers that keep a state block are told which
+        # positions of a chunk are padding (`serve/decode.py::paged_programs`)
         self._pad_id = (
-            -1 if self._sparse_layers or linear_layers_of(self.cfg) else 0
+            -1 if self._sparse_layers or state_layers_of(self.cfg) else 0
         )
         self.temperature = temperature
         self.top_k = top_k

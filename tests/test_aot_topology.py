@@ -479,6 +479,64 @@ def test_the_agent_cell_s_step_and_chunk_fit_the_chip_beside_its_pool(topo, monk
         assert held < 14.5e9 < 16e9, (name, held)
 
 
+def test_the_assist_cell_s_step_and_chunks_fit_the_chip_beside_its_pool(topo, monkeypatch):
+    """`serve_lfm2_assist_c64` as the engine builds it: the model from the
+    configuration's glue at its published widths, 64 slots, tables of 256
+    pages, the 16384-block K/V pool of 6 attention layers held as (blocks,
+    16, 4, 128) (two 64-wide heads a lane row), a 64-block state pool of 18
+    tails. The step and the three chunk buckets compile for v5e with the
+    paged decode / chunk kernel in every attention layer and the three
+    grouped products of every sparse layer as Mosaic calls (the expert width
+    1792 as one tile: `_gmm_tile`), the donated pools are updated in place, and what
+    a call holds is under the chip's 16 GB."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from bench_matrix import modelglue, spec
+    from pytorch_distributed_example_tpu.serve.cache import init_paged_cache
+    from pytorch_distributed_example_tpu.serve.decode import paged_programs
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    placed = lambda tree: jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), tree)
+    cell = spec.load_cell("serve_lfm2_assist_c64")
+    config, eng = cell["config"], cell["traffic"]["engine"]
+    model = modelglue.build_model(config, eng["max_seq_len"], remat=False)
+    params = placed(jax.eval_shape(
+        modelglue.init_fn(model, config), jax.random.PRNGKey(0))["params"])
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(params))
+    assert weights == pytest.approx(8.93e9, rel=2e-3)
+    S, bs = eng["slots"], eng["block_size"]
+    nb = eng["max_seq_len"] // bs
+    tree = placed(jax.eval_shape(
+        lambda: init_paged_cache(model, eng["pool_blocks"], bs, state_blocks=S)))
+    assert tree["layers_2"]["attn"]["k"].shape == (eng["pool_blocks"], bs, 4, 128)
+    assert tree["layers_0"]["gated_conv"]["tail"].shape == (S, 2, 2048)
+    pool = eng["pool_blocks"] * bs * 8 * 64 * 2 * 2 * 6
+    assert pool == pytest.approx(3.22e9, rel=2e-3)
+    chunk, _, _, step = paged_programs(model, 0.0, None)
+    tables = lambda rows: (sd((rows, nb), jnp.int32), sd((rows, 1), jnp.int32))
+    lowered = {"step": step.lower(params, tree, sd((S,), jnp.int32), sd((S,), jnp.int32),
+                                  sd((S, 2), jnp.uint32), tables(S))}
+    for C in (128, 256, eng["prefill_chunk_tokens"]):
+        lowered[f"chunk{C}"] = chunk.lower(
+            params, tree, sd((1, C), jnp.int32), tables(1), sd((), jnp.int32))
+    for name, low in lowered.items():
+        compiled = low.compile()
+        calls = _custom_calls(compiled.as_text())
+        kernel = "paged_decode_attention" if name == "step" else "paged_chunk_attention"
+        assert sum(kernel in c for c in calls) == 6, name
+        assert sum("/moe/experts/" in c for c in calls) == 3 * 22, name
+        assert "ragged-dot" not in compiled.as_text(), name
+        m = compiled.memory_analysis()
+        assert m.alias_size_in_bytes >= pool  # the pool is written in place
+        held = m.argument_size_in_bytes + m.temp_size_in_bytes + (
+            m.output_size_in_bytes - m.alias_size_in_bytes)
+        assert held < 13.5e9 < 16e9, (name, held)
+
+
 @pytest.mark.parametrize("rows", [32, 128, 256, 512])
 def test_the_grouped_expert_kernel_compiles_and_keeps_its_scope(topo, monkeypatch, rows):
     """The sparse MLP as `serve_laguna_mixed_c32` runs it (256 experts of

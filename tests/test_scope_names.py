@@ -26,6 +26,7 @@ MLP = r"TransformerLM\)*/layers_\d+/mlp/"
 LINEAR = r"TransformerLM\)*/layers_\d+/linear_attn/"
 LATENT = r"TransformerLM\)*/layers_\d+/latent_attn/(latent_attn\.\w+/)*"
 HC = r"TransformerLM\)*/layers_\d+/hc_(attn|mlp)\.pre/"
+CONV = r"TransformerLM\)*/layers_\d+/gated_conv/"
 # program -> scope -> where it must appear (a regex on the whole path)
 EXPECTED = {
     "step": {
@@ -171,6 +172,34 @@ EXPECTED = {
         "hc_out": r"TransformerLM\)*/hc_out\.pre/hc_pre/",
         "absorb_q": LATENT + r"absorb_q/.*dot_general",
     },
+    # a model that mixes gated short-convolution layers with attention at
+    # head size 64 over sparse MLPs with a router bias and a tied head: the
+    # mixer's scope is its Flax name, with the two products under theirs and
+    # the tail's read, the taps and the tail's write under `conv_step` (one
+    # token a row) or `conv_chunk` (a prefill chunk)
+    "conv_step": {
+        "gated_conv": CONV,
+        "in_proj": CONV + r"in_proj/dot_general",
+        "conv_step": CONV + r"conv_step/mul",
+        "tail_read": CONV + r"conv_step/jit\(_take\)",
+        "tail_write": CONV + r"conv_step/scatter",
+        "out_proj": CONV + r"out_proj/dot_general",
+        "cache_attention": LAYER + r"cache_attention/jit\(_per_device\)$",
+        "kv_scatter": LAYER + r"kv_scatter/scatter",
+        "moe": MLP + r"moe/router/logistic",
+        "lm_head": r"TransformerLM\)*/lm_head/tok_embed\.attend/dot_general",
+        "sample": r"^jit\(step\)/sample/",
+    },
+    "conv_prefill_chunk": {
+        "gated_conv": CONV,
+        "in_proj": CONV + r"in_proj/dot_general",
+        "conv_chunk": CONV + r"conv_chunk/mul",
+        "tail_write": CONV + r"conv_chunk/scatter",
+        "out_proj": CONV + r"out_proj/dot_general",
+        "cache_attention": LAYER + r"cache_attention/jit\(_chunk_per_device\)$",
+        "kv_scatter": LAYER + r"kv_scatter/scatter",
+        "moe": MLP + r"moe/experts/ragged_dot",
+    },
     # the kernels' calls carry their names (`name=` on the `pallas_call`), so
     # the trace says which form of the backward a step ran: in the resident
     # regime ONE call a layer (the backward's path goes through `checkpoint`)
@@ -260,6 +289,27 @@ def _latent_model(rank=16, **kw):
         model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
 
 
+def _conv_model():
+    """Conv and attention layers 2:1 at head size 64 (4 heads over 2 KV
+    heads of a 256-wide model: the paged pool holds the two as one
+    128-lane row and both kernels take it), a dense then sparse MLPs with
+    a router bias, a tied head."""
+    from pytorch_distributed_example_tpu.models.transformer import LayerSpec, RopeSpec
+
+    rope = RopeSpec(1e6)
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=256, n_heads=4, n_kv_heads=2, n_layers=3, d_ff=64,
+        max_seq_len=64, use_flash=False, rope_pairs="halves", qk_head_norm=True,
+        tie_embeddings=True, sparse_score="sigmoid", sparse_choice_bias=True,
+        sparse_norm_eps=1e-6, sparse_experts=4, sparse_top_k=2, sparse_d_ff=16,
+        experts_held=(0, 2),
+        layers=(LayerSpec("conv", rope=rope), LayerSpec("conv", rope=rope, mlp="sparse"),
+                LayerSpec("full", rope=rope, mlp="sparse")))
+    model = TransformerLM(cfg)
+    return model, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+
+
 def _loss(logits, y):
     return optax.softmax_cross_entropy_with_integer_labels(
         logits[:, :-1], y[:, 1:]).mean()
@@ -314,6 +364,13 @@ def serve_paths():
     out["streams_step"] = _paths(sstep.lower(svars["params"], stree, lanes, lanes, rngs, bt))
     out["streams_prefill_chunk"] = _paths(schunk.lower(
         svars["params"], stree, jnp.zeros((1, 16), jnp.int32), bt[:1], 0))
+    conv, cvars = _conv_model()
+    cchunk, _, _, cstep = paged_programs(conv, 0.0, None)
+    ctree = init_paged_cache(conv, nblk, bs, state_blocks=S)
+    out["conv_step"] = _paths(cstep.lower(
+        cvars["params"], ctree, lanes, lanes, rngs, (bt, state)))
+    out["conv_prefill_chunk"] = _paths(cchunk.lower(
+        cvars["params"], ctree, jnp.zeros((1, 16), jnp.int32), (bt[:1], state[:1]), 0))
     wide_hybrid, wvars = _hybrid_model(16, 64)
     out["hybrid_step_kernel"] = _paths(paged_programs(wide_hybrid, 0.0, None)[3].lower(
         wvars["params"], init_paged_cache(wide_hybrid, nblk, bs, state_blocks=S),
@@ -373,7 +430,7 @@ SERVE = ("step", "step_kernel", "prefill_chunk", "first_token", "pattern_step",
          "pattern_prefill_chunk", "pattern_step_kernel", "hybrid_step",
          "hybrid_step_kernel", "hybrid_prefill_chunk", "latent_step",
          "latent_prefill_chunk", "latent_step_kernel", "latent_prefill_chunk_kernel",
-         "streams_step", "streams_prefill_chunk")
+         "streams_step", "streams_prefill_chunk", "conv_step", "conv_prefill_chunk")
 CASES = [(prog, scope) for prog, scopes_ in EXPECTED.items() for scope in scopes_]
 
 
@@ -461,6 +518,23 @@ def test_a_latent_step_holds_one_kernel_call_a_layer_and_its_work_list_outside(s
     assert len(jaxpr.eqns) > 40  # the work list, with its shared half
 
 
+def test_a_conv_layer_traces_one_form_a_program_and_no_attention(serve_paths):
+    """The step holds `conv_step` and no `conv_chunk`, a chunk the reverse;
+    a conv layer traces nothing under `attn`, an attention layer nothing
+    under `gated_conv`; at head size 64 the step gathers no keys."""
+    step, chunk = serve_paths["conv_step"], serve_paths["conv_prefill_chunk"]
+    assert step["program"] == "jit_step" and chunk["program"] == "jit_prefill_chunk"
+    assert not [p for p in step["paths"] if "conv_chunk" in p]
+    assert not [p for p in chunk["paths"] if "/conv_step/" in p]
+    for low in (step, chunk):
+        assert not [p for p in low["paths"] if re.search(r"layers_[01]/attn/", p)]
+        assert not [p for p in low["paths"] if "layers_2/gated_conv" in p]
+        assert not [p for p in low["paths"] if "kv_gather" in p]
+    mixers = {re.search(r"layers_\d+", p).group() for p in step["paths"]
+              if "/gated_conv/conv_step/" in p}
+    assert mixers == {"layers_0", "layers_1"}
+
+
 def test_the_kernel_step_gathers_nothing(serve_paths):
     """With the decode kernel in the step no operation is traced under
     `kv_gather`, and the old path's step keeps both scopes."""
@@ -479,7 +553,7 @@ def test_the_kernel_step_gathers_nothing(serve_paths):
 METRICS = Path(__file__).resolve().parents[1] / "bench_matrix" / "layer_metrics"
 READ_BY = {
     "decode_cache_attention_ms": ["step", "step_kernel", "pattern_step", "hybrid_step",
-                                  "latent_step", "latent_step_kernel"],
+                                  "latent_step", "latent_step_kernel", "conv_step"],
     "decode_latent_attention_ms": ["latent_step", "latent_step_kernel"],
     "prefill_latent_attention_ms": ["latent_prefill_chunk", "latent_prefill_chunk_kernel"],
     "prefill_latent_cache_attention_ms": ["latent_prefill_chunk",
@@ -495,10 +569,13 @@ READ_BY = {
     "recurrence_decode_roofline": ["hybrid_step", "hybrid_step_kernel"],
     "prefill_linear_attention_ms": ["hybrid_prefill_chunk"],
     "prefill_chunk_scan_ms": ["hybrid_prefill_chunk"],
-    "decode_moe_ms": ["pattern_step", "latent_step"],
-    "prefill_moe_ms": ["pattern_prefill_chunk", "latent_prefill_chunk"],
+    "decode_gated_conv_ms": ["conv_step"],
+    "prefill_gated_conv_ms": ["conv_prefill_chunk"],
+    "gqa64_decode_roofline": ["conv_step"],
+    "decode_moe_ms": ["pattern_step", "latent_step", "conv_step"],
+    "prefill_moe_ms": ["pattern_prefill_chunk", "latent_prefill_chunk", "conv_prefill_chunk"],
     "decode_window_attention_ms": ["pattern_step", "pattern_step_kernel"],
-    "moe_decode_roofline": ["pattern_step"],
+    "moe_decode_roofline": ["pattern_step", "conv_step"],
     "prefill_cache_attention_ms": ["prefill_chunk"],
     "train_mlp_ms": ["ddp", "fsdp"],
     "train_attention_ms": ["ddp", "fsdp"],
