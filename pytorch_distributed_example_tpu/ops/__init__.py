@@ -1,6 +1,8 @@
 """Pallas TPU kernels for the framework's hot ops (flash attention for
 training, paged decode and chunk attention over the serve block pool, the
-decode step's gated delta rule over the pool of recurrent state blocks) — plus the
+decode step's gated delta rule over the pool of recurrent state blocks, the
+sparse MLP's three grouped products as one kernel: `grouped_mlp.py`, reached
+through `parallel/expert_parallel.py::grouped_swiglu`) — plus the
 jnp-level block-scaled quantization codec (`quant.py`) shared by the
 quantized collectives and the int8 paged KV cache."""
 

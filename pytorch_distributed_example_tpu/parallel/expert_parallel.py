@@ -17,9 +17,9 @@ follows the standard top-k token-choice recipe (Switch/GShard family):
 Entry points:
   * `dropless_moe(...)` — the DROPLESS layer of a patterned model
     (`models/transformer.py::SparseMoE`): every assignment is computed,
-    grouped by expert, through a grouped matmul (`grouped_swiglu`: the
-    Pallas `gmm` kernel where `grouped_kernel_ok`, `jax.lax.ragged_dot`
-    elsewhere); told which experts it holds, it routes over all and
+    grouped by expert, through a grouped SwiGLU (`grouped_swiglu`: the
+    one Pallas kernel of `ops/grouped_mlp.py` where `grouped_kernel_ok`,
+    `jax.lax.ragged_dot` elsewhere); told which experts it holds, it routes over all and
     returns its own experts' part. No capacity, no exchange (one chip
     holds what it is told it holds);
   * `moe_mlp(...)` — plain function usable inside any shard_map over an
@@ -155,48 +155,20 @@ def moe_mlp(
     return out.astype(x.dtype), aux
 
 
-# Tiles of the grouped-matmul kernel: rows of one group, contraction,
-# columns. Measured on a TPU v5 lite (PERF.md section 6, PR 27) at 256
-# experts of 2048 x 512 in bfloat16: (128, 512, 512) read the hit experts'
-# weights at 427 GB/s with 256 rows over 162 experts and 423 GB/s with 4096
-# rows over 256, `ragged_dot` (XLA's own call at 256-row tiles) at 360 and
-# 275; (128, 2048, 512) gave 388 / 392, (512, 512, 512) 271.
-GMM_TILING = (128, 512, 512)
-# A matrix side that is not whole tiles of 512 takes ONE tile of whole 256s
-# as wide as divides it, up to this many values (an expert width of 1792 =
-# 7 x 256 is one tile). Measured on a TPU v5 lite (PERF.md section 6,
-# PR 43) at 16 experts of 2048 x 1792 in bfloat16, 256 rows: the gate / up
-# product at column tiles of 256 / 896 / 1792 takes 333 / 222 / 213 us (353
-# / 530 / 551 GB/s of the experts' weights), the down product at
-# contraction tiles of 256 / 896 / 1792 takes 310 / 235 / 216 us; at 2048
-# rows 360 / 234 / 231 and 342 / 265 / 269.
-GMM_WIDEST_TILE = 2048
-
-
-def _gmm_tile(width: int):
-    """The contraction or column tile of the grouped kernel for a matrix
-    side of `width`: `GMM_TILING`'s where it divides the width, else the
-    widest multiple of 256 up to `GMM_WIDEST_TILE` that does; None where
-    none does."""
-    if width % GMM_TILING[1] == 0:
-        return GMM_TILING[1]
-    return next(
-        (t for t in range(GMM_WIDEST_TILE, 0, -256) if width % t == 0), None
-    )
-
-
 def grouped_kernel_ok(rows: int, d_in: int, d_mid: int, dtype) -> bool:
-    """Whether `grouped_swiglu` runs the Pallas grouped-matmul kernel —
-    THE predicate, from shapes and dtype alone: whole row tiles (the
-    kernel refuses a ragged last one), both products' contraction and
-    column widths (`d_in` x `d_mid`, then `d_mid` x `d_in`) whole tiles
-    (`_gmm_tile`), and the 2-byte operands the tiling was measured with."""
+    """Whether `grouped_swiglu` runs the Pallas kernel of
+    `ops/grouped_mlp.py`: THE predicate, from shapes and dtype alone: whole
+    row tiles (the kernel has no ragged last one), an expert width the
+    kernel has a tile for (`swiglu_tile`), and the 2-byte operands the
+    tiles were measured with."""
     import jax.numpy as jnp
 
+    from ..ops.grouped_mlp import ROW_TILE, swiglu_tile
+
+    itemsize = jnp.dtype(dtype).itemsize
     return bool(
-        rows % GMM_TILING[0] == 0
-        and _gmm_tile(d_in) and _gmm_tile(d_mid)
-        and jnp.dtype(dtype).itemsize == 2
+        rows % ROW_TILE == 0 and itemsize == 2
+        and swiglu_tile(d_in, d_mid, itemsize)
     )
 
 
@@ -206,40 +178,23 @@ def grouped_swiglu(rows, w_gate, w_up, w_down, sizes):
     `w_gate[g]`, `w_up[g]` (D, F) and `w_down[g]` (F, D). Float32 (N, D);
     rows past the last group hold nothing to read.
 
-    Where `grouped_kernel_ok`, the three products are the Pallas `gmm`
-    kernel of `jax.experimental.pallas.ops.tpu.megablox` (a tile of
-    `GMM_TILING[0]` rows a group and row tile, only the groups that have
-    rows; a Mosaic call that keeps the caller's scope path in a device
-    trace, which XLA's rewrite of `ragged_dot` on a TPU does not);
-    elsewhere `jax.lax.ragged_dot`. Both accumulate in float32."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
+    Where `grouped_kernel_ok`, ONE Pallas call a layer
+    (`ops/grouped_mlp.py::grouped_swiglu_kernel`: only the groups that have
+    rows, each expert's tiles streamed once, the SwiGLU kept in VMEM; a
+    Mosaic call that keeps the caller's scope path in a device trace, which
+    XLA's rewrite of `ragged_dot` on a TPU does not); elsewhere three
+    `jax.lax.ragged_dot`. Both accumulate in float32 and cast the SwiGLU to
+    the rows' dtype before the down product; both are differentiable."""
+    from ..ops import grouped_mlp
+    from ..ops.flash_attention import _interpret_default
 
-    if grouped_kernel_ok(rows.shape[0], w_gate.shape[1], w_gate.shape[2], rows.dtype):
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-        from ..ops.flash_attention import _interpret_default
-
-        interpret = _interpret_default()
-
-        def dot(a, w):
-            # the kernel's products are one pass over 2-byte operands into
-            # float32, exact; an ambient `jax_default_matmul_precision`
-            # (the test harness pins "highest") would ask Mosaic for a
-            # float32 contraction of them, which it refuses
-            with jax.default_matmul_precision("default"):
-                return gmm(
-                    a, w, sizes, preferred_element_type=jnp.float32,
-                    tiling=(GMM_TILING[0], _gmm_tile(w.shape[1]), _gmm_tile(w.shape[2])),
-                    interpret=interpret,
-                )
-    else:
-        dot = lambda a, w: lax.ragged_dot(
-            a, w, sizes, preferred_element_type=jnp.float32
-        )
-    h = jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up)
-    return dot(h.astype(rows.dtype), w_down)
+    D, F = w_gate.shape[1:]
+    if not grouped_kernel_ok(rows.shape[0], D, F, rows.dtype):
+        return grouped_mlp.ragged_swiglu(rows, w_gate, w_up, w_down, sizes)
+    return grouped_mlp.grouped_swiglu_kernel(
+        rows, w_gate, w_up, w_down, sizes,
+        grouped_mlp.swiglu_tile(D, F, rows.dtype.itemsize), _interpret_default(),
+    )
 
 
 def _share_of_assignments(x, order, sizes, weights, experts, top_k: int, few: int):
@@ -378,7 +333,9 @@ def dropless_moe(
         sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
     # four times what a uniform router would place here, in whole row tiles
     # of the grouped kernel
-    few = -(-4 * T * top_k * held // (n_experts * GMM_TILING[0])) * GMM_TILING[0]
+    from ..ops.grouped_mlp import ROW_TILE
+
+    few = -(-4 * T * top_k * held // (n_experts * ROW_TILE)) * ROW_TILE
     if few < T * top_k:
         y, stats = _share_of_assignments(
             x, order, sizes, jnp.where(mine, weight, 0.0).reshape(T * top_k),

@@ -170,6 +170,52 @@ def _custom_calls(hlo):
     ]
 
 
+def _scoped_vmem(call):
+    """(stated, used) bytes of a Mosaic call's scoped VMEM, as the compiled
+    text has them."""
+    return tuple(int(re.search(rf'"{key}":\[\{{[^}}]*"size":"(\d+)"', call).group(1))
+                 for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
+
+
+def _expert_calls(calls):
+    """How many of the Mosaic calls are the sparse MLP's one kernel under
+    its `moe/experts` scope, each stating a VMEM limit the chip has (v5e:
+    128 MiB a core; inside a whole program the compiler also counts what it
+    places in VMEM around the call, so what is USED is held against the
+    stated limit where the kernel is compiled alone)."""
+    mine = [c for c in calls if re.search(r'op_name="[^"]*/moe/experts/[^"]*grouped_swiglu', c)]
+    assert all(_scoped_vmem(call)[0] <= 100 << 20 for call in mine)
+    return len(mine)
+
+
+def _cell_as_the_engine_builds_it(topo, monkeypatch, name):
+    """(config, engine parameters, model, abstract parameters, `sd`, `placed`)
+    of a serve cell: the model from the configuration's glue at its published
+    widths, everything placed on one described v5e chip."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from bench_matrix import modelglue, spec
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    placed = lambda tree: jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), tree)
+    cell = spec.load_cell(name)
+    config, eng = cell["config"], cell["traffic"]["engine"]
+    model = modelglue.build_model(config, eng["max_seq_len"], remat=False)
+    params = placed(jax.eval_shape(
+        modelglue.init_fn(model, config), jax.random.PRNGKey(0))["params"])
+    return config, eng, model, params, sd, placed
+
+
+def _held(m):
+    """Bytes a call holds by the compiler's `memory_analysis()`: arguments,
+    temporaries and the outputs that alias no argument."""
+    return m.argument_size_in_bytes + m.temp_size_in_bytes + (
+        m.output_size_in_bytes - m.alias_size_in_bytes)
+
+
 def _call_names(calls):
     """The instructions' names without their numbers, sorted."""
     return sorted(re.search(r"%(\w+?)(\.\d+)? = ", line).group(1) for line in calls)
@@ -434,26 +480,18 @@ def test_the_agent_cell_s_step_and_chunk_fit_the_chip_beside_its_pool(topo, monk
     the configuration's glue at its published widths, 32 slots, tables of
     1024 pages, the 16384-block latent pool. The step and the 512-token chunk
     compile for v5e with both latent kernels inside, the donated pool is
-    updated in place (no second 2.68 GB), and what a call holds (weights
+    updated in place (no second 2.68 GB), the six sparse layers' grouped
+    products are one Mosaic call each, and what a call holds (weights
     11.33 GB + pool 2.68 + its temporaries and, for a chunk, 0.27 GB of
     logits) is under the chip's 16 GB."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
 
-    from bench_matrix import modelglue, spec
     from pytorch_distributed_example_tpu.serve.cache import init_paged_cache
     from pytorch_distributed_example_tpu.serve.decode import paged_programs
 
-    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
-    one = SingleDeviceSharding(topo.devices[0])
-    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
-    placed = lambda tree: jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), tree)
-    cell = spec.load_cell("serve_xing_agent_prefix_c32")
-    config, eng = cell["config"], cell["traffic"]["engine"]
-    model = modelglue.build_model(config, eng["max_seq_len"], remat=False)
-    params = placed(jax.eval_shape(
-        modelglue.init_fn(model, config), jax.random.PRNGKey(0))["params"])
+    config, eng, model, params, sd, placed = _cell_as_the_engine_builds_it(
+        topo, monkeypatch, "serve_xing_agent_prefix_c32")
     S, bs, C = eng["slots"], eng["block_size"], eng["prefill_chunk_tokens"]
     nb = eng["max_seq_len"] // bs
     tree = placed(jax.eval_shape(lambda: init_paged_cache(model, eng["pool_blocks"], bs)))
@@ -471,11 +509,12 @@ def test_the_agent_cell_s_step_and_chunk_fit_the_chip_beside_its_pool(topo, monk
         calls = _custom_calls(compiled.as_text())
         kernel = "latent_decode_attention" if name == "step" else "latent_chunk_attention"
         assert sum(kernel in c for c in calls) == config["num_hidden_layers"], name
+        # ONE Mosaic call a sparse layer holds its three grouped products
+        assert _expert_calls(calls) == 6 and "ragged-dot" not in compiled.as_text(), name
         m = compiled.memory_analysis()
         assert m.argument_size_in_bytes == pytest.approx(14.016e9, rel=2e-3)
         assert m.alias_size_in_bytes >= pool  # the pool is written in place
-        held = m.argument_size_in_bytes + m.temp_size_in_bytes + (
-            m.output_size_in_bytes - m.alias_size_in_bytes)
+        held = _held(m)
         assert held < 14.5e9 < 16e9, (name, held)
 
 
@@ -486,26 +525,17 @@ def test_the_assist_cell_s_step_and_chunks_fit_the_chip_beside_its_pool(topo, mo
     16, 4, 128) (two 64-wide heads a lane row), a 64-block state pool of 18
     tails. The step and the three chunk buckets compile for v5e with the
     paged decode / chunk kernel in every attention layer and the three
-    grouped products of every sparse layer as Mosaic calls (the expert width
-    1792 as one tile: `_gmm_tile`), the donated pools are updated in place, and what
-    a call holds is under the chip's 16 GB."""
+    grouped products of every sparse layer as ONE Mosaic call (the expert
+    width 1792 as one tile: `ops.grouped_mlp.swiglu_tile`), the donated pools
+    are updated in place, and what a call holds is under the chip's 16 GB."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
 
-    from bench_matrix import modelglue, spec
     from pytorch_distributed_example_tpu.serve.cache import init_paged_cache
     from pytorch_distributed_example_tpu.serve.decode import paged_programs
 
-    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
-    one = SingleDeviceSharding(topo.devices[0])
-    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
-    placed = lambda tree: jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), tree)
-    cell = spec.load_cell("serve_lfm2_assist_c64")
-    config, eng = cell["config"], cell["traffic"]["engine"]
-    model = modelglue.build_model(config, eng["max_seq_len"], remat=False)
-    params = placed(jax.eval_shape(
-        modelglue.init_fn(model, config), jax.random.PRNGKey(0))["params"])
+    config, eng, model, params, sd, placed = _cell_as_the_engine_builds_it(
+        topo, monkeypatch, "serve_lfm2_assist_c64")
     weights = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(params))
     assert weights == pytest.approx(8.93e9, rel=2e-3)
     S, bs = eng["slots"], eng["block_size"]
@@ -528,12 +558,11 @@ def test_the_assist_cell_s_step_and_chunks_fit_the_chip_beside_its_pool(topo, mo
         calls = _custom_calls(compiled.as_text())
         kernel = "paged_decode_attention" if name == "step" else "paged_chunk_attention"
         assert sum(kernel in c for c in calls) == 6, name
-        assert sum("/moe/experts/" in c for c in calls) == 3 * 22, name
+        assert _expert_calls(calls) == 22, name
         assert "ragged-dot" not in compiled.as_text(), name
         m = compiled.memory_analysis()
         assert m.alias_size_in_bytes >= pool  # the pool is written in place
-        held = m.argument_size_in_bytes + m.temp_size_in_bytes + (
-            m.output_size_in_bytes - m.alias_size_in_bytes)
+        held = _held(m)
         assert held < 13.5e9 < 16e9, (name, held)
 
 
@@ -541,12 +570,10 @@ def test_the_assist_cell_s_step_and_chunks_fit_the_chip_beside_its_pool(topo, mo
 def test_the_grouped_expert_kernel_compiles_and_keeps_its_scope(topo, monkeypatch, rows):
     """The sparse MLP as `serve_laguna_mixed_c32` runs it (256 experts of
     2048 x 512 in bfloat16, top 8; a decode step's 32 rows and the three
-    chunk buckets): the three grouped products are Mosaic calls for v5e, and
-    each keeps `moe/experts` in its path, which the cell's MoE metrics
+    chunk buckets): the three grouped products are ONE Mosaic call for v5e
+    that keeps `moe/experts` in its path, which the cell's MoE metrics
     read. (`jax.lax.ragged_dot` compiles too, to XLA's own `ragged-dot-none`
     call with no path: three quarters of the step would read as unscoped.)"""
-    import re
-
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -573,9 +600,78 @@ def test_the_grouped_expert_kernel_compiles_and_keeps_its_scope(topo, monkeypatc
         sd((E, F, D), jnp.bfloat16), sd((rows,), jnp.bool_),
     ).compile().as_text()
     calls = _custom_calls(hlo)
-    assert len(calls) == 3 and "ragged-dot" not in hlo
-    for call in calls:
-        assert re.search(r'op_name="[^"]*/moe/experts/[^"]*pallas_call', call), call[:300]
+    assert len(calls) == 1 and _expert_calls(calls) == 1 and "ragged-dot" not in hlo
+
+
+def test_the_grouped_expert_kernel_compiles_at_the_widest_experts(topo, monkeypatch):
+    """The kernel alone at `serve_pangu_longdoc_c8`'s experts, 7680 x 2048:
+    the one shape of the four configurations whose expert is more than one
+    tile (`swiglu_tile`: 512 of the 2048, copies of 7.5 MiB), the 512 rows a
+    chunk's `_share_of_assignments` hands it over the 8 held experts, and
+    the most VMEM any of them states."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_example_tpu.ops.grouped_mlp import swiglu_tile, vmem_limit
+    from pytorch_distributed_example_tpu.parallel.expert_parallel import (
+        grouped_kernel_ok,
+        grouped_swiglu,
+    )
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    rows, D, F, E = 512, 7680, 2048, 8
+    assert grouped_kernel_ok(rows, D, F, jnp.bfloat16) and swiglu_tile(D, F, 2) == 512
+    assert vmem_limit(D, 512, 2) == max(
+        vmem_limit(d, swiglu_tile(d, f, 2), 2)
+        for d, f in ((2048, 512), (2048, 1792), (3584, 1024), (D, F)))
+    hlo = jax.jit(grouped_swiglu).lower(
+        sd((rows, D), jnp.bfloat16), sd((E, D, F), jnp.bfloat16), sd((E, D, F), jnp.bfloat16),
+        sd((E, F, D), jnp.bfloat16), sd((E,), jnp.int32),
+    ).compile().as_text()
+    calls = _custom_calls(hlo)
+    assert len(calls) == 1 and "grouped_swiglu" in calls[0] and "ragged-dot" not in hlo
+    stated, used = _scoped_vmem(calls[0])
+    assert used <= stated == vmem_limit(D, 512, 2) <= 100 << 20
+
+
+def test_the_patterned_cell_s_step_and_chunk_hold_one_expert_call_a_layer(topo, monkeypatch):
+    """`serve_laguna_mixed_c32` as the engine builds it: the model from the
+    configuration's glue at its published widths, 32 slots, the full pool of
+    16384 blocks beside the window pool of 32 x 66. The step and the
+    512-token chunk compile for v5e with ONE Mosaic call under
+    `moe/experts` in each of the four sparse layers and no `ragged-dot`,
+    and what a call holds is under the chip's 16 GB."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_example_tpu.serve.cache import init_paged_cache
+    from pytorch_distributed_example_tpu.serve.decode import paged_programs
+
+    config, eng, model, params, sd, placed = _cell_as_the_engine_builds_it(
+        topo, monkeypatch, "serve_laguna_mixed_c32")
+    S, bs, C = eng["slots"], eng["block_size"], eng["prefill_chunk_tokens"]
+    nb = eng["max_seq_len"] // bs
+    tree = placed(jax.eval_shape(lambda: init_paged_cache(
+        model, eng["pool_blocks"], bs, window_blocks=S * 66)))
+    assert model.cfg.cache_kinds == ("full", "window")
+    tables = lambda rows: (sd((rows, nb), jnp.int32), sd((rows, nb), jnp.int32))
+    chunk, _, _, step = paged_programs(model, 0.0, None)
+    lowered = {
+        "step": step.lower(params, tree, sd((S,), jnp.int32), sd((S,), jnp.int32),
+                           sd((S, 2), jnp.uint32), tables(S)),
+        "chunk": chunk.lower(params, tree, sd((1, C), jnp.int32), tables(1),
+                             sd((), jnp.int32)),
+    }
+    for name, low in lowered.items():
+        compiled = low.compile()
+        assert _expert_calls(_custom_calls(compiled.as_text())) == 4, name
+        assert "ragged-dot" not in compiled.as_text(), name
+        m = compiled.memory_analysis()
+        held = _held(m)
+        assert held < 11.5e9 < 16e9, (name, held)
 
 
 def test_tp2_paged_decode_step_compiles_under_mesh(topo, monkeypatch):

@@ -1,8 +1,8 @@
 """The patterned model's two pieces that exist for the chip, at a small size
 on the CPU: the comparison the benchmark's cell makes (`correct`), whose
 float32 reference is told the system's routing through the glue's replay of
-the prefill, and the Pallas grouped-matmul kernel the sparse layers take
-where their shapes tile. A file beside `test_sparse_window.py` (whose toy
+the prefill, and the one Pallas kernel (`ops/grouped_mlp.py`) the sparse
+layers' three grouped products are where their shapes tile. A file beside `test_sparse_window.py` (whose toy
 configuration, weights and helpers it shares) so that the two run on two
 workers."""
 
@@ -125,7 +125,7 @@ def test_which_sparse_layers_take_the_grouped_kernel(rows, d_in, d_mid, dtype, o
 @pytest.mark.parametrize("held", [(0, 8), (2, 4)], ids=["all", "a_range"])
 def test_the_grouped_kernel_is_the_grouped_product(monkeypatch, held):
     """At sizes that tile (bfloat16, 64 rows x 2 = one row tile, experts of
-    512 x 512) `dropless_moe` runs the Pallas kernel, interpreted here: the
+    512 x 512) `dropless_moe` runs the one Pallas kernel, interpreted here: the
     layer it gives is the `ragged_dot` one to bfloat16's rounding, with
     masked rows, experts no row chose and a held range; the counters are
     equal."""
@@ -152,3 +152,114 @@ def test_the_grouped_kernel_is_the_grouped_product(monkeypatch, held):
     y, y0 = np.asarray(y, np.float32), np.asarray(y0, np.float32)
     assert np.isfinite(y).all() and not y[~np.asarray(mask)].any()
     np.testing.assert_allclose(y, y0, atol=2e-2 * np.abs(y0).max())
+
+
+def _experts(D, F, G, seed=7):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    wg, wu = (jax.random.normal(k, (G, D, F)) * D ** -0.5 for k in ks[:2])
+    wd = jax.random.normal(ks[2], (G, F, D)) * F ** -0.5
+    return tuple(w.astype(jnp.bfloat16) for w in (wg, wu, wd))
+
+
+def _kernel_against_ragged(D, F, sizes, rows, tf):
+    """The kernel at tiles of `tf` and the `ragged_dot` form over the
+    same sorted rows: (got, want), the rows that belong to a group."""
+    from pytorch_distributed_example_tpu.ops import grouped_mlp as gm
+
+    sizes = jnp.asarray(sizes, jnp.int32)
+    x = jax.random.normal(jax.random.PRNGKey(3), (rows, D)).astype(jnp.bfloat16)
+    w = _experts(D, F, sizes.shape[0])
+    got = gm.grouped_swiglu_kernel(x, *w, sizes, tf, True)
+    placed = int(sizes.sum())
+    return got[:placed], gm.ragged_swiglu(x, *w, sizes)[:placed]
+
+
+def _layer_against_ragged(monkeypatch, T, K, E, held, bias, D=256, F=256):
+    """`dropless_moe` holding experts `held` of `E`, once through the kernel
+    and once with the predicate saying no: (got, want, few, total)."""
+    from pytorch_distributed_example_tpu.ops.grouped_mlp import ROW_TILE
+    from pytorch_distributed_example_tpu.parallel import expert_parallel as ep
+
+    first, count = held
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    x = jax.random.normal(ks[0], (T, D)).astype(jnp.bfloat16)
+    router = jax.random.normal(ks[1], (D, E)) * D ** -0.5
+    w = tuple(a[first:first + count] for a in _experts(D, F, E))
+    few = -(-4 * T * K * count // (E * ROW_TILE)) * ROW_TILE
+    assert few < T * K and ep.grouped_kernel_ok(few, D, F, x.dtype)
+    mask = jnp.arange(T) % 5 != 0
+    run = lambda: jax.jit(lambda *a: ep.dropless_moe(
+        *a, n_experts=E, top_k=K, first_expert=first, row_mask=mask, score="sigmoid",
+        choice_bias=jnp.asarray(bias, jnp.float32)))(x, router, *w)
+    y, stats, _ = run()
+    monkeypatch.setattr(ep, "grouped_kernel_ok", lambda *a: False)
+    y0, stats0, _ = run()
+    np.testing.assert_array_equal(np.asarray(stats), np.asarray(stats0))
+    assert not np.asarray(y, np.float32)[~np.asarray(mask)].any()
+    return y, y0, few, int(stats[0])
+
+
+@pytest.mark.parametrize("case", [
+    "an_expert_no_row_chose", "a_group_over_two_row_tiles", "rows_past_the_last_group",
+    "the_expert_width_in_three_tiles", "a_width_512_does_not_divide",
+    "a_held_range_its_leading_rows", "a_held_range_every_row",
+])
+def test_the_one_kernel_is_the_ragged_dot_form(monkeypatch, case):
+    """`ops/grouped_mlp.py`'s kernel, interpreted here, against three
+    `ragged_dot`s to bfloat16's rounding: the work list's corners (an empty
+    group, a group that reaches into a second row tile and shares both with
+    others, row tiles no group reaches), the expert width in more than one
+    tile (the output tile accumulates over them), a hidden size that is whole
+    lane tiles and not whole 512s (3584 scaled down: 7 x 128), and a caller
+    that holds experts 2-3 of 16 through both branches of
+    `_share_of_assignments` (the bias sends every row's choices away from
+    the held range but a few, or all of them into it)."""
+    if case.startswith("a_held_range"):
+        into = case.endswith("every_row")
+        bias = [(4.0 if into else -4.0) if e in (2, 3) else 0.0 for e in range(16)]
+        bias[2] = 4.0 if into else 0.0  # some rows still choose a held expert
+        got, want, few, total = _layer_against_ragged(
+            monkeypatch, T=128, K=2, E=16, held=(2, 2), bias=bias)
+        assert (total > few) is into and total > 0, (total, few)
+    else:
+        D, F, sizes, rows, tf = {
+            "an_expert_no_row_chose": (256, 512, [40, 0, 60, 0, 0, 28], 128, 512),
+            "a_group_over_two_row_tiles": (256, 512, [100, 60, 96], 256, 512),
+            "rows_past_the_last_group": (256, 512, [30, 50], 384, 512),
+            "the_expert_width_in_three_tiles": (256, 768, [100, 0, 60, 96], 256, 256),
+            "a_width_512_does_not_divide": (896, 256, [70, 58, 1, 127], 256, 256),
+        }[case]
+        got, want = _kernel_against_ragged(D, F, sizes, rows, tf)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all() and np.abs(want).max() > 0.1
+    np.testing.assert_allclose(got, want, atol=2e-2 * np.abs(want).max())
+
+
+def test_the_sparse_layer_s_gradient_is_the_ragged_dot_form_s(monkeypatch):
+    """`jax.grad` through `dropless_moe` at a shape the kernel takes: its
+    backward is the VJP of the `ragged_dot` form, so the gradients of a
+    loss linear in the output are that form's, to the forward's rounding
+    where the loss is not."""
+    from pytorch_distributed_example_tpu.parallel import expert_parallel as ep
+
+    T, D, F, E, K = 64, 256, 512, 4, 2
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    x = jax.random.normal(ks[0], (T, D)).astype(jnp.bfloat16)
+    router = jax.random.normal(ks[1], (D, E)) * D ** -0.5
+    w = _experts(D, F, E)
+    towards = jax.random.normal(ks[2], (T, D))
+    assert ep.grouped_kernel_ok(T * K, D, F, x.dtype)
+
+    def loss(x, router, wg, wu, wd):
+        y, _, _ = ep.dropless_moe(x, router, wg, wu, wd, n_experts=E, top_k=K)
+        y = y.astype(jnp.float32)
+        return jnp.sum(y * towards) + 0.5 * jnp.sum(y * y)
+
+    grads = lambda: jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(x, router, *w)
+    got = grads()
+    monkeypatch.setattr(ep, "grouped_kernel_ok", lambda *a: False)
+    want = grads()
+    for g, g0 in zip(got, want):
+        g, g0 = np.asarray(g, np.float32), np.asarray(g0, np.float32)
+        assert g.shape == g0.shape and np.abs(g0).max() > 0
+        np.testing.assert_allclose(g, g0, atol=2e-2 * np.abs(g0).max())
