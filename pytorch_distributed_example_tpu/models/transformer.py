@@ -868,13 +868,21 @@ class LatentAttention(nn.Module):
 
     What it keeps between calls is ONE row of r + dr values a token:
     `c_kv` after its norm and `k_rope` after its rotation. No K and V
-    heads exist in the cache, so every cached call runs ABSORBED: a
-    head's up-projections move onto the query and the output,
+    heads exist in the cache, so a cached call either makes them from the
+    rows it reads or runs ABSORBED: a head's up-projections move onto the
+    query and the output,
 
         qt = q_nope W_uk^T (r);  s(i, j) = (qt_i . c_kv_j + q_rope_i . k_rope_j) / sqrt(dn + dr)
         ot_i = sum_j p(i, j) c_kv_j (r);  o_i = ot_i W_uv
 
-    the same numbers, with every head attending the one shared row.
+    the same numbers, with every head attending the one shared row. One
+    token a row, `generate()`'s cache and the gather fallback run absorbed
+    (2 r + dr values a pair and head, nothing a key); a prefill chunk that
+    `ops.latent_chunk_attention` takes runs as written, the kernel
+    up-projecting a key's heads in VMEM once a block of 512 queries (dn +
+    dr + dv values a pair, 2 r (dn + dv) products a key: less from ~171
+    queries a key up at the published widths), with no `absorb_q` /
+    `absorb_out` around it.
 
     * With no cache (`decode=False`: training, the tests) the layer runs
       as first written: keys and values up-projected, dense causal softmax.
@@ -969,11 +977,14 @@ class LatentAttention(nn.Module):
             q_rope = apply_rope_batched(q_rope, cos[safe], sin[safe])
             k_rope = apply_rope_batched(k_rope, cos[safe], sin[safe])
         row = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)  # (B, L, r + dr)
-        with jax.named_scope("absorb_q"):
-            qt = jnp.einsum("blhd,rhd->blhr", q_nope, w_uk)
-            q = jnp.concatenate([qt, q_rope], axis=-1)  # (B, L, H, r + dr)
+
+        def absorbed():
+            with jax.named_scope("absorb_q"):
+                qt = jnp.einsum("blhd,rhd->blhr", q_nope, w_uk)
+                return jnp.concatenate([qt, q_rope], axis=-1)  # (B, L, H, r + dr)
 
         if block_tables is None:
+            q = absorbed()
             with jax.named_scope("kv_scatter"):
                 held = jax.lax.dynamic_update_slice_in_dim(cl.value, row, idx, axis=1)
             if not self.is_initializing():  # flax: init only creates
@@ -982,51 +993,67 @@ class LatentAttention(nn.Module):
                 mask = _position_mask(pos[0], jnp.arange(M))[None]
                 ot = _latent_attention(q, held, r, scale, mask)
         else:
-            ot = self._paged(q, row, cl, pos, idx, block_tables, scale)
+            kernel = self._write_rows(row, cl, pos, block_tables)
+            if kernel == "latent_chunk":
+                # a chunk's L queries pay for a key's heads: the layer as
+                # written, over the pool, and no product outside the kernel
+                from ..ops import latent_chunk_attention
+
+                with jax.named_scope("cache_attention"):
+                    o = latent_chunk_attention(
+                        q_nope, q_rope, w_uk, w_uv, cl.value, block_tables,
+                        idx, scale,
+                    )
+                return dense(cfg.d_model, "o_proj")(o.reshape(B, L, H * dv))
+            ot = self._attend_pages(
+                absorbed(), kernel, cl.value, pos, idx, block_tables, scale
+            )
         with jax.named_scope("absorb_out"):
             o = jnp.einsum("blhr,rhd->blhd", ot, w_uv)
         return dense(cfg.d_model, "o_proj")(o.reshape(B, L, H * dv))
 
-    def _paged(self, q, row, pool, pos, idx, block_tables, scale):
-        """Write the call's rows into the pool, then attend each row's
-        pages: q (B, L, H, r + dr) absorbed, row (B, L, r + dr), pos
-        (B, L) absolute. Returns (B, L, H, r) in q's dtype."""
-        from ..ops import (
-            gather_paged_latent,
-            latent_chunk_attention,
-            latent_decode_attention,
-            paged_kernel,
-        )
+    def _write_rows(self, row, pool, pos, block_tables):
+        """Write the call's rows (B, L, r + dr) at absolute positions pos
+        (B, L) into the pool, and say which kernel of `ops.paged_kernel`
+        takes the call's attention over it."""
+        from ..ops import paged_kernel
 
         B, L, _ = row.shape
-        r = self.cfg.latent_kv_rank
         nblk, bs, W = pool.value.shape
-        # the pool may hold wider rows than the model caches
-        # (`ops.paged_attention.pool_latent_width`): zeros behind the
-        # row's values and behind the query's add nothing to a score
-        grow = lambda a: jnp.pad(
-            a, [(0, 0)] * (a.ndim - 1) + [(0, W - a.shape[-1])]
-        )
-        q, row = grow(q), grow(row)
         flat = _paged_write_index(pos, block_tables, nblk, bs)
         with jax.named_scope("kv_scatter"):
             pool.value = pool.value.reshape(nblk * bs, W).at[flat].set(
-                row.reshape(B * L, W), mode="drop"
+                _grow(row, W).reshape(B * L, W), mode="drop"
             ).reshape(nblk, bs, W)
-        kernel = paged_kernel(L, pool.value, block_tables, rank=r)
+        return paged_kernel(
+            L, pool.value, block_tables, rank=self.cfg.latent_kv_rank
+        )
+
+    def _attend_pages(self, q, kernel, pool, pos, idx, block_tables, scale):
+        """Each row's pages in the ABSORBED form: q (B, L, H, r + dr), pos
+        (B, L) absolute, `kernel` "latent_decode" or None (gather + einsum).
+        Returns (B, L, H, r) in q's dtype."""
+        from ..ops import gather_paged_latent, latent_decode_attention
+
+        r = self.cfg.latent_kv_rank
+        q = _grow(q, pool.shape[-1])
         with jax.named_scope("cache_attention"):
             if kernel == "latent_decode":
                 return latent_decode_attention(
-                    q[:, 0], pool.value, block_tables, idx, scale, rank=r
+                    q[:, 0], pool, block_tables, idx, scale, rank=r
                 )[:, None]
-            if kernel == "latent_chunk":
-                return latent_chunk_attention(
-                    q, pool.value, block_tables, idx, scale, rank=r
-                )
             with jax.named_scope("kv_gather"):
-                held = gather_paged_latent(pool.value, block_tables)
+                held = gather_paged_latent(pool, block_tables)
             mask = _position_mask(pos, jnp.arange(held.shape[1])[None])
             return _latent_attention(q, held, r, scale, mask)
+
+
+def _grow(a, width: int):
+    """`a` with zeros behind its last axis up to `width`: a latent pool may
+    hold wider rows than the model caches
+    (`ops.paged_attention.pool_latent_width`), and zeros behind a row's
+    values and behind a query's add nothing to a score."""
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, width - a.shape[-1])])
 
 
 def _flash_ok(L: int, Dh: int, window: Optional[int] = None) -> bool:

@@ -101,6 +101,29 @@ the work is compute and not memory (`_chunk_kernel`):
   block's first position run unmasked.
 * Queries ride in grouped by KV head, (row, query block, KV, rep *
   queries, Dh): two XLA transposes a call around the kernel.
+* The LATENT chunk kernel (`latent_chunk_attention`) is that design over a
+  pool that holds no KV heads, and it MAKES them: where a decode token
+  moves a head's up-projections onto its query (absorbed: 2 r + dr values a
+  pair), a chunk's L queries pay for a key's heads once (2 r (dn + dv)
+  FLOPs a key and head) and then score dn + dr values and sum dv, less
+  work from about 171 queries a key up (r 512, dn = dv 128, dr 64). A grid
+  step is one (row, query block of `CHUNK_QUERY_BLOCK`, GROUP of heads,
+  `_latent_chunk_heads`): what it holds in VMEM depends on neither L nor
+  the head count. The block's queries ride in transposed ((heads, dn +
+  rope lanes, queries): one XLA transpose a call, and one back for the
+  outputs); the group's columns of the layer's `kv_b_proj` come as the
+  layer stores them (no copy in the layer's program) and are laid out a
+  head in VMEM once a step, `W_uk` (r, dn) and `W_uv` transposed (dv, r).
+  The step walks the row's key blocks ONCE: a block's pages are copied as
+  above (one buffer pair: a row of the pool is a token's latent beside its
+  one rotary key), then, `LATENT_CHUNK_CHAINS` heads a pass, `k = c W_uk`
+  (keys, dn) is written beside the block's rotary lanes, `v^T = W_uv^T c^T`
+  (dv, keys) comes out of one product for the pass's heads, scores are
+  (keys, queries) a head and the accumulator (dv, queries): every (key,
+  head) is up-projected once a query block (once a chunk at the serve
+  cells' 512 tokens), and the latents are read heads / group times. Only
+  the blocks that hold the query block's own keys (and the row's last
+  page) are masked.
 
 Tolerance contract (tests/test_paged_attention.py tests to it). Scores
 and the running max / sum are float32, probabilities are cast to the
@@ -114,6 +137,12 @@ therefore agrees to float32 reassociation in float32 (max abs error
 <= 2e-5 at unit-scale inputs) and to bfloat16 rounding of scores and
 probabilities in bfloat16 (max abs error <= 2e-2 on outputs of unit
 scale). No lower precision, no approximation, no truncated span.
+The latent chunk kernel is held to the layer AS WRITTEN (`kv_up` and a
+masked softmax over `gather_paged_latent`), with its rounding: operands of
+the pool's dtype to the MXU, float32 accumulation, a key's up-projected
+heads rounded to the pool's dtype as `kv_up` rounds them, scores and
+softmax float32, probabilities cast before the value product; the same two
+bounds.
 """
 
 from __future__ import annotations
@@ -143,10 +172,16 @@ CHUNK_QUERY_BLOCK = 512
 #: VMEM the chunk kernel may take of a v5e TensorCore's 128 MiB: a query
 #: block of every head with its float32 accumulators stays resident
 CHUNK_VMEM_BYTES = 96 * 1024 * 1024
-#: queries of one grid step of the latent chunk kernel: every query meets
-#: the one shared row with all its heads, so a step's transposed score block
-#: is (keys, heads * queries): 256 x 4096 float32 at 128 heads and 32 queries
-LATENT_QUERY_BLOCK = 32
+#: heads of one grid step of the latent chunk kernel (`_latent_chunk_heads`):
+#: a block of `CHUNK_QUERY_BLOCK` queries of that many heads stays resident
+#: with its float32 accumulators, and the row's latents are read once a group
+#: of heads and query block. On a v5e, 512 queries of 128 heads over 13312
+#: keys, ms a call at 8 / 16 / 32 / 64 heads: 7.40 / 7.15 / 7.01 / 6.97 (at
+#: 128 queries 32 / 64 / 128: 3.75 / 3.68 / 3.66) — PERF.md, PR 45
+LATENT_CHUNK_HEADS = 32
+#: heads one pass of that kernel's head loop takes, their chains independent
+#: (1 / 2 / 4 at 32 heads a step: 7.76 / 7.26 / 7.01 ms)
+LATENT_CHUNK_CHAINS = 4
 #: rows of a group that meet a shared block of the decode kernel at a time:
 #: one float32 sublane tile, so that ONE query head of all of them is one
 #: strided vector of the stacked group
@@ -438,7 +473,9 @@ def _latent_kernel_for(L: int, pool, block_tables, rank):
     kernel's with the shared list).
     "latent_decode":
     one query token a row. "latent_chunk": more, in whole sublane tiles
-    and whole query blocks of `LATENT_QUERY_BLOCK`."""
+    and, past `CHUNK_QUERY_BLOCK`, whole query blocks of it: what a step of
+    that kernel holds in VMEM is a query block of `LATENT_CHUNK_HEADS` heads
+    at most, whatever L and the head count."""
     _, bs, W = pool.shape
     B, nb = block_tables.shape
     if pool.dtype not in (jnp.float32, jnp.bfloat16) or _partition.spec is not None:
@@ -455,7 +492,7 @@ def _latent_kernel_for(L: int, pool, block_tables, rank):
         return "latent_decode" if fits else None
     if (
         L % tile == 0
-        and L % min(L, LATENT_QUERY_BLOCK) == 0
+        and L % min(L, CHUNK_QUERY_BLOCK) == 0
         and 4 * (B * nb + 2 * B) <= SMEM_BYTES
     ):
         return "latent_chunk"
@@ -1606,30 +1643,56 @@ def latent_decode_attention(
     )
 
 
+def _latent_chunk_heads(H: int) -> tuple:
+    """(heads of a grid step of the latent chunk kernel, how many of them one
+    pass of its head loop takes) for H heads: the largest divisor of H within
+    `LATENT_CHUNK_HEADS`, and `LATENT_CHUNK_CHAINS` of them a pass where that
+    divides them."""
+    hg = max(d for d in range(1, min(H, LATENT_CHUNK_HEADS) + 1) if H % d == 0)
+    chains = max(d for d in range(1, LATENT_CHUNK_CHAINS + 1) if hg % d == 0)
+    return hg, chains
+
+
 def _latent_chunk_kernel(
-    tables_ref, n_pages_ref, start_ref, q_ref, pool_hbm, o_ref,
-    buf, sems, m_s, l_s, acc_s, *, scale, nb, P, bq, rank,
+    tables_ref, n_pages_ref, start_ref, q_ref, w_ref, pool_hbm, o_ref,
+    buf, sems, k_s, wk_s, wvt_s, m_s, l_s, acc_s, *, scale, nb, P, rank, chains,
 ):
-    """Grid step (b, i): queries `start[b] + i * bq ...` of row b with all
-    their heads, as (H * bq, W) with row h * bq + t = (head h, query t),
-    against the key blocks that hold a position <= the last of them:
-    `_chunk_kernel` with one shared row in the place of the KV heads."""
+    """Grid step (b, i, g): queries `start[b] + i * bq ...` of row b with the
+    `hg` heads of group g, transposed ((hg, dn + rope lanes, bq)), against
+    every key block that holds a position <= the last of them, each block
+    walked ONCE: its pages copied, then a head's keys and values made from
+    the block's latents (`k = c W_uk` (T, dn) beside the block's one rotary
+    key, `v^T = W_uv^T c^T` (dv, T)) in the pool's dtype, scored against
+    the head's queries and summed. `_chunk_kernel` with the up-projection
+    in the place of the strided read of a KV head. The group's weights come
+    as the layer stores them, (r, hg * (dn + dv)) with a head's `W_uk`
+    beside its `W_uv`, and are laid out a head once a step: `W_uk` (r, dn)
+    as it is, `W_uv` transposed."""
     b, i = pl.program_id(0), pl.program_id(1)
-    N = q_ref.shape[0]  # H * bq query rows
+    hg, _, bq = q_ref.shape
+    dn, dv = wk_s.shape[2], wvt_s.shape[1]
     bs = pool_hbm.shape[1]
     T = P * bs  # keys a compute block
     precision = _precision(buf.dtype)
     first_q = start_ref[b] + i * bq
     n_pages = jnp.minimum(n_pages_ref[b], (first_q + bq + bs - 1) // bs)
     n_blocks = (n_pages + P - 1) // P
+    dot = functools.partial(
+        lax.dot_general, precision=precision,
+        preferred_element_type=jnp.float32,
+    )
+    nn, nt = (((1,), (0,)), ((), ())), (((1,), (1,)), ((), ()))
 
-    @pl.when((b == 0) & (i == 0))
+    @pl.when((b == 0) & (i == 0) & (pl.program_id(2) == 0))
     def _():
         buf[...] = jnp.zeros_like(buf)  # see `_latent_decode_kernel`
 
     m_s[...] = jnp.full_like(m_s, NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
     acc_s[...] = jnp.zeros_like(acc_s)
+    for h in range(hg):
+        wk_s[h] = w_ref[:, h * (dn + dv):h * (dn + dv) + dn]
+        wvt_s[h] = w_ref[:, h * (dn + dv) + dn:(h + 1) * (dn + dv)].T
 
     def page_copies(blk, slot, fn):
         _page_copies(
@@ -1642,29 +1705,54 @@ def _latent_chunk_kernel(
         page_copies(0, 0, lambda cp: cp.start())
 
     def attend(slot, key0, masked):
-        """Scores TRANSPOSED, (keys, N), for the reasons `_chunk_kernel`
-        gives: the softmax reduces down the sublanes and its running
-        state is lane-dense rows."""
-        k = buf[slot]  # (T, W)
-        s = lax.dot_general(
-            k, q_ref[...], (((1,), (1,)), ((), ())),
-            precision=precision, preferred_element_type=jnp.float32,
-        ) * scale  # (T, N)
-        if masked:
-            key = lax.broadcasted_iota(jnp.int32, (T, N), 0)
-            t = lax.rem(lax.broadcasted_iota(jnp.int32, (T, N), 1), bq)
-            keep = key <= jnp.minimum(first_q + t, n_pages * bs - 1) - key0
-            s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_s[...]  # (1, N)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=0, keepdims=True)
-        acc_s[...] = alpha * acc_s[...] + lax.dot_general(
-            k[:, :rank], p.astype(k.dtype), (((0,), (0,)), ((), ())),
-            precision=precision, preferred_element_type=jnp.float32,
-        )  # (rank, N)
-        m_s[...] = m_new
+        """Scores TRANSPOSED, (keys, bq) a head, for the reasons
+        `_chunk_kernel` gives. A pass of the head loop takes `chains` heads:
+        their up-projections, then their independent chains (score product,
+        column max, `exp`, value product), then every store, so that one
+        head's softmax runs under another's products."""
+        for n in range(chains):
+            # the one rotary key of a token is every head's: lanes
+            # `rank ...` of its row, beside the head's own keys
+            k_s[n, :, dn:] = buf[slot, :, rank:]
+
+        def heads(j, carry):
+            h0 = j * chains
+            c = buf[slot, :, :rank]  # (T, r): the block's latents
+            vt = dot(
+                wvt_s[pl.ds(h0, chains)].reshape(chains * dv, rank), c, nt
+            ).astype(buf.dtype)  # (chains * dv, T): the heads' values
+            for n in range(chains):
+                k_s[n, :, :dn] = dot(c, wk_s[h0 + n], nn).astype(buf.dtype)
+            olds = [
+                (m_s[h0 + n], l_s[h0 + n], acc_s[h0 + n]) for n in range(chains)
+            ]
+            ss = [
+                dot(k_s[n], q_ref[h0 + n], nn) * scale for n in range(chains)
+            ]  # (T, bq) each
+            if masked:
+                key = lax.broadcasted_iota(jnp.int32, (T, bq), 0)
+                t = lax.broadcasted_iota(jnp.int32, (T, bq), 1)
+                keep = key <= jnp.minimum(first_q + t, n_pages * bs - 1) - key0
+                ss = [jnp.where(keep, s, NEG_INF) for s in ss]
+            news = []
+            for n, (s, (m_prev, l_prev, acc_prev)) in enumerate(zip(ss, olds)):
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+                # masked: exp(-1e30 - m) == 0 exactly, key 0 is in every
+                # query's first block so m is a score from there on
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)  # (1, bq)
+                news.append((
+                    m_new,
+                    alpha * l_prev + jnp.sum(p, axis=0, keepdims=True),
+                    alpha * acc_prev + dot(
+                        vt[n * dv:(n + 1) * dv], p.astype(buf.dtype), nn
+                    ),  # (dv, bq)
+                ))
+            for n, (m_new, l_new, acc_new) in enumerate(news):
+                m_s[h0 + n], l_s[h0 + n], acc_s[h0 + n] = m_new, l_new, acc_new
+            return carry
+
+        lax.fori_loop(0, hg // chains, heads, 0)
 
     def body(blk, carry):
         slot = blk % 2
@@ -1675,8 +1763,9 @@ def _latent_chunk_kernel(
 
         page_copies(blk, slot, lambda cp: cp.wait())
         key0 = blk * T
-        # the mask is work: only a block that crosses the diagonal (a
-        # key past the first query) or the row's last valid page pays it
+        # the mask is work: only a block that crosses the diagonal (a key
+        # past the first query: the query block's own keys) or the row's
+        # last valid page pays it
         masked = (key0 + T - 1 > first_q) | (key0 + T > n_pages * bs)
 
         @pl.when(masked)
@@ -1690,81 +1779,123 @@ def _latent_chunk_kernel(
         return carry
 
     lax.fori_loop(0, n_blocks, body, 0)
-    l = l_s[...]  # 0 on a row with no valid page: zeros, not 0 / 0
-    o_ref[...] = jnp.where(l > 0, acc_s[...] / l, 0.0).T.astype(o_ref.dtype)
+
+    def finish(h, carry):
+        l = l_s[h]  # 0 on a row with no valid page: zeros, not 0 / 0
+        o_ref[h] = jnp.where(l > 0, acc_s[h] / l, 0.0).astype(o_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, hg, finish, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "rank", "interpret"))
-def _latent_chunk_device(q, pool, block_tables, starts, *, scale, rank, interpret):
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _latent_chunk_device(
+    q_nope, q_rope, w_uk, w_uv, pool, block_tables, starts, *, scale, interpret
+):
     """The latent chunk kernel's call; a `jax.jit` of its own for the
-    reason `_per_device` is one."""
-    B, L, H, W = q.shape
-    nblk, bs, _ = pool.shape
+    reason `_per_device` is one. Around the call, as around
+    `paged_chunk_attention`, the two XLA transposes that regroup the queries
+    and the outputs by head; the weights go in as the layer stores them,
+    a head's `W_uk` beside its `W_uv` (the two slices of `kv_b_proj` put
+    together again: no copy in the layer's program)."""
+    B, L, H, dn = q_nope.shape
+    rank, _, dv = w_uv.shape
+    nblk, bs, W = pool.shape
     nb = block_tables.shape[1]
-    bq = min(L, LATENT_QUERY_BLOCK)
-    nq = L // bq
     P = _pages_per_block(bs, nb)
+    bq = min(L, CHUNK_QUERY_BLOCK)
+    hg, chains = _latent_chunk_heads(H)
     block_tables = block_tables.astype(jnp.int32)
     scalars = (
         block_tables.reshape(B * nb), _leading(block_tables < nblk),
         starts.astype(jnp.int32),
     )
-    # head h of query i * bq + t -> [i, h * bq + t]
-    qg = q.reshape(B, nq, bq, H, W).transpose(0, 1, 3, 2, 4).reshape(B, nq, H * bq, W)
-    block = lambda width: pl.BlockSpec(
-        (None, None, H * bq, width), lambda b, i, *_: (b, i, 0, 0)
+    # a head's query as the kernel scores it: [q_nope; q_rope; zeros] down
+    # the sublanes, against [k_nope | the lanes of a pool row behind its
+    # latent] (the rotary key, zeros where the pool holds wider rows)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    Dq = dn + W - rank
+    q = jnp.pad(q, [(0, 0)] * 3 + [(0, Dq - q.shape[-1])]).astype(pool.dtype)
+    qt = q.transpose(0, 2, 3, 1)  # (B, H, Dq, L)
+    heads = lambda rows: pl.BlockSpec(
+        (None, hg, rows, bq), lambda b, i, g, *_: (b, g, 0, i)
     )
+    w = jnp.concatenate([w_uk, w_uv], axis=-1).astype(pool.dtype)
+    w = w.reshape(rank, H * (dn + dv))
     f32 = jnp.float32
     with jax.named_scope("latent_chunk_kernel"):
         out = pl.pallas_call(
             functools.partial(
-                _latent_chunk_kernel, scale=scale, nb=nb, P=P, bq=bq, rank=rank
+                _latent_chunk_kernel, scale=scale, nb=nb, P=P, rank=rank,
+                chains=chains,
             ),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(scalars),
-                grid=(B, nq),
-                in_specs=[block(W), pl.BlockSpec(memory_space=pl.ANY)],
-                out_specs=block(rank),
+                grid=(B, L // bq, H // hg),
+                in_specs=[
+                    heads(Dq),
+                    pl.BlockSpec(
+                        (rank, hg * (dn + dv)), lambda b, i, g, *_: (0, g)
+                    ),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                ],
+                out_specs=heads(dv),
                 scratch_shapes=[
                     pltpu.VMEM((2, P * bs, W), pool.dtype),
                     pltpu.SemaphoreType.DMA((1, 2)),
-                    pltpu.VMEM((1, H * bq), f32),
-                    pltpu.VMEM((1, H * bq), f32),
-                    pltpu.VMEM((rank, H * bq), f32),
+                    pltpu.VMEM((chains, P * bs, Dq), pool.dtype),
+                    pltpu.VMEM((hg, rank, dn), pool.dtype),
+                    pltpu.VMEM((hg, dv, rank), pool.dtype),
+                    pltpu.VMEM((hg, 1, bq), f32),
+                    pltpu.VMEM((hg, 1, bq), f32),
+                    pltpu.VMEM((hg, dv, bq), f32),
                 ],
             ),
-            out_shape=jax.ShapeDtypeStruct((B, nq, H * bq, rank), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((B, H, dv, L), q_nope.dtype),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY),
+                dimension_semantics=(pltpu.ARBITRARY,) * 3,
                 vmem_limit_bytes=CHUNK_VMEM_BYTES,
             ),
             interpret=interpret,
             name="latent_chunk_attention",
-        )(*scalars, qg, pool)
-    out = out.reshape(B, nq, H, bq, rank).transpose(0, 1, 3, 2, 4)
-    return out.reshape(B, L, H, rank)
+        )(*scalars, qt, w, pool)
+    return out.transpose(0, 3, 1, 2)  # (B, L, H, dv)
 
 
 def latent_chunk_attention(
-    q, pool, block_tables, starts, scale, *, rank: int, interpret=None
+    q_nope, q_rope, w_uk, w_uv, pool, block_tables, starts, scale, *,
+    interpret=None,
 ):
     """A prefill chunk of L query tokens a row against a paged LATENT
-    pool, absorbed: `latent_decode_attention` carried to L queries as
-    `paged_chunk_attention` carries the decode kernel.
+    pool, in the UP-PROJECTED form of multi-head latent attention: the
+    layer as it is written, over the cache.
 
-    q: (B, L, H, W); pool, block_tables as `latent_decode_attention`
-    takes them; starts: (B,) int32. Query i of row b sits at absolute
-    position `starts[b] + i` and attends the rows at positions <= it (the
-    chunk's own, written first, included); returns (B, L, H, rank). A
-    grid step takes `LATENT_QUERY_BLOCK` queries with all their heads
-    (the one shared row is every head's key and value, so the heads of a
-    query block are one operand of H * bq rows) and walks the key blocks
-    up to its last query; a block is copied once and read as keys (W
-    values) and as values (the leading `rank`). Callers check
-    `paged_kernel` first; precision contract in the module docstring."""
+    q_nope: (B, L, H, dn) and q_rope: (B, L, H, dr), a head's query as the
+    layer makes it (rotated, NOT moved onto the latent); w_uk: (r, H, dn)
+    and w_uv: (r, H, dv), the layer's up-projections; pool, block_tables
+    as `latent_decode_attention` takes them, the leading r values of a row
+    its latent and the next dr its rotary key; starts: (B,) int32. Query i
+    of row b sits at absolute position `starts[b] + i` and attends the rows
+    at positions <= it (the chunk's own, written first, included); returns
+    the heads' outputs themselves, (B, L, H, dv) in q_nope's dtype.
+
+    A grid step takes a block of `CHUNK_QUERY_BLOCK` queries of a row (all
+    L up to that many) with a GROUP of heads (`_latent_chunk_heads`) and
+    walks the row's key blocks once: a block's pages are copied once a step,
+    each head's keys and values are made from them in VMEM (one product a
+    head and a block: every (key, head) is up-projected ONCE a query block),
+    scored over dn + dr values and summed over dv. That is 2 r (dn + dv)
+    FLOPs a key and head and 2 (dn + dr + dv) a pair where the absorbed
+    form pays 2 (2 r + dr) a pair: less from r (dn + dv) / (2 r - dn - dv)
+    queries a key up (171 at r 512, dn = dv 128), and the latents are read
+    H / heads-a-group times a query block. `w_uk` and `w_uv` are the two
+    halves of one (r, H, dn + dv) array in the layer, and the call puts
+    them together again: XLA hands the kernel the stored matrix. Callers
+    check `paged_kernel` first; design and precision contract in the module
+    docstring."""
     if interpret is None:
         interpret = _interpret_default()
     return _latent_chunk_device(
-        q, pool, block_tables, starts, scale=scale, rank=rank,
+        q_nope, q_rope, w_uk, w_uv, pool, block_tables, starts, scale=scale,
         interpret=interpret,
     )

@@ -421,7 +421,7 @@ def test_paged_chunk_kernel_compiles_at_the_serve_cells_widths(
 LATENT_CELLS = {"pangu_longdoc_c8": (8192, 8, 128), "xing_agent_prefix_c32": (16384, 32, 32)}
 
 
-@pytest.mark.parametrize("L", [1, 512, 128])
+@pytest.mark.parametrize("L", [1, 512, 128, 2048])
 @pytest.mark.parametrize("cell", sorted(LATENT_CELLS))
 def test_the_latent_kernels_compile_at_the_latent_cells_shapes(topo, monkeypatch, cell, L):
     """`ops.latent_decode_attention` and `ops.latent_chunk_attention` as
@@ -429,12 +429,13 @@ def test_the_latent_kernels_compile_at_the_latent_cells_shapes(topo, monkeypatch
     8192-block latent pool for the step, 128 heads) and as
     `serve_xing_agent_prefix_c32` does (32 rows x 1024 pages of 16384 blocks,
     32 heads, the softmax scale with YaRN's factor): one row for a chunk (the
-    largest and the smallest bucket), rows of 576 values held as 640, of
-    which 512 are values. Mosaic takes the page copies (it refused a pool
-    told 576: `Slice shape along dimension 2 must be aligned to tiling
-    (128)`), the 640-wide contraction and the chunk kernel's VMEM, and the
-    pool goes into the call as it is: no copy of its 1.17 or 2.68 GB in any
-    call."""
+    largest and the smallest bucket; the layer's own queries, 128 + 64 values
+    a head, and its two up-projections: the kernel makes a key's heads in
+    VMEM), rows of 576 values held as 640, of which 512 are values. Mosaic
+    takes the page copies (it refused a pool told 576: `Slice shape along
+    dimension 2 must be aligned to tiling (128)`), the 640-wide contraction
+    of a step, the chunk kernel's head group in VMEM, and the pool goes into
+    the call as it is: no copy of its 1.17 or 2.68 GB in any call."""
     import functools
 
     import jax
@@ -449,30 +450,49 @@ def test_the_latent_kernels_compile_at_the_latent_cells_shapes(topo, monkeypatch
     one = SingleDeviceSharding(topo.devices[0])
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
     (nblk, rows, H), bs, nb, rank = LATENT_CELLS[cell], 16, 1024, 512
-    W = pool_latent_width(rank + 64)
+    dn, dr, dv = 128, 64, 128
+    W = pool_latent_width(rank + dr)
     assert W == 640
     pool = sd((nblk, bs, W), jnp.bfloat16)
     B = rows if L == 1 else 1
     tables = sd((B, nb), jnp.int32)
     if L == 1:
         assert paged_kernel(1, pool, tables, rank=rank) == "latent_decode"
-        call, q, name = latent_decode_attention, sd((B, H, W), jnp.bfloat16), "latent_decode"
+        call, name = functools.partial(latent_decode_attention, rank=rank), "latent_decode"
+        operands = (sd((B, H, W), jnp.bfloat16),)
     else:
+        # the chunk's queries as the layer makes them, and its up-projections
         assert paged_kernel(L, pool, tables, rank=rank) == "latent_chunk"
-        call, q, name = latent_chunk_attention, sd((B, L, H, W), jnp.bfloat16), "latent_chunk"
+        # and the two halves of its `kv_b_proj`, sliced as the layer slices them
+        def call(q_nope, q_rope, w_kvb, pool, tables, starts, scale):
+            w_kvb = w_kvb.reshape(rank, H, dn + dv)
+            return latent_chunk_attention(
+                q_nope, q_rope, w_kvb[..., :dn], w_kvb[..., dn:], pool, tables, starts, scale)
+
+        name = "latent_chunk"
+        operands = (sd((B, L, H, dn), jnp.bfloat16), sd((B, L, H, dr), jnp.bfloat16),
+                    sd((rank, H * (dn + dv)), jnp.bfloat16))
     scale = 192 ** -0.5 * (2.0047 if H == 32 else 1.0)
-    compiled = jax.jit(functools.partial(call, scale=scale, rank=rank)).lower(
-        q, pool, tables, sd((B,), jnp.int32)).compile()
+    compiled = jax.jit(functools.partial(call, scale=scale)).lower(
+        *operands, pool, tables, sd((B,), jnp.int32)).compile()
     hlo = compiled.as_text()
     (custom,) = _custom_calls(hlo)
     assert f"{name}_attention" in custom
+    # the kernel's scope holds the call alone: the share of a roofline tells
+    # a whole run of the program by one operation a layer under it
+    assert [l for l in hlo.splitlines() if f"/{name}_kernel/" in l and " = " in l] == [custom]
     # the pool is an operand of the call itself, and nothing else has its shape
     assert "%pool" in custom.split("custom-call(")[1]
     assert not [l for l in hlo.splitlines()
                 if f" = bf16[{nblk},{bs},{W}]" in l and "parameter(" not in l]
+    if L > 1:  # and so is the layer's `kv_b_proj`, whole: no copy lays it out
+        assert not [l for l in hlo.splitlines() if f" = bf16[{rank},{H * (dn + dv)}]" in l
+                    and (" copy(" in l or " fusion(" in l)]
     # a chunk's temporaries are its queries and outputs regrouped by head
-    # (2 x 512 x 128 x (640 + 512) x 2 B = 151 MB), never the pool's 168 MB
-    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * L * H * (W + rank) * 2 + 2**20
+    # (2 x 512 x 128 x (128 + 64 + 128) x 2 B = 84 MB where the absorbed form
+    # held 151), never the pool's 168 MB nor a key's up-projected heads
+    width = W + rank if L == 1 else dn + dr + dv
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * L * H * width * 2 + 2**20
 
 
 def test_the_agent_cell_s_step_and_chunk_fit_the_chip_beside_its_pool(topo, monkeypatch):

@@ -31,7 +31,9 @@ from pytorch_distributed_example_tpu.ops import (
 from pytorch_distributed_example_tpu.parallel.expert_parallel import dropless_moe
 from pytorch_distributed_example_tpu.serve import ServeEngine
 
-from test_latent_moe import BS, M, _einsum_reference, _pool_and_tables, _serve_by_hand
+from test_latent_moe import (
+    BS, M, _chunk_operands, _einsum_reference, _layer_reference, _pool_and_tables, _serve_by_hand,
+)
 from test_paged_attention import NBLK, SPAN, share_tables, work_list  # tables that share pages
 from test_sparse_window import Probe  # keeps every prefill chunk's (start, tokens, logits)
 
@@ -640,13 +642,15 @@ def test_the_latent_decode_kernel_asks_for_no_more_vmem_than_a_kernel_gets(B, H)
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-5), (jnp.bfloat16, 7e-2)])
 @pytest.mark.parametrize("L,start", [(64, 0), (64, 200), (32, 250), (16, 37)])
 def test_the_latent_chunk_kernel_at_32_heads_is_the_masked_einsum(L, start, dtype, tol):
+    """One head group of 32, YaRN's factor in the softmax scale."""
     scale = 192 ** -0.5 * 2.0048
     rng = np.random.default_rng(L + start)
     pool, tables = _pool_and_tables(rng, (start + L - 1,), dtype=dtype)
-    q = jnp.asarray(rng.normal(size=(1, L, 32, 256)), dtype)
-    got = latent_chunk_attention(q, pool, jnp.asarray(tables), jnp.asarray([start]), scale,
-                                 rank=128, interpret=True)
-    want = _einsum_reference(q, pool, tables, start + np.arange(L)[None], 128, scale)
+    pool = pool.at[..., 128 + 16:].set(0)  # a pool holds zeros behind a row's values
+    operands = _chunk_operands(rng, 1, L, 32, dtype)
+    got = latent_chunk_attention(*operands, pool, jnp.asarray(tables), jnp.asarray([start]),
+                                 scale, interpret=True)
+    want = _layer_reference(*operands, pool, tables, start + np.arange(L)[None], scale)
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
                                atol=tol)
 

@@ -456,19 +456,62 @@ def test_the_latent_decode_kernel_is_the_masked_einsum(lengths, dtype, tol):
     assert not np.asarray(got[3], np.float32).any()  # no page read, zeros
 
 
+def _layer_reference(q_nope, q_rope, w_uk, w_uv, pool, tables, pos, scale):
+    """The layer as it is written, over the gathered rows: a key's heads
+    up-projected from its latent (`kv_up`), the one rotary key beside them,
+    a softmax masked by absolute position. q_nope (B, L, H, dn) and q_rope
+    (B, L, H, dr) at positions pos (B, L)."""
+    from pytorch_distributed_example_tpu.models.transformer import _position_mask
+
+    rank, dr = w_uk.shape[0], q_rope.shape[-1]
+    held = gather_paged_latent(pool, jnp.asarray(tables))
+    c_kv, k_rope = held[..., :rank], held[..., rank:rank + dr]
+    k_nope = jnp.einsum("bmr,rhd->bmhd", c_kv, w_uk)
+    v = jnp.einsum("bmr,rhd->bmhd", c_kv, w_uv)
+    f32 = dict(preferred_element_type=jnp.float32)
+    s = (jnp.einsum("blhd,bmhd->bhlm", q_nope, k_nope, **f32)
+         + jnp.einsum("blhd,bmd->bhlm", q_rope, k_rope, **f32)) * scale
+    mask = _position_mask(jnp.asarray(pos), jnp.arange(held.shape[1])[None])
+    p = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1).astype(v.dtype)
+    return jnp.einsum("bhlm,bmhd->blhd", p, v)
+
+
+def _chunk_operands(rng, B, L, H, dtype, rank=128, dn=32, dr=16, dv=24):
+    """A chunk's queries as the layer makes them and its two up-projections."""
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), dtype)
+    return (normal(B, L, H, dn), normal(B, L, H, dr),
+            normal(rank, H, dn) * rank ** -0.5, normal(rank, H, dv) * rank ** -0.5)
+
+
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 4e-2)])
-@pytest.mark.parametrize("L,start", [(64, 0), (64, 200), (32, 250), (16, 37)])
-def test_the_latent_chunk_kernel_is_the_masked_einsum(L, start, dtype, tol):
-    """Chunks that start at a block's edge and inside one, that cross the
-    256-key block, of one and of two query blocks."""
+@pytest.mark.parametrize("L,start,H", [
+    (64, 0, 8), (64, 200, 8), (32, 250, 8), (16, 37, 8),
+    (64, 480, 8),   # the chunk's own keys on both sides of a key block's edge
+    (32, 0, 48),    # heads in two groups of 24
+    (64, 560, 48),  # two groups, three key blocks
+    (1024, 40, 8),  # two query blocks: the second starts past the first's keys
+])
+def test_the_latent_chunk_kernel_is_the_masked_einsum(L, start, H, dtype, tol):
+    """Chunks that start at a page's edge and inside one, at the row's first
+    token, that cross a key block's edge with their queries' own keys, of one
+    head group and of two, of one query block and of two: the kernel
+    (interpreted) against the cache-free
+    arithmetic over the gathered rows, so it is held to the layer's
+    definition and to no other form of it."""
+    from pytorch_distributed_example_tpu.ops.paged_attention import (
+        CHUNK_QUERY_BLOCK, KEYS_PER_BLOCK, _latent_chunk_heads)
+
+    assert _latent_chunk_heads(8)[0] == 8 and _latent_chunk_heads(48)[0] == 24
+    assert (KEYS_PER_BLOCK, CHUNK_QUERY_BLOCK) == (256, 512)
     rng = np.random.default_rng(L + start)
-    pool, tables = _pool_and_tables(rng, (start + L - 1,), dtype=dtype)
-    q = jnp.asarray(rng.normal(size=(1, L, 8, 256)), dtype)
-    got = latent_chunk_attention(q, pool, jnp.asarray(tables), jnp.asarray([start]), 0.1,
-                                 rank=128, interpret=True)
+    pool, tables = _pool_and_tables(rng, (start + L - 1,), nblk=96, nb=72, dtype=dtype)
+    q_nope, q_rope, w_uk, w_uv = _chunk_operands(rng, 1, L, H, dtype)
+    pool = pool.at[..., 128 + 16:].set(0)  # a pool holds zeros behind a row's values
+    got = latent_chunk_attention(q_nope, q_rope, w_uk, w_uv, pool, jnp.asarray(tables),
+                                 jnp.asarray([start]), 0.1, interpret=True)
     pos = start + np.arange(L)[None]
-    want = _einsum_reference(q, pool, tables, pos, 128, 0.1)
-    assert got.shape == (1, L, 8, 128)
+    want = _layer_reference(q_nope, q_rope, w_uk, w_uv, pool, tables, pos, 0.1)
+    assert got.shape == (1, L, H, 24) and got.dtype == dtype
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
                                atol=tol)
 
@@ -478,11 +521,14 @@ def test_padded_queries_past_a_row_s_pages_stay_finite():
     what the row has, and a row with no page returns zeros."""
     rng = np.random.default_rng(0)
     pool, tables = _pool_and_tables(rng, (40, -1))
-    q = jnp.asarray(rng.normal(size=(2, 32, 8, 256)), jnp.float32)
+    pool = pool.at[..., 128 + 16:].set(0)
+    q_nope, q_rope, w_uk, w_uv = _chunk_operands(rng, 2, 32, 8, jnp.float32)
     got = np.asarray(latent_chunk_attention(
-        q, pool, jnp.asarray(tables), jnp.asarray([32, 0]), 0.1, rank=128, interpret=True))
+        q_nope, q_rope, w_uk, w_uv, pool, jnp.asarray(tables), jnp.asarray([32, 0]), 0.1,
+        interpret=True))
     assert np.isfinite(got).all() and not got[1].any()
-    want = _einsum_reference(q[:1, :9], pool, tables[:1], 32 + np.arange(9)[None], 128, 0.1)
+    want = _layer_reference(q_nope[:1, :9], q_rope[:1, :9], w_uk, w_uv, pool, tables[:1],
+                            32 + np.arange(9)[None], 0.1)
     np.testing.assert_allclose(got[0, :9], np.asarray(want)[0], atol=2e-5)
 
 
@@ -496,7 +542,9 @@ def test_padded_queries_past_a_row_s_pages_stay_finite():
     ((64, 8, 256), 128, jnp.bfloat16, 1, None),      # pages of half a sublane tile
     ((64, 16, 256), 128, jnp.int8, 1, None),
     ((64, 16, 256), 128, jnp.bfloat16, 20, None),    # a chunk of no whole sublane tiles
-    ((64, 16, 256), 128, jnp.bfloat16, 48, None),    # nor of whole query blocks
+    ((64, 16, 256), 128, jnp.bfloat16, 48, "latent_chunk"),  # whole tiles: one query block
+    ((8192, 16, 640), 512, jnp.bfloat16, 2048, "latent_chunk"),  # four query blocks
+    ((8192, 16, 640), 512, jnp.bfloat16, 768, None),  # past a query block, not whole ones
 ])
 def test_which_latent_pools_take_a_kernel(shape, rank, dtype, L, want):
     pool = jax.ShapeDtypeStruct(shape, dtype)

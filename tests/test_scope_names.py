@@ -148,9 +148,14 @@ EXPECTED = {
         "kernel_scope": r"^latent_decode_kernel/",
         "kv_scatter": LATENT + r"kv_scatter/scatter",
     },
+    # a chunk with the kernel runs the layer as written: the kernel's call is
+    # ALL the scope holds (`latent_chunk_roofline` divides by its time, and
+    # tells a whole run of the program by one operation a layer there)
     "latent_prefill_chunk_kernel": {
         "cache_attention": LATENT + r"cache_attention/jit\(_latent_chunk_device\)$",
         "kernel_scope": r"^latent_chunk_kernel/",
+        "kernel_call": r"^latent_chunk_kernel/latent_chunk_attention/pallas_call$",
+        "kv_scatter": LATENT + r"kv_scatter/scatter",
     },
     # a latent model with four residual streams and a router bias: both
     # halves of every map under its Flax name (`hc_attn` / `hc_mlp` a block,
@@ -484,6 +489,37 @@ def test_a_model_of_one_stream_traces_no_map(serve_paths):
     assert maps == {f"layers_{i}/hc_{s}" for i in (0, 1) for s in ("attn", "mlp")}
     assert [p for p in paths if "hc_out.pre/hc_pre" in p]
     assert not [p for p in paths if "hc_out" in p and "hc_sinkhorn" in p]  # the end mixes, no more
+
+
+def test_a_chunk_with_the_latent_kernel_moves_no_up_projection_outside_it(serve_paths):
+    """The chunk program whose layers call `ops.latent_chunk_attention` holds
+    no `absorb_q` / `absorb_out` product and no `kv_up` one: a key's heads
+    are made inside the kernel's call, under `latent_chunk_kernel`, and
+    nowhere else. The step beside it, the gather fallback and a model of
+    several streams keep the absorbed products (`EXPECTED` holds theirs)."""
+    import functools
+
+    from pytorch_distributed_example_tpu.ops import paged_attention
+
+    chunk = serve_paths["latent_prefill_chunk_kernel"]
+    assert chunk["program"] == "jit_prefill_chunk"
+    for scope in ("absorb_q", "absorb_out", "kv_up", "kv_gather"):
+        assert not [p for p in chunk["paths"] if f"/{scope}" in p], scope
+    calls = [p for p in chunk["paths"] if p.endswith("cache_attention/jit(_latent_chunk_device)")]
+    assert sorted(re.search(r"layers_\d+", p).group() for p in calls) == ["layers_0", "layers_1"]
+    step = serve_paths["latent_step_kernel"]["paths"]
+    assert [p for p in step if "/absorb_q/" in p] and [p for p in step if "/absorb_out/" in p]
+    # under the kernel's scope: the call alone
+    sd = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(functools.partial(
+        paged_attention._latent_chunk_device.__wrapped__, scale=0.1, interpret=False))(
+        sd((1, 16, 4, 8), jnp.float32), sd((1, 16, 4, 4), jnp.float32),
+        sd((128, 4, 8), jnp.float32), sd((128, 4, 8), jnp.float32),
+        sd((16, 8, 256), jnp.float32), sd((1, 8), jnp.int32), sd((1,), jnp.int32)).jaxpr
+    under = [e.primitive.name for e in jaxpr.eqns
+             if "latent_chunk_kernel" in str(e.source_info.name_stack)]
+    assert under == ["pallas_call"]
+    assert "dot_general" not in [e.primitive.name for e in jaxpr.eqns]
 
 
 def test_a_latent_step_holds_one_kernel_call_a_layer_and_its_work_list_outside(serve_paths):
