@@ -87,46 +87,29 @@ def _programs(model, temperature: float, top_k: Optional[int], eos_id):
 
 def init_cache(model, batch_size: int):
     """Empty KV cache for `model` at this batch size — built directly
-    from the config (per layer: (B, max_seq_len, kv_heads, head_dim) K/V
-    + index), no model trace on the request path. The structure mirrors
-    the module tree; `test_generate.py` pins it against
+    from the config, no model trace on the request path: per layer, under
+    its mixer's name, the leaves the mixer keeps
+    (`models.transformer.cache_leaves`) — (B, max_seq_len) entries and an
+    `index` where it keeps one a token, (B,) where it keeps one a row. The
+    structure mirrors the module tree; `test_generate.py` pins it against
     `model.init(decode=True)` so drift fails loudly."""
     import jax.numpy as jnp
 
-    from .transformer import STATE_KINDS, state_block_shapes
+    from .transformer import cache_leaves, layers_of
 
     cfg = model.cfg
-    B, M, KV, Dh = batch_size, cfg.max_seq_len, cfg.kv_heads, cfg.head_dim
-
-    def one_layer():
-        return {
-            "attn": {
-                "k": jnp.zeros((B, M, KV, Dh), cfg.dtype),
-                "v": jnp.zeros((B, M, KV, Dh), cfg.dtype),
-                "index": jnp.zeros((), jnp.int32),
-            }
-        }
-
-    def state_layer(kind):  # a mixer that keeps a state block, no K/V
-        mixer, leaves = state_block_shapes(cfg, kind)
-        return {mixer: {
-            leaf: jnp.zeros((B,) + shape, dtype)
+    tree = {}
+    for i, kind in enumerate(layers_of(cfg)):
+        mixer, span, leaves = cache_leaves(cfg, kind)
+        lead = (batch_size,) if span == "row" else (batch_size, cfg.max_seq_len)
+        layer = {
+            leaf: jnp.zeros(lead + shape, dtype)
             for leaf, (shape, dtype) in leaves.items()
-        }}
-
-    def latent_layer():  # one row a token: the latent and its rotary key
-        return {"latent_attn": {
-            "latent": jnp.zeros((B, M, cfg.latent_width), cfg.dtype),
-            "index": jnp.zeros((), jnp.int32),
-        }}
-
-    def layer(i):
-        kind = cfg.layers[i].attention if getattr(cfg, "layers", None) else "full"
-        if kind in STATE_KINDS:
-            return state_layer(kind)
-        return latent_layer() if kind == "latent" else one_layer()
-
-    return {f"layers_{i}": layer(i) for i in range(cfg.n_layers)}
+        }
+        if span != "row":
+            layer["index"] = jnp.zeros((), jnp.int32)
+        tree[f"layers_{i}"] = {mixer: layer}
+    return tree
 
 
 def generate(

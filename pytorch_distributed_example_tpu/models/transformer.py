@@ -54,13 +54,13 @@ class RopeSpec:
 
 
 # what a layer's mixer keeps between calls: every key and value, those of
-# a window, one STATE BLOCK a row whose leaves the mixer names
-# (`state_block_shapes`: a linear layer's recurrent state and conv tail, a
-# conv layer's tail alone), or one compressed latent a token. A kind's name
-# is the `LayerSpec.attention` of the layers that keep it.
+# a window, one STATE BLOCK a row whose leaves the mixer names (a linear
+# layer's recurrent state and conv tail, a conv layer's tail alone), or one
+# compressed latent a token. A kind's name is the `LayerSpec.attention` of
+# the layers that keep it. A kind is registered in two places: `cache_leaves`
+# below (what its mixer keeps) and its record in `serve/kinds.py` (what the
+# serve plane does with that).
 CACHE_KINDS = ("full", "window", "linear", "latent", "conv")
-# the kinds whose layers keep a state block and no keys
-STATE_KINDS = ("linear", "conv")
 # tokens of one sub-chunk of a linear layer's chunked scan
 # (`gated_delta_chunked`): a power of two that divides every prefill
 # bucket of the serve cells (128, 256, 512)
@@ -1110,17 +1110,65 @@ def conv_state_shapes(cfg) -> dict:
     return {"tail": ((cfg.conv_taps - 1, cfg.d_model), cfg.dtype)}
 
 
+def layers_of(cfg) -> Tuple[str, ...]:
+    """The kind of each layer (one of `CACHE_KINDS`), one a layer; a model
+    configuration without a pattern keeps every key and value in all."""
+    layers = getattr(cfg, "layers", None)
+    if not layers:
+        return ("full",) * cfg.n_layers
+    return tuple(spec.attention for spec in layers)
+
+
+def kv_shapes(cfg) -> dict:
+    """leaf -> (shape, dtype) of what ONE token keeps in an attention layer,
+    as `Attention` reads and writes it: its K and its V heads."""
+    kv = ((cfg.kv_heads, cfg.head_dim), cfg.dtype)
+    return {"k": kv, "v": kv}
+
+
+def latent_shapes(cfg) -> dict:
+    """leaf -> (shape, dtype) of what ONE token keeps in a latent layer, as
+    `LatentAttention` reads and writes it: `latent`, the compressed latent
+    and the shared rotary key in one row."""
+    return {"latent": ((cfg.latent_width,), cfg.dtype)}
+
+
+# kind -> (mixer, span, what one entry holds): the model's half of a kind's
+# registration, in `CACHE_KINDS`' order
+_CACHE_LEAVES = {
+    "full": ("attn", "tokens", kv_shapes),
+    "window": ("attn", "window", kv_shapes),
+    "linear": ("linear_attn", "row", linear_state_shapes),
+    "latent": ("latent_attn", "tokens", latent_shapes),
+    "conv": ("gated_conv", "row", conv_state_shapes),
+}
+assert tuple(_CACHE_LEAVES) == CACHE_KINDS
+# the kinds whose layers keep one state block a row and no keys
+STATE_KINDS = tuple(
+    kind for kind, (_, span, _) in _CACHE_LEAVES.items() if span == "row"
+)
+
+
+def cache_leaves(cfg, kind: str):
+    """(mixer, span, leaves) of what a layer of `kind` (one of
+    `CACHE_KINDS`) keeps between calls, in its mixer's own words: `mixer` is
+    the name its subtree sits under in a block; `span` says how the state
+    grows ("tokens": an entry a token, kept for ever; "window": an entry a
+    token, of which the last `cfg.window` are read; "row": ONE entry a
+    request, whatever its length); `leaves` is leaf -> (shape, dtype) of
+    one entry. `models/generate.py` builds the dense cache and
+    `serve/cache.py` the paged pool from this, without knowing which mixer
+    it is; how a pool pads an entry is the pool's business
+    (`serve/kinds.py`)."""
+    mixer, span, shapes = _CACHE_LEAVES[kind]
+    return mixer, span, shapes(cfg)
+
+
 def state_block_shapes(cfg, kind: str):
-    """(the mixer's name in a block, leaf -> (shape, dtype)) of the state
-    block ONE row keeps in a layer of `kind` (one of `STATE_KINDS`): the
-    leaves are the mixer's own, and `serve/cache.py` and
-    `models/generate.py` build a layer's block from them without knowing
-    which mixer it is."""
-    name, shapes = {
-        "linear": ("linear_attn", linear_state_shapes),
-        "conv": ("gated_conv", conv_state_shapes),
-    }[kind]
-    return name, shapes(cfg)
+    """(mixer, leaves) of `cache_leaves`, for a kind whose layers keep one
+    state block a row (`STATE_KINDS`)."""
+    mixer, _, leaves = cache_leaves(cfg, kind)
+    return mixer, leaves
 
 
 def _unit_lower_inverse(m):

@@ -17,20 +17,19 @@ profiler trace as `serve:*` annotations and reduced on `/serve` to a
 `host` block (`window.host`: ms a phase a call, `wait_share`, the
 longest call with its split).
 
-Three kinds of state, one manager (`cache.py::PagedKVCache`; the programs
-take one block table a kind the model has, `cfg.cache_kinds`): FULL K/V
-blocks (every key and value, paged and refcounted; nothing is refused
-with them), WINDOW K/V blocks (a window layer's last `window + chunk`
-keys in a second pool, recycled while the request runs) and ONE
-STATE BLOCK a request (a layer whose mixer keeps a fixed state keeps no
-K/V and gets no K/V pool; the block's leaves are the mixer's own: a
-linear-attention layer's float32 state and conv tail, a gated short
-convolution's two-token tail alone; held from admission to retirement,
-zero for a row at position 0, untouched by padding and parked lanes).
-With window layers or with layers that keep a state block (linear,
-conv) the engine refuses `prefix_cache`, `kv_quant`, `mesh=`,
-disaggregated roles and `precompiled=`; a preempted request of such a
-model prefills again from 0.
+Three families of table, one manager (`cache.py::PagedKVCache`; the
+programs take one block table a kind of layer the model has,
+`cfg.cache_kinds`): refcounted BLOCKS (every token kept, paged and
+shareable), WINDOW blocks (a window layer's last `window + chunk` keys in
+a second pool, recycled while the request runs) and ONE STATE BLOCK a
+request (a layer whose mixer keeps a fixed state keeps no K/V and gets no
+K/V pool; the block's leaves are the mixer's own; held from admission to
+retirement, zero for a row at position 0, untouched by padding and parked
+lanes). Which family a kind of layer rides, what its pool holds, which
+path it reports and what the engine refuses with it (`prefix_cache`,
+`kv_quant`, `mesh=`, disaggregated roles, `precompiled=`) is ONE record a
+kind, in `kinds.py`; a preempted request of a model that keeps a window
+or a state prefills again from 0.
 
 Prefix sharing (ISSUE 12): the pool's physical blocks are refcounted
 with copy-on-write divergence (`cache.py`), and a radix prefix index

@@ -18,58 +18,28 @@ more blocks at fixed pool bytes, quantize-on-scatter in the paged
 write, dequant inside `ops.gather_paged_kv` so attention math stays
 full precision.
 
-TWO KINDS of K/V state (a model whose `cfg.window_layers` marks some
-layers as keeping a window): the full layers share the pool and the
-tables described above; the WINDOW layers share a second, small pool
-(`window_num_blocks` blocks, sized by the manager from the slots, the
-window, the prefill chunk and the block size: a row never holds more
-than `window_blocks_per_slot`) under a second table of the same
-logical shape. A window-layer block wholly behind `position - window`
-goes back to the window free list WHILE the request runs
-(`ensure_blocks(..., first_pos=)`), its table entry turns invalid, and
-the attention paths never read before a row's first attended block.
-`tables()` hands the programs the pair; `free`, `bytes_live` and the
-live-block counts account both kinds. A model with no window layer
-gets exactly the single-kind tree, tables and accounting.
-
-A THIRD KIND, the state block (a model some of whose layers' mixers keep
-a fixed state and NO keys and values: `models/transformer.py::STATE_KINDS`,
-today `LinearAttention` ("linear") and `GatedConv` ("conv")): such a layer
-gets no K/V pool; it gets a pool of `slots` STATE BLOCKS, each the layer's
-whole memory of one request, whose LEAVES ITS OWN MIXER NAMES
-(`state_block_shapes`: a linear layer's `state` (H, dk, dv) float32 and
-`conv`, the few pre-conv inputs behind the last token; a conv layer's
-`tail` alone, the `conv_taps - 1` gated inputs behind it). The manager
-allocates, frees and counts a block without naming a leaf: a layer whose
-state is a tail and a layer whose state is a delta-rule matrix are one
-kind of block in one table. A request
-holds exactly one state block from `allocate()` to `free()`, whatever its
-length, the same block index in every such layer, addressed through a
-(slots, 1) table of its own that rides with the K/V tables (`tables()`:
-one table a kind the model has, in `cfg.cache_kinds`' order; the "linear"
-and the "conv" kind are handed the same table, as the "latent" kind is
-handed the "full" kind's). An invalid entry drops the write, as for K/V,
-and a block is never cleared: the mixer reads zero for a row whose first
-token stands at position 0, so a block taken over from a retired or
-preempted request starts clean. What is not carried for it: snapshots (a
-preempted request prefills again from 0; a prefix cannot be shared).
-
-A FOURTH KIND, the latent (a model whose `cfg.latent_layers` are
-multi-head latent attention mixers, `models/transformer.py::
-LatentAttention`): such a layer keeps ONE row of `cfg.latent_width` values a
-token (a compressed latent and one shared rotary key) where a full layer
-keeps K and V heads, so it gets ONE pool, (num_blocks, block_size,
-latent_width), and no V pool. It keeps every token, as a full layer does:
-latent blocks are allocated, refcounted and freed through the SAME tables
-and free lists as the full kind's (a block id names the same tokens in
-every layer that keeps every token), and `tables()` hands the programs
-that table under the kind's name. A row of more than 128 values is held
-in whole 128-value lane tiles (`ops.pool_latent_width`: 576 as 640, the
-bytes the device would pad it to anyway), and `latent_bytes_per_block`
-counts the rows as held. A shared prefix's latent blocks are attached and
-copied on write with the rest of the tree (`cow_block` copies every leaf).
-What is not carried for it: an int8 pool, tp, block migration
-(`serve/engine.py` refuses them).
+THREE FAMILIES of table (`serve/kinds.py` tells them, and which kind of
+layer rides which): the refcounted BLOCKS described above, for the layers
+that keep every token (K and V heads, or a latent row: a block id names the
+same tokens in each of them); a WINDOW pool of its own (`window_num_blocks`
+blocks, sized from the slots, the window, the prefill chunk and the block
+size: a row never holds more than `window_blocks_per_slot`) under a second
+table of the same logical shape, whose blocks wholly behind `position -
+window` go back to the window free list WHILE the request runs
+(`ensure_blocks(..., first_pos=)`: the entry turns invalid, and the
+attention paths never read before a row's first attended block); and the
+STATE blocks, one a request from `allocate()` to `free()` whatever its
+length, the same index in every layer that keeps one, under a (slots, 1)
+table. An invalid entry drops the write in every family, and a state block
+is never cleared: its mixer reads zero for a row whose first token stands
+at position 0, so a block taken over from a retired or preempted request
+starts clean. The manager allocates, frees and counts without naming a
+kind or a leaf: what a layer's pool looks like is its kind's record
+(`Kind.pool`, from the leaves its mixer names:
+`models/transformer.py::cache_leaves`), `tables()` hands the programs one
+table a kind the model has, in `cfg.cache_kinds`' order, and `free`,
+`bytes_live` and the live-block counts account every family. A model of
+full layers alone gets exactly the single tree, table and accounting.
 
 Physical blocks are REFCOUNTED (ISSUE 12): `attach_prefix` lets a
 slot reference blocks another request already filled (the prefix
@@ -96,7 +66,7 @@ tree functionally — callers own exactly one live version.
 from __future__ import annotations
 
 import functools
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -157,46 +127,67 @@ def _import_blocks_fn():
     return jax.jit(imp, donate_argnums=(0,))
 
 
-def window_layers_of(cfg) -> tuple:
-    """Per layer, whether it keeps a window of K/V only (a model
-    configuration that says nothing keeps the whole context in all)."""
-    return tuple(getattr(cfg, "window_layers", ())) or (False,) * cfg.n_layers
+def pool_avals(cfg, blocks: Dict[str, Optional[int]], block_size: int,
+               quantized: bool = False) -> Dict[str, tuple]:
+    """kind -> (its mixer's name, leaf -> `ShapeDtypeStruct` of ONE layer's
+    subtree as the pool holds it), for the kinds `cfg`'s layers are of: the
+    kind's record says what (`serve/kinds.py::Kind.pool`, over the leaves
+    the mixer names), of as many blocks as `blocks` gives the family of
+    table the kind rides."""
+    from ..models.transformer import cache_leaves
+    from .kinds import FAMILIES, KINDS, kinds_of
+
+    records = kinds_of(cfg)
+    for family in FAMILIES:
+        if blocks[family] is None and any(k.table == family for k in records):
+            riders = [k.name for k in KINDS.values() if k.table == family]
+            raise ValueError(
+                f"a model with {' or '.join(riders)} layers needs {family}_blocks"
+            )
+    pools = {}
+    for kind in records:
+        mixer, _, leaves = cache_leaves(cfg, kind.name)
+        pools[kind.name] = (
+            mixer, kind.pool(leaves, blocks[kind.table], block_size, quantized)
+        )
+    return pools
 
 
-def state_layers_of(cfg) -> dict:
-    """layer -> its kind, for the layers whose mixer keeps a state block
-    and no K/V (`models.transformer.STATE_KINDS`; a model configuration
-    that says nothing has none)."""
-    from ..models.transformer import STATE_KINDS
+def _empty_tree(cfg, pools):
+    """The pool tree of zeros: per layer, its kind's subtree of `pools`."""
+    import jax.numpy as jnp
 
-    layers = getattr(cfg, "layers", None) or ()
-    return {
-        i: spec.attention for i, spec in enumerate(layers)
-        if spec.attention in STATE_KINDS
-    }
+    from ..models.transformer import layers_of
+
+    tree = {}
+    for i, kind in enumerate(layers_of(cfg)):
+        mixer, avals = pools[kind]
+        tree[f"layers_{i}"] = {mixer: {
+            leaf: jnp.zeros(aval.shape, aval.dtype) for leaf, aval in avals.items()
+        }}
+    return tree
 
 
-def latent_layers_of(cfg) -> tuple:
-    """The layers that keep one latent row a token and no K and V heads
-    (a model configuration that says nothing has none)."""
-    return tuple(getattr(cfg, "latent_layers", ()))
+def _block_bytes(avals) -> int:
+    """Bytes ONE block pins in pools of these shapes (blocks lead)."""
+    return sum(
+        int(np.prod(aval.shape[1:])) * np.dtype(aval.dtype).itemsize
+        for aval in avals
+    )
 
 
 def init_paged_cache(model, num_blocks: int, block_size: int,
                      quantized: bool = False,
                      window_blocks: Optional[int] = None,
                      state_blocks: Optional[int] = None):
-    """Empty paged K/V pool tree for `model`: per layer one
-    (num_blocks, block_size, kv_heads, head_dim) K and V (kv_heads in
-    whole sublane tiles: `ops.paged_attention.pool_kv_heads`) — of
-    `window_blocks` blocks instead in a layer the model's pattern marks
-    as a window layer, and NONE in a layer whose mixer keeps a state
-    (`state_layers_of`), which gets `state_blocks` state blocks of the
-    leaves its mixer names (`models.transformer.state_block_shapes`) under
-    the mixer's name; a latent layer gets ONE (num_blocks, block_size, latent_width)
-    pool, `latent`, under its mixer's. Mirrors
-    `models.generate.init_cache`'s structure minus the scalar "index"
-    leaf (a shared pool has no per-row cursor).
+    """Empty paged pool tree for `model`: per layer, under its mixer's
+    name, what its kind keeps (`pool_avals`): a full layer one (num_blocks,
+    block_size, kv_heads, head_dim) K and V (as `ops.pool_kv_shape` holds
+    the heads), a window layer the same of `window_blocks` blocks, a latent
+    layer ONE (num_blocks, block_size, latent_width) pool, and a layer
+    whose mixer keeps a state `state_blocks` blocks of the leaves the mixer
+    names. Mirrors `models.generate.init_cache`'s structure minus the
+    scalar "index" leaf (a shared pool has no per-row cursor).
 
     `quantized=True` switches the pool to INT8 K/V plus per-(block
     slot, kv-head) f32 scale planes `k_scale`/`v_scale` of shape
@@ -206,71 +197,11 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
     requantizing the block's earlier tokens. The paged attention path
     detects the scale planes and dequantizes inside
     `ops.gather_paged_kv`, so the attention math stays cfg.dtype."""
-    import jax.numpy as jnp
-
-    from ..models.transformer import state_block_shapes
-    from ..ops.paged_attention import pool_kv_shape, pool_latent_width
-
-    cfg = model.cfg
-    KV, Dh = pool_kv_shape(cfg.kv_heads, cfg.head_dim)
-    windowed = window_layers_of(cfg)
-    if any(windowed) and window_blocks is None:
-        raise ValueError("a model with window layers needs window_blocks")
-    stateful = state_layers_of(cfg)
-    if stateful and state_blocks is None:
-        raise ValueError(
-            "a model with linear or conv layers needs state_blocks"
-        )
-    latent = latent_layers_of(cfg)
-    if latent and quantized:
-        raise ValueError("a latent pool has no int8 form")
-
-    def one_layer(num_blocks):
-        if quantized:
-            return {
-                "attn": {
-                    "k": jnp.zeros(
-                        (num_blocks, block_size, KV, Dh), jnp.int8
-                    ),
-                    "v": jnp.zeros(
-                        (num_blocks, block_size, KV, Dh), jnp.int8
-                    ),
-                    "k_scale": jnp.zeros(
-                        (num_blocks, block_size, KV), jnp.float32
-                    ),
-                    "v_scale": jnp.zeros(
-                        (num_blocks, block_size, KV), jnp.float32
-                    ),
-                }
-            }
-        return {
-            "attn": {
-                "k": jnp.zeros((num_blocks, block_size, KV, Dh), cfg.dtype),
-                "v": jnp.zeros((num_blocks, block_size, KV, Dh), cfg.dtype),
-            }
-        }
-
-    def state_layer(kind):
-        mixer, leaves = state_block_shapes(cfg, kind)
-        return {mixer: {
-            leaf: jnp.zeros((state_blocks,) + shape, dtype)
-            for leaf, (shape, dtype) in leaves.items()
-        }}
-
-    def latent_layer():
-        return {"latent_attn": {"latent": jnp.zeros(
-            (num_blocks, block_size, pool_latent_width(cfg.latent_width)),
-            cfg.dtype,
-        )}}
-
-    def layer(i):
-        if i in stateful:
-            return state_layer(stateful[i])
-        if i in latent:
-            return latent_layer()
-        return one_layer(window_blocks if windowed[i] else num_blocks)
-
-    return {f"layers_{i}": layer(i) for i in range(cfg.n_layers)}
+    return _empty_tree(model.cfg, pool_avals(
+        model.cfg,
+        {"blocks": num_blocks, "window": window_blocks, "state": state_blocks},
+        block_size, quantized,
+    ))
 
 
 class PagedKVCache:
@@ -315,41 +246,26 @@ class PagedKVCache:
             raise ValueError(f"slots must be >= 1, got {slots}")
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
+        from ..models.transformer import layers_of
+        from .kinds import KINDS, kinds_of
+
         cfg = model.cfg
         M = cfg.max_seq_len
-        windowed = window_layers_of(cfg)
-        self.window_layers = sum(windowed)
-        from ..models.transformer import state_block_shapes
-        from ..ops.paged_attention import pool_kv_shape, pool_latent_width
-
-        # heads and values of a token's K (or V) row as the pool holds them
-        self.pool_kv_heads, self.pool_head_dim = pool_kv_shape(
-            cfg.kv_heads, cfg.head_dim
-        )
-        # the layers whose mixer keeps a state block, and for each of them
-        # leaf -> (shape, dtype) of its block: the leaves are the mixer's own
-        stateful = state_layers_of(cfg)
-        self.linear_layers = sum(kind == "linear" for kind in stateful.values())
-        self.conv_layers = sum(kind == "conv" for kind in stateful.values())
-        self.state_layers = len(stateful)
-        self._state_leaves = [
-            state_block_shapes(cfg, kind)[1] for kind in stateful.values()
-        ]
-        # the latent kind: one row a token in one pool a layer, under the
-        # full kind's tables and free lists
-        self.latent_layers = len(latent_layers_of(cfg))
-        # values of a row as the model caches it, and as the pool holds
-        # it: whole lane tiles past 128 values
-        self.latent_values = cfg.latent_width if self.latent_layers else 0
-        self.latent_width = pool_latent_width(self.latent_values)
-        self.latent_rank = cfg.latent_kv_rank if self.latent_layers else 0
-        self.full_layers = (
-            cfg.n_layers - self.window_layers - self.state_layers
-            - self.latent_layers
-        )
         # the kinds of state the model's layers keep, in the order the
-        # programs take their tables
-        self.kinds = tuple(getattr(cfg, "cache_kinds", ("full",)))
+        # programs take their tables, and the family of table each rides
+        self.records = kinds_of(cfg)
+        self.kinds = tuple(kind.name for kind in self.records)
+        self._families = tuple(kind.table for kind in self.records)
+        # layers of each kind (`full_layers`, `window_layers`, ...: 0 for a
+        # kind the model has none of) and of those that ride each family
+        count = Counter(layers_of(cfg))
+        for name in KINDS:
+            setattr(self, f"{name}_layers", count[name])
+        self.layers = {name: count[name] for name in self.kinds}
+        riding = Counter()
+        for kind in self.records:
+            riding[kind.table] += count[kind.name]
+        self.state_layers = riding["state"]
         self.model = model
         self.slots = slots
         self.block_size = block_size
@@ -371,9 +287,9 @@ class PagedKVCache:
         # behind its next write and of the longest write (`chunk_tokens`;
         # None = a whole prompt in one program), a partial block at each
         # end: one more block than the longest span a gather takes
-        self.window = cfg.window if self.window_layers else None
+        self.window = cfg.window if riding["window"] else None
         self.window_blocks_per_slot = self.window_num_blocks = 0
-        if self.window_layers:
+        if riding["window"]:
             span = self.window + min(chunk_tokens or M, M)
             self.window_blocks_per_slot = min(
                 -(-span // block_size) + 2, self.blocks_per_seq
@@ -385,11 +301,20 @@ class PagedKVCache:
         self.state_invalid_block = self.state_num_blocks
         self.state_table = np.full((slots, 1), self.state_invalid_block, np.int32)
         self._state_free: List[int] = list(range(self.state_num_blocks))
-        self.tree = init_paged_cache(
-            model, num_blocks, block_size, quantized=quantized,
-            window_blocks=self.window_num_blocks or None,
-            state_blocks=self.state_num_blocks or None,
-        )
+        pools = pool_avals(cfg, {
+            "blocks": num_blocks, "window": self.window_num_blocks or None,
+            "state": self.state_num_blocks or None,
+        }, block_size, quantized)
+        self.tree = _empty_tree(cfg, pools)
+        # per kind, one layer's pool (leaf -> `ShapeDtypeStruct`): for
+        # callers that ask about a pool without naming the tree's keys
+        self.avals = {name: avals for name, (_, avals) in pools.items()}
+        # per gauge of `/serve` (`Kind.gauge`): the family whose live blocks
+        # it counts, and the bytes one of them pins across its layers
+        self._gauges: Dict[str, tuple] = {}
+        for kind in self.records:
+            _, had = self._gauges.get(kind.gauge, (None, 0))
+            self._gauges[kind.gauge] = (kind.table, had + self._kind_bytes(kind))
         self.window_tables = np.full(
             (slots, self.blocks_per_seq), self.window_invalid_block, np.int32
         )
@@ -499,7 +424,7 @@ class PagedKVCache:
             self._refcount[b] = 1
             self._slot_blocks[slot].append(b)
             self.block_tables[slot, j] = b
-        if self.window_layers:
+        if self.window_num_blocks:
             self._ensure_window(slot, upto_pos, first_pos)
         return True
 
@@ -528,25 +453,23 @@ class PagedKVCache:
     def tables(self, rows=slice(None), parked=()):
         """The block tables the programs take for the given slots: the
         (n, nb) table, or where the model's layers keep more than one
-        kind of state the tuple of one table a kind (full layers' (n,
-        nb), window layers' (n, nb), linear layers' (n, 1) state table,
-        latent layers' (n, nb): the full kind's, conv layers' (n, 1): the
-        state table again), in `cfg.cache_kinds`' order. `parked` slots' rows are handed
+        kind of state the tuple of one table a kind, each its family's
+        (`serve/kinds.py`: the (n, nb) block table, the (n, nb) window
+        table, the (n, 1) state table), in `cfg.cache_kinds`' order.
+        `parked` slots' rows are handed
         over all-invalid. Always COPIES: the engine goes on growing and
         freeing rows while the program it handed a table to is still
         queued, and a program may read its host arguments late (the CPU
         client aliases them, a device client copies them behind the
         dispatch)."""
         have = {
-            "full": (self.block_tables, self.invalid_block),
+            "blocks": (self.block_tables, self.invalid_block),
             "window": (self.window_tables, self.window_invalid_block),
-            "linear": (self.state_table, self.state_invalid_block),
-            "latent": (self.block_tables, self.invalid_block),
-            "conv": (self.state_table, self.state_invalid_block),
+            "state": (self.state_table, self.state_invalid_block),
         }
-        out = [have[kind][0][rows].copy() for kind in self.kinds]
-        for t, kind in zip(out, self.kinds):
-            t[list(parked)] = have[kind][1]
+        out = [have[family][0][rows].copy() for family in self._families]
+        for t, family in zip(out, self._families):
+            t[list(parked)] = have[family][1]
         return tuple(out) if len(out) > 1 else out[0]
 
     # -- refcount plumbing -------------------------------------------------
@@ -744,44 +667,16 @@ class PagedKVCache:
     def pool_utilization(self) -> float:
         return self.live_blocks / self.num_blocks
 
-    @property
-    def pool_aval(self):
-        """Shape and dtype of one layer's K (and V) pool, as
-        `init_paged_cache` builds it — for callers that ask about the
-        pool without naming the tree's keys."""
-        import jax
+    def _kind_bytes(self, kind) -> int:
+        """HBM bytes one block pins across the layers of `kind` (one of
+        `self.records`), AS HELD: the pool's padding (`ops.pool_kv_shape`,
+        `ops.pool_latent_width`) and a quantized pool's scale planes
+        included."""
+        return self.layers[kind.name] * _block_bytes(self.avals[kind.name].values())
 
-        cfg = self.model.cfg
-        return jax.ShapeDtypeStruct(
-            (self.num_blocks, self.block_size, self.pool_kv_heads, self.pool_head_dim),
-            np.int8 if self.quantized else cfg.dtype,
-        )
-
-    @property
-    def state_aval(self):
-        """Shape and dtype of one linear layer's `state` pool (None with
-        no linear layer), for callers that ask about it without naming
-        the tree's keys."""
-        if not self.linear_layers:
-            return None
-        import jax
-
-        from ..models.transformer import state_block_shapes
-
-        shape, dtype = state_block_shapes(self.model.cfg, "linear")[1]["state"]
-        return jax.ShapeDtypeStruct((self.state_num_blocks,) + shape, dtype)
-
-    @property
-    def latent_aval(self):
-        """Shape and dtype of one latent layer's pool (None with no
-        latent layer), as `pool_aval` is a full layer's."""
-        if not self.latent_layers:
-            return None
-        import jax
-
-        return jax.ShapeDtypeStruct(
-            (self.num_blocks, self.block_size, self.latent_width),
-            self.model.cfg.dtype,
+    def _family_bytes(self, family: str) -> int:
+        return sum(
+            self._kind_bytes(kind) for kind in self.records if kind.table == family
         )
 
     @functools.cached_property
@@ -790,57 +685,53 @@ class PagedKVCache:
         token (a full layer's K + V, a latent layer's rows, PLUS the
         per-token scale planes when quantized — the true pool cost, so
         fixed-pool-bytes comparisons account the scale overhead)."""
-        cfg = self.model.cfg
-        itemsize = (
-            1 if self.quantized else np.dtype(cfg.dtype).itemsize
-        )
-        return (
-            2 * self.full_layers * self.block_size * self.pool_kv_heads
-            * self.pool_head_dim * itemsize
-        ) + self.latent_bytes_per_block + self.scale_bytes_per_block
-
-    @functools.cached_property
-    def latent_bytes_per_block(self) -> int:
-        """HBM bytes one block pins across the latent layers, AS HELD:
-        rows of `latent_width` values (`ops.pool_latent_width`: 576
-        cached values are held as 640)."""
-        return (
-            self.latent_layers * self.block_size * self.latent_width
-            * np.dtype(self.model.cfg.dtype).itemsize
-        )
-
-    @property
-    def latent_live_blocks(self) -> int:
-        """Blocks some slot holds latent rows in (0 with no latent layer)."""
-        return self.live_blocks if self.latent_layers else 0
+        return self._family_bytes("blocks")
 
     @functools.cached_property
     def window_bytes_per_block(self) -> int:
-        """HBM bytes one window-kind block pins across the window layers."""
-        cfg = self.model.cfg
-        return (
-            2 * self.window_layers * self.block_size * self.pool_kv_heads
-            * self.pool_head_dim * np.dtype(cfg.dtype).itemsize
-        )
+        """HBM bytes one window block pins across the layers that keep a
+        window."""
+        return self._family_bytes("window")
 
     @functools.cached_property
     def state_bytes_per_block(self) -> int:
         """HBM bytes one state block pins across the layers that keep
         one, each layer's by the leaves its mixer names."""
-        return sum(
-            int(np.prod(shape)) * np.dtype(dtype).itemsize
-            for leaves in self._state_leaves
-            for shape, dtype in leaves.values()
-        )
+        return self._family_bytes("state")
 
     @functools.cached_property
     def scale_bytes_per_block(self) -> int:
-        """Scale-plane bytes one block pins (0 unquantized): one f32 per
-        (token slot, kv-head) for K and V across every layer."""
-        if not self.quantized:
-            return 0
+        """Of `bytes_per_block`, the bytes in planes the pool adds to its
+        layers' own leaves: a quantized pool's scales, one f32 per (token
+        slot, kv-head) for K and V (0 unquantized)."""
+        from ..models.transformer import cache_leaves
+
         cfg = self.model.cfg
-        return 2 * cfg.n_layers * self.block_size * self.pool_kv_heads * 4
+        return sum(
+            self.layers[kind.name] * _block_bytes(
+                aval for leaf, aval in self.avals[kind.name].items()
+                if leaf not in cache_leaves(cfg, kind.name)[2]
+            )
+            for kind in self.records if kind.table == "blocks"
+        )
+
+    def pool_gauges(self) -> Dict[str, tuple]:
+        """The pool as `/serve` counts it (`ServeMetrics.record_pool`): gauge
+        (`serve/kinds.py::Kind.gauge`, for the kinds the model has) -> (live
+        blocks of the family its layers ride, the bytes one such block pins
+        across them, that family's blocks recycled while their request
+        ran). The gauges' bytes add up to `bytes_live`."""
+        live = {
+            "blocks": self.live_blocks, "window": self.window_live_blocks,
+            "state": self.state_live_blocks,
+        }
+        return {
+            gauge: (
+                live[family], nbytes,
+                self.window_blocks_recycled if family == "window" else 0,
+            )
+            for gauge, (family, nbytes) in self._gauges.items()
+        }
 
     @property
     def wire_dtype(self) -> str:
@@ -870,13 +761,17 @@ class PagedKVCache:
         """What ONE slot costs in the dense (slots, max_seq_len, ...)
         layout — the paged-vs-dense comparison baseline (the share of a
         layer that keeps a state is its state block in either layout)."""
-        cfg = self.model.cfg
-        itemsize = np.dtype(cfg.dtype).itemsize
-        kv_layers = cfg.n_layers - self.state_layers - self.latent_layers
-        return (
-            2 * kv_layers * cfg.max_seq_len * cfg.kv_heads * cfg.head_dim
-            + self.latent_layers * cfg.max_seq_len * self.latent_values
-        ) * itemsize + self.state_bytes_per_block
+        from ..models.transformer import cache_leaves, layers_of
+
+        cfg, total = self.model.cfg, 0
+        for kind in layers_of(cfg):
+            _, span, leaves = cache_leaves(cfg, kind)
+            entry = sum(
+                int(np.prod(shape)) * np.dtype(dtype).itemsize
+                for shape, dtype in leaves.values()
+            )
+            total += entry * (1 if span == "row" else cfg.max_seq_len)
+        return total
 
     def slot_blocks(self, slot: int) -> List[int]:
         return list(self._slot_blocks[slot])

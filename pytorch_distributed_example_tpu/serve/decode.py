@@ -87,67 +87,54 @@ def layer_paths(
     program of `paged_programs(..., mesh, tp_axis)` applies the model to
     `rows` rows of `L` tokens (`step`: every slot, one token;
     `prefill_chunk`: one row, the chunk), for each kind of state `cache`
-    (a `PagedKVCache`) holds. An attention layer takes "decode_kernel"
-    or "chunk_kernel" (`ops/paged_attention.py`) or "gather" (the gather
-    + einsum), as `ops.paged_kernel` says under the context the programs
-    apply the model under; a linear layer takes, for one token a row,
-    its "recurrence_kernel" (`ops/delta_recurrence.py`, where
-    `delta_kernel_ok` says so) or the plain "recurrence", and for a chunk
-    its "chunk_scan"; a latent layer takes "latent_decode_kernel" or
-    "latent_chunk_kernel" (`ops/paged_attention.py`) or "gather"; a conv
-    layer takes "conv_step" for one token a row and "conv_chunk" for a
-    chunk (`models/transformer.py::GatedConv`: no kernel, a few fused
-    elementwise operations around its two products)."""
-    from ..ops import paged_kernel
-    from ..ops.delta_recurrence import delta_kernel_ok
-
-    paths = {}
+    (a `PagedKVCache`) holds. The kind's record names the path
+    (`serve/kinds.py::Kind.path`): a Pallas kernel's ("decode_kernel",
+    "chunk_kernel", "latent_decode_kernel", "latent_chunk_kernel",
+    "recurrence_kernel") where `ops.paged_kernel` or `ops.delta_recurrence.
+    delta_kernel_ok` says the kernel takes the call under the context the
+    programs apply the model under, else the plain one's ("gather",
+    "recurrence", "chunk_scan", "conv_step", "conv_chunk")."""
+    cfg, tables = cache.model.cfg, cache.tables(slice(0, rows))
+    if not isinstance(tables, tuple):
+        tables = (tables,)
     with _kernel_partition(mesh, tp_axis):
-        for kind, layers, table, window in (
-            ("full", cache.full_layers, cache.block_tables, None),
-            ("window", cache.window_layers, cache.window_tables, cache.window),
-        ):
-            if layers:
-                kernel = paged_kernel(L, cache.pool_aval, table[:rows], window)
-                paths[kind] = (layers, f"{kernel}_kernel" if kernel else "gather")
-        if cache.latent_layers:
-            kernel = paged_kernel(
-                L, cache.latent_aval, cache.block_tables[:rows],
-                rank=cache.latent_rank,
+        return {
+            kind.name: (
+                cache.layers[kind.name],
+                kind.path(cfg, cache.avals[kind.name], table, L),
             )
-            paths["latent"] = (
-                cache.latent_layers, f"{kernel}_kernel" if kernel else "gather"
-            )
-        if cache.linear_layers:
-            step = "recurrence_kernel" if delta_kernel_ok(cache.state_aval) else "recurrence"
-            paths["linear"] = (cache.linear_layers, step if L == 1 else "chunk_scan")
-        if cache.conv_layers:
-            paths["conv"] = (cache.conv_layers, "conv_step" if L == 1 else "conv_chunk")
-    return paths
+            for kind, table in zip(cache.records, tables)
+        }
 
 
 def step_shares_blocks(cache, paths: dict, mesh=None, tp_axis: str = "tp") -> bool:
     """Whether the step whose `layer_paths` are `paths` reads a block that
     several of its rows' tables hold ONCE a group: every kind of its layers
-    that reads `cache.block_tables` (full, latent; a window layer has
-    tables of its own and shares nothing) takes its decode kernel, and that
-    kernel shares for the kind's pool (`ops.paged_attention.decode_shares`:
-    THE predicate, asked under the context the programs apply the model
-    under). The engine counts `StepRecord.decode_shared_keys` where this
-    says so."""
-    from ..ops.paged_attention import decode_shares
-
-    kinds = [
-        (paths[kind][1], kernel, aval)
-        for kind, kernel, aval in (
-            ("full", "decode_kernel", cache.pool_aval),
-            ("latent", "latent_decode_kernel", cache.latent_aval),
-        ) if kind in paths
-    ]
+    that reads `cache.block_tables` (`serve/kinds.py::Kind.shares` says
+    which; a window layer has tables of its own and shares nothing) takes
+    its decode kernel, and that kernel shares for the kind's pool
+    (`ops.paged_attention.decode_shares`: THE predicate, asked under the
+    context the programs apply the model under). The engine counts
+    `StepRecord.decode_shared_keys` where this says so."""
     with _kernel_partition(mesh, tp_axis):
-        return bool(kinds) and all(
-            path == kernel and decode_shares(aval) for path, kernel, aval in kinds
-        )
+        said = [
+            kind.shares(cache.avals[kind.name], paths[kind.name][1])
+            for kind in cache.records if kind.name in paths
+        ]
+    said = [share for share in said if share is not None]
+    return bool(said) and all(said)
+
+
+def masks_padding(cfg) -> bool:
+    """Whether the programs of a model of `cfg` are told which rows are real
+    (`paged_programs`; the engine then pads a chunk with token id -1): a
+    sparse layer's padding must route to no expert, and a kind of layer may
+    say its padding is masked (`serve/kinds.py::Kind.masks_padding`)."""
+    from .kinds import kinds_of
+
+    return bool(getattr(cfg, "sparse_layers", ())) or any(
+        kind.masks_padding for kind in kinds_of(cfg)
+    )
 
 
 def kernel_layers(paths: dict) -> int:
@@ -250,9 +237,9 @@ def paged_programs(
 
     A model with SPARSE layers (`cfg.sparse_layers`) is told which rows
     are real, because a row that is not must route to no expert, and so
-    is a model with LINEAR or CONV layers (`cfg.linear_layers`,
-    `cfg.conv_layers`), whose padding must leave a row's state block as
-    its last token left it: in
+    is a model with layers of a kind that masks padding (`serve/kinds.py::
+    Kind.masks_padding`: those that keep a state block, which padding must
+    leave as the row's last token left it): in
     `prefill_chunk` the engine pads a chunk with token id -1 (the rows
     `chunk >= 0` are real; the padding embeds as token 0), in `step` a
     row is live when its table row holds a valid block (the engine hands
@@ -267,11 +254,9 @@ def paged_programs(
     import jax.numpy as jnp
     from jax import lax
 
-    from .cache import state_layers_of
-
     M = model.cfg.max_seq_len
     sparse = tuple(getattr(model.cfg, "sparse_layers", ()))
-    masked = bool(sparse or state_layers_of(model.cfg))
+    masked = masks_padding(model.cfg)
 
     def apply_paged(params, tree, tokens, positions, bt, row_mask=None):
         kw, mutable = {}, ["cache"]

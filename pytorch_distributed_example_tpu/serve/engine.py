@@ -68,32 +68,25 @@ list. Outputs are token-exact with sharing on or off: a cached block
 holds exactly the K/V the attaching request would have recomputed
 (same tokens, same absolute positions, same params).
 
-Layer patterns (`TransformerConfig.layers`): a model whose pattern
-keeps a WINDOW of K/V in some layers is served from two pools under two
-tables (`serve/cache.py`): the window pool is sized here from `slots`,
-the window, `prefill_chunk_tokens` and `block_size`, and a row's window
-blocks behind `position - window` are recycled while it runs. A model
+Layer patterns (`TransformerConfig.layers`): what the serve plane does
+with each kind of layer a pattern may hold is ONE record in
+`serve/kinds.py` — which family of table its layers ride (`serve/cache.py`:
+the refcounted blocks that keep every token; a window pool of its own,
+sized here from `slots`, the window, `prefill_chunk_tokens` and
+`block_size`, whose blocks behind `position - window` are recycled while
+the request runs; one state block a request from admission to retirement),
+whether its chunk padding is told apart from tokens (a state block must
+stay as the last real token left it; a chunk that starts at position 0
+reads a zero state, so preemption frees the block and the requeued request
+prefills again from 0: no snapshot is kept), and which of prefix sharing,
+the int8 pool, a tp mesh, disaggregated roles and pre-warmed executables
+are NOT carried with it and why: those are refused at construction, in
+the record's words. A model
 with SPARSE (dropless MoE) layers has its parked lanes and chunk padding
 route nowhere, and every decode step brings back, in its one readback,
 the assignments computed and the distinct experts hit per sparse layer
 (`ServeMetrics.record_moe_step`, `StepRecord.moe` and a `serve:moe_step`
-host annotation, all written when the step is read back). What is not
-carried with window layers is refused at construction: prefix sharing,
-the int8 pool, a tp mesh, disaggregated roles and pre-warmed
-executables. A model with LINEAR
-(recurrent-state) layers holds one state block a request beside its K/V
-blocks from admission to retirement (`serve/cache.py`): its chunk
-padding is told apart from tokens and leaves the state as the last real
-token left it, a chunk that starts at position 0 reads a zero state
-(so preemption frees the block and the requeued request prefills again
-from 0: no snapshot is kept), and the same five things are refused.
-A model with LATENT layers (multi-head latent attention) keeps one row
-of `latent_width` values a token in one pool a layer, under the full
-kind's tables, free lists and refcounts; its cached calls run absorbed
-(`models/transformer.py::LatentAttention`). A shared prefix's latent
-blocks are attached, refcounted and copied on write as K/V blocks are
-(`cow_block` copies every leaf of the tree), so `prefix_cache=True` is
-carried; the other four are refused.
+host annotation, all written when the step is read back).
 
 What a call did (`StepRecord`, public as `engine.last_step` when the
 call returns): `step()` fills one small record as it goes, from values
@@ -173,16 +166,12 @@ from ..numerics import numerics_contract
 from ..ops.paged_attention import shared_decode_keys
 from ..types import DistError
 from .bucketing import bucket_for, bucket_lengths
-from .cache import (
-    PagedKVCache,
-    latent_layers_of,
-    state_layers_of,
-    window_layers_of,
-)
+from .cache import PagedKVCache
 from .decode import (
-    kernel_layers, layer_paths, paged_programs, step_shares_blocks,
-    sync_slot_lanes,
+    kernel_layers, layer_paths, masks_padding, paged_programs,
+    step_shares_blocks, sync_slot_lanes,
 )
+from .kinds import kinds_of
 from .metrics import ServeMetrics
 from .queue import (
     DEFAULT_CLASS,
@@ -342,61 +331,29 @@ class ServeEngine:
         self.model = model
         self.params = params["params"] if "params" in params else params
         self.cfg = model.cfg
-        # what is not carried with window layers, with layers that keep a
-        # state block (linear, conv) and with latent layers
-        refusals = {}
-        if any(window_layers_of(self.cfg)):
-            refusals["window"] = {
-                "prefix_cache=True (a shared prefix's window-layer blocks "
-                "are recycled under its other holders)": prefix_cache,
-                "kv_quant=True (an int8 pool of two kinds of blocks is untested)":
-                    kv_quant,
-                "mesh= (the window pool and the windowed decode kernel are "
-                "not partitioned over tp)": mesh is not None,
-                f"role={role!r} (block migration moves one kind of block)":
-                    role != "both",
-                "precompiled= (pre-warmed programs take one table)":
-                    bool(precompiled),
-            }
-        # a layer that keeps a state block, whichever its mixer (a linear
-        # layer's recurrent state, a conv layer's tail): the same refusals
-        for kind in sorted(set(state_layers_of(self.cfg).values())):
-            refusals[kind] = {
-                "prefix_cache=True (a shared prefix's recurrent state is "
-                "not snapshotted at the prefix's end)": prefix_cache,
-                "kv_quant=True (an int8 pool beside float32 state blocks is "
-                "untested)": kv_quant,
-                "mesh= (the state pool and the recurrence are not "
-                "partitioned over tp)": mesh is not None,
-                f"role={role!r} (block migration moves K/V blocks, not a "
-                "state block)": role != "both",
-                "precompiled= (pre-warmed programs take one table)":
-                    bool(precompiled),
-            }
-        if latent_layers_of(self.cfg):
-            refusals["latent"] = {
-                "kv_quant=True (a latent pool has no int8 form)": kv_quant,
-                "mesh= (a latent pool has no KV heads to partition over tp)":
-                    mesh is not None,
-                f"role={role!r} (block migration moves K/V blocks, not "
-                "latent ones)": role != "both",
-                "precompiled= (pre-warmed programs take a K/V pool)":
-                    bool(precompiled),
-            }
-        for kind, refused in refusals.items():
-            for what, asked in refused.items():
-                if asked:
+        # what is not carried with layers of some kind: the kind's record
+        # says which features and why (`serve/kinds.py::Kind.not_carried`)
+        asked = {
+            "prefix_cache": ("prefix_cache=True", prefix_cache),
+            "kv_quant": ("kv_quant=True", kv_quant),
+            "mesh": ("mesh=", mesh is not None),
+            "role": (f"role={role!r}", role != "both"),
+            "precompiled": ("precompiled=", bool(precompiled)),
+        }
+        for kind in kinds_of(self.cfg):
+            for feature, why in kind.not_carried.items():
+                what, on = asked[feature]
+                if on:
                     raise ValueError(
-                        f"a model with {kind} layers cannot be served with {what}"
+                        f"a model with {kind.name} layers cannot be served "
+                        f"with {what} ({why})"
                     )
         # sparse (dropless MoE) layers: padding routes nowhere, and the
         # decode step's readback carries two counters a layer
         self._sparse_layers = len(getattr(self.cfg, "sparse_layers", ()))
         # sparse layers and layers that keep a state block are told which
         # positions of a chunk are padding (`serve/decode.py::paged_programs`)
-        self._pad_id = (
-            -1 if self._sparse_layers or state_layers_of(self.cfg) else 0
-        )
+        self._pad_id = -1 if masks_padding(self.cfg) else 0
         self.temperature = temperature
         self.top_k = top_k
         self.eos_id = eos_id
@@ -948,7 +905,7 @@ class ServeEngine:
             except _TRANSIENT:
                 self._evict(slot, requeue_counter=True)
                 continue
-            # padding is token 0, or -1 where sparse or linear layers must
+            # padding is token 0, or -1 where a layer must
             # tell it from a token (`serve/decode.py::paged_programs`)
             chunk = np.full((1, C), self._pad_id, np.int32)
             chunk[0, : end - pf.pos] = req.prompt[pf.pos:end]
@@ -1247,13 +1204,7 @@ class ServeEngine:
             cow_copies=self.cache.cow_copies,
             bytes_deduplicated=self.cache.bytes_deduplicated,
             prefix_stats=self.prefix.stats() if self.prefix else None,
-            window_blocks_live=self.cache.window_live_blocks,
-            window_blocks_recycled=self.cache.window_blocks_recycled,
-            window_bytes_per_block=self.cache.window_bytes_per_block,
-            state_blocks_live=self.cache.state_live_blocks,
-            state_bytes_per_block=self.cache.state_bytes_per_block,
-            latent_blocks_live=self.cache.latent_live_blocks,
-            latent_bytes_per_block=self.cache.latent_bytes_per_block,
+            gauges=self.cache.pool_gauges(),
         )
 
     def _decode_tick(self, overlapped: bool) -> None:

@@ -205,26 +205,11 @@ class ServeMetrics:
         self.moe_experts_hit: List[int] = []  # last step, per sparse layer
         self.moe_assignments_total = 0
         self._moe_hit_sum = 0.0  # of per-step means over the layers
-        # the two kinds of K/V state (`serve/cache.py`): the full layers'
-        # live blocks are `pool_blocks_live` (`full_blocks_live` on
-        # `/serve`); the window layers' gauge, and the window blocks handed
-        # back while their request ran (cumulative)
-        self.window_blocks_live = 0
-        self.window_bytes_per_block = 0
-        self.window_blocks_recycled = 0
-        # the third kind, a linear layer's recurrent state: one block a
-        # live request (gauge), and the bytes a block pins
-        self.state_blocks_live = 0
-        self.state_bytes_per_block = 0
-        # the fourth kind, a latent layer's rows: the blocks that hold
-        # them (the full kind's table names them) and the bytes a block
-        # pins in the latent pools AS HELD (the device's tiling included);
-        # those bytes are part of `pool_bytes_per_block`
-        self.latent_blocks_live = 0
-        self.latent_bytes_per_block = 0
-        # of the prefix plane's counters, the blocks that hold latent rows
-        self.prefix_latent_blocks_attached = 0
-        self.prefix_latent_blocks_copied = 0
+        # the pool as the cache counts it (`PagedKVCache.pool_gauges`, last
+        # observation): gauge -> (live blocks, bytes a block pins, blocks
+        # recycled while their request ran), for the kinds of layer the
+        # model has; bytes are AS HELD (the device's tiling included)
+        self.pool_gauges: Dict[str, tuple] = {}
         # (row, expert) pairs the routers chose, last step and in all: a
         # chip that holds a share of the experts computes that share of
         # them (`moe_assignments`)
@@ -365,16 +350,6 @@ class ServeMetrics:
         with self._lock:
             self.pipeline_flushes[cause] = self.pipeline_flushes.get(cause, 0) + 1
 
-    @property
-    def state_bytes_live(self) -> int:
-        """Bytes the live requests' recurrent state blocks pin."""
-        return self.state_blocks_live * self.state_bytes_per_block
-
-    @property
-    def latent_bytes_live(self) -> int:
-        """Bytes the live latent blocks pin, as the device holds them."""
-        return self.latent_blocks_live * self.latent_bytes_per_block
-
     def record_layer_paths(self, decode: Dict, prefill: Dict) -> None:
         """Which path each kind of layer takes in the engine's step and
         in its chunk of the budget's length: facts of its lifetime."""
@@ -461,13 +436,8 @@ class ServeMetrics:
         cow_copies: int = 0,
         bytes_deduplicated: int = 0,
         prefix_stats: Optional[Dict] = None,
-        window_blocks_live: int = 0,
-        window_blocks_recycled: int = 0,
-        window_bytes_per_block: int = 0,
-        state_blocks_live: int = 0,
-        state_bytes_per_block: int = 0,
-        latent_blocks_live: int = 0,
-        latent_bytes_per_block: int = 0,
+        *,
+        gauges: Dict[str, tuple],
     ) -> None:
         """Per-step paged-pool observation. Gauges keep the LAST value;
         utilization and bytes-per-live-request also accumulate a
@@ -481,18 +451,12 @@ class ServeMetrics:
         gauges plus the cumulative `cow_copies` come from the cache,
         and `prefix_stats` is `PrefixIndex.stats()` verbatim — the
         index is the ONE place hit/miss/reuse counting lives, so the
-        two surfaces can never drift."""
+        two surfaces can never drift. `gauges` is
+        `PagedKVCache.pool_gauges()`: the same pool told a kind of layer
+        at a time, whose bytes add up to everything the live requests
+        pin."""
         with self._lock:
-            self.window_blocks_live = window_blocks_live
-            self.window_blocks_recycled = window_blocks_recycled
-            self.window_bytes_per_block = window_bytes_per_block
-            self.state_blocks_live = state_blocks_live
-            self.state_bytes_per_block = state_bytes_per_block
-            self.latent_blocks_live = latent_blocks_live
-            self.latent_bytes_per_block = latent_bytes_per_block
-            # every block of a model with a latent layer holds a row of it
-            if latent_bytes_per_block:
-                self.prefix_latent_blocks_copied = cow_copies
+            self.pool_gauges = gauges
             self.pool_blocks_live = blocks_live
             self.pool_blocks_total = blocks_total
             self.pool_bytes_per_block = bytes_per_block
@@ -517,10 +481,6 @@ class ServeMetrics:
                     "blocks_attached"
                 ]
                 self.prefix_index_nodes = prefix_stats["nodes"]
-                if latent_bytes_per_block:
-                    self.prefix_latent_blocks_attached = prefix_stats[
-                        "blocks_attached"
-                    ]
             if blocks_total:
                 self._pool_util_sum += blocks_live / blocks_total
                 self._pool_samples += 1
@@ -528,10 +488,8 @@ class ServeMetrics:
                     (self.clock(), blocks_live / blocks_total)
                 )
             if live_requests > 0:
-                self._bytes_per_req_sum += (
-                    blocks_live * bytes_per_block
-                    + window_blocks_live * window_bytes_per_block
-                    + state_blocks_live * state_bytes_per_block
+                self._bytes_per_req_sum += sum(
+                    live * nbytes for live, nbytes, _ in gauges.values()
                 ) / live_requests
                 self._bytes_per_req_samples += 1
 
@@ -799,6 +757,12 @@ class ServeMetrics:
                 self._bytes_per_req_sum / self._bytes_per_req_samples
                 if self._bytes_per_req_samples else 0.0
             )
+            # the pool a kind of layer at a time (`record_pool`'s gauges;
+            # zeros for a kind the model has no layer of)
+            gauges = self.pool_gauges
+            window_live, _, recycled = gauges.get("window", (0, 0, 0))
+            state_live, state_bytes, _ = gauges.get("state", (0, 0, 0))
+            latent_live, latent_bytes, _ = gauges.get("latent", (0, 0, 0))
             by_class = {}
             for k, st in sorted(self._by_class.items()):
                 spec = self._classes.get(k)
@@ -900,10 +864,8 @@ class ServeMetrics:
                         self.pool_blocks_live / self.pool_blocks_total, 4
                     ) if self.pool_blocks_total else 0.0,
                     "mean_utilization": round(mean_util, 4),
-                    "bytes_live": (
-                        self.pool_blocks_live * self.pool_bytes_per_block
-                        + self.window_blocks_live * self.window_bytes_per_block
-                        + self.state_bytes_live
+                    "bytes_live": sum(
+                        live * nbytes for live, nbytes, _ in gauges.values()
                     ),
                     "bytes_per_live_request_mean": round(mean_bpr, 1),
                     "dense_bytes_per_request": self.dense_bytes_per_request,
@@ -919,12 +881,12 @@ class ServeMetrics:
                     ),
                     "effective_slots": self.effective_slots,
                     "full_blocks_live": self.pool_blocks_live,
-                    "window_blocks_live": self.window_blocks_live,
-                    "window_blocks_recycled": self.window_blocks_recycled,
-                    "state_blocks_live": self.state_blocks_live,
-                    "state_bytes_live": self.state_bytes_live,
-                    "latent_blocks_live": self.latent_blocks_live,
-                    "latent_bytes_live": self.latent_bytes_live,
+                    "window_blocks_live": window_live,
+                    "window_blocks_recycled": recycled,
+                    "state_blocks_live": state_live,
+                    "state_bytes_live": state_live * state_bytes,
+                    "latent_blocks_live": latent_live,
+                    "latent_bytes_live": latent_live * latent_bytes,
                 },
                 # prefix sharing (ISSUE 12): hit rate + tokens whose
                 # prefill compute/pool writes were skipped, block-level
@@ -944,11 +906,13 @@ class ServeMetrics:
                     "cached_blocks": self.prefix_cached_blocks,
                     "index_nodes": self.prefix_index_nodes,
                     "cow_copies": self.cow_copies,
+                    # every block of a model with a latent layer holds a
+                    # row of it: of the two counters above, its share
                     "prefix_latent_blocks_attached": (
-                        self.prefix_latent_blocks_attached
+                        self.prefix_blocks_attached if latent_bytes else 0
                     ),
                     "prefix_latent_blocks_copied": (
-                        self.prefix_latent_blocks_copied
+                        self.cow_copies if latent_bytes else 0
                     ),
                     "bytes_deduplicated": self.bytes_deduplicated,
                     "peak_bytes_deduplicated": (

@@ -574,7 +574,7 @@ def test_a_state_block_s_leaves_are_its_mixer_s_own(small):
     cache = PagedKVCache(model, 3, block_size=BS)
     assert (cache.full_layers, cache.conv_layers, cache.linear_layers) == (2, 6, 0)
     assert cache.state_layers == 6 and cache.kinds == ("full", "conv")
-    assert cache.state_bytes_per_block == 6 * 2 * 64 * 4 and cache.state_aval is None
+    assert cache.state_bytes_per_block == 6 * 2 * 64 * 4 and "linear" not in cache.avals
     slot = cache.allocate()
     full, state = cache.tables()
     assert state.shape == (3, 1) and state[slot, 0] == cache.state_block(slot) == 0
@@ -724,7 +724,7 @@ def test_an_engine_at_head_size_64_runs_both_kernels_and_gives_generate_s_tokens
         variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
         engine = ServeEngine(model, variables, slots=3, block_size=16,
                              prefill_chunk_tokens=32, min_bucket=16)
-        assert engine.cache.pool_aval.shape == (3 * 16, 16, 1, 128)
+        assert engine.cache.avals["full"]["k"].shape == (3 * 16, 16, 1, 128)
         assert engine.cache.bytes_per_block == 2 * 2 * 16 * 2 * 64 * jnp.dtype(dtype).itemsize
         snap = engine.metrics.snapshot()
         assert snap["decode"]["layer_paths"] == {"full": [2, "decode_kernel"],

@@ -674,7 +674,7 @@ def test_a_pool_of_whole_tiles_serves_a_model_of_ten_kv_heads(heads):
     variables = model.init(jax.random.PRNGKey(2), jnp.zeros((1, 8), jnp.int32))
     engine = ServeEngine(model, variables, slots=2, block_size=BS, prefill_chunk_tokens=8,
                          min_bucket=4)
-    assert engine.cache.tree["layers_0"]["attn"]["k"].shape[2] == engine.cache.pool_kv_heads == 16
+    assert engine.cache.tree["layers_0"]["attn"]["k"].shape[2] == engine.cache.avals["full"]["k"].shape[2] == 16
     prompts = {"a": tokens_of(13, 71) % 64, "b": tokens_of(6, 72) % 64}
     for rid, prompt in prompts.items():
         engine.submit(prompt, 7, rid=rid)

@@ -83,6 +83,12 @@ def wide():
     return build(WIDE)
 
 
+def latent_gauge(cache):
+    """(live blocks, bytes one of them pins) that `cache` counts for its latent
+    layers (`PagedKVCache.pool_gauges`); (0, 0) for a model with none."""
+    return cache.pool_gauges().get("latent", (0, 0, 0))[:2]
+
+
 def tokens_of(n, seed=0):
     return np.random.default_rng(seed).integers(0, SMALL["vocab_size"], (n,), dtype=np.int32)
 
@@ -351,8 +357,8 @@ def served(small):
     live, steps, shares = [], 0, []
     while engine.step():
         steps += 1
-        live.append((engine.metrics.latent_blocks_live, engine.metrics.latent_bytes_live,
-                     engine.cache.latent_live_blocks))
+        blocks, nbytes, _ = engine.metrics.pool_gauges["latent"]
+        live.append((blocks, blocks * nbytes, latent_gauge(engine.cache)[0]))
         shares.append((engine.metrics.moe_assignments, engine.metrics.moe_routed))
         assert steps < 400
     return {"engine": engine, "done": engine.completions, "prompt": prompt,
@@ -392,13 +398,13 @@ def test_latent_blocks_are_counted_as_held_and_go_back_at_retirement(served):
     cache = engine.cache
     assert cache.kinds == ("latent",) and cache.latent_layers == 3 and cache.full_layers == 0
     # a row of 40 values is held as it is; 3 layers x 8 tokens x 40 x 4 bytes
-    assert cache.latent_width == 40 and cache.latent_bytes_per_block == 3 * BS * 40 * 4
-    assert cache.bytes_per_block == cache.latent_bytes_per_block
+    assert cache.avals["latent"]["latent"].shape[-1] == 40 and latent_gauge(cache)[1] == 3 * BS * 40 * 4
+    assert cache.bytes_per_block == latent_gauge(cache)[1]
     assert max(held for _, _, held in served["live"]) >= 6  # 45 tokens alone hold 6 blocks
     # the gauge is the pool as the step found it
-    assert all(nbytes == b * cache.latent_bytes_per_block for b, nbytes, _ in served["live"])
+    assert all(nbytes == b * latent_gauge(cache)[1] for b, nbytes, _ in served["live"])
     assert max(b for b, _, _ in served["live"]) >= 6
-    assert cache.live_blocks == 0 and cache.latent_live_blocks == 0 and cache.bytes_live == 0
+    assert cache.live_blocks == 0 and latent_gauge(cache)[0] == 0 and cache.bytes_live == 0
     snap = engine.metrics.snapshot()["cache_pool"]  # the last step's gauge
     assert (snap["latent_blocks_live"], snap["latent_bytes_live"]) == served["live"][-1][:2]
     paths = engine.metrics.snapshot()
@@ -728,9 +734,9 @@ def test_latent_blocks_are_allocated_on_write_and_freed_on_retire_beside_other_k
     assert set(tree["layers_2"]["linear_attn"]) == {"state", "conv"}
     assert (cache.full_layers, cache.latent_layers, cache.linear_layers) == (1, 1, 1)
     slot = cache.allocate()
-    assert cache.live_blocks == 0 and cache.latent_live_blocks == 0  # nothing written yet
+    assert cache.live_blocks == 0 and latent_gauge(cache)[0] == 0  # nothing written yet
     assert cache.ensure_blocks(slot, 9)
-    assert cache.live_blocks == cache.latent_live_blocks == 3
+    assert cache.live_blocks == latent_gauge(cache)[0] == 3
     full, state, latent = cache.tables()
     # the latent kind rides the full kind's table: a block id names the same
     # tokens in every layer that keeps every token
@@ -738,11 +744,11 @@ def test_latent_blocks_are_allocated_on_write_and_freed_on_retire_beside_other_k
     assert state.shape == (2, 1) and full.shape == (2, 16)
     assert not np.shares_memory(full, latent)
     itemsize = 4
-    assert cache.latent_bytes_per_block == 4 * 20 * itemsize
-    assert cache.bytes_per_block == 2 * 4 * 2 * 16 * itemsize + cache.latent_bytes_per_block
+    assert latent_gauge(cache)[1] == 4 * 20 * itemsize
+    assert cache.bytes_per_block == 2 * 4 * 2 * 16 * itemsize + latent_gauge(cache)[1]
     assert cache.bytes_live == 3 * cache.bytes_per_block + cache.state_bytes_per_block
     assert cache.free(slot) == 3
-    assert cache.live_blocks == cache.latent_live_blocks == 0
+    assert cache.live_blocks == latent_gauge(cache)[0] == 0
     assert (cache.tables()[2] == cache.invalid_block).all()
     with pytest.raises(ValueError, match="no int8 form"):
         init_paged_cache(model, 8, 4, quantized=True, state_blocks=2)
@@ -768,8 +774,8 @@ def test_a_model_without_latent_layers_gets_the_tables_it_had():
     cfg = TransformerConfig(vocab_size=64, d_model=32, n_layers=2, n_heads=2, n_kv_heads=1,
                             d_ff=64, max_seq_len=32, use_flash=False)
     cache = PagedKVCache(TransformerLM(cfg), 2, block_size=4)
-    assert cache.kinds == ("full",) and cache.latent_layers == 0 and cache.latent_aval is None
-    assert cache.latent_bytes_per_block == 0 and cache.latent_live_blocks == 0
+    assert cache.kinds == ("full",) and cache.latent_layers == 0 and "latent" not in cache.avals
+    assert latent_gauge(cache)[1] == 0 and latent_gauge(cache)[0] == 0
     assert isinstance(cache.tables(), np.ndarray)
     assert cache.bytes_per_block == 2 * 2 * 4 * 1 * 16 * 4
     assert cache.dense_bytes_per_request == 2 * 2 * 32 * 1 * 16 * 4
