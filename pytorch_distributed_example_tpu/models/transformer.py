@@ -65,6 +65,10 @@ CACHE_KINDS = ("full", "window", "linear", "latent", "conv")
 # (`gated_delta_chunked`): a power of two that divides every prefill
 # bucket of the serve cells (128, 256, 512)
 LINEAR_CHUNK = 64
+# tokens of one block of a sub-chunk under a decay a key channel
+# (`_channel_decayed_pairs`): inside a block the decayed dot products are
+# summed channel by channel, between blocks they are matrix products
+LINEAR_BLOCK = 16
 # normalisation pairs of a hyper-connection map written out a trip of its
 # loop (`HyperConnection._sinkhorn`)
 SINKHORN_UNROLL = 5
@@ -114,6 +118,9 @@ class TransformerConfig:
     layers: Optional[Tuple[LayerSpec, ...]] = None  # one entry a layer
     window: Optional[int] = None  # keys a "window" layer attends
     attn_gate: bool = False  # per-head sigmoid gate on the attention output
+    # an ELEMENTWISE sigmoid gate on the attention output, from a projection
+    # of its own of the output's full width (the two gates refuse each other)
+    attn_out_gate: bool = False
     rope_pairs: str = "interleaved"  # or "halves" (the Hugging Face port)
     # the "sparse" MLP: dropless top-k over `sparse_experts` SwiGLU experts
     # of width `sparse_d_ff`, weights normalised then times `routed_scale`,
@@ -138,6 +145,14 @@ class TransformerConfig:
     linear_value_dim: int = 0
     linear_conv: int = 4
     linear_neg_eigval: bool = False
+    # what a linear layer's state is decayed by: "head" is one alpha a head
+    # and token (Gated DeltaNet: full projections for the decay and for a
+    # silu output gate), "channel" one alpha a KEY CHANNEL of a head, a
+    # vector of `linear_key_dim` (Kimi Delta Attention: a sigmoid output
+    # gate; the decay and that gate through low-rank pairs of
+    # `linear_gate_rank`, which "channel" needs and "head" refuses)
+    linear_decay: str = "head"
+    linear_gate_rank: int = 0
     # each sublayer's OUTPUT is normed before the residual add,
     # h = x + norm(mixer(x)), and nothing norms its input
     post_norm: bool = False
@@ -195,6 +210,14 @@ class TransformerConfig:
             raise ValueError(f"rope_pairs {self.rope_pairs!r}")
         if self.sparse_score not in ("softmax", "sigmoid"):
             raise ValueError(f"sparse_score {self.sparse_score!r}")
+        if self.linear_decay not in ("head", "channel"):
+            raise ValueError(f"linear_decay {self.linear_decay!r}")
+        if bool(self.linear_gate_rank) != (self.linear_decay == "channel"):
+            raise ValueError(
+                "linear_gate_rank belongs to linear_decay 'channel', which needs one"
+            )
+        if self.attn_gate and self.attn_out_gate:
+            raise ValueError("attn_gate and attn_out_gate are two gates of one output")
         if self.post_norm and self.sandwich_norm:
             raise ValueError("post_norm and sandwich_norm are two placements")
         if self.qk_norm and self.qk_head_norm:
@@ -494,8 +517,8 @@ class Attention(nn.Module):
     @nn.nowrap
     def _project_out(self, o, x, dense):
         """(B, L, H * Dh) attention output -> the block's residual term:
-        each head times its sigmoid gate where the model has one, then
-        `o_proj`."""
+        each head (`attn_gate`) or each value (`attn_out_gate`) times its
+        sigmoid gate where the model has one, then `o_proj`."""
         if self.spec is not None and self.cfg.attn_gate:
             B, L, _ = o.shape
             H = self.heads
@@ -504,6 +527,10 @@ class Attention(nn.Module):
                 o = (o.reshape(B, L, H, -1) * g[..., None].astype(o.dtype)).reshape(
                     B, L, -1
                 )
+        elif self.spec is not None and self.cfg.attn_out_gate:
+            with jax.named_scope("attn_gate"):
+                g = jax.nn.sigmoid(dense(o.shape[-1], "out_gate")(x))  # (B, L, H * Dh)
+                o = o * g.astype(o.dtype)
         return dense(self.cfg.d_model, "o_proj")(o)
 
     @nn.compact
@@ -1192,25 +1219,81 @@ def _unit_lower_inverse(m):
     return T
 
 
+def _channel_decayed_pairs(k, G, block: int):
+    """`pairs(a)` = sum_c a_ic k_jc e^(G_ic - G_jc), (..., C, C), meant for
+    j <= i only (the caller masks): what a decay a key channel leaves of a
+    sub-chunk's dot products of `a` (its queries, or its keys) with its
+    keys. a, k, G: (..., C, dk), G the running sum of log(alpha) <= 0 inside
+    the sub-chunk; what depends on k and G alone is made once.
+
+    The decay no longer leaves the dot product, and its factorised form
+    (a e^G)(k e^-G)^T overflows float32 inside a sub-chunk once G passes
+    -88, which the gate's initial range reaches in a few tokens. So the
+    sub-chunk is cut into blocks of `block` tokens (the source's secondary
+    chunking), and nothing is ever raised to a positive power:
+    * i and j in one block: the sum over the channels written out, with
+      e^(G_i - G_j) formed from the difference (<= 0 for j <= i);
+    * j in an earlier block than i's: both sides measured from R, the sum
+      up to the token before i's block: (a_i e^(G_i - R)) . (k_j e^(R -
+      G_j)), two exponents <= 0 and a float32 product at `HIGH`. A factor
+      underflows only where the whole term does."""
+    *lead, C, dk = k.shape
+    c0 = min(block, C)
+    if C % c0:
+        raise ValueError(f"a sub-chunk of {C} tokens in blocks of {c0}")
+    n = C // c0
+    blocks = lambda x: x.reshape(*lead, n, c0, dk)
+    Gb = blocks(G)
+    # (..., n, 1, dk): the running sum where each block starts
+    R = jnp.concatenate(
+        [jnp.zeros_like(Gb[..., :1, :1, :]), Gb[..., :-1, -1:, :]], axis=-3
+    )
+    since = jnp.exp(Gb - R)  # from its block's start to token i
+    # token j's key as block I's start sees it, (..., n, C, dk)
+    until = k[..., None, :, :] * jnp.exp(jnp.minimum(R - G[..., None, :, :], 0.0))
+    # (..., n, c0, c0, dk): k_j e^(G_i - G_j), i and j in one block
+    within = blocks(k)[..., None, :, :] * jnp.exp(
+        jnp.minimum(Gb[..., :, None, :] - Gb[..., None, :, :], 0.0)
+    )
+    same = (jnp.arange(C)[:, None] // c0 == jnp.arange(C)[None, :] // c0).reshape(
+        n, c0, n, c0
+    )
+
+    def pairs(a):
+        ab = blocks(a)
+        far = jnp.einsum(
+            "...id,...jd->...ij", ab * since, until, precision=jax.lax.Precision.HIGH
+        ).reshape(*lead, n, c0, n, c0)
+        near = jnp.sum(ab[..., :, None, :] * within, axis=-1)  # (..., n, c0, c0)
+        return jnp.where(same, near[..., :, :, None, :], far).reshape(*lead, C, C)
+
+    return pairs
+
+
 def gated_delta_chunked(q, k, v, g, beta, state, chunk: int):
     """The gated delta rule over L tokens, from `state`, in its chunked
     (WY) form. q, k: (B, L, H, dk), L2-normalised, q scaled; v: (B, L, H,
-    dv); g = log(alpha) <= 0 and beta: (B, L, H); state: (B, H, dk, dv);
-    all float32. Returns (o (B, L, H, dv), the state after token L - 1).
+    dv); beta: (B, L, H); g = log(alpha) <= 0: (B, L, H), one decay a head,
+    or (B, L, H, dk), one a key channel (a vector a head: state ROW c is
+    decayed by alpha_c); state: (B, H, dk, dv); all float32. Returns (o (B,
+    L, H, dv), the state after token L - 1).
 
-    Per token S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T
-    k_t)^T, o_t = S_t^T q_t. Inside a sub-chunk of `chunk` tokens, with G
-    the running sum of g, the rule's corrections solve a unit
+    Per token S_t = Diag(alpha_t) S_{t-1} + beta_t k_t (v_t - (Diag(alpha_t)
+    S_{t-1})^T k_t)^T, o_t = S_t^T q_t. Inside a sub-chunk of `chunk`
+    tokens, with G the running sum of g, the rule's corrections solve a unit
     lower-triangular system (I + A) u = beta v - (beta k e^G) S_in, A_ij
-    = beta_i (k_i . k_j) e^(G_i - G_j) for j < i; the state moves
-    sub-chunk by sub-chunk in a `lax.scan`. Everything is float32: the
-    products at `Precision.HIGH`, the triangular inverse at `HIGHEST`,
-    the rest elementwise. A position with g = 0 and beta = 0 (padding)
-    leaves the state as it found it; L is padded up to whole sub-chunks
-    with such positions."""
+    = beta_i sum_c k_ic k_jc e^(G_ic - G_jc) for j < i (with one decay a
+    head: beta_i (k_i . k_j) e^(G_i - G_j); with a vector the decay stays
+    inside the sum: `_channel_decayed_pairs`, in blocks of `LINEAR_BLOCK`
+    tokens); the state moves sub-chunk by sub-chunk in a `lax.scan`.
+    Everything is float32: the products at `Precision.HIGH`, the triangular
+    inverse at `HIGHEST`, the rest elementwise. A position with g = 0 and
+    beta = 0 (padding) leaves the state as it found it; L is padded up to
+    whole sub-chunks with such positions."""
     B, L, H, dk = q.shape
     dv = v.shape[-1]
     C = chunk
+    channel = g.ndim == 4
     pad = -L % C
     if pad:
         widen = lambda a: jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
@@ -1224,31 +1307,45 @@ def gated_delta_chunked(q, k, v, g, beta, state, chunk: int):
     # prefill against the float32 reference, which could then not be told
     # from a state kept in bfloat16 (PERF.md section 6, PR 31)
     mm = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGH)
-    G = jnp.cumsum(g, axis=-1)  # (B, H, N, C)
-    rows, cols = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
-    decay = jnp.exp(
-        jnp.where(cols <= rows, G[..., :, None] - G[..., None, :], -jnp.inf)
-    )  # e^(G_i - G_j) for j <= i, else 0
-    kb, into = k * beta[..., None], jnp.exp(G)[..., None]  # e^G: from S_in to token i
-    A = jnp.where(cols < rows, mm("bhnid,bhnjd->bhnij", kb, k) * decay, 0.0)
+    # the two forms in the order the scalar one was written in, so that a
+    # model of one decay a head lowers to the text it lowered to before
+    if channel:
+        G = jnp.cumsum(g, axis=3)  # (B, H, N, C, dk)
+        rows, cols = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+        kb, into = k * beta[..., None], jnp.exp(G)  # e^G: from S_in to token i
+        pairs = _channel_decayed_pairs(k, G, LINEAR_BLOCK)
+    else:
+        G = jnp.cumsum(g, axis=-1)  # (B, H, N, C)
+        rows, cols = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+        decay = jnp.exp(
+            jnp.where(cols <= rows, G[..., :, None] - G[..., None, :], -jnp.inf)
+        )  # e^(G_i - G_j) for j <= i, else 0
+        kb, into = k * beta[..., None], jnp.exp(G)[..., None]
+        pairs = lambda a: mm("bhnid,bhnjd->bhnij", a, k) * decay
+    A = jnp.where(cols < rows, pairs(kb), 0.0)
     T = _unit_lower_inverse(A + jnp.eye(C, dtype=A.dtype))
     u = mm("bhnij,bhnjd->bhnid", T, v * beta[..., None])  # corrected values
     w = mm("bhnij,bhnjd->bhnid", T, kb * into)  # what S_in costs them
-    qk = jnp.where(cols <= rows, mm("bhnid,bhnjd->bhnij", q, k) * decay, 0.0)
+    qk = jnp.where(cols <= rows, pairs(q), 0.0)
     q_in = q * into
-    last = G[..., -1:]  # (B, H, N, 1)
-    k_out = k * jnp.exp(last - G)[..., None]
+    if channel:
+        last = G[..., -1:, :]  # (B, H, N, 1, dk)
+        k_out, left = k * jnp.exp(last - G), jnp.exp(last)[..., 0, :]
+    else:
+        last = G[..., -1:]  # (B, H, N, 1)
+        k_out, left = k * jnp.exp(last - G)[..., None], jnp.exp(last)
 
     def sub_chunk(S, xs):
-        u_i, w_i, qk_i, q_i, k_i, decay_i = xs
+        # left_i: what the sub-chunk leaves of S_in, (B, H, 1) or a state row's (B, H, dk)
+        u_i, w_i, qk_i, q_i, k_i, left_i = xs
         new = u_i - mm("bhid,bhde->bhie", w_i, S)  # (B, H, C, dv)
         o = mm("bhid,bhde->bhie", q_i, S) + mm("bhij,bhje->bhie", qk_i, new)
-        S = S * decay_i[..., None] + mm("bhid,bhie->bhde", k_i, new)
+        S = S * left_i[..., None] + mm("bhid,bhie->bhde", k_i, new)
         return S, o
 
     per_chunk = lambda a: jnp.moveaxis(a, 2, 0)  # N leads: what the scan walks
     S, o = jax.lax.scan(sub_chunk, state, tuple(
-        per_chunk(a) for a in (u, w, qk, q_in, k_out, jnp.exp(last))
+        per_chunk(a) for a in (u, w, qk, q_in, k_out, left)
     ))
     # (N, B, H, C, dv) -> (B, L, H, dv)
     o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(B, N * C, H, dv)
@@ -1256,8 +1353,9 @@ def gated_delta_chunked(q, k, v, g, beta, state, chunk: int):
 
 
 class LinearAttention(nn.Module):
-    """The "linear" mixer of a layer pattern: a Gated DeltaNet layer
-    (Yang, Kautz, Hatamizadeh, arXiv:2412.06464). Per token, with H =
+    """The "linear" mixer of a layer pattern: a gated delta rule in one of
+    two forms. `cfg.linear_decay == "head"` is a Gated DeltaNet layer (Yang,
+    Kautz, Hatamizadeh, arXiv:2412.06464). Per token, with H =
     `cfg.linear_heads` heads of key width dk and value width dv:
 
         q, k, v = silu(conv(W_q x)), silu(conv(W_k x)), silu(conv(W_v x))
@@ -1269,10 +1367,23 @@ class LinearAttention(nn.Module):
         o = S^T q
         y = W_o [RMSNorm_dv(o) * w_norm * silu(W_g x)]
 
+    `"channel"` is Kimi Delta Attention (arXiv:2510.26692): the decay is a
+    VECTOR a head and token, one alpha a key channel, which decays the
+    state's rows each by its own; `dt_bias` is one a channel (`A_log` still
+    one a head); the output gate is a sigmoid; and the decay's and the
+    gate's projections are low-rank pairs of `cfg.linear_gate_rank`, traced
+    under the scope `kda_gate`:
+
+        g = -exp(A_log) * softplus(W_f2 (W_f1 x) + dt_bias)    (H x dk)
+        S <- Diag(alpha) S;  S <- S + beta k (v - S^T k)^T;  o = S^T q
+        y = W_o [RMSNorm_dv(o) * w_norm * sigmoid(W_g2 (W_g1 x))]
+
     Everything the rule is computed from is float32: the projections of q,
     k, v and the gates keep their products' float32 (the rule multiplies a
-    rounding of them by ten on its way to the logits), the conv, the
-    norms and the state too; the mixer's input and output are `cfg.dtype`.
+    rounding of them by ten on its way to the logits; the second product
+    of a low-rank pair takes its float32 input at `Precision.HIGH`), the
+    conv, the norms and the state too; the mixer's input and output are
+    `cfg.dtype`.
 
     What it keeps between calls is one recurrent state a row, `state`
     (H, dk, dv) float32, and the `linear_conv - 1` pre-conv inputs behind
@@ -1291,15 +1402,16 @@ class LinearAttention(nn.Module):
       drops the write, so a parked lane changes nothing. One token a row
       (the decode step) takes the recurrence itself: `ops.delta_recurrence.
       paged_delta_step`, a kernel that reads, updates and writes each live
-      row's block in place, where `delta_kernel_ok` says it takes the pool,
+      row's block in place (either form of the decay), where
+      `delta_kernel_ok` says it takes the pool,
       else the same update in `jax.numpy` (`_delta_step` between a gather
       and a scatter). More than one token (a prefill chunk) takes the
       chunked form from the row's state.
 
     `row_mask` ((B, L) bool) marks real tokens; the others must be a
     row's trailing positions (the padding of a prefill chunk) and leave
-    the state (alpha = 1, beta = 0) and the conv tail (taken behind the
-    last real token) as that token left them."""
+    the state (alpha = 1 in every channel, beta = 0) and the conv tail
+    (taken behind the last real token) as that token left them."""
 
     cfg: TransformerConfig
 
@@ -1332,15 +1444,37 @@ class LinearAttention(nn.Module):
         beta = jax.nn.sigmoid(wide(H, "b_proj")(x).astype(f32))
         if cfg.linear_neg_eigval:
             beta = 2.0 * beta
+        channel = cfg.linear_decay == "channel"
+
+        def low_rank(feats, name):
+            """x through the pair `name`_a, `name`_b, the float32 in between
+            kept."""
+            inner = wide(cfg.linear_gate_rank, f"{name}_a")(x).astype(f32)
+            return nn.Dense(
+                feats, use_bias=False, dtype=f32, name=f"{name}_b",
+                precision=jax.lax.Precision.HIGH,
+            )(inner)
+
         a_log = self.param("A_log", _a_log_init, (H,))
-        dt_bias = self.param("dt_bias", _dt_bias_init, (H,))
-        g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
-            wide(H, "a_proj")(x).astype(f32) + dt_bias.astype(f32)
-        )  # log(alpha), (B, L, H)
+        if channel:
+            dt_bias = self.param("dt_bias", _dt_bias_init, (H, dk))
+            with jax.named_scope("kda_gate"):
+                g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
+                    low_rank(H * dk, "f_proj").reshape(B, L, H, dk)
+                    + dt_bias.astype(f32)
+                )  # log(alpha), (B, L, H, dk)
+                out_gate = jax.nn.sigmoid(
+                    low_rank(H * dv, "g_proj").reshape(B, L, H, dv)
+                )
+        else:
+            dt_bias = self.param("dt_bias", _dt_bias_init, (H,))
+            g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+                wide(H, "a_proj")(x).astype(f32) + dt_bias.astype(f32)
+            )  # log(alpha), (B, L, H)
         real = L
         if row_mask is not None:
             beta = jnp.where(row_mask[..., None], beta, 0.0)
-            g = jnp.where(row_mask[..., None], g, 0.0)
+            g = jnp.where(row_mask[(...,) + (None,) * (g.ndim - 2)], g, 0.0)
             real = jnp.sum(row_mask, axis=1).astype(jnp.int32)  # (B,)
 
         # -- the state this call starts from, and where it leaves it ------
@@ -1426,25 +1560,29 @@ class LinearAttention(nn.Module):
             w_norm = self.param("norm", nn.initializers.ones, (dv,))
             var = jnp.mean(o * o, axis=-1, keepdims=True)
             o = o * jax.lax.rsqrt(var + cfg.norm_eps) * w_norm.astype(f32)
-            gate = wide(H * dv, "g_proj")(x).astype(f32).reshape(B, L, H, dv)
-            o = (o * jax.nn.silu(gate)).astype(cfg.dtype).reshape(B, L, H * dv)
+            if not channel:
+                out_gate = jax.nn.silu(
+                    wide(H * dv, "g_proj")(x).astype(f32).reshape(B, L, H, dv)
+                )
+            o = (o * out_gate).astype(cfg.dtype).reshape(B, L, H * dv)
         return dense(cfg.d_model, "o_proj")(o)
 
 
 def _delta_step(q, k, v, alpha, beta, S):
     """One token of the gated delta rule for every row: q, k (B, H, dk),
-    v (B, H, dv), alpha, beta (B, H), S (B, H, dk, dv), float32. Returns
-    (o (B, H, dv), the new S). Both S^T k and S^T q come from the one
-    read of S (o = alpha S^T q + (k . q) d, d the rule's correction), the
-    update is the second and only other pass: elementwise and reductions,
-    nothing rounds the state."""
-    Sk = jnp.sum(S * k[..., None], axis=-2)
-    Sq = jnp.sum(S * q[..., None], axis=-2)
-    a = alpha[..., None]
-    d = beta[..., None] * (v - a * Sk)  # (B, H, dv)
+    v (B, H, dv), beta (B, H), alpha (B, H), one decay a head, or (B, H,
+    dk), one a state ROW; S (B, H, dk, dv), float32. Returns (o (B, H,
+    dv), the new S). Both (alpha S)^T k and (alpha S)^T q come from the one
+    read of S, as S^T (alpha k) and S^T (alpha q) (o = (alpha S)^T q + (k .
+    q) d, d the rule's correction), the update is the second and only other
+    pass: elementwise and reductions, nothing rounds the state."""
+    a = alpha if alpha.ndim == k.ndim else alpha[..., None]  # a state row's decay
+    Sk = jnp.sum(S * (a * k)[..., None], axis=-2)
+    Sq = jnp.sum(S * (a * q)[..., None], axis=-2)
+    d = beta[..., None] * (v - Sk)  # (B, H, dv)
     kq = jnp.sum(k * q, axis=-1, keepdims=True)
     new = a[..., None] * S + k[..., None] * d[..., None, :]
-    return a * Sq + kq * d, new
+    return Sq + kq * d, new
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):

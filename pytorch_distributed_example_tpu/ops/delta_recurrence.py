@@ -23,6 +23,13 @@ Shape of the kernel (design per /opt/skills/guides/pallas_guide.md):
   ride in transposed, (dk, 128) a step with a head a lane, and alpha and
   beta repeated along the lanes beside v, all arranged outside (a few KB
   a row).
+* The decay is one alpha a head (alpha: (B, H)), as above, or a VECTOR a
+  head, one alpha a key channel (alpha: (B, H, dk)): it then varies down
+  the sublanes, one value a state row, and rides in as a third group of
+  lanes beside k and q; the row c of the state is decayed by alpha_c, and
+  S^T (alpha k), S^T (alpha q) take the place of alpha S^T k, alpha S^T q.
+  Which of the two a call is, is its `alpha`'s rank: two programs, and a
+  model of one form traces the other nowhere.
 * A row whose first token stands at position 0 (`fresh`) reads zeros
   whatever its block held.
 * The state's rows of dv = 192 values are padded to 256 lanes by the
@@ -54,19 +61,20 @@ def delta_kernel_ok(pool) -> bool:
     """Whether `paged_delta_step` takes a decode call over `pool`, the
     state pool (blocks, H, dk, dv) of a linear layer (an array or a
     `ShapeDtypeStruct`: shape and dtype alone are read): a float32 pool whose state rows fill whole sublane tiles (dk a multiple
-    of 8) and at least half a lane tile (dv a multiple of 64), two heads'
-    k and q in one lane tile, and no partition context (a tp engine is
+    of 8) and at least half a lane tile (dv a multiple of 64), a step's
+    heads' k and q (and a vector decay's alpha) in one lane tile, and no
+    partition context (a tp engine is
     refused with linear layers). The toy widths of the CPU tests take the
     `jax.numpy` path, as small heads do in `ops.paged_kernel`."""
     _, H, dk, dv = pool.shape
     return (
         pool.dtype == jnp.float32 and dk % 8 == 0 and dv % 64 == 0
-        and 2 * _heads_a_step(H) <= LANES and _partition.spec is None
+        and 3 * _heads_a_step(H) <= LANES and _partition.spec is None
     )
 
 
 def _kernel(order_ref, table_ref, fresh_ref, live_ref, kq_ref, vab_ref,
-            pool_ref, o_ref, out_ref, *, heads: int):
+            pool_ref, o_ref, out_ref, *, heads: int, channel: bool):
     i = pl.program_id(0)
     n_live = live_ref[0]
 
@@ -77,19 +85,28 @@ def _kernel(order_ref, table_ref, fresh_ref, live_ref, kq_ref, vab_ref,
     @pl.when(i < n_live)
     def _():
         fresh = fresh_ref[order_ref[i]] != 0
-        kq = kq_ref[0, 0]  # (dk, 128): lane h is head h's k, lane heads + h its q
+        # (dk, 128): lane h is head h's k, lane heads + h its q and, with a
+        # decay a channel, lane 2 heads + h its alpha
+        kq = kq_ref[0, 0]
         for h in range(heads):
             S = jnp.where(fresh, 0.0, pool_ref[0, h])  # (dk, dv)
             k, q = kq[:, h:h + 1], kq[:, heads + h:heads + h + 1]  # (dk, 1)
-            # v, alpha and beta as (1, dv) rows (alpha and beta repeated along
+            # v, beta (and one alpha a head) as (1, dv) rows (repeated along
             # the lanes outside: Mosaic broadcasts along lanes or sublanes, not both)
-            v, a, b = (vab_ref[0, 0, j * heads + h:j * heads + h + 1, :] for j in range(3))
-            Sk = jnp.sum(S * k, axis=0, keepdims=True)  # (1, dv)
-            Sq = jnp.sum(S * q, axis=0, keepdims=True)
-            d = b * (v - a * Sk)
+            row = lambda j: vab_ref[0, 0, j * heads + h:j * heads + h + 1, :]
+            if channel:
+                v, b = row(0), row(1)
+                a = kq[:, 2 * heads + h:2 * heads + h + 1]  # (dk, 1): a state row's
+                Sk = jnp.sum(S * (a * k), axis=0, keepdims=True)  # (1, dv)
+                Sq = jnp.sum(S * (a * q), axis=0, keepdims=True)
+            else:
+                v, a, b = row(0), row(1), row(2)
+                Sk = a * jnp.sum(S * k, axis=0, keepdims=True)  # (1, dv)
+                Sq = a * jnp.sum(S * q, axis=0, keepdims=True)
+            d = b * (v - Sk)
             out_ref[0, h] = a * S + k * d
             # (k . q) d, summed down the sublanes
-            o_ref[0, 0, h:h + 1, :] = a * Sq + jnp.sum((k * q) * d, axis=0, keepdims=True)
+            o_ref[0, 0, h:h + 1, :] = Sq + jnp.sum((k * q) * d, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -107,10 +124,13 @@ def _call(pool, block, fresh, q, k, v, alpha, beta, *, interpret):
     table = jnp.minimum(block, nblk - 1).astype(jnp.int32)
     # k and q down the sublanes: (B, G, dk, 128), lane h head h's k, lane hb + h its q
     columns = lambda a: jnp.swapaxes(a.reshape(B, G, hb, dk), 2, 3)
-    kq = jnp.concatenate([columns(k), columns(q)], axis=-1)
-    kq = jnp.pad(kq, [(0, 0)] * 3 + [(0, LANES - 2 * hb)])
+    channel = alpha.ndim == 3  # one alpha a state row: down the sublanes beside k and q
+    down = [columns(k), columns(q)] + ([columns(alpha)] if channel else [])
+    kq = jnp.concatenate(down, axis=-1)
+    kq = jnp.pad(kq, [(0, 0)] * 3 + [(0, LANES - len(down) * hb)])
     rows = lambda a: jnp.broadcast_to(a.reshape(B, G, hb, 1), (B, G, hb, dv))
-    vab = jnp.concatenate([v.reshape(B, G, hb, dv), rows(alpha), rows(beta)], axis=2)
+    along = [v.reshape(B, G, hb, dv)] + ([] if channel else [rows(alpha)]) + [rows(beta)]
+    vab = jnp.concatenate(along, axis=2)
 
     def at(i, g, order_ref, table_ref, fresh_ref, live_ref):
         # the step's (row, group); past the last live row, the last live step's
@@ -127,13 +147,13 @@ def _call(pool, block, fresh, q, k, v, alpha, beta, *, interpret):
         return (refs[1][r], g, 0, 0)
 
     o, pool = pl.pallas_call(
-        functools.partial(_kernel, heads=hb),
+        functools.partial(_kernel, heads=hb, channel=channel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(B, G),
             in_specs=[
                 pl.BlockSpec((1, 1, dk, LANES), row_block),
-                pl.BlockSpec((1, 1, 3 * hb, dv), row_block),
+                pl.BlockSpec((1, 1, len(along) * hb, dv), row_block),
                 pl.BlockSpec((1, hb, dk, dv), state_block),
             ],
             out_specs=[
@@ -167,9 +187,10 @@ def paged_delta_step(pool, block, fresh, q, k, v, alpha, beta, *, interpret=None
     program and updated in place; block: (B,) int32, each row's state block
     (== blocks: the row holds none, its state is neither read nor written
     and its output is zero); fresh: (B,) bool, the row starts from a zero
-    state; q, k: (B, H, dk), v: (B, H, dv), alpha, beta: (B, H), float32.
-    Returns (o (B, H, dv), the pool). The rule and its float32 arithmetic
-    are `models.transformer._delta_step`'s."""
+    state; q, k: (B, H, dk), v: (B, H, dv), beta: (B, H), alpha: (B, H), one
+    decay a head, or (B, H, dk), one a state row; float32. Returns (o (B, H,
+    dv), the pool). The rule and its float32 arithmetic are
+    `models.transformer._delta_step`'s."""
     if interpret is None:
         interpret = _interpret_default()
     return _call(pool, block, fresh, q, k, v, alpha, beta, interpret=interpret)

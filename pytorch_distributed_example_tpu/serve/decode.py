@@ -93,7 +93,9 @@ def layer_paths(
     "recurrence_kernel") where `ops.paged_kernel` or `ops.delta_recurrence.
     delta_kernel_ok` says the kernel takes the call under the context the
     programs apply the model under, else the plain one's ("gather",
-    "recurrence", "chunk_scan", "conv_step", "conv_chunk")."""
+    "recurrence", "chunk_scan", "conv_step", "conv_chunk"); a linear layer
+    whose decay is a vector a head says so before its form
+    ("vector_recurrence_kernel", "vector_chunk_scan")."""
     cfg, tables = cache.model.cfg, cache.tables(slice(0, rows))
     if not isinstance(tables, tuple):
         tables = (tables,)

@@ -95,9 +95,13 @@ def _latent_path(cfg, avals, table, L):
 
 
 def _linear_path(cfg, avals, table, L):
+    """The form of the recurrence, "vector_" before it where the decay is a
+    vector a head (`cfg.linear_decay == "channel"`)."""
+    form = "vector_" if cfg.linear_decay == "channel" else ""
     if L > 1:
-        return "chunk_scan"
-    return "recurrence_kernel" if delta_kernel_ok(avals["state"]) else "recurrence"
+        return f"{form}chunk_scan"
+    kernel = "_kernel" if delta_kernel_ok(avals["state"]) else ""
+    return f"{form}recurrence{kernel}"
 
 
 def _conv_path(cfg, avals, table, L):

@@ -353,6 +353,91 @@ def test_the_recurrence_kernel_compiles_at_the_hybrid_cell_s_widths(topo, monkey
     assert mem.alias_size_in_bytes >= nbytes and mem.temp_size_in_bytes < nbytes // 8
 
 
+def test_the_recurrence_kernel_compiles_with_a_decay_a_state_row(topo, monkeypatch):
+    """`paged_delta_step` with a VECTOR decay (alpha: (B, H, dk)) at the
+    long-context cell's shape: 16 rows over a pool of 16 state blocks of 64
+    heads x 128 x 128 float32; the decay rides down the sublanes beside k
+    and q, the pool is aliased as in the scalar form."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_example_tpu.ops.delta_recurrence import (
+        delta_kernel_ok, paged_delta_step)
+
+    monkeypatch.setenv("TDX_FLASH_INTERPRET", "0")  # TPU target, CPU process
+    one = SingleDeviceSharding(topo.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    nblk, B, H, dk, dv = 16, 16, 64, 128, 128
+    pool = sd((nblk, H, dk, dv), jnp.float32)
+    assert delta_kernel_ok(pool)
+    vec = lambda d: sd((B, H, d), jnp.float32)
+    compiled = jax.jit(paged_delta_step, donate_argnums=(0,)).lower(
+        pool, sd((B,), jnp.int32), sd((B,), jnp.bool_), vec(dk), vec(dk), vec(dv),
+        vec(dk), sd((B, H), jnp.float32),
+    ).compile()
+    (call,) = _custom_calls(compiled.as_text())
+    assert "paged_delta_step" in call
+    mem = compiled.memory_analysis()
+    nbytes = nblk * H * dk * dv * 4
+    assert mem.alias_size_in_bytes >= nbytes and mem.temp_size_in_bytes < nbytes // 8
+
+
+def test_the_longctx_cell_s_step_and_chunks_fit_the_chip_beside_its_pool(topo, monkeypatch):
+    """`serve_solar_longctx_c16` as the engine builds it: the model from the
+    configuration's glue at its published widths, 16 slots, tables of 2048
+    pages, a 32768-block K/V pool of ONE softmax layer and a 16-block state
+    pool of three linear layers (64 x 128 x 128 float32 and a 3-token tail).
+    The step and the three chunk buckets compile for v5e: the recurrence
+    kernel in every linear layer of the step (the chunk's scan is XLA's), the
+    paged decode / chunk kernel in the softmax layer, the grouped kernel in
+    every sparse MLP (1280-wide experts in tiles of 256), the donated pools
+    updated in place, and what a call holds is under the chip's 16 GB."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_example_tpu.serve.cache import init_paged_cache
+    from pytorch_distributed_example_tpu.serve.decode import paged_programs
+
+    config, eng, model, params, sd, placed = _cell_as_the_engine_builds_it(
+        topo, monkeypatch, "serve_solar_longctx_c16")
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(params))
+    assert weights == pytest.approx(6.62e9, rel=2e-3)
+    S, bs = eng["slots"], eng["block_size"]
+    nb = eng["max_seq_len"] // bs
+    assert nb == 2048
+    tree = placed(jax.eval_shape(
+        lambda: init_paged_cache(model, eng["pool_blocks"], bs, state_blocks=S)))
+    assert tree["layers_0"]["attn"]["k"].shape == (eng["pool_blocks"], bs, 8, 128)
+    assert tree["layers_1"]["linear_attn"]["state"].shape == (S, 64, 128, 128)
+    assert tree["layers_1"]["linear_attn"]["conv"].shape == (S, 3, 3 * 64 * 128)
+    pools = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    assert pools == pytest.approx(2.15e9 + 0.21e9, rel=5e-3)
+    chunk, _, _, step = paged_programs(model, 0.0, None)
+    tables = lambda rows: (sd((rows, nb), jnp.int32), sd((rows, 1), jnp.int32))
+    lowered = {"step": step.lower(params, tree, sd((S,), jnp.int32), sd((S,), jnp.int32),
+                                  sd((S, 2), jnp.uint32), tables(S))}
+    for C in (128, 256, eng["prefill_chunk_tokens"]):
+        lowered[f"chunk{C}"] = chunk.lower(
+            params, tree, sd((1, C), jnp.int32), tables(1), sd((), jnp.int32))
+    for name, low in lowered.items():
+        compiled = low.compile()
+        calls = _custom_calls(compiled.as_text())
+        kernel = "paged_decode_attention" if name == "step" else "paged_chunk_attention"
+        assert sum(kernel in c for c in calls) == 1, name
+        assert sum("paged_delta_step" in c for c in calls) == (3 if name == "step" else 0), name
+        # a chunk's sparse layer holds the kernel in both branches of
+        # `_share_of_assignments` (a share of the rows, or every one)
+        assert sum("grouped_swiglu" in c for c in calls) == (4 if name == "step" else 8), name
+        if name == "step":
+            assert _expert_calls(calls) == 4
+        assert "ragged-dot" not in compiled.as_text(), name
+        m = compiled.memory_analysis()
+        assert m.alias_size_in_bytes >= pools  # both pools are written in place
+        held = _held(m)
+        assert held < 10.0e9 < 16e9, (name, held)
+
+
 @pytest.mark.parametrize("heads,window,nblk", [(48, None, 16384), (64, 512, 2112)])
 def test_paged_decode_kernel_compiles_at_the_patterned_cell_s_widths(
     topo, monkeypatch, heads, window, nblk

@@ -205,6 +205,41 @@ EXPECTED = {
         "kv_scatter": LAYER + r"kv_scatter/scatter",
         "moe": MLP + r"moe/experts/ragged_dot",
     },
+    # a model that mixes linear layers whose decay is a vector a head (KDA:
+    # low-rank gates under `kda_gate`) with NoPE attention under an
+    # elementwise gate, every MLP sparse with a shared expert: the linear
+    # kind's scopes where the hybrid model has them, `kda_gate` beside them
+    # (the running sum of a chunk is the scan's own: no scope inside it)
+    "kda_step": {
+        "linear_attn": LINEAR + r"(q|k|v|o|b)_proj/dot_general",
+        "kda_gate": LINEAR + r"kda_gate/(f|g)_proj_(a|b)/dot_general",
+        "softplus": LINEAR + r"kda_gate/jit\(softplus\)",
+        "short_conv": LINEAR + r"short_conv/",
+        "recurrence": LINEAR + r"recurrence/",
+        "state_write": LINEAR + r"recurrence/scatter",
+        "gated_norm": LINEAR + r"gated_norm/",
+        "attn_gate": LAYER + r"attn_gate/out_gate/dot_general",
+        "cache_attention": LAYER + r"cache_attention/.*dot_general",
+        "moe": MLP + r"moe/router/logistic",
+        "shared_expert": MLP + r"moe/shared_expert/(gate|up|down)_proj/dot_general",
+        "sample": r"^jit\(step\)/sample/",
+    },
+    "kda_step_kernel": {
+        "recurrence": LINEAR + r"recurrence/jit\(_call\)$",
+        "kernel_body": r"^paged_delta_step/",
+        "tail_write": LINEAR + r"recurrence/scatter",
+    },
+    "kda_prefill_chunk": {
+        "kda_gate": LINEAR + r"kda_gate/(f|g)_proj_(a|b)/dot_general",
+        "running_sum": LINEAR + r"chunk_scan/jit\(cumsum\)",
+        "short_conv": LINEAR + r"short_conv/",
+        "chunk_scan": LINEAR + r"chunk_scan/.*dot_general",
+        "state_write": LINEAR + r"chunk_scan/scatter",
+        "gated_norm": LINEAR + r"gated_norm/",
+        "attn_gate": LAYER + r"attn_gate/",
+        "kv_scatter": LAYER + r"kv_scatter/scatter",
+        "moe": MLP + r"moe/experts/ragged_dot",
+    },
     # the kernels' calls carry their names (`name=` on the `pallas_call`), so
     # the trace says which form of the backward a step ran: in the resident
     # regime ONE call a layer (the backward's path goes through `checkpoint`)
@@ -273,6 +308,28 @@ def _hybrid_model(key_dim=8, value_dim=12):
         use_flash=False, post_norm=True, qk_norm=True, linear_heads=2,
         linear_key_dim=key_dim, linear_value_dim=value_dim, linear_neg_eigval=True,
         layers=(LayerSpec("linear"),) * 3 + (full,))
+    model = TransformerLM(cfg)
+    return model, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+
+
+def _kda_model(width=8):
+    """A softmax layer (NoPE, an elementwise gate) and two linear ones with a
+    decay a key channel through rank-4 pairs, sparse MLPs with a shared
+    expert and half the experts held."""
+    from pytorch_distributed_example_tpu.models.transformer import LayerSpec, RopeSpec
+
+    nope = RopeSpec(rotary_fraction=0.0)
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=3, d_ff=64, max_seq_len=64,
+        use_flash=False, rope_pairs="halves", attn_out_gate=True, linear_heads=2,
+        linear_key_dim=width, linear_value_dim=width, linear_neg_eigval=True,
+        linear_decay="channel", linear_gate_rank=4, sparse_score="sigmoid",
+        sparse_choice_bias=True, sparse_experts=4, sparse_top_k=2, sparse_d_ff=16,
+        shared_d_ff=16, experts_held=(0, 2),
+        layers=(LayerSpec("full", rope=nope, mlp="sparse"),
+                LayerSpec("linear", rope=nope, mlp="sparse"),
+                LayerSpec("linear", rope=nope, mlp="sparse")))
     model = TransformerLM(cfg)
     return model, jax.eval_shape(
         model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
@@ -376,6 +433,16 @@ def serve_paths():
         cvars["params"], ctree, lanes, lanes, rngs, (bt, state)))
     out["conv_prefill_chunk"] = _paths(cchunk.lower(
         cvars["params"], ctree, jnp.zeros((1, 16), jnp.int32), (bt[:1], state[:1]), 0))
+    for name, width in (("kda_step", 8), ("kda_step_kernel", 64)):
+        kda, kvars = _kda_model(width)
+        kchunk, _, _, kstep = paged_programs(kda, 0.0, None)
+        ktree = init_paged_cache(kda, nblk, bs, state_blocks=S)
+        out[name] = _paths(kstep.lower(
+            kvars["params"], ktree, lanes, lanes, rngs, (bt, state)))
+        if width == 8:
+            out["kda_prefill_chunk"] = _paths(kchunk.lower(
+                kvars["params"], ktree, jnp.zeros((1, 16), jnp.int32),
+                (bt[:1], state[:1]), 0))
     wide_hybrid, wvars = _hybrid_model(16, 64)
     out["hybrid_step_kernel"] = _paths(paged_programs(wide_hybrid, 0.0, None)[3].lower(
         wvars["params"], init_paged_cache(wide_hybrid, nblk, bs, state_blocks=S),
@@ -435,7 +502,8 @@ SERVE = ("step", "step_kernel", "prefill_chunk", "first_token", "pattern_step",
          "pattern_prefill_chunk", "pattern_step_kernel", "hybrid_step",
          "hybrid_step_kernel", "hybrid_prefill_chunk", "latent_step",
          "latent_prefill_chunk", "latent_step_kernel", "latent_prefill_chunk_kernel",
-         "streams_step", "streams_prefill_chunk", "conv_step", "conv_prefill_chunk")
+         "streams_step", "streams_prefill_chunk", "conv_step", "conv_prefill_chunk",
+         "kda_step", "kda_step_kernel", "kda_prefill_chunk")
 CASES = [(prog, scope) for prog, scopes_ in EXPECTED.items() for scope in scopes_]
 
 
@@ -600,18 +668,29 @@ READ_BY = {
     "decode_hc_sinkhorn_ms": ["streams_step"],
     "prefill_hc_ms": ["streams_prefill_chunk"],
     "hc_chunk_roofline": ["streams_prefill_chunk"],
-    "decode_linear_attention_ms": ["hybrid_step", "hybrid_step_kernel"],
-    "decode_recurrence_ms": ["hybrid_step", "hybrid_step_kernel"],
-    "recurrence_decode_roofline": ["hybrid_step", "hybrid_step_kernel"],
-    "prefill_linear_attention_ms": ["hybrid_prefill_chunk"],
-    "prefill_chunk_scan_ms": ["hybrid_prefill_chunk"],
+    "decode_linear_attention_ms": ["hybrid_step", "hybrid_step_kernel", "kda_step",
+                                   "kda_step_kernel"],
+    "decode_recurrence_ms": ["hybrid_step", "hybrid_step_kernel", "kda_step",
+                             "kda_step_kernel"],
+    "recurrence_decode_roofline": ["hybrid_step", "hybrid_step_kernel", "kda_step",
+                                   "kda_step_kernel"],
+    "prefill_linear_attention_ms": ["hybrid_prefill_chunk", "kda_prefill_chunk"],
+    "prefill_chunk_scan_ms": ["hybrid_prefill_chunk", "kda_prefill_chunk"],
+    "prefill_kda_gate_ms": ["kda_prefill_chunk"],
+    "kda_decode_linear_attention_ms": ["kda_step", "kda_step_kernel"],
+    "kda_decode_recurrence_ms": ["kda_step", "kda_step_kernel"],
+    "kda_recurrence_decode_roofline": ["kda_step", "kda_step_kernel"],
+    "kda_prefill_linear_attention_ms": ["kda_prefill_chunk"],
+    "kda_prefill_chunk_scan_ms": ["kda_prefill_chunk"],
+    "chunk_scan_roofline": ["kda_prefill_chunk"],
     "decode_gated_conv_ms": ["conv_step"],
     "prefill_gated_conv_ms": ["conv_prefill_chunk"],
     "gqa64_decode_roofline": ["conv_step"],
-    "decode_moe_ms": ["pattern_step", "latent_step", "conv_step"],
-    "prefill_moe_ms": ["pattern_prefill_chunk", "latent_prefill_chunk", "conv_prefill_chunk"],
+    "decode_moe_ms": ["pattern_step", "latent_step", "conv_step", "kda_step"],
+    "prefill_moe_ms": ["pattern_prefill_chunk", "latent_prefill_chunk", "conv_prefill_chunk",
+                       "kda_prefill_chunk"],
     "decode_window_attention_ms": ["pattern_step", "pattern_step_kernel"],
-    "moe_decode_roofline": ["pattern_step", "conv_step"],
+    "moe_decode_roofline": ["pattern_step", "conv_step", "kda_step"],
     "prefill_cache_attention_ms": ["prefill_chunk"],
     "train_mlp_ms": ["ddp", "fsdp"],
     "train_attention_ms": ["ddp", "fsdp"],
